@@ -7,7 +7,7 @@
         test-compile compile-gates test-chaos test-obs test-serving \
         serving-gates test-pipeline test-stream stream-gates test-slo \
         slo-gates quality-gates test-quality e2e bench bench-regress \
-        wheel clean lint \
+        chip-smoke chip-smoke-cpu wheel clean lint \
         check-invariants
 
 all: proto native test
@@ -227,8 +227,22 @@ e2e:
 	python -m pytest tests/test_allreduce_e2e.py tests/test_ps_e2e.py \
 	       tests/test_cluster_eval_e2e.py tests/test_k8s.py -q
 
+# Needs the attached TPU chip (one process per chip): bench.py exits
+# non-zero before any row when jax finds no TPU.
 bench:
 	python bench.py
+
+# The quickest proof that the system still starts on the chip: train ->
+# export -> serve at DeepFM's full width through the normal entry
+# points, plus the Pallas kernels against their XLA twins.  From a
+# sandbox without a chip: `chiprun -- python chip_smoke.py`.
+chip-smoke:
+	python chip_smoke.py
+
+# The same script at tiny size on the CPU backend (rehearse before
+# spending chip time); its last line says ok=false and names the cpu.
+chip-smoke-cpu:
+	python chip_smoke.py --cpu
 
 # The canonical way to publish a perf claim (ROADMAP item 5): run the
 # bench, gate every tracked metric against BASELINE.md's recorded
@@ -242,6 +256,6 @@ wheel:
 	python -m pip wheel --no-deps --wheel-dir dist .
 
 clean:
-	rm -rf dist build .elasticdl_build
+	rm -rf dist build .elasticdl_build .jax_cache
 	rm -f elasticdl_tpu/native/libedl_kernels.so
 	find . -name __pycache__ -type d -prune -exec rm -rf {} +

@@ -133,7 +133,6 @@ _SPEC_KWARGS = frozenset(
         "input_output_aliases",
         "interpret",
         "check_vma",
-        "check_rep",
         "axis_name",
         "axis_size",
         "nondiff_argnums",

@@ -231,7 +231,9 @@ def add_train_arguments(parser: argparse.ArgumentParser):
     parser.add_argument(
         "--jax_compilation_cache_dir", default="",
         help="Persistent XLA compilation cache directory (shared across "
-        "worker restarts). Elastic recovery restarts the world with fresh "
+        "worker restarts); used only when JAX_COMPILATION_CACHE_DIR is "
+        "unset, and defaults to <repo>/.jax_cache (common/"
+        "compile_cache.py). Elastic recovery restarts the world with fresh "
         "processes; with the cache, the re-formed world's compiles are "
         "disk hits instead of recompiles — the dominant recovery cost "
         "after process start (BASELINE.md elasticity numbers).",

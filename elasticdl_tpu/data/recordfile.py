@@ -33,6 +33,7 @@ def _native():
 
     return native_mod.record_file()
 
+
 MAGIC = b"ETRF"
 FOOTER_MAGIC = b"FTRE"
 VERSION = 1

@@ -223,6 +223,7 @@ def _build_worker_manager(args, master, rendezvous, worker_env,
             ),
             **common,
         )
+    _refuse_workers_sharing_a_chip(args.num_workers, worker_env)
     return LocalProcessManager(
         worker_argv_fn=worker_argv_from_args(args, master.addr),
         worker_env=worker_env,
@@ -236,6 +237,40 @@ def _build_worker_manager(args, master, rendezvous, worker_env,
         ),
         **common,
     )
+
+
+def _local_tpu_chips() -> int:
+    """TPU chips attached to this host, read from sysfs by jax's own
+    scan.  No backend is initialized: the master stays off the
+    accelerator, or its worker could not take the chip."""
+    from jax._src import hardware_utils
+
+    return hardware_utils.num_available_tpu_chips_and_device_id()[0]
+
+
+def _refuse_workers_sharing_a_chip(num_workers: int, worker_env) -> None:
+    """A chip belongs to ONE process at a time, and every local worker
+    builds its mesh over all of the host's devices (parallel/mesh.py).
+    Seen on a one-chip v5e host with --num_workers=2: the second worker
+    to reach the TPU dies in libtpu ("Internal error when accessing
+    libtpu multi-process lockfile"), the first waits in world formation,
+    and the job hangs.  Refuse the combination up front, with the
+    reason."""
+    platforms = worker_env.get(
+        "JAX_PLATFORMS", os.environ.get("JAX_PLATFORMS", "")
+    )
+    if num_workers <= 1 or platforms == "cpu":
+        return
+    chips = _local_tpu_chips()
+    if chips:
+        raise ValueError(
+            f"--num_workers={num_workers} cannot work on this host: it has "
+            f"{chips} TPU chip(s), a chip belongs to one process at a time, "
+            "and every local worker takes all of them. One worker process "
+            "drives every chip of a host — use --num_workers=1 (and "
+            "--mesh_model_axis to shape the mesh) — or set JAX_PLATFORMS=cpu "
+            "for a multi-process CPU world."
+        )
 
 
 def _ensure_elastic_checkpointing(args, mode: str):
@@ -293,10 +328,6 @@ def run_allreduce_job(args, mode: str = Mode.TRAINING) -> int:
             master.task_manager.create_evaluation_tasks(model_version=0)
 
     worker_env = {}
-    if os.environ.get("ELASTICDL_FORCE_PLATFORM"):
-        worker_env["ELASTICDL_FORCE_PLATFORM"] = os.environ[
-            "ELASTICDL_FORCE_PLATFORM"
-        ]
     # Extra worker env as 'K=V;K2=V2' (e.g. XLA_FLAGS overrides in tests).
     for pair in os.environ.get("ELASTICDL_WORKER_ENV", "").split(";"):
         if "=" in pair:

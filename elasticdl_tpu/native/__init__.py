@@ -41,14 +41,13 @@ def build_native(force: bool = False) -> Optional[str]:
             os.path.getmtime(src) for src in _SOURCES
         ):
             return _SO_PATH
-        # Unlink before relinking: if the stale .so is already dlopen'd,
-        # a fresh inode is the only way a retry CDLL sees the new build
-        # (dlopen caches by pathname/inode), and overwriting a mapped
-        # file risks SIGBUS in the running process.
-        try:
-            os.unlink(_SO_PATH)
-        except OSError:
-            pass
+    # Link to a private name and rename over the target: a fresh inode
+    # is the only way a retry CDLL sees the new build (dlopen caches by
+    # pathname/inode) and overwriting a mapped file risks SIGBUS in a
+    # running process — and the rename is atomic, so another process
+    # loading or building at the same moment (two workers starting on a
+    # fresh checkout) sees a whole library, the old one or the new.
+    tmp_path = os.path.join(_DIR, f"libedl_kernels.{os.getpid()}.tmp.so")
     # Prefer linking zlib for its optimized CRC-32 (measured 2.1x the
     # in-file slicing-by-8 — recordfile.cc); fall back to the
     # self-contained build where zlib headers aren't installed.
@@ -61,10 +60,11 @@ def build_native(force: bool = False) -> Optional[str]:
             try:
                 subprocess.run(
                     [compiler, "-O3", "-shared", "-fPIC", "-std=c++17",
-                     *extra, *_SOURCES, "-o", _SO_PATH,
+                     *extra, *_SOURCES, "-o", tmp_path,
                      *(["-lz"] if extra else [])],
                     check=True, capture_output=True, timeout=120,
                 )
+                os.replace(tmp_path, _SO_PATH)
                 if zlib_failed:
                     # Succeeded only WITHOUT zlib: say so — the silent
                     # symptom is large-record CRC at ~1.8 GB/s instead
@@ -384,11 +384,16 @@ def record_file() -> Optional[NativeRecordFile]:
     broken native build must never take the data plane down."""
     global _record_file, _record_file_failed
     if _record_file is None and not _record_file_failed:
+        # Said once, at the first ETRF access of the process, so a
+        # silent fall to the slow codec shows in the job's own log
+        # (chip_smoke.py requires the first line in the worker's).
         try:
             _record_file = NativeRecordFile()
+            logger.info("ETRF record codec: native")
         except Exception:
             logger.exception(
-                "Native record file unavailable; using the Python codec"
+                "ETRF record codec: python (native record file "
+                "unavailable)"
             )
             _record_file_failed = True
     return _record_file

@@ -80,11 +80,33 @@ BOUNDS = ("compute", "hbm", "host", "sparse-row")
 #: size budget — see WorkerTelemetry.snapshot_json).
 MAX_SNAPSHOT_WINDOWS = 5
 
-# -- chip ceilings (MUST mirror bench.py's roofline constants; a tier-1
-# test asserts the two never diverge) ---------------------------------
-PEAK_BF16_FLOPS = 197e12          # v5e bf16 peak
-HBM_BYTES_PER_SEC = 819e9         # v5e HBM bandwidth
-SPARSE_FLOOR_NS_PER_ROW = 25.0    # measured sparse gather/scatter floor
+#: `jax.devices()[0].device_kind` of one TPU v5e chip.
+V5E = "TPU v5 lite"
+
+#: Chip ceilings, keyed by `device_kind` — the ONE table (bench.py
+#: imports it).  bf16 peak and HBM bandwidth are the published peaks
+#: (Google Cloud documentation, "TPU v5e"); the sparse floor is this
+#: repo's own measured gather/scatter floor on that chip (BASELINE.md).
+#: A device that is not listed has no ceilings: `roofline()` then emits
+#: no mfu / floor_frac / bw_frac, and bench.py refuses to run.
+DEVICE_PEAKS: Dict[str, Dict[str, float]] = {
+    V5E: {
+        "bf16_flops": 197e12,
+        "hbm_bytes_per_sec": 819e9,
+        "sparse_floor_ns_per_row": 25.0,
+    },
+}
+
+
+def local_device_kind() -> Optional[str]:
+    """`device_kind` of this process's first local device; None when jax
+    is absent or the backend cannot say."""
+    try:
+        import jax
+
+        return jax.local_devices()[0].device_kind
+    except Exception:  # any backend quirk: anatomy must never crash a step
+        return None
 
 #: The transformer bench shape (mirrors bench.TRANSFORMER_BENCH — same
 #: single-definition rule, cross-checked by the same tier-1 test).
@@ -108,8 +130,9 @@ def transformer_flops_per_token(cfg: dict = TRANSFORMER_BENCH) -> float:
 #: Per-zoo-model analytic cost table.  ``train_flops_per_example`` is
 #: TRAIN flops (3x fwd); the optional resource keys drive the roofline
 #: verdict the way BENCH_r04 derived it by hand:
-#: ``sparse_rows_per_example`` -> the 25 ns/row gather/scatter floor,
-#: ``hbm_bytes_per_example`` -> the 819 GB/s bandwidth roofline.
+#: ``sparse_rows_per_example`` -> the device's gather/scatter floor,
+#: ``hbm_bytes_per_example`` -> its HBM bandwidth roofline
+#: (DEVICE_PEAKS).
 MODEL_FLOPS: Dict[str, dict] = {
     # Dense tower is ~50k params; sparse row traffic is the wall
     # (26 embedding rows/sample — BENCH_r04 `bound: sparse-row-count`).
@@ -150,22 +173,26 @@ def infer_model_key(name: str) -> Optional[str]:
 
 
 def roofline(examples_per_s: float, fractions: Dict[str, float],
-             model_key: Optional[str]) -> dict:
+             model_key: Optional[str], device_kind: Optional[str]) -> dict:
     """MFU + ``bound:`` verdict for a measured rate, the BENCH_r04 way.
 
     Priority: a host-starved step is host-bound no matter the model
     (the chip's ceilings are unreachable while it waits); then the
     model's named scarce resource (sparse row traffic / HBM bytes);
-    compute is the default when the MXU is the binding engine."""
+    compute is the default when the MXU is the binding engine.
+
+    Every fraction is against `device_kind`'s row of DEVICE_PEAKS; a
+    device without one (the CPU included) gets the host verdict only."""
     out: dict = {}
-    spec = MODEL_FLOPS.get(model_key or "")
+    host_frac = sum(fractions.get(p, 0.0) for p in HOST_PHASES)
+    peaks = DEVICE_PEAKS.get(device_kind or "")
+    spec = MODEL_FLOPS.get(model_key or "") if peaks else None
     if spec and examples_per_s > 0:
         out["mfu"] = round(
             examples_per_s * spec["train_flops_per_example"]
-            / PEAK_BF16_FLOPS,
+            / peaks["bf16_flops"],
             4,
         )
-    host_frac = sum(fractions.get(p, 0.0) for p in HOST_PHASES)
     if host_frac > 0.5:
         out["bound"] = "host"
         return out
@@ -174,7 +201,7 @@ def roofline(examples_per_s: float, fractions: Dict[str, float],
         if rows:
             ns_per_row = 1e9 / (examples_per_s * rows)
             out["floor_frac"] = round(
-                SPARSE_FLOOR_NS_PER_ROW / ns_per_row, 3
+                peaks["sparse_floor_ns_per_row"] / ns_per_row, 3
             )
             if out["floor_frac"] > 0.5:
                 out["bound"] = "sparse-row"
@@ -182,7 +209,7 @@ def roofline(examples_per_s: float, fractions: Dict[str, float],
         hbm_bytes = spec.get("hbm_bytes_per_example")
         if hbm_bytes:
             out["bw_frac"] = round(
-                examples_per_s * hbm_bytes / HBM_BYTES_PER_SEC, 3
+                examples_per_s * hbm_bytes / peaks["hbm_bytes_per_sec"], 3
             )
             if out["bw_frac"] > out.get("mfu", 0.0):
                 out["bound"] = "hbm"
@@ -320,10 +347,14 @@ class StepAnatomy:
         worker_id: int = 0,
         clock: Callable[[], float] = time.monotonic,
         max_windows: int = MAX_SNAPSHOT_WINDOWS,
+        device_kind: Optional[str] = None,
     ):
         self._lock = make_lock("StepAnatomy._lock")
         self._worker_id = int(worker_id)
         self._clock = clock
+        # The device the roofline fractions are judged against; None =
+        # ask jax at the first snapshot (the worker's own device).
+        self._device_kind = device_kind
         self._watcher = RetraceWatcher()
         self._model_key: Optional[str] = None
         self._open_phase: Optional[str] = None
@@ -507,7 +538,12 @@ class StepAnatomy:
         accounted = sum(totals.values())
         if accounted > 0 and examples > 0:
             fractions = phase_fractions(totals)
-            snap.update(roofline(examples / accounted, fractions, model_key))
+            kind = self._device_kind
+            if kind is None:
+                kind = self._device_kind = local_device_kind()
+            snap.update(
+                roofline(examples / accounted, fractions, model_key, kind)
+            )
         return snap
 
 

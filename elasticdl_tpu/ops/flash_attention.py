@@ -447,14 +447,8 @@ def flash_ring_step_carry(q, k_blk, v_blk, acc, lse, q_pos, k_pos, *,
 
 
 def _vma_of(x):
-    """`x`'s varying-mesh-axes type, or None on jax versions without
-    `jax.typeof` (pre-typed-vma releases: there is no vma type system
-    to satisfy, and the ring runs shard_map with the check disabled via
-    the check_rep fallback — see parallel/compile.shard_map_call)."""
-    typeof = getattr(jax, "typeof", None)
-    if typeof is None:
-        return None
-    return getattr(typeof(x), "vma", None)
+    """`x`'s varying-mesh-axes type (empty outside shard_map)."""
+    return getattr(jax.typeof(x), "vma", None)
 
 
 def _out_struct(shape, dtype, like):
@@ -476,7 +470,7 @@ def _match_vma(x, like):
         return x
     have = _vma_of(x) or frozenset()
     missing = tuple(set(want) - set(have))
-    return jax.lax.pvary(x, missing) if missing else x
+    return jax.lax.pcast(x, missing, to="varying") if missing else x
 
 
 def _dq_ring_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,

@@ -19,8 +19,9 @@ side — 2.4x on the transformer):
 
 ``fused_lookup``       gather-and-lane-select in one kernel: each
                        storage row is DMA'd HBM->VMEM once, the packed
-                       slot's lanes are selected with an EXACT f32
-                       dynamic slice (no MXU contraction, so no
+                       slot's lanes are selected EXACTLY (a lane rotate
+                       by the slot's dynamic offset plus a static slice
+                       — what Mosaic lowers; no MXU contraction, so no
                        precision= escape hatch needed), and only the
                        compact ``[n, dim_pad]`` result is written back.
 ``fused_dedup_apply``  the optimizer update in one pass: the sort-free
@@ -56,7 +57,12 @@ unmeasured code — flip AUTO_FUSED_READY once the evidence lands.
 Every kernel runs in Pallas interpret mode off-TPU (same
 ``_use_interpret()`` pattern as flash_attention), so tier-1 CPU tests
 exercise the real kernel bodies, and ``scripts/convergence_ab.py
---sparse-kernel fused`` gates end-to-end training quality.
+--sparse-kernel fused`` gates end-to-end training quality.  What the
+interpreter cannot see — Mosaic's lowering, the chip's 1 MB of SMEM,
+the tiling — is held by tests/test_tpu_compile.py (each kernel compiled
+with ``interpret=False`` for a described v5e at DeepFM's widths) and by
+chip_smoke.py's kernels phase (each kernel against its XLA twin on the
+chip: bit-equal at PR 21).
 
 Sharded dispatch (round 7): ``pl.pallas_call`` is not
 SPMD-partitionable the way the XLA gather/scatter ops are, so on a
@@ -90,13 +96,18 @@ from jax.experimental.pallas import tpu as pltpu
 from elasticdl_tpu.parallel import packed as pk
 from elasticdl_tpu.parallel.packed import PackedSpec
 
-#: Ids processed per grid step.  VMEM cost per step is bounded by
-#: TILE x dim_padded f32 (the gsum / output tiles) + a double-buffered
-#: pair of 512 B row scratches — ~130 KB at the default, far under the
-#: ~16 MB scoped-VMEM budget (see docs/design.md "VMEM budget math").
+#: Ids processed per grid step.  VMEM per step, in TILED bytes (a block's
+#: lane dim pads to 128 and its sublane dim to 8): the gsum / output
+#: tile is TILE x 128 lanes x 4 B = 64 KB, double-buffered by the
+#: pipeline = 128 KB, plus the row scratches (one (8, 128) f32 tile =
+#: 4 KB per storage row held) — ~140 KB at the default, far under the
+#: 16 MB scoped-VMEM budget the compile for a v5e accepts.  The per-id
+#: scalars (block, lane offset, touched) ride in SMEM one TILE at a
+#: time: the whole id arrays do not fit its 1 MB (`_smem_tile`).
 DEFAULT_IDS_PER_TILE = 128
-#: Batch rows per grid step of the FM kernel (x fields x (1+dim) f32 for
-#: the bet/acts tiles — 8 x 26 x 16 x 4 B = 13 KB at DeepFM shapes).
+#: Batch rows per grid step of the FM kernel.  Its bet and acts blocks
+#: are (8, fields, dim) -> tiled (8, 32, 128) f32 = 128 KB each at
+#: DeepFM's 26 fields x dim 9, double-buffered = 512 KB for the pair.
 DEFAULT_FM_BATCH_TILE = 8
 
 KERNELS = ("xla", "fused", "auto")
@@ -241,6 +252,16 @@ def _block_and_lane(spec: PackedSpec, ids):
     return blocks, lane0
 
 
+def _slot_to_lane0(row, lane0):
+    """Rotate a ``[1, block_width]`` storage row so the packed slot that
+    starts at lane `lane0` starts at lane 0.  Mosaic has no value-level
+    dynamic lane slice; a lane rotate by a dynamic amount plus a STATIC
+    slice (or an iota mask) is what it lowers, and it moves bits
+    unchanged, so the exactness contracts hold."""
+    width = row.shape[-1]
+    return pltpu.roll(row, jax.lax.rem(width - lane0, width), 1)
+
+
 # ----------------------------------------------------------------------
 # fused lookup: gather + lane select in one kernel
 # ----------------------------------------------------------------------
@@ -252,11 +273,10 @@ def _lookup_kernel(blocks_ref, lane0_ref, table_ref, out_ref, rows, sem,
     HBM->VMEM (double-buffered: row i+1's fetch overlaps row i's
     select) and write only the slot's dim_padded lanes to the compact
     output block."""
-    g = pl.program_id(0)
 
     def fetch(i, slot):
         return pltpu.make_async_copy(
-            table_ref.at[pl.ds(blocks_ref[g * tile + i], 1), :],
+            table_ref.at[pl.ds(blocks_ref[0, i], 1), :],
             rows.at[slot],
             sem.at[slot],
         )
@@ -271,14 +291,27 @@ def _lookup_kernel(blocks_ref, lane0_ref, table_ref, out_ref, rows, sem,
             fetch(i + 1, 1 - slot).start()
 
         fetch(i, slot).wait()
-        row = rows[slot, 0, :]
-        sel = jax.lax.dynamic_slice(
-            row, (lane0_ref[g * tile + i],), (dim_padded,)
-        )
-        out_ref[pl.ds(i, 1), :] = sel[None, :]
+        sel = _slot_to_lane0(rows[slot], lane0_ref[0, i])
+        out_ref[pl.ds(i, 1), :] = sel[:, :dim_padded]
         return 0
 
     jax.lax.fori_loop(0, tile, body, 0)
+
+
+def _smem_tile(tile):
+    """BlockSpec handing each grid step its own `tile` scalars in SMEM.
+    (Scalar-prefetching the WHOLE id arrays does not fit: the chip has
+    1 MB of SMEM and DeepFM's 8192 x 26 ids are 832 KB per array.)"""
+    return pl.BlockSpec(
+        (None, 1, tile), lambda g: (g, 0, 0), memory_space=pltpu.SMEM
+    )
+
+
+def _scalar_tiles(x, tile):
+    """[n_pad] per-id scalars -> the [n_pad // tile, 1, tile] array
+    `_smem_tile` blocks (SMEM blocks must span the array's last two
+    dims, so the tile index gets a leading dim of its own)."""
+    return x.reshape((-1, 1, tile))
 
 
 def _lookup_impl(spec: PackedSpec, interpret: bool, tile: int, packed, ids):
@@ -293,21 +326,20 @@ def _lookup_impl(spec: PackedSpec, interpret: bool, tile: int, packed, ids):
         functools.partial(
             _lookup_kernel, tile=tile, dim_padded=spec.dim_padded
         ),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(n_pad // tile,),
-            in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
-            out_specs=pl.BlockSpec(
-                (tile, spec.dim_padded), lambda g, *_: (g, 0)
-            ),
-            scratch_shapes=[
-                pltpu.VMEM((2, 1, spec.block_width), packed.dtype),
-                pltpu.SemaphoreType.DMA((2,)),
-            ],
-        ),
+        grid=(n_pad // tile,),
+        in_specs=[
+            _smem_tile(tile),
+            _smem_tile(tile),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((tile, spec.dim_padded), lambda g: (g, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((2, 1, spec.block_width), packed.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+        ],
         out_shape=jax.ShapeDtypeStruct((n_pad, spec.dim_padded), packed.dtype),
         interpret=interpret,
-    )(blocks, lane0, packed)
+    )(_scalar_tiles(blocks, tile), _scalar_tiles(lane0, tile), packed)
     return out[:n, : spec.dim]
 
 
@@ -495,24 +527,34 @@ def _dedup_apply_kernel(blocks_ref, lane0_ref, touched_ref, gsum_ref,
     DMA the table row + slot rows HBM->VMEM (all fetches in flight
     together), apply the optimizer math to the slot's lanes, DMA the
     updated rows back.  The TPU grid is sequential, so two
-    representatives sharing a storage row serialize correctly."""
+    representatives sharing a storage row serialize correctly.
+
+    The math runs on whole block_width-lane rows rotated so the slot
+    sits at lane 0 (`_slot_to_lane0`), and only the slot's dim_padded
+    lanes are selected into the written row: elementwise ops see the
+    same per-lane inputs as a dim_padded-wide slice would, so the bits
+    are those of the narrow formulation, and a vreg is 128 lanes wide
+    either way."""
     # refs layout: n_tables ANY-space input refs, n_tables output refs
     # (input_output_aliases makes each pair one buffer — read and write
     # through the OUTPUT ref), then scratch: rows VMEM
-    # [n_tables, 1, block_width] and the in/out DMA semaphores.
+    # [n_tables, 1, block_width], the widened-gradient row and the
+    # in/out DMA semaphores.
     tables = refs[n_tables : 2 * n_tables]
-    rows, sem_in, sem_out = refs[2 * n_tables :]
-    g = pl.program_id(0)
-    lane = jax.lax.broadcasted_iota(jnp.int32, (1, dim_padded), 1)[0]
+    rows, gwide, sem_in, sem_out = refs[2 * n_tables :]
+    width = rows.shape[-1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, width), 1)
+    slot_lanes = lane < dim_padded
     lane_mask = (lane < dim).astype(gsum_ref.dtype)
+    # Lanes past the slot never reach a written row (slot_lanes), but
+    # they do go through the math: keep them finite.
+    gwide[...] = jnp.zeros(gwide.shape, gwide.dtype)
 
     def body(i, _):
-        pos = g * tile + i
-
-        @pl.when(touched_ref[pos] != 0)
+        @pl.when(touched_ref[0, i] != 0)
         def _apply():
-            block = blocks_ref[pos]
-            lane0 = lane0_ref[pos]
+            block = blocks_ref[0, i]
+            lane0 = lane0_ref[0, i]
             for t in range(n_tables):
                 pltpu.make_async_copy(
                     tables[t].at[pl.ds(block, 1), :],
@@ -526,23 +568,22 @@ def _dedup_apply_kernel(blocks_ref, lane0_ref, touched_ref, gsum_ref,
                     sem_in.at[t],
                 ).wait()
             subs = tuple(
-                jax.lax.dynamic_slice(
-                    rows[t, 0, :], (lane0,), (dim_padded,)
-                )
-                for t in range(n_tables)
+                _slot_to_lane0(rows[t], lane0) for t in range(n_tables)
             )
-            gvec = gsum_ref[i, :]
-            tr = tr_ref[0, 0]
+            gwide[:, :dim_padded] = gsum_ref[pl.ds(i, 1), :]
+            gvec = gwide[...]
             if kind == "adam":
                 # Scatter-path contract: tr = max(t_before + 1, 1) read
                 # from the count slot's first real lane.
-                tr = jnp.maximum(subs[3][0] + 1.0, 1.0)
+                tr = jnp.maximum(
+                    jnp.broadcast_to(subs[3][:, 0:1], (1, width)) + 1.0, 1.0
+                )
+            else:
+                tr = jnp.full((1, width), tr_ref[0, 0], gvec.dtype)
             deltas = _apply_math(kind, hyper, lane_mask, gvec, subs, tr)
             for t in range(n_tables):
-                updated = jax.lax.dynamic_update_slice(
-                    rows[t, 0, :], subs[t] + deltas[t], (lane0,)
-                )
-                rows[t, 0, :] = updated
+                updated = jnp.where(slot_lanes, subs[t] + deltas[t], subs[t])
+                rows[t] = pltpu.roll(updated, lane0, 1)  # back to its slot
                 pltpu.make_async_copy(
                     rows.at[t],
                     tables[t].at[pl.ds(block, 1), :],
@@ -569,7 +610,17 @@ def _dedup_apply_core(spec, kind, hyper, tables, ids, grads, tr,
     safe, gsum, touched = pk.dedup_representatives(spec, ids, grads)
     tch = touched.astype(tables[0].dtype)[:, None]
     gsum = gsum * tch  # the scatter path's masking, same bits
+    return _apply_representatives(
+        spec, kind, hyper, tables, safe, gsum, touched, tr, interpret, tile
+    )
 
+
+def _apply_representatives(spec, kind, hyper, tables, safe, gsum, touched,
+                           tr, interpret, tile):
+    """The kernel pass alone, over an already segment-combined batch
+    (`pk.dedup_representatives`' triple).  Split from the prologue so
+    tests/test_tpu_compile.py compiles the Pallas call at DeepFM's real
+    id count without the prologue's XLA scatters."""
     n = safe.shape[0]
     tile = min(tile, _pad_to_tile(max(n, 1), 8))
     n_pad = _pad_to_tile(max(n, 1), tile)
@@ -582,9 +633,8 @@ def _dedup_apply_core(spec, kind, hyper, tables, ids, grads, tr,
     gsum_pad = jnp.pad(gsum, ((0, pad), (0, 0)))
 
     n_tables = len(tables)
-    # Operand order: 3 prefetch scalars, gsum tile, tr scalar, then the
-    # aliased table refs.  input_output_aliases indexes INCLUDE the
-    # prefetch operands.
+    # Operand order: 3 per-tile scalar arrays, gsum tile, tr scalar,
+    # then the aliased table refs.
     aliases = {5 + t: t for t in range(n_tables)}
     outs = pl.pallas_call(
         functools.partial(
@@ -596,29 +646,35 @@ def _dedup_apply_core(spec, kind, hyper, tables, ids, grads, tr,
             dim=spec.dim,
             n_tables=n_tables,
         ),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=(n_pad // tile,),
-            in_specs=[
-                pl.BlockSpec((tile, spec.dim_padded), lambda g, *_: (g, 0)),
-                pl.BlockSpec(memory_space=pltpu.SMEM),
-            ]
-            + [pl.BlockSpec(memory_space=pltpu.ANY)] * n_tables,
-            out_specs=[pl.BlockSpec(memory_space=pltpu.ANY)] * n_tables,
-            scratch_shapes=[
-                pltpu.VMEM(
-                    (n_tables, 1, spec.block_width), tables[0].dtype
-                ),
-                pltpu.SemaphoreType.DMA((n_tables,)),
-                pltpu.SemaphoreType.DMA((n_tables,)),
-            ],
-        ),
+        grid=(n_pad // tile,),
+        in_specs=[
+            _smem_tile(tile),
+            _smem_tile(tile),
+            _smem_tile(tile),
+            pl.BlockSpec((tile, spec.dim_padded), lambda g: (g, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+        ]
+        + [pl.BlockSpec(memory_space=pl.ANY)] * n_tables,
+        out_specs=[pl.BlockSpec(memory_space=pl.ANY)] * n_tables,
+        scratch_shapes=[
+            pltpu.VMEM((n_tables, 1, spec.block_width), tables[0].dtype),
+            pltpu.VMEM((1, spec.block_width), tables[0].dtype),
+            pltpu.SemaphoreType.DMA((n_tables,)),
+            pltpu.SemaphoreType.DMA((n_tables,)),
+        ],
         out_shape=[
             jax.ShapeDtypeStruct(t.shape, t.dtype) for t in tables
         ],
         input_output_aliases=aliases,
         interpret=interpret,
-    )(blocks, lane0, touched_pad, gsum_pad, tr, *tables)
+    )(
+        _scalar_tiles(blocks, tile),
+        _scalar_tiles(lane0, tile),
+        _scalar_tiles(touched_pad, tile),
+        gsum_pad,
+        tr,
+        *tables,
+    )
     return tuple(outs)
 
 
@@ -752,7 +808,7 @@ def fused_dedup_apply(
 # ----------------------------------------------------------------------
 
 
-def _fm_kernel(blocks_ref, lane0_ref, bet_ref, valid_ref, table_ref,
+def _fm_kernel(blocks_ref, lane0_ref, valid_ref, bet_ref, table_ref,
                acts_ref, first_ref, sumv_ref, sumsq_ref, rows, sem,
                *, batch_tile, fields, dim):
     """Grid step over `batch_tile` examples x `fields` ids: DMA each
@@ -760,21 +816,20 @@ def _fm_kernel(blocks_ref, lane0_ref, bet_ref, valid_ref, table_ref,
     validity, and accumulate the first-order sum + FM partial sums in
     VMEM registers while the activations stream to their output block —
     the FM term never re-reads [batch, fields, dim] from HBM."""
-    g = pl.program_id(0)
 
     def fetch(pos, slot):
         return pltpu.make_async_copy(
-            table_ref.at[pl.ds(blocks_ref[pos], 1), :],
+            table_ref.at[pl.ds(blocks_ref[0, pos], 1), :],
             rows.at[slot],
             sem.at[slot],
         )
 
     def example(b, _):
-        base = (g * batch_tile + b) * fields
+        base = b * fields
         fetch(base, 0).start()
 
         def field(f, carry):
-            first, sv, ss = carry
+            s, ss = carry
             slot = jax.lax.rem(f, 2)
 
             @pl.when(f + 1 < fields)
@@ -782,27 +837,20 @@ def _fm_kernel(blocks_ref, lane0_ref, bet_ref, valid_ref, table_ref,
                 fetch(base + f + 1, 1 - slot).start()
 
             fetch(base + f, slot).wait()
-            sel = jax.lax.dynamic_slice(
-                rows[slot, 0, :], (lane0_ref[base + f],), (dim,)
-            )
-            a = (sel + bet_ref[b, f, :]) * valid_ref[b, f]
-            acts_ref[b, f, :] = a
-            v = a[1:]
-            return first + a[0], sv + v, ss + v * v
+            sel = _slot_to_lane0(rows[slot], lane0_ref[0, base + f])
+            a = (sel[:, :dim] + bet_ref[b, pl.ds(f, 1), :]) * valid_ref[
+                0, base + f
+            ]
+            acts_ref[b, pl.ds(f, 1), :] = a
+            # Lane 0 of `s` is the first-order sum, lanes 1.. the FM
+            # vector sums; lane 0 of `ss` is unused.
+            return s + a, ss + a * a
 
-        first, sv, ss = jax.lax.fori_loop(
-            0,
-            fields,
-            field,
-            (
-                jnp.zeros((), acts_ref.dtype),
-                jnp.zeros((dim - 1,), acts_ref.dtype),
-                jnp.zeros((dim - 1,), acts_ref.dtype),
-            ),
-        )
-        first_ref[b, 0] = first
-        sumv_ref[b, :] = sv
-        sumsq_ref[b, :] = ss
+        zero = jnp.zeros((1, dim), acts_ref.dtype)
+        s, ss = jax.lax.fori_loop(0, fields, field, (zero, zero))
+        first_ref[pl.ds(b, 1), :] = s[:, :1]
+        sumv_ref[pl.ds(b, 1), :] = s[:, 1:]
+        sumsq_ref[pl.ds(b, 1), :] = ss[:, 1:]
         return 0
 
     jax.lax.fori_loop(0, batch_tile, example, 0)
@@ -821,34 +869,30 @@ def _fm_impl(spec, interpret, batch_tile, packed, bet, ids, valid):
     )
     valid_pad = jnp.pad(
         valid.astype(packed.dtype), ((0, pad), (0, 0))
-    )
+    ).reshape((-1,))
+    ids_tile = batch_tile * fields
     acts, first, sumv, sumsq = pl.pallas_call(
         functools.partial(
             _fm_kernel, batch_tile=batch_tile, fields=fields, dim=dim
         ),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(b_pad // batch_tile,),
-            in_specs=[
-                pl.BlockSpec(
-                    (batch_tile, fields, dim), lambda g, *_: (g, 0, 0)
-                ),
-                pl.BlockSpec((batch_tile, fields), lambda g, *_: (g, 0)),
-                pl.BlockSpec(memory_space=pltpu.ANY),
-            ],
-            out_specs=[
-                pl.BlockSpec(
-                    (batch_tile, fields, dim), lambda g, *_: (g, 0, 0)
-                ),
-                pl.BlockSpec((batch_tile, 1), lambda g, *_: (g, 0)),
-                pl.BlockSpec((batch_tile, dim - 1), lambda g, *_: (g, 0)),
-                pl.BlockSpec((batch_tile, dim - 1), lambda g, *_: (g, 0)),
-            ],
-            scratch_shapes=[
-                pltpu.VMEM((2, 1, spec.block_width), packed.dtype),
-                pltpu.SemaphoreType.DMA((2,)),
-            ],
-        ),
+        grid=(b_pad // batch_tile,),
+        in_specs=[
+            _smem_tile(ids_tile),
+            _smem_tile(ids_tile),
+            _smem_tile(ids_tile),
+            pl.BlockSpec((batch_tile, fields, dim), lambda g: (g, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=[
+            pl.BlockSpec((batch_tile, fields, dim), lambda g: (g, 0, 0)),
+            pl.BlockSpec((batch_tile, 1), lambda g: (g, 0)),
+            pl.BlockSpec((batch_tile, dim - 1), lambda g: (g, 0)),
+            pl.BlockSpec((batch_tile, dim - 1), lambda g: (g, 0)),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((2, 1, spec.block_width), packed.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+        ],
         out_shape=[
             jax.ShapeDtypeStruct((b_pad, fields, dim), packed.dtype),
             jax.ShapeDtypeStruct((b_pad, 1), packed.dtype),
@@ -856,7 +900,13 @@ def _fm_impl(spec, interpret, batch_tile, packed, bet, ids, valid):
             jax.ShapeDtypeStruct((b_pad, dim - 1), packed.dtype),
         ],
         interpret=interpret,
-    )(blocks, lane0, bet_pad, valid_pad, packed)
+    )(
+        _scalar_tiles(blocks, ids_tile),
+        _scalar_tiles(lane0, ids_tile),
+        _scalar_tiles(valid_pad, ids_tile),
+        bet_pad,
+        packed,
+    )
     return (
         acts[:batch],
         first[:batch, 0],

@@ -210,10 +210,9 @@ def shard_map_call(
     out_specs,
     check_vma: Optional[bool] = None,
 ):
-    """`jax.shard_map` with the jax.experimental fallback and the
-    check_vma/check_rep kwarg rename handled in one place.  Trace-safe
-    (no journaling): model bodies build shard_mapped callables under
-    trace (ring attention inside a zoo model's `__call__`).
+    """`jax.shard_map` over `mesh`.  Trace-safe (no journaling): model
+    bodies build shard_mapped callables under trace (ring attention
+    inside a zoo model's `__call__`).
 
     `check_vma=False` is the documented escape hatch for Pallas bodies
     in interpret mode (CPU tests/dryruns trip a jax limitation inside
@@ -223,17 +222,10 @@ def shard_map_call(
     """
     import jax
 
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-
     kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    if check_vma is None:
-        return sm(fn, **kwargs)
-    try:
-        return sm(fn, check_vma=check_vma, **kwargs)
-    except TypeError:  # older jax: the flag was called check_rep
-        return sm(fn, check_rep=check_vma, **kwargs)
+    if check_vma is not None:
+        kwargs["check_vma"] = check_vma
+    return jax.shard_map(fn, **kwargs)
 
 
 def jit_utility(fn: Callable, **jit_kwargs):

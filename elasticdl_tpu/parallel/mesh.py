@@ -70,13 +70,14 @@ def build_mesh(
         np.asarray(devices).reshape(data, model), (DATA_AXIS, MODEL_AXIS)
     )
     logger.info(
-        "Built mesh %dx%d (%s x %s) over %d %s device(s)",
+        "Built mesh %dx%d (%s x %s) over %d %s device(s) [%s]",
         data,
         model,
         DATA_AXIS,
         MODEL_AXIS,
         len(devices),
         devices[0].platform,
+        devices[0].device_kind,
     )
     return mesh
 

@@ -138,9 +138,12 @@ def mark_iid(initializer):
     """Tag an initializer as elementwise-i.i.d. (its distribution does not
     depend on the shape argument — uniform/normal with fixed scale), which
     lets packed_init generate DIRECTLY in packed storage shape.  That
-    matters at scale: the logical->packed relayout of a [26M, 9] init
-    crashes the TPU compiler outright (tpu_compile_helper exit 1,
-    reproducible round 3)."""
+    matters at scale: the logical [vocab, dim] array pads its minor dim
+    to 128 lanes on the chip, so the logical->packed relayout of a
+    [26M, 9] init needs 24.8 GB of a v5e's 15.75 GB HBM and the
+    compiler refuses it (RESOURCE_EXHAUSTED; compiled for a described
+    v5e with jax 0.9.0 — at [2.6M, 9] it compiles with 2.5 GiB of
+    temporaries)."""
     initializer.packed_iid_safe = True
     return initializer
 
@@ -154,8 +157,9 @@ def packed_init(spec: PackedSpec, initializer):
     Untagged initializers may be shape-DEPENDENT (fan-scaled variance,
     row-indexed conventions), so they are invoked with the logical
     (vocab, dim) shape and repacked — correct for any initializer, but the
-    relayout does not compile on TPU past ~10M-row tables (see mark_iid);
-    tag large-table initializers i.i.d. or initialize on host.
+    relayout's lane-padded temporaries outgrow a 16 GB chip somewhere
+    past 10M rows (see mark_iid); tag large-table initializers i.i.d.
+    or initialize on host.
     """
 
     def init(key, shape, dtype=jnp.float32):

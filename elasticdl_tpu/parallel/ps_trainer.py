@@ -21,6 +21,7 @@ either interchangeably.
 
 from __future__ import annotations
 
+import json
 from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import flax.linen as nn
@@ -458,6 +459,19 @@ class ShardedEmbeddingTrainer:
             self._mesh.devices.size,
             self._emb_tx.name,
             self._sparse_kernel,
+        )
+        # What the placement DID, not what the mesh promises: the bytes
+        # of table storage each local device holds (chip_smoke.py's
+        # four-chip run reads this line).
+        table_bytes: Dict[int, int] = {}
+        for table in self._state.tables.values():
+            for shard in table.addressable_shards:
+                table_bytes[shard.device.id] = (
+                    table_bytes.get(shard.device.id, 0) + shard.data.nbytes
+                )
+        logger.info(
+            "Embedding-table bytes per local device: %s",
+            json.dumps({str(k): v for k, v in sorted(table_bytes.items())}),
         )
         # Journal the kernel decision (host-side, init-time — the obs
         # plane never rides the traced step): postmortems and the

@@ -460,20 +460,23 @@ def make_ring_attention(mesh, *, axis: str = MODEL_AXIS,
         _ring_dispatch, axis_name=axis, causal=causal, layout=layout,
         impl=impl,
     )
-    # Built through the compile layer's shard_map shim (the one place
-    # that owns the jax.shard_map fallback + check_vma/check_rep
-    # rename).  check_vma stays ON for the pure-XLA engine; it is off
-    # only where the pallas engine can be selected — kernel interpret
-    # mode (CPU tests/dryruns) trips a jax limitation inside the kernel
-    # interpreter ("Primitive dynamic_slice requires varying manual
-    # axes to match ... as a temporary workaround pass
-    # check_vma=False"); collective placement is pinned by the
+    # check_vma stays ON wherever the kernels are compiled (the flash
+    # kernels carry their varying-axes types: tests/test_tpu_compile.py
+    # compiles this ring for a 2x2 v5e mesh with the check on) and for
+    # the pure-XLA engine.  It is off only where the Pallas engine runs
+    # in INTERPRET mode (CPU tests/dryruns), which trips a jax limitation
+    # inside the kernel interpreter ("Primitive dynamic_slice requires
+    # varying manual axes to match ... as a temporary workaround pass
+    # check_vma=False"); collective placement there is pinned by the
     # parity+HLO-structure tests instead.
+    from elasticdl_tpu.ops.flash_attention import _use_interpret
+
+    interpreted = impl != "xla" and _use_interpret()
     return pc.shard_map_call(
         fn, mesh,
         in_specs=(spec, spec, spec),
         out_specs=spec,
-        check_vma=None if impl == "xla" else False,
+        check_vma=False if interpreted else None,
     )
 
 

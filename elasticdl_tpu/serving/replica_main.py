@@ -314,7 +314,7 @@ def main(argv=None) -> int:
     os.makedirs(args.serve_dir, exist_ok=True)
     obs.init_journal(args.serve_dir)
 
-    from elasticdl_tpu.common import faults
+    from elasticdl_tpu.common import compile_cache, faults
     from elasticdl_tpu.obs import tracing
     from elasticdl_tpu.obs.exporter import MetricsExporter
     from elasticdl_tpu.serving.batcher import BatcherConfig, MicroBatcher
@@ -329,6 +329,7 @@ def main(argv=None) -> int:
     # own `proc`, so every replica gets its own Perfetto pid row even
     # though the whole fleet appends to ONE serve-dir journal.
     tracing.set_process(f"replica_{args.replica_id}")
+    logger.info("JAX compilation cache: %s", compile_cache.configure())
 
     replica = ServingReplica(
         args.model_dir,

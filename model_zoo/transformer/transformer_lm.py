@@ -29,12 +29,15 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 
+from elasticdl_tpu.common.log_utils import get_logger
 from elasticdl_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
 from elasticdl_tpu.parallel.ring_attention import (
     blockwise_attention,
     make_ring_attention,
 )
 from model_zoo import datasets
+
+logger = get_logger("model_zoo.transformer")
 
 VOCAB = 256
 SEQ_LEN = 128
@@ -75,6 +78,7 @@ class CausalSelfAttention(nn.Module):
     def _single_device_attend(self, t: int, head_dim: int):
         from elasticdl_tpu.ops import flash_attention
         from elasticdl_tpu.ops.flash_attention import (
+            _use_interpret,
             supports,
             warn_if_vmem_is_sole_blocker,
         )
@@ -84,10 +88,22 @@ class CausalSelfAttention(nn.Module):
             and jax.default_backend() == "tpu"
             and supports(t, head_dim)
         )
+        # Trace-time record of the engine this compile got (once per
+        # compile): chip_smoke.py reads it from the worker log, so a
+        # demotion to the fallback or to the interpreter is never silent.
         if use_pallas:
+            logger.info(
+                "attention engine: pallas flash_attention T=%d D=%d "
+                "(interpret=%s)",
+                t, head_dim, _use_interpret(),
+            )
             return partial(flash_attention, causal=True)
         if self.attn_impl == "auto" and jax.default_backend() == "tpu":
             warn_if_vmem_is_sole_blocker("model_zoo.transformer", t, head_dim)
+        logger.info(
+            "attention engine: xla blockwise_attention T=%d D=%d",
+            t, head_dim,
+        )
         return partial(blockwise_attention, causal=True)
 
     @nn.compact

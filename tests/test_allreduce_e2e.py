@@ -30,7 +30,6 @@ WORKER_ENV = {
     # Workers run single-CPU-device processes (override the test harness's
     # 8 virtual devices); the world then has one device per process.
     "XLA_FLAGS": "--xla_force_host_platform_device_count=1",
-    "ELASTICDL_FORCE_PLATFORM": "cpu",
     "JAX_PLATFORMS": "cpu",
 }
 
@@ -54,7 +53,6 @@ def job_args(tmp_path, n_records, records_per_task, minibatch, num_workers,
 
 @pytest.fixture
 def worker_env(monkeypatch):
-    monkeypatch.setenv("ELASTICDL_FORCE_PLATFORM", "cpu")
     monkeypatch.setenv(
         "ELASTICDL_WORKER_ENV",
         ";".join(f"{k}={v}" for k, v in WORKER_ENV.items()),

@@ -21,14 +21,12 @@ from elasticdl_tpu.client import api
 
 WORKER_ENV = {
     "XLA_FLAGS": "--xla_force_host_platform_device_count=1",
-    "ELASTICDL_FORCE_PLATFORM": "cpu",
     "JAX_PLATFORMS": "cpu",
 }
 
 
 @pytest.fixture
 def worker_env(monkeypatch):
-    monkeypatch.setenv("ELASTICDL_FORCE_PLATFORM", "cpu")
     monkeypatch.setenv(
         "ELASTICDL_WORKER_ENV",
         ";".join(f"{k}={v}" for k, v in WORKER_ENV.items()),

@@ -176,7 +176,6 @@ def test_ps_cluster_job_uses_columnar_path(tmp_path):
         max_restarts=0,
         worker_env={
             "XLA_FLAGS": "--xla_force_host_platform_device_count=1",
-            "ELASTICDL_FORCE_PLATFORM": "cpu",
             "JAX_PLATFORMS": "cpu",
         },
         log_dir=str(tmp_path / "logs"),

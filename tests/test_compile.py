@@ -349,10 +349,7 @@ def test_ring_attention_hlo_parity_with_hand_rolled_shard_map():
     ported_fn = ra.make_ring_attention(mesh, causal=True, impl="xla")
     ported = jax.jit(ported_fn).lower(q, q, q).compile().as_text()
 
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-    hand_fn = sm(
+    hand_fn = jax.shard_map(
         partial(
             ra._ring_dispatch, axis_name=MODEL_AXIS, causal=True,
             scale=None, layout="contiguous", impl="xla",

@@ -25,7 +25,6 @@ from elasticdl_tpu.master.rendezvous_server import ElasticRendezvous
 
 WORKER_ENV = {
     "XLA_FLAGS": "--xla_force_host_platform_device_count=1",
-    "ELASTICDL_FORCE_PLATFORM": "cpu",
     "JAX_PLATFORMS": "cpu",
 }
 

@@ -627,7 +627,7 @@ def test_request_tracing_fleet_e2e(tmp_path, obs_registry_snapshot):
         assert validator.validate_file(journal_path) == []
         return _events(journal_path)
 
-    base_env = {"JAX_PLATFORMS": "cpu", "ELASTICDL_FORCE_PLATFORM": "cpu"}
+    base_env = {"JAX_PLATFORMS": "cpu"}
 
     # -- stall run: 0.35s execute stalls starting at the 5th dispatch ---
     events = run_fleet(

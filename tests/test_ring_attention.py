@@ -82,16 +82,11 @@ def test_ring_gradients_match_dense():
     mesh = build_mesh(MeshConfig(data=2, model=4))
     q, k, v = _qkv(b=2, t=32, seed=7)
     spec = P(DATA_AXIS, MODEL_AXIS, None, None)
-    # check off: this jax version's replication checker rejects the
-    # causal ring's lax.cond skip under transposition ("branches of
-    # cond produced mismatched replication types ... pass
-    # check_rep=False") — the numerics under test are unaffected.
     ring = pc.shard_map_call(
         partial(ring_attention, axis_name=MODEL_AXIS, causal=True),
         mesh,
         in_specs=(spec, spec, spec),
         out_specs=spec,
-        check_vma=False,
     )
     sharding = NamedSharding(mesh, spec)
     qs, ks, vs = (jax.device_put(x, sharding) for x in (q, k, v))

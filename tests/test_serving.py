@@ -104,7 +104,6 @@ np.save({str(tmp_path / 'out.npy')!r}, out)
     env = {
         **os.environ,
         "JAX_PLATFORMS": "cpu",
-        "ELASTICDL_FORCE_PLATFORM": "cpu",
     }
     subprocess.run(
         [sys.executable, "-c", script],
@@ -568,7 +567,7 @@ def test_serving_fleet_e2e(tmp_path, obs_registry_snapshot):
     warm = str(tmp_path / "warm.npz")
     with open(warm, "wb") as fh:
         fh.write(encode_features({k: v[:1] for k, v in feats.items()}))
-    env = {"JAX_PLATFORMS": "cpu", "ELASTICDL_FORCE_PLATFORM": "cpu"}
+    env = {"JAX_PLATFORMS": "cpu"}
     manager = start_serving_fleet(
         2, gen1_dir, serve_dir,
         worker_env=env,

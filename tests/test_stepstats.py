@@ -68,7 +68,9 @@ def _fed_anatomy(worker_id=0, data_wait=0.0, stage=0.0, execute=0.0,
                  bookkeep=0.0, examples=0, steps=1, windows=1):
     """A StepAnatomy with deterministic phase seconds via a fake clock."""
     clock = _Clock()
-    anatomy = StepAnatomy(worker_id=worker_id, clock=clock)
+    anatomy = StepAnatomy(
+        worker_id=worker_id, clock=clock, device_kind=stepstats.V5E
+    )
     for _ in range(windows):
         if data_wait:
             with anatomy.phase("data_wait"):
@@ -177,27 +179,45 @@ def test_mfu_math_matches_flops_table():
     anatomy.set_model("transformer_lm")
     snap = anatomy.snapshot()
     flops = stepstats.MODEL_FLOPS["transformer_lm"]["train_flops_per_example"]
-    expected = (4096 / 2.0) * flops / stepstats.PEAK_BF16_FLOPS
+    peak = stepstats.DEVICE_PEAKS[stepstats.V5E]["bf16_flops"]
+    expected = (4096 / 2.0) * flops / peak
     assert snap["mfu"] == pytest.approx(expected, rel=1e-3)
     assert snap["bound"] == "compute"
 
 
 def test_roofline_verdicts():
     # Host-starved: data_wait dominates regardless of model.
+    v5e = stepstats.V5E
     host = stepstats.roofline(
-        1000.0, {"data_wait": 0.7, "execute": 0.3}, "resnet50"
+        1000.0, {"data_wait": 0.7, "execute": 0.3}, "resnet50", v5e
     )
     assert host["bound"] == "host"
     # DeepFM at ~1M samples/s: the BENCH_r04 sparse-row-count wall.
-    sparse = stepstats.roofline(975_000.0, {"execute": 1.0}, "deepfm")
+    sparse = stepstats.roofline(975_000.0, {"execute": 1.0}, "deepfm", v5e)
     assert sparse["bound"] == "sparse-row"
     assert sparse["floor_frac"] == pytest.approx(0.634, abs=0.01)
     # ResNet-50 at its measured rate: bandwidth-bound, not MXU-bound.
-    hbm = stepstats.roofline(2_665.0, {"execute": 1.0}, "resnet50")
+    hbm = stepstats.roofline(2_665.0, {"execute": 1.0}, "resnet50", v5e)
     assert hbm["bound"] == "hbm"
     assert hbm["bw_frac"] > hbm["mfu"]
     # No FLOPs row -> no verdict invented.
-    assert "bound" not in stepstats.roofline(10.0, {"execute": 1.0}, None)
+    assert "bound" not in stepstats.roofline(
+        10.0, {"execute": 1.0}, None, v5e
+    )
+
+
+@pytest.mark.parametrize("device_kind", ["cpu", None, "TPU v9 imaginary"])
+def test_roofline_has_no_peaks_for_an_unlisted_device(device_kind):
+    """A peak assumed for a device the table does not list is a number
+    about nothing: such a device gets no mfu / floor_frac / bw_frac and
+    no chip-side verdict — only the host verdict, which needs no peak."""
+    for model in ("deepfm", "resnet50", "transformer_lm"):
+        assert stepstats.roofline(
+            975_000.0, {"execute": 1.0}, model, device_kind
+        ) == {}
+    assert stepstats.roofline(
+        1000.0, {"data_wait": 0.7, "execute": 0.3}, "resnet50", device_kind
+    ) == {"bound": "host"}
 
 
 def test_roofline_constants_match_bench():
@@ -210,9 +230,7 @@ def test_roofline_constants_match_bench():
     )
     bench = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(bench)
-    assert stepstats.PEAK_BF16_FLOPS == bench.PEAK_BF16_FLOPS
-    assert stepstats.HBM_BYTES_PER_SEC == bench.HBM_BYTES_PER_SEC
-    assert stepstats.SPARSE_FLOOR_NS_PER_ROW == bench.SPARSE_FLOOR_NS_PER_ROW
+    assert bench.DEVICE_PEAKS is stepstats.DEVICE_PEAKS
     assert stepstats.TRANSFORMER_BENCH == bench.TRANSFORMER_BENCH
     assert stepstats.transformer_flops_per_token() == pytest.approx(
         bench._transformer_flops_per_token()

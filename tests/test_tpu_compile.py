@@ -1,0 +1,284 @@
+"""The Pallas kernels of the main path compile for the chip.
+
+Interpret mode (every other kernel test here) cannot see what Mosaic
+refuses: a dynamic lane slice, a block that misses the tiling, more
+SMEM or VMEM than the chip has.  The TPU compiler is installed in the
+sandbox and compiles for a DESCRIBED `v5e:2x2` device from shapes alone
+(/opt/skills/guides/on-chip-measurement section 2), so each kernel entry
+point is lowered with `interpret=False` at the real widths of the cell
+that uses it.  Nothing runs: a pass here is not a chip run.
+"""
+
+import functools
+import importlib
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+from elasticdl_tpu.ops import sparse_embedding as ske
+from elasticdl_tpu.parallel import ring_attention, sparse_optim
+from elasticdl_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
+from elasticdl_tpu.parallel.packed import PackedSpec
+
+# `elasticdl_tpu.ops.flash_attention` the attribute is the function (the
+# package re-exports it over the submodule's name).
+fa = importlib.import_module("elasticdl_tpu.ops.flash_attention")
+
+# DeepFM at full width (bench.py bench_deepfm): 26 fields x vocab
+# 100000 resident rows, minibatch 8192, embedding_dim 8 -> the combined
+# 1+8 table; (rows, 16) is the unpadded-slot variant of the same table.
+ROWS, BATCH, FIELDS = 2_600_000, 8192, 26
+N_IDS = BATCH * FIELDS
+DEEPFM_TABLE = PackedSpec(ROWS, 9)
+# Transformer bench (bench.TRANSFORMER_BENCH: d512 / 8 heads -> D=64)
+# and the ring-engine bench shape (bench.RING_BENCH: D=128).
+B, T, H = 4, 2048, 8
+
+# Kernel kind -> the hyperparameters its sparse optimizer hands the kernel.
+_HYPER = {
+    "sgd": sparse_optim.sgd().hyperparams,
+    "momentum": sparse_optim.momentum().hyperparams,
+    "adagrad": sparse_optim.adagrad().hyperparams,
+    "adam": sparse_optim.adam().hyperparams,
+    "adam_global": sparse_optim.adam(bias_correction="global").hyperparams,
+}
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as exc:  # no libtpu here: nothing to compile with
+        pytest.skip(f"cannot describe a v5e:2x2 topology: {exc}")
+
+
+@pytest.fixture(autouse=True)
+def _no_persistent_cache():
+    """A compile for a described device is written to the persistent
+    cache but cannot be read back without a chip (the next one warns and
+    recompiles): keep the cache out of these cases."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+def _flash_fwd(d):
+    def fn(q, k, v):
+        return fa.flash_attention(q, k, v, causal=True, interpret=False)
+
+    return fn, [((B, T, H, d), jnp.bfloat16)] * 3
+
+
+def _flash_bwd(d):
+    def loss(q, k, v):
+        out = fa.flash_attention(q, k, v, causal=True, interpret=False)
+        return jnp.sum(out.astype(jnp.float32))
+
+    return jax.grad(loss, argnums=(0, 1, 2)), [((B, T, H, d), jnp.bfloat16)] * 3
+
+
+def _ring_step_carry():
+    d = 128
+
+    def fn(q, k, v, acc, lse, q_pos, k_pos):
+        return fa.flash_ring_step_carry(
+            q, k, v, acc, lse, q_pos, k_pos,
+            causal=True, scale=d ** -0.5, interpret=False,
+        )
+
+    blk = ((B, H, T, d), jnp.bfloat16)
+    return fn, [
+        blk, blk, blk,
+        ((B, H, T, d), jnp.float32), ((B, H, T, 1), jnp.float32),
+        ((T,), jnp.int32), ((T,), jnp.int32),
+    ]
+
+
+def _ring_step_bwd():
+    d = 128
+
+    def fn(q, k, v, do, lse, delta, q_pos, k_pos):
+        return fa.flash_ring_step_bwd(
+            q, k, v, do, lse, delta, q_pos, k_pos,
+            causal=True, scale=d ** -0.5, interpret=False,
+        )
+
+    blk = ((B, H, T, d), jnp.bfloat16)
+    stat = ((B, H, T, 1), jnp.float32)
+    return fn, [
+        blk, blk, blk, ((B, H, T, d), jnp.float32), stat, stat,
+        ((T,), jnp.int32), ((T,), jnp.int32),
+    ]
+
+
+def _fused_lookup():
+    spec = PackedSpec(ROWS, 16)
+
+    def fn(packed, ids):
+        return ske.fused_lookup(spec, packed, ids, interpret=False)
+
+    return fn, [(spec.packed_shape, jnp.float32), ((N_IDS,), jnp.int32)]
+
+
+def _fused_lookup_fm():
+    spec = DEEPFM_TABLE
+
+    def fn(packed, bet, ids, valid):
+        return ske.fused_lookup_fm(
+            spec, packed, bet, ids, valid, interpret=False
+        )
+
+    return fn, [
+        (spec.packed_shape, jnp.float32),
+        ((BATCH, FIELDS, spec.dim), jnp.float32),
+        ((BATCH, FIELDS), jnp.int32),
+        ((BATCH, FIELDS), jnp.bool_),
+    ]
+
+
+def _fused_apply(kind):
+    """The apply KERNEL at DeepFM's id count, fed an already
+    segment-combined batch: the XLA dedup prologue in front of it is the
+    scatter path's own code and takes ~20 s to compile at this size."""
+    spec = DEEPFM_TABLE
+    n_tables = 1 + len(ske._KIND_SLOTS[kind])
+
+    def fn(safe, gsum, touched, tr, *tables):
+        return ske._apply_representatives(
+            spec, kind, _HYPER[kind], tables, safe, gsum, touched, tr,
+            False, ske.DEFAULT_IDS_PER_TILE,
+        )
+
+    return fn, [
+        ((N_IDS,), jnp.int32), ((N_IDS, spec.dim), jnp.float32),
+        ((N_IDS,), jnp.bool_), ((1, 1), jnp.float32),
+    ] + [(spec.packed_shape, jnp.float32)] * n_tables
+
+
+_CASES = {
+    "flash_fwd_d64": functools.partial(_flash_fwd, 64),
+    "flash_fwd_d128": functools.partial(_flash_fwd, 128),
+    "flash_bwd_d64": functools.partial(_flash_bwd, 64),
+    "flash_bwd_d128": functools.partial(_flash_bwd, 128),
+    "ring_step_carry": _ring_step_carry,
+    "ring_step_bwd": _ring_step_bwd,
+    "fused_lookup": _fused_lookup,
+    "fused_lookup_fm": _fused_lookup_fm,
+    **{
+        f"fused_apply_{kind}": functools.partial(_fused_apply, kind)
+        for kind in ske._KIND_SLOTS
+    },
+}
+
+
+@pytest.mark.parametrize("case", sorted(_CASES))
+def test_kernel_compiles_for_v5e(topo, case):
+    fn, shapes = _CASES[case]()
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    args = [
+        jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+        for shape, dtype in shapes
+    ]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def _four_chip_mesh(topo):
+    return jax.sharding.Mesh(
+        np.asarray(topo.devices).reshape(2, 2), (DATA_AXIS, MODEL_AXIS)
+    )
+
+
+@pytest.mark.parametrize("kernel", ["lookup", "lookup_fm", "apply_adam"])
+def test_fused_kernels_compile_sharded_on_four_chip_mesh(topo, kernel):
+    """`--sparse_kernel=fused --mesh_model_axis=2`: the shard_map route
+    of each fused kernel (table blocks over `model`, batch over `data`)
+    on a 2x2 mesh of described chips.  The apply case feeds 256 x 26 ids
+    (the XLA dedup prologue's compile time grows steeply past 32k ids);
+    table widths are DeepFM's."""
+    mesh = _four_chip_mesh(topo)
+    spec = DEEPFM_TABLE
+
+    def sharded(shape, dtype, *axes):
+        return jax.ShapeDtypeStruct(
+            shape, dtype, sharding=NamedSharding(mesh, P(*axes))
+        )
+
+    table = sharded(spec.packed_shape, jnp.float32, MODEL_AXIS)
+    if kernel == "lookup":
+        fn = functools.partial(
+            ske.fused_lookup, spec, mesh=mesh, interpret=False
+        )
+        args = [table, sharded((N_IDS,), jnp.int32, DATA_AXIS)]
+    elif kernel == "lookup_fm":
+        fn = functools.partial(
+            ske.fused_lookup_fm, spec, mesh=mesh, interpret=False
+        )
+        args = [
+            table,
+            sharded((BATCH, FIELDS, spec.dim), jnp.float32, DATA_AXIS),
+            sharded((BATCH, FIELDS), jnp.int32, DATA_AXIS),
+            sharded((BATCH, FIELDS), jnp.bool_, DATA_AXIS),
+        ]
+    else:
+        fn = functools.partial(
+            ske.fused_dedup_apply, spec, "adam", _HYPER["adam"],
+            mesh=mesh, interpret=False,
+        )
+        n = 256 * FIELDS
+        args = [
+            table, {name: table for name in ske._KIND_SLOTS["adam"]},
+            sharded((n,), jnp.int32, DATA_AXIS),
+            sharded((n, spec.dim), jnp.float32, DATA_AXIS),
+        ]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("check_vma", [False, True])
+def test_ring_attention_compiles_on_four_chip_mesh(topo, check_vma):
+    """The Pallas ring engine under shard_map on a 2x2 mesh of described
+    chips, forward and backward.  `check_vma=False` is how
+    parallel/ring_attention.make_ring_attention builds it today (the
+    kernel INTERPRETER trips the checker); True shows the compiled
+    kernels carry their varying-axes types and do not need the escape."""
+    from elasticdl_tpu.parallel import compile as pc
+
+    mesh = _four_chip_mesh(topo)
+    spec = P(DATA_AXIS, MODEL_AXIS, None, None)
+    ring = pc.shard_map_call(
+        functools.partial(
+            ring_attention.ring_attention_pallas, axis_name=MODEL_AXIS,
+            causal=True, interpret=False,
+        ),
+        mesh, in_specs=(spec, spec, spec), out_specs=spec,
+        check_vma=check_vma,
+    )
+
+    def loss(q, k, v):
+        return jnp.sum(ring(q, k, v).astype(jnp.float32))
+
+    arg = jax.ShapeDtypeStruct(
+        (B, 2 * T, H, 128), jnp.bfloat16, sharding=NamedSharding(mesh, spec)
+    )
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        arg, arg, arg
+    ).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert "collective-permute" in text

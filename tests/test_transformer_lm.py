@@ -102,14 +102,12 @@ def test_lm_cluster_e2e_cp_and_tp(tmp_path, monkeypatch, extra_model_params):
     from elasticdl_tpu.common.constants import Mode
     from elasticdl_tpu.master.job_runner import run_allreduce_job
 
-    monkeypatch.setenv("ELASTICDL_FORCE_PLATFORM", "cpu")
     monkeypatch.setenv(
         "ELASTICDL_WORKER_ENV",
         ";".join(
             f"{k}={v}"
             for k, v in {
                 "XLA_FLAGS": "--xla_force_host_platform_device_count=2",
-                "ELASTICDL_FORCE_PLATFORM": "cpu",
                 "JAX_PLATFORMS": "cpu",
             }.items()
         ),
@@ -209,10 +207,8 @@ def test_cp_worker_kill_elastic_recovery(tmp_path, monkeypatch):
 
     worker_env = {
         "XLA_FLAGS": "--xla_force_host_platform_device_count=2",
-        "ELASTICDL_FORCE_PLATFORM": "cpu",
         "JAX_PLATFORMS": "cpu",
     }
-    monkeypatch.setenv("ELASTICDL_FORCE_PLATFORM", "cpu")
     n_records = 512
     args = parse_master_args([
         "--model_zoo=model_zoo",

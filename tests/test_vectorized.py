@@ -167,7 +167,6 @@ def test_deepfm_trains_from_criteo_etrf_file(tmp_path):
         env={
             **__import__("os").environ,
             "JAX_PLATFORMS": "cpu",
-            "ELASTICDL_FORCE_PLATFORM": "cpu",
         },
     )
     assert proc.returncode == 0, proc.stderr[-2000:]
