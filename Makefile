@@ -200,7 +200,8 @@ test-sparse: sparse-gates
 test-obs: slo-gates
 	JAX_PLATFORMS=cpu python -m pytest tests/test_obs.py \
 	       tests/test_telemetry.py tests/test_goodput.py \
-	       tests/test_stepstats.py tests/test_tracing.py -q
+	       tests/test_stepstats.py tests/test_tracing.py \
+	       tests/test_span_vocabulary.py -q
 	JAX_PLATFORMS=cpu python -m pytest tests/test_slo.py -q -m 'not slow'
 	python scripts/validate_journal.py --selftest --check-sources
 	python scripts/validate_journal.py tests/golden_journal.jsonl

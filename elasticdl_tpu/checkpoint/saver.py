@@ -24,9 +24,11 @@ retained.
 
 from __future__ import annotations
 
+import contextlib
 import json
 import os
 import pickle
+import resource
 import shutil
 import tempfile
 import time
@@ -36,6 +38,7 @@ from typing import Any, Dict, Optional, Tuple
 from elasticdl_tpu import obs
 from elasticdl_tpu.common import faults
 from elasticdl_tpu.common.log_utils import get_logger
+from elasticdl_tpu.obs import tracing
 
 logger = get_logger("checkpoint.saver")
 
@@ -64,6 +67,59 @@ def _ckpt_metrics():
             "Corrupt checkpoints quarantined (integrity failures)",
         ),
     )
+
+def tree_nbytes(tree) -> int:
+    """Bytes of a pytree's array leaves (host or device)."""
+    import jax
+
+    return int(sum(
+        getattr(leaf, "nbytes", 0) for leaf in jax.tree.leaves(tree)
+    ))
+
+
+def _host_io_marks() -> dict:
+    """What the host's page cache and this process's I/O look like now:
+    `Dirty` / `Writeback` kB of /proc/meminfo and the getrusage
+    counters a slow save moves (major faults, blocks written,
+    involuntary context switches).  Two small reads."""
+    marks = {}
+    try:
+        with open("/proc/meminfo") as f:
+            for line in f:
+                key, _, rest = line.partition(":")
+                if key in ("Dirty", "Writeback"):
+                    marks[key.lower() + "_kb"] = int(rest.split()[0])
+    except (OSError, ValueError, IndexError):
+        pass
+    usage = resource.getrusage(resource.RUSAGE_SELF)
+    marks.update(
+        majflt=usage.ru_majflt, oublock=usage.ru_oublock,
+        nivcsw=usage.ru_nivcsw,
+    )
+    return marks
+
+
+@contextlib.contextmanager
+def save_span(**fields):
+    """The parent `checkpoint.save` span of one save (every rank opens
+    it around its gather and write; the children are
+    `checkpoint.save.gather` / `.write` / `.crc` / `.commit`).  It
+    closes with the host's dirty and write-back kB at its start and
+    end and the process's rusage deltas over it — what tells a save
+    slowed by piled-up dirty pages from one slowed by the device."""
+    before = _host_io_marks()
+    with tracing.span("checkpoint.save", **fields) as span:
+        try:
+            yield span
+        finally:
+            after = _host_io_marks()
+            for key in ("dirty_kb", "writeback_kb"):
+                if key in before and key in after:
+                    span.fields[key + "_start"] = before[key]
+                    span.fields[key + "_end"] = after[key]
+            for key in ("majflt", "oublock", "nivcsw"):
+                span.fields[key] = after[key] - before[key]
+
 
 _STATE_FILE = "state.pkl"
 _INTEGRITY_FILE = "integrity.json"
@@ -233,25 +289,40 @@ class CheckpointSaver:
         import jax
 
         start = time.monotonic()
-        host_state = jax.device_get(state)
         final_dir = self._step_dir(step)
         if os.path.exists(final_dir):
             return final_dir
+        if any(isinstance(x, jax.Array) for x in jax.tree.leaves(state)):
+            # A caller that hands over device arrays (the trainers'
+            # `state_to_host` has its own gather span and hands over
+            # host ones).
+            with tracing.span(
+                "checkpoint.save.gather", bytes=tree_nbytes(state)
+            ):
+                state = jax.device_get(state)
         tmp_dir = tempfile.mkdtemp(
             prefix=f"step_{step:012d}.tmp", dir=self._dir
         )
         state_path = os.path.join(tmp_dir, _STATE_FILE)
-        with open(state_path, "wb") as f:
-            pickle.dump(host_state, f)
-        write_integrity_manifest(tmp_dir, [_STATE_FILE])
-        _apply_write_fault(state_path)
-        os.rename(tmp_dir, final_dir)
-        save_hist, _restore, saves, _quarantines = _ckpt_metrics()
-        save_hist.observe(time.monotonic() - start, kind="full")
-        saves.inc(kind="full")
-        obs.journal().record("checkpoint_saved", step=step, kind="full")
-        logger.info("Saved checkpoint at step %d -> %s", step, final_dir)
-        self._garbage_collect()
+        with tracing.span("checkpoint.save.write") as span:
+            with open(state_path, "wb") as f:
+                pickle.dump(state, f)
+            span.fields["bytes"] = os.path.getsize(state_path)
+        with tracing.span(
+            "checkpoint.save.crc", bytes=os.path.getsize(state_path)
+        ):
+            write_integrity_manifest(tmp_dir, [_STATE_FILE])
+        with tracing.span("checkpoint.save.commit", bytes=0):
+            _apply_write_fault(state_path)
+            os.rename(tmp_dir, final_dir)
+            save_hist, _restore, saves, _quarantines = _ckpt_metrics()
+            save_hist.observe(time.monotonic() - start, kind="full")
+            saves.inc(kind="full")
+            obs.journal().record("checkpoint_saved", step=step, kind="full")
+            logger.info(
+                "Saved checkpoint at step %d -> %s", step, final_dir
+            )
+            self._garbage_collect()
         return final_dir
 
     def load_latest(self) -> Tuple[Optional[Any], int]:
@@ -276,8 +347,14 @@ class CheckpointSaver:
                 continue
             path = os.path.join(step_dir, _STATE_FILE)
             try:
-                with open(path, "rb") as f:
-                    state = pickle.load(f)
+                # The real restore (read + unpickle); the caller places
+                # the state on the device inside `checkpoint.restore`.
+                with tracing.span(
+                    "checkpoint.restore.load", step=step,
+                    bytes=os.path.getsize(path),
+                ):
+                    with open(path, "rb") as f:
+                        state = pickle.load(f)
                 _save, restore_hist, _saves, _q = _ckpt_metrics()
                 restore_hist.observe(
                     time.monotonic() - start, kind="full"

@@ -46,10 +46,12 @@ from elasticdl_tpu.checkpoint.saver import (
     CheckpointSaver,
     _apply_write_fault,
     _ckpt_metrics,
+    tree_nbytes,
     verify_integrity,
     write_integrity_manifest,
 )
 from elasticdl_tpu.common.log_utils import get_logger
+from elasticdl_tpu.obs import tracing
 
 logger = get_logger("checkpoint.sharded")
 
@@ -136,30 +138,40 @@ class ShardedCheckpointSaver(CheckpointSaver):
         os.makedirs(tmp_dir, exist_ok=True)
 
         entries: Dict[str, np.ndarray] = {}
-        for name, array in sharded.items():
-            dim0 = array.shape[0]
-            seen: set = set()
-            for shard in array.addressable_shards:
-                lo, hi = _interval(shard, dim0)
-                if (lo, hi) in seen:
-                    continue  # replicas of the same rows on other devices
-                seen.add((lo, hi))
-                if (lo, hi) == (0, dim0) and process != 0:
-                    continue  # fully replicated array: rank 0 writes it
-                entries[f"{name}|{lo}|{hi}"] = np.asarray(shard.data)
+        with tracing.span("checkpoint.save.gather") as span:
+            for name, array in sharded.items():
+                dim0 = array.shape[0]
+                seen: set = set()
+                for shard in array.addressable_shards:
+                    lo, hi = _interval(shard, dim0)
+                    if (lo, hi) in seen:
+                        continue  # replicas of these rows on other devices
+                    seen.add((lo, hi))
+                    if (lo, hi) == (0, dim0) and process != 0:
+                        continue  # fully replicated: rank 0 writes it
+                    entries[f"{name}|{lo}|{hi}"] = np.asarray(shard.data)
+            if process == 0:
+                dense_state = jax.device_get(dense_state)
+            span.fields["bytes"] = tree_nbytes(entries) + (
+                tree_nbytes(dense_state) if process == 0 else 0
+            )
         shard_files = [
             f"shards_p{i}of{n_processes}.npz" for i in range(n_processes)
         ]
-        np.savez(os.path.join(tmp_dir, shard_files[process]), **entries)
-        # Keep the shared tmp dir's mtime fresh while the save is live so
-        # a restarting peer's stale-tmp sweep (saver.sweep_stale_tmp)
-        # never mistakes an in-flight save for crashed-save garbage.
-        os.utime(tmp_dir)
-
-        if process == 0:
-            with open(os.path.join(tmp_dir, _DENSE), "wb") as f:
-                pickle.dump(jax.device_get(dense_state), f)
+        with tracing.span("checkpoint.save.write") as span:
+            written = [os.path.join(tmp_dir, shard_files[process])]
+            np.savez(written[0], **entries)
+            # Keep the shared tmp dir's mtime fresh while the save is
+            # live so a restarting peer's stale-tmp sweep
+            # (saver.sweep_stale_tmp) never mistakes an in-flight save
+            # for crashed-save garbage.
             os.utime(tmp_dir)
+            if process == 0:
+                written.append(os.path.join(tmp_dir, _DENSE))
+                with open(written[1], "wb") as f:
+                    pickle.dump(dense_state, f)
+                os.utime(tmp_dir)
+            span.fields["bytes"] = sum(map(os.path.getsize, written))
 
         if n_processes > 1:
             from jax.experimental import multihost_utils
@@ -193,31 +205,39 @@ class ShardedCheckpointSaver(CheckpointSaver):
             # would otherwise pass verification and crash restore) — is
             # checksummed post-barrier (all writers are done), before the
             # commit rename publishes anything.
-            write_integrity_manifest(
-                tmp_dir, shard_files + [_DENSE, _MANIFEST]
-            )
-            _apply_write_fault(os.path.join(tmp_dir, _DENSE))
-            try:
-                os.rename(tmp_dir, final_dir)
-            except OSError:
-                if not os.path.exists(final_dir):
-                    raise
-            save_hist, _restore, saves, _q = _ckpt_metrics()
-            save_hist.observe(time.monotonic() - start, kind="sharded")
-            saves.inc(kind="sharded")
-            obs.journal().record(
-                "checkpoint_saved",
-                step=step,
-                kind="sharded",
-                n_processes=n_processes,
-            )
-            logger.info(
-                "Saved sharded checkpoint at step %d (%d arrays, %d procs)",
-                step,
-                len(sharded),
-                n_processes,
-            )
-            self._garbage_collect()
+            inventory = shard_files + [_DENSE, _MANIFEST]
+            with tracing.span(
+                "checkpoint.save.crc",
+                bytes=sum(
+                    os.path.getsize(os.path.join(tmp_dir, name))
+                    for name in inventory
+                ),
+            ):
+                write_integrity_manifest(tmp_dir, inventory)
+            with tracing.span("checkpoint.save.commit", bytes=0):
+                _apply_write_fault(os.path.join(tmp_dir, _DENSE))
+                try:
+                    os.rename(tmp_dir, final_dir)
+                except OSError:
+                    if not os.path.exists(final_dir):
+                        raise
+                save_hist, _restore, saves, _q = _ckpt_metrics()
+                save_hist.observe(time.monotonic() - start, kind="sharded")
+                saves.inc(kind="sharded")
+                obs.journal().record(
+                    "checkpoint_saved",
+                    step=step,
+                    kind="sharded",
+                    n_processes=n_processes,
+                )
+                logger.info(
+                    "Saved sharded checkpoint at step %d (%d arrays, "
+                    "%d procs)",
+                    step,
+                    len(sharded),
+                    n_processes,
+                )
+                self._garbage_collect()
         return final_dir
 
     # -- restore ----------------------------------------------------------

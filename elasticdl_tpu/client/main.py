@@ -27,6 +27,9 @@ def _print_usage():
 
 
 def main(argv=None):
+    from elasticdl_tpu.obs import tracing
+
+    tracing.note_main_start()  # the end of the `proc.start` span
     argv = list(sys.argv[1:] if argv is None else argv)
     if not argv or argv[0] in ("-h", "--help"):
         _print_usage()

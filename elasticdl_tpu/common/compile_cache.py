@@ -43,4 +43,33 @@ def configure(flag_dir: str = "") -> str:
     # second process's) small jits are disk hits too.
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    _count_events()
     return cache_dir
+
+
+_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+_MISS_EVENT = "/jax/compilation_cache/cache_misses"
+_events = {_HIT_EVENT: 0, _MISS_EVENT: 0}
+_listening = False
+
+
+def _count_events() -> None:
+    """Count JAX's own cache-hit / cache-miss events (once a process),
+    so that a `compile.build` span can say which its build was."""
+    global _listening
+    if _listening:
+        return
+    _listening = True
+    import jax
+
+    def on_event(event: str, **_kwargs) -> None:
+        if event in _events:
+            _events[event] += 1
+
+    jax.monitoring.register_event_listener(on_event)
+
+
+def hits_and_misses() -> tuple:
+    """(persistent-cache hits, misses) this process has seen since
+    `configure()`; (0, 0) in a process that never configured."""
+    return _events[_HIT_EVENT], _events[_MISS_EVENT]

@@ -112,31 +112,46 @@ class StepProfiler:
                 logger.exception("start_trace failed; profiling disabled")
                 self._done = True
 
-    def after_steps(self, current_step: int):
+    def after_steps(self, current_step: int, wait_for=None):
         """Steps up to current_step have run: stop once the last
-        in-window step (end - 1) is done."""
+        in-window step (end - 1) is done.  `wait_for` is what the last
+        dispatched program returns (its loss): the stop blocks on it so
+        that the trace ends after that program, not inside it."""
         if self._tracing and current_step >= self._window[1] - 1:
-            self.stop()
+            self.stop(wait_for)
 
-    def stop(self):
+    def stop(self, wait_for=None):
         # Drop the shutdown hook first (bound-method equality): repeated
         # in-process construction (tests, e2e harnesses) must not pin
         # every historical profiler until interpreter exit.
         atexit.unregister(self.stop)
         if not self._tracing:
             return
+        import time
+
         import jax
 
+        start = time.monotonic()
         try:
+            # The device still runs the last dispatched program: wait
+            # for it (or, with nothing to wait on, for every effect the
+            # process has in flight) before the trace is cut.
+            if wait_for is not None:
+                jax.block_until_ready(wait_for)
+            else:
+                jax.effects_barrier()
             jax.profiler.stop_trace()
             logger.info("Profile trace written to %s", self._dir)
         except Exception:
             logger.exception("stop_trace failed")
         self._tracing = False
         self._done = True
-        self._journal_window("close")
+        # The wait and the dump: what a traced run stalls for here.
+        self._journal_window(
+            "close", duration_s=round(time.monotonic() - start, 6)
+        )
 
-    def _journal_window(self, action: str, at_step=None):
+    def _journal_window(self, action: str, at_step=None, duration_s=None):
         """Journal a ``profile_window`` event so postmortem timelines
         (obs.report) can point at the TensorBoard trace that covers an
         anomalous window.  Best-effort: journaling failure must never
@@ -153,6 +168,8 @@ class StepProfiler:
             )
             if at_step is not None:
                 fields["at_step"] = int(at_step)
+            if duration_s is not None:
+                fields["duration_s"] = duration_s
             obs.journal().record("profile_window", **fields)
         except Exception:
             logger.exception("profile_window journal record failed")

@@ -23,9 +23,12 @@ unchanged (reference-parity behaviour).
 from __future__ import annotations
 
 import inspect
+import time
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import numpy as np
+
+from elasticdl_tpu.obs import tracing
 
 Tree = Any  # nested dict/tuple of np.ndarray, all sharing axis-0 length
 
@@ -82,6 +85,7 @@ def materialize_columnar_task(
     read_columns = getattr(reader, "read_columns", None)
     if read_columns is None or columnar_dataset_fn is None:
         return None
+    read_start = time.monotonic()
     if (
         parse_pool is not None
         and "parse_pool" in inspect.signature(read_columns).parameters
@@ -91,6 +95,21 @@ def materialize_columnar_task(
         chunks = list(read_columns(task))
     if not chunks:
         return None
+    # `data.decode`: the task's chunks to one set of columns, then the
+    # model's columnar transform (its permutation included).  What the
+    # reader spent before it (index load, reads, the chunks' parse) is
+    # `read_columns_s`; a record-file reader journals the first two as
+    # `data.index_load` / `data.read`.
+    with tracing.span(
+        "data.decode",
+        read_columns_s=round(time.monotonic() - read_start, 6),
+    ) as span:
+        columnar = _decode(chunks, task, columnar_dataset_fn, mode, metadata)
+        span.fields["records"] = columnar.n
+    return columnar
+
+
+def _decode(chunks, task, columnar_dataset_fn, mode, metadata):
     if len(chunks) == 1:
         columns: Dict[str, np.ndarray] = chunks[0]
     else:

@@ -10,6 +10,7 @@ from __future__ import annotations
 
 import os
 import sys
+import time
 from dataclasses import dataclass
 from typing import Optional
 
@@ -162,11 +163,18 @@ def build_master(args, model_spec=None, rendezvous_server=None) -> Master:
 
     tensorboard_service = None
     if getattr(args, "tensorboard_log_dir", ""):
-        from elasticdl_tpu.master.tensorboard_service import TensorBoardService
+        from elasticdl_tpu.obs import tracing
 
-        tensorboard_service = TensorBoardService(
-            args.tensorboard_log_dir, task_manager=task_manager
-        )
+        # The writer's import (torch's tensorboard, TensorFlow behind
+        # it) is most of a master's boot.
+        with tracing.span("master.tensorboard_init"):
+            from elasticdl_tpu.master.tensorboard_service import (
+                TensorBoardService,
+            )
+
+            tensorboard_service = TensorBoardService(
+                args.tensorboard_log_dir, task_manager=task_manager
+            )
 
     evaluation_service = None
     if model_spec.eval_metrics_fn is not None and evaluation_shards:
@@ -246,6 +254,25 @@ def start_master(args, model_spec=None, rendezvous_server=None) -> Master:
     tracing.set_process("master")
     tracing.install_flight_recorder()
     master = build_master(args, model_spec, rendezvous_server)
+    tracing.record_proc_start()  # the journal exists from here on
+    with tracing.span("master.serve_ready") as ready:
+        _serve(master, args)
+        if tracing.main_start_ts() is not None:
+            # The whole boot at a glance: main's first line -> serving.
+            ready.fields["since_main_s"] = round(
+                time.time() - tracing.main_start_ts(), 6
+            )
+    # Phase accounting starts here: idle until the first dispatch or
+    # world declaration opens a real phase.
+    from elasticdl_tpu.obs import goodput
+
+    goodput.ledger().transition("idle", cause="master_start")
+    return master
+
+
+def _serve(master: Master, args) -> None:
+    """The gRPC server, the metrics exporter and the `master_start`
+    record: from here workers can reach the master."""
     master.server, master.port = start_master_server(
         master.servicer, port=args.master_port
     )
@@ -281,12 +308,6 @@ def start_master(args, model_spec=None, rendezvous_server=None) -> Master:
             master.metrics_exporter.port if master.metrics_exporter else None
         ),
     )
-    # Phase accounting starts here: idle until the first dispatch or
-    # world declaration opens a real phase.
-    from elasticdl_tpu.obs import goodput
-
-    goodput.ledger().transition("idle", cause="master_start")
-    return master
 
 
 def mode_from_job_type(job_type: str) -> str:
@@ -308,7 +329,9 @@ def main(argv=None):
     master server for debugging.
     """
     from elasticdl_tpu.common import faults
+    from elasticdl_tpu.obs import tracing
 
+    tracing.note_main_start()  # the end of the `proc.start` span
     if faults.install_from_env():
         logger.warning(
             "Fault injection armed from %s=%r",

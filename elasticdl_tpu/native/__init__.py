@@ -15,11 +15,13 @@ from __future__ import annotations
 import ctypes
 import os
 import subprocess
+import time
 from typing import Optional
 
 import numpy as np
 
 from elasticdl_tpu.common.log_utils import get_logger
+from elasticdl_tpu.obs import tracing
 
 logger = get_logger("native")
 
@@ -299,6 +301,26 @@ class NativeRecordFile:
                 yield bytes(view[offset : offset + int(length)])
                 offset += int(length)
 
+    def _range_size(self, handle, lo: int, hi: int, task: dict) -> int:
+        """`edl_rf_range_size`, which on a handle's FIRST call reads the
+        file's whole index (8 B a record; `load_index` in
+        recordfile.cc): that call is the `data.index_load` span, and
+        the bytes it read are the task's `index_bytes`."""
+        if task["index_loaded"]:
+            total = int(self._lib.edl_rf_range_size(handle, lo, hi))
+        else:
+            index_bytes = 8 * int(self._lib.edl_rf_count(handle))
+            with tracing.span(
+                "data.index_load", index_bytes=index_bytes,
+                opens=task["opens"],
+            ):
+                total = int(self._lib.edl_rf_range_size(handle, lo, hi))
+            task["index_loaded"] = True
+            task["index_bytes"] += index_bytes
+        if total < 0:
+            raise IOError(self._error())
+        return total
+
     def read_range_buffers(self, path: str, start: int, end: int,
                            max_bytes: int = 0):
         """Yield (payloads np.uint8 buffer, lengths np.uint32) CHUNKS of
@@ -306,11 +328,21 @@ class NativeRecordFile:
         Python objects (the vectorized data-plane path; see
         data/vectorized.py).  `max_bytes` overrides the default chunk
         byte bound (and lifts the record cap — the caller's byte budget
-        is the bound; see data/recordfile.read_range_buffers)."""
+        is the bound; see data/recordfile.read_range_buffers).
+
+        One call is one task's read: it journals `data.index_load`
+        (above) and, when the generator closes, one `data.read` span —
+        the `edl_rf_read_range` calls summed — carrying the task's
+        counters (`records`, `payload_bytes`, `index_bytes`, `opens`).
+        Counters ride on spans because a job that ends by SIGKILL
+        never writes an exit-time registry snapshot."""
         bytes_cap = max_bytes or self.CHUNK_BYTES
         handle = self._lib.edl_rf_open(path.encode())
         if not handle:
             raise IOError(self._error())
+        task = {"opens": 1, "index_loaded": False, "index_bytes": 0,
+                "records": 0, "payload_bytes": 0}
+        read_start_ts, read_s = None, 0.0
         try:
             count = int(self._lib.edl_rf_count(handle))
             start = max(0, start)
@@ -321,33 +353,43 @@ class NativeRecordFile:
                     end - pos if max_bytes
                     else min(self.CHUNK_RECORDS, end - pos)
                 )
-                total = int(self._lib.edl_rf_range_size(handle, pos, pos + n))
-                if total < 0:
-                    raise IOError(self._error())
+                total = self._range_size(handle, pos, pos + n, task)
                 while n > 1 and total > bytes_cap:
                     n //= 2  # range_size is O(1) over the index
-                    total = int(
-                        self._lib.edl_rf_range_size(handle, pos, pos + n)
-                    )
-                    if total < 0:
-                        raise IOError(self._error())
+                    total = self._range_size(handle, pos, pos + n, task)
                 buf = np.empty(total, np.uint8)
                 lengths = np.empty(n, np.uint32)
-                read = self._lib.edl_rf_read_range(
-                    handle,
-                    pos,
-                    pos + n,
-                    buf.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)),
-                    total,
-                    lengths.ctypes.data_as(ctypes.POINTER(ctypes.c_uint32)),
-                )
+                if read_start_ts is None:
+                    read_start_ts = time.time()
+                t_read = time.monotonic()
+                with tracing.annotate("data.read"):
+                    read = self._lib.edl_rf_read_range(
+                        handle,
+                        pos,
+                        pos + n,
+                        buf.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)),
+                        total,
+                        lengths.ctypes.data_as(
+                            ctypes.POINTER(ctypes.c_uint32)),
+                    )
+                read_s += time.monotonic() - t_read
                 if read < 0:
                     raise IOError(self._error())
                 used = int(lengths[:read].sum())
+                task["records"] += int(read)
+                task["payload_bytes"] += used
                 yield buf[:used], lengths[:read]
                 pos += read
         finally:
             self._lib.edl_rf_close(handle)
+            if read_start_ts is not None:
+                tracing.record_child_span(
+                    "data.read", read_start_ts, read_s,
+                    records=task["records"],
+                    payload_bytes=task["payload_bytes"],
+                    index_bytes=task["index_bytes"],
+                    opens=task["opens"],
+                )
 
     def write_records(self, path: str, records) -> int:
         handle = self._lib.edl_rf_writer_open(path.encode())

@@ -14,8 +14,17 @@ registry, or lock call of this module ever executes under jit):
   compiled (lowering/retrace; detected via the jit compile-cache size,
   polled per dispatch — never inside the traced region);
 - ``execute``    — device execution of an already-compiled program;
-- ``bookkeep``   — optimizer/bookkeeping host work (version reports,
-  telemetry folds, checkpoint cadence decisions).
+- ``bookkeep``   — bookkeeping host work between dispatches (version
+  reports, telemetry folds, counters).  A checkpoint save and the
+  profiler's stop run OUTSIDE it: the save has its own
+  ``checkpoint.save`` span and goodput phase, the dump its
+  ``profile_window`` close event.
+
+Every ``phase()`` / ``dispatch()`` call also enters a profiler
+annotation of the span's name for its real interval (``step.<phase>``;
+``step.dispatch`` for a dispatch, whichever of compile / execute it is
+booked under afterwards) — obs/tracing.py ``annotate``, a no-op without
+jax or without a running profile.
 
 One more clock rides BESIDE the exclusive phases: ``overlap_s``, the
 async staging engine's credit ledger (data/pipeline.py).  Host work
@@ -56,6 +65,7 @@ from typing import Callable, Dict, List, Optional
 
 from elasticdl_tpu.analysis.runtime import make_lock
 from elasticdl_tpu.common.log_utils import get_logger
+from elasticdl_tpu.obs.tracing import annotate
 
 logger = get_logger("obs.stepstats")
 
@@ -336,7 +346,8 @@ class StepAnatomy:
         with anatomy.dispatch(n_steps, n_examples):
             trainer.train_window(staged)   # books compile OR execute
         with anatomy.phase("bookkeep"):
-            report_version(); maybe_checkpoint()
+            report_version()
+        maybe_checkpoint()                 # its own span, not bookkeep
         anatomy.close_window()             # one window per dispatch flush
 
     ``snapshot()`` is called from the heartbeat thread; mutators run on
@@ -416,7 +427,8 @@ class StepAnatomy:
             self._open_phase = name
         start = self._clock()
         try:
-            yield
+            with annotate(f"step.{name}"):
+                yield
         finally:
             elapsed = max(0.0, self._clock() - start)
             with self._lock:
@@ -458,7 +470,8 @@ class StepAnatomy:
             self._open_phase = "execute"
         start = self._clock()
         try:
-            yield
+            with annotate("step.dispatch", steps=int(n_steps)):
+                yield
         finally:
             elapsed = max(0.0, self._clock() - start)
             compiled = self._watcher.poll()

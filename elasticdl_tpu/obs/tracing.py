@@ -43,6 +43,18 @@ lengths.  All clock reads happen HERE, strictly outside traced code
 (the instrumented sites are host-side control-plane code), keeping the
 trace-purity analysis rule green.
 
+Two sinks, one vocabulary: every span opened HERE (``Tracer.span``) and
+every step phase opened in obs/stepstats.py also enters a
+``jax.profiler.TraceAnnotation`` of the SAME name for the real interval
+of the call (``annotate`` below), so a profile taken with
+``--profile_steps`` carries the program's spans on the device ops' own
+timeline.  The sink exists only in a process that has already imported
+jax (``sys.modules``): the master and the serving supervisor stay off
+jax, and with no profile running an annotation is an atomic load.
+``SPAN_NAMES`` is the bounded list of training-path span names with what
+each one times; ``DEVICE_SCOPES`` is the list of ``jax.named_scope``
+names the trainers put on device ops.
+
 Crash flight recorder: ``install_flight_recorder()`` registers an
 atexit hook (reached from SIGTERM via the worker main's
 SIGTERM->SystemExit conversion, the PR-3 shutdown path) that flushes
@@ -59,6 +71,7 @@ import contextvars
 import itertools
 import json
 import os
+import sys
 import time
 from dataclasses import dataclass, field
 from typing import Dict, Optional
@@ -76,10 +89,82 @@ _TRACER_SEQ = itertools.count()
 #: (mirrors stepstats.PHASES; imported lazily there to avoid a cycle).
 _WINDOW_PHASES = ("data_wait", "stage", "compile", "execute", "bookkeep")
 
+#: The training path's span names (master, worker, checkpoint, data)
+#: and what each one times.  "interval" spans are journaled with their
+#: real start and length and, where jax is loaded, entered as a
+#: TraceAnnotation of the same name; "aggregate" spans are a dispatch
+#: window's phase totals laid out back to back (sound as sums, not as
+#: positions on a timeline); "after" spans are journaled after the fact
+#: from two clock reads and have no annotation.  PERF.md section 3 names
+#: the reader of each (a benchmark metric or an obs command).
+SPAN_NAMES: Dict[str, str] = {
+    # task chain (obs.trace waterfall, obs.report slowest chains)
+    "task.lifetime": "after: master, dispatch -> report (trace root)",
+    "rpc.get_task": "after: master, dispatcher under the RPC handler",
+    "rpc.report_task_result": "after: master, report handler",
+    "worker.get_task": "after: worker, client half of dispatch",
+    "worker.report_task": "interval: worker, result report RPC",
+    "worker.task": "interval: worker, one task's execution",
+    "worker.join_world": "interval: worker, rendezvous join",
+    "rendezvous.formation": "after: master, declaration -> all polled",
+    # step anatomy: journal aggregates + annotation intervals
+    "step.data_wait": "aggregate; annotation: each wait for a batch",
+    "step.stage": "aggregate; annotation: each host->device staging",
+    "step.compile": "aggregate: dispatches during which a jit compiled",
+    "step.execute": "aggregate: dispatch clock of compiled programs",
+    "step.dispatch": "annotation only: each device dispatch call "
+                     "(what step.compile/step.execute clock)",
+    "step.bookkeep": "aggregate; annotation: telemetry, version report",
+    # host data plane (one set per task of a record-file reader)
+    "data.index_load": "interval: first range_size after open (the "
+                       "file's whole index is read here)",
+    "data.read": "after: the task's read_range calls, summed",
+    "data.decode": "interval: columnar concatenate + model transform",
+    # checkpoint
+    "checkpoint.save": "interval: one save, all ranks",
+    "checkpoint.save.gather": "interval: device -> host",
+    "checkpoint.save.write": "interval: serialise and write, to close",
+    "checkpoint.save.crc": "interval: the manifest's re-read (CRC32)",
+    "checkpoint.save.commit": "interval: rename + garbage-collect",
+    "checkpoint.restore": "interval: newest step + its CRC check",
+    "checkpoint.restore.load": "interval: read, unpickle, place",
+    # start-up
+    "proc.start": "after: process creation -> first line of main",
+    "master.tensorboard_init": "interval: TensorFlow import + writer",
+    "master.serve_ready": "interval: gRPC server + exporter start",
+    "worker.backend_init": "interval: first jax.devices()",
+    "state.init": "interval: model.init / restore + placement",
+    "compile.build": "interval: first call of a jitted entrypoint",
+}
+
+#: ``jax.named_scope`` names on device ops (op metadata only; they show
+#: as path components of an op's ``op_name`` in HLO and in the trace).
+#: PS trainer: fwd_bwd, dense_update, sparse_apply > (grad_accumulate,
+#: sparse_adam).  Dense trainer: fwd_bwd > (attn, mlp, lm_head_loss),
+#: optimizer.
+DEVICE_SCOPES = (
+    "fwd_bwd", "dense_update", "sparse_apply", "grad_accumulate",
+    "sparse_adam", "attn", "mlp", "lm_head_loss", "optimizer",
+)
+
 #: Size bound on the flight recorder's final registry snapshot: the
 #: journal is size-capped, and a pathological registry must not spend
 #: the whole budget on one exit record.
 MAX_REGISTRY_SNAPSHOT_BYTES = 32 << 10
+
+
+def annotate(name: str, **fields):
+    """The profiler sink: a ``jax.profiler.TraceAnnotation(name)`` to
+    enter around the real interval of the work, or a null context in a
+    process that has not imported jax (never imports it).  Scalar
+    fields ride along as the event's stats."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return contextlib.nullcontext()
+    try:
+        return jax.profiler.TraceAnnotation(name, **fields)
+    except Exception:  # a profiler quirk must never break the work
+        return contextlib.nullcontext()
 
 
 @dataclass
@@ -208,7 +293,8 @@ class Tracer:
         token = self._current.set(span)
         error = None
         try:
-            yield span
+            with annotate(name):
+                yield span
         except BaseException as exc:
             error = type(exc).__name__
             raise
@@ -347,8 +433,68 @@ def record_span(name: str, start_ts: float, duration_s: float, **kwargs):
     return _tracer.record_span(name, start_ts, duration_s, **kwargs)
 
 
+def record_child_span(name: str, start_ts: float, duration_s: float,
+                      **fields):
+    """An after-the-fact span as a child of the CURRENT span (a task's
+    summed reads under its `worker.task`); a root where none is open."""
+    return _tracer.record_span(
+        name, start_ts, duration_s,
+        trace_id=_tracer.current_trace_id(),
+        parent_id=_tracer.current_span_id(), **fields
+    )
+
+
 def set_process(label: str) -> None:
     _tracer.set_process(label)
+
+
+# ---------------------------------------------------------------------------
+# Process start
+# ---------------------------------------------------------------------------
+
+_main_start_ts: Optional[float] = None
+_proc_start_recorded = False
+
+
+def note_main_start() -> None:
+    """Call on the first line of a process's `main`: the end of
+    `proc.start` (the first note wins)."""
+    global _main_start_ts
+    if _main_start_ts is None:
+        _main_start_ts = time.time()
+
+
+def main_start_ts() -> Optional[float]:
+    return _main_start_ts
+
+
+def _process_created_ts() -> Optional[float]:
+    """Wall-clock time the kernel created this process: `starttime` of
+    /proc/self/stat (clock ticks after boot) against /proc/uptime."""
+    try:
+        with open("/proc/self/stat") as f:
+            ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        with open("/proc/uptime") as f:
+            uptime_s = float(f.read().split()[0])
+        age_s = uptime_s - ticks / os.sysconf("SC_CLK_TCK")
+        return time.time() - max(0.0, age_s)
+    except (OSError, ValueError, IndexError):
+        return None
+
+
+def record_proc_start() -> Optional[dict]:
+    """Journal `proc.start` once the process has a journal: process
+    creation -> the first line of main (interpreter start and the
+    imports before main).  Nothing where main never noted its start or
+    /proc cannot say when the process was created."""
+    global _proc_start_recorded
+    created = _process_created_ts()
+    if _proc_start_recorded or _main_start_ts is None or created is None:
+        return None
+    _proc_start_recorded = True
+    return _tracer.record_span(
+        "proc.start", created, max(0.0, _main_start_ts - created)
+    )
 
 
 # ---------------------------------------------------------------------------

@@ -769,7 +769,10 @@ def flash_attention(
         )
     scale = scale if scale is not None else 1.0 / (d ** 0.5)
     interpret = _use_interpret() if interpret is None else interpret
-    # Kernels run in [B, H, T, D].
-    qt, kt, vt = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
-    out = _flash(qt, kt, vt, scale, causal, block_q, block_k, interpret)
-    return out.transpose(0, 2, 1, 3)
+    # Kernels run in [B, H, T, D].  `attn` is the device scope of
+    # attention (obs/tracing.py DEVICE_SCOPES): the transposes and both
+    # passes of the kernel carry it whoever calls.
+    with jax.named_scope("attn"):
+        qt, kt, vt = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
+        out = _flash(qt, kt, vt, scale, causal, block_q, block_k, interpret)
+        return out.transpose(0, 2, 1, 3)

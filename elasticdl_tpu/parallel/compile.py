@@ -244,6 +244,37 @@ def jit_utility(fn: Callable, **jit_kwargs):
 # ---------------------------------------------------------------------------
 
 
+class _BuildSpan:
+    """A jitted entrypoint whose FIRST call — tracing, lowering, the XLA
+    compile or the persistent cache's load, and the dispatch — runs
+    inside a `compile.build` span (`entrypoint`, and `cache_hit`: every
+    program the call built came from the persistent cache).  Every
+    later call goes straight through; attributes (`_cache_size`,
+    `lower`, ...) are the jitted function's own."""
+
+    def __init__(self, jitted, name: str):
+        self._jitted, self._name, self._built = jitted, name, False
+
+    def __call__(self, *args, **kwargs):
+        if self._built:
+            return self._jitted(*args, **kwargs)
+        from elasticdl_tpu.common import compile_cache
+        from elasticdl_tpu.obs import tracing
+
+        self._built = True
+        hits, misses = compile_cache.hits_and_misses()
+        with tracing.span("compile.build", entrypoint=self._name) as span:
+            out = self._jitted(*args, **kwargs)
+            hits_now, misses_now = compile_cache.hits_and_misses()
+            span.fields["cache_hit"] = (
+                hits_now > hits and misses_now == misses
+            )
+        return out
+
+    def __getattr__(self, attr):
+        return getattr(self._jitted, attr)
+
+
 def _journal_plan(record: Dict[str, Any]) -> None:
     # Host-side only (trainer init / _compile_steps time); the obs
     # plane never rides a traced step (trace-purity rule).
@@ -353,4 +384,4 @@ class CompilePlan:
                 "donated_argnums": list(donate_argnums),
                 "devices": int(self.mesh.devices.size),
             })
-        return compiled
+        return _BuildSpan(compiled, name)

@@ -22,7 +22,9 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 
+from elasticdl_tpu.checkpoint.saver import tree_nbytes
 from elasticdl_tpu.common.log_utils import get_logger
+from elasticdl_tpu.obs import tracing
 from elasticdl_tpu.parallel import compile as pc
 from elasticdl_tpu.parallel import sharding as shd
 from elasticdl_tpu.worker.trainer import TrainState, _model_apply
@@ -249,61 +251,10 @@ class DataParallelTrainer:
 
     def ensure_initialized(self, features) -> TrainState:
         if self._state is None:
-            from elasticdl_tpu.layers.embedding import (
-                SPECS_COLLECTION,
-                export_spec_map,
-            )
-
-            rng = jax.random.PRNGKey(self._seed)
-            features = jax.tree.map(jnp.asarray, features)
-            # Structure first (no FLOPs, no memory), shardings from it,
-            # then a jitted init whose out_shardings birth the state in
-            # its final layout — under FSDP no device ever holds the
-            # full params+opt_state (the point of sharding them).
-            state_shapes, _specs_shapes = jax.eval_shape(
-                self._make_state, rng, features
-            )
-            if self._pending_sharded_restore is not None:
-                # Restore path: the checkpoint supplies every value, so
-                # never run (or even compile) the full init — the shape
-                # tree is template enough, and the tiny export specs come
-                # from a specs-only jit whose unused param computations
-                # XLA dead-code-eliminates.
-                # Specs-only jit: the outputs are a handful of [2] int32
-                # packed-table specs (host-bound, layout-irrelevant) and
-                # the param computations feeding them are dead-code-
-                # eliminated — declaring shardings here would force the
-                # full init to compile (jit_utility is the compile
-                # layer's sanctioned non-step passthrough).
-                specs = pc.jit_utility(
-                    lambda r, f: self._make_state(r, f)[1]
-                )(rng, features)
-                self._state = self._restore_sharded(state_shapes)
-            else:
-                plan = self._plan()
-                repl = plan.replicated()
-                init = plan.compile(
-                    self._make_state,
-                    name="dp_init",
-                    out_shardings=(
-                        self._state_shardings(state_shapes, plan),
-                        jax.tree.map(lambda _: repl, _specs_shapes),
-                    ),
-                )
-                self._state, specs = init(rng, features)
-            self._export_specs = export_spec_map(
-                {SPECS_COLLECTION: jax.device_get(specs)}
-            )
-            logger.info(
-                "Initialized %s model over %d-way data parallel: "
-                "%d parameters",
-                self._dense_sharding,
-                self._dp,
-                sum(
-                    int(np.prod(p.shape))
-                    for p in jax.tree.leaves(state_shapes.params)
-                ),
-            )
+            # `state.init`: shapes, then the jitted init that births the
+            # state in its layout (or the restore in its place).
+            with tracing.span("state.init", trainer="dp_trainer"):
+                self._init_state(features)
         if self._pending_sharded_restore is not None:
             # State arrived via the setter (or was already live) after a
             # deferred restore was registered: apply it now.
@@ -311,6 +262,63 @@ class DataParallelTrainer:
         if self._train_step is None:
             self._compile_steps(self._state)
         return self._state
+
+    def _init_state(self, features) -> None:
+        from elasticdl_tpu.layers.embedding import (
+            SPECS_COLLECTION,
+            export_spec_map,
+        )
+
+        rng = jax.random.PRNGKey(self._seed)
+        features = jax.tree.map(jnp.asarray, features)
+        # Structure first (no FLOPs, no memory), shardings from it,
+        # then a jitted init whose out_shardings birth the state in
+        # its final layout — under FSDP no device ever holds the
+        # full params+opt_state (the point of sharding them).
+        state_shapes, _specs_shapes = jax.eval_shape(
+            self._make_state, rng, features
+        )
+        if self._pending_sharded_restore is not None:
+            # Restore path: the checkpoint supplies every value, so
+            # never run (or even compile) the full init — the shape
+            # tree is template enough, and the tiny export specs come
+            # from a specs-only jit whose unused param computations
+            # XLA dead-code-eliminates.
+            # Specs-only jit: the outputs are a handful of [2] int32
+            # packed-table specs (host-bound, layout-irrelevant) and
+            # the param computations feeding them are dead-code-
+            # eliminated — declaring shardings here would force the
+            # full init to compile (jit_utility is the compile
+            # layer's sanctioned non-step passthrough).
+            specs = pc.jit_utility(
+                lambda r, f: self._make_state(r, f)[1]
+            )(rng, features)
+            self._state = self._restore_sharded(state_shapes)
+        else:
+            plan = self._plan()
+            repl = plan.replicated()
+            init = plan.compile(
+                self._make_state,
+                name="dp_init",
+                out_shardings=(
+                    self._state_shardings(state_shapes, plan),
+                    jax.tree.map(lambda _: repl, _specs_shapes),
+                ),
+            )
+            self._state, specs = init(rng, features)
+        self._export_specs = export_spec_map(
+            {SPECS_COLLECTION: jax.device_get(specs)}
+        )
+        logger.info(
+            "Initialized %s model over %d-way data parallel: "
+            "%d parameters",
+            self._dense_sharding,
+            self._dp,
+            sum(
+                int(np.prod(p.shape))
+                for p in jax.tree.leaves(state_shapes.params)
+            ),
+        )
 
     # -- compiled steps -------------------------------------------------
 
@@ -326,11 +334,15 @@ class DataParallelTrainer:
             loss = jnp.sum(losses * mask) / jnp.maximum(jnp.sum(mask), 1.0)
             return loss, new_model_state
 
-        (loss, new_model_state), grads = jax.value_and_grad(
-            compute_loss, has_aux=True
-        )(state.params)
-        updates, new_opt_state = self._tx.update(grads, state.opt_state, state.params)
-        new_params = optax.apply_updates(state.params, updates)
+        with jax.named_scope("fwd_bwd"):
+            (loss, new_model_state), grads = jax.value_and_grad(
+                compute_loss, has_aux=True
+            )(state.params)
+        with jax.named_scope("optimizer"):
+            updates, new_opt_state = self._tx.update(
+                grads, state.opt_state, state.params
+            )
+            new_params = optax.apply_updates(state.params, updates)
         if not mutable_keys:
             new_model_state = state.model_state
         return (
@@ -438,9 +450,12 @@ class DataParallelTrainer:
         this full-gather remains for export/debug paths."""
         if self._state is None:
             return None
-        if self._dense_sharding == "replicated":
-            return jax.device_get(self._state)
-        return shd.gather_to_host(self._state)
+        with tracing.span(
+            "checkpoint.save.gather", bytes=tree_nbytes(self._state)
+        ):
+            if self._dense_sharding == "replicated":
+                return jax.device_get(self._state)
+            return shd.gather_to_host(self._state)
 
     # -- sharded checkpointing (FSDP) -----------------------------------
 
@@ -467,7 +482,9 @@ class DataParallelTrainer:
             key = self._leaf_key(path)
             if sharding.is_fully_replicated:
                 if jax.process_index() == 0:
-                    dense_leaves[key] = jax.device_get(leaf)
+                    # The saver brings it to the host, inside its
+                    # `checkpoint.save.gather` span.
+                    dense_leaves[key] = leaf
             else:
                 sharded[key] = leaf
         dense = None
@@ -483,6 +500,13 @@ class DataParallelTrainer:
         self._host_step = step
 
     def _restore_sharded(self, template: TrainState) -> TrainState:
+        saver, step = self._pending_sharded_restore
+        with tracing.span("checkpoint.restore.load", step=step) as span:
+            restored = self._restore_sharded_inner(template)
+            span.fields["bytes"] = tree_nbytes(restored)
+        return restored
+
+    def _restore_sharded_inner(self, template: TrainState) -> TrainState:
         saver, step = self._pending_sharded_restore
         self._pending_sharded_restore = None
         shardings = self._state_shardings(template)

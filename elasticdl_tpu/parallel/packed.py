@@ -246,8 +246,9 @@ def grad_accumulate(spec: PackedSpec, packed_like, ids, grads):
     (zeros elsewhere).  This IS the dedup: duplicate ids sum, exactly like
     the reference's IndexedSlices -> unsorted_segment_sum before its Eigen
     sparse-apply kernels (elasticdl/pkg/kernel/capi)."""
-    block_ids, rows = expand_updates(spec, ids, grads)
-    return jnp.zeros_like(packed_like).at[block_ids].add(rows)
+    with jax.named_scope("grad_accumulate"):
+        block_ids, rows = expand_updates(spec, ids, grads)
+        return jnp.zeros_like(packed_like).at[block_ids].add(rows)
 
 
 def touched_mask(spec: PackedSpec, acc):

@@ -30,7 +30,9 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 
+from elasticdl_tpu.checkpoint.saver import tree_nbytes
 from elasticdl_tpu.common.log_utils import get_logger
+from elasticdl_tpu.obs import tracing
 from elasticdl_tpu.layers.embedding import (
     IDS_COLLECTION,
     OOV_COLLECTION,
@@ -337,6 +339,14 @@ class ShardedEmbeddingTrainer:
     def ensure_initialized(self, features) -> PSTrainState:
         if self._state is not None:
             return self._state
+        # `state.init`: model.init, the slots, and placement (or the
+        # restore over it: `checkpoint.restore.load` nests inside).
+        with tracing.span("state.init", trainer="ps_trainer"):
+            self._init_state(features)
+        self._compile_steps()
+        return self._state
+
+    def _init_state(self, features) -> None:
         rng = jax.random.PRNGKey(self._seed)
         # Init with the GLOBAL batch shape (local rows x process count):
         # perturbation variables take their shape from init, and apply runs
@@ -495,8 +505,6 @@ class ShardedEmbeddingTrainer:
             tables=len(tables),
             table_rows=total_rows,
         )
-        self._compile_steps()
-        return self._state
 
     def _compile_steps(self):
         plan = self._plan()
@@ -561,9 +569,10 @@ class ShardedEmbeddingTrainer:
             loss = jnp.sum(losses * mask) / jnp.maximum(jnp.sum(mask), 1.0)
             return loss, muts
 
-        (loss, muts), (dense_grads, perturb_grads) = jax.value_and_grad(
-            compute_loss, argnums=(0, 1), has_aux=True
-        )(state.params, self._zero_perturbations())
+        with jax.named_scope("fwd_bwd"):
+            (loss, muts), (dense_grads, perturb_grads) = jax.value_and_grad(
+                compute_loss, argnums=(0, 1), has_aux=True
+            )(state.params, self._zero_perturbations())
         return loss, muts, dense_grads, perturb_grads
 
     @staticmethod
@@ -589,10 +598,11 @@ class ShardedEmbeddingTrainer:
             yield key, spec, flat_ids, flat_grads
 
     def _dense_and_state(self, state, muts, dense_grads):
-        updates, new_opt_state = self._tx.update(
-            dense_grads, state.opt_state, state.params
-        )
-        new_params = optax.apply_updates(state.params, updates)
+        with jax.named_scope("dense_update"):
+            updates, new_opt_state = self._tx.update(
+                dense_grads, state.opt_state, state.params
+            )
+            new_params = optax.apply_updates(state.params, updates)
         new_model_state = (
             {k: muts[k] for k in state.model_state.keys() if k in muts}
             or state.model_state
@@ -612,9 +622,11 @@ class ShardedEmbeddingTrainer:
         for key, spec, flat_ids, flat_grads in self._sparse_batches(
             muts, perturb_grads, new_tables
         ):
-            new_tables[key], new_slots[key] = self._emb_tx.apply(
-                spec, new_tables[key], new_slots[key], flat_ids, flat_grads
-            )
+            with jax.named_scope("sparse_apply"):
+                new_tables[key], new_slots[key] = self._emb_tx.apply(
+                    spec, new_tables[key], new_slots[key],
+                    flat_ids, flat_grads,
+                )
         return (
             PSTrainState(
                 state.step + 1,
@@ -680,11 +692,12 @@ class ShardedEmbeddingTrainer:
         for key in self._table_paths:
             spec = self._table_specs[key]
             ids_w, grads_w = sparse[key]  # [W, n], [W, n, dim]
-            new_tables[key], new_slots[key] = self._emb_tx.apply(
-                spec, new_tables[key], new_slots[key],
-                ids_w.reshape((-1,)),
-                grads_w.reshape((-1, spec.dim)),
-            )
+            with jax.named_scope("sparse_apply"):
+                new_tables[key], new_slots[key] = self._emb_tx.apply(
+                    spec, new_tables[key], new_slots[key],
+                    ids_w.reshape((-1,)),
+                    grads_w.reshape((-1, spec.dim)),
+                )
         return (
             state._replace(tables=new_tables, slots=new_slots),
             (losses, jnp.sum(oovs)),
@@ -891,13 +904,15 @@ class ShardedEmbeddingTrainer:
         state = self._state
         # Dense state is replicated and only rank 0 writes it — don't pay
         # the device->host transfer on the other N-1 ranks' hot path.
+        # The saver brings it to the host with the table rows, inside
+        # its `checkpoint.save.gather` span.
         dense = None
         if jax.process_index() == 0:
             dense = {
-                "step": jax.device_get(state.step),
-                "params": jax.device_get(state.params),
-                "opt_state": jax.device_get(state.opt_state),
-                "model_state": jax.device_get(state.model_state),
+                "step": state.step,
+                "params": state.params,
+                "opt_state": state.opt_state,
+                "model_state": state.model_state,
                 "scalar_slots": self._scalar_slots(state),
             }
         saver.save(step, dense, self._sharded_arrays(state))
@@ -914,6 +929,13 @@ class ShardedEmbeddingTrainer:
         dense state replicates from rank 0's pickle; each table/slot row
         interval is read by whichever process now owns it — world-size
         agnostic, which is what restart-the-world shrink/grow needs."""
+        saver, step = self._pending_sharded_restore
+        with tracing.span("checkpoint.restore.load", step=step) as span:
+            restored = self._restore_sharded_inner(template)
+            span.fields["bytes"] = tree_nbytes(restored)
+        return restored
+
+    def _restore_sharded_inner(self, template: PSTrainState) -> PSTrainState:
         saver, step = self._pending_sharded_restore
         self._pending_sharded_restore = None
         shardings = self._state_shardings(template)
@@ -1030,17 +1052,23 @@ class ShardedEmbeddingTrainer:
         if self._state is None:
             return None
         state = self._state
-        return PSTrainState(
-            step=jax.device_get(state.step),
-            params=jax.device_get(state.params),
-            opt_state=jax.device_get(state.opt_state),
-            model_state=jax.device_get(state.model_state),
-            tables={k: shd.gather_to_host(v) for k, v in state.tables.items()},
-            slots={
-                k: {n: shd.gather_to_host(v) for n, v in group.items()}
-                for k, group in state.slots.items()
-            },
-        )
+        with tracing.span(
+            "checkpoint.save.gather", bytes=tree_nbytes(state)
+        ):
+            return PSTrainState(
+                step=jax.device_get(state.step),
+                params=jax.device_get(state.params),
+                opt_state=jax.device_get(state.opt_state),
+                model_state=jax.device_get(state.model_state),
+                tables={
+                    k: shd.gather_to_host(v)
+                    for k, v in state.tables.items()
+                },
+                slots={
+                    k: {n: shd.gather_to_host(v) for n, v in group.items()}
+                    for k, group in state.slots.items()
+                },
+            )
 
     def get_variables_numpy(self) -> dict:
         """Flat {path: logical np.ndarray} — packed tables are unpacked to

@@ -55,6 +55,7 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 
 from elasticdl_tpu.parallel import packed as pk
@@ -159,6 +160,17 @@ def select_mode(spec: PackedSpec, n_ids: int, mode: str) -> str:
     return (
         "scatter" if _use_scatter(spec, n_ids, mode) else "stream"
     )
+
+
+def _scoped(name: str, fn):
+    """`fn` traced under `jax.named_scope(name)`: the device ops it
+    emits carry the name in their `op_name` (metadata only)."""
+
+    def scoped(*args):
+        with jax.named_scope(name):
+            return fn(*args)
+
+    return scoped
 
 
 def _fused_apply(kind: str, hyper: dict, mesh=None):
@@ -419,6 +431,8 @@ def adam(
 
     hyper = {"learning_rate": lr, "beta_1": beta_1, "beta_2": beta_2,
              "epsilon": epsilon, "bias_correction": bias_correction}
+    stream_apply_acc = _scoped("sparse_adam", stream_apply_acc)
+    scatter_apply = _scoped("sparse_adam", scatter_apply)
     return SparseOptimizer(
         "adam", init_slots,
         _dual_apply(mode, stream_apply_acc, scatter_apply,

@@ -25,6 +25,7 @@ import jax
 import numpy as np
 
 from elasticdl_tpu import obs
+from elasticdl_tpu.checkpoint.saver import save_span
 from elasticdl_tpu.common import faults
 from elasticdl_tpu.common.constants import Mode, TaskExecCounterKey
 from elasticdl_tpu.common.log_utils import get_logger
@@ -221,7 +222,7 @@ class CollectiveWorker:
         finally:
             heartbeat.stop()
             if self._profiler is not None:
-                self._profiler.stop()
+                self._profiler.stop()  # no-op unless a window is open
 
     def _verify_restore_consistency(self):
         """Post-restore world-formation check over the control-plane
@@ -616,10 +617,16 @@ class CollectiveWorker:
                 batch_count += len(pending)
                 record_count += pending_real
                 pending, pending_real = [], 0
-                if self._profiler is not None:
-                    self._profiler.after_steps(self._trainer.step)
                 self._report_version_if_due()
-                self._maybe_checkpoint()
+            # Outside bookkeep, each under its own name: a profile's
+            # stop waits for the last dispatched program and writes the
+            # trace (`profile_window` close, duration_s); a cadence save
+            # has its `checkpoint.save` span and goodput phase.
+            if self._profiler is not None:
+                self._profiler.after_steps(
+                    self._trainer.step, wait_for=last_loss
+                )
+            self._maybe_checkpoint()
             if self._anatomy is not None:
                 if prefetcher is not None:
                     # Producer time hidden behind this flush's device
@@ -834,9 +841,7 @@ class CollectiveWorker:
             # span nests under worker.task when the save fired from a
             # mid-task cadence check (root-less at job end).
             with goodput.ledger().phase("checkpoint_save", cause="cadence"):
-                with tracing.span(
-                    "checkpoint.save", rank=self._world.rank, step=step
-                ):
+                with save_span(rank=self._world.rank, step=step):
                     if self._sharded_ckpt:
                         # Collective: every rank writes its own shards.
                         self._trainer.save_checkpoint(self._ckpt, step)

@@ -31,6 +31,9 @@ def main(argv=None):
     import os
     import signal
 
+    from elasticdl_tpu.obs import tracing
+
+    tracing.note_main_start()  # the end of the `proc.start` span
     try:
         signal.signal(signal.SIGTERM, _sigterm_to_systemexit)
     except ValueError:
@@ -50,8 +53,6 @@ def main(argv=None):
     # SystemExit conversion above — flushes open spans + a final
     # registry snapshot, so a preempted worker leaves a complete trace
     # tail instead of a cliff.
-    from elasticdl_tpu.obs import tracing
-
     tracing.set_process(f"worker_{args.worker_id}")
     tracing.install_flight_recorder()
     if getattr(args, "tensorboard_log_dir", ""):
@@ -67,6 +68,7 @@ def main(argv=None):
             args.tensorboard_log_dir,
             filename=f"events_worker_{args.worker_id}.jsonl",
         )
+    tracing.record_proc_start()
     from elasticdl_tpu.common import compile_cache
 
     # Persistent compile cache: a re-formed world's jit compiles are
@@ -182,6 +184,14 @@ def _build_collective_worker(
     # All devices of the joined world, shaped (data, model): the model
     # axis carries sharded embedding tables and — for mesh-aware zoo
     # models — ring-attention context parallelism.
+    import jax
+
+    from elasticdl_tpu.obs import tracing
+
+    # The first `jax.devices()` of the process brings the backend up
+    # (on a TPU host: the runtime's start, seconds).
+    with tracing.span("worker.backend_init") as backend:
+        backend.fields["devices"] = len(jax.devices())
     mesh = build_mesh(
         MeshConfig(model=getattr(args, "mesh_model_axis", 1))
     )

@@ -235,10 +235,14 @@ class Worker:
                 batch_count += 1
                 record_count += n
                 with self._anat_phase("bookkeep"):
-                    if self._profiler is not None:
-                        self._profiler.after_steps(self._trainer.step)
                     if self._trainer.step % self._report_every == 0:
                         self._report_version()
+                # Outside bookkeep: a profile's stop waits for the
+                # device and writes the trace.
+                if self._profiler is not None:
+                    self._profiler.after_steps(
+                        self._trainer.step, wait_for=last_loss
+                    )
         finally:
             # Task boundary (or an exception): drain the read-ahead so
             # no stale in-flight batch survives into the next task.

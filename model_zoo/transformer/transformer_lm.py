@@ -201,18 +201,23 @@ class Block(nn.Module):
             self.num_heads, self.dtype, self.mesh, self.attn_impl,
             self.cp_layout, self.model_axis_mode, name="attn",
         )
-        h = nn.LayerNorm(dtype=self.dtype)(x)
-        x = x + attn(h)
-        h = nn.LayerNorm(dtype=self.dtype)(x)
-        h = nn.Dense(e * self.mlp_ratio, dtype=self.dtype)(h)
-        if _tp_active(self.mesh, self.model_axis_mode):
-            # Column-parallel up-projection / row-parallel down-projection
-            # (the Megatron MLP): hidden shards over the model axis
-            # (batch stays on `data`), the residual add below stays
-            # replicated over `model`.
-            h = _constrain(self.mesh, h, DATA_AXIS, None, MODEL_AXIS)
-        h = nn.gelu(h)
-        return x + nn.Dense(e, dtype=self.dtype)(h)
+        # Device scopes (obs/tracing.py DEVICE_SCOPES): each sublayer
+        # with its LayerNorm and residual, so a block's device time is
+        # `attn` + `mlp`.
+        with jax.named_scope("attn"):
+            h = nn.LayerNorm(dtype=self.dtype)(x)
+            x = x + attn(h)
+        with jax.named_scope("mlp"):
+            h = nn.LayerNorm(dtype=self.dtype)(x)
+            h = nn.Dense(e * self.mlp_ratio, dtype=self.dtype)(h)
+            if _tp_active(self.mesh, self.model_axis_mode):
+                # Column-parallel up-projection / row-parallel
+                # down-projection (the Megatron MLP): hidden shards over
+                # the model axis (batch stays on `data`), the residual
+                # add below stays replicated over `model`.
+                h = _constrain(self.mesh, h, DATA_AXIS, None, MODEL_AXIS)
+            h = nn.gelu(h)
+            return x + nn.Dense(e, dtype=self.dtype)(h)
 
 
 class _Bf16AccF32Head(nn.Module):
@@ -288,11 +293,14 @@ class TransformerLM(nn.Module):
                 model_axis_mode=self.model_axis_mode,
                 name=f"block_{i}",
             )(x)
-        x = nn.LayerNorm(dtype=self.dtype)(x)
-        if self.logits_compute == "bf16":
-            return _Bf16AccF32Head(self.vocab, name="lm_head")(x)
-        # Logits in f32: the loss softmax wants full precision.
-        return nn.Dense(self.vocab, dtype=jnp.float32, name="lm_head")(x)
+        with jax.named_scope("lm_head_loss"):
+            x = nn.LayerNorm(dtype=self.dtype)(x)
+            if self.logits_compute == "bf16":
+                return _Bf16AccF32Head(self.vocab, name="lm_head")(x)
+            # Logits in f32: the loss softmax wants full precision.
+            return nn.Dense(
+                self.vocab, dtype=jnp.float32, name="lm_head"
+            )(x)
 
 
 def custom_model(
@@ -335,9 +343,10 @@ def custom_model(
 
 def loss(labels, predictions):
     """Mean next-token cross-entropy; labels [B, T], logits [B, T, V]."""
-    return optax.softmax_cross_entropy_with_integer_labels(
-        predictions.astype(jnp.float32), labels.astype(jnp.int32)
-    ).mean()
+    with jax.named_scope("lm_head_loss"):
+        return optax.softmax_cross_entropy_with_integer_labels(
+            predictions.astype(jnp.float32), labels.astype(jnp.int32)
+        ).mean()
 
 
 def optimizer(lr: float = 3e-3):

@@ -210,6 +210,16 @@ EVENT_OPTIONAL_FIELDS = {
         # every member request links to via `batch_span_id`.
         "rows", "outcome", "batch_rows", "bucket", "generation",
         "requests", "batch_span_id", "addr",
+        # Training-path spans (obs/tracing.py SPAN_NAMES).  data.*: the
+        # task's counters; checkpoint.*: bytes moved, and on the parent
+        # checkpoint.save the host's dirty / write-back kB at its start
+        # and end and the process's rusage deltas; start-up spans.
+        "records", "payload_bytes", "index_bytes", "opens",
+        "read_columns_s", "bytes", "rank", "step",
+        "dirty_kb_start", "dirty_kb_end", "writeback_kb_start",
+        "writeback_kb_end", "majflt", "oublock", "nivcsw",
+        "entrypoint", "cache_hit", "trainer", "devices", "since_main_s",
+        "flushed",
     ),
     "phase_transition": ("cause",),
     "rescale_cost": (
@@ -231,7 +241,7 @@ EVENT_OPTIONAL_FIELDS = {
         "totals", "fractions", "steps", "examples", "retraces", "bound",
         "dominant_phase", "overlap_s",
     ),
-    "profile_window": ("step_start", "step_end"),
+    "profile_window": ("step_start", "step_end", "at_step", "duration_s"),
     "bench_regress": ("details", "baseline"),
     "sparse_kernel_selected": (
         "requested", "route", "optimizer", "tables", "table_rows",

@@ -1,0 +1,596 @@
+"""The one span vocabulary and its two sinks (ISSUE 24).
+
+- the profiler sink is a no-op, and imports nothing, in a process
+  without jax (the master), and enters a ``TraceAnnotation`` of the
+  span's own name where jax is loaded;
+- ``step.bookkeep`` no longer holds a checkpoint save or the profiler's
+  stop;
+- the save's four children lie inside ``checkpoint.save``, in order,
+  with the bytes of the files they wrote; the parent carries the host's
+  I/O marks;
+- ``data.index_load`` / ``data.read`` / ``data.decode`` carry the
+  task's counters;
+- start-up spans (``proc.start``, ``compile.build``, ``state.init``);
+- the device scopes change op metadata only: outputs are bit-equal with
+  and without them, and the names are on the compiled HLO's ``op_name``s.
+"""
+
+import contextlib
+import os
+import subprocess
+import sys
+import time
+
+import numpy as np
+import pytest
+
+from elasticdl_tpu import obs
+from elasticdl_tpu.obs import tracing
+from elasticdl_tpu.obs.journal import EventJournal
+from elasticdl_tpu.obs.stepstats import StepAnatomy
+
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+ZOO = os.path.join(REPO_ROOT, "model_zoo")
+
+
+def _spans_since(marker, name=None):
+    return [
+        e for e in obs.journal().tail(2000)
+        if e.get("event") == "span" and e["ts"] >= marker
+        and (name is None or e["name"] == name)
+    ]
+
+
+# ---------------------------------------------------------------------------
+# The second sink
+# ---------------------------------------------------------------------------
+
+
+def test_master_opens_spans_without_importing_jax():
+    """PR 21's property, held by a test: the master process never
+    imports jax, and the annotation sink does not change that."""
+    code = (
+        "import sys\n"
+        "import elasticdl_tpu.master.main\n"
+        "from elasticdl_tpu.obs import tracing, stepstats\n"
+        "assert tracing.annotate('x').__class__.__name__ == 'nullcontext'\n"
+        "with tracing.span('master.serve_ready'):\n"
+        "    pass\n"
+        "anatomy = stepstats.StepAnatomy(0)\n"
+        "with anatomy.phase('bookkeep'):\n"
+        "    pass\n"
+        "with anatomy.dispatch(1, 1):\n"
+        "    pass\n"
+        "assert 'jax' not in sys.modules, 'the master imported jax'\n"
+        "print('off-jax')\n"
+    )
+    proc = subprocess.run(
+        [sys.executable, "-c", code], cwd=REPO_ROOT, capture_output=True,
+        text=True, timeout=300,
+    )
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    assert "off-jax" in proc.stdout
+
+
+class _Recorder:
+    """Stands in for jax.profiler.TraceAnnotation."""
+
+    entered = []
+
+    def __init__(self, name, **fields):
+        self.name, self.fields = name, fields
+
+    def __enter__(self):
+        _Recorder.entered.append((self.name, self.fields))
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+@pytest.fixture
+def annotations(monkeypatch):
+    import jax
+
+    _Recorder.entered = []
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _Recorder)
+    return _Recorder.entered
+
+
+def test_span_and_phases_enter_an_annotation_of_the_same_name(annotations):
+    tracer = tracing.Tracer(journal=EventJournal(), proc="t")
+    with tracer.span("checkpoint.save.write", bytes=1):
+        pass
+    anatomy = StepAnatomy(0)
+    for phase in ("data_wait", "stage", "bookkeep"):
+        with anatomy.phase(phase):
+            pass
+    with anatomy.dispatch(8, 64):
+        pass
+    assert [name for name, _ in annotations] == [
+        "checkpoint.save.write", "step.data_wait", "step.stage",
+        "step.bookkeep", "step.dispatch",
+    ]
+    assert annotations[-1][1] == {"steps": 8}
+
+
+def test_every_emitted_name_is_in_the_vocabulary():
+    """SPAN_NAMES is the bounded list: the names this PR's sites emit."""
+    for name in (
+        "step.dispatch", "data.index_load", "data.read", "data.decode",
+        "checkpoint.save.gather", "checkpoint.save.write",
+        "checkpoint.save.crc", "checkpoint.save.commit",
+        "checkpoint.restore.load", "proc.start",
+        "master.tensorboard_init", "master.serve_ready",
+        "worker.backend_init", "state.init", "compile.build",
+    ):
+        assert name in tracing.SPAN_NAMES
+    assert set(tracing.DEVICE_SCOPES) >= {
+        "fwd_bwd", "dense_update", "sparse_apply", "grad_accumulate",
+        "sparse_adam", "attn", "mlp", "lm_head_loss", "optimizer",
+    }
+
+
+# ---------------------------------------------------------------------------
+# step.bookkeep is bookkeeping
+# ---------------------------------------------------------------------------
+
+
+def _collective_worker(saver, profiler=None, anatomy=None):
+    from elasticdl_tpu.parallel import MeshConfig, build_mesh
+    from elasticdl_tpu.parallel.elastic import WorldInfo
+    from elasticdl_tpu.worker.collective_worker import CollectiveWorker
+
+    class Reader:
+        metadata = None
+
+        def shard_names(self):
+            return ["s"]
+
+        def read_records(self, task):
+            for i in range(task.start, task.end):
+                yield np.full((2,), i, np.float32), np.int32(i)
+
+    class Trainer:
+        mesh = build_mesh(MeshConfig())
+        state = object()
+        step = 0
+
+        def local_block(self, mb):
+            return mb
+
+        def ensure_initialized(self, features):
+            return self.state
+
+        def stage_batch(self, features, labels, mask):
+            return features, labels, mask
+
+        def train_step_staged(self, staged):
+            self.step += 1
+            return np.float32(0.5)
+
+        def state_to_host(self):
+            return {"w": np.zeros(4, np.float32)}
+
+    class Spec:
+        columnar_dataset_fn = None
+
+        @staticmethod
+        def dataset_fn(dataset, mode, metadata):
+            return dataset
+
+    class Client:
+        def report_version(self, step):
+            pass
+
+    return CollectiveWorker(
+        master_client=Client(), model_spec=Spec(), data_reader=Reader(),
+        minibatch_size=4,
+        world=WorldInfo(rank=0, world_size=1, rendezvous_id=1,
+                        coordinator_addr=""),
+        trainer=Trainer(), checkpoint_saver=saver, checkpoint_steps=2,
+        profiler=profiler, anatomy=anatomy,
+    )
+
+
+def test_bookkeep_holds_neither_the_save_nor_the_profilers_stop():
+    from elasticdl_tpu.proto import elasticdl_pb2 as pb
+
+    class SleepySaver:
+        saves = 0
+
+        def save(self, state, step):
+            time.sleep(0.2)
+            SleepySaver.saves += 1
+
+    class SleepyProfiler:
+        stops = 0
+
+        def before_steps(self, step, n=1):
+            pass
+
+        def after_steps(self, step, wait_for=None):
+            assert wait_for is not None  # the last program's loss
+            time.sleep(0.1)
+            SleepyProfiler.stops += 1
+
+        def stop(self):
+            pass
+
+    anatomy = StepAnatomy(0)
+    worker = _collective_worker(SleepySaver(), SleepyProfiler(), anatomy)
+    marker = time.time()
+    task = pb.Task(task_id=1, type=pb.TRAINING, shard_name="s",
+                   start=0, end=16)
+    worker._process_train_task(task)
+    assert SleepySaver.saves >= 1 and SleepyProfiler.stops >= 1
+    totals = anatomy.totals()
+    assert totals.get("bookkeep", 0.0) < 0.05, totals
+    saves = _spans_since(marker, "checkpoint.save")
+    assert saves and sum(e["duration_s"] for e in saves) >= 0.2
+    for key in ("majflt", "oublock", "nivcsw"):
+        assert isinstance(saves[0][key], int)
+
+
+def test_profiler_stop_waits_and_journals_its_own_duration(tmp_path):
+    import jax.numpy as jnp
+
+    from elasticdl_tpu.common.profiler import StepProfiler
+
+    marker = time.time() - 1
+    profiler = StepProfiler(str(tmp_path), "1,2", worker_id=3)
+    profiler.before_steps(0)
+    profiler.after_steps(1, wait_for=jnp.ones(4) * 2)
+    close = [
+        e for e in obs.journal().tail(50)
+        if e["event"] == "profile_window" and e["ts"] >= marker
+        and e["action"] == "close"
+    ]
+    assert len(close) == 1 and close[0]["duration_s"] > 0
+
+
+# ---------------------------------------------------------------------------
+# The save's parts
+# ---------------------------------------------------------------------------
+
+
+def _assert_parts_inside(parent, parts):
+    order = ["checkpoint.save.gather", "checkpoint.save.write",
+             "checkpoint.save.crc", "checkpoint.save.commit"]
+    assert [p["name"] for p in parts] == order
+    lo, hi = parent["start_ts"], parent["start_ts"] + parent["duration_s"]
+    cursor = lo
+    for part in parts:
+        assert part["parent_span_id"] == parent["span_id"]
+        assert part["start_ts"] >= cursor - 1e-3
+        cursor = part["start_ts"] + part["duration_s"]
+        assert cursor <= hi + 1e-3
+
+
+def test_full_save_has_four_children_in_order_with_the_files_bytes(tmp_path):
+    import jax.numpy as jnp
+
+    from elasticdl_tpu.checkpoint.saver import CheckpointSaver, save_span
+
+    saver = CheckpointSaver(str(tmp_path))
+    state = {"w": jnp.arange(4096, dtype=jnp.float32)}
+    marker = time.time()
+    with save_span(rank=0, step=7):
+        final = saver.save(state, 7)
+    spans = _spans_since(marker)
+    parent = [e for e in spans if e["name"] == "checkpoint.save"][0]
+    parts = sorted(
+        (e for e in spans if e["name"].startswith("checkpoint.save.")),
+        key=lambda e: e["start_ts"],
+    )
+    _assert_parts_inside(parent, parts)
+    size = os.path.getsize(os.path.join(final, "state.pkl"))
+    by_name = {p["name"]: p for p in parts}
+    assert by_name["checkpoint.save.gather"]["bytes"] == 4096 * 4
+    assert by_name["checkpoint.save.write"]["bytes"] == size
+    assert by_name["checkpoint.save.crc"]["bytes"] == size
+    assert "dirty_kb_start" in parent and "writeback_kb_end" in parent
+    # and the restore's real part has a span of its own
+    marker = time.time()
+    restored, step = saver.load_latest()
+    assert step == 7
+    load = _spans_since(marker, "checkpoint.restore.load")
+    assert len(load) == 1 and load[0]["bytes"] == size
+
+
+def test_sharded_save_has_four_children_in_order_with_the_files_bytes(
+    tmp_path,
+):
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from elasticdl_tpu.checkpoint import ShardedCheckpointSaver
+    from elasticdl_tpu.checkpoint.saver import save_span
+    from elasticdl_tpu.parallel import MeshConfig, build_mesh
+
+    mesh = build_mesh(MeshConfig())
+    table = jax.device_put(
+        jnp.arange(64 * 16, dtype=jnp.float32).reshape(64, 16),
+        NamedSharding(mesh, P(("data", "model"))),
+    )
+    saver = ShardedCheckpointSaver(str(tmp_path))
+    marker = time.time()
+    with save_span(rank=0, step=3):
+        final = saver.save(3, {"step": jnp.int32(3)}, {"table|t": table})
+    spans = _spans_since(marker)
+    parent = [e for e in spans if e["name"] == "checkpoint.save"][0]
+    parts = sorted(
+        (e for e in spans if e["name"].startswith("checkpoint.save.")),
+        key=lambda e: e["start_ts"],
+    )
+    _assert_parts_inside(parent, parts)
+    by_name = {p["name"]: p for p in parts}
+    written = sum(
+        os.path.getsize(os.path.join(final, name))
+        for name in ("shards_p0of1.npz", "dense.pkl")
+    )
+    assert by_name["checkpoint.save.gather"]["bytes"] >= 64 * 16 * 4
+    assert by_name["checkpoint.save.write"]["bytes"] == written
+    assert by_name["checkpoint.save.crc"]["bytes"] == written + os.path.getsize(
+        os.path.join(final, "manifest.json")
+    )
+
+
+# ---------------------------------------------------------------------------
+# The host data plane
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture
+def etrf_file(tmp_path):
+    from elasticdl_tpu.data import recordfile
+
+    path = str(tmp_path / "d.etrf")
+    recordfile.write_records(path, (bytes([i % 251]) * 24 for i in range(9000)))
+    return path
+
+
+def test_index_load_carries_the_index_bytes_once_per_open(etrf_file):
+    from elasticdl_tpu import native
+
+    codec = native.record_file()
+    if codec is None:
+        pytest.skip("no native record codec here")
+    marker = time.time()
+    # 9000 records in chunks of 4096: three ranges of ONE open handle.
+    chunks = list(codec.read_range_buffers(etrf_file, 0, 9000))
+    assert len(chunks) == 3
+    loads = _spans_since(marker, "data.index_load")
+    reads = _spans_since(marker, "data.read")
+    assert len(loads) == 1 and len(reads) == 1
+    assert loads[0]["index_bytes"] == 8 * 9000 and loads[0]["opens"] == 1
+    assert reads[0]["records"] == 9000
+    assert reads[0]["payload_bytes"] == 9000 * 24
+    assert reads[0]["index_bytes"] == 8 * 9000 and reads[0]["opens"] == 1
+    # A second range of the open handle loads no index: 0 bytes more.
+    task = {"opens": 1, "index_loaded": True, "index_bytes": 0}
+    handle = codec._lib.edl_rf_open(etrf_file.encode())
+    try:
+        codec._lib.edl_rf_range_size(handle, 0, 10)
+        marker = time.time()
+        assert codec._range_size(handle, 10, 20, task) == 10 * 24
+    finally:
+        codec._lib.edl_rf_close(handle)
+    assert task["index_bytes"] == 0
+    assert not _spans_since(marker, "data.index_load")
+
+
+def test_python_codec_journals_the_same_read_span(etrf_file, monkeypatch):
+    from elasticdl_tpu.data import recordfile
+
+    monkeypatch.setenv("ELASTICDL_DISABLE_NATIVE", "1")
+    marker = time.time()
+    chunks = list(recordfile.read_range_buffers(etrf_file, 100, 300))
+    assert sum(len(lengths) for _, lengths in chunks) == 200
+    reads = _spans_since(marker, "data.read")
+    assert len(reads) == 1
+    assert reads[0]["records"] == 200 and reads[0]["index_bytes"] == 0
+    assert reads[0]["payload_bytes"] == 200 * 24
+
+
+def test_decode_span_counts_the_tasks_records():
+    from elasticdl_tpu.data.columnar import materialize_columnar_task
+
+    class Reader:
+        def read_columns(self, task):
+            yield {"x": np.zeros((5, 2), np.float32)}
+            yield {"x": np.ones((3, 2), np.float32)}
+
+    def columnar_fn(columns, mode, metadata):
+        return columns, None
+
+    marker = time.time()
+    task = materialize_columnar_task(Reader(), object(), columnar_fn,
+                                     "training", None)
+    assert task.n == 8
+    decode = _spans_since(marker, "data.decode")
+    assert len(decode) == 1 and decode[0]["records"] == 8
+    assert decode[0]["read_columns_s"] >= 0
+
+
+# ---------------------------------------------------------------------------
+# Start-up
+# ---------------------------------------------------------------------------
+
+
+def test_proc_start_spans_creation_to_main(monkeypatch):
+    monkeypatch.setattr(tracing, "_main_start_ts", None)
+    monkeypatch.setattr(tracing, "_proc_start_recorded", False)
+    assert tracing.record_proc_start() is None  # main never noted
+    tracing.note_main_start()
+    record = tracing.record_proc_start()
+    assert record["name"] == "proc.start"
+    # This test process was created well before "main" was noted here.
+    assert 0 < record["duration_s"] < 24 * 3600
+    assert abs(record["start_ts"] + record["duration_s"] - time.time()) < 5
+    assert tracing.record_proc_start() is None  # once a process
+
+
+def test_first_call_of_a_compiled_entrypoint_is_a_build_span():
+    import jax.numpy as jnp
+
+    from elasticdl_tpu.parallel import MeshConfig, build_mesh
+    from elasticdl_tpu.parallel import compile as pc
+
+    plan = pc.CompilePlan(build_mesh(MeshConfig()), trainer="test")
+    double = plan.compile(lambda x: x * 2, name="double", journal=False)
+    marker = time.time()
+    assert float(double(jnp.float32(2))) == 4.0
+    assert float(double(jnp.float32(3))) == 6.0
+    builds = _spans_since(marker, "compile.build")
+    assert len(builds) == 1
+    assert builds[0]["entrypoint"] == "double"
+    assert builds[0]["cache_hit"] in (True, False)
+    assert double._cache_size() == 1  # the jitted function's own
+
+
+# ---------------------------------------------------------------------------
+# Device scopes: metadata only
+# ---------------------------------------------------------------------------
+
+
+def _no_scopes(monkeypatch):
+    import jax
+
+    monkeypatch.setattr(
+        jax, "named_scope", lambda name: contextlib.nullcontext()
+    )
+
+
+def _dense_window(seed=0):
+    """(trainer, staged window) of a tiny transformer on the dp trainer."""
+    sys.path.insert(0, ZOO)
+    from transformer import transformer_lm as zoo
+
+    from elasticdl_tpu.parallel import MeshConfig, build_mesh
+    from elasticdl_tpu.parallel.dp_trainer import DataParallelTrainer
+
+    model = zoo.custom_model(vocab=64, d_model=32, num_heads=2,
+                             num_layers=1, max_len=16)
+    trainer = DataParallelTrainer(
+        model=model, loss_fn=zoo.loss, optimizer=zoo.optimizer(),
+        mesh=build_mesh(MeshConfig()),
+    )
+    rng = np.random.RandomState(seed)
+    tokens = rng.randint(0, 64, size=(8, 16)).astype(np.int32)
+    trainer.ensure_initialized(tokens)
+    batch = (tokens, tokens, np.ones((8,), np.float32))
+    return trainer, trainer.stage_window([batch, batch])
+
+
+def _sparse_window(seed=0):
+    """(trainer, staged window) of a tiny DeepFM on the PS trainer."""
+    sys.path.insert(0, ZOO)
+    from deepfm import deepfm_functional_api as zoo
+
+    from elasticdl_tpu.parallel import MeshConfig, build_mesh
+    from elasticdl_tpu.parallel.ps_trainer import ShardedEmbeddingTrainer
+
+    mesh = build_mesh(MeshConfig())
+    trainer = ShardedEmbeddingTrainer(
+        model=zoo.custom_model(vocab_size=32, embedding_dim=4, hidden=16),
+        loss_fn=zoo.loss, optimizer=zoo.optimizer(), mesh=mesh,
+        embedding_optimizer=zoo.embedding_optimizer(),
+        sparse_apply_every=2,
+    )
+    rng = np.random.RandomState(seed)
+    features = {
+        "dense": rng.rand(8, 13).astype(np.float32),
+        "cat": rng.randint(0, 32, size=(8, 26)).astype(np.int32),
+    }
+    labels = rng.randint(0, 2, size=(8,)).astype(np.int32)
+    trainer.ensure_initialized(features)
+    batch = (features, labels, np.ones((8,), np.float32))
+    return trainer, trainer.stage_window([batch, batch])
+
+
+def _window_outputs(build):
+    import jax
+
+    trainer, window = build()
+    losses = trainer.train_window(window)
+    state = jax.device_get(trainer.state)
+    return np.asarray(losses), [np.asarray(x) for x in jax.tree.leaves(state)]
+
+
+def _op_names(trainer, jitted, window):
+    import re
+
+    text = jitted.lower(trainer.state, *window).compile().as_text()
+    return " ".join(re.findall(r'op_name="([^"]+)"', text))
+
+
+@pytest.mark.parametrize("build,jit_attr,scopes", [
+    (_dense_window, "_train_window_jit",
+     ("fwd_bwd", "attn", "mlp", "lm_head_loss", "optimizer")),
+    (_sparse_window, "_train_window",
+     ("fwd_bwd", "dense_update", "sparse_apply", "grad_accumulate",
+      "sparse_adam")),
+])
+def test_scopes_are_on_the_op_names_and_leave_outputs_bit_equal(
+    build, jit_attr, scopes, monkeypatch,
+):
+    trainer, window = build()
+    names = _op_names(trainer, getattr(trainer, jit_attr), window)
+    for scope in scopes:
+        assert f"/{scope}/" in names or f"({scope})" in names, scope
+    with_scopes = _window_outputs(build)
+    _no_scopes(monkeypatch)
+    trainer, window = build()
+    bare = _op_names(trainer, getattr(trainer, jit_attr), window)
+    for scope in ("fwd_bwd", "sparse_apply", "optimizer", "dense_update"):
+        assert f"/{scope}/" not in bare
+    without = _window_outputs(build)
+    np.testing.assert_array_equal(with_scopes[0], without[0])
+    assert len(with_scopes[1]) == len(without[1])
+    for a, b in zip(with_scopes[1], without[1]):
+        np.testing.assert_array_equal(a, b)
+
+
+def test_every_literal_span_name_in_the_training_path_is_in_the_list():
+    """The bounded list is a gate: a span opened under a new name in the
+    master, worker, checkpoint, data or parallel code fails here until
+    SPAN_NAMES says what it times (the serving plane keeps its own
+    names; obs/trace.py and obs/report.py only build synthetic
+    journals for their selftests)."""
+    import ast
+
+    pkg = os.path.join(REPO_ROOT, "elasticdl_tpu")
+    skip = (os.path.join(pkg, "serving"), os.path.join(pkg, "obs", "trace.py"),
+            os.path.join(pkg, "obs", "report.py"),
+            os.path.join(pkg, "obs", "slo.py"))
+    seen = set()
+    for folder, _, files in os.walk(pkg):
+        for fname in files:
+            path = os.path.join(folder, fname)
+            if not fname.endswith(".py") or path.startswith(skip):
+                continue
+            with open(path) as f:
+                tree = ast.parse(f.read())
+            for node in ast.walk(tree):
+                if not isinstance(node, ast.Call):
+                    continue
+                func = node.func
+                called = getattr(func, "attr", getattr(func, "id", ""))
+                if called not in ("span", "record_span", "annotate"):
+                    continue
+                first = node.args[0] if node.args else next(
+                    (kw.value for kw in node.keywords if kw.arg == "name"),
+                    None,
+                )
+                if isinstance(first, ast.Constant) and isinstance(
+                    first.value, str
+                ):
+                    seen.add((first.value, os.path.relpath(path, pkg)))
+    unknown = sorted(
+        (name, where) for name, where in seen
+        if name not in tracing.SPAN_NAMES
+    )
+    assert not unknown, unknown
+    assert len({name for name, _ in seen}) >= 20
