@@ -9,8 +9,11 @@ its shard-addressable record format.  ETRF is this framework's equivalent:
              where index (at index_offset) is record_count u64 file offsets
 
 The index footer makes `count_records` and `read_range` O(1) seeks instead
-of scans — that is what makes dynamic sharding cheap for the master.  The
-native C++ implementation (elasticdl_tpu/native/recordfile.cc) reads and
+of scans — that is what makes dynamic sharding cheap for the master.  Both
+codecs read only the index entries a range needs (its first record's, and
+for the native codec's size query the end boundary's), never the whole
+index: a task's cost does not grow with the file.  The native C++
+implementation (elasticdl_tpu/native/recordfile.cc) reads and
 writes the same format and is preferred automatically when the toolchain
 built it (`read_range`/`count_records` dispatch below); this module is the
 always-available fallback and the reference implementation for parity

@@ -12,6 +12,7 @@ back to the pure-Python/JAX paths.
 
 from __future__ import annotations
 
+import _ctypes
 import ctypes
 import os
 import subprocess
@@ -100,7 +101,7 @@ def build_native(force: bool = False) -> Optional[str]:
 
 # Must match edl_abi_version() in recordfile.cc; bump both on any C-ABI
 # change so a stale .so can never be called with shifted arguments.
-_ABI_VERSION = 2
+_ABI_VERSION = 3
 
 
 def _bind(lib):
@@ -140,6 +141,8 @@ def _bind(lib):
     lib.edl_rf_open.restype = voidp
     lib.edl_rf_count.argtypes = [voidp]
     lib.edl_rf_count.restype = ll
+    lib.edl_rf_index_bytes_read.argtypes = [voidp]
+    lib.edl_rf_index_bytes_read.restype = ll
     lib.edl_rf_range_size.argtypes = [voidp, ll, ll]
     lib.edl_rf_range_size.restype = ll
     lib.edl_rf_read_range.argtypes = [voidp, ll, ll, u8p, ll, u32p]
@@ -154,6 +157,18 @@ def _bind(lib):
     return lib
 
 
+def _open_library(path):
+    lib = ctypes.CDLL(path)
+    try:
+        return _bind(lib)
+    except AttributeError:
+        # dlopen answers a path it has already loaded with the loaded
+        # library, whatever file is there by now: unload the rejected
+        # one, or the retry after the rebuild binds it again.
+        _ctypes.dlclose(lib._handle)
+        raise
+
+
 def load():
     """Load (building if needed) the native library; None if unavailable."""
     global _lib, _load_failed
@@ -164,7 +179,7 @@ def load():
         _load_failed = True
         return None
     try:
-        _lib = _bind(ctypes.CDLL(path))
+        _lib = _open_library(path)
     except (OSError, AttributeError):
         # Corrupt/arch-mismatched .so, or a stale one predating newer
         # symbols but with a fresher mtime (tar/rsync preserve source
@@ -175,7 +190,7 @@ def load():
             _load_failed = True
             return None
         try:
-            _lib = _bind(ctypes.CDLL(path))
+            _lib = _open_library(path)
         except Exception:
             logger.exception("Rebuilt native library still unusable")
             _load_failed = True
@@ -302,21 +317,21 @@ class NativeRecordFile:
                 offset += int(length)
 
     def _range_size(self, handle, lo: int, hi: int, task: dict) -> int:
-        """`edl_rf_range_size`, which on a handle's FIRST call reads the
-        file's whole index (8 B a record; `load_index` in
-        recordfile.cc): that call is the `data.index_load` span, and
-        the bytes it read are the task's `index_bytes`."""
+        """`edl_rf_range_size`, which reads the two index entries the
+        range needs (its first record's and its end boundary's; 8 B
+        each, none that the handle read last).  A handle's FIRST call
+        is the `data.index_load` span, closed with the index bytes the
+        handle has really read (`edl_rf_index_bytes_read`)."""
         if task["index_loaded"]:
             total = int(self._lib.edl_rf_range_size(handle, lo, hi))
         else:
-            index_bytes = 8 * int(self._lib.edl_rf_count(handle))
             with tracing.span(
-                "data.index_load", index_bytes=index_bytes,
-                opens=task["opens"],
-            ):
+                "data.index_load", opens=task["opens"],
+            ) as span:
                 total = int(self._lib.edl_rf_range_size(handle, lo, hi))
+                span.fields["index_bytes"] = int(
+                    self._lib.edl_rf_index_bytes_read(handle))
             task["index_loaded"] = True
-            task["index_bytes"] += index_bytes
         if total < 0:
             raise IOError(self._error())
         return total
@@ -333,14 +348,16 @@ class NativeRecordFile:
         One call is one task's read: it journals `data.index_load`
         (above) and, when the generator closes, one `data.read` span —
         the `edl_rf_read_range` calls summed — carrying the task's
-        counters (`records`, `payload_bytes`, `index_bytes`, `opens`).
+        counters (`records`, `payload_bytes`, `opens`, and `index_bytes`:
+        what the handle counted of index read from the file, a few
+        8-byte entries, not the index's size).
         Counters ride on spans because a job that ends by SIGKILL
         never writes an exit-time registry snapshot."""
         bytes_cap = max_bytes or self.CHUNK_BYTES
         handle = self._lib.edl_rf_open(path.encode())
         if not handle:
             raise IOError(self._error())
-        task = {"opens": 1, "index_loaded": False, "index_bytes": 0,
+        task = {"opens": 1, "index_loaded": False,
                 "records": 0, "payload_bytes": 0}
         read_start_ts, read_s = None, 0.0
         try:
@@ -355,7 +372,7 @@ class NativeRecordFile:
                 )
                 total = self._range_size(handle, pos, pos + n, task)
                 while n > 1 and total > bytes_cap:
-                    n //= 2  # range_size is O(1) over the index
+                    n //= 2  # range_size is O(1): one more index entry
                     total = self._range_size(handle, pos, pos + n, task)
                 buf = np.empty(total, np.uint8)
                 lengths = np.empty(n, np.uint32)
@@ -381,13 +398,14 @@ class NativeRecordFile:
                 yield buf[:used], lengths[:read]
                 pos += read
         finally:
+            index_bytes = int(self._lib.edl_rf_index_bytes_read(handle))
             self._lib.edl_rf_close(handle)
             if read_start_ts is not None:
                 tracing.record_child_span(
                     "data.read", read_start_ts, read_s,
                     records=task["records"],
                     payload_bytes=task["payload_bytes"],
-                    index_bytes=task["index_bytes"],
+                    index_bytes=index_bytes,
                     opens=task["opens"],
                 )
 

@@ -13,7 +13,11 @@
 // The C API is batch-oriented: one call reads a whole [start, end) range
 // (CRC-checked) into a caller buffer with per-record lengths — a single
 // Python<->C crossing per task instead of per record, which is where the
-// native reader earns its keep on the data plane.  Thread-safety: one
+// native reader earns its keep on the data plane.  Index access is
+// O(range), not O(file): a call reads the 8-byte entries it needs (the
+// range's first record and its end boundary) at index_offset + 8*i, as
+// the Python codec does, and every entry read is checked against the
+// record area before anything seeks to it.  Thread-safety: one
 // reader/writer handle per thread; error text is thread-local.
 
 #include <cstdint>
@@ -134,7 +138,13 @@ struct Reader {
   FILE* file = nullptr;
   uint64_t count = 0;
   uint64_t index_offset = 0;
-  std::vector<uint64_t> index;  // loaded lazily on first range read
+  // The two index entries read last (least recently used goes first):
+  // enough that a range_size -> read_range pair, a halved retry and the
+  // next chunk (whose start is this one's end) read no entry twice.
+  uint64_t memo_pos[2] = {UINT64_MAX, UINT64_MAX};  // no such record
+  uint64_t memo_offset[2] = {0, 0};
+  int memo_next = 0;
+  uint64_t index_bytes_read = 0;
 };
 
 struct Writer {
@@ -142,21 +152,40 @@ struct Writer {
   std::vector<uint64_t> offsets;
 };
 
-bool load_index(Reader* r) {
-  if (!r->index.empty() || r->count == 0) return true;
-  if (fseek(r->file, static_cast<long>(r->index_offset), SEEK_SET) != 0) {
-    set_error("seek to index failed");
-    return false;
+// File offset of record i's head, or of the end of the record area for
+// i == count: entry i of the index, read from index_offset + 8*i.  An
+// entry outside [header, index_offset) is a corrupt index, caught here
+// so that no caller seeks to it.
+bool record_offset(Reader* r, uint64_t i, uint64_t* offset) {
+  if (i == r->count) {
+    *offset = r->index_offset;
+    return true;
   }
-  std::vector<uint8_t> raw(r->count * 8);
-  if (fread(raw.data(), 1, raw.size(), r->file) != raw.size()) {
+  for (int k = 0; k < 2; ++k) {
+    if (r->memo_pos[k] == i) {
+      *offset = r->memo_offset[k];
+      r->memo_next = 1 - k;
+      return true;
+    }
+  }
+  uint8_t raw[8];
+  if (fseek(r->file, static_cast<long>(r->index_offset + 8 * i),
+            SEEK_SET) != 0 ||
+      fread(raw, 1, 8, r->file) != 8) {
     set_error("truncated index");
     return false;
   }
-  r->index.resize(r->count);
-  for (uint64_t i = 0; i < r->count; ++i) {
-    r->index[i] = read_u64(raw.data() + i * 8);
+  r->index_bytes_read += 8;
+  uint64_t value = read_u64(raw);
+  if (value < kHeaderSize || value > r->index_offset - kRecordHead) {
+    set_error("corrupt index (offset outside the record area)");
+    return false;
   }
+  int k = r->memo_next;
+  r->memo_pos[k] = i;
+  r->memo_offset[k] = value;
+  r->memo_next = 1 - k;
+  *offset = value;
   return true;
 }
 
@@ -168,7 +197,7 @@ extern "C" {
 // refuses to bind a library whose version doesn't match, which converts
 // "stale .so with a fresher mtime called with shifted arguments" from
 // heap corruption into a clean rebuild.
-long long edl_abi_version() { return 2; }
+long long edl_abi_version() { return 3; }
 
 const char* edl_rf_last_error() { return g_last_error.c_str(); }
 
@@ -208,10 +237,23 @@ void* edl_rf_open(const char* path) {
     fclose(f);
     return nullptr;
   }
+  uint64_t count = read_u64(footer);
+  uint64_t index_offset = read_u64(footer + 8);
+  // The footer must account for the file: header, records, count
+  // entries of index, footer.  (Entries are read one at a time later;
+  // this is what says they are all there.)
+  uint64_t index_end = static_cast<uint64_t>(size) - kFooterSize;
+  if (index_offset < kHeaderSize || index_offset > index_end ||
+      (index_end - index_offset) % 8 != 0 ||
+      (index_end - index_offset) / 8 != count) {
+    set_error("truncated index (footer disagrees with the file's size)");
+    fclose(f);
+    return nullptr;
+  }
   Reader* r = new Reader();
   r->file = f;
-  r->count = read_u64(footer);
-  r->index_offset = read_u64(footer + 8);
+  r->count = count;
+  r->index_offset = index_offset;
   return r;
 }
 
@@ -219,27 +261,34 @@ long long edl_rf_count(void* handle) {
   return static_cast<long long>(static_cast<Reader*>(handle)->count);
 }
 
+// Index bytes this handle has read from the file so far (8 an entry
+// that was not in the memo): the data plane's `index_bytes` counter.
+long long edl_rf_index_bytes_read(void* handle) {
+  return static_cast<long long>(
+      static_cast<Reader*>(handle)->index_bytes_read);
+}
+
 // Total payload bytes of records [start, end) (clamped); -1 on error.
 // O(1): records are contiguous, so the byte span between the start
 // record's offset and the end boundary (next record's offset, or the
 // index itself for the last record) minus the fixed per-record heads IS
-// the payload total — no I/O beyond the already-loaded index.
+// the payload total — two index entries read, at most.
 long long edl_rf_range_size(void* handle, long long start, long long end) {
   Reader* r = static_cast<Reader*>(handle);
   if (start < 0) start = 0;
   if (end > static_cast<long long>(r->count)) end = r->count;
   if (start >= end) return 0;
-  if (!load_index(r)) return -1;
-  uint64_t boundary = (end < static_cast<long long>(r->count))
-                          ? r->index[end]
-                          : r->index_offset;
-  long long total = static_cast<long long>(boundary - r->index[start]) -
-                    static_cast<long long>(kRecordHead) * (end - start);
-  if (boundary < r->index[start] || total < 0) {
+  uint64_t first;
+  uint64_t boundary;
+  if (!record_offset(r, start, &first) || !record_offset(r, end, &boundary)) {
+    return -1;
+  }
+  uint64_t heads = kRecordHead * static_cast<uint64_t>(end - start);
+  if (boundary < first || boundary - first < heads) {
     set_error("corrupt index (non-monotonic offsets)");
     return -1;
   }
-  return total;
+  return static_cast<long long>(boundary - first - heads);
 }
 
 // Read records [start, end) into buf (payloads back-to-back, at most
@@ -254,8 +303,9 @@ long long edl_rf_read_range(void* handle, long long start, long long end,
   if (start < 0) start = 0;
   if (end > static_cast<long long>(r->count)) end = r->count;
   if (start >= end) return 0;
-  if (!load_index(r)) return -1;
-  if (fseek(r->file, static_cast<long>(r->index[start]), SEEK_SET) != 0) {
+  uint64_t first;
+  if (!record_offset(r, start, &first)) return -1;
+  if (fseek(r->file, static_cast<long>(first), SEEK_SET) != 0) {
     set_error("seek failed");
     return -1;
   }

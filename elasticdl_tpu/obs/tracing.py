@@ -116,8 +116,8 @@ SPAN_NAMES: Dict[str, str] = {
                      "(what step.compile/step.execute clock)",
     "step.bookkeep": "aggregate; annotation: telemetry, version report",
     # host data plane (one set per task of a record-file reader)
-    "data.index_load": "interval: first range_size after open (the "
-                       "file's whole index is read here)",
+    "data.index_load": "interval: first range_size after open (reads "
+                       "the range's two index entries)",
     "data.read": "after: the task's read_range calls, summed",
     "data.decode": "interval: columnar concatenate + model transform",
     # checkpoint

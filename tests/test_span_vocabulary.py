@@ -352,6 +352,8 @@ def etrf_file(tmp_path):
 
 
 def test_index_load_carries_the_index_bytes_once_per_open(etrf_file):
+    """`index_bytes` is what the handle really read of the index: the
+    entries its ranges needed, 8 bytes each, not the index's size."""
     from elasticdl_tpu import native
 
     codec = native.record_file()
@@ -363,21 +365,27 @@ def test_index_load_carries_the_index_bytes_once_per_open(etrf_file):
     assert len(chunks) == 3
     loads = _spans_since(marker, "data.index_load")
     reads = _spans_since(marker, "data.read")
+    # One interval per open, around the first range's size query: it
+    # read entry 0 and entry 4096.
     assert len(loads) == 1 and len(reads) == 1
-    assert loads[0]["index_bytes"] == 8 * 9000 and loads[0]["opens"] == 1
+    assert loads[0]["index_bytes"] == 16 and loads[0]["opens"] == 1
     assert reads[0]["records"] == 9000
     assert reads[0]["payload_bytes"] == 9000 * 24
-    assert reads[0]["index_bytes"] == 8 * 9000 and reads[0]["opens"] == 1
-    # A second range of the open handle loads no index: 0 bytes more.
-    task = {"opens": 1, "index_loaded": True, "index_bytes": 0}
+    # The task: entry 8192 more (4096 was in the memo, and the file's
+    # end is the footer's index_offset), of an index of 72,000 bytes.
+    assert reads[0]["index_bytes"] == 24 and reads[0]["opens"] == 1
+    # A second range of the open handle adds its own few bytes and no
+    # second span.
+    task = {"opens": 1, "index_loaded": True}
     handle = codec._lib.edl_rf_open(etrf_file.encode())
     try:
         codec._lib.edl_rf_range_size(handle, 0, 10)
+        assert codec._lib.edl_rf_index_bytes_read(handle) == 16
         marker = time.time()
         assert codec._range_size(handle, 10, 20, task) == 10 * 24
+        assert codec._lib.edl_rf_index_bytes_read(handle) == 24
     finally:
         codec._lib.edl_rf_close(handle)
-    assert task["index_bytes"] == 0
     assert not _spans_since(marker, "data.index_load")
 
 
