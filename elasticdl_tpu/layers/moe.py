@@ -1,0 +1,336 @@
+"""A routed expert layer that holds a RANGE of the experts.
+
+Expert parallelism divides a layer's experts over chips.  Each chip then
+routes its tokens over ALL experts (the router keeps its published
+width), computes the part of the result that ITS experts give for the
+tokens routed to them, and adds what every chip computes alike (the
+shared expert).  `SparseMoeBlock` is that layer for one chip: it is told
+which experts it holds (`held = (first, count)`) and adds nothing that
+stands in for the absent chips or their exchange.  With
+`held = (0, num_experts)` it is the whole layer.
+
+    p = softmax(W_r x)  over all experts, float32
+    top-k of p, weights divided by their sum (over all k, held or not)
+    y = sum_k w_k * W_down,k (silu(W_gate,k x) * W_up,k x)   held k only
+      + sigmoid(w_sg . x) * shared(x)
+
+**No pair is dropped, whatever the imbalance.**  A capacity bound would
+make shapes static by dropping; a dense product over a worst-case
+[tokens x k] slab would cost 32 times the useful work when one chip of 32
+holds the experts.  Here the (token, expert) pairs of held experts are
+sorted by expert and cut into blocks of `block_rows` rows, each block one
+expert's; a loop whose trip count is the number of blocks the routing
+NEEDED (a device-side `while`, not a static bound) gathers a block's
+rows, runs the expert's three products and scatter-adds the weighted
+result.  Work is the pairs held, rounded up to a block an expert.  Sort
+was chosen over `lax.ragged_dot` because the latter needs a static row
+count, which without dropping is tokens x k.  A dynamic trip count has
+no reverse-mode rule, so `grouped_expert_mlp` carries its own backward
+pass: the same loop, recomputing a block's activations and accumulating
+the held experts' weight gradients in float32.
+
+Counters (the ``routing`` collection, cumulative, uint32, updated only
+where the collection is mutable, i.e. in training): pairs routed to held
+experts, rows the loop processed, and the load of each held expert.  The
+worker journals their per-task differences as ``moe.routing``.
+"""
+
+from __future__ import annotations
+
+from functools import partial
+from typing import Any, Tuple
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+import numpy as np
+from flax.traverse_util import flatten_dict
+
+ROUTING_COLLECTION = "routing"
+
+
+def _block_plan(local_ids, n_held: int, block: int):
+    """local_ids [P]: a pair's expert counted from the first held one, or
+    `n_held` for a pair of an expert held elsewhere."""
+    order = jnp.argsort(local_ids, stable=True)
+    counts = jnp.bincount(local_ids, length=n_held + 1)[:n_held]
+    blocks = (counts + block - 1) // block
+    return {
+        "order": order.astype(jnp.int32),
+        "counts": counts.astype(jnp.int32),
+        "group_start": (jnp.cumsum(counts) - counts).astype(jnp.int32),
+        "blocks": blocks.astype(jnp.int32),
+        "block_end": jnp.cumsum(blocks).astype(jnp.int32),
+    }
+
+
+def _block_rows(j, plan, block: int):
+    """Block j -> (its expert, its pairs [block], which of them are real)."""
+    expert = jnp.searchsorted(plan["block_end"], j, side="right")
+    first = plan["block_end"][expert] - plan["blocks"][expert]
+    offset = (j - first) * block + jnp.arange(block, dtype=jnp.int32)
+    valid = offset < plan["counts"][expert]
+    at = jnp.where(valid, plan["group_start"][expert] + offset, 0)
+    return expert, plan["order"][at], valid
+
+
+def _expert_forward(xb, w_gate, w_up, w_down, dtype):
+    gate = jnp.dot(xb, w_gate, preferred_element_type=jnp.float32)
+    up = jnp.dot(xb, w_up, preferred_element_type=jnp.float32)
+    hidden = (jax.nn.silu(gate) * up).astype(dtype)
+    return gate, up, hidden, jnp.dot(
+        hidden, w_down, preferred_element_type=jnp.float32
+    )
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def grouped_expert_mlp(x, w_gate, w_up, w_down, pair_weight, plan,
+                       top_k: int, block: int):
+    """x [N, d] in the compute dtype; w_* [held, ...] float32;
+    pair_weight [N * top_k] float32; plan from `_block_plan`.
+    -> (y [N, d] float32, rows processed (float32 scalar))."""
+    out, _ = _grouped_fwd(x, w_gate, w_up, w_down, pair_weight, plan,
+                          top_k, block)
+    return out
+
+
+def _grouped_fwd(x, w_gate, w_up, w_down, pair_weight, plan, top_k, block):
+    dtype = x.dtype
+    with jax.named_scope("moe_experts"):
+        wg, wu, wd = (w.astype(dtype) for w in (w_gate, w_up, w_down))
+
+        def body(j, carry):
+            y, rows = carry
+            expert, pair, valid = _block_rows(j, plan, block)
+            token = pair // top_k
+            _, _, _, yb = _expert_forward(
+                x[token], wg[expert], wu[expert], wd[expert], dtype
+            )
+            weight = jnp.where(valid, pair_weight[pair], 0.0)
+            return (
+                y.at[token].add(weight[:, None] * yb),
+                rows + jnp.sum(valid).astype(jnp.float32),
+            )
+
+        y, rows = jax.lax.fori_loop(
+            0, plan["block_end"][-1], body,
+            (jnp.zeros(x.shape, jnp.float32), jnp.float32(0.0)),
+        )
+    return (y, rows), (x, w_gate, w_up, w_down, pair_weight, plan)
+
+
+def _grouped_bwd(top_k, block, residuals, cotangent):
+    x, w_gate, w_up, w_down, pair_weight, plan = residuals
+    dy, _ = cotangent
+    dtype = x.dtype
+    n_pairs = pair_weight.shape[0]
+    with jax.named_scope("moe_experts"):
+        wg, wu, wd = (w.astype(dtype) for w in (w_gate, w_up, w_down))
+
+        def body(j, carry):
+            dx, dwg, dwu, dwd, dweight = carry
+            expert, pair, valid = _block_rows(j, plan, block)
+            token = pair // top_k
+            xb = x[token]
+            gate, up, hidden, yb = _expert_forward(
+                xb, wg[expert], wu[expert], wd[expert], dtype
+            )
+            dyb = dy[token]
+            weight = jnp.where(valid, pair_weight[pair], 0.0)
+            dweight = dweight.at[jnp.where(valid, pair, n_pairs)].set(
+                jnp.sum(dyb * yb, axis=-1), mode="drop"
+            )
+            # Rows that are not real carry weight 0: everything below
+            # is 0 for them.
+            dyb = (weight[:, None] * dyb).astype(dtype)
+            dhidden = jnp.dot(
+                dyb, wd[expert].T, preferred_element_type=jnp.float32
+            )
+            sig = jax.nn.sigmoid(gate)
+            dup = (dhidden * gate * sig).astype(dtype)
+            dgate = (
+                dhidden * up * sig * (1.0 + gate * (1.0 - sig))
+            ).astype(dtype)
+            dxb = jnp.dot(
+                dgate, wg[expert].T, preferred_element_type=jnp.float32
+            ) + jnp.dot(dup, wu[expert].T, preferred_element_type=jnp.float32)
+            f32 = dict(preferred_element_type=jnp.float32)
+            return (
+                dx.at[token].add(dxb),
+                dwg.at[expert].add(jnp.dot(xb.T, dgate, **f32)),
+                dwu.at[expert].add(jnp.dot(xb.T, dup, **f32)),
+                dwd.at[expert].add(jnp.dot(hidden.T, dyb, **f32)),
+                dweight,
+            )
+
+        dx, dwg, dwu, dwd, dweight = jax.lax.fori_loop(
+            0, plan["block_end"][-1], body,
+            (
+                jnp.zeros(x.shape, jnp.float32),
+                jnp.zeros(w_gate.shape, jnp.float32),
+                jnp.zeros(w_up.shape, jnp.float32),
+                jnp.zeros(w_down.shape, jnp.float32),
+                jnp.zeros((n_pairs,), jnp.float32),
+            ),
+        )
+    no_plan = jax.tree.map(
+        lambda a: np.zeros(a.shape, jax.dtypes.float0), plan
+    )
+    return dx.astype(dtype), dwg, dwu, dwd, dweight, no_plan
+
+
+grouped_expert_mlp.defvjp(_grouped_fwd, _grouped_bwd)
+
+
+class GatedMLP(nn.Module):
+    """down(silu(gate x) * up x), no biases (the shared expert); operands
+    in `dtype`, float32 results."""
+
+    width: int
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        dense = partial(
+            nn.Dense, use_bias=False, dtype=self.dtype,
+            dot_general=partial(
+                jax.lax.dot_general, preferred_element_type=jnp.float32
+            ),
+        )
+        hidden = nn.silu(dense(self.width, name="gate_proj")(x)) * dense(
+            self.width, name="up_proj"
+        )(x)
+        return dense(x.shape[-1], name="down_proj")(hidden)
+
+
+class SparseMoeBlock(nn.Module):
+    num_experts: int                 # the router's width, as published
+    top_k: int
+    expert_width: int
+    shared_width: int
+    held: Tuple[int, int]            # (first held expert, how many)
+    norm_topk_prob: bool = True
+    dtype: Any = jnp.bfloat16
+    block_rows: int = 128
+
+    @nn.compact
+    def __call__(self, x):
+        first, n_held = self.held
+        if not (0 <= first and n_held > 0
+                and first + n_held <= self.num_experts):
+            raise ValueError(
+                f"held={self.held} is no range of {self.num_experts} experts"
+            )
+        shape, d = x.shape, x.shape[-1]
+        x = x.reshape(-1, d)
+        n = x.shape[0]
+        init = nn.initializers.lecun_normal()
+        expert_init = nn.initializers.variance_scaling(
+            1.0, "fan_in", "truncated_normal", in_axis=-2, out_axis=-1,
+            batch_axis=(0,),
+        )
+        width = self.expert_width
+        w_gate = self.param(
+            "experts_gate_proj", expert_init, (n_held, d, width), jnp.float32)
+        w_up = self.param(
+            "experts_up_proj", expert_init, (n_held, d, width), jnp.float32)
+        w_down = self.param(
+            "experts_down_proj", expert_init, (n_held, width, d), jnp.float32)
+        with jax.named_scope("moe_route"):
+            router = self.param("gate", init, (d, self.num_experts),
+                                jnp.float32)
+            logits = jnp.dot(
+                x.astype(jnp.float32), router,
+                precision=jax.lax.Precision.HIGHEST,
+            )
+            weight, expert = jax.lax.top_k(
+                jax.nn.softmax(logits, axis=-1), self.top_k
+            )
+            if self.norm_topk_prob:
+                weight = weight / jnp.sum(weight, axis=-1, keepdims=True)
+            is_held = (expert >= first) & (expert < first + n_held)
+            local = jnp.where(is_held, expert - first, n_held).reshape(-1)
+            plan = _block_plan(local.astype(jnp.int32), n_held,
+                               self.block_rows)
+        y, rows = grouped_expert_mlp(
+            x.astype(self.dtype), w_gate, w_up, w_down, weight.reshape(-1),
+            plan, self.top_k, self.block_rows,
+        )
+        with jax.named_scope("moe_shared"):
+            shared = GatedMLP(self.shared_width, self.dtype,
+                              name="shared_expert")(x)
+            shared_gate = self.param("shared_expert_gate", init, (d, 1),
+                                     jnp.float32)
+            # A block's product like the expert's own: operands in
+            # `dtype`, written out so that no backend's default decides.
+            y = y + jax.nn.sigmoid(jnp.dot(
+                x.astype(self.dtype), shared_gate.astype(self.dtype),
+                preferred_element_type=jnp.float32,
+            )) * shared
+        self._count(is_held, rows, plan["counts"], n_held)
+        return y.reshape(shape)
+
+    def _count(self, is_held, rows, counts, n_held: int) -> None:
+        zero = lambda *s: jnp.zeros(s, jnp.uint32)  # noqa: E731
+        pairs = self.variable(ROUTING_COLLECTION, "pairs", zero)
+        processed = self.variable(ROUTING_COLLECTION, "processed", zero)
+        load = self.variable(ROUTING_COLLECTION, "load", zero, n_held)
+        if self.is_mutable_collection(ROUTING_COLLECTION) and (
+            not self.is_initializing()
+        ):
+            pairs.value = pairs.value + jnp.sum(is_held).astype(jnp.uint32)
+            processed.value = processed.value + rows.astype(jnp.uint32)
+            load.value = load.value + counts.astype(jnp.uint32)
+
+
+class RoutingLedger:
+    """A task's share of the cumulative ``routing`` counters: the worker
+    reads them where it has already fetched the task's loss (no device
+    sync of its own inside a step) and journals the difference to the
+    last reading as a ``moe.routing`` span.  uint32 differences are
+    right across a wrap."""
+
+    def __init__(self):
+        self._seen = None  # None: not seeded yet
+
+    @staticmethod
+    def _read(model_state) -> dict:
+        """{counter: [layers, ...]} of every expert layer's counters."""
+        flat = flatten_dict(dict(model_state.get(ROUTING_COLLECTION, {})))
+        if not flat:
+            return {}
+        flat = jax.device_get(flat)
+        layers = sorted({path[:-1] for path in flat})
+        return {
+            key: np.stack([
+                np.asarray(flat[layer + (key,)], np.uint32)
+                for layer in layers
+            ])
+            for key in ("pairs", "processed", "load")
+        }
+
+    def seed_once(self, model_state) -> None:
+        """Before the first task: counters restored from a checkpoint are
+        not this job's tasks' (no state yet: they will start at zero)."""
+        if self._seen is None:
+            self._seen = self._read(model_state or {})
+
+    def task_delta(self, model_state):
+        """-> the span's fields, or None for a model that counts nothing."""
+        now = self._read(model_state)
+        if not now:
+            return None
+        seen = self._seen or {key: 0 * value for key, value in now.items()}
+        self._seen = now
+        pairs, processed, load = (
+            (now[key] - seen[key]).astype("int64")
+            for key in ("pairs", "processed", "load")
+        )
+        return {
+            "layers": int(load.shape[0]),
+            "held": int(load.shape[1]),
+            "pairs": int(pairs.sum()),
+            "dropped": int(pairs.sum() - processed.sum()),
+            "load_max": int(load.max()),
+            "load_mean": float(load.mean()),
+        }

@@ -135,16 +135,23 @@ SPAN_NAMES: Dict[str, str] = {
     "worker.backend_init": "interval: first jax.devices()",
     "state.init": "interval: model.init / restore + placement",
     "compile.build": "interval: first call of a jitted entrypoint",
+    # expert routing (layers/moe.py): a task's counters ride on a span
+    "moe.routing": "after: worker, one a task of a model with expert "
+                   "layers: pairs routed to held experts, dropped (0), "
+                   "largest and mean load of a held expert",
 }
 
 #: ``jax.named_scope`` names on device ops (op metadata only; they show
 #: as path components of an op's ``op_name`` in HLO and in the trace).
 #: PS trainer: fwd_bwd, dense_update, sparse_apply > (grad_accumulate,
 #: sparse_adam).  Dense trainer: fwd_bwd > (attn, mlp, lm_head_loss),
-#: optimizer.
+#: optimizer; with the hybrid expert model (model_zoo/qwen3_next) fwd_bwd
+#: > (gdn > gdn_scan, attn, moe > (moe_route, moe_experts, moe_shared),
+#: lm_head_loss).
 DEVICE_SCOPES = (
     "fwd_bwd", "dense_update", "sparse_apply", "grad_accumulate",
     "sparse_adam", "attn", "mlp", "lm_head_loss", "optimizer",
+    "gdn", "gdn_scan", "moe", "moe_route", "moe_experts", "moe_shared",
 )
 
 #: Size bound on the flight recorder's final registry snapshot: the

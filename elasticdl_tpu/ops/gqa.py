@@ -1,0 +1,233 @@
+"""Rotary position embedding and grouped-query causal attention.
+
+Rotary is the rotate-half form on the first `rotary_dim` of a head's
+dimensions (a partial rotary factor leaves the rest untouched).
+
+Grouped-query attention gives every key-value head to `Hq / Hkv` query
+heads.  Two engines:
+
+- `ops.flash_attention`, the Pallas kernel, where the backend is a TPU and
+  `supports(T, D)` holds (K and V of a head resident in VMEM: T <= 4096 at
+  a head size of 256).  It takes equal head counts, so K and V are
+  repeated to the query heads for it.
+- `causal_gqa_attention`, flash numerics in XLA ops, for everything else.
+  `parallel.ring_attention.blockwise_attention` scores ALL queries against
+  one key chunk at a time, and the reverse pass JAX derives for its scan
+  keeps every chunk's probabilities ([B, H, T, chunk] each: 8 GiB at
+  T = 8192, 16 heads, 2 sequences); cutting its queries into independent
+  rematerialised blocks leaves XLA free to hold several blocks' slabs at
+  once (11 GB, compiled for a v5e).  So this engine walks the query blocks
+  in a `lax.scan` and, for each, only the key blocks a causal mask lets it
+  see (a device-side loop with a trip count of its own, so the half of
+  the score matrix above the diagonal is never computed), keeps the
+  output and the log-sum-exp, and has its own backward pass that
+  recomputes a block's probabilities from them.  One [B, Hq, block, block]
+  slab is alive at a time.  Query heads of one key-value head are batched
+  into one product, so K and V are never repeated.
+"""
+
+from __future__ import annotations
+
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+
+from elasticdl_tpu.common.log_utils import get_logger
+
+logger = get_logger("ops.gqa")
+
+
+def rotary_tables(positions, rotary_dim: int, theta: float):
+    """-> (cos, sin), each [T, rotary_dim], float32."""
+    inv_freq = 1.0 / (
+        theta ** (jnp.arange(0, rotary_dim, 2, dtype=jnp.float32) / rotary_dim)
+    )
+    angles = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]
+    angles = jnp.concatenate([angles, angles], axis=-1)
+    return jnp.cos(angles), jnp.sin(angles)
+
+
+def apply_rotary(x, cos, sin):
+    """x [B, T, H, D]; rotates the first `cos.shape[-1]` dimensions."""
+    rotary_dim = cos.shape[-1]
+    rot, rest = x[..., :rotary_dim], x[..., rotary_dim:]
+    half = rotary_dim // 2
+    rotated = jnp.concatenate([-rot[..., half:], rot[..., :half]], axis=-1)
+    rot = (
+        rot.astype(jnp.float32) * cos[None, :, None, :]
+        + rotated.astype(jnp.float32) * sin[None, :, None, :]
+    )
+    return jnp.concatenate([rot.astype(x.dtype), rest], axis=-1)
+
+
+def repeat_kv(x, n_rep: int):
+    """[B, T, Hkv, D] -> [B, T, Hkv * n_rep, D]; query head h reads
+    key-value head h // n_rep."""
+    return x if n_rep == 1 else jnp.repeat(x, n_rep, axis=2)
+
+
+NEG_INF = -1e30
+
+
+def _block_size(t: int, block: int) -> int:
+    """The largest power-of-two fraction of `block` that divides t, or t."""
+    while block >= 64:
+        if t % block == 0:
+            return block
+        block //= 2
+    return t
+
+
+def _scores(q_i, k_j, i, j, block, scale):
+    """[B,Bq,N,G,D] x [B,Bk,N,D] -> masked scores [B,N,G,Bq,Bk], float32."""
+    s = jnp.einsum(
+        "bqngd,bknd->bngqk", q_i, k_j, preferred_element_type=jnp.float32
+    ) * scale
+    rows = i * block + jnp.arange(block)
+    cols = j * block + jnp.arange(block)
+    return jnp.where(cols[None, :] > rows[:, None], NEG_INF, s)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(3,))
+def causal_gqa_attention(q, k, v, block: int):
+    """q [B, T, Hq, D]; k, v [B, T, Hkv, D] -> [B, T, Hq, D]; softmax of
+    q k^T / sqrt(D) under a causal mask, float32 accumulation."""
+    return _gqa_fwd(q, k, v, block)[0]
+
+
+def _blocked(x, block):  # [B, T, ...] -> [T / block, B, block, ...]
+    b, t = x.shape[:2]
+    return jnp.moveaxis(
+        x.reshape((b, t // block, block) + x.shape[2:]), 1, 0
+    )
+
+
+def _gqa_fwd(q, k, v, block):
+    b, t, hq, d = q.shape
+    n = k.shape[2]
+    g = hq // n
+    scale = 1.0 / (d ** 0.5)
+    qb = _blocked(q.reshape(b, t, n, g, d), block)
+    kb, vb = _blocked(k, block), _blocked(v, block)
+
+    def q_step(_, xs):
+        q_i, i = xs
+
+        def kv_step(j, carry):
+            m, l, acc = carry
+            s = _scores(q_i, kb[j], i, j, block, scale)
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+            p = jnp.exp(s - m_new[..., None])
+            fix = jnp.exp(m - m_new)
+            acc = acc * fix[..., None] + jnp.einsum(
+                "bngqk,bknd->bngqd", p.astype(v.dtype), vb[j],
+                preferred_element_type=jnp.float32,
+            )
+            return m_new, l * fix + jnp.sum(p, axis=-1), acc
+
+        m, l, acc = jax.lax.fori_loop(0, i + 1, kv_step, (
+            jnp.full((b, n, g, block), NEG_INF, jnp.float32),
+            jnp.zeros((b, n, g, block), jnp.float32),
+            jnp.zeros((b, n, g, block, d), jnp.float32),
+        ))
+        return None, ((acc / l[..., None]).astype(q.dtype), m + jnp.log(l))
+
+    _, (out, lse) = jax.lax.scan(q_step, None, (qb, jnp.arange(t // block)))
+    # out [T/block, B, N, G, block, D] -> [B, T, Hq, D]
+    out = jnp.moveaxis(out, (0, 4), (1, 2)).reshape(b, t, hq, d)
+    return out, (q, k, v, out, lse)
+
+
+def _gqa_bwd(block, residuals, dout):
+    q, k, v, out, lse = residuals
+    b, t, hq, d = q.shape
+    n = k.shape[2]
+    g = hq // n
+    scale = 1.0 / (d ** 0.5)
+    qb = _blocked(q.reshape(b, t, n, g, d), block)
+    dob = _blocked(dout.reshape(b, t, n, g, d), block)
+    delta = _blocked(jnp.sum(
+        dout.astype(jnp.float32) * out.astype(jnp.float32), axis=-1
+    ).reshape(b, t, n, g), block)                    # [T/block,B,block,N,G]
+    kb, vb = _blocked(k, block), _blocked(v, block)
+
+    def q_step(carry, xs):
+        q_i, do_i, delta_i, lse_i, i = xs
+        delta_i = jnp.moveaxis(delta_i, 1, 3)        # [B,N,G,block]
+
+        def kv_step(j, inner):
+            dq_i, dk, dv = inner
+            s = _scores(q_i, kb[j], i, j, block, scale)
+            p = jnp.exp(s - lse_i[..., None])
+            dv_j = jnp.einsum(
+                "bngqk,bqngd->bknd", p.astype(q.dtype), do_i,
+                preferred_element_type=jnp.float32,
+            )
+            dp = jnp.einsum(
+                "bqngd,bknd->bngqk", do_i, vb[j],
+                preferred_element_type=jnp.float32,
+            )
+            ds = (p * (dp - delta_i[..., None]) * scale).astype(q.dtype)
+            dq_i = dq_i + jnp.einsum(
+                "bngqk,bknd->bqngd", ds, kb[j],
+                preferred_element_type=jnp.float32,
+            )
+            dk_j = jnp.einsum(
+                "bngqk,bqngd->bknd", ds, q_i,
+                preferred_element_type=jnp.float32,
+            )
+            return dq_i, dk.at[j].add(dk_j), dv.at[j].add(dv_j)
+
+        dq_i, dk, dv = jax.lax.fori_loop(
+            0, i + 1, kv_step,
+            (jnp.zeros(q_i.shape, jnp.float32),) + carry,
+        )
+        return (dk, dv), dq_i.astype(q.dtype)
+
+    (dk, dv), dq = jax.lax.scan(
+        q_step,
+        (jnp.zeros(kb.shape, jnp.float32), jnp.zeros(vb.shape, jnp.float32)),
+        (qb, dob, delta, lse, jnp.arange(t // block)),
+    )
+
+    def unblocked(x, like):  # [T/block, B, block, ...] -> like's shape
+        return jnp.moveaxis(x, 0, 1).reshape(like.shape).astype(like.dtype)
+
+    return unblocked(dq, q), unblocked(dk, k), unblocked(dv, v)
+
+
+causal_gqa_attention.defvjp(_gqa_fwd, _gqa_bwd)
+
+
+def causal_attention(q, k, v, *, impl: str = "auto", block: int = 512):
+    """Causal softmax attention scaled by 1/sqrt(D), grouped-query heads.
+    q [B, T, Hq, D]; k, v [B, T, Hkv, D] -> [B, T, Hq, D]."""
+    # (`elasticdl_tpu.ops` exports the FUNCTION under the module's name)
+    from elasticdl_tpu.ops.flash_attention import (
+        _use_interpret, flash_attention, supports,
+    )
+
+    if impl not in ("auto", "pallas", "xla"):
+        raise ValueError(f"impl must be auto, pallas or xla, got {impl!r}")
+    _, t, hq, d = q.shape
+    use_pallas = impl == "pallas" or (
+        impl == "auto" and jax.default_backend() == "tpu"
+        and supports(t, d)
+    )
+    if use_pallas:
+        logger.info(
+            "attention engine: pallas flash_attention T=%d D=%d "
+            "(interpret=%s)", t, d, _use_interpret(),
+        )
+        n_rep = hq // k.shape[2]
+        return flash_attention(
+            q, repeat_kv(k, n_rep), repeat_kv(v, n_rep), causal=True
+        )
+    block = _block_size(t, block)
+    logger.info(
+        "attention engine: xla causal_gqa_attention T=%d D=%d "
+        "(blocks of %d)", t, d, block,
+    )
+    with jax.named_scope("attn"):
+        return causal_gqa_attention(q, k, v, block)

@@ -32,6 +32,7 @@ from elasticdl_tpu.common.log_utils import get_logger
 from elasticdl_tpu.common.model_utils import ModelSpec
 from elasticdl_tpu.data.columnar import materialize_columnar_task
 from elasticdl_tpu.data.dataset import Dataset, SequentialRecords, _stack
+from elasticdl_tpu.layers.moe import RoutingLedger
 from elasticdl_tpu.data.pipeline import (
     ParsePool,
     PipelineConfig,
@@ -135,6 +136,8 @@ class CollectiveWorker:
         # back to the already-compiled per-step program instead of
         # compiling a one-off K-step scan per distinct tail size.
         self._effective_window: Optional[int] = None
+        # Per-task reading of a model's expert-routing counters.
+        self._routing = RoutingLedger()
         self._columnar_logged = False
         # Task-type -> reader: evaluation/prediction shards address their
         # own data sources when configured.
@@ -520,7 +523,30 @@ class CollectiveWorker:
                 )
         return max(1, cand)
 
+    def _journal_routing(self, start_ts: float, steps: int) -> None:
+        """`moe.routing`, one span a task: what the model's expert layers
+        counted over the task's steps (layers/moe.py; nothing for a model
+        without them).  Called where the task's loss has been fetched."""
+        model_state = getattr(self._trainer.state, "model_state", None)
+        if not model_state:
+            return
+        fields = self._routing.task_delta(model_state)
+        if fields is not None:
+            tracing.record_child_span(
+                "moe.routing", start_ts, time.time() - start_ts,
+                step=self._trainer.step, steps=steps, **fields,
+            )
+            if fields["dropped"]:
+                raise RuntimeError(
+                    f"the expert layers dropped {fields['dropped']} routed "
+                    "pair(s): they are built to drop none"
+                )
+
     def _process_train_task(self, task) -> dict:
+        task_start_ts = time.time()
+        self._routing.seed_once(
+            getattr(self._trainer.state, "model_state", None)
+        )
         batch_count = 0
         record_count = 0
         last_loss = None
@@ -727,6 +753,8 @@ class CollectiveWorker:
                 float(np.asarray(last_loss)),
                 batch_count,
             )
+        if last_loss is not None:
+            self._journal_routing(task_start_ts, batch_count)
         self._report_version()
         counters = {
             TaskExecCounterKey.BATCH_COUNT: batch_count,

@@ -127,7 +127,9 @@ def test_master_sigkill_midjob_workers_ride_through(tmp_path):
     from elasticdl_tpu.data.reader import build_data_reader
     from elasticdl_tpu.worker.worker import Worker
 
-    n_records, rpt, epochs = 1024, 32, 2
+    # Long enough (256 tasks, ~10 s) that the kill lands mid-job even when a
+    # loaded machine delays this thread or the snapshot by seconds.
+    n_records, rpt, epochs = 4096, 32, 2
     port = _free_port()
     ckpt_dir = tmp_path / "ckpt"
     ckpt_dir.mkdir()

@@ -123,12 +123,27 @@ def test_every_emitted_name_is_in_the_vocabulary():
         "checkpoint.restore.load", "proc.start",
         "master.tensorboard_init", "master.serve_ready",
         "worker.backend_init", "state.init", "compile.build",
+        "moe.routing",
     ):
         assert name in tracing.SPAN_NAMES
     assert set(tracing.DEVICE_SCOPES) >= {
         "fwd_bwd", "dense_update", "sparse_apply", "grad_accumulate",
         "sparse_adam", "attn", "mlp", "lm_head_loss", "optimizer",
+        "gdn", "gdn_scan", "moe", "moe_route", "moe_experts", "moe_shared",
     }
+
+
+def test_every_device_scope_and_the_routing_span_name_their_reader():
+    """`docs/observability.md` and PERF.md's span table name every device
+    scope, and the `moe.routing` span, with the reader of each: nothing on
+    the lists is without one."""
+    with open(os.path.join(REPO_ROOT, "docs", "observability.md")) as f:
+        docs = f.read()
+    with open(os.path.join(REPO_ROOT, "PERF.md")) as f:
+        perf = f.read()
+    for name in tracing.DEVICE_SCOPES + ("moe.routing",):
+        assert f"`{name}`" in docs, name
+        assert f"`{name}`" in perf, name
 
 
 # ---------------------------------------------------------------------------
@@ -534,9 +549,38 @@ def _op_names(trainer, jitted, window):
     return " ".join(re.findall(r'op_name="([^"]+)"', text))
 
 
+def _hybrid_window(seed=0):
+    """(trainer, staged window) of a tiny Qwen3-Next on the dp trainer,
+    each layer rematerialised as the benchmark's configuration runs it."""
+    sys.path.insert(0, REPO_ROOT)
+    from model_zoo.qwen3_next import qwen3_next_lm as zoo
+
+    from elasticdl_tpu.parallel import MeshConfig, build_mesh
+    from elasticdl_tpu.parallel.dp_trainer import DataParallelTrainer
+
+    model = zoo.custom_model(
+        vocab_size=64, hidden_size=32, head_dim=16, num_attention_heads=2,
+        linear_key_head_dim=8, linear_value_head_dim=8,
+        moe_intermediate_size=16, shared_expert_intermediate_size=16,
+        experts_first=2, experts_held=4, remat=True,
+    )
+    trainer = DataParallelTrainer(
+        model=model, loss_fn=zoo.loss, optimizer=zoo.optimizer(),
+        mesh=build_mesh(MeshConfig()),
+    )
+    rng = np.random.RandomState(seed)
+    tokens = rng.randint(0, 64, size=(8, 16)).astype(np.int32)
+    trainer.ensure_initialized(tokens)
+    batch = (tokens, tokens, np.ones((8,), np.float32))
+    return trainer, trainer.stage_window([batch, batch])
+
+
 @pytest.mark.parametrize("build,jit_attr,scopes", [
     (_dense_window, "_train_window_jit",
      ("fwd_bwd", "attn", "mlp", "lm_head_loss", "optimizer")),
+    (_hybrid_window, "_train_window_jit",
+     ("fwd_bwd", "gdn", "gdn_scan", "attn", "moe", "moe_route",
+      "moe_experts", "moe_shared", "lm_head_loss", "optimizer")),
     (_sparse_window, "_train_window",
      ("fwd_bwd", "dense_update", "sparse_apply", "grad_accumulate",
       "sparse_adam")),
