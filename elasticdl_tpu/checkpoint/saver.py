@@ -12,28 +12,50 @@ replicated, so any rank-0 host snapshot is complete; the sharded-embedding
 engine layers orbax sharded save/restore on top of this interface.
 
 Format: one directory per step, written atomically (tmp + rename), holding
-a pickled host pytree plus a CRC32 integrity manifest (`integrity.json`,
-written before the commit rename).  Restore verifies every inventoried
-file against its checksum: a torn write — power loss mid-flush, a dying
-NFS client, an injected `ckpt.write:truncate` fault — is detected, the
-snapshot is QUARANTINED (renamed aside, never deleted: it is forensic
-evidence), and restore falls back to the next-newest good step instead of
-crashing or silently loading garbage.  `keep_max` old checkpoints are
-retained.
+the host pytree in `state.pkl` plus a CRC32 integrity manifest
+(`integrity.json`, written before the commit rename).  `state.pkl` is
+
+    b"EDLRAW01" | u64 skeleton bytes | u64 buffer count | u64 x count
+    buffer lengths | skeleton | the buffers, back to back
+
+(little-endian).  The skeleton is a protocol-5 pickle of the tree in which
+every plain array leaf is a persistent id (buffer index, dtype, shape and
+axis order as stored); the leaves' bytes are the buffers, handed to the
+file from the host arrays' own memory (no `tobytes()`, no pickle
+framing), and restore reads each buffer into an array of its own, which
+the restored leaf then views.  Everything that is not a plain array
+(Python scalars, typed PRNG keys, array subclasses) is pickled into the
+skeleton as ever.  A `state.pkl` that starts with anything but the magic
+is what this module wrote before: one plain `pickle.dump` of the tree,
+which `load_latest` still reads.
+
+A save writes every byte once and reads none back: the size and CRC32
+the manifest records are taken from the bytes on their way to the file
+(`ChecksumWriter`).  Restore verifies every inventoried file against its
+checksum: a torn write — power loss mid-flush, a dying NFS client, an
+injected `ckpt.write:truncate` fault — is detected, the snapshot is
+QUARANTINED (renamed aside, never deleted: it is forensic evidence), and
+restore falls back to the next-newest good step instead of crashing or
+silently loading garbage.  `keep_max` old checkpoints are retained.
 """
 
 from __future__ import annotations
 
 import contextlib
+import io
 import json
 import os
 import pickle
 import resource
 import shutil
+import struct
 import tempfile
 import time
 import zlib
-from typing import Any, Dict, Optional, Tuple
+from concurrent.futures import ThreadPoolExecutor
+from typing import Any, Dict, Iterable, List, Optional, Tuple
+
+import numpy as np
 
 from elasticdl_tpu import obs
 from elasticdl_tpu.common import faults
@@ -149,23 +171,143 @@ def file_crc32(path: str, chunk_bytes: int = 1 << 20) -> int:
             crc = zlib.crc32(chunk, crc)
 
 
-def write_integrity_manifest(step_dir: str, filenames) -> str:
-    """Checksum `filenames` (relative to `step_dir`) into integrity.json.
+def _gf2_times(matrix: List[int], vector: int) -> int:
+    total = 0
+    for row in matrix:
+        if not vector:
+            break
+        if vector & 1:
+            total ^= row
+        vector >>= 1
+    return total
+
+
+#: `_APPEND_ZEROS[k]` is the GF(2) operator on a CRC32 register that
+#: appending 2**k zero bytes applies (each the square of the one before;
+#: grown on demand, a few hundred integers in all).
+_APPEND_ZEROS: List[List[int]] = []
+
+
+def crc32_combine(crc_a: int, crc_b: int, len_b: int) -> int:
+    """crc32(A + B) from crc32(A), crc32(B) and len(B): zlib's
+    `crc32_combine`, which Python's `zlib` does not export.  Applies the
+    operator "append len(B) zero bytes" to crc32(A), one factor for each
+    set bit of len(B)."""
+    if not _APPEND_ZEROS:
+        operator = [0xEDB88320] + [1 << n for n in range(31)]  # one bit
+        for _ in range(3):  # two, four, eight zero bits: one byte
+            operator = [_gf2_times(operator, row) for row in operator]
+        _APPEND_ZEROS.append(operator)
+    k = 0
+    while len_b > 0:
+        if k == len(_APPEND_ZEROS):
+            last = _APPEND_ZEROS[-1]
+            _APPEND_ZEROS.append([_gf2_times(last, row) for row in last])
+        if len_b & 1:
+            crc_a = _gf2_times(_APPEND_ZEROS[k], crc_a)
+        len_b >>= 1
+        k += 1
+    return crc_a ^ crc_b
+
+
+#: Writes are handed to the file in pieces of this size (16 MB pieces
+#: went out a tenth faster than 64 MB to 1 GB ones where it was
+#: measured, `PERF.md` §6 PR 27); a piece of at least `_BESIDE_BYTES`
+#: has its checksum taken on the helper thread while the file takes it
+#: (`zlib.crc32` and `file.write` both release the GIL), smaller ones
+#: are folded in line.
+_PIECE_BYTES = 16 << 20
+_BESIDE_BYTES = 1 << 20
+
+
+class ChecksumWriter:
+    """The write-only file both savers write through: `crc32` and `size`
+    are those of the bytes it was handed, taken as they pass, so the
+    integrity manifest never reads a file this process has just
+    written.  `write` takes any contiguous buffer (an array's own
+    memory, a `PickleBuffer`, bytes) and copies none of it."""
+
+    def __init__(self, path: str):
+        self.crc32 = 0
+        self.size = 0
+        self._file = open(path, "wb")
+        self._helper = ThreadPoolExecutor(
+            max_workers=1, thread_name_prefix="ckpt-crc"
+        )
+        self._member: Optional[Tuple[int, int]] = None
+
+    def __enter__(self) -> "ChecksumWriter":
+        return self
+
+    def __exit__(self, *exc_info) -> None:
+        self.close()
+
+    def _fold(self, piece) -> None:
+        self.crc32 = zlib.crc32(piece, self.crc32)
+
+    def write(self, data) -> int:
+        view = memoryview(data)
+        if view.ndim != 1 or view.itemsize != 1:
+            view = view.cast("B")
+        for lo in range(0, view.nbytes, _PIECE_BYTES):
+            piece = view[lo:lo + _PIECE_BYTES]
+            if piece.nbytes < _BESIDE_BYTES:
+                self._fold(piece)
+                self._file.write(piece)
+                continue
+            folding = self._helper.submit(self._fold, piece)
+            try:
+                self._file.write(piece)
+            finally:
+                folding.result()
+        self.size += view.nbytes
+        return view.nbytes
+
+    def begin_member(self) -> None:
+        """Start a stretch whose own CRC32 the caller needs too (a zip
+        member's data): one pass over its bytes serves both."""
+        self._member = (self.crc32, self.size)
+        self.crc32 = 0
+
+    def end_member(self) -> int:
+        """-> the CRC32 of the bytes written since `begin_member`; the
+        file's running CRC32 goes on as if it had never been reset."""
+        before, start = self._member
+        self._member = None
+        member = self.crc32
+        self.crc32 = crc32_combine(before, member, self.size - start)
+        return member
+
+    def close(self) -> None:
+        self._helper.shutdown()
+        self._file.close()
+
+
+def write_integrity_manifest(
+    step_dir: str,
+    filenames: Iterable[str],
+    known: Optional[Dict[str, Tuple[int, int]]] = None,
+) -> int:
+    """Inventory `filenames` (relative to `step_dir`) into integrity.json.
     Called while the checkpoint is still a tmp dir, BEFORE the atomic
-    commit rename — the manifest is part of what the rename publishes."""
-    manifest = {
-        "files": {
-            name: {
-                "crc32": file_crc32(os.path.join(step_dir, name)),
-                "size": os.path.getsize(os.path.join(step_dir, name)),
-            }
-            for name in filenames
-        }
-    }
-    path = os.path.join(step_dir, _INTEGRITY_FILE)
-    with open(path, "w") as f:
-        json.dump(manifest, f)
-    return path
+    commit rename — the manifest is part of what the rename publishes.
+    `known` is {name: (crc32, size)} as the writers took them from the
+    bytes in flight (`ChecksumWriter`); only a file it does not name is
+    read back.  -> the bytes read back (0 when every file was known)."""
+    known = known or {}
+    files = {}
+    reread = 0
+    for name in filenames:
+        if name in known:
+            crc, size = known[name]
+        else:
+            path = os.path.join(step_dir, name)
+            crc, size = file_crc32(path), os.path.getsize(path)
+            reread += size
+        files[name] = {"crc32": crc, "size": size}
+    with open(os.path.join(step_dir, _INTEGRITY_FILE), "w") as f:
+        json.dump({"files": files}, f)
+    return reread
 
 
 def verify_integrity(step_dir: str, check_crc: bool = True) -> Optional[str]:
@@ -210,6 +352,131 @@ def verify_integrity(step_dir: str, check_crc: bool = True) -> Optional[str]:
                     f"{meta['crc32']:#010x}"
                 )
     return None
+
+
+#: First bytes of a `state.pkl` in the raw layout (module docstring).  A
+#: pickle stream starts with its PROTO opcode, 0x80.
+_RAW_MAGIC = b"EDLRAW01"
+
+
+def _dense_view(array: np.ndarray):
+    """-> (`array`'s elements as a C-contiguous view, the order of
+    `array`'s axes in that view or None for their own order), without a
+    copy, whatever dense layout the array has: C, Fortran, or any other
+    order of its axes (weights that a TPU keeps transposed come back
+    from `jax.device_get` that way).  None for an array with gaps or
+    overlaps (a slice with a step, a broadcast)."""
+    if array.flags.c_contiguous:
+        return array, None
+    axes = tuple(
+        int(axis) for axis in np.argsort(
+            [-stride for stride in array.strides], kind="stable"
+        )
+    )
+    view = array.transpose(axes)
+    return (view, axes) if view.flags.c_contiguous else None
+
+
+class _RawLeafPickler(pickle.Pickler):
+    """Pickles a host tree's skeleton and sets its plain array leaves
+    aside as raw byte views (`buffers`), each named in the skeleton by a
+    persistent id (index, dtype, shape as stored, axes as stored).  That
+    takes in float32 as much as bfloat16, 0-d, read-only (what
+    `jax.device_get` returns) and transposed arrays, none of them
+    copied; an array that is not dense is copied once into one that is
+    (`copied_bytes`)."""
+
+    def __init__(self, file):
+        super().__init__(file, protocol=5)
+        self.buffers: List[np.ndarray] = []
+        self.copied_bytes = 0
+        self._pid_of: Dict[int, tuple] = {}
+
+    def persistent_id(self, obj):
+        if type(obj) is not np.ndarray or obj.dtype.hasobject:
+            return None
+        pid = self._pid_of.get(id(obj))
+        if pid is None:
+            dense = _dense_view(obj)
+            if dense is None:
+                dense = np.ascontiguousarray(obj), None
+                self.copied_bytes += obj.nbytes
+            view, axes = dense
+            pid = (len(self.buffers), obj.dtype, view.shape, axes)
+            # The buffer's base keeps `obj` (and so its id) alive.
+            self.buffers.append(view.reshape(-1).view(np.uint8))
+            self._pid_of[id(obj)] = pid
+        return pid
+
+
+class _RawLeafUnpickler(pickle.Unpickler):
+    def __init__(self, file, buffers: List[np.ndarray]):
+        super().__init__(file)
+        self._buffers = buffers
+
+    def persistent_load(self, pid):
+        index, dtype, shape, axes = pid
+        array = self._buffers[index].view(dtype).reshape(shape)
+        # (a transposed leaf comes back in the layout it was saved from)
+        return array if axes is None else array.transpose(np.argsort(axes))
+
+
+def write_state(writer: ChecksumWriter, state: Any) -> int:
+    """Write a host tree in the raw layout.  -> `copied_bytes`: array
+    bytes that did not go to the file from the leaf's own memory."""
+    skeleton = io.BytesIO()
+    pickler = _RawLeafPickler(skeleton)
+    pickler.dump(state)
+    buffers = pickler.buffers
+    writer.write(_RAW_MAGIC + struct.pack(
+        f"<{2 + len(buffers)}Q", skeleton.tell(), len(buffers),
+        *(buffer.nbytes for buffer in buffers),
+    ))
+    writer.write(skeleton.getbuffer())
+    for buffer in buffers:
+        writer.write(buffer)
+    return pickler.copied_bytes
+
+
+def _read_exact(f, into) -> None:
+    view = memoryview(into)
+    if view.nbytes:
+        view = view.cast("B")
+    while view.nbytes:
+        n = f.readinto(view)
+        if not n:
+            raise EOFError("state file ends inside what its header lists")
+        view = view[n:]
+
+
+def read_state(path: str) -> Any:
+    """Read a `state.pkl` in either layout, told apart by its first
+    bytes: the raw one (each buffer read into an array of its own, which
+    the restored leaves then view: writable, no second copy) or the
+    plain pickle stream this module wrote before."""
+    size = os.path.getsize(path)
+    with open(path, "rb") as f:
+        if f.read(len(_RAW_MAGIC)) != _RAW_MAGIC:
+            f.seek(0)
+            return pickle.load(f)
+        counts = np.empty(2, "<u8")
+        _read_exact(f, counts)
+        skeleton_bytes, n_buffers = (int(x) for x in counts)
+        head = len(_RAW_MAGIC) + 8 * (2 + n_buffers)
+        if head + skeleton_bytes > size:
+            raise ValueError("state file's header lists more than the file")
+        lengths = np.empty(n_buffers, "<u8")
+        _read_exact(f, lengths)
+        if head + skeleton_bytes + int(lengths.sum()) != size:
+            raise ValueError(
+                "state file's header does not add up to its size"
+            )
+        skeleton = bytearray(skeleton_bytes)
+        _read_exact(f, skeleton)
+        buffers = [np.empty(int(n), np.uint8) for n in lengths]
+        for buffer in buffers:
+            _read_exact(f, buffer)
+    return _RawLeafUnpickler(io.BytesIO(skeleton), buffers).load()
 
 
 def _apply_write_fault(state_path: str) -> None:
@@ -305,13 +572,16 @@ class CheckpointSaver:
         )
         state_path = os.path.join(tmp_dir, _STATE_FILE)
         with tracing.span("checkpoint.save.write") as span:
-            with open(state_path, "wb") as f:
-                pickle.dump(state, f)
-            span.fields["bytes"] = os.path.getsize(state_path)
+            with ChecksumWriter(state_path) as writer:
+                span.fields["copied_bytes"] = write_state(writer, state)
+            span.fields["bytes"] = writer.size
         with tracing.span(
-            "checkpoint.save.crc", bytes=os.path.getsize(state_path)
-        ):
-            write_integrity_manifest(tmp_dir, [_STATE_FILE])
+            "checkpoint.save.crc", bytes=writer.size
+        ) as span:
+            span.fields["reread_bytes"] = write_integrity_manifest(
+                tmp_dir, [_STATE_FILE],
+                known={_STATE_FILE: (writer.crc32, writer.size)},
+            )
         with tracing.span("checkpoint.save.commit", bytes=0):
             _apply_write_fault(state_path)
             os.rename(tmp_dir, final_dir)
@@ -353,8 +623,7 @@ class CheckpointSaver:
                     "checkpoint.restore.load", step=step,
                     bytes=os.path.getsize(path),
                 ):
-                    with open(path, "rb") as f:
-                        state = pickle.load(f)
+                    state = read_state(path)
                 _save, restore_hist, _saves, _q = _ckpt_metrics()
                 restore_hist.observe(
                     time.monotonic() - start, kind="full"

@@ -20,8 +20,18 @@ rank-0 rename after a cross-process barrier):
       dense.pkl            - replicated state (dense params, opt state,
                              batch stats, step counter); rank 0 writes it
       shards_p0of2.npz     - process 0's rows: entries named
-                             "<array>|<row_lo>|<row_hi>"
+                             "<array>|<row_lo>|<row_hi>" (a zip of stored
+                             `.npy` members, as `np.savez` writes and
+                             `np.load` reads; `write_npz` hands each
+                             array's bytes to the file from its own
+                             memory)
       shards_p1of2.npz     - process 1's rows
+
+Every file goes out through `saver.ChecksumWriter`, so the CRC32 and size
+`integrity.json` records come from the bytes in flight; a rank other
+than 0 leaves its file's pair in a sidecar (`<file>.crc`) in the shared
+tmp dir before the barrier, which rank 0 folds into the manifest and
+removes before the rename.
 
 Restore is world-size agnostic: a re-formed world of ANY process/device
 count reads the row intervals its new sharding assigns it, reassembled
@@ -33,9 +43,11 @@ process shares, same as elasticity itself.
 
 from __future__ import annotations
 
+import io
 import json
 import os
 import pickle
+import struct
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
@@ -44,6 +56,7 @@ import numpy as np
 from elasticdl_tpu import obs
 from elasticdl_tpu.checkpoint.saver import (
     CheckpointSaver,
+    ChecksumWriter,
     _apply_write_fault,
     _ckpt_metrics,
     tree_nbytes,
@@ -57,6 +70,103 @@ logger = get_logger("checkpoint.sharded")
 
 _MANIFEST = "manifest.json"
 _DENSE = "dense.pkl"
+_SIDECAR_SUFFIX = ".crc"
+
+# The zip container of an `.npz`, written member by member to a file
+# that is never sought: every member is stored (method 0) with bit 3 set
+# (its CRC32 and sizes follow its data in a descriptor) and carries
+# ZIP64 sizes and offsets whatever its size, so one code path serves a
+# 100-byte and a 100 GB shard file.  This is what `zipfile` itself
+# writes to an unseekable stream with `force_zip64`.
+_ZIP_VERSION = 45  # 4.5: ZIP64
+_ZIP_DESCRIPTOR_FLAG = 0x08
+_ZIP_UTF8_FLAG = 0x800
+_ZIP_DOS_DATE = (1 << 5) | 1  # 1980-01-01: the files carry no clock
+_ZIP_LOCAL = struct.Struct("<4s5H3L2H")
+_ZIP_DESCRIPTOR = struct.Struct("<4sL2Q")
+_ZIP_CENTRAL = struct.Struct("<4s6H3L5H2L")
+_ZIP_END64 = struct.Struct("<4sQ2H2L4Q")
+_ZIP_END64_LOCATOR = struct.Struct("<4sLQL")
+_ZIP_END = struct.Struct("<4s4H2LH")
+_ZIP_MAX32 = 0xFFFFFFFF
+
+
+def write_npz(writer: ChecksumWriter, entries: Dict[str, np.ndarray]) -> int:
+    """Write what `np.savez(file, **entries)` would, through `writer`:
+    each member is the array's `.npy` header and then its bytes, handed
+    over from the array's own memory, with the member's CRC32 and the
+    file's taken in the same one pass.  -> `copied_bytes` (arrays that
+    were neither C- nor Fortran-contiguous and had to be copied once)."""
+    copied = 0
+    directory = []
+    for key, array in entries.items():
+        if array.dtype.hasobject:
+            raise ValueError(f"{key}: object arrays are not checkpointed")
+        if not (array.flags.c_contiguous or array.flags.f_contiguous):
+            array = np.ascontiguousarray(array)
+            copied += array.nbytes
+        name = (key + ".npy").encode("utf-8")
+        flags = _ZIP_DESCRIPTOR_FLAG | (
+            0 if name.isascii() else _ZIP_UTF8_FLAG
+        )
+        npy_header = io.BytesIO()
+        np.lib.format.write_array_header_1_0(
+            npy_header, np.lib.format.header_data_from_array_1_0(array)
+        )
+        offset = writer.size
+        writer.write(_ZIP_LOCAL.pack(
+            b"PK\x03\x04", _ZIP_VERSION, flags, 0, 0, _ZIP_DOS_DATE,
+            0, _ZIP_MAX32, _ZIP_MAX32, len(name), 20,
+        ) + name + struct.pack("<2H2Q", 1, 16, 0, 0))
+        writer.begin_member()
+        writer.write(npy_header.getbuffer())
+        # (the header says `fortran_order` for a Fortran-ordered array,
+        # whose bytes are its transpose's)
+        stored = array if array.flags.c_contiguous else array.T
+        writer.write(stored.reshape(-1).view(np.uint8))
+        size = npy_header.tell() + array.nbytes
+        crc = writer.end_member()
+        writer.write(_ZIP_DESCRIPTOR.pack(b"PK\x07\x08", crc, size, size))
+        directory.append((name, flags, crc, size, offset))
+    directory_offset = writer.size
+    for name, flags, crc, size, offset in directory:
+        writer.write(_ZIP_CENTRAL.pack(
+            b"PK\x01\x02", _ZIP_VERSION, _ZIP_VERSION, flags, 0, 0,
+            _ZIP_DOS_DATE, crc, _ZIP_MAX32, _ZIP_MAX32, len(name), 28,
+            0, 0, 0, 0, _ZIP_MAX32,
+        ) + name + struct.pack("<2H3Q", 1, 24, size, size, offset))
+    directory_bytes = writer.size - directory_offset
+    end = _ZIP_END.pack(
+        b"PK\x05\x06", 0, 0, min(len(directory), 0xFFFF),
+        min(len(directory), 0xFFFF), min(directory_bytes, _ZIP_MAX32),
+        min(directory_offset, _ZIP_MAX32), 0,
+    )
+    if directory:
+        # (`np.load` knows an EMPTY archive by its plain end record.)
+        end = _ZIP_END64.pack(
+            b"PK\x06\x06", _ZIP_END64.size - 12, _ZIP_VERSION,
+            _ZIP_VERSION, 0, 0, len(directory), len(directory),
+            directory_bytes, directory_offset,
+        ) + _ZIP_END64_LOCATOR.pack(b"PK\x06\x07", 0, writer.size, 1) + end
+    writer.write(end)
+    return copied
+
+
+def _take_sidecar(tmp_dir: str, name: str) -> Optional[Tuple[int, int]]:
+    """The (crc32, size) a peer rank left for its file `name`, if it
+    left one that fits the file; the sidecar itself is removed (it is
+    not part of the checkpoint)."""
+    path = os.path.join(tmp_dir, name + _SIDECAR_SUFFIX)
+    try:
+        with open(path) as f:
+            noted = json.load(f)
+        os.unlink(path)
+        pair = int(noted["crc32"]), int(noted["size"])
+    except (OSError, ValueError, KeyError, TypeError):
+        return None
+    if pair[1] != os.path.getsize(os.path.join(tmp_dir, name)):
+        return None
+    return pair
 
 
 def _interval(shard, dim0: int) -> Tuple[int, int]:
@@ -158,20 +268,33 @@ class ShardedCheckpointSaver(CheckpointSaver):
         shard_files = [
             f"shards_p{i}of{n_processes}.npz" for i in range(n_processes)
         ]
+        # {file: (crc32, size)} as this rank's writers took them.
+        known: Dict[str, Tuple[int, int]] = {}
         with tracing.span("checkpoint.save.write") as span:
-            written = [os.path.join(tmp_dir, shard_files[process])]
-            np.savez(written[0], **entries)
+            mine = shard_files[process]
+            with ChecksumWriter(os.path.join(tmp_dir, mine)) as writer:
+                copied = write_npz(writer, entries)
+            known[mine] = (writer.crc32, writer.size)
             # Keep the shared tmp dir's mtime fresh while the save is
             # live so a restarting peer's stale-tmp sweep
             # (saver.sweep_stale_tmp) never mistakes an in-flight save
             # for crashed-save garbage.
             os.utime(tmp_dir)
             if process == 0:
-                written.append(os.path.join(tmp_dir, _DENSE))
-                with open(written[1], "wb") as f:
-                    pickle.dump(dense_state, f)
+                # A plain pickle stream (readers outside this package
+                # read it with `pickle.load`): its arrays pass through
+                # pickle's own copy.
+                with ChecksumWriter(os.path.join(tmp_dir, _DENSE)) as writer:
+                    pickle.dump(dense_state, writer)
+                copied += tree_nbytes(dense_state)
+                known[_DENSE] = (writer.crc32, writer.size)
                 os.utime(tmp_dir)
-            span.fields["bytes"] = sum(map(os.path.getsize, written))
+            else:
+                sidecar = os.path.join(tmp_dir, mine + _SIDECAR_SUFFIX)
+                with open(sidecar, "w") as f:
+                    json.dump({"crc32": writer.crc32, "size": writer.size}, f)
+            span.fields["bytes"] = sum(size for _crc, size in known.values())
+            span.fields["copied_bytes"] = copied
 
         if n_processes > 1:
             from jax.experimental import multihost_utils
@@ -179,10 +302,14 @@ class ShardedCheckpointSaver(CheckpointSaver):
             multihost_utils.sync_global_devices(f"edl_sharded_ckpt_{step}")
 
         if process == 0:
+            for name in shard_files[1:]:
+                noted = _take_sidecar(tmp_dir, name)
+                if noted is not None:
+                    known[name] = noted
             # Stale files from a previous world that died mid-save in this
             # same tmp dir (different process count -> different names)
-            # are swept; the manifest inventories exactly this world's
-            # files, and restores read nothing else.
+            # are swept, its sidecars too; the manifest inventories
+            # exactly this world's files, and restores read nothing else.
             for fname in os.listdir(tmp_dir):
                 if fname.startswith("shards_p") and fname not in shard_files:
                     os.unlink(os.path.join(tmp_dir, fname))
@@ -198,22 +325,24 @@ class ShardedCheckpointSaver(CheckpointSaver):
                     for name, array in sharded.items()
                 },
             }
-            with open(os.path.join(tmp_dir, _MANIFEST), "w") as f:
-                json.dump(manifest, f)
+            with ChecksumWriter(os.path.join(tmp_dir, _MANIFEST)) as writer:
+                writer.write(json.dumps(manifest).encode())
+            known[_MANIFEST] = (writer.crc32, writer.size)
             # Integrity inventory: every file a restore may read —
             # INCLUDING manifest.json itself (a torn metadata manifest
             # would otherwise pass verification and crash restore) — is
-            # checksummed post-barrier (all writers are done), before the
-            # commit rename publishes anything.
+            # inventoried post-barrier (all writers are done), before the
+            # commit rename publishes anything.  A peer's file whose
+            # sidecar is missing is read back.
             inventory = shard_files + [_DENSE, _MANIFEST]
-            with tracing.span(
-                "checkpoint.save.crc",
-                bytes=sum(
+            with tracing.span("checkpoint.save.crc") as span:
+                span.fields["reread_bytes"] = write_integrity_manifest(
+                    tmp_dir, inventory, known=known
+                )
+                span.fields["bytes"] = sum(
                     os.path.getsize(os.path.join(tmp_dir, name))
                     for name in inventory
-                ),
-            ):
-                write_integrity_manifest(tmp_dir, inventory)
+                )
             with tracing.span("checkpoint.save.commit", bytes=0):
                 _apply_write_fault(os.path.join(tmp_dir, _DENSE))
                 try:

@@ -123,8 +123,10 @@ SPAN_NAMES: Dict[str, str] = {
     # checkpoint
     "checkpoint.save": "interval: one save, all ranks",
     "checkpoint.save.gather": "interval: device -> host",
-    "checkpoint.save.write": "interval: serialise and write, to close",
-    "checkpoint.save.crc": "interval: the manifest's re-read (CRC32)",
+    "checkpoint.save.write": "interval: every file written once, its "
+                             "CRC32 taken in flight (`copied_bytes`)",
+    "checkpoint.save.crc": "interval: the integrity manifest alone "
+                           "(`reread_bytes`: 0 unless a file is read back)",
     "checkpoint.save.commit": "interval: rename + garbage-collect",
     "checkpoint.restore": "interval: newest step + its CRC check",
     "checkpoint.restore.load": "interval: read, unpickle, place",

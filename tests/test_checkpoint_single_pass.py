@@ -1,0 +1,535 @@
+"""The single-pass checkpoint write (PR 27).
+
+A save hands every byte to the file once, from the host array's own
+memory, and the size and CRC32 that `integrity.json` records are taken
+from those bytes as they pass: no `tobytes()` of array data, no pickle
+framing around it, no second read of a file this process has just
+written.  The guarantees around it (manifest before the rename,
+quarantine and fallback on a torn file, the parent commit's checkpoints
+still restore) are held here too.
+"""
+
+import importlib.util
+import json
+import os
+import pickle
+import struct
+import time
+import zipfile
+import zlib
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P
+
+from elasticdl_tpu import obs
+from elasticdl_tpu.checkpoint import ShardedCheckpointSaver, saver as saver_mod
+from elasticdl_tpu.checkpoint.saver import (
+    CheckpointSaver,
+    ChecksumWriter,
+    crc32_combine,
+    file_crc32,
+    read_state,
+    save_span,
+    write_integrity_manifest,
+    write_state,
+)
+from elasticdl_tpu.checkpoint.sharded import write_npz
+from elasticdl_tpu.common import faults
+from elasticdl_tpu.parallel import MeshConfig, build_mesh
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _spans_since(marker, name):
+    return [
+        e for e in obs.journal().tail(2000)
+        if e.get("event") == "span" and e["ts"] >= marker
+        and e["name"] == name
+    ]
+
+
+def _same_bits(a, b):
+    a, b = np.asarray(a), np.asarray(b)
+    return (
+        a.dtype == b.dtype and a.shape == b.shape
+        and np.ascontiguousarray(a).tobytes()
+        == np.ascontiguousarray(b).tobytes()
+    )
+
+
+# ---------------------------------------------------------------------------
+# The shared writer
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("len_b", [0, 1, 7, 4096, 77777, (1 << 20) + 3])
+def test_crc32_combine_is_the_crc_of_the_concatenation(len_b):
+    rng = np.random.default_rng(len_b)
+    a = rng.integers(0, 256, 1234, dtype=np.uint8).tobytes()
+    b = rng.integers(0, 256, len_b, dtype=np.uint8).tobytes()
+    assert crc32_combine(zlib.crc32(a), zlib.crc32(b), len_b) == zlib.crc32(
+        a + b
+    )
+
+
+@pytest.mark.parametrize("piece,beside", [(16 << 20, 1 << 20), (4096, 1024)])
+def test_checksum_writer_takes_crc_and_size_from_the_bytes_in_flight(
+    tmp_path, monkeypatch, piece, beside
+):
+    """Whatever the piece size and whichever thread folds a piece, the
+    running CRC32 is that of the file; a member's own CRC32 comes out of
+    the same pass."""
+    monkeypatch.setattr(saver_mod, "_PIECE_BYTES", piece)
+    monkeypatch.setattr(saver_mod, "_BESIDE_BYTES", beside)
+    rng = np.random.default_rng(0)
+    head = b"header"
+    body = rng.normal(size=(700, 301)).astype(np.float32)  # read-only too
+    body.flags.writeable = False
+    tail = rng.integers(0, 256, 999, dtype=np.uint8)
+    path = str(tmp_path / "f.bin")
+    with ChecksumWriter(path) as writer:
+        writer.write(head)
+        writer.begin_member()
+        writer.write(body.reshape(-1).view(np.uint8))
+        writer.write(pickle.PickleBuffer(tail))
+        member = writer.end_member()
+        writer.write(b"")
+        writer.write(bytearray(b"end"))
+    data = open(path, "rb").read()
+    assert data == head + body.tobytes() + tail.tobytes() + b"end"
+    assert (writer.size, writer.crc32) == (len(data), zlib.crc32(data))
+    assert member == zlib.crc32(body.tobytes() + tail.tobytes())
+    assert file_crc32(path) == writer.crc32
+
+
+def test_manifest_reads_back_only_what_it_was_not_given(tmp_path):
+    (tmp_path / "a.bin").write_bytes(b"a" * 100)
+    (tmp_path / "b.bin").write_bytes(b"b" * 50)
+    reread = write_integrity_manifest(
+        str(tmp_path), ["a.bin", "b.bin"], known={"a.bin": (123, 100)}
+    )
+    assert reread == 50
+    files = json.loads((tmp_path / "integrity.json").read_text())["files"]
+    assert files["a.bin"] == {"crc32": 123, "size": 100}
+    assert files["b.bin"] == {"crc32": zlib.crc32(b"b" * 50), "size": 50}
+    # `delta.py`'s callers give nothing and have everything read back.
+    assert write_integrity_manifest(str(tmp_path), ["a.bin", "b.bin"]) == 150
+
+
+# ---------------------------------------------------------------------------
+# (a) CheckpointSaver: every leaf kind, bit for bit, writable
+# ---------------------------------------------------------------------------
+
+
+def _leaf(kind):
+    rng = np.random.default_rng(1)
+    return {
+        "float32": lambda: rng.normal(size=(37, 5)).astype(np.float32),
+        "bfloat16": lambda: jnp.asarray(
+            rng.normal(size=(9, 3)), jnp.bfloat16
+        ),
+        "zero_d": lambda: np.asarray(7, np.int32),
+        "non_contiguous": lambda: rng.normal(size=(8, 10))[:, ::3],
+        "fortran": lambda: np.asfortranarray(rng.normal(size=(4, 6))),
+        "permuted_axes": lambda: rng.normal(size=(3, 4, 5)).transpose(1, 2, 0),
+        "int64": lambda: rng.integers(-9, 9, size=11),
+        "empty": lambda: np.zeros((0, 4), np.float32),
+        "device_array": lambda: jnp.arange(12.0).reshape(3, 4),
+        "read_only_host": lambda: jax.device_get(jnp.arange(5, dtype=jnp.uint8)),
+    }[kind]()
+
+
+@pytest.mark.parametrize("kind", [
+    "float32", "bfloat16", "zero_d", "non_contiguous", "fortran",
+    "permuted_axes", "int64", "empty", "device_array", "read_only_host",
+])
+def test_array_leaf_round_trips_bit_for_bit_and_writable(tmp_path, kind):
+    leaf = _leaf(kind)
+    saver = CheckpointSaver(str(tmp_path))
+    saver.save({"layer": {"w": leaf}, "step": 3}, 3)
+    restored, step = saver.load_latest()
+    got = restored["layer"]["w"]
+    assert step == 3 and restored["step"] == 3
+    assert type(got) is np.ndarray and _same_bits(got, leaf)
+    assert got.flags.writeable
+    if kind in ("fortran", "permuted_axes"):
+        assert got.strides == leaf.strides  # the layout it was saved from
+    got[...] = 0  # a restored state may be updated in place
+
+
+def test_whole_tree_round_trips_with_scalars_and_a_typed_key(tmp_path):
+    shared = np.arange(6, dtype=np.float32)
+    state = {
+        "params": {"w": _leaf("float32"), "b": _leaf("bfloat16")},
+        "count": _leaf("zero_d"),
+        "key": jax.random.key(11),
+        "lr": 3.5, "epoch": 2, "name": "adam", "none": None,
+        "tuple": (1, _leaf("int64")),
+        "twice": [shared, shared],
+    }
+    saver = CheckpointSaver(str(tmp_path))
+    saver.save(state, 5)
+    restored, step = saver.load_latest()
+    assert step == 5
+    assert jax.tree.structure(restored) == jax.tree.structure(state)
+    for got, want in zip(jax.tree.leaves(restored), jax.tree.leaves(state)):
+        if isinstance(want, jax.Array) and jax.dtypes.issubdtype(
+            want.dtype, jax.dtypes.prng_key
+        ):
+            assert _same_bits(
+                jax.random.key_data(got), jax.random.key_data(want)
+            )
+        elif hasattr(want, "dtype"):
+            assert _same_bits(got, want)
+        else:
+            assert got == want and type(got) is type(want)
+    # One leaf held twice is written once and still shares its memory.
+    assert np.shares_memory(restored["twice"][0], restored["twice"][1])
+
+
+def test_raw_layout_copies_nothing_but_what_is_not_dense(tmp_path):
+    """A transposed array (how a TPU's weights may come back from
+    `jax.device_get`) goes out from its own memory too."""
+    tree = {
+        "w": _leaf("float32"), "b": np.asarray(_leaf("bfloat16")),
+        "n": _leaf("zero_d"), "py": 1.5, "f": _leaf("fortran"),
+        "p": _leaf("permuted_axes"),
+    }
+    with ChecksumWriter(str(tmp_path / "a")) as writer:
+        assert write_state(writer, tree) == 0
+    strided = _leaf("non_contiguous")
+    with ChecksumWriter(str(tmp_path / "b")) as writer:
+        assert write_state(writer, {"s": strided, **tree}) == strided.nbytes
+    # The arrays' bytes are in the file as they are in memory.
+    data = (tmp_path / "a").read_bytes()
+    assert data.startswith(b"EDLRAW01")
+    assert tree["w"].tobytes() in data and tree["b"].tobytes() in data
+    assert tree["f"].T.tobytes() in data
+
+
+# ---------------------------------------------------------------------------
+# (b) the manifest's numbers are the committed files' own
+# ---------------------------------------------------------------------------
+
+
+def _sharded_save(tmp_path, step=3, rows=64):
+    mesh = build_mesh(MeshConfig())
+    table = jax.device_put(
+        jnp.arange(rows * 16, dtype=jnp.float32).reshape(rows, 16),
+        NamedSharding(mesh, P(("data", "model"))),
+    )
+    saver = ShardedCheckpointSaver(str(tmp_path))
+    dense = {"step": jnp.int32(step), "params": {"k": jnp.ones((4, 3))}}
+    with save_span(rank=0, step=step):
+        final = saver.save(step, dense, {"table|t": table})
+    return saver, final, table
+
+
+@pytest.mark.parametrize(
+    "name", ["state.pkl", "shards_p0of1.npz", "dense.pkl", "manifest.json"]
+)
+def test_manifest_matches_the_committed_file(tmp_path, name):
+    if name == "state.pkl":
+        final = CheckpointSaver(str(tmp_path)).save(
+            {"w": _leaf("float32"), "k": jax.random.key(0)}, 1
+        )
+    else:
+        _saver, final, _table = _sharded_save(tmp_path)
+    files = json.load(open(os.path.join(final, "integrity.json")))["files"]
+    path = os.path.join(final, name)
+    assert files[name] == {
+        "crc32": file_crc32(path), "size": os.path.getsize(path),
+    }
+    assert saver_mod.verify_integrity(final) is None
+
+
+# ---------------------------------------------------------------------------
+# (c) no read-back
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture
+def no_big_rereads(monkeypatch):
+    real = file_crc32
+
+    def guarded(path, *args, **kwargs):
+        assert os.path.getsize(path) <= 1 << 20, f"{path} was read back"
+        return real(path, *args, **kwargs)
+
+    monkeypatch.setattr(saver_mod, "file_crc32", guarded)
+
+
+@pytest.mark.parametrize("kind", ["full", "sharded"])
+def test_save_reads_nothing_back(tmp_path, no_big_rereads, kind):
+    marker = time.time()
+    if kind == "full":
+        state = {"w": np.ones((600, 1000), np.float32), "n": 3}
+        with save_span(rank=0, step=2):
+            final = CheckpointSaver(str(tmp_path)).save(state, 2)
+        big = os.path.join(final, "state.pkl")
+    else:
+        _saver, final, _table = _sharded_save(tmp_path, step=2, rows=32768)
+        big = os.path.join(final, "shards_p0of1.npz")
+    assert os.path.getsize(big) > 1 << 20 and os.path.isdir(final)
+    (crc,) = _spans_since(marker, "checkpoint.save.crc")
+    (write,) = _spans_since(marker, "checkpoint.save.write")
+    assert crc["reread_bytes"] == 0
+    files = json.load(open(os.path.join(final, "integrity.json")))["files"]
+    assert crc["bytes"] == sum(meta["size"] for meta in files.values())
+    # What was copied on the way: nothing of a contiguous tree; of a
+    # sharded save only the dense part, which stays a plain pickle.
+    assert write["copied_bytes"] == (0 if kind == "full" else 4 + 4 * 3 * 4)
+
+
+# ---------------------------------------------------------------------------
+# (d) the parent commit's checkpoints still restore
+# ---------------------------------------------------------------------------
+
+
+def test_plain_pickle_state_file_of_the_parent_still_restores(tmp_path):
+    state = {"w": _leaf("float32"), "step": 4, "k": jax.random.key(2)}
+    step_dir = tmp_path / "step_000000000004"
+    step_dir.mkdir()
+    with open(step_dir / "state.pkl", "wb") as f:
+        pickle.dump(jax.device_get(state), f)  # saver.py:307-310 at PR 26
+    write_integrity_manifest(str(step_dir), ["state.pkl"])
+    saver = CheckpointSaver(str(tmp_path))
+    restored, step = saver.load_latest()
+    assert step == 4 and _same_bits(restored["w"], state["w"])
+    # The two layouts live side by side in one directory; the newest wins.
+    saver.save({"w": state["w"] + 1, "step": 5}, 5)
+    restored, step = saver.load_latest()
+    assert step == 5 and _same_bits(restored["w"], state["w"] + 1)
+    with open(tmp_path / "step_000000000005" / "state.pkl", "rb") as f:
+        assert f.read(8) == b"EDLRAW01"
+    with open(step_dir / "state.pkl", "rb") as f:
+        assert f.read(1) == b"\x80"  # a pickle stream's PROTO opcode
+
+
+# ---------------------------------------------------------------------------
+# (e) a torn file of the new layout
+# ---------------------------------------------------------------------------
+
+
+def test_torn_raw_state_file_is_quarantined_and_restore_falls_back(tmp_path):
+    saver = CheckpointSaver(str(tmp_path), keep_max=5)
+    old = {"w": np.full((300, 300), 1.0, np.float32), "step": 1}
+    saver.save(old, 1)
+    faults.install("ckpt.write:truncate@1")  # tears after the checksum
+    try:
+        saver.save({"w": np.full((300, 300), 2.0, np.float32), "step": 2}, 2)
+    finally:
+        faults.clear()
+    torn = tmp_path / "step_000000000002" / "state.pkl"
+    files = json.load(open(torn.parent / "integrity.json"))["files"]
+    assert os.path.getsize(torn) < files["state.pkl"]["size"]
+    restored, step = saver.load_latest()
+    assert step == 1 and _same_bits(restored["w"], old["w"])
+    assert "step_000000000002.quarantined" in os.listdir(tmp_path)
+    assert saver.steps() == [1]
+
+
+@pytest.mark.parametrize("damage", ["cut_buffers", "cut_header", "lengths"])
+def test_damaged_raw_state_file_without_a_manifest_is_quarantined(
+    tmp_path, damage
+):
+    """A snapshot with no manifest passes verification vacuously; the
+    raw layout's own header then has to add up to the file's size."""
+    saver = CheckpointSaver(str(tmp_path), keep_max=5)
+    saver.save({"w": _leaf("float32"), "step": 1}, 1)
+    final = saver.save({"w": _leaf("float32") * 2, "step": 2}, 2)
+    os.unlink(os.path.join(final, "integrity.json"))
+    path = os.path.join(final, "state.pkl")
+    data = open(path, "rb").read()
+    if damage == "cut_buffers":
+        data = data[:-100]
+    elif damage == "cut_header":
+        data = data[:20]
+    else:
+        data = data[:8] + (1 << 40).to_bytes(8, "little") + data[16:]
+    open(path, "wb").write(data)
+    with pytest.raises((ValueError, EOFError)):
+        read_state(path)
+    restored, step = saver.load_latest()
+    assert step == 1
+    assert any(n.endswith(".quarantined") for n in os.listdir(tmp_path))
+
+
+# ---------------------------------------------------------------------------
+# (f) the shard file is still what numpy reads
+# ---------------------------------------------------------------------------
+
+
+def _entries():
+    rng = np.random.default_rng(2)
+    return {
+        "table|fm_embedding/embedding|0|64": rng.normal(size=(64, 128)).astype(
+            np.float32
+        ),
+        "slot|fm_embedding/embedding|m|0|64": rng.normal(size=(64, 128)).astype(
+            np.float32
+        ),
+        "slot|emb|step|0|3": np.arange(3, dtype=np.int32),
+        "strided|0|4": rng.normal(size=(4, 10))[:, ::2],
+        "fortran|0|5": np.asfortranarray(rng.normal(size=(5, 7))),
+        "empty|0|0": np.zeros((0, 16), np.float32),
+        "tablé|0|2": np.ones((2, 2), np.float16),
+    }
+
+
+def test_written_npz_holds_the_members_np_savez_writes(tmp_path):
+    entries = _entries()
+    mine, theirs = str(tmp_path / "mine.npz"), str(tmp_path / "theirs.npz")
+    with ChecksumWriter(mine) as writer:
+        copied = write_npz(writer, entries)
+    np.savez(theirs, **entries)
+    assert copied == entries["strided|0|4"].nbytes
+    assert (writer.size, writer.crc32) == (
+        os.path.getsize(mine), file_crc32(mine)
+    )
+    with zipfile.ZipFile(mine) as a, zipfile.ZipFile(theirs) as b:
+        assert a.testzip() is None  # every member's own CRC32 holds
+        assert a.namelist() == b.namelist()
+        for name in b.namelist():
+            assert a.read(name) == b.read(name), name
+            assert a.getinfo(name).compress_type == zipfile.ZIP_STORED
+    with np.load(mine) as shards:  # plain numpy, no pickle
+        assert set(shards.files) == set(entries)
+        for key, want in entries.items():
+            assert _same_bits(shards[key], want)
+
+
+def test_an_npz_of_nothing_opens(tmp_path):
+    path = str(tmp_path / "none.npz")
+    with ChecksumWriter(path) as writer:
+        assert write_npz(writer, {}) == 0
+    with np.load(path) as shards:
+        assert shards.files == []
+    assert file_crc32(path) == writer.crc32
+
+
+def test_every_member_carries_zip64_sizes_and_offset_whatever_its_size(
+    tmp_path,
+):
+    """One code path for a 100-byte and a 7 GB shard file: the directory
+    gives every member's sizes and offset in the ZIP64 extra field (the
+    32-bit fields say "look there"), and `zipfile` reads them from it."""
+    path = str(tmp_path / "a.npz")
+    with ChecksumWriter(path) as writer:
+        write_npz(writer, {"a|0|2": np.ones((2, 2), np.float32),
+                           "b|0|1": np.zeros(1, np.int8)})
+    with zipfile.ZipFile(path) as z:
+        first, second = z.infolist()
+    assert first.header_offset == 0 and second.header_offset > 0
+    assert first.file_size == first.compress_size == 128 + 16
+    for info in (first, second):
+        assert struct.unpack("<2H3Q", info.extra) == (
+            1, 24, info.file_size, info.compress_size, info.header_offset,
+        )
+        assert info.flag_bits & 0x08 and info.extract_version == 45
+
+
+def test_sharded_checkpoint_is_read_the_way_the_reference_reads_it(tmp_path):
+    """`perfbench/configs/deepfm_reference.py` opens `shards_p*.npz` with
+    numpy and `dense.pkl` with pickle, by hand."""
+    spec = importlib.util.spec_from_file_location(
+        "deepfm_reference",
+        os.path.join(REPO, "perfbench", "configs", "deepfm_reference.py"),
+    )
+    reference = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(reference)
+    mesh = build_mesh(MeshConfig())
+    dim, padded = 11, 16
+    logical = np.random.default_rng(3).normal(size=(512, padded)).astype(
+        np.float32
+    )
+    packed = logical.reshape(-1, 128)  # 8 rows of 16 lanes to a block
+    table = jax.device_put(
+        jnp.asarray(packed), NamedSharding(mesh, P(("data", "model")))
+    )
+    saver = ShardedCheckpointSaver(str(tmp_path))
+    dense = {"step": jnp.int32(9), "params": {"dnn": {"kernel": jnp.ones((3, 2))}}}
+    final = saver.save(9, dense, {"table|fm_embedding/embedding": table})
+    rows = np.array([0, 7, 8, 100, 511, 100])
+    got = reference._table_rows(final, "fm_embedding/embedding", dim, rows)
+    assert _same_bits(got, logical[rows, :dim])
+    with open(os.path.join(final, "dense.pkl"), "rb") as f:
+        params = pickle.load(f)["params"]
+    assert _same_bits(params["dnn"]["kernel"], np.ones((3, 2), np.float32))
+    # and the program's own restore reads the same rows
+    reader = saver.row_reader(9, "table|fm_embedding/embedding")
+    assert _same_bits(reader.read(0, packed.shape[0]), packed)
+    saver.release(9)
+
+
+# ---------------------------------------------------------------------------
+# (g) two processes: a peer's checksum reaches rank 0's manifest
+# ---------------------------------------------------------------------------
+
+
+def _save_as_rank(monkeypatch, tmp_path, rank, table, step=6):
+    from jax.experimental import multihost_utils
+
+    monkeypatch.setattr(jax, "process_index", lambda: rank)
+    monkeypatch.setattr(jax, "process_count", lambda: 2)
+    monkeypatch.setattr(
+        multihost_utils, "sync_global_devices", lambda name: None
+    )
+    saver = ShardedCheckpointSaver(str(tmp_path))
+    dense = {"step": jnp.int32(step)} if rank == 0 else None
+    with save_span(rank=rank, step=step):
+        return saver.save(step, dense, {"table|t": table})
+
+
+@pytest.mark.parametrize("sidecar", ["present", "missing", "stale"])
+def test_peer_ranks_checksum_reaches_rank_zeros_manifest(
+    tmp_path, monkeypatch, sidecar
+):
+    mesh = build_mesh(MeshConfig())
+    table = jax.device_put(
+        jnp.arange(64 * 16, dtype=jnp.float32).reshape(64, 16),
+        NamedSharding(mesh, P(("data", "model"))),
+    )
+    # Rank 1 writes its file and leaves its checksum beside it ...
+    _save_as_rank(monkeypatch, tmp_path, 1, table)
+    tmp_dir = tmp_path / "step_000000000006.shared.tmp"
+    peer = tmp_dir / "shards_p1of2.npz"
+    note = tmp_dir / "shards_p1of2.npz.crc"
+    assert json.loads(note.read_text()) == {
+        "crc32": file_crc32(str(peer)), "size": os.path.getsize(peer),
+    }
+    if sidecar == "missing":
+        note.unlink()
+    elif sidecar == "stale":  # of a file that has since been rewritten
+        note.write_text(json.dumps({"crc32": 1, "size": 1}))
+    # ... and rank 0, past the barrier, folds it into the manifest.
+    rereads = []
+    real = file_crc32
+    monkeypatch.setattr(
+        saver_mod, "file_crc32",
+        lambda path, *a, **k: rereads.append(os.path.basename(path))
+        or real(path, *a, **k),
+    )
+    marker = time.time()
+    final = _save_as_rank(monkeypatch, tmp_path, 0, table)
+    monkeypatch.setattr(saver_mod, "file_crc32", real)
+    (crc,) = _spans_since(marker, "checkpoint.save.crc")
+    peer = os.path.join(final, "shards_p1of2.npz")
+    if sidecar == "present":
+        assert rereads == [] and crc["reread_bytes"] == 0
+    else:
+        assert rereads == ["shards_p1of2.npz"]
+        assert crc["reread_bytes"] == os.path.getsize(peer)
+    files = json.load(open(os.path.join(final, "integrity.json")))["files"]
+    assert files["shards_p1of2.npz"] == {
+        "crc32": real(peer), "size": os.path.getsize(peer),
+    }
+    # The sidecars are not part of the checkpoint.
+    assert sorted(os.listdir(final)) == [
+        "dense.pkl", "integrity.json", "manifest.json",
+        "shards_p0of2.npz", "shards_p1of2.npz",
+    ]
+    assert saver_mod.verify_integrity(final) is None
