@@ -409,7 +409,24 @@ class _RawLeafPickler(pickle.Pickler):
         return pid
 
 
-class _RawLeafUnpickler(pickle.Unpickler):
+#: A `state.pkl` names its tree's classes by the module they lived in when
+#: it was written.  Those that have moved since, as (module, name) then
+#: -> module now, all relative to this package.
+_PACKAGE = __name__.partition(".")[0]
+_MOVED_CLASSES = {("worker.trainer", "TrainState"): "parallel.trainer"}
+
+
+class _StateUnpickler(pickle.Unpickler):
+    """Unpickles a saved tree of either layout, whichever tree wrote it."""
+
+    def find_class(self, module, name):
+        package, _, rest = module.partition(".")
+        if package == _PACKAGE and (rest, name) in _MOVED_CLASSES:
+            module = f"{_PACKAGE}.{_MOVED_CLASSES[rest, name]}"
+        return super().find_class(module, name)
+
+
+class _RawLeafUnpickler(_StateUnpickler):
     def __init__(self, file, buffers: List[np.ndarray]):
         super().__init__(file)
         self._buffers = buffers
@@ -458,7 +475,7 @@ def read_state(path: str) -> Any:
     with open(path, "rb") as f:
         if f.read(len(_RAW_MAGIC)) != _RAW_MAGIC:
             f.seek(0)
-            return pickle.load(f)
+            return _StateUnpickler(f).load()
         counts = np.empty(2, "<u8")
         _read_exact(f, counts)
         skeleton_bytes, n_buffers = (int(x) for x in counts)

@@ -1,13 +1,12 @@
 """Client API: run/submit jobs.
 
 Parity: elasticdl_client/api.py in the reference.  Local mode runs the
-master and one worker in-process (the reference's local-mode test harness,
-SURVEY.md §4); cluster modes hand off to the pod/process manager.
+master and a world of one worker in this process, on the same worker loop
+as the cluster modes (the reference's local-mode test harness, SURVEY.md
+§4); cluster modes hand off to the pod/process manager.
 """
 
 from __future__ import annotations
-
-import numpy as np
 
 from elasticdl_tpu.common.args import parse_master_args
 from elasticdl_tpu.common.constants import DistributionStrategy, Mode
@@ -15,8 +14,8 @@ from elasticdl_tpu.common.log_utils import get_logger
 from elasticdl_tpu.common.model_utils import load_model_spec
 from elasticdl_tpu.data.reader import build_data_reader
 from elasticdl_tpu.master.main import start_master
+from elasticdl_tpu.worker.main import _build_collective_worker, save_model
 from elasticdl_tpu.worker.master_client import MasterClient
-from elasticdl_tpu.worker.worker import Worker
 
 logger = get_logger("client.api")
 
@@ -82,20 +81,9 @@ def _run_local(args, mode: str):
         else None
     )
 
-    from elasticdl_tpu.common.profiler import StepProfiler
-    from elasticdl_tpu.data.pipeline import PipelineConfig
-
     client = MasterClient(master.addr, worker_id=0)
-    worker = Worker(
-        master_client=client,
-        model_spec=model_spec,
-        data_reader=data_reader,
-        minibatch_size=args.minibatch_size,
-        validation_data_reader=validation_reader,
-        profiler=StepProfiler(
-            args.tensorboard_log_dir, args.profile_steps, worker_id=0
-        ),
-        pipeline=PipelineConfig.from_args(args),
+    worker = _build_collective_worker(
+        args, model_spec, data_reader, client, validation_reader
     )
     try:
         worker.run()
@@ -111,44 +99,3 @@ def _run_local(args, mode: str):
     finally:
         client.close()
         master.stop()
-
-
-def save_model(trainer, output_path: str, args=None):
-    """Export the trained model as a servable artifact directory (the
-    reference's `get_model_to_export` analogue — serving/export.py).
-    A legacy flat-variables `.npz` is still written when the path ends in
-    `.npz` (external consumers of the round-1 format)."""
-    if trainer.state is None:
-        logger.warning("No variables to save (model never initialized)")
-        return
-    if output_path.endswith(".npz"):
-        import jax
-
-        variables = trainer.get_variables_numpy()  # collective (PS tables)
-        if jax.process_index() == 0:
-            np.savez(output_path, **variables)
-            logger.info(
-                "Saved %d variables to %s", len(variables), output_path
-            )
-        return
-    from elasticdl_tpu.serving import export_model
-
-    # Record the RESOLVED model params — job flags that model_utils
-    # injects into model_params (sparse_apply_every, use_bf16) included
-    # — not the raw --model_params string: a flag-dependent model
-    # structure (DeepFM's per-mode table layout follows
-    # sparse_apply_every at >10M rows) must rebuild identically at
-    # serving load, where the job flags no longer exist.
-    model_params = getattr(args, "model_params", "")
-    if args is not None and getattr(args, "model_def", ""):
-        from elasticdl_tpu.common.args import format_dict_params
-        from elasticdl_tpu.common.model_utils import load_model_spec
-
-        model_params = format_dict_params(load_model_spec(args).model_params)
-    export_model(
-        trainer,
-        output_path,
-        model_zoo=getattr(args, "model_zoo", ""),
-        model_def=getattr(args, "model_def", ""),
-        model_params=model_params,
-    )

@@ -4,9 +4,9 @@ BENCH_r04's e2e decomposition shows the strict-mode DeepFM chip number
 (~973k samples/s/chip) collapsing to ~276k end to end with `bound:
 host-core` and `host_parse_frac 0.685`: parse, stage, and H2D all
 serialize with device compute.  This module is the shared machinery that
-breaks the serialization, used by both the training step loops
-(worker/collective_worker.py, worker/worker.py) and the serving
-micro-batcher (serving/batcher.py):
+breaks the serialization, used by both the training step loop
+(worker/collective_worker.py) and the serving micro-batcher
+(serving/batcher.py):
 
   ParsePool        multi-core host parse: `parse_buffer` (and any other
                    pure chunk->columns fn) runs on worker threads off the

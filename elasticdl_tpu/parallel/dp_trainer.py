@@ -27,7 +27,11 @@ from elasticdl_tpu.common.log_utils import get_logger
 from elasticdl_tpu.obs import tracing
 from elasticdl_tpu.parallel import compile as pc
 from elasticdl_tpu.parallel import sharding as shd
-from elasticdl_tpu.worker.trainer import TrainState, _model_apply
+from elasticdl_tpu.parallel.trainer import (
+    TrainState,
+    model_apply,
+    unbox_partitioned,
+)
 
 logger = get_logger("parallel.dp_trainer")
 
@@ -51,7 +55,7 @@ def per_example_loss_fn(loss_fn: Callable) -> Callable:
 
 
 class DataParallelTrainer:
-    """Same public surface as worker.trainer.Trainer, over an N-device mesh.
+    """The dense trainer (parallel/trainer.Trainer) over an N-device mesh.
 
     Batch sharded over `data`; loss is a mask-weighted mean so padded
     rows contribute zero gradient.  Dense state placement is selectable
@@ -69,6 +73,7 @@ class DataParallelTrainer:
     """
 
     FSDP_MIN_LEAF = 1024  # elements; below this, sharding buys nothing
+    apply_every = 1  # no sparse tables: every step applies everything
 
     def __init__(
         self,
@@ -234,12 +239,11 @@ class DataParallelTrainer:
             SPECS_COLLECTION,
             strip_capture_collections,
         )
-        from elasticdl_tpu.worker.trainer import _unbox_partitioned
 
         variables = dict(self._model.init(rng, features))
         specs = variables.get(SPECS_COLLECTION, {})
         variables = strip_capture_collections(variables)
-        variables = _unbox_partitioned(variables)
+        variables = unbox_partitioned(variables)
         params = variables.pop("params")
         state = TrainState(
             jnp.zeros((), jnp.int32),
@@ -327,7 +331,7 @@ class DataParallelTrainer:
 
         def compute_loss(params):
             variables = {"params": params, **state.model_state}
-            outputs, new_model_state = _model_apply(
+            outputs, new_model_state = model_apply(
                 self._model, variables, features, train=True, mutable=mutable_keys
             )
             losses = self._per_example_loss(labels, outputs)
@@ -362,7 +366,7 @@ class DataParallelTrainer:
 
     def _eval_step_impl(self, state: TrainState, features):
         variables = {"params": state.params, **state.model_state}
-        outputs, _ = _model_apply(
+        outputs, _ = model_apply(
             self._model, variables, features, train=False, mutable=False
         )
         return outputs
@@ -425,6 +429,11 @@ class DataParallelTrainer:
         self._state, losses = self._train_window_jit(self._state, *window)
         self._host_step += k
         return losses
+
+    def consume_oov_count(self) -> int:
+        """Out-of-vocabulary ids since the last call: the dense path
+        counts none (parallel/trainer.Trainer)."""
+        return 0
 
     def eval_step_local(self, features):
         """Collective-mode eval: local slice in, FULL global outputs out
@@ -537,7 +546,8 @@ class DataParallelTrainer:
         return jax.tree_util.tree_unflatten(treedef, leaves)
 
     def get_variables_numpy(self) -> dict:
-        """Flat logical view; packed tables unpacked (see worker.trainer).
+        """Flat {path: np.ndarray} view of all variables (for export);
+        packed embedding tables unpacked to their logical [vocab, dim].
         COLLECTIVE under FSDP in multi-process worlds (see state_to_host)."""
         from elasticdl_tpu.parallel import packed as pk
 

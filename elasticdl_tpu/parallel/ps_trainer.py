@@ -47,7 +47,7 @@ from elasticdl_tpu.parallel import sharding as shd
 from elasticdl_tpu.parallel.dp_trainer import per_example_loss_fn
 from elasticdl_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
 from elasticdl_tpu.parallel.sparse_optim import SparseOptimizer, sgd
-from elasticdl_tpu.worker.trainer import _model_apply
+from elasticdl_tpu.parallel.trainer import model_apply, unbox_partitioned
 
 logger = get_logger("parallel.ps_trainer")
 
@@ -75,14 +75,6 @@ class PSTrainState(NamedTuple):
 
 def _path_key(path) -> str:
     return "/".join(str(getattr(p, "key", p)) for p in path)
-
-
-def _unbox(tree):
-    return jax.tree.map(
-        lambda x: x.unbox() if isinstance(x, nn.Partitioned) else x,
-        tree,
-        is_leaf=lambda x: isinstance(x, nn.Partitioned),
-    )
 
 
 class ShardedEmbeddingTrainer:
@@ -166,9 +158,9 @@ class ShardedEmbeddingTrainer:
         if sparse_apply_every == "auto":
             # Resolved at ensure_initialized, the first point the
             # resident table row count is known (AUTO_APPLY_TABLE_ROWS
-            # below).  None means "unresolved"; consumers that peek
-            # before init (collective_worker window sizing) treat it as
-            # strict and re-sync after the trainer initializes.
+            # below).  None means "unresolved": `apply_every` reads 1
+            # until then, and the worker loop reads it again right after
+            # ensure_initialized.
             self._sparse_apply_every = None
         else:
             self._sparse_apply_every = max(1, int(sparse_apply_every))
@@ -222,6 +214,12 @@ class ShardedEmbeddingTrainer:
     @property
     def mesh(self):
         return self._mesh
+
+    @property
+    def apply_every(self) -> int:
+        """Train steps between sparse-table applies; 1 while `auto` is
+        still unresolved (before ensure_initialized)."""
+        return self._sparse_apply_every or 1
 
     def jitted_entrypoints(self) -> dict:
         """Current jitted entrypoints by name for the step-anatomy
@@ -407,13 +405,14 @@ class ShardedEmbeddingTrainer:
             for key, table in tables.items()
         }
         self._perturb_shapes = jax.tree.map(
-            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), _unbox(perturbs)
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
+            unbox_partitioned(perturbs),
         )
         state = PSTrainState(
             step=jnp.zeros((), jnp.int32),
             params=params,
             opt_state=self._tx.init(params),
-            model_state=_unbox(model_state),
+            model_state=unbox_partitioned(model_state),
             tables=tables,
             slots=slots,
         )
@@ -561,7 +560,7 @@ class ShardedEmbeddingTrainer:
                 PERTURBATIONS: perturbs,
                 **state.model_state,
             }
-            outputs, muts = _model_apply(
+            outputs, muts = model_apply(
                 self._model, variables, features, train=True,
                 mutable=mutable_keys,
             )
@@ -766,7 +765,7 @@ class ShardedEmbeddingTrainer:
             PERTURBATIONS: self._zero_perturbations(),
             **state.model_state,
         }
-        outputs, _ = _model_apply(
+        outputs, _ = model_apply(
             self._model, variables, features, train=False,
             mutable=[IDS_COLLECTION],
         )

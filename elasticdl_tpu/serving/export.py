@@ -101,8 +101,8 @@ def export_model(
     model_params: str = "",
     chunk_rows: int = 65536,
 ) -> str:
-    """Write the servable artifact for a trained Trainer /
-    DataParallelTrainer / ShardedEmbeddingTrainer.
+    """Write the servable artifact for a trained DataParallelTrainer /
+    ShardedEmbeddingTrainer.
 
     In a multi-process world EVERY process must call this (PS-mode tables
     are sharded across all processes, so materializing them is a
@@ -216,9 +216,9 @@ class ServingModel:
         self.tables: Dict[str, np.ndarray] = tables or {}
 
     def predict(self, features):
-        from elasticdl_tpu.worker.trainer import _model_apply
+        from elasticdl_tpu.parallel.trainer import model_apply
 
-        outputs, _ = _model_apply(
+        outputs, _ = model_apply(
             self._model, self._variables, features, train=False, mutable=False
         )
         return outputs

@@ -12,7 +12,7 @@ A `ServingReplica` owns the device side of the serving plane:
   through `CompilePlan` (parallel/compile.py), so its placement is
   declared and journaled (`compile_plan` event, trainer="serving") like
   every training entry point.  The step is the model's eval path
-  (`_model_apply(train=False, mutable=False)`) — under
+  (`model_apply(train=False, mutable=False)`) — under
   `--sparse_kernel fused` the Embedding layers route lookups through
   `fused_lookup_fm`'s forward (single-device Pallas or the shard_map
   dispatch when a multi-device dispatch mesh is registered); no backward
@@ -206,9 +206,9 @@ class ServingReplica:
         model = served.model
 
         def _serve_step(variables, features):
-            from elasticdl_tpu.worker.trainer import _model_apply
+            from elasticdl_tpu.parallel.trainer import model_apply
 
-            outputs, _ = _model_apply(
+            outputs, _ = model_apply(
                 model, variables, features, train=False, mutable=False
             )
             return outputs

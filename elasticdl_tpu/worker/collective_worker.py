@@ -1,4 +1,9 @@
-"""AllReduce-mode worker: lockstep task loop over a multi-process world.
+"""The worker: one lockstep task loop for every distribution strategy.
+
+worker/main.py decides, from the strategy, the world this loop runs in
+(joined through the master's rendezvous, or the world of one that Local
+mode is), the trainer and its devices, and how many failed tasks the
+loop rides through; the loop itself never asks which strategy it serves.
 
 Parity: elasticdl/python/worker/allreduce_trainer.py + worker.py in the
 reference — per-step gradient allreduce with elastic re-formation on
@@ -25,6 +30,7 @@ import jax
 import numpy as np
 
 from elasticdl_tpu import obs
+from elasticdl_tpu.checkpoint import ShardedCheckpointSaver
 from elasticdl_tpu.checkpoint.saver import save_span
 from elasticdl_tpu.common import faults
 from elasticdl_tpu.common.constants import Mode, TaskExecCounterKey
@@ -39,13 +45,12 @@ from elasticdl_tpu.data.pipeline import (
     Prefetcher,
     StagingPipeline,
 )
-from elasticdl_tpu.obs import goodput, tracing
+from elasticdl_tpu.obs import goodput, quality, tracing
 from elasticdl_tpu.parallel import elastic
 from elasticdl_tpu.parallel import sharding as shd
-from elasticdl_tpu.parallel.dp_trainer import DataParallelTrainer
 from elasticdl_tpu.parallel.elastic import WorldInfo
+from elasticdl_tpu.parallel.trainer import Trainer
 from elasticdl_tpu.proto import elasticdl_pb2 as pb
-from elasticdl_tpu.worker.worker import concat_named, named_arrays
 
 logger = get_logger("worker.collective_worker")
 
@@ -58,7 +63,7 @@ class CollectiveWorker:
         data_reader,
         minibatch_size: int,
         world: WorldInfo,
-        trainer: DataParallelTrainer,
+        trainer: Trainer,
         checkpoint_saver=None,
         checkpoint_steps: int = 0,
         report_version_every_steps: int = 20,
@@ -70,6 +75,7 @@ class CollectiveWorker:
         telemetry=None,
         anatomy=None,
         pipeline: Optional[PipelineConfig] = None,
+        max_task_failures: int = 0,
     ):
         self._mc = master_client
         self._spec = model_spec
@@ -86,9 +92,7 @@ class CollectiveWorker:
         # bound to the telemetry collector (worker/main wiring), so its
         # windows ride the same heartbeat.  None = anatomy off.
         self._anatomy = anatomy or getattr(telemetry, "anatomy", None)
-        if self._anatomy is not None and hasattr(
-            trainer, "jitted_entrypoints"
-        ):
+        if self._anatomy is not None:
             self._anatomy.watch_jits(trainer.jitted_entrypoints)
         # Each process supplies `block` rows per collective step (>= mb,
         # rounded up to divide its local device count).
@@ -97,6 +101,11 @@ class CollectiveWorker:
         self._ckpt_steps = checkpoint_steps
         self._report_every = report_version_every_steps
         self._wait_sleep_s = wait_sleep_s
+        # Consecutive failed tasks the loop reports and rides through.
+        # 0 where a supervisor re-forms the world: a failed collective
+        # step likely poisons it, so die and be relaunched (reference:
+        # Horovod shutdown/re-init on HorovodInternalError).
+        self._max_task_failures = max_task_failures
         self._last_reported_version = 0
         self._last_ckpt_step = 0
         self._profiler = profiler
@@ -129,7 +138,7 @@ class CollectiveWorker:
         # rows unknown until then) — reads 1 here and re-syncs via
         # _sync_apply_every() right after ensure_initialized, before
         # anything compiles.
-        self._apply_every = int(getattr(trainer, "_sparse_apply_every", 1) or 1)
+        self._apply_every = trainer.apply_every
         self._grow_explicit_window_to_apply_multiple()
         # Pinned from the first task (standard task size) so the job
         # compiles ONE fused-scan executable; smaller (tail) tasks fall
@@ -162,7 +171,7 @@ class CollectiveWorker:
         self._metadata = data_reader.metadata
 
     @property
-    def trainer(self) -> DataParallelTrainer:
+    def trainer(self) -> Trainer:
         return self._trainer
 
     @property
@@ -173,13 +182,11 @@ class CollectiveWorker:
 
     @property
     def _sharded_ckpt(self) -> bool:
-        """Sharded protocol when both sides support it: the trainer keeps
-        mesh-sharded state (PS tables) and the saver speaks per-process
-        shard files (checkpoint/sharded.py) — every rank reads/writes only
-        its own rows instead of rank 0 pickling a full gather."""
-        return hasattr(self._trainer, "save_checkpoint") and hasattr(
-            self._ckpt, "latest_step"
-        )
+        """Sharded protocol when the saver speaks per-process shard files
+        (checkpoint/sharded.py): every rank reads/writes only its own
+        rows of the trainer's mesh-sharded state (PS tables, FSDP
+        leaves) instead of rank 0 pickling a full gather."""
+        return isinstance(self._ckpt, ShardedCheckpointSaver)
 
     def restore_from_checkpoint(self):
         if self._ckpt is None:
@@ -276,6 +283,7 @@ class CollectiveWorker:
     def _run_task_loop(self):
         self.restore_from_checkpoint()
         self._verify_restore_consistency()
+        failed_in_a_row = 0
         while True:
             # Queue wait is data_wait — but only for REAL tasks: a WAIT
             # poll is queue idleness (the ledger's `idle` phase below),
@@ -309,9 +317,7 @@ class CollectiveWorker:
                 goodput.ledger().transition("idle", cause="wait_task")
                 time.sleep(self._wait_sleep_s)
                 continue
-            spec = faults.fire("worker.task")
-            if spec is not None and spec.kind == "crash":
-                faults.crash_now(spec)
+            _crash_site("worker.task")
             try:
                 type_name = pb.TaskType.Name(task.type)
             except ValueError:
@@ -324,9 +330,7 @@ class CollectiveWorker:
             # The span closes the worker half of the trace chain: its
             # journal record carries the dispatch-minted trace id (leader
             # ranks — the fixed-shape broadcast drops strings, so
-            # non-leader ranks span without one).  Same name+labelset as
-            # the Local-mode worker's span: both paths share one
-            # histogram family in-process.
+            # non-leader ranks span without one).
             span_fields = dict(task_id=task.task_id, rank=self._world.rank)
             if task.trace_id:
                 span_fields["trace_id"] = task.trace_id
@@ -347,10 +351,9 @@ class CollectiveWorker:
                         task.task_id, str(exc) or repr(exc),
                         trace_id=task.trace_id,
                     )
-                # A failed collective step likely poisons the world: die and
-                # let the pod manager re-form it (reference: Horovod
-                # shutdown/re-init on HorovodInternalError).
-                raise
+                failed_in_a_row += 1
+                if failed_in_a_row > self._max_task_failures:
+                    raise
             else:
                 # The collective step SUCCEEDED on every rank; a lost
                 # success report is only an RPC-plane fault and must not
@@ -361,6 +364,7 @@ class CollectiveWorker:
                     self._mc.report_task_result_best_effort(
                         task.task_id, "", counters, trace_id=task.trace_id
                     )
+                failed_in_a_row = 0
         self._report_version(force=True)
         self._maybe_checkpoint(force=True)
 
@@ -487,9 +491,7 @@ class CollectiveWorker:
         """Re-read the trainer's (possibly auto-resolved) apply interval;
         True if it changed.  Called once right after ensure_initialized —
         nothing has compiled yet, so window sizing may still move."""
-        resolved = int(
-            getattr(self._trainer, "_sparse_apply_every", 1) or 1
-        )
+        resolved = self._trainer.apply_every
         if resolved == self._apply_every:
             return False
         self._apply_every = resolved
@@ -523,11 +525,15 @@ class CollectiveWorker:
                 )
         return max(1, cand)
 
+    def _model_state(self):
+        state = self._trainer.state
+        return None if state is None else state.model_state
+
     def _journal_routing(self, start_ts: float, steps: int) -> None:
         """`moe.routing`, one span a task: what the model's expert layers
         counted over the task's steps (layers/moe.py; nothing for a model
         without them).  Called where the task's loss has been fetched."""
-        model_state = getattr(self._trainer.state, "model_state", None)
+        model_state = self._model_state()
         if not model_state:
             return
         fields = self._routing.task_delta(model_state)
@@ -544,9 +550,7 @@ class CollectiveWorker:
 
     def _process_train_task(self, task) -> dict:
         task_start_ts = time.time()
-        self._routing.seed_once(
-            getattr(self._trainer.state, "model_state", None)
-        )
+        self._routing.seed_once(self._model_state())
         batch_count = 0
         record_count = 0
         last_loss = None
@@ -610,9 +614,7 @@ class CollectiveWorker:
                     self._trainer.step, len(pending)
                 )
             flush_start = time.monotonic()
-            if len(pending) == window_steps and hasattr(
-                self._trainer, "stage_window"
-            ):
+            if len(pending) == window_steps:
                 window = stage_call(self._trainer.stage_window, pending)
                 with self._anat_dispatch(len(pending), pending_real):
                     losses = self._trainer.train_window(window)
@@ -695,6 +697,12 @@ class CollectiveWorker:
                 if item is None:
                     break
                 features, labels, mask, global_real = item
+                _crash_site("worker.step")
+                # Train-side skew sketch of the host batch (never a
+                # device read); a ragged tail's pad rows, copies of row
+                # 0, are sketched with it.  Returns at once until
+                # --quality_drift_bins enables a monitor.
+                quality.note_train_batch(features)
                 if self._trainer.state is None:
                     # First touch: model init + eval_shape + jit build is
                     # compile-plane time, not execute.
@@ -760,13 +768,11 @@ class CollectiveWorker:
             TaskExecCounterKey.BATCH_COUNT: batch_count,
             TaskExecCounterKey.RECORD_COUNT: record_count,
         }
-        consume_oov = getattr(self._trainer, "consume_oov_count", None)
-        if consume_oov is not None:
-            # Task boundary — the one place a device sync is already paid
-            # (the task-done log above materialized the last loss).
-            oov = consume_oov()
-            if oov:
-                counters[TaskExecCounterKey.OOV_LOOKUP_COUNT] = oov
+        # Task boundary — the one place a device sync is already paid
+        # (the task-done log above materialized the last loss).
+        oov = self._trainer.consume_oov_count()
+        if oov:
+            counters[TaskExecCounterKey.OOV_LOOKUP_COUNT] = oov
         return counters
 
     # Leader-side eval outputs flush cadence: bounds the accumulated
@@ -878,3 +884,36 @@ class CollectiveWorker:
                         if self._world.is_leader:
                             self._ckpt.save(host_state, step)
             self._last_ckpt_step = step
+
+
+def _crash_site(site: str) -> None:
+    """A crash-injection site (common/faults.py): returns at once unless
+    the registry is armed with a `crash` spec that triggers at this call."""
+    spec = faults.fire(site)
+    if spec is not None and spec.kind == "crash":
+        faults.crash_now(spec)
+
+
+def named_arrays(tree, default_name: str = "output") -> dict:
+    """Flatten a model-output/label pytree into {name: np.ndarray}.
+
+    Dicts (the multi-output contract) keep their keys, nesting joined with
+    '/'; a bare tensor maps to `default_name`.  The reference aggregates
+    arbitrary named outputs/labels through Keras metrics (SURVEY.md §3.5).
+    """
+    if isinstance(tree, dict):
+        flat = {}
+        for key, value in tree.items():
+            if isinstance(value, dict):
+                for sub, arr in named_arrays(value, default_name).items():
+                    flat[f"{key}/{sub}"] = arr
+            else:
+                flat[str(key)] = np.asarray(value)
+        return flat
+    return {default_name: np.asarray(tree)}
+
+
+def concat_named(batches: list) -> dict:
+    """Concatenate a list of {name: array} dicts along axis 0."""
+    names = batches[0].keys()
+    return {name: np.concatenate([b[name] for b in batches]) for name in names}

@@ -12,7 +12,6 @@ from elasticdl_tpu.common.log_utils import get_logger
 from elasticdl_tpu.common.model_utils import load_model_spec
 from elasticdl_tpu.data.reader import build_data_reader
 from elasticdl_tpu.worker.master_client import MasterClient
-from elasticdl_tpu.worker.worker import Worker
 
 logger = get_logger("worker.main")
 
@@ -107,37 +106,10 @@ def main(argv=None):
         else None
     )
     client = MasterClient(args.master_addr, worker_id=args.worker_id)
-    if args.distribution_strategy in (
-        "AllreduceStrategy",
-        "ParameterServerStrategy",
-    ):
-        worker = _build_collective_worker(
-            args, model_spec, data_reader, client,
-            validation_reader, prediction_reader,
-        )
-    else:
-        from elasticdl_tpu.common.profiler import StepProfiler
-        from elasticdl_tpu.obs.stepstats import StepAnatomy
-
-        from elasticdl_tpu.data.pipeline import PipelineConfig
-
-        anatomy = StepAnatomy(args.worker_id)
-        anatomy.set_model(
-            getattr(args, "model_def", "") or getattr(args, "model_zoo", "")
-        )
-        worker = Worker(
-            master_client=client,
-            model_spec=model_spec,
-            data_reader=data_reader,
-            minibatch_size=args.minibatch_size,
-            validation_data_reader=validation_reader,
-            prediction_data_reader=prediction_reader,
-            profiler=StepProfiler(
-                args.tensorboard_log_dir, args.profile_steps, args.worker_id
-            ),
-            anatomy=anatomy,
-            pipeline=PipelineConfig.from_args(args),
-        )
+    worker = _build_collective_worker(
+        args, model_spec, data_reader, client,
+        validation_reader, prediction_reader,
+    )
     worker.run()
     if args.output and "training" in args.job_type:
         # Export the servable artifact at job end (reference: the master's
@@ -145,73 +117,134 @@ def main(argv=None):
         # lockstep — materializing process-spanning PS tables is a
         # collective row-gather — and only rank 0 writes; tables stream
         # out in bounded row chunks, so this works at any table size.
-        from elasticdl_tpu.client.api import save_model
-
         save_model(worker.trainer, args.output, args)
     return 0
+
+
+def save_model(trainer, output_path: str, args=None):
+    """Export the trained model as a servable artifact directory (the
+    reference's `get_model_to_export` analogue — serving/export.py).
+    A legacy flat-variables `.npz` is still written when the path ends in
+    `.npz` (external consumers of the round-1 format)."""
+    if trainer.state is None:
+        logger.warning("No variables to save (model never initialized)")
+        return
+    if output_path.endswith(".npz"):
+        import jax
+        import numpy as np
+
+        variables = trainer.get_variables_numpy()  # collective (PS tables)
+        if jax.process_index() == 0:
+            np.savez(output_path, **variables)
+            logger.info(
+                "Saved %d variables to %s", len(variables), output_path
+            )
+        return
+    from elasticdl_tpu.serving import export_model
+
+    # Record the RESOLVED model params — job flags that model_utils
+    # injects into model_params (sparse_apply_every, use_bf16) included
+    # — not the raw --model_params string: a flag-dependent model
+    # structure (DeepFM's per-mode table layout follows
+    # sparse_apply_every at >10M rows) must rebuild identically at
+    # serving load, where the job flags no longer exist.
+    model_params = getattr(args, "model_params", "")
+    if args is not None and getattr(args, "model_def", ""):
+        from elasticdl_tpu.common.args import format_dict_params
+
+        model_params = format_dict_params(load_model_spec(args).model_params)
+    export_model(
+        trainer,
+        output_path,
+        model_zoo=getattr(args, "model_zoo", ""),
+        model_def=getattr(args, "model_def", ""),
+        model_params=model_params,
+    )
+
+
+#: Consecutive failed tasks a Local worker reports and rides through:
+#: nothing would relaunch it.
+LOCAL_TASK_FAILURES = 10
 
 
 def _build_collective_worker(
     args, model_spec, data_reader, client,
     validation_reader=None, prediction_reader=None,
 ):
-    """Join the elastic world, build the mesh-wide trainer, restore state."""
-    from elasticdl_tpu.checkpoint import CheckpointSaver
+    """Build the worker for `args.distribution_strategy`: form the world,
+    build the mesh-wide trainer, wire the loop.  The strategy decides
+    three things, here and nowhere else: the world (joined through the
+    master, or Local's world of one), the trainer and its devices, and
+    how many failed tasks the loop rides through."""
+    import jax
+
+    from elasticdl_tpu.checkpoint import (
+        CheckpointSaver,
+        ShardedCheckpointSaver,
+    )
+    from elasticdl_tpu.common.constants import DistributionStrategy
+    from elasticdl_tpu.common.profiler import StepProfiler
+    from elasticdl_tpu.data.pipeline import PipelineConfig
+    from elasticdl_tpu.obs import tracing
+    from elasticdl_tpu.obs.stepstats import StepAnatomy
     from elasticdl_tpu.obs.telemetry import WorkerTelemetry
+    from elasticdl_tpu.ops import sparse_embedding as ske
     from elasticdl_tpu.parallel import MeshConfig, build_mesh
     from elasticdl_tpu.parallel.dp_trainer import DataParallelTrainer
-    from elasticdl_tpu.parallel.elastic import join_world
+    from elasticdl_tpu.parallel.elastic import WorldInfo, join_world
     from elasticdl_tpu.worker.collective_worker import CollectiveWorker
 
-    world = join_world(client)
+    strategy = args.distribution_strategy
+    local = strategy == DistributionStrategy.LOCAL
+    sharded_embeddings = strategy == DistributionStrategy.PARAMETER_SERVER
+    if local:
+        # A master and a world of one: no rendezvous to join, and no
+        # supervisor that would re-form a world.
+        world = WorldInfo(
+            rank=0, world_size=1, rendezvous_id=0, coordinator_addr=""
+        )
+    else:
+        world = join_world(client)
     # Worker telemetry plane: step times / task progress / RPC retries
     # collected here ride the liveness heartbeat to the master's
     # aggregator (docs/observability.md "Worker telemetry plane").
-    telemetry = WorkerTelemetry(args.worker_id)
+    telemetry = WorkerTelemetry(client.worker_id)
     telemetry.bind_retry_stats(client.retry_stats)
     telemetry.set_rendezvous(world.rendezvous_id)
     # Step-anatomy ledger (docs/observability.md "Step anatomy"): the
     # phase decomposition rides the same heartbeat snapshot; the
     # CollectiveWorker reads it off the telemetry binding and registers
     # the trainer's jitted entrypoints for retrace detection.
-    from elasticdl_tpu.obs.stepstats import StepAnatomy
-
-    anatomy = StepAnatomy(args.worker_id)
-    anatomy.set_model(
-        getattr(args, "model_def", "") or getattr(args, "model_zoo", "")
-    )
+    anatomy = StepAnatomy(client.worker_id)
+    anatomy.set_model(args.model_def or args.model_zoo)
     telemetry.bind_anatomy(anatomy)
-    # All devices of the joined world, shaped (data, model): the model
-    # axis carries sharded embedding tables and — for mesh-aware zoo
-    # models — ring-attention context parallelism.
-    import jax
-
-    from elasticdl_tpu.obs import tracing
-
     # The first `jax.devices()` of the process brings the backend up
     # (on a TPU host: the runtime's start, seconds).
     with tracing.span("worker.backend_init") as backend:
         backend.fields["devices"] = len(jax.devices())
-    mesh = build_mesh(
-        MeshConfig(model=getattr(args, "mesh_model_axis", 1))
-    )
+    if local:
+        # Local trains on one device.
+        mesh = build_mesh(MeshConfig(), devices=jax.devices()[:1])
+    else:
+        # All devices of the joined world, shaped (data, model): the
+        # model axis carries sharded embedding tables and — for
+        # mesh-aware zoo models — ring-attention context parallelism.
+        mesh = build_mesh(MeshConfig(model=args.mesh_model_axis))
     # --sparse_kernel resolution is STRATEGY-INDEPENDENT (the Embedding
     # layers run under every trainer).  Multi-device meshes run the
     # fused kernels through the shard_map dispatch
-    # (ops/sparse_embedding.py "Sharded dispatch") — the v1 whole-job
-    # downgrade to xla is gone.  Register BOTH process defaults BEFORE
-    # the model is built: the kernel default (Embedding layers that did
-    # not thread sparse_kernel explicitly resolve it at trace time; zoo
-    # models that declare the param get the same value via model_params,
-    # common/model_utils.py) and the dispatch mesh (layers that did not
-    # thread `mesh` still route per-shard kernel bodies instead of
-    # tracing an unpartitionable pallas_call into an SPMD program).
-    from elasticdl_tpu.ops import sparse_embedding as ske
-
-    sparse_kernel = getattr(args, "sparse_kernel", "auto") or "auto"
+    # (ops/sparse_embedding.py "Sharded dispatch").  Register BOTH
+    # process defaults BEFORE the model is built: the kernel default
+    # (Embedding layers that did not thread sparse_kernel explicitly
+    # resolve it at trace time; zoo models that declare the param get
+    # the same value via model_params, common/model_utils.py) and the
+    # dispatch mesh (layers that did not thread `mesh` still route
+    # per-shard kernel bodies instead of tracing an unpartitionable
+    # pallas_call into an SPMD program).
+    sparse_kernel = args.sparse_kernel or "auto"
     ske.set_default_kernel(sparse_kernel)
     ske.set_dispatch_mesh(mesh)
-    if args.distribution_strategy == "ParameterServerStrategy":
+    if sharded_embeddings:
         from elasticdl_tpu.parallel.ps_trainer import ShardedEmbeddingTrainer
 
         trainer = ShardedEmbeddingTrainer(
@@ -224,7 +257,7 @@ def _build_collective_worker(
                 if model_spec.embedding_optimizer is not None
                 else None
             ),
-            sparse_apply_every=getattr(args, "sparse_apply_every", 1),
+            sparse_apply_every=args.sparse_apply_every,
             sparse_kernel=sparse_kernel,
         )
     else:
@@ -237,24 +270,17 @@ def _build_collective_worker(
         )
     saver = None
     if args.checkpoint_dir:
-        if (
-            args.distribution_strategy == "ParameterServerStrategy"
-            or args.dense_sharding == "fsdp"
-        ):
-            # Mesh-sharded state (PS tables / FSDP dense leaves): each
-            # process writes its own shard files, so no host ever gathers
-            # the full model (checkpoint/sharded.py).
-            from elasticdl_tpu.checkpoint import ShardedCheckpointSaver
-
-            saver = ShardedCheckpointSaver(
-                args.checkpoint_dir, keep_max=args.keep_checkpoint_max
-            )
-        else:
-            saver = CheckpointSaver(
-                args.checkpoint_dir, keep_max=args.keep_checkpoint_max
-            )
-    from elasticdl_tpu.common.profiler import StepProfiler
-
+        # Mesh-sharded state (PS tables / FSDP dense leaves): each
+        # process writes its own shard files, so no host ever gathers
+        # the full model (checkpoint/sharded.py).
+        saver_cls = (
+            ShardedCheckpointSaver
+            if sharded_embeddings or args.dense_sharding == "fsdp"
+            else CheckpointSaver
+        )
+        saver = saver_cls(
+            args.checkpoint_dir, keep_max=args.keep_checkpoint_max
+        )
     return CollectiveWorker(
         master_client=client,
         model_spec=model_spec,
@@ -267,18 +293,13 @@ def _build_collective_worker(
         validation_data_reader=validation_reader,
         prediction_data_reader=prediction_reader,
         profiler=StepProfiler(
-            args.tensorboard_log_dir, args.profile_steps, args.worker_id
+            args.tensorboard_log_dir, args.profile_steps, client.worker_id
         ),
         train_window_steps=args.train_window_steps,
         telemetry=telemetry,
-        pipeline=_pipeline_config(args),
+        pipeline=PipelineConfig.from_args(args),
+        max_task_failures=LOCAL_TASK_FAILURES if local else 0,
     )
-
-
-def _pipeline_config(args):
-    from elasticdl_tpu.data.pipeline import PipelineConfig
-
-    return PipelineConfig.from_args(args)
 
 
 if __name__ == "__main__":

@@ -81,7 +81,7 @@ def convert(
     from jax.experimental import jax2tf
 
     from elasticdl_tpu.serving import load_for_serving
-    from elasticdl_tpu.worker.trainer import _model_apply
+    from elasticdl_tpu.parallel.trainer import model_apply
 
     served = load_for_serving(artifact_dir, model_zoo=model_zoo, mmap=True)
     if sample:
@@ -102,7 +102,7 @@ def convert(
 
     def forward(leaves_, feats):
         vars_ = jax.tree.unflatten(treedef, list(leaves_))
-        outputs, _ = _model_apply(
+        outputs, _ = model_apply(
             model, vars_, feats, train=False, mutable=False
         )
         return outputs

@@ -50,6 +50,53 @@ def obs_registry_snapshot():
         registry.restore(saved)
 
 
+@pytest.fixture
+def dense_step_losses(monkeypatch):
+    """Every loss the dense trainer's steps produce, in step order, under
+    whichever of the worker loop's two dispatch forms ran them (a fused
+    window of K steps, or one staged step)."""
+    import numpy as np
+
+    from elasticdl_tpu.parallel.dp_trainer import DataParallelTrainer
+
+    losses = []
+    window, step = (
+        DataParallelTrainer.train_window,
+        DataParallelTrainer.train_step_staged,
+    )
+
+    def spy_window(self, staged):
+        out = window(self, staged)
+        losses.extend(float(x) for x in np.asarray(out))
+        return out
+
+    def spy_step(self, staged):
+        out = step(self, staged)
+        losses.append(float(out))
+        return out
+
+    monkeypatch.setattr(DataParallelTrainer, "train_window", spy_window)
+    monkeypatch.setattr(DataParallelTrainer, "train_step_staged", spy_step)
+    return losses
+
+
+def one_device_trainer(model, loss_fn, optimizer, seed=0):
+    """The dense trainer as Local mode builds it: on a mesh of one device."""
+    import jax
+
+    from elasticdl_tpu.parallel import (
+        DataParallelTrainer,
+        MeshConfig,
+        build_mesh,
+    )
+
+    return DataParallelTrainer(
+        model, loss_fn, optimizer,
+        mesh=build_mesh(MeshConfig(), devices=jax.devices()[:1]),
+        seed=seed,
+    )
+
+
 def run_kill_recovery_job(
     args, n_records, worker_env, log_dir, progress_fraction=8,
     wait_timeout=480, recovery_bound_s=240.0,

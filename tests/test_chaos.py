@@ -125,7 +125,7 @@ def test_master_sigkill_midjob_workers_ride_through(tmp_path):
     from elasticdl_tpu.common.args import parse_master_args
     from elasticdl_tpu.common.model_utils import load_model_spec
     from elasticdl_tpu.data.reader import build_data_reader
-    from elasticdl_tpu.worker.worker import Worker
+    from elasticdl_tpu.worker.main import _build_collective_worker
 
     # Long enough (256 tasks, ~10 s) that the kill lands mid-job even when a
     # loaded machine delays this thread or the snapshot by seconds.
@@ -158,14 +158,12 @@ def test_master_sigkill_midjob_workers_ride_through(tmp_path):
                 f"localhost:{port}", worker_id=wid, retry_policy=CHAOS_POLICY
             )
             clients.append(client)
-            workers.append(Worker(
-                master_client=client,
-                model_spec=model_spec,
-                data_reader=build_data_reader(
-                    args, model_spec, args.training_data
-                ),
-                minibatch_size=args.minibatch_size,
-                wait_sleep_s=0.1,
+            # Local mode's worker (the default strategy): a world of one
+            # per client, no supervisor to relaunch it.
+            workers.append(_build_collective_worker(
+                args, model_spec,
+                build_data_reader(args, model_spec, args.training_data),
+                client,
             ))
 
         def run(worker):

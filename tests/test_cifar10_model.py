@@ -7,7 +7,7 @@ the mnist DNN has no non-trainable state, so this is the coverage for it.
 import numpy as np
 
 from elasticdl_tpu.parallel import DataParallelTrainer, MeshConfig, build_mesh
-from elasticdl_tpu.worker.trainer import Trainer
+from tests.conftest import one_device_trainer
 from model_zoo.cifar10 import cifar10_functional_api as zoo
 from model_zoo import datasets
 
@@ -34,7 +34,7 @@ def _as_dataset(reader):
 
 
 def test_resnet20_trains_and_updates_batch_stats():
-    trainer = Trainer(
+    trainer = one_device_trainer(
         zoo.custom_model(use_bf16=False), zoo.loss, zoo.optimizer(lr=0.05)
     )
     feats, labels = _batch(16)
@@ -53,7 +53,7 @@ def test_resnet20_dp_matches_single_device():
     dp = DataParallelTrainer(
         zoo.custom_model(use_bf16=False), zoo.loss, zoo.optimizer(), mesh, seed=0
     )
-    single = Trainer(
+    single = one_device_trainer(
         zoo.custom_model(use_bf16=False), zoo.loss, zoo.optimizer(), seed=0
     )
     feats, labels = _batch(16, seed=1)
@@ -70,7 +70,9 @@ def test_resnet20_dp_matches_single_device():
 
 
 def test_resnet20_bf16_forward_finite():
-    trainer = Trainer(zoo.custom_model(use_bf16=True), zoo.loss, zoo.optimizer())
+    trainer = one_device_trainer(
+        zoo.custom_model(use_bf16=True), zoo.loss, zoo.optimizer()
+    )
     feats, labels = _batch(8)
     loss = trainer.train_step(feats, labels)
     assert np.isfinite(float(loss))

@@ -14,7 +14,8 @@ import optax
 from elasticdl_tpu.layers import Embedding
 from elasticdl_tpu.parallel import MeshConfig, build_mesh, sparse_optim
 from elasticdl_tpu.parallel.ps_trainer import ShardedEmbeddingTrainer
-from elasticdl_tpu.worker.trainer import Trainer, TrainState
+from elasticdl_tpu.parallel.trainer import TrainState
+from tests.conftest import one_device_trainer
 
 VOCAB, DIM = 32, 8
 
@@ -219,7 +220,9 @@ def test_sparse_path_matches_dense_autodiff_sgd():
         SparseModel(), _loss, optax.sgd(0.2), mesh,
         embedding_optimizer=sparse_optim.sgd(0.2), seed=0,
     )
-    dense_trainer = Trainer(DenseModel(), _loss, optax.sgd(0.2), seed=0)
+    dense_trainer = one_device_trainer(
+        DenseModel(), _loss, optax.sgd(0.2), seed=0
+    )
 
     rng = np.random.RandomState(0)
     ids = rng.randint(0, VOCAB, size=(16, 3)).astype(np.int32)
@@ -303,7 +306,7 @@ def test_checkpoint_restore_roundtrip():
 def test_embedding_trains_densely_under_local_trainer():
     """Outside PS mode the table is a normal param: dense autodiff must
     train it (no silent freeze)."""
-    trainer = Trainer(SparseModel(), _loss, optax.sgd(0.2), seed=0)
+    trainer = one_device_trainer(SparseModel(), _loss, optax.sgd(0.2), seed=0)
     rng = np.random.RandomState(0)
     ids = rng.randint(0, VOCAB, size=(16, 3)).astype(np.int32)
     labels = rng.randint(0, 4, size=16).astype(np.int32)
@@ -319,7 +322,7 @@ def test_dense_trainer_handles_ragged_batches():
     """The capture collections (perturbations/ids) must NOT live in
     model_state: they'd freeze the init batch's shape (crash on a ragged
     final batch) and grow the sow tuple every step (recompile per step)."""
-    trainer = Trainer(SparseModel(), _loss, optax.sgd(0.2), seed=0)
+    trainer = one_device_trainer(SparseModel(), _loss, optax.sgd(0.2), seed=0)
     rng = np.random.RandomState(0)
     for batch in (16, 16, 7, 16, 3):  # ragged sizes interleaved
         ids = rng.randint(0, VOCAB, size=(batch, 3)).astype(np.int32)
@@ -368,7 +371,7 @@ def test_masked_batch_does_not_touch_adam_slots():
 def test_dense_trainer_exports_logical_table_shape():
     """Export from the Local/AllReduce path must show [vocab, dim], not the
     packed storage shape (same contract as the PS trainer)."""
-    trainer = Trainer(SparseModel(), _loss, optax.sgd(0.1), seed=0)
+    trainer = one_device_trainer(SparseModel(), _loss, optax.sgd(0.1), seed=0)
     rng = np.random.RandomState(0)
     ids = rng.randint(0, VOCAB, size=(8, 3)).astype(np.int32)
     labels = rng.randint(0, 4, size=8).astype(np.int32)

@@ -29,25 +29,10 @@ def _train_args(tmp_path, extra=()):
     )
 
 
-def test_local_train_end_to_end(tmp_path):
+def test_local_train_end_to_end(tmp_path, dense_step_losses):
     args = _train_args(tmp_path)
-    losses = []
-
-    # Wrap the trainer step to observe the loss trajectory.
-    from elasticdl_tpu.worker import trainer as trainer_mod
-
-    original = trainer_mod.Trainer.train_step
-
-    def spy(self, features, labels):
-        loss = original(self, features, labels)
-        losses.append(float(loss))
-        return loss
-
-    trainer_mod.Trainer.train_step = spy
-    try:
-        assert api._run_local(args, mode="training") == 0
-    finally:
-        trainer_mod.Trainer.train_step = original
+    losses = dense_step_losses  # the loss trajectory, step by step
+    assert api._run_local(args, mode="training") == 0
 
     assert len(losses) == 20  # 640 records / 32 batch
     # Loss decreases substantially on the learnable synthetic task.
@@ -160,8 +145,9 @@ def test_eval_tasks_read_from_validation_reader():
     import numpy as np
 
     from elasticdl_tpu.data.reader import NumpyDataReader
+    from elasticdl_tpu.parallel.elastic import WorldInfo
     from elasticdl_tpu.proto import elasticdl_pb2 as pb
-    from elasticdl_tpu.worker.worker import Worker
+    from elasticdl_tpu.worker.collective_worker import CollectiveWorker
 
     train_reader = NumpyDataReader(
         np.zeros((8, 2), np.float32), np.zeros(8, np.int32), shard_name="d"
@@ -173,17 +159,29 @@ def test_eval_tasks_read_from_validation_reader():
     class Spec:
         dataset_fn = staticmethod(lambda ds, mode, meta: ds)
 
-    worker = Worker.__new__(Worker)  # wire only what _get_batches needs
-    from elasticdl_tpu.data.task_data_service import TaskDataService
+    class Trainer:  # only what the loop asks of it before a step
+        apply_every = 1
 
-    worker._minibatch_size = 4
-    worker._task_data_service = TaskDataService(train_reader, Spec.dataset_fn)
-    worker._eval_data_service = TaskDataService(val_reader, Spec.dataset_fn)
-    worker._predict_data_service = worker._task_data_service
+        def local_block(self, per_rank_batch):
+            return per_rank_batch
+
+    worker = CollectiveWorker(
+        master_client=None,
+        model_spec=Spec(),
+        data_reader=train_reader,
+        minibatch_size=4,
+        world=WorldInfo(
+            rank=0, world_size=1, rendezvous_id=0, coordinator_addr=""
+        ),
+        trainer=Trainer(),
+        validation_data_reader=val_reader,
+    )
     task = pb.Task(task_id=1, shard_name="d", start=0, end=8, type=pb.EVALUATION)
     from elasticdl_tpu.common.constants import Mode
 
-    batches = list(worker._get_batches(task, Mode.EVALUATION))
-    assert all(np.all(f == 1.0) for f, _l in batches)
-    train_batches = list(worker._get_batches(task, Mode.TRAINING))
-    assert all(np.all(f == 0.0) for f, _l in train_batches)
+    batches = list(worker._local_batches(task, Mode.EVALUATION))
+    assert len(batches) == 2
+    assert all(np.all(f == 1.0) for f, _l, _mask, _real in batches)
+    task.type = pb.TRAINING
+    train_batches = list(worker._local_batches(task, Mode.TRAINING))
+    assert all(np.all(f == 0.0) for f, _l, _mask, _real in train_batches)

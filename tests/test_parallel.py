@@ -18,7 +18,7 @@ from elasticdl_tpu.parallel import (
     build_mesh,
 )
 from elasticdl_tpu.parallel import sharding as shd
-from elasticdl_tpu.worker.trainer import Trainer
+from tests.conftest import one_device_trainer
 from model_zoo.mnist import mnist_functional_api as zoo
 
 
@@ -57,7 +57,9 @@ def test_dp_trainer_matches_single_device():
     dp = DataParallelTrainer(
         zoo.custom_model(), zoo.loss, zoo.optimizer(), mesh, seed=0
     )
-    single = Trainer(zoo.custom_model(), zoo.loss, zoo.optimizer(), seed=0)
+    single = one_device_trainer(
+        zoo.custom_model(), zoo.loss, zoo.optimizer(), seed=0
+    )
 
     for feats, labels in _toy_batches():
         dp_loss = dp.train_step(feats, labels)
@@ -80,7 +82,9 @@ def test_dp_trainer_ragged_batch():
     dp = DataParallelTrainer(
         zoo.custom_model(), zoo.loss, zoo.optimizer(), mesh, seed=0
     )
-    single = Trainer(zoo.custom_model(), zoo.loss, zoo.optimizer(), seed=0)
+    single = one_device_trainer(
+        zoo.custom_model(), zoo.loss, zoo.optimizer(), seed=0
+    )
     rng = np.random.RandomState(1)
     feats = rng.rand(13, 28, 28).astype(np.float32)
     labels = rng.randint(0, 10, size=13).astype(np.int32)
@@ -132,7 +136,9 @@ def test_train_step_local_indivisible_minibatch():
     dp = DataParallelTrainer(
         zoo.custom_model(), zoo.loss, zoo.optimizer(), mesh, seed=0
     )
-    single = Trainer(zoo.custom_model(), zoo.loss, zoo.optimizer(), seed=0)
+    single = one_device_trainer(
+        zoo.custom_model(), zoo.loss, zoo.optimizer(), seed=0
+    )
     rng = np.random.RandomState(3)
     feats = rng.rand(10, 28, 28).astype(np.float32)
     labels = rng.randint(0, 10, size=10).astype(np.int32)
@@ -164,6 +170,7 @@ class TestRestoreConsistency:
 
         class FakeTrainer:
             mesh = build_mesh(MeshConfig())
+            apply_every = 1
 
             def local_block(self, mb):
                 return mb
@@ -252,6 +259,7 @@ class TestChunkedEvalReporting:
 
         class FakeTrainer:
             mesh = build_mesh(MeshConfig())
+            apply_every = 1
 
             def local_block(self, mb_):
                 return mb_
@@ -325,9 +333,11 @@ class TestAutoWindowSizing:
             def shard_names(self):
                 return ["s"]
 
+        every = apply_every
+
         class FakeTrainer:
             mesh = build_mesh(MeshConfig())
-            _sparse_apply_every = apply_every
+            apply_every = every
 
             def local_block(self, mb):
                 return mb
@@ -394,7 +404,7 @@ def test_auto_apply_resync_grows_explicit_window():
 
     class FakeTrainer:
         mesh = build_mesh(MeshConfig())
-        _sparse_apply_every = None  # auto, unresolved until init
+        apply_every = 1  # auto, unresolved until init
 
         def local_block(self, mb):
             return mb
@@ -417,7 +427,7 @@ def test_auto_apply_resync_grows_explicit_window():
     assert worker._apply_every == 1
     assert worker._window_steps == 10
 
-    trainer._sparse_apply_every = 32  # what ensure_initialized resolves
+    trainer.apply_every = 32  # what ensure_initialized resolves
     assert worker._sync_apply_every() is True
     assert worker._apply_every == 32
     assert worker._window_steps == 32  # grown to the chunk multiple

@@ -334,10 +334,9 @@ def test_pipeline_config_from_parsed_args():
 # ---------------------------------------------------------------------------
 
 
-def _local_losses(tmp_path, pipeline_mode):
+def _local_losses(losses, pipeline_mode):
     from elasticdl_tpu.client import api
     from elasticdl_tpu.common.args import parse_master_args
-    from elasticdl_tpu.worker import trainer as trainer_mod
 
     args = parse_master_args(
         [
@@ -352,27 +351,16 @@ def _local_losses(tmp_path, pipeline_mode):
             "--pipeline_inflight", "3",
         ]
     )
-    losses = []
-    original = trainer_mod.Trainer.train_step
-
-    def spy(self, features, labels):
-        loss = original(self, features, labels)
-        losses.append(float(loss))
-        return loss
-
-    trainer_mod.Trainer.train_step = spy
-    try:
-        assert api._run_local(args, mode="training") == 0
-    finally:
-        trainer_mod.Trainer.train_step = original
-    return losses
+    del losses[:]
+    assert api._run_local(args, mode="training") == 0
+    return list(losses)
 
 
-def test_async_pipeline_loss_curve_bit_identical_to_sync(tmp_path):
+def test_async_pipeline_loss_curve_bit_identical_to_sync(dense_step_losses):
     """The pipeline moves host work in TIME, never in EFFECT: the same
     job through the async prefetch path must reproduce the sync loss
     sequence bit for bit on CPU."""
-    sync_losses = _local_losses(tmp_path, "sync")
-    async_losses = _local_losses(tmp_path, "async")
+    sync_losses = _local_losses(dense_step_losses, "sync")
+    async_losses = _local_losses(dense_step_losses, "async")
     assert len(sync_losses) == 10  # 320 records / 32 batch
     assert async_losses == sync_losses  # exact float equality, per step

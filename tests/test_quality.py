@@ -413,7 +413,7 @@ def test_quality_call_sites_pass_purity_and_cardinality_rules():
             "elasticdl_tpu/serving/frontend.py",
             "elasticdl_tpu/serving/replica_main.py",
             "elasticdl_tpu/data/stream.py",
-            "elasticdl_tpu/worker/worker.py",
+            "elasticdl_tpu/worker/collective_worker.py",
             "elasticdl_tpu/worker/main.py",
             "scripts/loadgen.py",
         )

@@ -9,7 +9,7 @@ import numpy as np
 import optax
 import pytest
 
-from elasticdl_tpu.worker.trainer import Trainer
+from tests.conftest import one_device_trainer
 from model_zoo import datasets
 from model_zoo.resnet50 import resnet50_subclass as zoo
 
@@ -48,7 +48,9 @@ def _flat_keys(tree, prefix=""):
 
 def test_trains_and_bn_state_updates():
     model = zoo.custom_model(num_classes=4, use_bf16=True)
-    trainer = Trainer(model, zoo.loss, optax.sgd(0.05, momentum=0.9), seed=0)
+    trainer = one_device_trainer(
+        model, zoo.loss, optax.sgd(0.05, momentum=0.9), seed=0
+    )
     rng = np.random.RandomState(0)
     # Raw uint8 pixels: the input contract since round 5 — the model
     # normalizes (0-255 scale) on device.

@@ -219,11 +219,12 @@ def test_fault_crash_kills_the_process_like_sigkill():
 
 
 def test_worker_task_loop_is_a_crash_injection_site(monkeypatch):
-    """The simple worker fires the `worker.task` site before each task —
+    """The worker loop fires the `worker.task` site before each task —
     crash_now intercepted so the test process survives."""
     from types import SimpleNamespace
 
-    from elasticdl_tpu.worker.worker import Worker
+    from elasticdl_tpu.parallel.elastic import WorldInfo
+    from elasticdl_tpu.worker.collective_worker import CollectiveWorker
 
     class _Boom(Exception):
         pass
@@ -247,12 +248,19 @@ def test_worker_task_loop_is_a_crash_injection_site(monkeypatch):
         def report_version(self, *a, **k):
             pass
 
-    worker = Worker(
+    worker = CollectiveWorker(
         master_client=_OneTaskClient(),
         model_spec=SimpleNamespace(dataset_fn=None, callbacks=None),
-        data_reader=SimpleNamespace(metadata=None),
+        data_reader=SimpleNamespace(
+            metadata=None, shard_names=lambda: ["s"]
+        ),
         minibatch_size=2,
-        trainer=SimpleNamespace(step=0),
+        world=WorldInfo(
+            rank=0, world_size=1, rendezvous_id=0, coordinator_addr=""
+        ),
+        trainer=SimpleNamespace(
+            step=0, apply_every=1, local_block=lambda per_rank: per_rank
+        ),
     )
     with pytest.raises(_Boom):
         worker.run()

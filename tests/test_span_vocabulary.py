@@ -152,8 +152,12 @@ def test_every_device_scope_and_the_routing_span_name_their_reader():
 
 
 def _collective_worker(saver, profiler=None, anatomy=None):
-    from elasticdl_tpu.parallel import MeshConfig, build_mesh
+    import flax.linen as nn
+    import jax.numpy as jnp
+    import optax
+
     from elasticdl_tpu.parallel.elastic import WorldInfo
+    from tests.conftest import one_device_trainer
     from elasticdl_tpu.worker.collective_worker import CollectiveWorker
 
     class Reader:
@@ -166,26 +170,15 @@ def _collective_worker(saver, profiler=None, anatomy=None):
             for i in range(task.start, task.end):
                 yield np.full((2,), i, np.float32), np.int32(i)
 
-    class Trainer:
-        mesh = build_mesh(MeshConfig())
-        state = object()
-        step = 0
+    class Model(nn.Module):
+        @nn.compact
+        def __call__(self, x):
+            return nn.Dense(1)(x)[:, 0]
 
-        def local_block(self, mb):
-            return mb
-
-        def ensure_initialized(self, features):
-            return self.state
-
-        def stage_batch(self, features, labels, mask):
-            return features, labels, mask
-
-        def train_step_staged(self, staged):
-            self.step += 1
-            return np.float32(0.5)
-
-        def state_to_host(self):
-            return {"w": np.zeros(4, np.float32)}
+    trainer = one_device_trainer(
+        Model(), lambda labels, out: jnp.mean((out - labels) ** 2),
+        optax.sgd(0.0),
+    )
 
     class Spec:
         columnar_dataset_fn = None
@@ -203,7 +196,7 @@ def _collective_worker(saver, profiler=None, anatomy=None):
         minibatch_size=4,
         world=WorldInfo(rank=0, world_size=1, rendezvous_id=1,
                         coordinator_addr=""),
-        trainer=Trainer(), checkpoint_saver=saver, checkpoint_steps=2,
+        trainer=trainer, checkpoint_saver=saver, checkpoint_steps=2,
         profiler=profiler, anatomy=anatomy,
     )
 

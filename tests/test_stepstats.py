@@ -635,7 +635,6 @@ def test_new_call_sites_pass_purity_and_cardinality_rules():
             "elasticdl_tpu/obs/trace.py",
             "elasticdl_tpu/common/profiler.py",
             "elasticdl_tpu/worker/collective_worker.py",
-            "elasticdl_tpu/worker/worker.py",
             "elasticdl_tpu/worker/master_client.py",
             "elasticdl_tpu/master/servicer.py",
             "elasticdl_tpu/master/task_manager.py",
