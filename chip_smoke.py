@@ -12,7 +12,8 @@ strict apply; weights random from --seed, data written from --seed):
 
   kernels      every Pallas kernel a user flag reaches, once, against its
                XLA twin (fused sparse lookup / lookup+FM / dedup+apply,
-               flash attention fwd+bwd, the ring step) — correctness only
+               flash attention fwd+bwd, the ring step) and the delta
+               rule's kernel pair, which the backend picks — correctness only
   train        `python -m elasticdl_tpu.client.main train` with
                ParameterServerStrategy on an ETRF file: master -> task
                dispatch -> one worker subprocess -> file -> native codec
@@ -579,6 +580,42 @@ def phase_kernels(args) -> dict:
         scale = float(jnp.max(jnp.abs(w.astype(jnp.float32))))
         check(f"ring step bwd d{which} ~ xla ring engine", g, w,
               0.05, 0.05 * scale, secs)
+
+    # -- the chunked gated delta rule's kernel pair against its XLA engine
+    # (both three bfloat16 passes a float32 product on the chip, 2e-5 rms
+    # apart there; one pass reads 4e-3) ------------------------------------
+    from elasticdl_tpu.ops import gated_delta
+
+    t_rule = shape["t"] // 2
+    keys = jax.random.split(jax.random.PRNGKey(args.seed + 11), 5)
+    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)  # noqa: E731
+    rule_args = [
+        unit(jax.random.normal(keys[0], (1, t_rule, 2, 128))) / 128 ** 0.5,
+        unit(jax.random.normal(keys[1], (1, t_rule, 2, 128))),
+        jax.random.normal(keys[2], (1, t_rule, 4, 128)),
+        -0.3 * jnp.exp(jax.random.normal(keys[3], (1, t_rule, 4))),
+        jax.nn.sigmoid(jax.random.normal(keys[4], (1, t_rule, 4))),
+    ]
+
+    def rule_grads(rule):
+        def loss(*xs):
+            out, state = rule(*xs)
+            return jnp.sum(out ** 2) + jnp.sum(state), out
+
+        return jax.grad(loss, argnums=range(5), has_aux=True)
+
+    (got_g, got_out), secs = run(
+        "delta rule", rule_grads(gated_delta.chunk_gated_delta_rule_pallas),
+        *rule_args,
+    )
+    want_g, want_out = twin(
+        rule_grads(gated_delta.chunk_gated_delta_rule_xla), *rule_args
+    )
+    check("delta rule fwd ~ xla engine", got_out, want_out, 1e-3, 1e-3, secs)
+    for g, w, which in zip(got_g, want_g, ("q", "k", "v", "g", "beta")):
+        scale = float(jnp.max(jnp.abs(w)))
+        check(f"delta rule bwd d{which} ~ xla engine", g, w,
+              1e-3, 1e-3 * scale, secs)
 
     return {"device": device, "checks": len(checks)}
 

@@ -101,6 +101,7 @@ class GatedDeltaNet(nn.Module):
     conv_kernel: int
     eps: float
     dtype: Any
+    mesh: Any = None  # what the program is compiled for: the rule's engine
 
     @nn.compact
     def __call__(self, x):
@@ -151,7 +152,8 @@ class GatedDeltaNet(nn.Module):
         # repeat_interleave; the rule repeats q and k a group at a time).
         with jax.named_scope("gdn_scan"):
             out, _ = chunk_gated_delta_rule(
-                _l2norm(q) / np.sqrt(dk), _l2norm(k), v, g, beta
+                _l2norm(q) / np.sqrt(dk), _l2norm(k), v, g, beta,
+                mesh=self.mesh,
             )
         # Gated RMSNorm per head (w from 1), in float32.
         weight = self.param("norm", nn.initializers.ones_init(), (dv,),
@@ -221,7 +223,7 @@ class DecoderLayer(nn.Module):
                     c.linear_num_key_heads, c.linear_num_value_heads,
                     c.linear_key_head_dim, c.linear_value_head_dim,
                     c.linear_conv_kernel_dim, c.rms_norm_eps, c.dtype,
-                    name="linear_attn",
+                    c.mesh, name="linear_attn",
                 )(h)
         with jax.named_scope("moe"):
             h = RMSNorm(c.rms_norm_eps, name="post_attention_layernorm")(x)
@@ -263,6 +265,7 @@ class Qwen3NextConfig:
     dtype: Any = jnp.bfloat16
     attn_impl: str = "auto"
     remat: bool = False
+    mesh: Any = None
 
 
 class Qwen3NextLM(nn.Module):
@@ -294,17 +297,20 @@ class Qwen3NextLM(nn.Module):
             )
 
 
-def custom_model(use_bf16: bool = True, **config):
+def custom_model(use_bf16: bool = True, mesh=None, **config):
     """`config`: the source's `config.json` keys this model reads (see
     `Qwen3NextConfig`), plus `experts_first` / `experts_held` (the range of
     experts this chip holds), `attn_impl` and `remat` (rematerialise each
-    decoder layer in the backward pass)."""
+    decoder layer in the backward pass).  `mesh`: the job's mesh, which
+    `ModelSpec.build_model` hands to a model that names it; under a mesh
+    of several devices the delta rule's kernels run a data shard's
+    sequences a device (`ops/gated_delta.py`)."""
     unknown = set(config) - set(Qwen3NextConfig.__dataclass_fields__)
     if unknown:
         raise ValueError(f"qwen3_next_lm has no parameter(s) {sorted(unknown)}")
     config.setdefault("experts_held", config.get("num_experts", 8))
     return Qwen3NextLM(Qwen3NextConfig(
-        dtype=jnp.bfloat16 if use_bf16 else jnp.float32, **config
+        dtype=jnp.bfloat16 if use_bf16 else jnp.float32, mesh=mesh, **config
     ))
 
 

@@ -24,9 +24,10 @@ import pytest
 from elasticdl_tpu.layers.moe import (
     ROUTING_COLLECTION, RoutingLedger, SparseMoeBlock,
 )
-from elasticdl_tpu.ops import gqa
+from elasticdl_tpu.ops import gated_delta, gqa
 from elasticdl_tpu.ops.gated_delta import (
-    chunk_gated_delta_rule, gated_delta_rule_recurrent,
+    chunk_gated_delta_rule, chunk_gated_delta_rule_pallas,
+    chunk_gated_delta_rule_xla, gated_delta_rule_recurrent,
 )
 from model_zoo.qwen3_next import qwen3_next_lm as zoo
 
@@ -264,20 +265,26 @@ def test_the_cell_checks_precisions_the_reference_has():
         ref.forward({}, np.zeros((1, 4), np.int32), TINY, "float16")
 
 
-def _dot_precisions(jaxpr, found):
+def _dots(jaxpr, in_kernel=False):
     """Every `dot_general` of a jaxpr and of the jaxprs inside it ->
-    [(operand dtype, precision)]."""
+    (the equation, whether a `pallas_call` holds it)."""
     for eqn in jaxpr.eqns:
         if eqn.primitive.name == "dot_general":
-            found.append((
-                eqn.invars[0].aval.dtype, eqn.params["precision"],
-            ))
+            yield eqn, in_kernel
+        within = in_kernel or eqn.primitive.name == "pallas_call"
         for value in eqn.params.values():
             for inner in value if isinstance(value, (list, tuple)) else [value]:
                 inner = getattr(inner, "jaxpr", inner)
                 if hasattr(inner, "eqns"):
-                    _dot_precisions(inner, found)
-    return found
+                    yield from _dots(inner, within)
+
+
+def _dot_precisions(jaxpr):
+    """-> [(operand dtype, precision)] of every product."""
+    return [
+        (eqn.invars[0].aval.dtype, eqn.params["precision"])
+        for eqn, _ in _dots(jaxpr)
+    ]
 
 
 def test_float32_products_ask_for_their_precision():
@@ -293,7 +300,7 @@ def test_float32_products_ask_for_their_precision():
     found = _dot_precisions(
         jax.make_jaxpr(lambda v, t: module.apply(v, t))(
             variables, tokens
-        ).jaxpr, [],
+        ).jaxpr
     )
     float32 = [p for dtype, p in found if dtype == jnp.float32]
     assert float32 and len(float32) < len(found)
@@ -303,10 +310,42 @@ def test_float32_products_ask_for_their_precision():
     rule = _dot_precisions(
         jax.make_jaxpr(lambda *a: chunk_gated_delta_rule(*a))(
             *_delta_inputs(200, seed=0)
-        ).jaxpr, [],
+        ).jaxpr
     )
     assert len(rule) > 10
     assert all(p == (high, high) for _, p in rule)
+
+
+@pytest.mark.parametrize("passes", ["forward", "backward"])
+def test_kernel_products_are_three_bfloat16_passes(passes):
+    """The Pallas engine's own jaxprs (interpret mode on a CPU computes
+    in float32 whatever is asked, so no number here can say it): Mosaic
+    takes no `Precision.HIGH`, so every product in the kernels is HIGH
+    written out: bfloat16 operands, float32 accumulation, and the three
+    terms hi hi + hi lo + lo hi as one contraction of [hi | hi | lo]
+    with [hi | lo | hi], three times the product's own length; no
+    float32 operand reaches a product at any precision."""
+    inputs = _delta_inputs(200, seed=0, hk=1, hv=2, dk=128, dv=128)
+
+    def forward(*a):
+        return chunk_gated_delta_rule_pallas(*a, interpret=False)[0]
+
+    fn = forward if passes == "forward" else jax.grad(
+        lambda *a: jnp.sum(forward(*a)), argnums=range(5)
+    )
+    dots = list(_dots(jax.make_jaxpr(fn)(*inputs).jaxpr))
+    # 17 products a chunk forward; the backward kernel walks forward too
+    assert len(dots) >= (17 if passes == "forward" else 60)
+    for eqn, in_kernel in dots:
+        assert in_kernel  # outside its kernels the engine multiplies nothing
+        lhs, rhs = (var.aval for var in eqn.invars)
+        assert lhs.dtype == rhs.dtype == jnp.bfloat16, (lhs, rhs)
+        assert eqn.outvars[0].aval.dtype == jnp.float32
+        assert eqn.params["precision"] is None
+        (lhs_axes, rhs_axes), _ = eqn.params["dimension_numbers"]
+        contracted = lhs.shape[lhs_axes[0]]
+        assert contracted == rhs.shape[rhs_axes[0]]
+        assert contracted in (3 * 64, 3 * 128, 3 * 256), contracted
 
 
 # ---------------------------------------------------------------------------
@@ -361,6 +400,158 @@ def test_chunked_delta_rule_gradients_match_the_recurrence(t):
     )(*inputs)
     for name, g, w in zip("q k v g beta".split(), got, want):
         assert float(jnp.abs(g - w).max()) < 2e-5 * float(jnp.abs(w).max()), name
+
+
+# The Pallas engine (interpret mode here) at head sizes it takes: one
+# chunk, one group, a padded tail, several groups; one pair of value
+# heads a key head, two pairs of one key head (their q and k gradients
+# add up in the kernel), two key heads.  Its products are three bfloat16
+# passes, float32 to about 1e-5 of a product where the XLA engine on a
+# CPU is float32 itself.
+_KERNEL_CASES = [
+    (64, dict(b=1, hk=1, hv=2)),
+    (128, dict(b=2, hk=1, hv=2)),
+    (200, dict(b=2, hk=1, hv=2)),
+    (1100, dict(b=1, hk=1, hv=2)),
+    (200, dict(b=1, hk=1, hv=4)),
+    (200, dict(b=1, hk=2, hv=4)),
+]
+
+
+def _kernel(*inputs):
+    return chunk_gated_delta_rule_pallas(*inputs, interpret=True)
+
+
+@pytest.mark.parametrize("t,shape", _KERNEL_CASES)
+def test_delta_rule_kernel_matches_the_recurrence(t, shape):
+    inputs = _delta_inputs(t, seed=t, dk=128, dv=128, **shape)
+    want, want_state = _recurrent(*inputs)
+    xla, xla_state = chunk_gated_delta_rule_xla(*inputs)
+    got, got_state = jax.jit(_kernel)(*inputs)
+    scale = max(float(jnp.abs(want).max()), 1.0)
+    for other, other_state in ((want, want_state), (xla, xla_state)):
+        assert float(jnp.abs(got - other).max()) < 1e-5 * scale
+        assert float(jnp.abs(got_state - other_state).max()) < 2e-5
+
+
+@pytest.mark.parametrize("t,shape", _KERNEL_CASES)
+def test_delta_rule_kernel_gradients_match_the_recurrence(t, shape):
+    """All five gradients, through the outputs and the final state."""
+    inputs = _delta_inputs(t, seed=100 + t, dk=128, dv=128, **shape)
+    rng = np.random.default_rng(t)
+    weight = jnp.asarray(rng.normal(size=inputs[2].shape), jnp.float32)
+    state_weight = jnp.asarray(
+        rng.normal(size=(inputs[2].shape[0], inputs[2].shape[2], 128, 128)),
+        jnp.float32,
+    )
+
+    def grads(rule):
+        def total(*a):
+            out, state = rule(*a)
+            return jnp.sum(out * weight) + jnp.sum(state * state_weight)
+
+        return jax.jit(jax.grad(total, argnums=range(5)))(*inputs)
+
+    got = grads(_kernel)
+    for other in (grads(_recurrent), grads(chunk_gated_delta_rule_xla)):
+        for name, g, w in zip("q k v g beta".split(), got, other):
+            assert (
+                float(jnp.abs(g - w).max()) < 5e-5 * float(jnp.abs(w).max())
+            ), name
+
+
+def _cpu_mesh(data, model):
+    from elasticdl_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
+
+    return jax.sharding.Mesh(
+        np.asarray(jax.devices()[:data * model]).reshape(data, model),
+        (DATA_AXIS, MODEL_AXIS),
+    )
+
+
+@pytest.mark.parametrize("backend,devices,mesh,hk,hv,dk,engine,why", [
+    # the published shapes on one chip, the cell's case
+    ("tpu", 1, None, 16, 32, 128, "pallas", "one device"),
+    ("tpu", 4, (1, 1), 16, 32, 128, "pallas", "one device"),
+    # a mesh of several chips: the kernels a data shard a device
+    ("tpu", 4, (2, 2), 16, 32, 128, "pallas",
+     "under shard_map over {'data': 2, 'model': 2}"),
+    # several chips and no mesh named: the trace may be for all of them
+    ("tpu", 4, None, 16, 32, 128, "xla", "4 devices and no mesh given"),
+    # heads of 256: fewer of them a grid step, for VMEM
+    ("tpu", 1, None, 16, 32, 256, "pallas", "one device"),
+    # one key head's sixteen value heads of 256 do not fit in VMEM
+    ("tpu", 1, None, 1, 16, 256, "xla",
+     "head sizes or counts the kernels do not take"),
+    # a head is no whole lane tile
+    ("tpu", 1, None, 2, 4, 16, "xla",
+     "head sizes or counts the kernels do not take"),
+    # no two value heads a key head
+    ("tpu", 1, None, 2, 2, 128, "xla",
+     "head sizes or counts the kernels do not take"),
+    # interpret mode is for tests
+    ("cpu", 1, None, 16, 32, 128, "xla", "backend cpu"),
+])
+def test_delta_rule_engine_choice(backend, devices, mesh, hk, hv, dk, engine,
+                                  why, monkeypatch):
+    """On a TPU the kernels where `supports` holds and the trace is for
+    one device or names its mesh, the XLA form for every other shape,
+    for a trace that may be for several devices and off the TPU; the
+    worker's log line says which and why (traced only: shapes, no
+    device)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    monkeypatch.setattr(jax, "device_count", lambda: devices)
+    mesh = mesh and _cpu_mesh(*mesh)
+    t = 8192
+    qk = jax.ShapeDtypeStruct((2, t, hk, dk), jnp.float32)
+    v = jax.ShapeDtypeStruct((2, t, hv, dk), jnp.float32)
+    gate = jax.ShapeDtypeStruct((2, t, hv), jnp.float32)
+    lines = []
+    handler = logging.Handler()
+    handler.emit = lambda record: lines.append(record.getMessage())
+    gated_delta.logger.addHandler(handler)
+    try:
+        jaxpr = jax.make_jaxpr(  # a new function: no cached trace
+            lambda *a: chunk_gated_delta_rule(*a, mesh=mesh)
+        )(qk, qk, v, gate, gate)
+    finally:
+        gated_delta.logger.removeHandler(handler)
+    assert [aval.shape for aval in jaxpr.out_avals] == [
+        v.shape, (2, hv, dk, dk)
+    ]
+    assert lines == [
+        f"delta rule engine: {engine} chunk_gated_delta_rule "
+        f"T={t} Dk={dk} Dv={dk} ({why})"
+    ]
+    assert ("pallas_call" in str(jaxpr)) == (engine == "pallas")
+    assert ("shard_map" in str(jaxpr)) == why.startswith("under shard_map")
+
+
+@pytest.mark.parametrize("b,mesh", [(2, (2, 2)), (4, (4, 1)), (1, (2, 1))])
+def test_delta_rule_kernel_under_a_mesh_is_the_kernel(b, mesh):
+    """Under a mesh of several devices each pass runs inside a shard_map
+    over the data axis (a sequence or two a device; all of them on every
+    device where the axis does not divide the batch): outputs, final
+    state and all five gradients are the unmapped kernels' own."""
+    inputs = _delta_inputs(200, seed=7 + b, dk=128, dv=128, b=b, hk=1, hv=2)
+    rng = np.random.default_rng(b)
+    weight = jnp.asarray(rng.normal(size=inputs[2].shape), jnp.float32)
+
+    def run(mesh):
+        def total(*a):
+            out, state = chunk_gated_delta_rule_pallas(
+                *a, interpret=True, mesh=mesh
+            )
+            return jnp.sum(out * weight) + jnp.sum(state), (out, state)
+
+        return jax.jit(
+            jax.value_and_grad(total, argnums=range(5), has_aux=True)
+        )(*inputs)
+
+    (_, want), want_grads = run(None)
+    (_, got), got_grads = run(_cpu_mesh(*mesh))
+    for g, w in zip(got + got_grads, want + want_grads):
+        np.testing.assert_allclose(g, w, rtol=1e-6, atol=1e-6)
 
 
 def test_reference_delta_rule_is_the_written_recurrence():

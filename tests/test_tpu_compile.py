@@ -170,7 +170,35 @@ def _fused_apply(kind):
     ] + [(spec.packed_shape, jnp.float32)] * n_tables
 
 
+def _delta_rule(backward, d=128):
+    """The delta rule's kernels at the heads and the 2 x 8192 tokens of
+    `qwen3-next.train-synth-8k` (16 key and 32 value heads of 128), and
+    at heads of 256, where `supports` holds because fewer heads go into
+    a grid step (sixteen of them would need 144 MB of a v5e's 128)."""
+    from elasticdl_tpu.ops import gated_delta
+
+    assert gated_delta.supports(d, d, 16, 32)
+
+    def fwd(*args):
+        return gated_delta.chunk_gated_delta_rule_pallas(
+            *args, interpret=False
+        )
+
+    def bwd(*args):
+        return jax.grad(
+            lambda *a: jnp.sum(fwd(*a)[0]), argnums=range(5)
+        )(*args)
+
+    qk, v, gate = (2, 8192, 16, d), (2, 8192, 32, d), (2, 8192, 32)
+    return bwd if backward else fwd, [
+        (shape, jnp.float32) for shape in (qk, qk, v, gate, gate)
+    ]
+
+
 _CASES = {
+    "delta_rule_fwd": functools.partial(_delta_rule, False),
+    "delta_rule_bwd": functools.partial(_delta_rule, True),
+    "delta_rule_bwd_d256": functools.partial(_delta_rule, True, 256),
     "flash_fwd_d64": functools.partial(_flash_fwd, 64),
     "flash_fwd_d128": functools.partial(_flash_fwd, 128),
     "flash_bwd_d64": functools.partial(_flash_bwd, 64),
@@ -200,7 +228,10 @@ def test_kernel_compiles_for_v5e(topo, case):
 
 # The hybrid expert model's sublayers (model_zoo/qwen3_next) at the
 # widths and the 2 x 8192 tokens of `qwen3-next.train-synth-8k`: XLA ops
-# only, so what the compile shows is that forward and backward FIT, with
+# (`gdn_pallas`: the DeltaNet sublayer as a TPU backend traces it, its
+# rule in the Pallas kernels; a described device leaves
+# `jax.default_backend()` at the CPU, so the test says "tpu" for it),
+# so what the compile shows is that forward and backward FIT, with
 # the temporaries that decided their form (the whole-sequence delta rule
 # needed 10.2 GB where the grouped scan needs 6.6 with float32 projection
 # results; independent rematerialised query blocks 11.3 GB where the
@@ -208,13 +239,14 @@ def test_kernel_compiles_for_v5e(topo, case):
 _HYBRID_TOKENS = (2, 8192, 2048)
 
 
-def _hybrid_sublayer(kind):
+def _hybrid_sublayer(kind, mesh=None):
     from elasticdl_tpu.layers.moe import SparseMoeBlock
     from model_zoo.qwen3_next import qwen3_next_lm as zoo
 
     bf16 = jnp.bfloat16
-    if kind == "gdn":
-        return zoo.GatedDeltaNet(16, 32, 128, 128, 4, 1e-6, bf16), bf16, 7.5
+    if kind in ("gdn", "gdn_pallas"):
+        return (zoo.GatedDeltaNet(16, 32, 128, 128, 4, 1e-6, bf16, mesh),
+                bf16, 7.5)
     if kind == "attn":
         return (zoo.GatedAttention(16, 2, 256, 64, 1e7, 1e-6, bf16, "xla"),
                 bf16, 2.0)
@@ -222,10 +254,9 @@ def _hybrid_sublayer(kind):
             jnp.float32, 1.0)
 
 
-@pytest.mark.parametrize("kind", ["gdn", "attn", "moe"])
-def test_hybrid_sublayer_compiles_and_fits_for_v5e(topo, kind):
-    module, dtype, temp_gb = _hybrid_sublayer(kind)
-    one_chip = SingleDeviceSharding(topo.devices[0])
+def _sublayer_fwd_bwd(module, dtype, weights, tokens):
+    """The sublayer's forward and backward compiled for the described
+    device(s) the two shardings name."""
     variables = jax.eval_shape(
         lambda: module.init(
             jax.random.PRNGKey(0), jnp.zeros(_HYBRID_TOKENS, dtype)
@@ -240,23 +271,54 @@ def test_hybrid_sublayer_compiles_and_fits_for_v5e(topo, kind):
 
         return jax.grad(total, argnums=(0, 1))(variables["params"], x)
 
-    def on_chip(tree):
-        return jax.tree.map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
-            tree,
-        )
-
-    compiled = jax.jit(fwd_bwd).lower(
-        on_chip(variables),
-        jax.ShapeDtypeStruct(_HYBRID_TOKENS, dtype, sharding=one_chip),
+    return jax.jit(fwd_bwd).lower(
+        jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=weights),
+            variables,
+        ),
+        jax.ShapeDtypeStruct(_HYBRID_TOKENS, dtype, sharding=tokens),
     ).compile()
+
+
+@pytest.mark.parametrize("kind", ["gdn", "gdn_pallas", "attn", "moe"])
+def test_hybrid_sublayer_compiles_and_fits_for_v5e(topo, kind, monkeypatch):
+    module, dtype, temp_gb = _hybrid_sublayer(kind)
+    if kind == "gdn_pallas":
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        monkeypatch.setattr(jax, "device_count", lambda: 1)
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    compiled = _sublayer_fwd_bwd(module, dtype, one_chip, one_chip)
     assert compiled.memory_analysis().temp_size_in_bytes < temp_gb * 1e9
+    assert ("delta_rule_bwd" in compiled.as_text()) == (kind == "gdn_pallas")
 
 
 def _four_chip_mesh(topo):
     return jax.sharding.Mesh(
         np.asarray(topo.devices).reshape(2, 2), (DATA_AXIS, MODEL_AXIS)
     )
+
+
+@pytest.mark.parametrize("names_mesh", [True, False])
+def test_delta_rule_sublayer_compiles_on_four_chip_mesh(
+    topo, names_mesh, monkeypatch
+):
+    """The DeltaNet sublayer as `dp_trainer` compiles it on a four-chip
+    host: weights on every chip, the two sequences split over `data`.
+    A Mosaic kernel cannot be partitioned automatically, so the model
+    hands the rule the job's mesh and the kernels run a sequence a
+    device under a shard_map; a trace that names no mesh keeps the XLA
+    engine, which compiles for the four as it did before the kernels."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "device_count", lambda: 4)
+    mesh = _four_chip_mesh(topo)
+    module, dtype, _ = _hybrid_sublayer(
+        "gdn_pallas", mesh if names_mesh else None
+    )
+    compiled = _sublayer_fwd_bwd(
+        module, dtype, NamedSharding(mesh, P()),
+        NamedSharding(mesh, P(DATA_AXIS)),
+    )
+    assert ("delta_rule_bwd" in compiled.as_text()) == names_mesh
 
 
 @pytest.mark.parametrize("kernel", ["lookup", "lookup_fm", "apply_adam"])
