@@ -7,12 +7,38 @@ tokens routed to them, and adds what every chip computes alike (the
 shared expert).  `SparseMoeBlock` is that layer for one chip: it is told
 which experts it holds (`held = (first, count)`) and adds nothing that
 stands in for the absent chips or their exchange.  With
-`held = (0, num_experts)` it is the whole layer.
+`held = (0, num_experts)` it is the whole layer.  Two architectures, by
+the block's one field `kind` (a router and its experts go together; no
+other pairing exists):
 
+    kind="softmax_gated_silu"                          (model_zoo/qwen3_next)
     p = softmax(W_r x)  over all experts, float32
     top-k of p, weights divided by their sum (over all k, held or not)
     y = sum_k w_k * W_down,k (silu(W_gate,k x) * W_up,k x)   held k only
-      + sigmoid(w_sg . x) * shared(x)
+      + sigmoid(w_sg . x) * shared(x)       shared: the same gated form
+
+    kind="sigmoid_relu2"                               (model_zoo/nemotron_h)
+    s = sigmoid(W_r x)  over all experts, float32
+    top-k of s + b (a selection bias: it chooses, and is in no weight)
+    w = s at the chosen, divided by their sum, times `routed_scale`
+    y = sum_k w_k * W_down,k relu(W_up,k x)^2                held k only
+      + W_down,s relu(W_up,s x)^2           the shared expert, ungated
+
+Both run the SAME block plan, loop, counters and scopes; the expert form
+is two or three stacked weight tensors handed to one loop.
+
+**The selection bias balances the load, and no loss does.**  `b` is in no
+weight, so the loss has no gradient for it.  The source moves it by the
+rule of DeepSeek-V3's router, whose `gate` this is (auxiliary-loss-free
+balancing, arXiv:2408.15664): after a step, `b_e <- b_e + rate` for an
+expert that was chosen less often than the mean of all experts and
+`- rate` for one chosen more often.  `_load_violation` hands the
+optimizer exactly that: in the backward pass the bias receives
+`sign(chosen_e - mean)` over ALL experts (the router counts its own
+top-k, held or not) IN PLACE OF a gradient, so plain gradient descent at
+`rate` on this one parameter IS the rule (`model_zoo/nemotron_h`'s
+`optimizer` does that and keeps AdamW off it).  Under data parallelism
+the counts are the global batch's, as every chip's router must agree.
 
 **No pair is dropped, whatever the imbalance.**  A capacity bound would
 make shapes static by dropping; a dense product over a worst-case
@@ -21,7 +47,7 @@ holds the experts.  Here the (token, expert) pairs of held experts are
 sorted by expert and cut into blocks of `block_rows` rows, each block one
 expert's; a loop whose trip count is the number of blocks the routing
 NEEDED (a device-side `while`, not a static bound) gathers a block's
-rows, runs the expert's three products and scatter-adds the weighted
+rows, runs the expert's products and scatter-adds the weighted
 result.  Work is the pairs held, rounded up to a block an expert.  Sort
 was chosen over `lax.ragged_dot` because the latter needs a static row
 count, which without dropping is tokens x k.  A dynamic trip count has
@@ -74,37 +100,69 @@ def _block_rows(j, plan, block: int):
     return expert, plan["order"][at], valid
 
 
-def _expert_forward(xb, w_gate, w_up, w_down, dtype):
-    gate = jnp.dot(xb, w_gate, preferred_element_type=jnp.float32)
-    up = jnp.dot(xb, w_up, preferred_element_type=jnp.float32)
-    hidden = (jax.nn.silu(gate) * up).astype(dtype)
-    return gate, up, hidden, jnp.dot(
-        hidden, w_down, preferred_element_type=jnp.float32
-    )
+def _expert_forward(xb, weights, dtype):
+    """A block's rows through ONE expert.  `weights` says the form: three
+    tensors (gate, up, down) are `down(silu(gate x) * up x)`, two (up,
+    down) are `down(relu(up x)^2)`.  -> (what the backward needs of the
+    first products, hidden in `dtype`, y float32)."""
+    f32 = dict(preferred_element_type=jnp.float32)
+    if len(weights) == 3:
+        w_gate, w_up, w_down = weights
+        gate = jnp.dot(xb, w_gate, **f32)
+        up = jnp.dot(xb, w_up, **f32)
+        saved, hidden = (gate, up), (jax.nn.silu(gate) * up).astype(dtype)
+    else:
+        w_up, w_down = weights
+        up = jnp.dot(xb, w_up, **f32)
+        saved, hidden = (up,), jnp.square(jax.nn.relu(up)).astype(dtype)
+    return saved, hidden, jnp.dot(hidden, w_down, **f32)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(6, 7))
-def grouped_expert_mlp(x, w_gate, w_up, w_down, pair_weight, plan,
-                       top_k: int, block: int):
-    """x [N, d] in the compute dtype; w_* [held, ...] float32;
+def _expert_backward(xb, weights, saved, hidden, dyb, dtype):
+    """dyb [block, d] in `dtype`, already weighted -> (dx float32, the
+    weights' gradients in their order, float32)."""
+    f32 = dict(preferred_element_type=jnp.float32)
+    dhidden = jnp.dot(dyb, weights[-1].T, **f32)
+    if len(weights) == 3:
+        gate, up = saved
+        sig = jax.nn.sigmoid(gate)
+        dup = (dhidden * gate * sig).astype(dtype)
+        dgate = (
+            dhidden * up * sig * (1.0 + gate * (1.0 - sig))
+        ).astype(dtype)
+        dxb = jnp.dot(dgate, weights[0].T, **f32) + jnp.dot(
+            dup, weights[1].T, **f32
+        )
+        first = (jnp.dot(xb.T, dgate, **f32), jnp.dot(xb.T, dup, **f32))
+    else:
+        (up,) = saved
+        dup = (dhidden * 2.0 * jax.nn.relu(up)).astype(dtype)
+        dxb = jnp.dot(dup, weights[0].T, **f32)
+        first = (jnp.dot(xb.T, dup, **f32),)
+    return dxb, first + (jnp.dot(hidden.T, dyb, **f32),)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def grouped_expert_mlp(x, weights, pair_weight, plan, top_k: int, block: int):
+    """x [N, d] in the compute dtype; weights: the held experts' stacked
+    float32 tensors, (gate, up, down) or (up, down) (`_expert_forward`);
     pair_weight [N * top_k] float32; plan from `_block_plan`.
     -> (y [N, d] float32, rows processed (float32 scalar))."""
-    out, _ = _grouped_fwd(x, w_gate, w_up, w_down, pair_weight, plan,
-                          top_k, block)
+    out, _ = _grouped_fwd(x, weights, pair_weight, plan, top_k, block)
     return out
 
 
-def _grouped_fwd(x, w_gate, w_up, w_down, pair_weight, plan, top_k, block):
+def _grouped_fwd(x, weights, pair_weight, plan, top_k, block):
     dtype = x.dtype
     with jax.named_scope("moe_experts"):
-        wg, wu, wd = (w.astype(dtype) for w in (w_gate, w_up, w_down))
+        cast = tuple(w.astype(dtype) for w in weights)
 
         def body(j, carry):
             y, rows = carry
             expert, pair, valid = _block_rows(j, plan, block)
             token = pair // top_k
-            _, _, _, yb = _expert_forward(
-                x[token], wg[expert], wu[expert], wd[expert], dtype
+            _, _, yb = _expert_forward(
+                x[token], tuple(w[expert] for w in cast), dtype
             )
             weight = jnp.where(valid, pair_weight[pair], 0.0)
             return (
@@ -116,25 +174,24 @@ def _grouped_fwd(x, w_gate, w_up, w_down, pair_weight, plan, top_k, block):
             0, plan["block_end"][-1], body,
             (jnp.zeros(x.shape, jnp.float32), jnp.float32(0.0)),
         )
-    return (y, rows), (x, w_gate, w_up, w_down, pair_weight, plan)
+    return (y, rows), (x, weights, pair_weight, plan)
 
 
 def _grouped_bwd(top_k, block, residuals, cotangent):
-    x, w_gate, w_up, w_down, pair_weight, plan = residuals
+    x, weights, pair_weight, plan = residuals
     dy, _ = cotangent
     dtype = x.dtype
     n_pairs = pair_weight.shape[0]
     with jax.named_scope("moe_experts"):
-        wg, wu, wd = (w.astype(dtype) for w in (w_gate, w_up, w_down))
+        cast = tuple(w.astype(dtype) for w in weights)
 
         def body(j, carry):
-            dx, dwg, dwu, dwd, dweight = carry
+            dx, dweights, dweight = carry
             expert, pair, valid = _block_rows(j, plan, block)
             token = pair // top_k
             xb = x[token]
-            gate, up, hidden, yb = _expert_forward(
-                xb, wg[expert], wu[expert], wd[expert], dtype
-            )
+            mine = tuple(w[expert] for w in cast)
+            saved, hidden, yb = _expert_forward(xb, mine, dtype)
             dyb = dy[token]
             weight = jnp.where(valid, pair_weight[pair], 0.0)
             dweight = dweight.at[jnp.where(valid, pair, n_pairs)].set(
@@ -143,43 +200,39 @@ def _grouped_bwd(top_k, block, residuals, cotangent):
             # Rows that are not real carry weight 0: everything below
             # is 0 for them.
             dyb = (weight[:, None] * dyb).astype(dtype)
-            dhidden = jnp.dot(
-                dyb, wd[expert].T, preferred_element_type=jnp.float32
-            )
-            sig = jax.nn.sigmoid(gate)
-            dup = (dhidden * gate * sig).astype(dtype)
-            dgate = (
-                dhidden * up * sig * (1.0 + gate * (1.0 - sig))
-            ).astype(dtype)
-            dxb = jnp.dot(
-                dgate, wg[expert].T, preferred_element_type=jnp.float32
-            ) + jnp.dot(dup, wu[expert].T, preferred_element_type=jnp.float32)
-            f32 = dict(preferred_element_type=jnp.float32)
+            dxb, dmine = _expert_backward(xb, mine, saved, hidden, dyb, dtype)
             return (
                 dx.at[token].add(dxb),
-                dwg.at[expert].add(jnp.dot(xb.T, dgate, **f32)),
-                dwu.at[expert].add(jnp.dot(xb.T, dup, **f32)),
-                dwd.at[expert].add(jnp.dot(hidden.T, dyb, **f32)),
+                tuple(dw.at[expert].add(d) for dw, d in zip(dweights, dmine)),
                 dweight,
             )
 
-        dx, dwg, dwu, dwd, dweight = jax.lax.fori_loop(
+        dx, dweights, dweight = jax.lax.fori_loop(
             0, plan["block_end"][-1], body,
             (
                 jnp.zeros(x.shape, jnp.float32),
-                jnp.zeros(w_gate.shape, jnp.float32),
-                jnp.zeros(w_up.shape, jnp.float32),
-                jnp.zeros(w_down.shape, jnp.float32),
+                tuple(jnp.zeros(w.shape, jnp.float32) for w in weights),
                 jnp.zeros((n_pairs,), jnp.float32),
             ),
         )
     no_plan = jax.tree.map(
         lambda a: np.zeros(a.shape, jax.dtypes.float0), plan
     )
-    return dx.astype(dtype), dwg, dwu, dwd, dweight, no_plan
+    return dx.astype(dtype), dweights, dweight, no_plan
 
 
 grouped_expert_mlp.defvjp(_grouped_fwd, _grouped_bwd)
+
+
+def _dense(dtype):
+    """The shared experts' projections: no bias, operands in `dtype`,
+    float32 results."""
+    return partial(
+        nn.Dense, use_bias=False, dtype=dtype,
+        dot_general=partial(
+            jax.lax.dot_general, preferred_element_type=jnp.float32
+        ),
+    )
 
 
 class GatedMLP(nn.Module):
@@ -191,16 +244,61 @@ class GatedMLP(nn.Module):
 
     @nn.compact
     def __call__(self, x):
-        dense = partial(
-            nn.Dense, use_bias=False, dtype=self.dtype,
-            dot_general=partial(
-                jax.lax.dot_general, preferred_element_type=jnp.float32
-            ),
-        )
+        dense = _dense(self.dtype)
         hidden = nn.silu(dense(self.width, name="gate_proj")(x)) * dense(
             self.width, name="up_proj"
         )(x)
         return dense(x.shape[-1], name="down_proj")(hidden)
+
+
+class Relu2MLP(nn.Module):
+    """down(relu(up x)^2), no biases (the ungated shared expert);
+    operands in `dtype`, float32 results."""
+
+    width: int
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        dense = _dense(self.dtype)
+        hidden = jnp.square(nn.relu(dense(self.width, name="up_proj")(x)))
+        return dense(x.shape[-1], name="down_proj")(hidden)
+
+
+@jax.custom_vjp
+def _load_violation(weight, select_bias, violation):
+    """`weight`, unchanged.  In the backward pass the selection bias
+    receives `violation` (sign(times chosen - mean), over all experts) in
+    place of a gradient: what the balancing rule descends (module
+    docstring)."""
+    return weight
+
+
+def _load_violation_fwd(weight, select_bias, violation):
+    return weight, violation
+
+
+def _load_violation_bwd(violation, cotangent):
+    return cotangent, violation, jnp.zeros_like(violation)
+
+
+_load_violation.defvjp(_load_violation_fwd, _load_violation_bwd)
+
+
+class _BiasedGate(nn.Module):
+    """The source's `gate` of a sigmoid router: its `weight` and the
+    `e_score_correction_bias` that takes part in the selection only."""
+
+    num_experts: int
+
+    @nn.compact
+    def __call__(self, d: int):
+        return (
+            self.param("weight", nn.initializers.lecun_normal(),
+                       (d, self.num_experts), jnp.float32),
+            self.param("e_score_correction_bias", nn.initializers.zeros_init(),
+                       (self.num_experts,), jnp.float32),
+        )
 
 
 class SparseMoeBlock(nn.Module):
@@ -212,6 +310,11 @@ class SparseMoeBlock(nn.Module):
     norm_topk_prob: bool = True
     dtype: Any = jnp.bfloat16
     block_rows: int = 128
+    # The two architectures (module docstring): a softmax router with
+    # gated-SiLU experts, or a sigmoid router (a selection bias, a scale
+    # on the weights) with relu^2 experts and an ungated shared expert.
+    kind: str = "softmax_gated_silu"
+    routed_scale: float = 1.0
 
     @nn.compact
     def __call__(self, x):
@@ -221,6 +324,9 @@ class SparseMoeBlock(nn.Module):
             raise ValueError(
                 f"held={self.held} is no range of {self.num_experts} experts"
             )
+        if self.kind not in ("softmax_gated_silu", "sigmoid_relu2"):
+            raise ValueError(f"no expert layer of kind {self.kind!r}")
+        gated = self.kind == "softmax_gated_silu"
         shape, d = x.shape, x.shape[-1]
         x = x.reshape(-1, d)
         n = x.shape[0]
@@ -230,43 +336,69 @@ class SparseMoeBlock(nn.Module):
             batch_axis=(0,),
         )
         width = self.expert_width
-        w_gate = self.param(
-            "experts_gate_proj", expert_init, (n_held, d, width), jnp.float32)
-        w_up = self.param(
-            "experts_up_proj", expert_init, (n_held, d, width), jnp.float32)
-        w_down = self.param(
-            "experts_down_proj", expert_init, (n_held, width, d), jnp.float32)
+        weights = tuple(
+            self.param(f"experts_{name}", expert_init, (n_held,) + dims,
+                       jnp.float32)
+            for name, dims in (
+                [("gate_proj", (d, width))] if gated else []
+            ) + [("up_proj", (d, width)), ("down_proj", (width, d))]
+        )
         with jax.named_scope("moe_route"):
-            router = self.param("gate", init, (d, self.num_experts),
-                                jnp.float32)
+            if gated:
+                router = self.param("gate", init, (d, self.num_experts),
+                                    jnp.float32)
+            else:
+                router, select_bias = _BiasedGate(
+                    self.num_experts, name="gate"
+                )(d)
             logits = jnp.dot(
                 x.astype(jnp.float32), router,
                 precision=jax.lax.Precision.HIGHEST,
             )
-            weight, expert = jax.lax.top_k(
-                jax.nn.softmax(logits, axis=-1), self.top_k
-            )
+            if gated:
+                weight, expert = jax.lax.top_k(
+                    jax.nn.softmax(logits, axis=-1), self.top_k
+                )
+            else:
+                scores = jax.nn.sigmoid(logits)
+                _, expert = jax.lax.top_k(scores + select_bias, self.top_k)
+                weight = jnp.take_along_axis(scores, expert, axis=-1)
+                chosen = jnp.bincount(
+                    expert.reshape(-1), length=self.num_experts
+                ).astype(jnp.float32)
+                weight = _load_violation(
+                    weight, select_bias, jnp.sign(chosen - jnp.mean(chosen))
+                )
             if self.norm_topk_prob:
                 weight = weight / jnp.sum(weight, axis=-1, keepdims=True)
+            if self.routed_scale != 1.0:
+                weight = weight * self.routed_scale
             is_held = (expert >= first) & (expert < first + n_held)
             local = jnp.where(is_held, expert - first, n_held).reshape(-1)
             plan = _block_plan(local.astype(jnp.int32), n_held,
                                self.block_rows)
         y, rows = grouped_expert_mlp(
-            x.astype(self.dtype), w_gate, w_up, w_down, weight.reshape(-1),
+            x.astype(self.dtype), weights, weight.reshape(-1),
             plan, self.top_k, self.block_rows,
         )
         with jax.named_scope("moe_shared"):
-            shared = GatedMLP(self.shared_width, self.dtype,
-                              name="shared_expert")(x)
-            shared_gate = self.param("shared_expert_gate", init, (d, 1),
-                                     jnp.float32)
-            # A block's product like the expert's own: operands in
-            # `dtype`, written out so that no backend's default decides.
-            y = y + jax.nn.sigmoid(jnp.dot(
-                x.astype(self.dtype), shared_gate.astype(self.dtype),
-                preferred_element_type=jnp.float32,
-            )) * shared
+            if gated:
+                shared = GatedMLP(
+                    self.shared_width, self.dtype, name="shared_expert"
+                )(x)
+                shared_gate = self.param("shared_expert_gate", init, (d, 1),
+                                         jnp.float32)
+                # A block's product like the expert's own: operands in
+                # `dtype`, written out so that no backend's default decides.
+                shared = jax.nn.sigmoid(jnp.dot(
+                    x.astype(self.dtype), shared_gate.astype(self.dtype),
+                    preferred_element_type=jnp.float32,
+                )) * shared
+            else:
+                shared = Relu2MLP(
+                    self.shared_width, self.dtype, name="shared_experts"
+                )(x)
+            y = y + shared
         self._count(is_held, rows, plan["counts"], n_held)
         return y.reshape(shape)
 

@@ -130,6 +130,7 @@ def test_every_emitted_name_is_in_the_vocabulary():
         "fwd_bwd", "dense_update", "sparse_apply", "grad_accumulate",
         "sparse_adam", "attn", "mlp", "lm_head_loss", "optimizer",
         "gdn", "gdn_scan", "moe", "moe_route", "moe_experts", "moe_shared",
+        "ssm", "ssm_scan",
     }
 
 
@@ -568,11 +569,40 @@ def _hybrid_window(seed=0):
     return trainer, trainer.stage_window([batch, batch])
 
 
+def _state_space_window(seed=0):
+    """(trainer, staged window) of a tiny Nemotron-H on the dp trainer,
+    each layer rematerialised as the benchmark's configuration runs it."""
+    sys.path.insert(0, REPO_ROOT)
+    from model_zoo.nemotron_h import nemotron_h_lm as zoo
+
+    from elasticdl_tpu.parallel import MeshConfig, build_mesh
+    from elasticdl_tpu.parallel.dp_trainer import DataParallelTrainer
+
+    model = zoo.custom_model(
+        vocab_size=64, hidden_size=32, hybrid_override_pattern="ME*",
+        mamba_head_dim=8, ssm_state_size=8, chunk_size=8, head_dim=8,
+        moe_intermediate_size=16, moe_shared_expert_intermediate_size=16,
+        experts_first=2, experts_held=4, remat=True,
+    )
+    trainer = DataParallelTrainer(
+        model=model, loss_fn=zoo.loss, optimizer=zoo.optimizer(),
+        mesh=build_mesh(MeshConfig()),
+    )
+    rng = np.random.RandomState(seed)
+    tokens = rng.randint(0, 64, size=(8, 16)).astype(np.int32)
+    trainer.ensure_initialized(tokens)
+    batch = (tokens, tokens, np.ones((8,), np.float32))
+    return trainer, trainer.stage_window([batch, batch])
+
+
 @pytest.mark.parametrize("build,jit_attr,scopes", [
     (_dense_window, "_train_window_jit",
      ("fwd_bwd", "attn", "mlp", "lm_head_loss", "optimizer")),
     (_hybrid_window, "_train_window_jit",
      ("fwd_bwd", "gdn", "gdn_scan", "attn", "moe", "moe_route",
+      "moe_experts", "moe_shared", "lm_head_loss", "optimizer")),
+    (_state_space_window, "_train_window_jit",
+     ("fwd_bwd", "ssm", "ssm_scan", "attn", "moe", "moe_route",
       "moe_experts", "moe_shared", "lm_head_loss", "optimizer")),
     (_sparse_window, "_train_window",
      ("fwd_bwd", "dense_update", "sparse_apply", "grad_accumulate",

@@ -292,6 +292,86 @@ def test_hybrid_sublayer_compiles_and_fits_for_v5e(topo, kind, monkeypatch):
     assert ("delta_rule_bwd" in compiled.as_text()) == (kind == "gdn_pallas")
 
 
+# The state-space hybrid (model_zoo/nemotron_h) at the widths and the
+# 1 x 8192 tokens of `nemotron3-nano.train-synth-8k`.  The Mamba-2
+# sublayer is XLA ops: what its compile shows is that forward and
+# backward fit, the decays of 64 heads x 64 chunks ([128, 128] float32
+# each, 268 MB) among the temporaries.  The whole two-step window program
+# is what the worker runs: 8.0 GB of state donated and 3.25 GB of
+# temporaries with each layer rematerialised (at 2 x 8192 it needs
+# 16.8 GB and does not fit), the attention layer in the Pallas kernel
+# exactly at `supports`' cap (K + V of a head are 8 MiB of float32).
+_NEMOTRON = dict(
+    vocab_size=16384, hidden_size=2688, hybrid_override_pattern="MEMEM*EME",
+    mamba_num_heads=64, mamba_head_dim=64, n_groups=8, ssm_state_size=128,
+    conv_kernel=4, chunk_size=128, num_attention_heads=32,
+    num_key_value_heads=2, head_dim=128, n_routed_experts=128,
+    num_experts_per_tok=6, moe_intermediate_size=1856,
+    moe_shared_expert_intermediate_size=3712, experts_first=56,
+    experts_held=8, remat=True,
+)
+
+
+def test_mamba2_sublayer_compiles_and_fits_for_v5e(topo):
+    from model_zoo.nemotron_h import nemotron_h_lm as zoo
+
+    module = zoo.Mamba2Mixer(64, 64, 8, 128, 4, 128, 1e-5, jnp.bfloat16)
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    x = jax.ShapeDtypeStruct((1, 8192, 2688), jnp.float32, sharding=one_chip)
+    variables = jax.eval_shape(
+        lambda: module.init(jax.random.PRNGKey(0), jnp.zeros(x.shape, x.dtype))
+    )
+
+    def fwd_bwd(variables, x):
+        return jax.grad(
+            lambda p, x: jnp.sum(module.apply({"params": p}, x)), (0, 1)
+        )(variables["params"], x)
+
+    compiled = jax.jit(fwd_bwd).lower(
+        jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+            variables,
+        ), x,
+    ).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 3.0e9  # 2.34
+
+
+def test_nemotron_window_program_compiles_and_fits_for_v5e(topo, monkeypatch):
+    """`dp_trainer`'s two-step window program as the worker compiles it
+    for the cell (a described device leaves `jax.default_backend()` at the
+    CPU, so the test says "tpu" for the attention engine's choice)."""
+    from elasticdl_tpu.parallel import MeshConfig, build_mesh
+    from elasticdl_tpu.parallel.dp_trainer import DataParallelTrainer
+    from model_zoo.nemotron_h import nemotron_h_lm as zoo
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = build_mesh(MeshConfig(data=1, model=1), devices=topo.devices[:1])
+    trainer = DataParallelTrainer(
+        zoo.custom_model(use_bf16=True, **_NEMOTRON), zoo.loss,
+        zoo.optimizer(), mesh,
+    )
+    on_chip = NamedSharding(mesh, P())
+    state, _ = jax.eval_shape(
+        lambda: trainer._make_state(
+            jax.random.PRNGKey(0), jnp.zeros((1, 8192), jnp.int32)
+        )
+    )
+    state = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=on_chip),
+        state,
+    )
+    window = jax.ShapeDtypeStruct((2, 1, 8192), jnp.int32, sharding=on_chip)
+    mask = jax.ShapeDtypeStruct((2, 1), jnp.float32, sharding=on_chip)
+    compiled = jax.jit(
+        trainer._train_window_impl, donate_argnums=(0,)
+    ).lower(state, window, window, mask).compile()
+    memory = compiled.memory_analysis()
+    assert 8.0e9 < memory.argument_size_in_bytes < 8.01e9  # 12 B x 667M
+    assert memory.alias_size_in_bytes > 8.0e9              # donated
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 12.5e9
+    assert "tpu_custom_call" in compiled.as_text()         # the flash kernel
+
+
 def _four_chip_mesh(topo):
     return jax.sharding.Mesh(
         np.asarray(topo.devices).reshape(2, 2), (DATA_AXIS, MODEL_AXIS)
