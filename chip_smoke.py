@@ -12,8 +12,9 @@ strict apply; weights random from --seed, data written from --seed):
 
   kernels      every Pallas kernel a user flag reaches, once, against its
                XLA twin (fused sparse lookup / lookup+FM / dedup+apply,
-               flash attention fwd+bwd, the ring step) and the delta
-               rule's kernel pair, which the backend picks — correctness only
+               flash attention fwd+bwd, the ring step), the delta
+               rule's kernel pair and the two passes around it
+               (ops/gdn_passes.py), which the backend picks — correctness only
   train        `python -m elasticdl_tpu.client.main train` with
                ParameterServerStrategy on an ETRF file: master -> task
                dispatch -> one worker subprocess -> file -> native codec
@@ -616,6 +617,42 @@ def phase_kernels(args) -> dict:
         scale = float(jnp.max(jnp.abs(w)))
         check(f"delta rule bwd d{which} ~ xla engine", g, w,
               1e-3, 1e-3 * scale, secs)
+
+    # -- what surrounds the rule, one pass each way, against the plain
+    # jax.numpy chain: float32 elementwise on both sides, so 1e-5 ----------
+    from elasticdl_tpu.ops import gdn_passes
+
+    keys = jax.random.split(jax.random.PRNGKey(args.seed + 13), 5)
+    rows, gate, d_out = (
+        jax.random.normal(k, (2, t_rule, 4 * 128)) for k in keys[:3]
+    )
+    taps = jax.random.normal(keys[3], (4, 4 * 128))
+    weight = jax.random.normal(keys[4], (128,))
+
+    def pass_grads(pallas):
+        def loss(rows, taps, gate, weight):
+            mixed = gdn_passes.conv_silu(
+                rows, taps, head=128, scale=128 ** -0.5, pallas=pallas
+            )
+            out = gdn_passes.gated_rms_norm(
+                rows + mixed, gate, weight, pallas=pallas
+            )
+            return jnp.sum(out * d_out), (mixed, out)
+
+        return jax.grad(loss, argnums=range(4), has_aux=True)
+
+    (got_g, got_out), secs = run(
+        "gdn passes", pass_grads(True), rows, taps, gate, weight
+    )
+    want_g, want_out = twin(pass_grads(False), rows, taps, gate, weight)
+    for g, w, which in zip(
+        got_out + got_g, want_out + want_g,
+        ("conv_silu", "gated_rms_norm", "d rows", "d taps", "d gate",
+         "d weight"),
+    ):
+        scale = max(float(jnp.max(jnp.abs(w))), 1.0)
+        check(f"gdn passes {which} ~ jax.numpy chain", g, w,
+              1e-5, 1e-5 * scale, secs)
 
     return {"device": device, "checks": len(checks)}
 

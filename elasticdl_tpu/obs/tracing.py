@@ -148,14 +148,14 @@ SPAN_NAMES: Dict[str, str] = {
 #: PS trainer: fwd_bwd, dense_update, sparse_apply > (grad_accumulate,
 #: sparse_adam).  Dense trainer: fwd_bwd > (attn, mlp, lm_head_loss),
 #: optimizer; with the hybrid expert model (model_zoo/qwen3_next) fwd_bwd
-#: > (gdn > gdn_scan, attn, moe > (moe_route, moe_experts, moe_shared),
-#: lm_head_loss); with the state-space hybrid (model_zoo/nemotron_h)
+#: > (gdn > (gdn_mix, gdn_scan), attn, moe > (moe_route, moe_experts,
+#: moe_shared), lm_head_loss); with the state-space hybrid (model_zoo/nemotron_h)
 #: fwd_bwd > (ssm > ssm_scan, attn, moe > (...), lm_head_loss).
 DEVICE_SCOPES = (
     "fwd_bwd", "dense_update", "sparse_apply", "grad_accumulate",
     "sparse_adam", "attn", "mlp", "lm_head_loss", "optimizer",
     "gdn", "gdn_scan", "moe", "moe_route", "moe_experts", "moe_shared",
-    "ssm", "ssm_scan",
+    "ssm", "ssm_scan", "gdn_mix",
 )
 
 #: Size bound on the flight recorder's final registry snapshot: the

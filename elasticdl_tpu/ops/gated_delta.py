@@ -99,7 +99,11 @@ jit(_backward_call)/delta_rule_bwd` in the compiled program's metadata).
 
 Shapes: q, k [B, T, Hk, Dk]; v [B, T, H, Dv]; g, beta [B, T, H], with
 Hk = H or a divisor of it (key head i then serves value heads
-i H / Hk .. (i + 1) H / Hk - 1).
+i H / Hk .. (i + 1) H / Hk - 1); or, through
+`chunk_gated_delta_rule_rows`, the same tensors as head-major rows
+[B, T, Hk Dk] and [B, T, H Dv], which is what the kernels read and
+write: a layer that holds them so (`ops/gdn_passes.py` computes what
+surrounds the rule in that layout) moves no tensor on the way in or out.
 q and k arrive normalised and scaled as the caller's layer defines.
 """
 
@@ -201,16 +205,18 @@ def _chunk_group(state, xs):
     return state, jnp.stack(outs, axis=2)                 # [B,H,G,C,Dv]
 
 
-def _engine(dk, dv, hk, h, mesh):
+def _engine(supported, mesh,
+            unsupported="head sizes or counts the kernels do not take"):
     """-> ("pallas" or "xla", why, for the log).  The kernels run where
-    the backend is a TPU, `supports` holds and the trace is known to be
+    the backend is a TPU, they take the shapes (`supported`) and the
+    trace is known to be
     for one device or comes with the `mesh` to map them over: a Mosaic
     kernel cannot be partitioned automatically, so a trace that may be
     for several devices and names no mesh keeps the engine that can."""
     if jax.default_backend() != "tpu":
         return "xla", f"backend {jax.default_backend()}"
-    if not supports(dk, dv, hk, h):
-        return "xla", "head sizes or counts the kernels do not take"
+    if not supported:
+        return "xla", unsupported
     if mesh is None and jax.device_count() > 1:
         return "xla", f"{jax.device_count()} devices and no mesh given"
     if mesh is not None and mesh.devices.size > 1:
@@ -227,15 +233,34 @@ def chunk_gated_delta_rule(q, k, v, g, beta, mesh=None):
     q and k may have fewer heads than v (each then serves
     `Hv / Hk` consecutive value heads).
     -> (o [B,T,Hv,Dv], final S [B,Hv,Dk,Dv])."""
-    t, dk, dv = v.shape[1], k.shape[-1], v.shape[-1]
-    engine, why = _engine(dk, dv, k.shape[2], v.shape[2], mesh)
+    b, t, h, dv = v.shape
+    out, state = chunk_gated_delta_rule_rows(
+        q.reshape(b, t, -1), k.reshape(b, t, -1), v.reshape(b, t, -1),
+        g, beta, k.shape[2], mesh=mesh,
+    )
+    return out.reshape(b, t, h, dv), state
+
+
+def chunk_gated_delta_rule_rows(q, k, v, g, beta, hk, mesh=None):
+    """`chunk_gated_delta_rule` of head-major rows, the layout the
+    kernels read and write: q, k [B, T, Hk Dk], v [B, T, Hv Dv] (g and
+    beta [B, T, Hv] say how many value heads) -> (o [B, T, Hv Dv],
+    final S).  A layer that holds its tensors as rows goes through no
+    [B, T, H, D] on the way in or out."""
+    b, t, h = g.shape
+    dk, dv = k.shape[-1] // hk, v.shape[-1] // h
+    engine, why = _engine(supports(dk, dv, hk, h), mesh)
     logger.info(
         "delta rule engine: %s chunk_gated_delta_rule T=%d Dk=%d Dv=%d (%s)",
         engine, t, dk, dv, why,
     )
     if engine == "pallas":
-        return chunk_gated_delta_rule_pallas(q, k, v, g, beta, mesh=mesh)
-    return chunk_gated_delta_rule_xla(q, k, v, g, beta)
+        return _rows_pallas(q, k, v, g, beta, hk, None, mesh)
+    out, state = chunk_gated_delta_rule_xla(
+        q.reshape(b, t, hk, dk), k.reshape(b, t, hk, dk),
+        v.reshape(b, t, h, dv), g, beta,
+    )
+    return out.reshape(b, t, h * dv), state
 
 
 def _padded(xs, t, chunks):
@@ -773,14 +798,21 @@ def _compiler_params(vmem_limit_bytes):
     )
 
 
-def _over_batch(mesh, b, call_for):
+def _several(mesh):
+    """The mesh where a kernel call has to be mapped over it, else None."""
+    return mesh if mesh is not None and mesh.devices.size > 1 else None
+
+
+def _over_batch(mesh, b, call_for, whole=()):
     """The kernel call for `b` sequences: `call_for(b)` as it is for one
     device.  Under a multi-device `mesh` (a Mosaic kernel cannot be
     partitioned automatically, and the sequences do not depend on each
     other) the call for one shard's sequences inside a `shard_map`,
     every operand and result split over the data axis along its leading,
     batch, axis, or whole on every device where that axis does not
-    divide `b`.  The `custom_vjp` stays outside: each pass is mapped on
+    divide `b`; the operands at the positions `whole` have no batch axis
+    (a layer's parameters) and are whole on every device.  The
+    `custom_vjp` stays outside: each pass is mapped on
     its own, and no derivative is taken through the `shard_map`."""
     if mesh is None:
         return call_for(b)
@@ -794,10 +826,18 @@ def _over_batch(mesh, b, call_for):
     spec = P(DATA_AXIS) if split else P()
     # check_vma=False: the kernel interpreter trips the checker off the
     # TPU, as under the ring's shard_map (parallel/ring_attention.py).
-    return pc.shard_map_call(
-        call_for(b // shards if split else b), mesh,
-        in_specs=spec, out_specs=spec, check_vma=False,
-    )
+    call = call_for(b // shards if split else b)
+
+    def mapped(*operands):
+        return pc.shard_map_call(
+            call, mesh,
+            in_specs=tuple(
+                P() if i in whole else spec for i in range(len(operands))
+            ),
+            out_specs=spec, check_vma=False,
+        )(*operands)
+
+    return mapped
 
 
 # Both kernel calls are jitted: a program's layers (and a process's
@@ -907,7 +947,18 @@ def chunk_gated_delta_rule_pallas(q, k, v, g, beta, interpret=None,
                                   mesh=None):
     """`chunk_gated_delta_rule` in the Pallas kernels (interpret mode off
     the TPU; under a multi-device `mesh`, a shard's sequences a device:
-    `_over_batch`).  The kernels read q, k, v as the [B, T, H D] rows they are
+    `_over_batch`), of [B, T, H, D] tensors: the kernels take them as the
+    [B, T, H D] rows they are (`_rows_pallas`)."""
+    b, t, h, dv = v.shape
+    out, state = _rows_pallas(
+        q.reshape(b, t, -1), k.reshape(b, t, -1), v.reshape(b, t, -1),
+        g, beta, k.shape[2], interpret, mesh,
+    )
+    return out.reshape(b, t, h, dv), state
+
+
+def _rows_pallas(q, k, v, g, beta, hk, interpret, mesh):
+    """The kernels read q, k, v as the [B, T, H D] rows they are
     and write o the same way.  g and beta, small, are laid out for them
     here, a block's heads two by two: G (the chunk's cumulative sum),
     G_C - G and beta as columns [B, blocks, chunks x 2C, 3 x pairs], G
@@ -915,8 +966,8 @@ def chunk_gated_delta_rule_pallas(q, k, v, g, beta, interpret=None,
     the lanes [B, blocks, chunks, heads, lanes]."""
     out_dtype = v.dtype
     q, k, v, g, beta = (x.astype(jnp.float32) for x in (q, k, v, g, beta))
-    b, t, h, dv = v.shape
-    hk, dk = k.shape[2:]
+    b, t, h = g.shape
+    dk, dv = k.shape[-1] // hk, v.shape[-1] // h
     hb = _heads_a_block(hk, h, dk, dv)
     blocks, pairs = h // hb, hb // 2
     n = -(-t // CHUNK)
@@ -935,8 +986,7 @@ def chunk_gated_delta_rule_pallas(q, k, v, g, beta, interpret=None,
     g_cum = jnp.cumsum(chunked(g), axis=-1)
     g_last = g_cum[..., -1:]
     out, state = _delta_walk(
-        q.reshape(b, n * CHUNK, hk * dk), k.reshape(b, n * CHUNK, hk * dk),
-        v.reshape(b, n * CHUNK, h * dv),
+        q, k, v,
         jnp.concatenate([
             column(g_cum), column(g_last - g_cum), column(chunked(beta)),
         ], axis=-1),
@@ -946,6 +996,6 @@ def chunk_gated_delta_rule_pallas(q, k, v, g, beta, interpret=None,
         ),
         (hk, h, dk, dv, every,
          _use_interpret() if interpret is None else interpret,
-         mesh if mesh is not None and mesh.devices.size > 1 else None),
+         _several(mesh)),
     )
-    return out.reshape(b, n * CHUNK, h, dv)[:, :t].astype(out_dtype), state
+    return (out if n * CHUNK == t else out[:, :t]).astype(out_dtype), state

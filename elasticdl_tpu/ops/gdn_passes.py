@@ -1,0 +1,510 @@
+"""What surrounds the gated delta rule in a Gated DeltaNet layer, each as
+ONE pass over head-major [B, T, H D] rows, the layout the rule's kernels
+read and write (`ops/gated_delta.py`):
+
+- `conv_silu`: rows in float32 -> causal depthwise convolution of
+  `taps.shape[0]` taps accumulated in float32 (+ `bias`) -> silu -> where
+  `head` is given, the l2-norm of each head of `head` columns,
+  ``x rsqrt(sum x^2 + eps) scale`` -> rows in float32.  It knows nothing
+  of DeltaNet: a layer calls it once for each of its tensors (q with
+  ``scale = 1 / sqrt(Dk)``, k, v without the norm), and a state-space
+  layer's conv + silu has the same form (rows, taps, bias).
+- `gated_rms_norm`: o rows and gate rows z in float32 -> per head
+  ``weight (o rsqrt(mean o^2 + eps)) silu(z)`` -> rows in `dtype`.
+
+Two engines, chosen by the caller from what `engine` can see (the log
+says which and why, once a trace, beside the rule's line):
+
+- Pallas kernels on a TPU where `supports` holds and the trace is for
+  one device or names its mesh (then a shard's sequences a device under
+  the rule's `_over_batch`).  Grid (sequence, block of `ROWS` rows, head),
+  every step independent of every other:
+  the `taps - 1` rows before a block come from a second, 8-row block of
+  the same array (the rows a float32 tile holds), and the backward pass,
+  one kernel too, reads 8 rows after the block as well, recomputes the
+  convolution and silu there from the input it already has, and writes
+  d(rows) and one partial sum a block of d(taps) (and d(bias)); XLA adds
+  the partial sums up.  Everything is float32, in and out and between;
+  the one cast is `gated_rms_norm`'s result to `dtype`, which is what the
+  out-projection's product takes.
+- the plain `jax.numpy` chain (`conv_silu_xla`, `gated_rms_norm_xla`)
+  everywhere else: the definition the tests hold the kernels to, and the
+  engine of the CPU's tests and of head sizes that are no whole lane
+  tiles.
+
+Neither engine names a scope: the caller's `jax.named_scope` (the
+model's `gdn_mix`) reaches the `custom_vjp`s' backward kernels too.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from elasticdl_tpu.ops import gated_delta
+from elasticdl_tpu.ops.flash_attention import _use_interpret
+
+ROWS = 2048  # rows a block; its columns are one head (or one lane tile)
+LANE_TILE = 128
+HALO = 8     # rows of the block before (and after): one float32 tile's
+# What a pass may hold in VMEM: a block is 1 MiB at heads of 128, and a
+# dozen of them are live in the backward kernel, the pipeline's second
+# buffers among them (`supports`).
+VMEM_LIMIT = 64 << 20
+
+
+# ----------------------------------------------------------------------
+# The definition: plain jax.numpy
+# ----------------------------------------------------------------------
+
+
+def conv_silu_xla(rows, taps, bias=None, *, head=0, scale=1.0, eps=1e-6):
+    """`conv_silu` in XLA ops: the taps over shifted slices of the padded
+    rows, accumulated in float32."""
+    b, t, width = rows.shape
+    k = taps.shape[0]
+    padded = jnp.pad(rows, ((0, 0), (k - 1, 0), (0, 0))).astype(jnp.float32)
+    mixed = sum(padded[:, j:j + t] * taps[j] for j in range(k))
+    if bias is not None:
+        mixed = mixed + bias
+    mixed = jax.nn.silu(mixed)
+    if not head:
+        return mixed
+    heads = mixed.reshape(b, t, width // head, head)
+    heads = heads * jax.lax.rsqrt(
+        jnp.sum(heads * heads, axis=-1, keepdims=True) + eps
+    )
+    return (heads * scale).reshape(b, t, width)
+
+
+def gated_rms_norm_xla(rows, gate, weight, *, eps=1e-6, dtype=jnp.float32):
+    """`gated_rms_norm` in XLA ops."""
+    b, t, width = rows.shape
+    head = weight.shape[0]
+    heads = rows.astype(jnp.float32).reshape(b, t, width // head, head)
+    heads = heads * jax.lax.rsqrt(
+        jnp.mean(heads * heads, axis=-1, keepdims=True) + eps
+    )
+    gate = gate.astype(jnp.float32).reshape(heads.shape)
+    return (weight * heads * jax.nn.silu(gate)).reshape(b, t, width).astype(
+        dtype
+    )
+
+
+# ----------------------------------------------------------------------
+# The choice of engine
+# ----------------------------------------------------------------------
+
+
+def supports(t: int, dk: int, dv: int, taps: int) -> bool:
+    """Whether the kernels take these shapes: a head has to be whole
+    lane tiles of the rows, a block's rows whole float32 tiles, the rows
+    before a block that a tap reaches have to lie in one tile, and the
+    backward kernel's dozen live blocks of `ROWS` rows of one head have
+    to fit in `VMEM_LIMIT` (heads of 512 compile for a described v5e,
+    heads of 1024 do not)."""
+    return (
+        dk % LANE_TILE == 0 and dv % LANE_TILE == 0 and t % HALO == 0
+        and 1 <= taps <= HALO + 1
+        and 12 * ROWS * max(dk, dv) * 4 <= VMEM_LIMIT
+    )
+
+
+def engine(t, hk, hv, dk, dv, taps, mesh=None):
+    """-> "pallas" or "xla" for both passes of a layer with these
+    shapes, by the rule's own `_engine` (backend, what the trace is for)
+    and `supports`; the worker's log says which and why."""
+    found, why = gated_delta._engine(
+        supports(t, dk, dv, taps), mesh,
+        "head sizes, a length or taps the kernels do not take",
+    )
+    gated_delta.logger.info(
+        "gdn passes engine: %s T=%d Hk=%d Hv=%d D=%s (%s)", found, t, hk, hv,
+        dk if dk == dv else f"{dk}/{dv}", why,
+    )
+    return found
+
+
+def _blocks(t: int, width: int, head: int, rows: int):
+    """-> (rows a block, columns a block, grid of one sequence).  A
+    block's columns are ONE head, so that a kernel's body is written
+    once and not once a head (what a body traces it traces at every
+    start of a worker), or one lane tile where no norm goes by head."""
+    rows = min(t, rows)
+    columns = head or LANE_TILE
+    return rows, columns, (pl.cdiv(t, rows), width // columns)
+
+
+def _params():
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "parallel"),
+        vmem_limit_bytes=VMEM_LIMIT,
+    )
+
+
+# ----------------------------------------------------------------------
+# conv + silu (+ l2-norm by head)
+# ----------------------------------------------------------------------
+
+
+def _window(refs, first_row, t):
+    """`refs`' rows (halos and the block, in order) one under the other,
+    zero where the sequence has no such row: before its start, and past
+    its end in a last block that is not whole."""
+    window = jnp.concatenate([ref[0] for ref in refs], axis=0)
+    rows = first_row + jax.lax.broadcasted_iota(
+        jnp.int32, (window.shape[0], 1), 0
+    )
+    return jnp.where((rows >= 0) & (rows < t), window, 0.0)
+
+
+def _conv(window, taps_ref, bias_ref, n):
+    """`n` rows of the causal convolution, the first of them the
+    window's row `HALO` (the window starts `HALO` rows before)."""
+    k = taps_ref.shape[0]
+    first = HALO - (k - 1)
+    mixed = sum(
+        window[first + j:first + j + n] * taps_ref[j:j + 1]
+        for j in range(k)
+    )
+    return mixed if bias_ref is None else mixed + bias_ref[...]
+
+
+def _conv_fwd_kernel(*refs, t, head, scale, eps, has_bias):
+    x_ref, before_ref, taps_ref = refs[:3]
+    bias_ref = refs[3] if has_bias else None
+    o_ref = refs[-1]
+    rows = x_ref.shape[1]
+    window = _window(
+        (before_ref, x_ref), pl.program_id(1) * rows - HALO, t
+    )
+    mixed = _conv(window, taps_ref, bias_ref, rows)
+    mixed = mixed * jax.nn.sigmoid(mixed)
+    if head:  # the block is one head
+        mixed = mixed * jax.lax.rsqrt(
+            jnp.sum(mixed * mixed, axis=-1, keepdims=True) + eps
+        ) * scale
+    o_ref[0] = mixed
+
+
+def _conv_bwd_kernel(*refs, t, head, scale, eps, has_bias):
+    """d(rows) of a block and the block's share of d(taps) (and, in the
+    row after them, of d(bias)): the convolution and silu once more over
+    the block and the `HALO` rows after it, whose d(conv) a tap carries
+    back into the block."""
+    x_ref, before_ref, after_ref, taps_ref = refs[:4]
+    bias_ref = refs[4] if has_bias else None
+    do_ref, do_after_ref, dx_ref, dtaps_ref = refs[-4:]
+    rows, k = x_ref.shape[1], taps_ref.shape[0]
+    block_row = pl.program_id(1) * rows
+    window = _window((before_ref, x_ref, after_ref), block_row - HALO, t)
+    conv = _conv(window, taps_ref, bias_ref, rows + HALO)
+    gate = jax.nn.sigmoid(conv)
+    d_mixed = _window((do_ref, do_after_ref), block_row, t)
+    if head:
+        mixed = conv * gate
+        norm = jax.lax.rsqrt(
+            jnp.sum(mixed * mixed, axis=-1, keepdims=True) + eps
+        )
+        d_mixed = (scale * norm) * (d_mixed - mixed * (norm * norm) * (
+            jnp.sum(d_mixed * mixed, axis=-1, keepdims=True)
+        ))
+    d_conv = d_mixed * (gate * (1.0 + conv * (1.0 - gate)))
+    dx_ref[0] = sum(
+        d_conv[k - 1 - j:k - 1 - j + rows] * taps_ref[j:j + 1]
+        for j in range(k)
+    )
+    first = HALO - (k - 1)
+    for j in range(k):
+        dtaps_ref[0, 0, j:j + 1] = jnp.sum(
+            d_conv[:rows] * window[first + j:first + j + rows],
+            axis=0, keepdims=True,
+        )
+    if has_bias:
+        dtaps_ref[0, 0, k:k + 1] = jnp.sum(
+            d_conv[:rows], axis=0, keepdims=True
+        )
+
+
+def _row_specs(t, rows, columns):
+    """Block specs of (a block, the `HALO` rows before it, the `HALO`
+    rows after it) of [B, T, W] rows, each clamped into the array: what
+    lies outside the sequence is zeroed in the kernel."""
+    tiles, last = rows // HALO, pl.cdiv(t, HALO) - 1
+    return (
+        pl.BlockSpec((1, rows, columns), lambda s, i, j: (s, i, j)),
+        pl.BlockSpec(
+            (1, HALO, columns),
+            lambda s, i, j: (s, jnp.maximum(i * tiles - 1, 0), j),
+        ),
+        pl.BlockSpec(
+            (1, HALO, columns),
+            lambda s, i, j: (s, jnp.minimum((i + 1) * tiles, last), j),
+        ),
+    )
+
+
+def _column_spec(rows, columns):
+    """A [rows, W] parameter's columns of a block."""
+    return pl.BlockSpec((rows, columns), lambda s, i, j: (0, j))
+
+
+def _conv_plan(static, rows, taps, bias):
+    """What both conv calls are built from -> (the kernel's keywords,
+    grid of one sequence, the rows' three specs, the parameters'
+    specs)."""
+    head, scale, eps, block_rows, _, _ = static
+    _, t, width = rows.shape
+    block_rows, columns, grid = _blocks(t, width, head, block_rows)
+    parameters = [_column_spec(taps.shape[0], columns)] + (
+        [] if bias is None else [_column_spec(1, columns)]
+    )
+    keywords = dict(
+        t=t, head=head, scale=scale, eps=eps, has_bias=bias is not None
+    )
+    return keywords, grid, _row_specs(t, block_rows, columns), parameters
+
+
+# Every kernel call is jitted, as the rule's are: a program's layers share
+# one trace and one lowering of each (a layer's q, k and v each have their own).
+@functools.partial(jax.jit, static_argnums=0)
+def _conv_forward_call(static, rows, taps, bias):
+    keywords, grid, (block, before, _), parameters = _conv_plan(
+        static, rows, taps, bias
+    )
+    *_, interpret, mesh = static
+
+    def call_for(b):
+        return pl.pallas_call(
+            functools.partial(_conv_fwd_kernel, **keywords),
+            grid=(b,) + grid,
+            in_specs=[block, before] + parameters,
+            out_specs=block,
+            out_shape=jax.ShapeDtypeStruct((b,) + rows.shape[1:], jnp.float32),
+            compiler_params=_params(),
+            name="conv_silu_fwd",
+            interpret=interpret,
+        )
+
+    return gated_delta._over_batch(
+        mesh, rows.shape[0], call_for, whole=range(2, 2 + len(parameters))
+    )(rows, rows, taps, *([] if bias is None else [bias]))
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _conv_backward_call(static, rows, taps, bias, d_out):
+    """-> (d rows, [B, blocks, taps (+ 1), W] partial sums of d taps
+    (and d bias))."""
+    keywords, grid, (block, before, after), parameters = _conv_plan(
+        static, rows, taps, bias
+    )
+    *_, interpret, mesh = static
+    _, t, width = rows.shape
+    sums = taps.shape[0] + (bias is not None)
+
+    def call_for(b):
+        return pl.pallas_call(
+            functools.partial(_conv_bwd_kernel, **keywords),
+            grid=(b,) + grid,
+            in_specs=[block, before, after] + parameters + [block, after],
+            out_specs=[
+                block,
+                pl.BlockSpec(
+                    (1, 1, sums, block.block_shape[2]),
+                    lambda s, i, j: (s, i, 0, j),
+                ),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((b, t, width), jnp.float32),
+                jax.ShapeDtypeStruct((b, grid[0], sums, width), jnp.float32),
+            ],
+            compiler_params=_params(),
+            name="conv_silu_bwd",
+            interpret=interpret,
+        )
+
+    return gated_delta._over_batch(
+        mesh, rows.shape[0], call_for,
+        whole=range(3, 3 + len(parameters)),
+    )(rows, rows, rows, taps, *([] if bias is None else [bias]), d_out, d_out)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _conv_silu(rows, taps, bias, static):
+    return _conv_forward_call(static, rows, taps, bias)
+
+
+def _conv_silu_fwd(rows, taps, bias, static):
+    return _conv_forward_call(static, rows, taps, bias), (rows, taps, bias)
+
+
+def _conv_silu_bwd(static, residuals, d_out):
+    rows, taps, bias = residuals
+    d_rows, sums = _conv_backward_call(static, rows, taps, bias, d_out)
+    sums = jnp.sum(sums, axis=(0, 1))
+    k = taps.shape[0]
+    return d_rows, sums[:k], None if bias is None else sums[k:]
+
+
+_conv_silu.defvjp(_conv_silu_fwd, _conv_silu_bwd)
+
+
+def conv_silu(rows, taps, bias=None, *, head=0, scale=1.0, eps=1e-6,
+              pallas=False, interpret=None, mesh=None):
+    """rows [B, T, W] -> silu(causal depthwise conv(rows, taps [K, W])
+    (+ bias [W])), and with `head` the l2-norm of each `head` columns
+    times `scale`; float32.  `pallas`: the kernels (`engine` says where
+    they run; interpret mode off the TPU), else the XLA chain."""
+    if not pallas:
+        return conv_silu_xla(
+            rows, taps, bias, head=head, scale=scale, eps=eps
+        )
+    return _conv_silu(
+        rows.astype(jnp.float32), taps.astype(jnp.float32),
+        None if bias is None else bias.astype(jnp.float32).reshape(1, -1),
+        (head, float(scale), eps, ROWS,
+         _use_interpret() if interpret is None else interpret,
+         gated_delta._several(mesh)),
+    )
+
+
+# ----------------------------------------------------------------------
+# RMS norm by head, gated
+# ----------------------------------------------------------------------
+
+
+def _norm_fwd_kernel(o_ref, z_ref, w_ref, y_ref, *, eps):
+    out, gate = o_ref[0], z_ref[0]  # one head's columns
+    out = out * jax.lax.rsqrt(
+        jnp.mean(out * out, axis=-1, keepdims=True) + eps
+    )
+    y_ref[0] = (w_ref[...] * out * (gate * jax.nn.sigmoid(gate))).astype(
+        y_ref.dtype
+    )
+
+
+def _norm_bwd_kernel(o_ref, z_ref, w_ref, dy_ref, do_ref, dz_ref, dw_ref, *,
+                     t, eps):
+    weight = w_ref[...]
+    rows = o_ref.shape[1]
+    # past the sequence's end a block holds anything: no share of it may
+    # reach the sum over the block's rows
+    inside = pl.program_id(1) * rows + jax.lax.broadcasted_iota(
+        jnp.int32, (rows, 1), 0
+    ) < t
+    out = jnp.where(inside, o_ref[0], 0.0)
+    gate = jnp.where(inside, z_ref[0], 0.0)
+    d_y = jnp.where(inside, dy_ref[0].astype(jnp.float32), 0.0)
+    norm = jax.lax.rsqrt(jnp.mean(out * out, axis=-1, keepdims=True) + eps)
+    normed = out * norm
+    sig = jax.nn.sigmoid(gate)
+    silu = gate * sig
+    dw_ref[0, 0, 0] = jnp.sum(d_y * normed * silu, axis=0, keepdims=True)
+    dz_ref[0] = (d_y * weight * normed) * (sig * (1.0 + gate * (1.0 - sig)))
+    d_normed = d_y * weight * silu
+    do_ref[0] = norm * (d_normed - normed * jnp.mean(
+        d_normed * normed, axis=-1, keepdims=True
+    ))
+
+
+def _norm_specs(t, width, head, rows):
+    block_rows, columns, grid = _blocks(t, width, head, rows)
+    return (
+        grid, pl.BlockSpec((1, block_rows, columns), lambda s, i, j: (s, i, j)),
+        pl.BlockSpec((1, head), lambda s, i, j: (0, 0)),
+    )
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _norm_forward_call(static, rows, gate, weight):
+    eps, dtype, block_rows, interpret, mesh = static
+    _, t, width = rows.shape
+    head = weight.shape[1]
+    grid, block, whole = _norm_specs(t, width, head, block_rows)
+
+    def call_for(b):
+        return pl.pallas_call(
+            functools.partial(_norm_fwd_kernel, eps=eps),
+            grid=(b,) + grid,
+            in_specs=[block, block, whole],
+            out_specs=block,
+            out_shape=jax.ShapeDtypeStruct((b, t, width), dtype),
+            compiler_params=_params(),
+            name="gated_norm_fwd",
+            interpret=interpret,
+        )
+
+    return gated_delta._over_batch(mesh, rows.shape[0], call_for, whole=(2,))(
+        rows, gate, weight
+    )
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _norm_backward_call(static, rows, gate, weight, d_out):
+    """-> (d rows, d gate, [B, blocks, heads, 1, head] partial sums of
+    d weight)."""
+    eps, _, block_rows, interpret, mesh = static
+    _, t, width = rows.shape
+    head = weight.shape[1]
+    grid, block, whole = _norm_specs(t, width, head, block_rows)
+
+    def call_for(b):
+        return pl.pallas_call(
+            functools.partial(_norm_bwd_kernel, t=t, eps=eps),
+            grid=(b,) + grid,
+            in_specs=[block, block, whole, block],
+            out_specs=[
+                block, block,
+                pl.BlockSpec(
+                    (1, 1, 1, 1, head), lambda s, i, j: (s, i, j, 0, 0)
+                ),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((b, t, width), jnp.float32),
+                jax.ShapeDtypeStruct((b, t, width), jnp.float32),
+                jax.ShapeDtypeStruct((b,) + grid + (1, head), jnp.float32),
+            ],
+            compiler_params=_params(),
+            name="gated_norm_bwd",
+            interpret=interpret,
+        )
+
+    return gated_delta._over_batch(mesh, rows.shape[0], call_for, whole=(2,))(
+        rows, gate, weight, d_out
+    )
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _gated_rms_norm(rows, gate, weight, static):
+    return _norm_forward_call(static, rows, gate, weight)
+
+
+def _gated_rms_norm_fwd(rows, gate, weight, static):
+    return _norm_forward_call(static, rows, gate, weight), (rows, gate, weight)
+
+
+def _gated_rms_norm_bwd(static, residuals, d_out):
+    d_rows, d_gate, sums = _norm_backward_call(static, *residuals, d_out)
+    return d_rows, d_gate, jnp.sum(sums, axis=(0, 1, 2))
+
+
+_gated_rms_norm.defvjp(_gated_rms_norm_fwd, _gated_rms_norm_bwd)
+
+
+def gated_rms_norm(rows, gate, weight, *, eps=1e-6, dtype=jnp.float32,
+                   pallas=False, interpret=None, mesh=None):
+    """rows, gate [B, T, H D] float32, weight [D] -> per head
+    weight (rows rsqrt(mean rows^2 + eps)) silu(gate), in `dtype`."""
+    if not pallas:
+        return gated_rms_norm_xla(rows, gate, weight, eps=eps, dtype=dtype)
+    return _gated_rms_norm(
+        rows.astype(jnp.float32), gate.astype(jnp.float32),
+        weight.astype(jnp.float32).reshape(1, -1),
+        (eps, jnp.dtype(dtype), ROWS,
+         _use_interpret() if interpret is None else interpret,
+         gated_delta._several(mesh)),
+    )

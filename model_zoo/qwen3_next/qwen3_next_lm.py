@@ -17,7 +17,9 @@ follow the source's (`embed_tokens`, `layers_<i>` with `input_layernorm`,
 - `linear_attn.in_proj_qkvz` is grouped by KEY head as the source's
   `fix_query_key_value_ordering` reads it: per key head
   [q (Dk), k (Dk), v (r Dv), z (r Dv)] with r = value heads per key head;
-  `in_proj_ba` per key head [b (r), a (r)];
+  `in_proj_ba` per key head [b (r), a (r)].  That is the order in the
+  state and in a checkpoint; the program multiplies by views of the
+  kernel's columns, so that its results are head-major (`_HeadMajorDense`);
 - `linear_attn.conv1d` is [width, channels] over the channels [q, k, v];
 - `mlp` holds a RANGE of the experts (`experts_first`, `experts_held`) as
   stacked [held, in, out] tensors (`layers/moe.py`); the router `gate`
@@ -33,7 +35,9 @@ Left out: the source's multi-token-prediction module and any auxiliary
 load-balancing loss (its config names neither a key nor a coefficient).
 
 Device scopes (obs/tracing.py DEVICE_SCOPES): `gdn` (the DeltaNet
-sublayer with its norm and residual) > `gdn_scan`; `attn`; `moe` >
+sublayer with its norm and residual) > `gdn_mix` (conv taps, silu and
+l2-norm before the rule; the gated norm after it), `gdn_scan` (the rule
+alone); `attn`; `moe` >
 `moe_route`, `moe_experts`, `moe_shared`; `lm_head_loss`.
 """
 
@@ -50,8 +54,8 @@ import numpy as np
 import optax
 
 from elasticdl_tpu.layers.moe import SparseMoeBlock
-from elasticdl_tpu.ops import gqa
-from elasticdl_tpu.ops.gated_delta import chunk_gated_delta_rule
+from elasticdl_tpu.ops import gdn_passes, gqa
+from elasticdl_tpu.ops.gated_delta import chunk_gated_delta_rule_rows
 # The rest of the zoo contract is that of any causal LM on
 # `synthetic://lm` data: mean next-token cross-entropy over float32
 # logits (under the `lm_head_loss` scope), perplexity and accuracy.
@@ -89,11 +93,43 @@ class RMSNorm(nn.Module):
         return x * (1.0 + weight)
 
 
-def _l2norm(x, eps=1e-6):
-    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + eps)
+class _HeadMajorDense(nn.Module):
+    """A projection whose `kernel` [in, features] keeps the source's
+    column order, per key head a run of each part in turn (`parts`:
+    their widths within one key head), and whose RESULTS are head-major:
+    one [B, T, groups x width] tensor a part, each the product with a
+    view of the kernel's columns (a slice and a reshape of the weight,
+    100 MB, where the result's would be a relayout of 800 MB).  Operands
+    in `dtype`, float32 results, as `_dense`."""
+
+    groups: int
+    parts: tuple
+    dtype: Any
+
+    @nn.compact
+    def __call__(self, x):
+        d, per_group = x.shape[-1], sum(self.parts)
+        kernel = self.param(
+            "kernel", nn.initializers.lecun_normal(),
+            (d, self.groups * per_group), jnp.float32,
+        ).astype(self.dtype).reshape(d, self.groups, per_group)
+        x = x.astype(self.dtype)
+        starts = np.cumsum((0,) + tuple(self.parts))
+        return [
+            jnp.dot(
+                x, kernel[:, :, lo:hi].reshape(d, -1),
+                preferred_element_type=jnp.float32,
+            )
+            for lo, hi in zip(starts, starts[1:])
+        ]
 
 
 class GatedDeltaNet(nn.Module):
+    """From the projections to the out-projection q, k, v, z and o are
+    head-major [B, T, H D] rows, the layout the rule's kernels read and
+    write: no tensor of the sequence's size is reshaped, split or
+    concatenated on the way (`ops/gdn_passes.py`, `ops/gated_delta.py`)."""
+
     num_k_heads: int
     num_v_heads: int
     head_k_dim: int
@@ -101,42 +137,35 @@ class GatedDeltaNet(nn.Module):
     conv_kernel: int
     eps: float
     dtype: Any
-    mesh: Any = None  # what the program is compiled for: the rule's engine
+    mesh: Any = None  # what the program is compiled for: the engines' choice
 
     @nn.compact
     def __call__(self, x):
-        b, t, d = x.shape
+        _, t, d = x.shape
         hk, hv, dk, dv = (self.num_k_heads, self.num_v_heads,
                           self.head_k_dim, self.head_v_dim)
         r = hv // hk
-        qkvz = _dense(2 * hk * dk + 2 * hv * dv, self.dtype, "in_proj_qkvz")(x)
-        ba = _dense(2 * hv, self.dtype, "in_proj_ba")(x).astype(jnp.float32)
-        qkvz = qkvz.reshape(b, t, hk, 2 * dk + 2 * r * dv)
-        q, k, v, z = jnp.split(
-            qkvz, [dk, 2 * dk, 2 * dk + r * dv], axis=-1
-        )
-        ba = ba.reshape(b, t, hk, 2 * r)
-        beta_in, a = ba[..., :r].reshape(b, t, hv), ba[..., r:].reshape(b, t, hv)
-        z = z.reshape(b, t, hv, dv)
-        # Causal depthwise convolution over [q | k | v], then silu: four
-        # taps accumulated in float32.
-        mixed = jnp.concatenate(
-            [q.reshape(b, t, hk * dk), k.reshape(b, t, hk * dk),
-             v.reshape(b, t, hv * dv)], axis=-1,
-        )
+        q, k, v, z = _HeadMajorDense(
+            hk, (dk, dk, r * dv, r * dv), self.dtype, name="in_proj_qkvz"
+        )(x)
+        beta_in, a = _HeadMajorDense(
+            hk, (r, r), self.dtype, name="in_proj_ba"
+        )(x)
         conv = self.param(
             "conv1d", nn.initializers.lecun_normal(),
-            (self.conv_kernel, mixed.shape[-1]), jnp.float32,
+            (self.conv_kernel, 2 * hk * dk + hv * dv), jnp.float32,
         )
-        padded = jnp.pad(mixed, ((0, 0), (self.conv_kernel - 1, 0), (0, 0)))
-        mixed = nn.silu(sum(
-            padded[:, j:j + t].astype(jnp.float32) * conv[j]
-            for j in range(self.conv_kernel)
-        ))
-        q, k, v = jnp.split(mixed, [hk * dk, 2 * hk * dk], axis=-1)
-        q = q.reshape(b, t, hk, dk)
-        k = k.reshape(b, t, hk, dk)
-        v = v.reshape(b, t, hv, dv)
+        pallas = gdn_passes.engine(
+            t, hk, hv, dk, dv, self.conv_kernel, self.mesh
+        ) == "pallas"
+        # Causal depthwise convolution over [q | k | v], then silu (the
+        # taps accumulated in float32), then the l2-norm of q and k by
+        # head: one pass over each.
+        mix = partial(gdn_passes.conv_silu, pallas=pallas, mesh=self.mesh)
+        with jax.named_scope("gdn_mix"):
+            q = mix(q, conv[:, :hk * dk], head=dk, scale=dk ** -0.5)
+            k = mix(k, conv[:, hk * dk:2 * hk * dk], head=dk)
+            v = mix(v, conv[:, 2 * hk * dk:])
         a_log = self.param(
             "A_log",
             lambda key, shape: jnp.log(
@@ -151,18 +180,17 @@ class GatedDeltaNet(nn.Module):
         # Each key head serves r consecutive value heads (the source's
         # repeat_interleave; the rule repeats q and k a group at a time).
         with jax.named_scope("gdn_scan"):
-            out, _ = chunk_gated_delta_rule(
-                _l2norm(q) / np.sqrt(dk), _l2norm(k), v, g, beta,
-                mesh=self.mesh,
+            out, _ = chunk_gated_delta_rule_rows(
+                q, k, v, g, beta, hk, mesh=self.mesh
             )
         # Gated RMSNorm per head (w from 1), in float32.
         weight = self.param("norm", nn.initializers.ones_init(), (dv,),
                             jnp.float32)
-        out = out * jax.lax.rsqrt(
-            jnp.mean(out * out, axis=-1, keepdims=True) + self.eps
-        )
-        out = weight * out * nn.silu(z.astype(jnp.float32))
-        out = out.reshape(b, t, hv * dv).astype(self.dtype)
+        with jax.named_scope("gdn_mix"):
+            out = gdn_passes.gated_rms_norm(
+                out, z, weight, eps=self.eps, dtype=self.dtype,
+                pallas=pallas, mesh=self.mesh,
+            )
         return _dense(d, self.dtype, "out_proj")(out)
 
 

@@ -24,7 +24,7 @@ import pytest
 from elasticdl_tpu.layers.moe import (
     ROUTING_COLLECTION, RoutingLedger, SparseMoeBlock,
 )
-from elasticdl_tpu.ops import gated_delta, gqa
+from elasticdl_tpu.ops import gated_delta, gdn_passes, gqa
 from elasticdl_tpu.ops.gated_delta import (
     chunk_gated_delta_rule, chunk_gated_delta_rule_pallas,
     chunk_gated_delta_rule_xla, gated_delta_rule_recurrent,
@@ -265,18 +265,28 @@ def test_the_cell_checks_precisions_the_reference_has():
         ref.forward({}, np.zeros((1, 4), np.int32), TINY, "float16")
 
 
-def _dots(jaxpr, in_kernel=False):
-    """Every `dot_general` of a jaxpr and of the jaxprs inside it ->
-    (the equation, whether a `pallas_call` holds it)."""
+def _eqns(jaxpr, kernel=None):
+    """Every equation of a jaxpr and of the jaxprs inside it -> (the
+    equation, the name of the `pallas_call` that holds it or None)."""
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "dot_general":
-            yield eqn, in_kernel
-        within = in_kernel or eqn.primitive.name == "pallas_call"
+        yield eqn, kernel
+        within = kernel
+        if eqn.primitive.name == "pallas_call":
+            within = eqn.params.get("name") or "pallas_call"
         for value in eqn.params.values():
             for inner in value if isinstance(value, (list, tuple)) else [value]:
                 inner = getattr(inner, "jaxpr", inner)
                 if hasattr(inner, "eqns"):
-                    yield from _dots(inner, within)
+                    yield from _eqns(inner, within)
+
+
+def _dots(jaxpr):
+    """Every `dot_general` of a jaxpr and of the jaxprs inside it ->
+    (the equation, whether a `pallas_call` holds it)."""
+    return (
+        (eqn, kernel is not None) for eqn, kernel in _eqns(jaxpr)
+        if eqn.primitive.name == "dot_general"
+    )
 
 
 def _dot_precisions(jaxpr):
@@ -314,6 +324,54 @@ def test_float32_products_ask_for_their_precision():
     )
     assert len(rule) > 10
     assert all(p == (high, high) for _, p in rule)
+
+
+def test_layer_moves_no_tensor_and_its_passes_are_float32(monkeypatch):
+    """The DeltaNet sublayer as a TPU traces it at the cell's shapes
+    (abstract), forward and backward: between the projections and the
+    rule, and between the rule and the out-projection, no tensor of
+    B T 2048 elements or more is reshaped, transposed, split,
+    concatenated, padded or sliced (each a relayout or a copy of 270-800
+    MB on a TPU); and inside the four kernels of the passes every
+    floating-point value is float32, but for the bfloat16 the gated norm
+    hands the out-projection and takes back as its cotangent."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "device_count", lambda: 1)
+    module = zoo.GatedDeltaNet(16, 32, 128, 128, 4, 1e-6, jnp.bfloat16)
+    x = jax.ShapeDtypeStruct((2, 8192, 2048), jnp.float32)
+    variables = jax.eval_shape(module.init, jax.random.PRNGKey(0), x)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda v, x: jnp.sum(module.apply(v, x).astype(jnp.float32)),
+        argnums=(0, 1),
+    ))(variables, x).jaxpr
+    moves = ("reshape", "transpose", "concatenate", "split", "pad", "slice",
+             "dynamic_slice", "gather", "squeeze", "expand_dims")
+    kernels = {}
+    for eqn, kernel in _eqns(jaxpr):
+        avals = [v.aval for v in list(eqn.invars) + list(eqn.outvars)
+                 if hasattr(v.aval, "shape")]
+        if kernel is None:
+            if eqn.primitive.name in moves:
+                assert max(
+                    int(np.prod(a.shape)) for a in avals
+                ) < 2 * 8192 * 2048, eqn
+            continue
+        kernels[kernel] = kernels.get(kernel, 0) + 1
+        if kernel.startswith(("conv_silu", "gated_norm")):
+            for aval in avals:
+                if not jnp.issubdtype(aval.dtype, jnp.floating):
+                    continue
+                assert aval.dtype == jnp.float32 or (
+                    kernel.startswith("gated_norm")
+                    and aval.dtype == jnp.bfloat16
+                    and eqn.primitive.name in (
+                        "get", "swap", "convert_element_type"
+                    )
+                ), (kernel, eqn)
+    assert set(kernels) == {
+        "conv_silu_fwd", "conv_silu_bwd", "gated_norm_fwd", "gated_norm_bwd",
+        "delta_rule_fwd", "delta_rule_bwd",
+    }
 
 
 @pytest.mark.parametrize("passes", ["forward", "backward"])
@@ -547,6 +605,280 @@ def test_delta_rule_kernel_under_a_mesh_is_the_kernel(b, mesh):
         return jax.jit(
             jax.value_and_grad(total, argnums=range(5), has_aux=True)
         )(*inputs)
+
+    (_, want), want_grads = run(None)
+    (_, got), got_grads = run(_cpu_mesh(*mesh))
+    for g, w in zip(got + got_grads, want + want_grads):
+        np.testing.assert_allclose(g, w, rtol=1e-6, atol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# What surrounds the rule: one pass each way over head-major rows
+# ---------------------------------------------------------------------------
+
+# T = 64 and 200 are one block of as many rows; with blocks of 256 rows
+# (the cell's 8192 are four of 2048) 1100 is four blocks and a ragged
+# fifth, each edge inside the convolution's reach.  One and two value
+# heads a key head, two key heads.
+_PASS_CASES = [
+    (t, hk, hv) for t in (64, 200, 1100)
+    for hk, hv in ((1, 1), (1, 2), (2, 4))
+]
+
+
+@pytest.fixture
+def blocks_of_256_rows(monkeypatch):
+    monkeypatch.setattr(gdn_passes, "ROWS", 256)
+
+
+def _close(got, want, limit, what):
+    assert _rel(got, want) < limit, what
+
+
+@pytest.mark.parametrize("t,hk,hv", _PASS_CASES)
+@pytest.mark.parametrize("bias", [False, True])
+def test_conv_silu_kernels_match_the_plain_chain(t, hk, hv, bias,
+                                                 blocks_of_256_rows):
+    """q's pass (the l2-norm by head, scaled), v's (none) and, with a
+    bias, a state-space layer's: outputs and every gradient (rows, taps,
+    bias) against the `jax.numpy` chain, which pads and shifts."""
+    rng = np.random.default_rng(t + hk + hv)
+    for width, head, scale in ((hk * 128, 128, 128 ** -0.5),
+                               (hv * 128, 0, 1.0)):
+        rows, weight = (
+            jnp.asarray(rng.normal(size=(2, t, width)), jnp.float32)
+            for _ in range(2)
+        )
+        taps = jnp.asarray(rng.normal(size=(4, width)), jnp.float32)
+        offset = bias and jnp.asarray(rng.normal(size=(width,)), jnp.float32)
+
+        def run(pallas):
+            def total(rows, taps, offset):
+                out = gdn_passes.conv_silu(
+                    rows, taps, offset if bias else None, head=head,
+                    scale=scale, pallas=pallas, interpret=True,
+                )
+                return jnp.sum(out * weight), out
+
+            return jax.jit(jax.value_and_grad(
+                total, argnums=(0, 1, 2) if bias else (0, 1), has_aux=True
+            ))(rows, taps, offset)
+
+        ((_, got), got_grads), ((_, want), want_grads) = run(True), run(False)
+        _close(got, want, 1e-6, (width, "out"))
+        for g, w, name in zip(got_grads, want_grads, ("rows", "taps", "bias")):
+            assert g.shape == w.shape
+            _close(g, w, 2e-6, (width, name))
+
+
+@pytest.mark.parametrize("t,hk,hv", _PASS_CASES)
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_gated_norm_kernels_match_the_plain_chain(t, hk, hv, dtype,
+                                                  blocks_of_256_rows):
+    """Outputs (in the out-projection's operand type) and the gradients
+    of o, z and the norm's weight."""
+    rng = np.random.default_rng(t + hv)
+    out, gate, weight = (
+        jnp.asarray(rng.normal(size=(2, t, hv * 128)), jnp.float32)
+        for _ in range(3)
+    )
+    norm_weight = jnp.asarray(rng.normal(size=(128,)), jnp.float32)
+
+    def run(pallas):
+        def total(out, gate, norm_weight):
+            y = gdn_passes.gated_rms_norm(
+                out, gate, norm_weight, eps=1e-6, dtype=dtype,
+                pallas=pallas, interpret=True,
+            )
+            return jnp.sum(y.astype(jnp.float32) * weight), y
+
+        return jax.jit(jax.value_and_grad(
+            total, argnums=(0, 1, 2), has_aux=True
+        ))(out, gate, norm_weight)
+
+    ((_, got), got_grads), ((_, want), want_grads) = run(True), run(False)
+    assert got.dtype == want.dtype == dtype
+    # a bfloat16 result may round the last float32 bit the other way
+    _close(got.astype(jnp.float32), want.astype(jnp.float32),
+           1e-6 if dtype == jnp.float32 else 1e-3, "out")
+    for g, w, name in zip(got_grads, want_grads, ("o", "z", "weight")):
+        assert g.shape == w.shape
+        _close(g, w, 2e-6, name)
+
+
+def _parent_gated_delta_net(params, x, hk, hv, dk, dv, eps):
+    """`GatedDeltaNet.__call__` as it was before the layer kept one
+    layout (float32): the projection's result viewed by key head, split,
+    concatenated, padded and shifted, [B, T, H, D] into the rule."""
+    b, t, _ = x.shape
+    r = hv // hk
+    qkvz = (x @ params["in_proj_qkvz"]["kernel"]).reshape(
+        b, t, hk, 2 * dk + 2 * r * dv
+    )
+    q, k, v, z = jnp.split(qkvz, [dk, 2 * dk, 2 * dk + r * dv], axis=-1)
+    ba = (x @ params["in_proj_ba"]["kernel"]).reshape(b, t, hk, 2 * r)
+    beta_in, a = ba[..., :r].reshape(b, t, hv), ba[..., r:].reshape(b, t, hv)
+    mixed = jnp.concatenate(
+        [q.reshape(b, t, hk * dk), k.reshape(b, t, hk * dk),
+         v.reshape(b, t, hv * dv)], axis=-1,
+    )
+    conv = params["conv1d"]
+    padded = jnp.pad(mixed, ((0, 0), (conv.shape[0] - 1, 0), (0, 0)))
+    mixed = jax.nn.silu(sum(
+        padded[:, j:j + t] * conv[j] for j in range(conv.shape[0])
+    ))
+    q, k, v = jnp.split(mixed, [hk * dk, 2 * hk * dk], axis=-1)
+
+    def l2norm(x):
+        return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
+
+    g = -jnp.exp(params["A_log"]) * jax.nn.softplus(a + params["dt_bias"])
+    out, _ = chunk_gated_delta_rule_xla(
+        l2norm(q.reshape(b, t, hk, dk)) / np.sqrt(dk),
+        l2norm(k.reshape(b, t, hk, dk)), v.reshape(b, t, hv, dv),
+        g, jax.nn.sigmoid(beta_in),
+    )
+    out = out * jax.lax.rsqrt(
+        jnp.mean(out * out, axis=-1, keepdims=True) + eps
+    )
+    out = params["norm"] * out * jax.nn.silu(z.reshape(b, t, hv, dv))
+    return out.reshape(b, t, hv * dv) @ params["out_proj"]["kernel"]
+
+
+def _engines_as_on_a_tpu(monkeypatch):
+    """The engines a TPU would be given, in interpret mode: the choice
+    by shapes alone."""
+    monkeypatch.setattr(
+        gated_delta, "_engine",
+        lambda supported, mesh, *why: (
+            "pallas" if supported else "xla", "as on a tpu"
+        ),
+    )
+    monkeypatch.setattr(gated_delta, "_use_interpret", lambda: True)
+    monkeypatch.setattr(gdn_passes, "_use_interpret", lambda: True)
+
+
+@pytest.mark.parametrize("t,hk,hv", [(200, 1, 2), (320, 2, 4), (200, 1, 1)])
+def test_layer_in_one_layout_is_the_layer_it_was(t, hk, hv, monkeypatch):
+    """The whole DeltaNet sublayer on the path a TPU takes (the passes
+    and, where it takes the heads, the rule in their kernels) against
+    the body it had, from the same parameters in the source's column
+    order, at float32: forward to 1e-5, every gradient to 1e-4 of its
+    rms."""
+    _engines_as_on_a_tpu(monkeypatch)
+    module = zoo.GatedDeltaNet(hk, hv, 128, 128, 4, 1e-6, jnp.float32)
+    rng = np.random.default_rng(t)
+    x = jnp.asarray(rng.normal(size=(2, t, 64)), jnp.float32)
+    weight = jnp.asarray(rng.normal(size=(2, t, 64)), jnp.float32)
+    params = _perturbed(module.init(jax.random.PRNGKey(0), x)["params"], 3)
+
+    def new(p, x):
+        return module.apply({"params": p}, x)
+
+    def old(p, x):
+        return _parent_gated_delta_net(p, x, hk, hv, 128, 128, 1e-6)
+
+    with jax.default_matmul_precision("highest"):
+        _close(new(params, x), old(params, x), 1e-5, "forward")
+        got, want = (
+            jax.grad(lambda p, x: jnp.sum(f(p, x) * weight), argnums=(0, 1))(
+                params, x
+            )
+            for f in (new, old)
+        )
+    flat = jax.tree_util.tree_leaves_with_path(got)
+    assert len(flat) == 8  # seven parameters' gradients and x's
+    for (path, g), w in zip(flat, jax.tree.leaves(want)):
+        _close(g, w, 1e-4, jax.tree_util.keystr(path))
+
+
+@pytest.mark.parametrize("backend,devices,mesh,t,dk,taps,engine,why", [
+    # the published shapes on one chip, the cell's case
+    ("tpu", 1, None, 8192, 128, 4, "pallas", "one device"),
+    ("tpu", 4, (1, 1), 8192, 128, 4, "pallas", "one device"),
+    ("tpu", 4, (2, 2), 8192, 128, 4, "pallas",
+     "under shard_map over {'data': 2, 'model': 2}"),
+    ("tpu", 4, None, 8192, 128, 4, "xla", "4 devices and no mesh given"),
+    ("tpu", 1, None, 8192, 256, 4, "pallas", "one device"),
+    # a head is no whole lane tile; rows that are no whole tiles; taps
+    # that reach past the tile before a block
+    ("tpu", 1, None, 8192, 16, 4, "xla",
+     "head sizes, a length or taps the kernels do not take"),
+    ("tpu", 1, None, 150, 128, 4, "xla",
+     "head sizes, a length or taps the kernels do not take"),
+    ("tpu", 1, None, 8192, 128, 10, "xla",
+     "head sizes, a length or taps the kernels do not take"),
+    # a block of one head of 1024 is 8 MiB, a dozen of them past VMEM
+    ("tpu", 1, None, 8192, 1024, 4, "xla",
+     "head sizes, a length or taps the kernels do not take"),
+    ("cpu", 1, None, 8192, 128, 4, "xla", "backend cpu"),
+])
+def test_gdn_passes_engine_choice(backend, devices, mesh, t, dk, taps, engine,
+                                  why, monkeypatch):
+    """The passes' engine by the rule's own rule (backend, what the
+    trace is for) and their `supports`; the layer's trace logs it beside
+    the rule's line and holds the kernels, mapped where a mesh of
+    several devices is named (traced only: shapes, no device)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    monkeypatch.setattr(jax, "device_count", lambda: devices)
+    mesh = mesh and _cpu_mesh(*mesh)
+    module = zoo.GatedDeltaNet(2, 4, dk, dk, taps, 1e-6, jnp.bfloat16, mesh)
+    x = jax.ShapeDtypeStruct((2, t, 64), jnp.float32)
+    variables = jax.eval_shape(
+        zoo.GatedDeltaNet(2, 4, dk, dk, taps, 1e-6, jnp.float32).init,
+        jax.random.PRNGKey(0), x,
+    )
+    lines = []
+    handler = logging.Handler()
+    handler.emit = lambda record: lines.append(record.getMessage())
+    gated_delta.logger.addHandler(handler)
+    try:
+        jaxpr = str(jax.make_jaxpr(
+            jax.grad(lambda v, x: jnp.sum(module.apply(v, x)))
+        )(variables, x))
+    finally:
+        gated_delta.logger.removeHandler(handler)
+    assert lines[0] == (
+        f"gdn passes engine: {engine} T={t} Hk=2 Hv=4 D={dk} ({why})"
+    )
+    assert lines[1].startswith("delta rule engine: ")
+    kernels = ("conv_silu_fwd", "conv_silu_bwd", "gated_norm_fwd",
+               "gated_norm_bwd")
+    for name in kernels:
+        assert (name in jaxpr) == (engine == "pallas"), name
+    if why.startswith("under shard_map"):
+        # the three conv passes, the rule, the norm, each forward and
+        # backward, each mapped on its own
+        assert jaxpr.count("shard_map") >= 10
+
+
+@pytest.mark.parametrize("b,mesh", [(2, (2, 2)), (1, (2, 1))])
+def test_gdn_passes_under_a_mesh_are_the_kernels(b, mesh):
+    """Under a mesh of several devices each pass runs inside a shard_map
+    over the data axis, the taps and the norm's weight whole on every
+    device: outputs and every gradient are the unmapped kernels' own."""
+    rng = np.random.default_rng(b)
+    rows, gate, weight = (
+        jnp.asarray(rng.normal(size=(b, 200, 256)), jnp.float32)
+        for _ in range(3)
+    )
+    taps = jnp.asarray(rng.normal(size=(4, 256)), jnp.float32)
+    norm_weight = jnp.asarray(rng.normal(size=(128,)), jnp.float32)
+
+    def run(mesh):
+        def total(rows, taps, gate, norm_weight):
+            mixed = gdn_passes.conv_silu(
+                rows, taps, head=128, pallas=True, interpret=True, mesh=mesh
+            )
+            out = gdn_passes.gated_rms_norm(
+                mixed, gate, norm_weight, pallas=True, interpret=True,
+                mesh=mesh,
+            )
+            return jnp.sum(out * weight), (mixed, out)
+
+        return jax.jit(jax.value_and_grad(
+            total, argnums=range(4), has_aux=True
+        ))(rows, taps, gate, norm_weight)
 
     (_, want), want_grads = run(None)
     (_, got), got_grads = run(_cpu_mesh(*mesh))

@@ -130,7 +130,7 @@ def test_every_emitted_name_is_in_the_vocabulary():
         "fwd_bwd", "dense_update", "sparse_apply", "grad_accumulate",
         "sparse_adam", "attn", "mlp", "lm_head_loss", "optimizer",
         "gdn", "gdn_scan", "moe", "moe_route", "moe_experts", "moe_shared",
-        "ssm", "ssm_scan",
+        "ssm", "ssm_scan", "gdn_mix",
     }
 
 
@@ -599,7 +599,7 @@ def _state_space_window(seed=0):
     (_dense_window, "_train_window_jit",
      ("fwd_bwd", "attn", "mlp", "lm_head_loss", "optimizer")),
     (_hybrid_window, "_train_window_jit",
-     ("fwd_bwd", "gdn", "gdn_scan", "attn", "moe", "moe_route",
+     ("fwd_bwd", "gdn", "gdn_mix", "gdn_scan", "attn", "moe", "moe_route",
       "moe_experts", "moe_shared", "lm_head_loss", "optimizer")),
     (_state_space_window, "_train_window_jit",
      ("fwd_bwd", "ssm", "ssm_scan", "attn", "moe", "moe_route",

@@ -11,6 +11,7 @@ that uses it.  Nothing runs: a pass here is not a chip run.
 
 import functools
 import importlib
+import json
 import os
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
@@ -195,7 +196,45 @@ def _delta_rule(backward, d=128):
     ]
 
 
+def _gdn_pass(which, backward):
+    """The passes around the rule (`ops/gdn_passes.py`) at the cell's
+    rows: q's conv + silu + l2-norm over 16 heads of 128, v's conv +
+    silu over 32, the gated norm over 32 with its bfloat16 result."""
+    from elasticdl_tpu.ops import gdn_passes
+
+    rows = {"q": 16 * 128, "v": 32 * 128, "norm": 32 * 128}[which]
+    if which == "norm":
+        def fwd(out, gate, weight):
+            return gdn_passes.gated_rms_norm(
+                out, gate, weight, dtype=jnp.bfloat16, pallas=True,
+                interpret=False,
+            )
+
+        shapes = [(2, 8192, rows), (2, 8192, rows), (128,)]
+    else:
+        def fwd(x, taps):
+            return gdn_passes.conv_silu(
+                x, taps, head=128 if which == "q" else 0, scale=128 ** -0.5,
+                pallas=True, interpret=False,
+            )
+
+        shapes = [(2, 8192, rows), (4, rows)]
+
+    def bwd(*args):
+        return jax.grad(
+            lambda *a: jnp.sum(fwd(*a).astype(jnp.float32)),
+            argnums=range(len(shapes)),
+        )(*args)
+
+    return bwd if backward else fwd, [(s, jnp.float32) for s in shapes]
+
+
 _CASES = {
+    **{
+        f"gdn_{which}_{'bwd' if backward else 'fwd'}":
+            functools.partial(_gdn_pass, which, backward)
+        for which in ("q", "v", "norm") for backward in (False, True)
+    },
     "delta_rule_fwd": functools.partial(_delta_rule, False),
     "delta_rule_bwd": functools.partial(_delta_rule, True),
     "delta_rule_bwd_d256": functools.partial(_delta_rule, True, 256),
@@ -289,7 +328,61 @@ def test_hybrid_sublayer_compiles_and_fits_for_v5e(topo, kind, monkeypatch):
     one_chip = SingleDeviceSharding(topo.devices[0])
     compiled = _sublayer_fwd_bwd(module, dtype, one_chip, one_chip)
     assert compiled.memory_analysis().temp_size_in_bytes < temp_gb * 1e9
-    assert ("delta_rule_bwd" in compiled.as_text()) == (kind == "gdn_pallas")
+    for kernel in ("delta_rule_bwd", "conv_silu_bwd", "gated_norm_bwd"):
+        assert (kernel in compiled.as_text()) == (kind == "gdn_pallas")
+
+
+def test_qwen3_next_window_program_compiles_and_fits_for_v5e(
+    topo, monkeypatch
+):
+    """`dp_trainer`'s two-step window program as the worker compiles it
+    for `qwen3-next.train-synth-8k` (2 x 8192 tokens a step; "tpu" said
+    for the engines' choice, as above): 5.09 GB of state donated, and
+    with its temporaries 8.93 GB of the chip's 16 (13.06 GB before the
+    DeltaNet layers kept one layout, PR 29)."""
+    from elasticdl_tpu.parallel import MeshConfig, build_mesh
+    from elasticdl_tpu.parallel.dp_trainer import DataParallelTrainer
+    from model_zoo.qwen3_next import qwen3_next_lm as zoo
+
+    with open(os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "perfbench", "configs", "qwen3-next-80b-a3b.json",
+    )) as f:
+        model = {
+            k: v for k, v in json.load(f)["model"].items()
+            if k != "sample_tokens"
+        }
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "device_count", lambda: 1)
+    mesh = build_mesh(MeshConfig(data=1, model=1), devices=topo.devices[:1])
+    trainer = DataParallelTrainer(
+        zoo.custom_model(use_bf16=True, mesh=mesh, remat=True, **model),
+        zoo.loss, zoo.optimizer(), mesh,
+    )
+    on_chip = NamedSharding(mesh, P())
+    state, _ = jax.eval_shape(
+        lambda: trainer._make_state(
+            jax.random.PRNGKey(0), jnp.zeros((2, 8192), jnp.int32)
+        )
+    )
+    state = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=on_chip),
+        state,
+    )
+    window = jax.ShapeDtypeStruct((2, 2, 8192), jnp.int32, sharding=on_chip)
+    mask = jax.ShapeDtypeStruct((2, 2), jnp.float32, sharding=on_chip)
+    compiled = jax.jit(
+        trainer._train_window_impl, donate_argnums=(0,)
+    ).lower(state, window, window, mask).compile()
+    memory = compiled.memory_analysis()
+    assert 5.09e9 < memory.argument_size_in_bytes < 5.10e9  # 12 B x 424M
+    assert memory.alias_size_in_bytes > 5.09e9              # donated
+    total = memory.argument_size_in_bytes + memory.temp_size_in_bytes
+    assert total < 9.5e9, total
+    text = compiled.as_text()
+    for kernel in ("conv_silu_fwd", "conv_silu_bwd", "gated_norm_fwd",
+                   "gated_norm_bwd", "delta_rule_fwd", "delta_rule_bwd"):
+        assert kernel in text, kernel
 
 
 # The state-space hybrid (model_zoo/nemotron_h) at the widths and the
