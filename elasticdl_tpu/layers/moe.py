@@ -7,25 +7,49 @@ tokens routed to them, and adds what every chip computes alike (the
 shared expert).  `SparseMoeBlock` is that layer for one chip: it is told
 which experts it holds (`held = (first, count)`) and adds nothing that
 stands in for the absent chips or their exchange.  With
-`held = (0, num_experts)` it is the whole layer.  Two architectures, by
-the block's one field `kind` (a router and its experts go together; no
-other pairing exists):
+`held = (0, num_experts)` it is the whole layer.  What varies between
+architectures is said in fields.  `kind` names a score function with the
+expert form it comes with (the three models of the zoo know two
+pairings):
 
-    kind="softmax_gated_silu"                          (model_zoo/qwen3_next)
+    kind="softmax_gated_silu"         (model_zoo/qwen3_next, deepseek_v2)
     p = softmax(W_r x)  over all experts, float32
-    top-k of p, weights divided by their sum (over all k, held or not)
+    top-k of p; with `norm_topk_prob` the weights are divided by their
+    sum (over all k, held or not); times `routed_scale`
     y = sum_k w_k * W_down,k (silu(W_gate,k x) * W_up,k x)   held k only
-      + sigmoid(w_sg . x) * shared(x)       shared: the same gated form
+      + [sigmoid(w_sg . x) *] shared(x)     shared: the same gated form
 
     kind="sigmoid_relu2"                               (model_zoo/nemotron_h)
     s = sigmoid(W_r x)  over all experts, float32
     top-k of s + b (a selection bias: it chooses, and is in no weight)
     w = s at the chosen, divided by their sum, times `routed_scale`
     y = sum_k w_k * W_down,k relu(W_up,k x)^2                held k only
-      + W_down,s relu(W_up,s x)^2           the shared expert, ungated
+      + W_down,s relu(W_up,s x)^2           the shared expert
 
-Both run the SAME block plan, loop, counters and scopes; the expert form
+`shared_gated` says whether a sigmoid gate of the token multiplies the
+shared expert (Qwen3-Next's does; Nemotron-H's and DeepSeek-V2's, which
+is its `n_shared_experts` experts as ONE MLP of their summed width, do
+not; where the field is None the kind's own source decides).  The gated
+shared expert is the module `shared_expert` with `shared_expert_gate`,
+the ungated one `shared_experts`, as in the sources.  `balance_alpha`
+adds a softmax router's balancing loss (below).
+
+All run the SAME block plan, loop, counters and scopes; the expert form
 is two or three stacked weight tensors handed to one loop.
+
+**A softmax router's balancing loss** (`balance_alpha` > 0; DeepSeek-V2's
+`seq_aux`).  For each sequence of T tokens, `f_i` = (times expert i was
+chosen in the sequence) E / (k T) and `P_i` = the mean of `p_i` over the
+sequence; `L_bal = alpha * mean over sequences of sum_i f_i P_i`, over
+ALL E experts (it needs only the router).  The counts are constants: the
+gradient reaches the router through `P`.  As the source's
+`AddAuxiliaryLoss`, the loss the trainer reports does not change:
+`_with_auxiliary_loss` is the identity on the routing weights whose
+backward pass hands `L_bal` a cotangent of 1, so its gradient is ADDED
+to the router's and no trainer knows of it.  (A trainer that scales its
+loss's cotangent would have to scale this one too; none here does.)  The
+layer counts the loss in its ``routing`` collection (`balance`, a
+float32 sum), and the task's mean rides on ``moe.routing``.
 
 **The selection bias balances the load, and no loss does.**  `b` is in no
 weight, so the loss has no gradient for it.  The source moves it by the
@@ -64,7 +88,7 @@ worker journals their per-task differences as ``moe.routing``.
 from __future__ import annotations
 
 from functools import partial
-from typing import Any, Tuple
+from typing import Any, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -285,6 +309,42 @@ def _load_violation_bwd(violation, cotangent):
 _load_violation.defvjp(_load_violation_fwd, _load_violation_bwd)
 
 
+@jax.custom_vjp
+def _with_auxiliary_loss(weight, loss):
+    """`weight`, unchanged.  In the backward pass `loss` (a scalar)
+    receives a cotangent of 1, whatever `weight`'s is: its gradient is
+    added to the parameters' and the reported loss does not contain it
+    (module docstring)."""
+    return weight
+
+
+def _with_auxiliary_loss_fwd(weight, loss):
+    return weight, None
+
+
+def _with_auxiliary_loss_bwd(_, cotangent):
+    return cotangent, jnp.ones((), jnp.float32)
+
+
+_with_auxiliary_loss.defvjp(_with_auxiliary_loss_fwd, _with_auxiliary_loss_bwd)
+
+
+def sequence_balance_loss(scores, expert, sequences: int):
+    """scores [N, E] (a softmax over the experts), expert [N, k] (the
+    chosen), N = sequences x T -> mean over the sequences of
+    sum_i f_i P_i, f_i = count_i E / (k T) a constant, P_i = mean of
+    scores_i over the sequence (DeepSeek-V2's `seq_aux`, before alpha)."""
+    n, num_experts = scores.shape
+    t, k = n // sequences, expert.shape[-1]
+    chosen = jnp.sum(
+        expert.reshape(sequences, t * k, 1) == jnp.arange(num_experts),
+        axis=1, dtype=jnp.float32,
+    )
+    f = jax.lax.stop_gradient(chosen) * (num_experts / (k * t))
+    mean_score = jnp.mean(scores.reshape(sequences, t, num_experts), axis=1)
+    return jnp.mean(jnp.sum(f * mean_score, axis=-1))
+
+
 class _BiasedGate(nn.Module):
     """The source's `gate` of a sigmoid router: its `weight` and the
     `e_score_correction_bias` that takes part in the selection only."""
@@ -310,11 +370,16 @@ class SparseMoeBlock(nn.Module):
     norm_topk_prob: bool = True
     dtype: Any = jnp.bfloat16
     block_rows: int = 128
-    # The two architectures (module docstring): a softmax router with
-    # gated-SiLU experts, or a sigmoid router (a selection bias, a scale
-    # on the weights) with relu^2 experts and an ungated shared expert.
+    # A score function and the expert form it comes with (module
+    # docstring): a softmax router with gated-SiLU experts, or a sigmoid
+    # router (a selection bias) with relu^2 experts.
     kind: str = "softmax_gated_silu"
     routed_scale: float = 1.0
+    # Whether a sigmoid gate multiplies the shared expert; None: as the
+    # kind's first source has it (softmax: gated, sigmoid: not).
+    shared_gated: Optional[bool] = None
+    # > 0: the softmax router's sequence-wise balancing loss, times this.
+    balance_alpha: float = 0.0
 
     @nn.compact
     def __call__(self, x):
@@ -327,6 +392,11 @@ class SparseMoeBlock(nn.Module):
         if self.kind not in ("softmax_gated_silu", "sigmoid_relu2"):
             raise ValueError(f"no expert layer of kind {self.kind!r}")
         gated = self.kind == "softmax_gated_silu"
+        shared_gated = (
+            gated if self.shared_gated is None else self.shared_gated
+        )
+        if self.balance_alpha and not gated:
+            raise ValueError("the balancing loss is a softmax router's")
         shape, d = x.shape, x.shape[-1]
         x = x.reshape(-1, d)
         n = x.shape[0]
@@ -355,10 +425,15 @@ class SparseMoeBlock(nn.Module):
                 x.astype(jnp.float32), router,
                 precision=jax.lax.Precision.HIGHEST,
             )
+            balance = None
             if gated:
-                weight, expert = jax.lax.top_k(
-                    jax.nn.softmax(logits, axis=-1), self.top_k
-                )
+                scores = jax.nn.softmax(logits, axis=-1)
+                weight, expert = jax.lax.top_k(scores, self.top_k)
+                if self.balance_alpha:
+                    balance = self.balance_alpha * sequence_balance_loss(
+                        scores, expert, shape[0] if len(shape) == 3 else 1
+                    )
+                    weight = _with_auxiliary_loss(weight, balance)
             else:
                 scores = jax.nn.sigmoid(logits)
                 _, expert = jax.lax.top_k(scores + select_bias, self.top_k)
@@ -382,10 +457,11 @@ class SparseMoeBlock(nn.Module):
             plan, self.top_k, self.block_rows,
         )
         with jax.named_scope("moe_shared"):
-            if gated:
-                shared = GatedMLP(
-                    self.shared_width, self.dtype, name="shared_expert"
-                )(x)
+            shared = (GatedMLP if gated else Relu2MLP)(
+                self.shared_width, self.dtype,
+                name="shared_expert" if shared_gated else "shared_experts",
+            )(x)
+            if shared_gated:
                 shared_gate = self.param("shared_expert_gate", init, (d, 1),
                                          jnp.float32)
                 # A block's product like the expert's own: operands in
@@ -394,25 +470,29 @@ class SparseMoeBlock(nn.Module):
                     x.astype(self.dtype), shared_gate.astype(self.dtype),
                     preferred_element_type=jnp.float32,
                 )) * shared
-            else:
-                shared = Relu2MLP(
-                    self.shared_width, self.dtype, name="shared_experts"
-                )(x)
             y = y + shared
-        self._count(is_held, rows, plan["counts"], n_held)
+        self._count(is_held, rows, plan["counts"], n_held, balance)
         return y.reshape(shape)
 
-    def _count(self, is_held, rows, counts, n_held: int) -> None:
+    def _count(self, is_held, rows, counts, n_held: int, balance) -> None:
         zero = lambda *s: jnp.zeros(s, jnp.uint32)  # noqa: E731
         pairs = self.variable(ROUTING_COLLECTION, "pairs", zero)
         processed = self.variable(ROUTING_COLLECTION, "processed", zero)
         load = self.variable(ROUTING_COLLECTION, "load", zero, n_held)
-        if self.is_mutable_collection(ROUTING_COLLECTION) and (
+        counting = self.is_mutable_collection(ROUTING_COLLECTION) and (
             not self.is_initializing()
-        ):
+        )
+        if counting:
             pairs.value = pairs.value + jnp.sum(is_held).astype(jnp.uint32)
             processed.value = processed.value + rows.astype(jnp.uint32)
             load.value = load.value + counts.astype(jnp.uint32)
+        if balance is not None:  # only a layer that is told an alpha
+            total = self.variable(
+                ROUTING_COLLECTION, "balance",
+                lambda: jnp.zeros((), jnp.float32),
+            )
+            if counting:
+                total.value = total.value + jax.lax.stop_gradient(balance)
 
 
 class RoutingLedger:
@@ -435,10 +515,14 @@ class RoutingLedger:
         layers = sorted({path[:-1] for path in flat})
         return {
             key: np.stack([
-                np.asarray(flat[layer + (key,)], np.uint32)
+                np.asarray(flat[layer + (key,)], dtype)
                 for layer in layers
             ])
-            for key in ("pairs", "processed", "load")
+            for key, dtype in (
+                ("pairs", np.uint32), ("processed", np.uint32),
+                ("load", np.uint32), ("balance", np.float32),
+            )
+            if layers[0] + (key,) in flat
         }
 
     def seed_once(self, model_state) -> None:
@@ -447,8 +531,9 @@ class RoutingLedger:
         if self._seen is None:
             self._seen = self._read(model_state or {})
 
-    def task_delta(self, model_state):
-        """-> the span's fields, or None for a model that counts nothing."""
+    def task_delta(self, model_state, steps: int = 1):
+        """-> the span's fields, or None for a model that counts nothing.
+        `steps`: the task's steps (`balance_loss` is a mean over them)."""
         now = self._read(model_state)
         if not now:
             return None
@@ -458,7 +543,7 @@ class RoutingLedger:
             (now[key] - seen[key]).astype("int64")
             for key in ("pairs", "processed", "load")
         )
-        return {
+        fields = {
             "layers": int(load.shape[0]),
             "held": int(load.shape[1]),
             "pairs": int(pairs.sum()),
@@ -466,3 +551,8 @@ class RoutingLedger:
             "load_max": int(load.max()),
             "load_mean": float(load.mean()),
         }
+        if "balance" in now:  # the mean over the layers and the steps
+            fields["balance_loss"] = float(
+                np.mean(now["balance"] - seen["balance"]) / max(steps, 1)
+            )
+        return fields

@@ -140,7 +140,9 @@ SPAN_NAMES: Dict[str, str] = {
     # expert routing (layers/moe.py): a task's counters ride on a span
     "moe.routing": "after: worker, one a task of a model with expert "
                    "layers: pairs routed to held experts, dropped (0), "
-                   "largest and mean load of a held expert",
+                   "largest and mean load of a held expert, and the mean "
+                   "balancing loss where the routers have one "
+                   "(`balance_loss`)",
 }
 
 #: ``jax.named_scope`` names on device ops (op metadata only; they show
@@ -150,12 +152,14 @@ SPAN_NAMES: Dict[str, str] = {
 #: optimizer; with the hybrid expert model (model_zoo/qwen3_next) fwd_bwd
 #: > (gdn > (gdn_mix, gdn_scan), attn, moe > (moe_route, moe_experts,
 #: moe_shared), lm_head_loss); with the state-space hybrid (model_zoo/nemotron_h)
-#: fwd_bwd > (ssm > ssm_scan, attn, moe > (...), lm_head_loss).
+#: fwd_bwd > (ssm > ssm_scan, attn, moe > (...), lm_head_loss).  With the
+#: latent-attention model (model_zoo/deepseek_v2): fwd_bwd > (attn >
+#: (mla_latent, mla_core), mlp, moe > (...), lm_head_loss).
 DEVICE_SCOPES = (
     "fwd_bwd", "dense_update", "sparse_apply", "grad_accumulate",
     "sparse_adam", "attn", "mlp", "lm_head_loss", "optimizer",
     "gdn", "gdn_scan", "moe", "moe_route", "moe_experts", "moe_shared",
-    "ssm", "ssm_scan", "gdn_mix",
+    "ssm", "ssm_scan", "gdn_mix", "mla_latent", "mla_core",
 )
 
 #: Size bound on the flight recorder's final registry snapshot: the

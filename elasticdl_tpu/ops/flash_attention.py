@@ -87,7 +87,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal,
 
     m0 = jnp.full((block_q, 1), NEG_INF, jnp.float32)
     l0 = jnp.zeros((block_q, 1), jnp.float32)
-    acc0 = jnp.zeros((block_q, q.shape[1]), jnp.float32)
+    acc0 = jnp.zeros((block_q, v_ref.shape[3]), jnp.float32)
     m, l, acc = jax.lax.fori_loop(0, n_k, body, (m0, l0, acc0))
     l_safe = jnp.where(l == 0.0, 1.0, l)
     o_ref[0, 0] = (acc / l_safe).astype(o_ref.dtype)
@@ -96,6 +96,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal,
 
 def _fwd(q, k, v, scale, causal, block_q, block_k, interpret):
     b, h, t, d = q.shape
+    dv = v.shape[3]  # the values' head size may differ from q's and k's
     grid = (b, h, t // block_q)
     out, lse = pl.pallas_call(
         functools.partial(
@@ -106,14 +107,14 @@ def _fwd(q, k, v, scale, causal, block_q, block_k, interpret):
         in_specs=[
             pl.BlockSpec((1, 1, block_q, d), lambda b, h, i: (b, h, i, 0)),
             pl.BlockSpec((1, 1, t, d), lambda b, h, i: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, t, d), lambda b, h, i: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, t, dv), lambda b, h, i: (b, h, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, block_q, d), lambda b, h, i: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, block_q, dv), lambda b, h, i: (b, h, i, 0)),
             pl.BlockSpec((1, 1, block_q, 1), lambda b, h, i: (b, h, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, h, t, d), q.dtype),
+            jax.ShapeDtypeStruct((b, h, t, dv), q.dtype),
             jax.ShapeDtypeStruct((b, h, t, 1), jnp.float32),
         ],
         interpret=interpret,
@@ -232,6 +233,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 def _bwd(scale, causal, block_q, block_k, interpret, res, g):
     q, k, v, out, lse = res
     b, h, t, d = q.shape
+    dv = v.shape[3]
     do = g.astype(jnp.float32)
     # delta_i = rowsum(dO_i * O_i) — the softmax-jacobian diagonal term.
     delta = jnp.sum(
@@ -254,10 +256,10 @@ def _bwd(scale, causal, block_q, block_k, interpret, res, g):
                 (1, 1, block_k, d), lambda b, h, i, j: (b, h, j, 0)
             ),
             pl.BlockSpec(
-                (1, 1, block_k, d), lambda b, h, i, j: (b, h, j, 0)
+                (1, 1, block_k, dv), lambda b, h, i, j: (b, h, j, 0)
             ),
             pl.BlockSpec(
-                (1, 1, block_q, d), lambda b, h, i, j: (b, h, i, 0)
+                (1, 1, block_q, dv), lambda b, h, i, j: (b, h, i, 0)
             ),
             pl.BlockSpec(
                 (1, 1, block_q, 1), lambda b, h, i, j: (b, h, i, 0)
@@ -288,10 +290,10 @@ def _bwd(scale, causal, block_q, block_k, interpret, res, g):
                 (1, 1, block_k, d), lambda b, h, j, i: (b, h, j, 0)
             ),
             pl.BlockSpec(
-                (1, 1, block_k, d), lambda b, h, j, i: (b, h, j, 0)
+                (1, 1, block_k, dv), lambda b, h, j, i: (b, h, j, 0)
             ),
             pl.BlockSpec(
-                (1, 1, block_q, d), lambda b, h, j, i: (b, h, i, 0)
+                (1, 1, block_q, dv), lambda b, h, j, i: (b, h, i, 0)
             ),
             pl.BlockSpec(
                 (1, 1, block_q, 1), lambda b, h, j, i: (b, h, i, 0)
@@ -305,16 +307,16 @@ def _bwd(scale, causal, block_q, block_k, interpret, res, g):
                 (1, 1, block_k, d), lambda b, h, j, i: (b, h, j, 0)
             ),
             pl.BlockSpec(
-                (1, 1, block_k, d), lambda b, h, j, i: (b, h, j, 0)
+                (1, 1, block_k, dv), lambda b, h, j, i: (b, h, j, 0)
             ),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, h, t, d), k.dtype),
-            jax.ShapeDtypeStruct((b, h, t, d), v.dtype),
+            jax.ShapeDtypeStruct((b, h, t, dv), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, dv), jnp.float32),
         ],
         interpret=interpret,
     )(q, k, v, g, lse, delta)
@@ -692,20 +694,32 @@ def _kv_vmem_bytes_max() -> int:
     return _configured_scoped_vmem_kib() * 1024 // 2
 
 
-def shape_aligned(t: int, d: int, block: int = DEFAULT_BLOCK) -> bool:
+def shape_aligned(t: int, d: int, block: int = DEFAULT_BLOCK,
+                  d_v: Optional[int] = None) -> bool:
     """The pure shape-capability half of `supports()` (block/sublane
-    alignment), independent of the VMEM budget."""
+    alignment), independent of the VMEM budget.  `d` is the head size of
+    q and k, `d_v` that of v (d where not given)."""
     block = min(block, t)
-    return t % block == 0 and t % 8 == 0 and d % 8 == 0
+    d_v = d if d_v is None else d_v
+    return t % block == 0 and t % 8 == 0 and d % 8 == 0 and d_v % 8 == 0
 
 
-def supports(t: int, d: int, block: int = DEFAULT_BLOCK) -> bool:
-    """Whether the kernel handles this (seq_len, head_dim) shape within
+def supports(t: int, d: int, block: int = DEFAULT_BLOCK,
+             d_v: Optional[int] = None) -> bool:
+    """Whether the kernel handles this (seq_len, head sizes) shape within
     the CONFIGURED scoped-VMEM budget (LIBTPU_INIT_ARGS-aware)."""
-    return shape_aligned(t, d, block) and not kv_vmem_exceeded(t, d)
+    return shape_aligned(t, d, block, d_v) and not kv_vmem_exceeded(
+        t, d, d_v
+    )
 
 
-def kv_vmem_exceeded(t: int, d: int) -> bool:
+def kv_vmem_bytes(t: int, d: int, d_v: Optional[int] = None) -> int:
+    """K [T, d] and V [T, d_v] of one head, float32: what the forward
+    kernel keeps resident."""
+    return t * (d + (d if d_v is None else d_v)) * 4
+
+
+def kv_vmem_exceeded(t: int, d: int, d_v: Optional[int] = None) -> bool:
     """True when the KV block exceeds the configured scoped-VMEM budget —
     the operator can raise it with
     LIBTPU_INIT_ARGS=--xla_tpu_scoped_vmem_limit_kib (65536 is the
@@ -714,7 +728,7 @@ def kv_vmem_exceeded(t: int, d: int) -> bool:
     callers warn when this is the SOLE blocker (check `shape_aligned`
     too — advising the flag on a misaligned shape would point at a
     kernel that still cannot run)."""
-    return 2 * t * d * 4 > _kv_vmem_bytes_max()
+    return kv_vmem_bytes(t, d, d_v) > _kv_vmem_bytes_max()
 
 
 # The measured-working scoped-VMEM limit for the long-T kernel shapes
@@ -722,22 +736,25 @@ def kv_vmem_exceeded(t: int, d: int) -> bool:
 VMEM_FLAG_ADVICE = "LIBTPU_INIT_ARGS=--xla_tpu_scoped_vmem_limit_kib=65536"
 
 
-def warn_if_vmem_is_sole_blocker(logger_name: str, t: int, d: int) -> bool:
+def warn_if_vmem_is_sole_blocker(logger_name: str, t: int, d: int,
+                                 d_v: Optional[int] = None) -> bool:
     """Auto-mode honesty contract: when the Pallas kernel is rejected
     ONLY by the VMEM budget (shape alignment fine), log the flag that
     unlocks it — a silent fallback at long T leaves up to ~3x on the
     table exactly where the kernel matters most.  Returns whether the
     warning fired (trace-time, so once per compile)."""
-    if not (shape_aligned(t, d) and kv_vmem_exceeded(t, d)):
+    if not (shape_aligned(t, d, d_v=d_v) and kv_vmem_exceeded(t, d, d_v)):
         return False
     from elasticdl_tpu.common.log_utils import get_logger
 
     get_logger(logger_name).warning(
-        "attn impl=auto fell back to the XLA block engine at T=%d D=%d: "
-        "the KV block (%.1f MiB f32) exceeds the flag-free scoped-VMEM "
-        "budget. Set %s and force attn_impl=pallas to unlock the Pallas "
-        "kernel (up to ~3x at long T; BASELINE.md ring-attention table).",
-        t, d, 2 * t * d * 4 / 2**20, VMEM_FLAG_ADVICE,
+        "attn impl=auto fell back to the XLA block engine at T=%d Dqk=%d "
+        "Dv=%d: the KV block (%.1f MiB f32) exceeds the flag-free "
+        "scoped-VMEM budget. Set %s and force attn_impl=pallas to unlock "
+        "the Pallas kernel (up to ~3x at long T; BASELINE.md "
+        "ring-attention table).",
+        t, d, d if d_v is None else d_v,
+        kv_vmem_bytes(t, d, d_v) / 2**20, VMEM_FLAG_ADVICE,
     )
     return True
 
@@ -753,7 +770,8 @@ def flash_attention(
     block_k: int = DEFAULT_BLOCK,
     interpret: Optional[bool] = None,
 ):
-    """Self-attention [B, T, H, D] -> [B, T, H, D], Pallas kernels.
+    """Self-attention q, k [B, T, H, D], v [B, T, H, Dv] -> [B, T, H, Dv],
+    Pallas kernels; scores scaled by `scale` (1/sqrt(D) where not given).
 
     T must be a multiple of block_q/block_k (`supports()` checks); use
     parallel.ring_attention.blockwise_attention for irregular shapes.

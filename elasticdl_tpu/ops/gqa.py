@@ -1,15 +1,21 @@
 """Rotary position embedding and grouped-query causal attention.
 
 Rotary is the rotate-half form on the first `rotary_dim` of a head's
-dimensions (a partial rotary factor leaves the rest untouched).
+dimensions (a partial rotary factor leaves the rest untouched), with
+plain frequencies (`rotary_tables`) or YaRN's blend of interpolated and
+extrapolated ones (`yarn_rotary_tables`).
 
 Grouped-query attention gives every key-value head to `Hq / Hkv` query
-heads.  Two engines:
+heads.  q and k share a head size `Dqk`; v may have another, `Dv` (latent
+attention: scores over a head's position-free and rotary parts, values
+of the position-free size), and the output has v's.  The scores are
+scaled by `scale`, 1/sqrt(Dqk) where none is given.  Two engines:
 
 - `ops.flash_attention`, the Pallas kernel, where the backend is a TPU and
-  `supports(T, D)` holds (K and V of a head resident in VMEM: T <= 4096 at
-  a head size of 256).  It takes equal head counts, so K and V are
-  repeated to the query heads for it.
+  `supports(T, Dqk, d_v=Dv)` holds (K and V of a head resident in VMEM:
+  T (Dqk + Dv) 4 B within 8 MiB, so T <= 4096 at head sizes of 256 or of
+  192 and 128).  It takes equal head counts, so K and V are repeated to
+  the query heads for it.
 - `causal_gqa_attention`, flash numerics in XLA ops, for everything else.
   `parallel.ring_attention.blockwise_attention` scores ALL queries against
   one key chunk at a time, and the reverse pass JAX derives for its scan
@@ -28,6 +34,7 @@ heads.  Two engines:
 
 from __future__ import annotations
 
+import math
 from functools import partial
 
 import jax
@@ -38,14 +45,75 @@ from elasticdl_tpu.common.log_utils import get_logger
 logger = get_logger("ops.gqa")
 
 
-def rotary_tables(positions, rotary_dim: int, theta: float):
-    """-> (cos, sin), each [T, rotary_dim], float32."""
-    inv_freq = 1.0 / (
+def _plain_inv_freq(rotary_dim: int, theta: float):
+    return 1.0 / (
         theta ** (jnp.arange(0, rotary_dim, 2, dtype=jnp.float32) / rotary_dim)
     )
+
+
+def _angles(positions, inv_freq):
+    """[T, rotary_dim]: position x frequency, the pairs' halves side by
+    side (rotate-half form)."""
     angles = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]
-    angles = jnp.concatenate([angles, angles], axis=-1)
+    return jnp.concatenate([angles, angles], axis=-1)
+
+
+def rotary_tables(positions, rotary_dim: int, theta: float):
+    """-> (cos, sin), each [T, rotary_dim], float32."""
+    angles = _angles(positions, _plain_inv_freq(rotary_dim, theta))
     return jnp.cos(angles), jnp.sin(angles)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention-temperature term m(f, a) = 0.1 a ln f + 1 (1 for
+    f <= 1)."""
+    return 1.0 if factor <= 1.0 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_correction_range(rotary_dim: int, theta: float, original: int,
+                          beta_fast: float, beta_slow: float):
+    """-> (low, high): the rotary PAIRS between which YaRN blends.  Pair
+    i turns `original theta^(-2i/dim) / 2 pi` times over the positions
+    the model was first trained on; d(beta) is the pair that turns beta
+    times.  Pairs below floor(d(beta_fast)) keep their frequency, pairs
+    above ceil(d(beta_slow)) are interpolated."""
+    def pair(turns):
+        return rotary_dim * math.log(original / (turns * 2 * math.pi)) / (
+            2 * math.log(theta)
+        )
+
+    low = max(math.floor(pair(beta_fast)), 0)
+    high = min(math.ceil(pair(beta_slow)), rotary_dim - 1)
+    return low, high
+
+
+def yarn_rotary_tables(positions, rotary_dim: int, theta: float, *,
+                       factor: float, original: int, beta_fast: float = 32,
+                       beta_slow: float = 1, mscale: float = 1.0,
+                       mscale_all_dim: float = 0.0):
+    """-> (cos, sin), each [T, rotary_dim], float32, with YaRN's
+    frequencies (arXiv:2309.00071, as `deepseek_v2` computes them):
+    pair i of `rotary_dim / 2` has the extrapolated frequency
+    `theta^(-2i/dim)` and the interpolated one, that over `factor`;
+    `ramp_i = clip((i - low) / (high - low), 0, 1)` blends them,
+    `inv_freq_i = interpolated ramp_i + extrapolated (1 - ramp_i)`.  Both
+    tables are multiplied by m(factor, mscale) / m(factor, mscale_all_dim)
+    (`yarn_mscale`); the softmax scale's own factor is the caller's."""
+    extrapolated = _plain_inv_freq(rotary_dim, theta)
+    low, high = yarn_correction_range(
+        rotary_dim, theta, original, beta_fast, beta_slow
+    )
+    ramp = jnp.clip(
+        (jnp.arange(rotary_dim // 2, dtype=jnp.float32) - low)
+        / max(high - low, 1e-3), 0.0, 1.0,
+    )
+    angles = _angles(
+        positions, extrapolated / factor * ramp + extrapolated * (1.0 - ramp)
+    )
+    magnitude = yarn_mscale(factor, mscale) / yarn_mscale(
+        factor, mscale_all_dim
+    )
+    return jnp.cos(angles) * magnitude, jnp.sin(angles) * magnitude
 
 
 def apply_rotary(x, cos, sin):
@@ -89,11 +157,12 @@ def _scores(q_i, k_j, i, j, block, scale):
     return jnp.where(cols[None, :] > rows[:, None], NEG_INF, s)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(3,))
-def causal_gqa_attention(q, k, v, block: int):
-    """q [B, T, Hq, D]; k, v [B, T, Hkv, D] -> [B, T, Hq, D]; softmax of
-    q k^T / sqrt(D) under a causal mask, float32 accumulation."""
-    return _gqa_fwd(q, k, v, block)[0]
+@partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def causal_gqa_attention(q, k, v, block: int, scale=None):
+    """q [B, T, Hq, D]; k [B, T, Hkv, D]; v [B, T, Hkv, Dv] ->
+    [B, T, Hq, Dv]; softmax of q k^T scale (1/sqrt(D) where none is
+    given) under a causal mask, float32 accumulation."""
+    return _gqa_fwd(q, k, v, block, scale)[0]
 
 
 def _blocked(x, block):  # [B, T, ...] -> [T / block, B, block, ...]
@@ -103,11 +172,11 @@ def _blocked(x, block):  # [B, T, ...] -> [T / block, B, block, ...]
     )
 
 
-def _gqa_fwd(q, k, v, block):
+def _gqa_fwd(q, k, v, block, scale):
     b, t, hq, d = q.shape
-    n = k.shape[2]
+    n, dv = k.shape[2], v.shape[3]
     g = hq // n
-    scale = 1.0 / (d ** 0.5)
+    scale = 1.0 / (d ** 0.5) if scale is None else scale
     qb = _blocked(q.reshape(b, t, n, g, d), block)
     kb, vb = _blocked(k, block), _blocked(v, block)
 
@@ -129,24 +198,24 @@ def _gqa_fwd(q, k, v, block):
         m, l, acc = jax.lax.fori_loop(0, i + 1, kv_step, (
             jnp.full((b, n, g, block), NEG_INF, jnp.float32),
             jnp.zeros((b, n, g, block), jnp.float32),
-            jnp.zeros((b, n, g, block, d), jnp.float32),
+            jnp.zeros((b, n, g, block, dv), jnp.float32),
         ))
         return None, ((acc / l[..., None]).astype(q.dtype), m + jnp.log(l))
 
     _, (out, lse) = jax.lax.scan(q_step, None, (qb, jnp.arange(t // block)))
-    # out [T/block, B, N, G, block, D] -> [B, T, Hq, D]
-    out = jnp.moveaxis(out, (0, 4), (1, 2)).reshape(b, t, hq, d)
+    # out [T/block, B, N, G, block, Dv] -> [B, T, Hq, Dv]
+    out = jnp.moveaxis(out, (0, 4), (1, 2)).reshape(b, t, hq, dv)
     return out, (q, k, v, out, lse)
 
 
-def _gqa_bwd(block, residuals, dout):
+def _gqa_bwd(block, scale, residuals, dout):
     q, k, v, out, lse = residuals
     b, t, hq, d = q.shape
-    n = k.shape[2]
+    n, dv = k.shape[2], v.shape[3]
     g = hq // n
-    scale = 1.0 / (d ** 0.5)
+    scale = 1.0 / (d ** 0.5) if scale is None else scale
     qb = _blocked(q.reshape(b, t, n, g, d), block)
-    dob = _blocked(dout.reshape(b, t, n, g, d), block)
+    dob = _blocked(dout.reshape(b, t, n, g, dv), block)
     delta = _blocked(jnp.sum(
         dout.astype(jnp.float32) * out.astype(jnp.float32), axis=-1
     ).reshape(b, t, n, g), block)                    # [T/block,B,block,N,G]
@@ -200,9 +269,16 @@ def _gqa_bwd(block, residuals, dout):
 causal_gqa_attention.defvjp(_gqa_fwd, _gqa_bwd)
 
 
-def causal_attention(q, k, v, *, impl: str = "auto", block: int = 512):
-    """Causal softmax attention scaled by 1/sqrt(D), grouped-query heads.
-    q [B, T, Hq, D]; k, v [B, T, Hkv, D] -> [B, T, Hq, D]."""
+def _head_sizes(d: int, dv: int) -> str:
+    return f"D={d}" if d == dv else f"Dqk={d} Dv={dv}"
+
+
+def causal_attention(q, k, v, *, scale=None, impl: str = "auto",
+                     block: int = 512):
+    """Causal softmax attention, grouped-query heads; the scores are
+    scaled by `scale`, 1/sqrt(Dqk) where none is given.
+    q [B, T, Hq, Dqk]; k [B, T, Hkv, Dqk]; v [B, T, Hkv, Dv] (Dv may
+    differ from Dqk) -> [B, T, Hq, Dv]."""
     # (`elasticdl_tpu.ops` exports the FUNCTION under the module's name)
     from elasticdl_tpu.ops.flash_attention import (
         _use_interpret, flash_attention, supports,
@@ -211,23 +287,26 @@ def causal_attention(q, k, v, *, impl: str = "auto", block: int = 512):
     if impl not in ("auto", "pallas", "xla"):
         raise ValueError(f"impl must be auto, pallas or xla, got {impl!r}")
     _, t, hq, d = q.shape
+    dv = v.shape[3]
+    sizes = _head_sizes(d, dv)
     use_pallas = impl == "pallas" or (
         impl == "auto" and jax.default_backend() == "tpu"
-        and supports(t, d)
+        and supports(t, d, d_v=dv)
     )
     if use_pallas:
         logger.info(
-            "attention engine: pallas flash_attention T=%d D=%d "
-            "(interpret=%s)", t, d, _use_interpret(),
+            "attention engine: pallas flash_attention T=%d %s "
+            "(interpret=%s)", t, sizes, _use_interpret(),
         )
         n_rep = hq // k.shape[2]
         return flash_attention(
-            q, repeat_kv(k, n_rep), repeat_kv(v, n_rep), causal=True
+            q, repeat_kv(k, n_rep), repeat_kv(v, n_rep), causal=True,
+            scale=scale,
         )
     block = _block_size(t, block)
     logger.info(
-        "attention engine: xla causal_gqa_attention T=%d D=%d "
-        "(blocks of %d)", t, d, block,
+        "attention engine: xla causal_gqa_attention T=%d %s "
+        "(blocks of %d)", t, sizes, block,
     )
     with jax.named_scope("attn"):
-        return causal_gqa_attention(q, k, v, block)
+        return causal_gqa_attention(q, k, v, block, scale)

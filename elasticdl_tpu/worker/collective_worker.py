@@ -536,7 +536,7 @@ class CollectiveWorker:
         model_state = self._model_state()
         if not model_state:
             return
-        fields = self._routing.task_delta(model_state)
+        fields = self._routing.task_delta(model_state, steps)
         if fields is not None:
             tracing.record_child_span(
                 "moe.routing", start_ts, time.time() - start_ts,

@@ -130,7 +130,7 @@ def test_every_emitted_name_is_in_the_vocabulary():
         "fwd_bwd", "dense_update", "sparse_apply", "grad_accumulate",
         "sparse_adam", "attn", "mlp", "lm_head_loss", "optimizer",
         "gdn", "gdn_scan", "moe", "moe_route", "moe_experts", "moe_shared",
-        "ssm", "ssm_scan", "gdn_mix",
+        "ssm", "ssm_scan", "gdn_mix", "mla_latent", "mla_core",
     }
 
 
@@ -595,6 +595,35 @@ def _state_space_window(seed=0):
     return trainer, trainer.stage_window([batch, batch])
 
 
+def _latent_window(seed=0):
+    """(trainer, staged window) of a tiny DeepSeek-V2 on the dp trainer
+    (one dense and one expert layer), each layer rematerialised as the
+    benchmark's configuration runs it."""
+    sys.path.insert(0, REPO_ROOT)
+    from model_zoo.deepseek_v2 import deepseek_v2_lm as zoo
+
+    from elasticdl_tpu.parallel import MeshConfig, build_mesh
+    from elasticdl_tpu.parallel.dp_trainer import DataParallelTrainer
+
+    model = zoo.custom_model(
+        vocab_size=64, hidden_size=32, intermediate_size=48,
+        moe_intermediate_size=16, num_hidden_layers=2, qk_nope_head_dim=8,
+        qk_rope_head_dim=8, v_head_dim=8, kv_lora_rank=16,
+        rope_scaling_factor=40, rope_scaling_mscale_all_dim=0.707,
+        rope_scaling_original_max_position_embeddings=8,
+        experts_first=2, experts_held=4, remat=True,
+    )
+    trainer = DataParallelTrainer(
+        model=model, loss_fn=zoo.loss, optimizer=zoo.optimizer(),
+        mesh=build_mesh(MeshConfig()),
+    )
+    rng = np.random.RandomState(seed)
+    tokens = rng.randint(0, 64, size=(8, 16)).astype(np.int32)
+    trainer.ensure_initialized(tokens)
+    batch = (tokens, tokens, np.ones((8,), np.float32))
+    return trainer, trainer.stage_window([batch, batch])
+
+
 @pytest.mark.parametrize("build,jit_attr,scopes", [
     (_dense_window, "_train_window_jit",
      ("fwd_bwd", "attn", "mlp", "lm_head_loss", "optimizer")),
@@ -603,6 +632,9 @@ def _state_space_window(seed=0):
       "moe_experts", "moe_shared", "lm_head_loss", "optimizer")),
     (_state_space_window, "_train_window_jit",
      ("fwd_bwd", "ssm", "ssm_scan", "attn", "moe", "moe_route",
+      "moe_experts", "moe_shared", "lm_head_loss", "optimizer")),
+    (_latent_window, "_train_window_jit",
+     ("fwd_bwd", "attn", "mla_latent", "mla_core", "mlp", "moe", "moe_route",
       "moe_experts", "moe_shared", "lm_head_loss", "optimizer")),
     (_sparse_window, "_train_window",
      ("fwd_bwd", "dense_update", "sparse_apply", "grad_accumulate",

@@ -93,6 +93,22 @@ def _flash_bwd(d):
     return jax.grad(loss, argnums=(0, 1, 2)), [((B, T, H, d), jnp.bfloat16)] * 3
 
 
+def _flash_two_sizes(backward):
+    """Latent attention's heads (model_zoo/deepseek_v2): q and k of 192,
+    v of 128, at T = 4096, the longest `supports` lets the kernel take
+    (K + V of a head are 5 MiB of float32 there), with a scale."""
+    def out(q, k, v):
+        return fa.flash_attention(
+            q, k, v, causal=True, scale=0.1147, interpret=False
+        )
+
+    def loss(q, k, v):
+        return jnp.sum(out(q, k, v).astype(jnp.float32))
+
+    shapes = [((2, 4096, 16, d), jnp.bfloat16) for d in (192, 192, 128)]
+    return (jax.grad(loss, argnums=(0, 1, 2)) if backward else out), shapes
+
+
 def _ring_step_carry():
     d = 128
 
@@ -242,6 +258,8 @@ _CASES = {
     "flash_fwd_d128": functools.partial(_flash_fwd, 128),
     "flash_bwd_d64": functools.partial(_flash_bwd, 64),
     "flash_bwd_d128": functools.partial(_flash_bwd, 128),
+    "flash_fwd_d192_dv128": functools.partial(_flash_two_sizes, False),
+    "flash_bwd_d192_dv128": functools.partial(_flash_two_sizes, True),
     "ring_step_carry": _ring_step_carry,
     "ring_step_bwd": _ring_step_bwd,
     "fused_lookup": _fused_lookup,
@@ -463,6 +481,58 @@ def test_nemotron_window_program_compiles_and_fits_for_v5e(topo, monkeypatch):
     assert memory.alias_size_in_bytes > 8.0e9              # donated
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 12.5e9
     assert "tpu_custom_call" in compiled.as_text()         # the flash kernel
+
+
+# The latent-attention expert model (model_zoo/deepseek_v2) at the widths
+# and the 2 x 8192 tokens of `deepseek-v2-lite.train-synth-8k`: the whole
+# two-step window program as the worker runs it, 6.42 GB of state donated
+# (12 B x 535,060,992), each layer rematerialised, attention in the XLA
+# block engine (K and V of a head at 192 and 128 are 10 MiB of float32 at
+# T = 8192, past the Pallas kernel's cap).
+def test_deepseek_v2_window_program_compiles_and_fits_for_v5e(
+    topo, monkeypatch
+):
+    from elasticdl_tpu.parallel import MeshConfig, build_mesh
+    from elasticdl_tpu.parallel.dp_trainer import DataParallelTrainer
+    from model_zoo.deepseek_v2 import deepseek_v2_lm as zoo
+
+    with open(os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "perfbench", "configs", "deepseek-v2-lite.json",
+    )) as f:
+        model = {
+            k: v for k, v in json.load(f)["model"].items()
+            if k != "sample_tokens"
+        }
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = build_mesh(MeshConfig(data=1, model=1), devices=topo.devices[:1])
+    trainer = DataParallelTrainer(
+        zoo.custom_model(use_bf16=True, remat=True, **model), zoo.loss,
+        zoo.optimizer(), mesh,
+    )
+    on_chip = NamedSharding(mesh, P())
+    state, _ = jax.eval_shape(
+        lambda: trainer._make_state(
+            jax.random.PRNGKey(0), jnp.zeros((2, 8192), jnp.int32)
+        )
+    )
+    state = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=on_chip),
+        state,
+    )
+    window = jax.ShapeDtypeStruct((2, 2, 8192), jnp.int32, sharding=on_chip)
+    mask = jax.ShapeDtypeStruct((2, 2), jnp.float32, sharding=on_chip)
+    compiled = jax.jit(
+        trainer._train_window_impl, donate_argnums=(0,)
+    ).lower(state, window, window, mask).compile()
+    memory = compiled.memory_analysis()
+    print("deepseek window bytes", memory.argument_size_in_bytes,
+          memory.temp_size_in_bytes, memory.alias_size_in_bytes)
+    assert 6.42e9 < memory.argument_size_in_bytes < 6.43e9  # 12 B x 535M
+    assert memory.alias_size_in_bytes > 6.42e9              # donated
+    total = memory.argument_size_in_bytes + memory.temp_size_in_bytes
+    assert total < 13.0e9, total  # 12.51: the chip holds 16
+    assert "tpu_custom_call" not in compiled.as_text()      # the XLA engine
 
 
 def _four_chip_mesh(topo):
