@@ -165,8 +165,8 @@ def build_master(args, model_spec=None, rendezvous_server=None) -> Master:
     if getattr(args, "tensorboard_log_dir", ""):
         from elasticdl_tpu.obs import tracing
 
-        # The writer's import (torch's tensorboard, TensorFlow behind
-        # it) is most of a master's boot.
+        # The service writes its event files itself: this times the
+        # `tensorboard` package's protos' import and one open().
         with tracing.span("master.tensorboard_init"):
             from elasticdl_tpu.master.tensorboard_service import (
                 TensorBoardService,
@@ -262,6 +262,11 @@ def start_master(args, model_spec=None, rendezvous_server=None) -> Master:
             ready.fields["since_main_s"] = round(
                 time.time() - tracing.main_start_ts(), 6
             )
+        # What a boot paid for in imports: none of these is the
+        # master's own to load (a zoo module may bring jax).
+        ready.fields["heavy_imports"] = sorted(
+            {"torch", "tensorflow", "jax"} & set(sys.modules)
+        )
     # Phase accounting starts here: idle until the first dispatch or
     # world declaration opens a real phase.
     from elasticdl_tpu.obs import goodput

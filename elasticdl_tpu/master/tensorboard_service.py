@@ -8,9 +8,14 @@ progress (model version, records/tasks finished, worker-restart count)
 sampled on a background cadence, since the master — not any worker — is
 the single stable observer of an elastic job.
 
-Writer backend: torch.utils.tensorboard's SummaryWriter (pure event-file
-protocol, no TF runtime).  Missing backend degrades to a warning, never
-a job failure — observability must not take training down.
+Writer backend: `_EventFileWriter` below appends TFRecord-framed `Event`
+protos to one `events.out.tfevents.*` file itself.  It needs only the
+`tensorboard` package's protos (a tenth of a second to import): a
+master never imports a deep-learning framework to write a handful of
+60-byte scalars.  A writer that cannot be built (no `tensorboard`
+package, an unwritable `log_dir`) degrades to one warning and dropped
+scalars, never a job failure — observability must not take training
+down.
 
 Worker-side profiling (jax.profiler traces viewable in the same
 TensorBoard under the Profile plugin) lives in common/profiler.py; this
@@ -19,13 +24,99 @@ module is only the master's scalar plane.
 
 from __future__ import annotations
 
+import os
+import socket
+import struct
 import threading
+import time
 from typing import Callable, Dict, Optional
 
 from elasticdl_tpu.analysis.runtime import make_lock
 from elasticdl_tpu.common.log_utils import get_logger
 
 logger = get_logger("master.tensorboard")
+
+
+def _crc32c_table():
+    table = []
+    for n in range(256):
+        for _ in range(8):
+            n = (n >> 1) ^ (0x82F63B78 if n & 1 else 0)
+        table.append(n)
+    return table
+
+
+_CRC32C_TABLE = _crc32c_table()
+
+
+def crc32c(data: bytes) -> int:
+    """CRC-32C (Castagnoli), the checksum of the TFRecord framing."""
+    crc = 0xFFFFFFFF
+    for byte in data:
+        crc = _CRC32C_TABLE[(crc ^ byte) & 0xFF] ^ (crc >> 8)
+    return crc ^ 0xFFFFFFFF
+
+
+def masked_crc32c(data: bytes) -> int:
+    crc = crc32c(data)
+    return (((crc >> 15) | (crc << 17)) + 0xA282EAD8) & 0xFFFFFFFF
+
+
+def frame_record(payload: bytes) -> bytes:
+    """One TFRecord: little-endian length, its masked CRC, the payload,
+    the payload's masked CRC."""
+    header = struct.pack("<Q", len(payload))
+    return b"".join((
+        header,
+        struct.pack("<I", masked_crc32c(header)),
+        payload,
+        struct.pack("<I", masked_crc32c(payload)),
+    ))
+
+
+class _EventFileWriter:
+    """Appends scalar `Event`s to one TensorBoard event file.  Not
+    thread-safe: the service's lock serializes its callers."""
+
+    def __init__(self, log_dir: str):
+        from tensorboard.compat.proto import event_pb2, summary_pb2
+
+        self._event_pb2 = event_pb2
+        self._summary_pb2 = summary_pb2
+        os.makedirs(log_dir, exist_ok=True)
+        path = os.path.join(
+            log_dir,
+            "events.out.tfevents.%010d.%s.%d.0"
+            % (time.time(), socket.gethostname(), os.getpid()),
+        )
+        self._file = open(path, "ab")
+        self._write(
+            event_pb2.Event(
+                wall_time=time.time(), file_version="brain.Event:2"
+            )
+        )
+        self.flush()
+
+    def _write(self, event):
+        self._file.write(frame_record(event.SerializeToString()))
+
+    def add_scalar(self, tag: str, value: float, step: int):
+        summary = self._summary_pb2.Summary(
+            value=[
+                self._summary_pb2.Summary.Value(tag=tag, simple_value=value)
+            ]
+        )
+        self._write(
+            self._event_pb2.Event(
+                wall_time=time.time(), step=step, summary=summary
+            )
+        )
+
+    def flush(self):
+        self._file.flush()
+
+    def close(self):
+        self._file.close()
 
 
 class TensorBoardService:
@@ -49,9 +140,7 @@ class TensorBoardService:
         self._thread: Optional[threading.Thread] = None
         self._writer = None  # guarded-by: _lock
         try:
-            from torch.utils.tensorboard import SummaryWriter
-
-            self._writer = SummaryWriter(log_dir=log_dir)
+            self._writer = _EventFileWriter(log_dir)
             logger.info("TensorBoard events -> %s", log_dir)
         except Exception:
             logger.exception(
@@ -65,26 +154,30 @@ class TensorBoardService:
     ):
         """EvaluationService pushes each finalized round's metrics here
         (reference method name/contract)."""
-        if self._writer is None:
-            return
         with self._lock:
             for name, value in metrics.items():
-                try:
-                    self._writer.add_scalar(
-                        f"{prefix}/{name}", float(value), int(version)
-                    )
-                except Exception:
-                    logger.exception("Dropping scalar %s", name)
-            self._writer.flush()
+                self._add_scalar_locked(f"{prefix}/{name}", value, version)
+            self._flush_locked()
 
     def write_scalar(self, tag: str, value: float, step: int):
+        with self._lock:
+            self._add_scalar_locked(tag, value, step)
+
+    def _add_scalar_locked(self, tag: str, value, step):
         if self._writer is None:
             return
-        with self._lock:
-            try:
-                self._writer.add_scalar(tag, float(value), int(step))
-            except Exception:
-                logger.exception("Dropping scalar %s", tag)
+        try:
+            self._writer.add_scalar(tag, float(value), int(step))
+        except Exception:
+            logger.exception("Dropping scalar %s", tag)
+
+    def _flush_locked(self):
+        if self._writer is None:
+            return
+        try:
+            self._writer.flush()
+        except OSError:
+            logger.exception("TensorBoard flush failed")
 
     def bind(
         self,
@@ -140,6 +233,10 @@ class TensorBoardService:
             self.write_scalar(
                 "train/worker_restarts", self._restarts_fn(), version
             )
+        # No queue thread stands behind the writer: what a sample wrote
+        # is on disk when the sample returns.
+        with self._lock:
+            self._flush_locked()
 
     def _sample_loop(self):
         while not self._stop_event.wait(self._sample_interval_s):
@@ -152,10 +249,17 @@ class TensorBoardService:
         self._stop_event.set()
         if self._thread is not None:
             self._thread.join(timeout=5)
-        if self._writer is not None:
+        if self._writer is None:
+            return
+        try:
+            self._sample_progress()  # final datapoint at job end
+        except Exception:
+            logger.exception("TensorBoard final sample failed")
+        with self._lock:
+            writer, self._writer = self._writer, None
+            if writer is None:
+                return
             try:
-                self._sample_progress()  # final datapoint at job end
-                self._writer.flush()
-                self._writer.close()
-            except Exception:
-                pass
+                writer.close()
+            except OSError:
+                logger.exception("TensorBoard close failed")

@@ -132,8 +132,10 @@ SPAN_NAMES: Dict[str, str] = {
     "checkpoint.restore.load": "interval: read, unpickle, place",
     # start-up
     "proc.start": "after: process creation -> first line of main",
-    "master.tensorboard_init": "interval: TensorFlow import + writer",
-    "master.serve_ready": "interval: gRPC server + exporter start",
+    "master.tensorboard_init": "interval: the scalar service's event-"
+                               "file writer (protos + open)",
+    "master.serve_ready": "interval: gRPC server + exporter start "
+                          "(`since_main_s`, `heavy_imports`)",
     "worker.backend_init": "interval: first jax.devices()",
     "state.init": "interval: model.init / restore + placement",
     "compile.build": "interval: first call of a jitted entrypoint",
