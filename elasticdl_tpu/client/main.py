@@ -29,14 +29,17 @@ def _print_usage():
 def main(argv=None):
     from elasticdl_tpu.obs import tracing
 
-    tracing.note_main_start()  # the end of the `proc.start` span
+    # The end of `proc.start`; `elasticdl train` runs the master in this
+    # process, and its boot starts here (closed where it serves).
+    tracing.begin_boot("master.boot")
     argv = list(sys.argv[1:] if argv is None else argv)
     if not argv or argv[0] in ("-h", "--help"):
         _print_usage()
         return 0
     command, rest = argv[0], argv[1:]
     if command in ("train", "evaluate", "predict"):
-        from elasticdl_tpu.client import api
+        with tracing.early_span("master.imports"):
+            from elasticdl_tpu.client import api
 
         return getattr(api, command)(rest)
     if command == "zoo":

@@ -100,8 +100,27 @@ def _forward_flag(custom_model, model_params: dict, name, value) -> None:
         model_params[name] = value
 
 
+#: Frameworks a zoo module may bring along; `spec.load` says which of
+#: them entered `sys.modules` while it ran.
+_FRAMEWORKS = ("jax", "flax", "torch", "tensorflow")
+
+
 def load_model_spec(args) -> ModelSpec:
-    """Resolve the model-zoo contract from parsed args."""
+    """Resolve the model-zoo contract from parsed args, inside a
+    `spec.load` span: the zoo module's import is seconds of a master's
+    and of a worker's boot."""
+    from elasticdl_tpu.obs import tracing
+
+    with tracing.span("spec.load") as span:
+        absent = [name for name in _FRAMEWORKS if name not in sys.modules]
+        spec = _load_model_spec(args)
+        span.fields["imported"] = [
+            name for name in absent if name in sys.modules
+        ]
+    return spec
+
+
+def _load_model_spec(args) -> ModelSpec:
     module = load_module(args.model_zoo, args.model_def)
 
     def require(name):

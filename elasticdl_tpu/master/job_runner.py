@@ -22,6 +22,7 @@ from elasticdl_tpu.master.pod_manager import (
     worker_argv_from_args,
 )
 from elasticdl_tpu.master.rendezvous_server import ElasticRendezvous
+from elasticdl_tpu.obs import tracing
 
 logger = get_logger("master.job_runner")
 
@@ -321,6 +322,52 @@ def run_allreduce_job(args, mode: str = Mode.TRAINING) -> int:
     _ensure_elastic_checkpointing(args, mode)
     rendezvous = ElasticRendezvous()
     master = start_master(args, rendezvous_server=rendezvous)
+    # Serving, and no worker yet: what stands between the two is timed
+    # (`master.launch_worker` follows inside `manager.start()`).
+    with tracing.span("master.build_fleet"):
+        manager, policy_engine, slo_plane = _build_fleet(
+            args, mode, master, rendezvous
+        )
+    progress_persister = master.progress_persister
+    job_succeeded = False
+    try:
+        manager.start()
+        if policy_engine is not None:
+            policy_engine.start()
+        if slo_plane is not None:
+            slo_plane.start()
+        ok = manager.wait()
+        if master.evaluation_service is not None:
+            master.evaluation_service.finalize()
+            metrics = master.evaluation_service.latest_metrics
+            if metrics:
+                logger.info("Final metrics: %s", metrics)
+        if not ok:
+            logger.error("Job failed: %s", manager.failed_reason)
+            return 1
+        if not master.task_manager.finished():
+            logger.error("Workers exited but tasks remain unfinished")
+            return 1
+        logger.info("AllReduce job complete")
+        job_succeeded = True
+        return 0
+    finally:
+        if slo_plane is not None:
+            slo_plane.stop()
+        if policy_engine is not None:
+            policy_engine.stop()
+        manager.stop()
+        master.stop()
+        if job_succeeded and progress_persister is not None:
+            # Leaving a terminal snapshot behind would turn the next run
+            # with this checkpoint_dir into a silent no-op.
+            progress_persister.clear()
+
+
+def _build_fleet(args, mode: str, master, rendezvous):
+    """What a serving master still builds before its first worker: the
+    policy engine, the worker manager and the SLO plane, wired to the
+    master's services.  Returns (manager, policy_engine, slo_plane)."""
     if mode == Mode.EVALUATION:
         if master.evaluation_service is not None:
             master.evaluation_service.trigger_evaluation(model_version=0)
@@ -363,40 +410,7 @@ def run_allreduce_job(args, mode: str = Mode.TRAINING) -> int:
             restarts_fn=lambda: manager.restarts_used
         )
     slo_plane = _build_slo_plane(args, master, policy_engine)
-    progress_persister = master.progress_persister
-    job_succeeded = False
-    try:
-        manager.start()
-        if policy_engine is not None:
-            policy_engine.start()
-        if slo_plane is not None:
-            slo_plane.start()
-        ok = manager.wait()
-        if master.evaluation_service is not None:
-            master.evaluation_service.finalize()
-            metrics = master.evaluation_service.latest_metrics
-            if metrics:
-                logger.info("Final metrics: %s", metrics)
-        if not ok:
-            logger.error("Job failed: %s", manager.failed_reason)
-            return 1
-        if not master.task_manager.finished():
-            logger.error("Workers exited but tasks remain unfinished")
-            return 1
-        logger.info("AllReduce job complete")
-        job_succeeded = True
-        return 0
-    finally:
-        if slo_plane is not None:
-            slo_plane.stop()
-        if policy_engine is not None:
-            policy_engine.stop()
-        manager.stop()
-        master.stop()
-        if job_succeeded and progress_persister is not None:
-            # Leaving a terminal snapshot behind would turn the next run
-            # with this checkpoint_dir into a silent no-op.
-            progress_persister.clear()
+    return manager, policy_engine, slo_plane
 
 
 def run_ps_job(args, mode: str = Mode.TRAINING) -> int:

@@ -542,7 +542,7 @@ class KubernetesPodManager(ElasticWorkerManager):
             self._target_num_workers,
         )
         self._probe_started = time.time()
-        new_probe = self._substrate_launch(probe_ids)
+        new_probe = self._launch_each(probe_ids)
         with self._lock:
             if self._stopped:
                 stale, new_probe = new_probe, []

@@ -10,7 +10,6 @@ from __future__ import annotations
 
 import os
 import sys
-import time
 from dataclasses import dataclass
 from typing import Optional
 
@@ -23,6 +22,7 @@ from elasticdl_tpu.data.reader import build_data_reader
 from elasticdl_tpu.master.evaluation_service import EvaluationService
 from elasticdl_tpu.master.servicer import MasterServicer, start_master_server
 from elasticdl_tpu.master.task_manager import TaskManager, TaskProgressPersister
+from elasticdl_tpu.obs import tracing
 
 logger = get_logger("master.main")
 
@@ -94,8 +94,15 @@ def build_master(args, model_spec=None, rendezvous_server=None) -> Master:
             # itself is attributed by obs.report from the journal).
             goodput.ledger().seed_from_journal(journal_path)
 
-    model_spec = model_spec or load_model_spec(args)
+    model_spec = model_spec or load_model_spec(args)  # `spec.load`
+    with tracing.span("master.build"):
+        return _assemble_master(args, model_spec, rendezvous_server)
 
+
+def _assemble_master(args, model_spec, rendezvous_server) -> Master:
+    """Readers and shards, the task manager (or a predecessor's
+    progress), the services: `build_master` after the journal and the
+    model spec."""
     training_reader = None
     training_shards = {}
     if args.training_data:
@@ -163,8 +170,6 @@ def build_master(args, model_spec=None, rendezvous_server=None) -> Master:
 
     tensorboard_service = None
     if getattr(args, "tensorboard_log_dir", ""):
-        from elasticdl_tpu.obs import tracing
-
         # The service writes its event files itself: this times the
         # `tensorboard` package's protos' import and one open().
         with tracing.span("master.tensorboard_init"):
@@ -249,24 +254,20 @@ def start_master(args, model_spec=None, rendezvous_server=None) -> Master:
     # Tracing plane identity + crash flight recorder: master spans label
     # as `master` on the assembled trace, and a SIGTERM'd/exiting master
     # flushes its open spans + a final registry snapshot to the journal.
-    from elasticdl_tpu.obs import tracing
-
     tracing.set_process("master")
     tracing.install_flight_recorder()
     master = build_master(args, model_spec, rendezvous_server)
     tracing.record_proc_start()  # the journal exists from here on
-    with tracing.span("master.serve_ready") as ready:
+    with tracing.span("master.serve_ready"):
         _serve(master, args)
-        if tracing.main_start_ts() is not None:
-            # The whole boot at a glance: main's first line -> serving.
-            ready.fields["since_main_s"] = round(
-                time.time() - tracing.main_start_ts(), 6
-            )
-        # What a boot paid for in imports: none of these is the
-        # master's own to load (a zoo module may bring jax).
-        ready.fields["heavy_imports"] = sorted(
+    # The boot that main's first line opened ends here.  What it paid
+    # for in imports: none of these is the master's own to load (a zoo
+    # module may bring jax; `spec.load` says so).
+    tracing.end_boot(
+        heavy_imports=sorted(
             {"torch", "tensorflow", "jax"} & set(sys.modules)
         )
+    )
     # Phase accounting starts here: idle until the first dispatch or
     # world declaration opens a real phase.
     from elasticdl_tpu.obs import goodput
@@ -333,10 +334,10 @@ def main(argv=None):
     fleet supervision, reference master-pod behavior); Local starts a bare
     master server for debugging.
     """
+    # The end of `proc.start` and the start of `master.boot`.
+    tracing.begin_boot("master.boot")
     from elasticdl_tpu.common import faults
-    from elasticdl_tpu.obs import tracing
 
-    tracing.note_main_start()  # the end of the `proc.start` span
     if faults.install_from_env():
         logger.warning(
             "Fault injection armed from %s=%r",

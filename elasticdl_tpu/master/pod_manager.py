@@ -35,7 +35,7 @@ from typing import Callable, Dict, List, Optional
 from elasticdl_tpu import obs
 from elasticdl_tpu.analysis.runtime import make_lock
 from elasticdl_tpu.common.log_utils import get_logger
-from elasticdl_tpu.obs import goodput
+from elasticdl_tpu.obs import goodput, tracing
 
 logger = get_logger("master.pod_manager")
 
@@ -311,7 +311,29 @@ class ElasticWorkerManager:
     # Internals
     # ------------------------------------------------------------------
 
-    def _launch_world(self, n: int):
+    def _launch_each(
+        self, worker_ids: List[int], exit_noticed: Optional[float] = None
+    ) -> List:
+        """Start the workers, each inside a `master.launch_worker` span
+        (what leads to and includes its Popen / pod create).  `cause` is
+        `start` for the job's first world (ids from 0, never reused) and
+        `relaunch` for every later worker; where an exit brought the
+        relaunch about, `since_exit_s` counts from the monitor's notice
+        of it (`exit_noticed`, monotonic)."""
+        handles = []
+        for wid in worker_ids:
+            fields = {"cause": "relaunch" if worker_ids[0] else "start"}
+            if exit_noticed is not None:
+                fields["since_exit_s"] = round(
+                    time.monotonic() - exit_noticed, 6
+                )
+            with tracing.span(
+                "master.launch_worker", worker_id=wid, **fields
+            ):
+                handles.extend(self._substrate_launch([wid]))
+        return handles
+
+    def _launch_world(self, n: int, exit_noticed: Optional[float] = None):
         with self._lock:
             if self._stopped:
                 return
@@ -325,7 +347,7 @@ class ElasticWorkerManager:
             self._rendezvous.set_worker_hosts(
                 [(wid, self._worker_host(wid)) for wid in worker_ids]
             )
-        handles = self._substrate_launch(worker_ids)
+        handles = self._launch_each(worker_ids, exit_noticed)
         with self._lock:
             if self._stopped:
                 # stop() raced the launch; don't leak the new workers.
@@ -480,6 +502,7 @@ class ElasticWorkerManager:
             self._handle_churn_serialized(handles, crashed)
 
     def _handle_churn_serialized(self, handles: List, crashed):
+        exit_noticed = time.monotonic()
         for h, code in crashed:
             logger.warning(
                 "%s died (exit %s) — world re-formation",
@@ -526,7 +549,7 @@ class ElasticWorkerManager:
             self._restarts_used,
             self._max_restarts,
         )
-        self._launch_world(new_size)
+        self._launch_world(new_size, exit_noticed=exit_noticed)
 
 
 class WorkerProcess:

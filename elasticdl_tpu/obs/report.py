@@ -37,6 +37,13 @@ Reconstruction rules (mirroring the ledger's):
   live gauge); `requeue_redo` is replay waste, everything else is
   overhead.
 
+The "boot" section is one row a process incarnation that journaled a
+boot span (`master.boot`, `worker.boot`; obs/tracing.py): process
+creation -> the first task it saw acknowledged, the boot span's children
+by duration and its self time, and what each `compile.build` before that
+acknowledgement was made of.  The workers' rows come from the
+`events_worker_*.jsonl` files beside the journal given.
+
 `--scrape` joins a live (or saved) /metrics exposition: the report
 prints the exporter's `elasticdl_goodput_ratio` next to the replayed
 one so drift between the live gauge and the journal is visible.
@@ -46,12 +53,15 @@ Stdlib only.
 from __future__ import annotations
 
 import argparse
+import glob
 import json
+import os
 import sys
 import urllib.request
 from typing import Dict, List, Optional, Tuple
 
 from elasticdl_tpu.obs.goodput import GOODPUT_PHASES, PHASES
+from elasticdl_tpu.obs.tracing import covered_seconds
 
 
 def load_events(path: str) -> List[dict]:
@@ -207,6 +217,9 @@ def summarize(events: List[dict]) -> dict:
     }
     if task_chains:
         summary["task_chains"] = task_chains
+    boot = _boot_chains(events)
+    if boot:
+        summary["boot"] = boot
     if summaries:
         final = summaries[-1]
         summary["ledger_summary"] = {
@@ -627,6 +640,99 @@ def _slowest_task_chains(
     return chains
 
 
+#: The boot span of a process: `proc.start` ends where it starts.
+BOOT_SPANS = ("master.boot", "worker.boot")
+
+
+def _incarnation(span: dict) -> str:
+    """What tells one process's spans from another's of the same
+    `proc`: a span id is `s-<pid, salt, tracer>-<n>`."""
+    return str(span.get("span_id", "")).rsplit("-", 1)[0]
+
+
+def _boot_chains(events: List[dict]) -> List[dict]:
+    """One row a process incarnation that journaled a boot span: from
+    `proc.start` to the first task it saw acknowledged (the master: the
+    first `task_done`; a worker: the end of its first
+    `worker.report_task`), the boot span's children by duration with its
+    self time (what no child names), and the `compile.build`s before that
+    acknowledgement with what each was made of."""
+    spans = [
+        e for e in events
+        if e.get("event") == "span"
+        and isinstance(e.get("duration_s"), (int, float))
+        and isinstance(e.get("start_ts"), (int, float))
+    ]
+    first_done = min(
+        (e["ts"] for e in events if e.get("event") == "task_done"),
+        default=None,
+    )
+    chains = []
+    for boot in spans:
+        if boot.get("name") not in BOOT_SPANS:
+            continue
+        mine = [e for e in spans if _incarnation(e) == _incarnation(boot)]
+        boot_end = boot["start_ts"] + boot["duration_s"]
+        children = sorted(
+            (e for e in mine if e.get("parent_span_id") == boot["span_id"]),
+            key=lambda e: -e["duration_s"],
+        )
+        covered = covered_seconds(
+            (max(boot["start_ts"], e["start_ts"]),
+             min(boot_end, e["start_ts"] + e["duration_s"]))
+            for e in children
+        )
+        chain = {
+            "proc": boot.get("proc"),
+            "boot": boot["name"],
+            "start_ts": boot["start_ts"],
+            "boot_s": round(boot["duration_s"], 6),
+            "self_s": round(max(0.0, boot["duration_s"] - covered), 6),
+            "children": [
+                {
+                    key: child[key]
+                    for key in (
+                        "name", "duration_s", "imported", "jax_import_s",
+                        "devices", "world_size",
+                    )
+                    if key in child
+                }
+                for child in children
+            ],
+        }
+        if boot.get("heavy_imports") is not None:
+            chain["heavy_imports"] = boot["heavy_imports"]
+        for e in mine:
+            if e["name"] == "proc.start":
+                chain["proc_start_s"] = round(e["duration_s"], 6)
+                chain["start_ts"] = e["start_ts"]
+        if boot["name"] == "master.boot":
+            acknowledged = first_done
+        else:
+            acknowledged = min(
+                (e["start_ts"] + e["duration_s"] for e in mine
+                 if e["name"] == "worker.report_task"),
+                default=None,
+            )
+        if acknowledged is not None and acknowledged >= boot_end:
+            chain["first_ack_s"] = round(acknowledged - chain["start_ts"], 6)
+            chain["builds"] = [
+                {
+                    key: e[key]
+                    for key in (
+                        "entrypoint", "duration_s", "trace_s", "lower_s",
+                        "backend_s", "cache_read_s", "programs", "cache_hit",
+                    )
+                    if key in e
+                }
+                for e in mine
+                if e["name"] == "compile.build"
+                and e["start_ts"] < acknowledged
+            ]
+        chains.append(chain)
+    return sorted(chains, key=lambda c: c["start_ts"])
+
+
 def _compute_attribution(events: List[dict]) -> dict:
     """The compute-plane half of the postmortem (docs/observability.md
     "Step anatomy"): fold ``step_anatomy`` events (cumulative per-worker
@@ -722,6 +828,11 @@ def _fmt_duration(seconds: float) -> str:
     return f"{seconds:.1f}s"
 
 
+def _fmt_seconds(seconds: float) -> str:
+    """Boot spans are tenths of seconds: two places."""
+    return f"{seconds:.2f}s"
+
+
 def render_report(summary: dict, max_segments: int = 80) -> str:
     """The human half: timeline + attribution + rescale breakdown."""
     lines: List[str] = []
@@ -809,6 +920,50 @@ def render_report(summary: dict, max_segments: int = 80) -> str:
                 f"steps [{window.get('step_start')}, "
                 f"{window.get('step_end')}) -> {window.get('trace_dir')}"
             )
+    boot = summary.get("boot")
+    if boot:
+        lines.append("")
+        lines.append(
+            "boot (a row a process: process creation -> the first task it "
+            "saw acknowledged; the boot span's children by duration, self "
+            "= what no child names):"
+        )
+        for chain in boot:
+            head = f"  {chain.get('proc')}: "
+            if chain.get("first_ack_s") is not None:
+                head += f"{_fmt_seconds(chain['first_ack_s'])} to it = "
+            if chain.get("proc_start_s") is not None:
+                head += f"proc.start {_fmt_seconds(chain['proc_start_s'])} + "
+            head += f"{chain['boot']} {_fmt_seconds(chain['boot_s'])}"
+            if chain.get("heavy_imports") is not None:
+                head += f"  heavy_imports={chain['heavy_imports']}"
+            lines.append(head)
+            for child in chain["children"]:
+                notes = [
+                    f"{key}={child[key]}"
+                    for key in ("imported", "jax_import_s", "devices",
+                                "world_size")
+                    if key in child
+                ]
+                lines.append(
+                    f"    {_fmt_seconds(child['duration_s']):>8}  "
+                    f"{child['name']}"
+                    + (f"  ({', '.join(notes)})" if notes else "")
+                )
+            lines.append(f"    {_fmt_seconds(chain['self_s']):>8}  self")
+            for build in chain.get("builds", ()):
+                parts = ", ".join(
+                    f"{key[:-2]} {_fmt_seconds(build[key])}"
+                    for key in ("trace_s", "lower_s", "backend_s",
+                                "cache_read_s")
+                    if key in build
+                )
+                lines.append(
+                    f"    {_fmt_seconds(build['duration_s']):>8}  "
+                    f"compile.build {build.get('entrypoint')}: {parts}, "
+                    f"programs {build.get('programs')}, "
+                    f"cache_hit {build.get('cache_hit')}"
+                )
     task_chains = summary.get("task_chains")
     if task_chains:
         lines.append("")
@@ -1377,6 +1532,18 @@ def main(argv=None) -> int:
         print(f"{args.journal}: {exc}", file=sys.stderr)
         return 2
     summary = summarize(events)
+    # The workers' boot chains are in their own journals, beside this one.
+    beside = [
+        event
+        for path in sorted(glob.glob(os.path.join(
+            os.path.dirname(os.path.abspath(args.journal)),
+            "events_worker_*.jsonl",
+        )))
+        if os.path.abspath(path) != os.path.abspath(args.journal)
+        for event in load_events(path)
+    ]
+    if beside:
+        summary["boot"] = _boot_chains(events + beside)
     if args.scrape:
         try:
             ratio = parse_metric_value(

@@ -105,7 +105,8 @@ SPAN_NAMES: Dict[str, str] = {
     "worker.get_task": "after: worker, client half of dispatch",
     "worker.report_task": "interval: worker, result report RPC",
     "worker.task": "interval: worker, one task's execution",
-    "worker.join_world": "interval: worker, rendezvous join",
+    "worker.join_world": "interval: worker, the rank poll and, in a "
+                         "world of more than one, the distributed init",
     "rendezvous.formation": "after: master, declaration -> all polled",
     # step anatomy: journal aggregates + annotation intervals
     "step.data_wait": "aggregate; annotation: each wait for a batch",
@@ -130,15 +131,38 @@ SPAN_NAMES: Dict[str, str] = {
     "checkpoint.save.commit": "interval: rename + garbage-collect",
     "checkpoint.restore": "interval: newest step + its CRC check",
     "checkpoint.restore.load": "interval: read, unpickle, place",
-    # start-up
+    # start-up: one chain a process, `proc.start` then the boot span
+    # whose children name its parts (its self time is what is unnamed)
     "proc.start": "after: process creation -> first line of main",
+    "master.boot": "interval: first line of main (client main for "
+                   "`elasticdl train`) -> serving (`heavy_imports`)",
+    "master.imports": "after: client main, the import of the master's "
+                      "modules (grpc, numpy, the services): it ends "
+                      "before the journal exists",
+    "master.build": "interval: build_master after the spec: readers, "
+                    "create_shards, TaskManager, services (holds "
+                    "master.tensorboard_init)",
     "master.tensorboard_init": "interval: the scalar service's event-"
                                "file writer (protos + open)",
-    "master.serve_ready": "interval: gRPC server + exporter start "
-                          "(`since_main_s`, `heavy_imports`)",
+    "master.serve_ready": "interval: gRPC server + exporter start",
+    "master.build_fleet": "interval: serving -> manager.start(): policy "
+                          "engine, worker manager, SLO plane",
+    "master.launch_worker": "interval: one a worker process started, up "
+                            "to and with its Popen / pod create "
+                            "(`worker_id`, `cause`, `since_exit_s`)",
+    "worker.boot": "interval: first line of the worker's main -> "
+                   "worker.run() entered",
+    "worker.imports": "interval: arguments, journal, compile cache and "
+                      "the worker's imports (`jax_import_s`)",
+    "worker.build_trainer": "interval: mesh, build_model, trainer, "
+                            "saver, the worker object",
+    "spec.load": "interval: load_model_spec, the zoo module's import "
+                 "(`imported`: which frameworks it brought)",
     "worker.backend_init": "interval: first jax.devices()",
     "state.init": "interval: model.init / restore + placement",
-    "compile.build": "interval: first call of a jitted entrypoint",
+    "compile.build": "interval: first call of a jitted entrypoint "
+                     "(`trace_s`, `lower_s`, `backend_s`, `cache_read_s`, "
+                     "`programs`, `cache_hit`)",
     # expert routing (layers/moe.py): a task's counters ride on a span
     "moe.routing": "after: worker, one a task of a model with expert "
                    "layers: pairs routed to held experts, dropped (0), "
@@ -170,6 +194,17 @@ DEVICE_SCOPES = (
 MAX_REGISTRY_SNAPSHOT_BYTES = 32 << 10
 
 
+def covered_seconds(intervals) -> float:
+    """The length of the UNION of (start, end) intervals: what spans
+    that nest or overlap cover, nothing counted twice."""
+    covered, reach = 0.0, float("-inf")
+    for lo, hi in sorted(intervals):
+        if hi > reach:
+            covered += hi - max(lo, reach)
+            reach = hi
+    return covered
+
+
 def annotate(name: str, **fields):
     """The profiler sink: a ``jax.profiler.TraceAnnotation(name)`` to
     enter around the real interval of the work, or a null context in a
@@ -196,6 +231,8 @@ class Span:
     start_ts: float = 0.0
     start_monotonic: float = 0.0
     fields: dict = field(default_factory=dict)
+    #: Restores the span that was current before this one (open_span).
+    context_token: object = None
 
 
 class Tracer:
@@ -283,6 +320,33 @@ class Tracer:
         inherit from the enclosing span when not given; ``root=True``
         with a trace id makes this THE root span (span_id == trace_id,
         the cross-process parenting convention)."""
+        span = self.open_span(
+            name, trace_id=trace_id, parent_id=parent_id, root=root,
+            span_id=span_id, **fields
+        )
+        try:
+            with annotate(name):
+                yield span
+        except BaseException as exc:
+            span.fields.setdefault("error", type(exc).__name__)
+            raise
+        finally:
+            self.close_span(span)
+
+    def open_span(
+        self,
+        name: str,
+        trace_id: str = "",
+        parent_id: Optional[str] = None,
+        root: bool = False,
+        span_id: str = "",
+        **fields,
+    ) -> Span:
+        """The opening half of ``span()``, for an interval that no one
+        ``with`` block holds (a process's boot span opens in ``main`` and
+        closes where the process starts serving): the span becomes the
+        current one until ``close_span``, which must run in the same
+        thread."""
         parent = self._current.get()
         if not trace_id and parent is not None:
             trace_id = parent.trace_id
@@ -307,22 +371,19 @@ class Tracer:
         )
         with self._lock:
             self._open[span.span_id] = span
-        token = self._current.set(span)
-        error = None
+        span.context_token = self._current.set(span)
+        return span
+
+    def close_span(self, span: Span) -> dict:
+        """The closing half: journal the span with its length."""
         try:
-            with annotate(name):
-                yield span
-        except BaseException as exc:
-            error = type(exc).__name__
-            raise
-        finally:
-            self._current.reset(token)
-            duration_s = max(0.0, time.monotonic() - span.start_monotonic)
-            with self._lock:
-                self._open.pop(span.span_id, None)
-            if error is not None:
-                span.fields.setdefault("error", error)
-            self._emit(span, duration_s)
+            self._current.reset(span.context_token)
+        except ValueError:
+            pass  # closed in another thread than it was opened in
+        duration_s = max(0.0, time.monotonic() - span.start_monotonic)
+        with self._lock:
+            self._open.pop(span.span_id, None)
+        return self._emit(span, duration_s)
 
     def record_span(
         self,
@@ -481,10 +542,6 @@ def note_main_start() -> None:
         _main_start_ts = time.time()
 
 
-def main_start_ts() -> Optional[float]:
-    return _main_start_ts
-
-
 def _process_created_ts() -> Optional[float]:
     """Wall-clock time the kernel created this process: `starttime` of
     /proc/self/stat (clock ticks after boot) against /proc/uptime."""
@@ -502,16 +559,69 @@ def _process_created_ts() -> Optional[float]:
 def record_proc_start() -> Optional[dict]:
     """Journal `proc.start` once the process has a journal: process
     creation -> the first line of main (interpreter start and the
-    imports before main).  Nothing where main never noted its start or
-    /proc cannot say when the process was created."""
+    imports before main), and with it what main timed before there was
+    a journal (`early_span`).  Nothing where main never noted its start
+    or /proc cannot say when the process was created."""
     global _proc_start_recorded
-    created = _process_created_ts()
-    if _proc_start_recorded or _main_start_ts is None or created is None:
+    if _proc_start_recorded or _main_start_ts is None:
         return None
     _proc_start_recorded = True
+    parent_id = _boot_span.span_id if _boot_span is not None else ""
+    for name, start_ts, duration_s in _early_spans:
+        _tracer.record_span(name, start_ts, duration_s, parent_id=parent_id)
+    del _early_spans[:]
+    created = _process_created_ts()
+    if created is None:
+        return None
     return _tracer.record_span(
         "proc.start", created, max(0.0, _main_start_ts - created)
     )
+
+
+#: (name, start_ts, duration_s) of spans that closed before the process
+#: had its journal; `record_proc_start` journals them.
+_early_spans: list = []
+
+
+@contextlib.contextmanager
+def early_span(name: str):
+    """A part of main that ends before the process has its journal (the
+    master's imports, ahead of its arguments): timed here, journaled by
+    `record_proc_start` as a child of the boot span."""
+    start_ts, start = time.time(), time.monotonic()
+    try:
+        yield
+    finally:
+        _early_spans.append((name, start_ts, time.monotonic() - start))
+
+
+_boot_span: Optional[Span] = None
+
+
+def begin_boot(name: str) -> None:
+    """Call on the first line of a process's `main`, in place of
+    `note_main_start`: the end of `proc.start` and the start of the
+    process's boot span (`master.boot` / `worker.boot`), which stays the
+    current span of the main thread, so that what main opens until
+    `end_boot` is its child.  The first call of a process wins (`client
+    main` runs before the master's assembly)."""
+    global _boot_span
+    if _main_start_ts is None and _boot_span is None:
+        note_main_start()
+        _boot_span = _tracer.open_span(name)
+        # One clock read for the end of proc.start and the boot's start.
+        _boot_span.start_ts = _main_start_ts
+
+
+def end_boot(**fields) -> Optional[dict]:
+    """Close and journal the boot span (by now the process has its
+    journal); nothing in a process whose main opened none, or twice."""
+    global _boot_span
+    span, _boot_span = _boot_span, None
+    if span is None:
+        return None
+    span.fields.update(fields)
+    return _tracer.close_span(span)
 
 
 # ---------------------------------------------------------------------------

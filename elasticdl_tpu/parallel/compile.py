@@ -247,10 +247,15 @@ def jit_utility(fn: Callable, **jit_kwargs):
 class _BuildSpan:
     """A jitted entrypoint whose FIRST call — tracing, lowering, the XLA
     compile or the persistent cache's load, and the dispatch — runs
-    inside a `compile.build` span (`entrypoint`, and `cache_hit`: every
-    program the call built came from the persistent cache).  Every
-    later call goes straight through; attributes (`_cache_size`,
-    `lower`, ...) are the jitted function's own."""
+    inside a `compile.build` span: `entrypoint`, and what JAX's own
+    events say the call was made of (`common/compile_cache.BuildParts`):
+    `trace_s`, `lower_s`, `backend_s` (XLA's compile or the cache's
+    load), `cache_read_s` (the part of `backend_s` that was retrieval),
+    `programs` (backend requests) and `cache_hit` (every one of them came
+    from the persistent cache).  What is left of the span is its own:
+    argument handling, `device_put`s, the dispatch.  Every later call
+    goes straight through; attributes (`_cache_size`, `lower`, ...) are
+    the jitted function's own."""
 
     def __init__(self, jitted, name: str):
         self._jitted, self._name, self._built = jitted, name, False
@@ -262,13 +267,10 @@ class _BuildSpan:
         from elasticdl_tpu.obs import tracing
 
         self._built = True
-        hits, misses = compile_cache.hits_and_misses()
         with tracing.span("compile.build", entrypoint=self._name) as span:
-            out = self._jitted(*args, **kwargs)
-            hits_now, misses_now = compile_cache.hits_and_misses()
-            span.fields["cache_hit"] = (
-                hits_now > hits and misses_now == misses
-            )
+            with compile_cache.measuring() as parts:
+                out = self._jitted(*args, **kwargs)
+            span.fields.update(parts.fields())
         return out
 
     def __getattr__(self, attr):

@@ -125,21 +125,12 @@ def _join_world_inner(
             info.world_size,
             info.coordinator_addr,
         )
-        # Span: the worker-side half of world-formation cost (the
-        # distributed-init barrier) — the master-side half is
-        # elasticdl_rendezvous_formation_duration_seconds.
-        with obs.span(
-            "worker.join_world",
-            rendezvous_id=info.rendezvous_id,
-            rank=info.rank,
-            world_size=info.world_size,
-        ):
-            jax.distributed.initialize(
-                coordinator_address=info.coordinator_addr,
-                num_processes=info.world_size,
-                process_id=info.rank,
-                initialization_timeout=initialization_timeout_s,
-            )
+        jax.distributed.initialize(
+            coordinator_address=info.coordinator_addr,
+            num_processes=info.world_size,
+            process_id=info.rank,
+            initialization_timeout=initialization_timeout_s,
+        )
     return info
 
 

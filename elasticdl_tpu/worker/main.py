@@ -26,13 +26,31 @@ def _sigterm_to_systemexit(signum, frame):
     raise SystemExit(128 + signum)
 
 
-def main(argv=None):
+#: What `_build_collective_worker` imports (jax, flax and optax with the
+#: trainers): `main` loads them inside `worker.imports`, so that the
+#: seconds they take are named and not the builder's.
+_WORKER_STACK = (
+    "elasticdl_tpu.checkpoint", "elasticdl_tpu.common.profiler",
+    "elasticdl_tpu.data.pipeline", "elasticdl_tpu.obs.stepstats",
+    "elasticdl_tpu.obs.telemetry", "elasticdl_tpu.ops.sparse_embedding",
+    "elasticdl_tpu.parallel", "elasticdl_tpu.parallel.dp_trainer",
+    "elasticdl_tpu.parallel.ps_trainer", "elasticdl_tpu.parallel.elastic",
+    "elasticdl_tpu.worker.collective_worker",
+)
+
+
+def _configure_process(argv, imports):
+    """What `main` does before it loads the model: signals, faults,
+    arguments, the process's name and journal, the compile cache, and
+    the worker's imports.  `imports` is the open `worker.imports` span
+    (`jax_import_s`: jax alone, of the span's seconds)."""
+    import importlib
     import os
     import signal
+    import time
 
     from elasticdl_tpu.obs import tracing
 
-    tracing.note_main_start()  # the end of the `proc.start` span
     try:
         signal.signal(signal.SIGTERM, _sigterm_to_systemexit)
     except ValueError:
@@ -68,6 +86,10 @@ def main(argv=None):
             filename=f"events_worker_{args.worker_id}.jsonl",
         )
     tracing.record_proc_start()
+    started = time.monotonic()
+    import jax  # noqa: F401  (first import of the process)
+
+    imports.fields["jax_import_s"] = round(time.monotonic() - started, 6)
     from elasticdl_tpu.common import compile_cache
 
     # Persistent compile cache: a re-formed world's jit compiles are
@@ -93,6 +115,20 @@ def main(argv=None):
             bins=args.quality_drift_bins,
             origin=f"worker_{args.worker_id}",
         ))
+    for module in _WORKER_STACK:
+        importlib.import_module(module)
+    return args
+
+
+def main(argv=None):
+    from elasticdl_tpu.obs import tracing
+
+    # The end of `proc.start` and the start of `worker.boot`, which
+    # closes where `worker.run()` is entered.  A relaunched worker walks
+    # the same path: its spans carry its own `proc`.
+    tracing.begin_boot("worker.boot")
+    with tracing.span("worker.imports") as imports:
+        args = _configure_process(argv, imports)
     model_spec = load_model_spec(args)
     data_reader = build_data_reader(args, model_spec, args.training_data)
     validation_reader = (
@@ -110,6 +146,7 @@ def main(argv=None):
         args, model_spec, data_reader, client,
         validation_reader, prediction_reader,
     )
+    tracing.end_boot()
     worker.run()
     if args.output and "training" in args.job_type:
         # Export the servable artifact at job end (reference: the master's
@@ -178,6 +215,7 @@ def _build_collective_worker(
     how many failed tasks the loop rides through."""
     import jax
 
+    from elasticdl_tpu import obs
     from elasticdl_tpu.checkpoint import (
         CheckpointSaver,
         ShardedCheckpointSaver,
@@ -197,14 +235,23 @@ def _build_collective_worker(
     strategy = args.distribution_strategy
     local = strategy == DistributionStrategy.LOCAL
     sharded_embeddings = strategy == DistributionStrategy.PARAMETER_SERVER
-    if local:
-        # A master and a world of one: no rendezvous to join, and no
-        # supervisor that would re-form a world.
-        world = WorldInfo(
-            rank=0, world_size=1, rendezvous_id=0, coordinator_addr=""
+    # The worker-side half of world-formation cost: the rank poll and,
+    # in a world of more than one, the distributed-init barrier (the
+    # master-side half is
+    # elasticdl_rendezvous_formation_duration_seconds).
+    with obs.span("worker.join_world") as joined:
+        if local:
+            # A master and a world of one: no rendezvous to join, and no
+            # supervisor that would re-form a world.
+            world = WorldInfo(
+                rank=0, world_size=1, rendezvous_id=0, coordinator_addr=""
+            )
+        else:
+            world = join_world(client)
+        joined.fields.update(
+            rendezvous_id=world.rendezvous_id, rank=world.rank,
+            world_size=world.world_size,
         )
-    else:
-        world = join_world(client)
     # Worker telemetry plane: step times / task progress / RPC retries
     # collected here ride the liveness heartbeat to the master's
     # aggregator (docs/observability.md "Worker telemetry plane").
@@ -222,84 +269,88 @@ def _build_collective_worker(
     # (on a TPU host: the runtime's start, seconds).
     with tracing.span("worker.backend_init") as backend:
         backend.fields["devices"] = len(jax.devices())
-    if local:
-        # Local trains on one device.
-        mesh = build_mesh(MeshConfig(), devices=jax.devices()[:1])
-    else:
-        # All devices of the joined world, shaped (data, model): the
-        # model axis carries sharded embedding tables and — for
-        # mesh-aware zoo models — ring-attention context parallelism.
-        mesh = build_mesh(MeshConfig(model=args.mesh_model_axis))
-    # --sparse_kernel resolution is STRATEGY-INDEPENDENT (the Embedding
-    # layers run under every trainer).  Multi-device meshes run the
-    # fused kernels through the shard_map dispatch
-    # (ops/sparse_embedding.py "Sharded dispatch").  Register BOTH
-    # process defaults BEFORE the model is built: the kernel default
-    # (Embedding layers that did not thread sparse_kernel explicitly
-    # resolve it at trace time; zoo models that declare the param get
-    # the same value via model_params, common/model_utils.py) and the
-    # dispatch mesh (layers that did not thread `mesh` still route
-    # per-shard kernel bodies instead of tracing an unpartitionable
-    # pallas_call into an SPMD program).
-    sparse_kernel = args.sparse_kernel or "auto"
-    ske.set_default_kernel(sparse_kernel)
-    ske.set_dispatch_mesh(mesh)
-    if sharded_embeddings:
-        from elasticdl_tpu.parallel.ps_trainer import ShardedEmbeddingTrainer
+    # The mesh, the model, the trainer, the saver and the worker object.
+    with tracing.span("worker.build_trainer"):
+        if local:
+            # Local trains on one device.
+            mesh = build_mesh(MeshConfig(), devices=jax.devices()[:1])
+        else:
+            # All devices of the joined world, shaped (data, model): the
+            # model axis carries sharded embedding tables and — for
+            # mesh-aware zoo models — ring-attention context parallelism.
+            mesh = build_mesh(MeshConfig(model=args.mesh_model_axis))
+        # --sparse_kernel resolution is STRATEGY-INDEPENDENT (the Embedding
+        # layers run under every trainer).  Multi-device meshes run the
+        # fused kernels through the shard_map dispatch
+        # (ops/sparse_embedding.py "Sharded dispatch").  Register BOTH
+        # process defaults BEFORE the model is built: the kernel default
+        # (Embedding layers that did not thread sparse_kernel explicitly
+        # resolve it at trace time; zoo models that declare the param get
+        # the same value via model_params, common/model_utils.py) and the
+        # dispatch mesh (layers that did not thread `mesh` still route
+        # per-shard kernel bodies instead of tracing an unpartitionable
+        # pallas_call into an SPMD program).
+        sparse_kernel = args.sparse_kernel or "auto"
+        ske.set_default_kernel(sparse_kernel)
+        ske.set_dispatch_mesh(mesh)
+        if sharded_embeddings:
+            from elasticdl_tpu.parallel.ps_trainer import (
+                ShardedEmbeddingTrainer,
+            )
 
-        trainer = ShardedEmbeddingTrainer(
-            model=model_spec.build_model(mesh=mesh),
-            loss_fn=model_spec.loss,
-            optimizer=model_spec.optimizer(),
-            mesh=mesh,
-            embedding_optimizer=(
-                model_spec.embedding_optimizer()
-                if model_spec.embedding_optimizer is not None
-                else None
+            trainer = ShardedEmbeddingTrainer(
+                model=model_spec.build_model(mesh=mesh),
+                loss_fn=model_spec.loss,
+                optimizer=model_spec.optimizer(),
+                mesh=mesh,
+                embedding_optimizer=(
+                    model_spec.embedding_optimizer()
+                    if model_spec.embedding_optimizer is not None
+                    else None
+                ),
+                sparse_apply_every=args.sparse_apply_every,
+                sparse_kernel=sparse_kernel,
+            )
+        else:
+            trainer = DataParallelTrainer(
+                model=model_spec.build_model(mesh=mesh),
+                loss_fn=model_spec.loss,
+                optimizer=model_spec.optimizer(),
+                mesh=mesh,
+                dense_sharding=args.dense_sharding,
+            )
+        saver = None
+        if args.checkpoint_dir:
+            # Mesh-sharded state (PS tables / FSDP dense leaves): each
+            # process writes its own shard files, so no host ever gathers
+            # the full model (checkpoint/sharded.py).
+            saver_cls = (
+                ShardedCheckpointSaver
+                if sharded_embeddings or args.dense_sharding == "fsdp"
+                else CheckpointSaver
+            )
+            saver = saver_cls(
+                args.checkpoint_dir, keep_max=args.keep_checkpoint_max
+            )
+        return CollectiveWorker(
+            master_client=client,
+            model_spec=model_spec,
+            data_reader=data_reader,
+            minibatch_size=args.minibatch_size,
+            world=world,
+            trainer=trainer,
+            checkpoint_saver=saver,
+            checkpoint_steps=args.checkpoint_steps,
+            validation_data_reader=validation_reader,
+            prediction_data_reader=prediction_reader,
+            profiler=StepProfiler(
+                args.tensorboard_log_dir, args.profile_steps, client.worker_id
             ),
-            sparse_apply_every=args.sparse_apply_every,
-            sparse_kernel=sparse_kernel,
+            train_window_steps=args.train_window_steps,
+            telemetry=telemetry,
+            pipeline=PipelineConfig.from_args(args),
+            max_task_failures=LOCAL_TASK_FAILURES if local else 0,
         )
-    else:
-        trainer = DataParallelTrainer(
-            model=model_spec.build_model(mesh=mesh),
-            loss_fn=model_spec.loss,
-            optimizer=model_spec.optimizer(),
-            mesh=mesh,
-            dense_sharding=args.dense_sharding,
-        )
-    saver = None
-    if args.checkpoint_dir:
-        # Mesh-sharded state (PS tables / FSDP dense leaves): each
-        # process writes its own shard files, so no host ever gathers
-        # the full model (checkpoint/sharded.py).
-        saver_cls = (
-            ShardedCheckpointSaver
-            if sharded_embeddings or args.dense_sharding == "fsdp"
-            else CheckpointSaver
-        )
-        saver = saver_cls(
-            args.checkpoint_dir, keep_max=args.keep_checkpoint_max
-        )
-    return CollectiveWorker(
-        master_client=client,
-        model_spec=model_spec,
-        data_reader=data_reader,
-        minibatch_size=args.minibatch_size,
-        world=world,
-        trainer=trainer,
-        checkpoint_saver=saver,
-        checkpoint_steps=args.checkpoint_steps,
-        validation_data_reader=validation_reader,
-        prediction_data_reader=prediction_reader,
-        profiler=StepProfiler(
-            args.tensorboard_log_dir, args.profile_steps, client.worker_id
-        ),
-        train_window_steps=args.train_window_steps,
-        telemetry=telemetry,
-        pipeline=PipelineConfig.from_args(args),
-        max_task_failures=LOCAL_TASK_FAILURES if local else 0,
-    )
 
 
 if __name__ == "__main__":

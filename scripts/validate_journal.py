@@ -218,8 +218,12 @@ EVENT_OPTIONAL_FIELDS = {
         "read_columns_s", "bytes", "rank", "step",
         "dirty_kb_start", "dirty_kb_end", "writeback_kb_start",
         "writeback_kb_end", "majflt", "oublock", "nivcsw",
-        "entrypoint", "cache_hit", "trainer", "devices", "since_main_s",
-        "flushed",
+        "entrypoint", "cache_hit", "trainer", "devices", "flushed",
+        # The boot chains (master.boot / worker.boot and their
+        # children) and what a compile.build was made of.
+        "heavy_imports", "imported", "jax_import_s", "cause",
+        "since_exit_s", "trace_s", "lower_s", "backend_s",
+        "cache_read_s", "programs",
     ),
     "phase_transition": ("cause",),
     "rescale_cost": (

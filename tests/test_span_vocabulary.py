@@ -16,6 +16,7 @@
 """
 
 import contextlib
+import json
 import os
 import subprocess
 import sys
@@ -123,7 +124,7 @@ def test_every_emitted_name_is_in_the_vocabulary():
         "checkpoint.restore.load", "proc.start",
         "master.tensorboard_init", "master.serve_ready",
         "worker.backend_init", "state.init", "compile.build",
-        "moe.routing",
+        "moe.routing", *BOOT_CHAIN_SPANS,
     ):
         assert name in tracing.SPAN_NAMES
     assert set(tracing.DEVICE_SCOPES) >= {
@@ -134,17 +135,54 @@ def test_every_emitted_name_is_in_the_vocabulary():
     }
 
 
-def test_every_device_scope_and_the_routing_span_name_their_reader():
+#: The start-up chain (ISSUE 34): the two boot spans, their children,
+#: and what the master does between serving and its worker's creation.
+BOOT_CHAIN_SPANS = (
+    "master.boot", "master.imports", "spec.load", "master.build",
+    "master.build_fleet", "master.launch_worker", "worker.boot",
+    "worker.imports", "worker.join_world", "worker.build_trainer",
+)
+#: The fields those spans and `compile.build` carry, and the metric
+#: files that read the chain.
+BOOT_CHAIN_FIELDS = (
+    "heavy_imports", "imported", "jax_import_s", "cause", "since_exit_s",
+    "trace_s", "lower_s", "backend_s", "cache_read_s", "programs",
+)
+BOOT_CHAIN_METRICS = (
+    "setup_named_share", "setup_largest_gap_s", "harness_prepare_s",
+    "worker_launch_s", "build_trace_s", "build_lower_s", "build_backend_s",
+    "build_cache_read_s", "master_main_to_serving_s", "master_spec_load_s",
+    "worker_boot_s", "worker_imports_s", "worker_spec_load_s",
+    "trainer_build_s",
+)
+
+
+def test_every_device_scope_and_start_up_span_names_its_reader():
     """`docs/observability.md` and PERF.md's span table name every device
-    scope, and the `moe.routing` span, with the reader of each: nothing on
-    the lists is without one."""
+    scope, the `moe.routing` span and every span and field of the
+    start-up chain, with the reader of each: nothing on the lists is
+    without one, and `since_main_s` is gone from both."""
     with open(os.path.join(REPO_ROOT, "docs", "observability.md")) as f:
         docs = f.read()
     with open(os.path.join(REPO_ROOT, "PERF.md")) as f:
         perf = f.read()
-    for name in tracing.DEVICE_SCOPES + ("moe.routing",):
+    for name in (
+        tracing.DEVICE_SCOPES + ("moe.routing",) + BOOT_CHAIN_SPANS
+        + BOOT_CHAIN_FIELDS
+    ):
         assert f"`{name}`" in docs, name
         assert f"`{name}`" in perf, name
+    with open(os.path.join(REPO_ROOT, "BENCHMARK.json")) as f:
+        declared = {m["name"]: m for m in json.load(f)["per_layer"]}
+    for name in BOOT_CHAIN_METRICS:
+        assert f"`{name}`" in perf, name
+        assert declared[name]["moves"] == "setup_s"
+        assert os.path.exists(os.path.join(
+            REPO_ROOT, "perfbench", "metrics", name + ".json"))
+    span_table = perf[perf.index("| span / family |"):perf.index("## 4. Cells")]
+    assert "since_main_s" not in span_table.replace(
+        "`since_main_s` gone", "")
+    assert "`since_main_s`" not in docs
 
 
 # ---------------------------------------------------------------------------
@@ -467,6 +505,280 @@ def test_first_call_of_a_compiled_entrypoint_is_a_build_span():
     assert double._cache_size() == 1  # the jitted function's own
 
 
+def _journal_spans(path):
+    with open(path) as f:
+        return [
+            e for e in map(json.loads, f) if e.get("event") == "span"
+        ]
+
+
+def _covered_share(parent, children):
+    """The share of `parent`'s interval inside the union of `children`."""
+    lo = parent["start_ts"]
+    hi, reach, covered = lo + parent["duration_s"], lo, 0.0
+    for child in sorted(children, key=lambda e: e["start_ts"]):
+        end = min(hi, child["start_ts"] + child["duration_s"])
+        covered += max(0.0, end - max(reach, child["start_ts"]))
+        reach = max(reach, end)
+    return covered / parent["duration_s"]
+
+
+@pytest.fixture(scope="module")
+def tiny_job(tmp_path_factory):
+    """`elasticdl train` as a user runs it, one worker process, on the
+    CPU: (master spans, worker spans) of its journals."""
+    tmp = tmp_path_factory.mktemp("boot_chain")
+    proc = subprocess.run(
+        [sys.executable, "-m", "elasticdl_tpu.client.main", "train",
+         "--distribution_strategy=AllreduceStrategy", "--num_workers=1",
+         "--model_zoo=model_zoo", "--model_def=mnist.mnist_functional_api",
+         "--training_data=synthetic://mnist?n=384", "--records_per_task=64",
+         "--minibatch_size=32", "--job_name=boot_chain",
+         f"--tensorboard_log_dir={tmp / 'tb'}",
+         f"--checkpoint_dir={tmp / 'ckpt'}"],
+        cwd=REPO_ROOT, capture_output=True, text=True, timeout=600,
+        env=dict(os.environ, JAX_PLATFORMS="cpu",
+                 XLA_FLAGS="--xla_force_host_platform_device_count=1"),
+    )
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    return (
+        _journal_spans(tmp / "tb" / "events.jsonl"),
+        _journal_spans(tmp / "tb" / "events_worker_0.jsonl"),
+        str(tmp / "tb" / "events.jsonl"),
+    )
+
+
+def test_master_boot_names_nine_tenths_of_itself_and_brings_no_jax(tiny_job):
+    master, _, _ = tiny_job
+    (boot,) = [e for e in master if e["name"] == "master.boot"]
+    (proc_start,) = [e for e in master if e["name"] == "proc.start"]
+    # The boot starts where proc.start ends: one clock read.
+    assert proc_start["start_ts"] + proc_start["duration_s"] == pytest.approx(
+        boot["start_ts"], abs=1e-3)
+    children = [e for e in master if e.get("parent_span_id") == boot["span_id"]]
+    assert {e["name"] for e in children} == {
+        "master.imports", "spec.load", "master.build", "master.serve_ready",
+    }
+    assert _covered_share(boot, children) >= 0.9
+    (build,) = [e for e in children if e["name"] == "master.build"]
+    (tb_init,) = [e for e in master if e["name"] == "master.tensorboard_init"]
+    assert tb_init["parent_span_id"] == build["span_id"]
+    # What the master holds of the heavy frameworks, its zoo module
+    # brought: none is its own import.
+    (spec,) = [e for e in children if e["name"] == "spec.load"]
+    assert boot["heavy_imports"] == ["jax"]
+    assert set(boot["heavy_imports"]) <= set(spec["imported"])
+    assert not any("since_main_s" in e for e in master)
+    # Serving -> the worker's creation is named too.
+    (fleet,) = [e for e in master if e["name"] == "master.build_fleet"]
+    (launch,) = [e for e in master if e["name"] == "master.launch_worker"]
+    assert (launch["worker_id"], launch["cause"]) == (0, "start")
+    assert "since_exit_s" not in launch
+    boot_end = boot["start_ts"] + boot["duration_s"]
+    assert boot_end <= fleet["start_ts"] <= launch["start_ts"]
+
+
+def test_worker_boot_has_its_five_children_in_order(tiny_job):
+    _, worker, _ = tiny_job
+    (boot,) = [e for e in worker if e["name"] == "worker.boot"]
+    children = sorted(
+        (e for e in worker if e.get("parent_span_id") == boot["span_id"]),
+        key=lambda e: e["start_ts"],
+    )
+    assert [e["name"] for e in children] == [
+        "worker.imports", "spec.load", "worker.join_world",
+        "worker.backend_init", "worker.build_trainer",
+    ]
+    for before, after in zip(children, children[1:]):
+        assert before["start_ts"] + before["duration_s"] <= after["start_ts"]
+    assert _covered_share(boot, children) >= 0.9
+    imports = children[0]
+    assert 0 < imports["jax_import_s"] <= imports["duration_s"]
+    assert children[2]["world_size"] == 1
+    assert {e["proc"] for e in children + [boot]} == {"worker_0"}
+    # The first task opens after the boot closed.
+    first_task = min(
+        e["start_ts"] for e in worker if e["name"] == "worker.task")
+    assert boot["start_ts"] + boot["duration_s"] <= first_task
+
+
+def test_a_task_after_warm_up_journals_the_spans_it_did(tiny_job):
+    """The start-up chain adds nothing inside a task: after the first,
+    every task journals the seven spans it journaled before ISSUE 34."""
+    _, worker, _ = tiny_job
+    by_task = {}
+    for e in worker:
+        if e.get("trace_id"):
+            by_task.setdefault(e["trace_id"], []).append(e["name"])
+    steady = list(by_task.values())[1:]
+    assert len(steady) >= 4
+    for names in steady:
+        assert sorted(names) == sorted([
+            "worker.get_task", "step.data_wait", "step.stage",
+            "step.execute", "step.bookkeep", "worker.task",
+            "worker.report_task",
+        ])
+
+
+def test_report_prints_a_boot_row_for_each_process(tiny_job, capsys):
+    from elasticdl_tpu.obs import report
+
+    master, worker, journal_path = tiny_job
+    assert report.main([journal_path, "--json", "-"]) == 0
+    out = capsys.readouterr().out
+    summary = json.loads(out[out.index('{\n  "wall_s"'):])
+    rows = {row["proc"]: row for row in summary["boot"]}
+    assert list(rows) == ["master", "worker_0"]  # by creation
+    for proc, spans, boot_name in (
+        ("master", master, "master.boot"),
+        ("worker_0", worker, "worker.boot"),
+    ):
+        row = rows[proc]
+        (boot,) = [e for e in spans if e["name"] == boot_name]
+        assert row["boot"] == boot_name
+        assert row["boot_s"] == pytest.approx(boot["duration_s"])
+        durations = [child["duration_s"] for child in row["children"]]
+        assert durations == sorted(durations, reverse=True)
+        assert 0 <= row["self_s"] <= 0.1 * row["boot_s"]
+        assert row["first_ack_s"] > row["proc_start_s"] + row["boot_s"]
+    assert rows["master"]["heavy_imports"] == ["jax"]
+    assert [b["entrypoint"] for b in rows["worker_0"]["builds"]] == [
+        "dp_init", "dp_train_window",
+    ]
+    assert rows["master"]["builds"] == []
+    text = out[:out.index('{\n  "wall_s"')]
+    assert "boot (a row a process" in text
+    assert "compile.build dp_train_window: trace " in text
+
+
+_BUILD_PROBE = """
+import json
+from elasticdl_tpu.common import compile_cache
+compile_cache.configure()
+import jax, jax.numpy as jnp
+from elasticdl_tpu import obs
+from elasticdl_tpu.parallel import MeshConfig, build_mesh, compile as pc
+
+inner = jax.jit(lambda x: jnp.tanh(x) @ x)
+plan = pc.CompilePlan(build_mesh(MeshConfig()), trainer="test")
+outer = plan.compile(
+    lambda x: inner(x) + inner(x * 2), name="outer", journal=False)
+x = jnp.ones((64, 64))
+outer(x).block_until_ready()
+(build,) = [
+    e for e in obs.journal().tail(100) if e.get("name") == "compile.build"]
+print(json.dumps(build))
+"""
+
+
+def test_build_span_says_what_it_was_made_of_and_reads_the_cache(tmp_path):
+    """A jit that calls a jit reports the inner trace inside the outer:
+    the parts are unions, so they sum to no more than the span.  A second
+    process finds the program in the persistent cache."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_ENABLE_COMPILATION_CACHE="true",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"))
+    builds = []
+    for _ in range(2):
+        proc = subprocess.run(
+            [sys.executable, "-c", _BUILD_PROBE], cwd=REPO_ROOT, env=env,
+            capture_output=True, text=True, timeout=300,
+        )
+        assert proc.returncode == 0, proc.stderr[-2000:]
+        builds.append(json.loads(proc.stdout.strip().splitlines()[-1]))
+    for build in builds:
+        assert build["entrypoint"] == "outer" and build["programs"] == 1
+        parts = build["trace_s"] + build["lower_s"] + build["backend_s"]
+        assert 0 < parts <= build["duration_s"] + 1e-6
+        assert min(build["trace_s"], build["lower_s"], build["backend_s"]) > 0
+        assert build["cache_read_s"] <= build["backend_s"] + 1e-6
+    cold, warm = builds
+    assert cold["cache_hit"] is False and cold["cache_read_s"] == 0
+    assert warm["cache_hit"] is True and warm["cache_read_s"] > 0
+
+
+def test_build_parts_take_the_union_of_what_jax_reports(monkeypatch):
+    """Durations that overlap (a jit traced inside another; a program
+    compiled while another is traced) are not summed."""
+    from elasticdl_tpu.common import compile_cache
+
+    clock = [100.0]
+    monkeypatch.setattr(compile_cache.time, "monotonic", lambda: clock[0])
+    parts = compile_cache.BuildParts()
+    trace, lower, backend = (event for event, _ in reversed(compile_cache._PARTS))
+    clock[0] = 103.0
+    parts.note(trace, 1.0)            # the inner jit: 102..103
+    parts.note(backend, 0.5)          # an eager op inside the trace
+    clock[0] = 104.0
+    parts.note(trace, 4.0)            # the outer: 100..104, holds both
+    clock[0] = 105.0
+    parts.note(lower, 1.0)
+    clock[0] = 108.0
+    parts.note(compile_cache._READ_EVENT, 2.0)
+    parts.note(backend, 3.0)
+    parts.note(trace, 50.0)           # began before the build: clipped
+    parts.hits = 2
+    fields = parts.fields()
+    assert fields == {
+        "backend_s": 3.5, "lower_s": 1.0, "trace_s": 3.5,
+        "cache_read_s": 2.0, "programs": 2, "cache_hit": True,
+    }
+
+
+class _FakeFleet:
+    """A test double of the substrate: handles whose exit codes the test
+    sets."""
+
+    def __new__(cls, **kwargs):
+        from elasticdl_tpu.master.pod_manager import ElasticWorkerManager
+
+        class Handle:
+            def __init__(self, worker_id):
+                self.worker_id, self.code = worker_id, None
+
+        class Fleet(ElasticWorkerManager):
+            launched = []
+
+            def _substrate_launch(self, worker_ids):
+                handles = [Handle(wid) for wid in worker_ids]
+                self.launched.extend(handles)
+                return handles
+
+            def _substrate_poll(self, handle):
+                return handle.code
+
+            def _substrate_terminate(self, handles):
+                pass
+
+        return Fleet(worker_argv_fn=lambda wid: [], **kwargs)
+
+
+def test_a_relaunch_says_its_cause_and_how_long_after_the_exit():
+    done = []
+    fleet = _FakeFleet(
+        num_workers=1, max_restarts=1, poll_interval_s=0.02,
+        job_finished_fn=lambda: bool(done),
+    )
+    marker = time.time()
+    try:
+        fleet.start()
+        fleet.launched[0].code = 137  # preempted
+        deadline = time.time() + 20
+        while len(fleet.launched) < 2 and time.time() < deadline:
+            time.sleep(0.02)
+        assert len(fleet.launched) == 2
+        done.append(True)
+        fleet.launched[1].code = 0
+        assert fleet.wait(timeout=20)
+    finally:
+        fleet.stop()
+    first, second = _spans_since(marker, "master.launch_worker")
+    assert (first["worker_id"], first["cause"]) == (0, "start")
+    assert "since_exit_s" not in first
+    assert (second["worker_id"], second["cause"]) == (1, "relaunch")
+    assert 0 <= second["since_exit_s"] < 5
+
+
 # ---------------------------------------------------------------------------
 # Device scopes: metadata only
 # ---------------------------------------------------------------------------
@@ -685,7 +997,8 @@ def test_every_literal_span_name_in_the_training_path_is_in_the_list():
                     continue
                 func = node.func
                 called = getattr(func, "attr", getattr(func, "id", ""))
-                if called not in ("span", "record_span", "annotate"):
+                if called not in ("span", "record_span", "annotate",
+                                  "early_span", "open_span", "begin_boot"):
                     continue
                 first = node.args[0] if node.args else next(
                     (kw.value for kw in node.keywords if kw.arg == "name"),

@@ -289,8 +289,10 @@ from elasticdl_tpu import obs
 from elasticdl_tpu.common.args import parse_master_args
 from elasticdl_tpu.common.model_utils import ModelSpec
 from elasticdl_tpu.master.main import start_master
+from elasticdl_tpu.obs import tracing
 from elasticdl_tpu.worker.master_client import MasterClient
 
+tracing.begin_boot("master.boot")  # as the first line of a main does
 log_dir, training_data = sys.argv[1], sys.argv[2]
 args = parse_master_args([
     "--distribution_strategy=AllreduceStrategy", "--num_workers=1",
@@ -307,16 +309,15 @@ master = start_master(args, model_spec=spec)
 client = MasterClient(master.addr, worker_id=0)
 task = client.get_task()
 client.close()
-(ready,) = [
+(boot,) = [
     e for e in obs.journal().tail(2000)
-    if e.get("event") == "span" and e["name"] == "master.serve_ready"
+    if e.get("event") == "span" and e["name"] == "master.boot"
 ]
 writer = master.tensorboard_service._writer
 master.stop()
 print(json.dumps({
     "served": [task.start, task.end],
-    "heavy_imports": ready["heavy_imports"],
-    "since_main_s": ready.get("since_main_s"),
+    "heavy_imports": boot["heavy_imports"],
     "writer": writer is not None,
 }))
 """
@@ -356,11 +357,11 @@ def test_master_boots_without_heavy_imports(
     if writable:
         # The journal's own line carries the field, as obs.trace reads it.
         with open(log_dir / "events.jsonl") as f:
-            (ready,) = [
+            (boot,) = [
                 e for e in map(json.loads, f)
-                if e.get("name") == "master.serve_ready"
+                if e.get("name") == "master.boot"
             ]
-        assert ready["heavy_imports"] == heavy_imports
+        assert boot["heavy_imports"] == heavy_imports
         assert glob.glob(str(log_dir / "events.out.tfevents.*"))
 
 
