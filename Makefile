@@ -171,7 +171,8 @@ compile-gates:
 # the no-direct-jit grep gate, the shard_map microbench selftest, and
 # the multi-device fused-vs-xla equivalence + per-shard HLO tests.
 test-compile: compile-gates
-	JAX_PLATFORMS=cpu python -m pytest tests/test_compile.py -q -m 'not slow'
+	JAX_PLATFORMS=cpu python -m pytest tests/test_compile.py \
+	       tests/test_executable_store.py -q -m 'not slow'
 	JAX_PLATFORMS=cpu python -m pytest tests/test_sparse_kernels.py \
 	       -q -m 'not slow' -k 'multi_device or multichip or dispatch_route'
 

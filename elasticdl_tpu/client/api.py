@@ -59,7 +59,9 @@ def _run_local(args, mode: str):
     """Master + one worker in this process, wired over localhost gRPC."""
     from elasticdl_tpu.common import compile_cache
 
-    compile_cache.configure(getattr(args, "jax_compilation_cache_dir", ""))
+    compile_cache.configure(
+        getattr(args, "jax_compilation_cache_dir", ""), args=args
+    )
     model_spec = load_model_spec(args)
     master = start_master(args, model_spec=model_spec)
     if mode == Mode.EVALUATION:

@@ -12,6 +12,11 @@ no compiled code pays for each program once and not once per process.
   fixed path inside the checkout (`<repo>/.jax_cache`, gitignored).
   Never a temp dir, a pid or a timestamp: the directory is part of the
   cache key, so a path that moves never hits.
+
+A process that also hands `configure()` its parsed arguments gets the
+executable store (`common/executable_store.py`) in that directory's
+`executables/`: `parallel/compile.py` loads a build from it without
+tracing, by a key those arguments are part of.
 """
 
 from __future__ import annotations
@@ -33,10 +38,19 @@ REPO_CACHE_DIR = os.path.join(
 )
 
 
-def configure(flag_dir: str = "") -> str:
+#: The process's executable store: `configure(args=...)` opens it,
+#: `parallel/compile.py` reads it at an entrypoint's first call.
+_store = None
+
+
+def configure(flag_dir: str = "", args=None) -> str:
     """Point this process at the persistent compile cache and return
     the directory in use.  Touches `jax.config` only — no backend is
-    initialized."""
+    initialized.  `args` (the process's parsed arguments) opens the
+    executable store beside the cache, unless the process was told to
+    keep no compiled programs (`jax_enable_compilation_cache` off); a
+    later call without them leaves it as it is."""
+    global _store
     import jax
 
     cache_dir = os.environ.get(ENV_VAR, "")
@@ -48,7 +62,20 @@ def configure(flag_dir: str = "") -> str:
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
     _count_events()
+    if args is not None:
+        _store = None
+        if jax.config.jax_enable_compilation_cache:
+            from elasticdl_tpu.common.executable_store import ExecutableStore
+
+            _store = ExecutableStore(
+                os.path.join(cache_dir, "executables"), args
+            )
     return cache_dir
+
+
+def executable_store():
+    """The store `configure()` opened for this process, or None."""
+    return _store
 
 
 _HIT_EVENT = "/jax/compilation_cache/cache_hits"

@@ -722,6 +722,7 @@ def _boot_chains(events: List[dict]) -> List[dict]:
                     for key in (
                         "entrypoint", "duration_s", "trace_s", "lower_s",
                         "backend_s", "cache_read_s", "programs", "cache_hit",
+                        "aot_hit", "aot_load_s", "aot_key", "aot_skip",
                     )
                     if key in e
                 }
@@ -955,14 +956,19 @@ def render_report(summary: dict, max_segments: int = 80) -> str:
                 parts = ", ".join(
                     f"{key[:-2]} {_fmt_seconds(build[key])}"
                     for key in ("trace_s", "lower_s", "backend_s",
-                                "cache_read_s")
+                                "cache_read_s", "aot_load_s")
+                    if key in build
+                )
+                stored = "".join(
+                    f", {key} {build[key]}"
+                    for key in ("aot_hit", "aot_key", "aot_skip")
                     if key in build
                 )
                 lines.append(
                     f"    {_fmt_seconds(build['duration_s']):>8}  "
                     f"compile.build {build.get('entrypoint')}: {parts}, "
                     f"programs {build.get('programs')}, "
-                    f"cache_hit {build.get('cache_hit')}"
+                    f"cache_hit {build.get('cache_hit')}{stored}"
                 )
     task_chains = summary.get("task_chains")
     if task_chains:

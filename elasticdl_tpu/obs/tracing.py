@@ -162,7 +162,8 @@ SPAN_NAMES: Dict[str, str] = {
     "state.init": "interval: model.init / restore + placement",
     "compile.build": "interval: first call of a jitted entrypoint "
                      "(`trace_s`, `lower_s`, `backend_s`, `cache_read_s`, "
-                     "`programs`, `cache_hit`)",
+                     "`programs`, `cache_hit`; the executable store: "
+                     "`aot_hit`, `aot_load_s`, `aot_key`, `aot_skip`)",
     # expert routing (layers/moe.py): a task's counters ride on a span
     "moe.routing": "after: worker, one a task of a model with expert "
                    "layers: pairs routed to held experts, dropped (0), "

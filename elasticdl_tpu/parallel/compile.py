@@ -45,7 +45,9 @@ form is a rule-table entry, not a new trainer.
 
 from __future__ import annotations
 
+import logging
 import re
+import time
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -244,24 +246,79 @@ def jit_utility(fn: Callable, **jit_kwargs):
 # ---------------------------------------------------------------------------
 
 
-class _BuildSpan:
-    """A jitted entrypoint whose FIRST call — tracing, lowering, the XLA
-    compile or the persistent cache's load, and the dispatch — runs
-    inside a `compile.build` span: `entrypoint`, and what JAX's own
-    events say the call was made of (`common/compile_cache.BuildParts`):
-    `trace_s`, `lower_s`, `backend_s` (XLA's compile or the cache's
-    load), `cache_read_s` (the part of `backend_s` that was retrieval),
-    `programs` (backend requests) and `cache_hit` (every one of them came
-    from the persistent cache).  What is left of the span is its own:
-    argument handling, `device_put`s, the dispatch.  Every later call
-    goes straight through; attributes (`_cache_size`, `lower`, ...) are
-    the jitted function's own."""
+class _EngineLines(logging.Handler):
+    """Collects the `... engine: pallas|xla ... (why)` lines the kernels
+    log while a program is traced, so that a build served from the store
+    can still say which kernels its program holds."""
 
-    def __init__(self, jitted, name: str):
+    def __init__(self):
+        super().__init__(level=logging.INFO)
+        self.lines: List[str] = []
+
+    def emit(self, record: logging.LogRecord) -> None:
+        line = record.getMessage()
+        if " engine: " in line and line not in self.lines:
+            self.lines.append(line)
+
+    def __enter__(self) -> List[str]:
+        logging.getLogger("elasticdl_tpu").addHandler(self)
+        return self.lines
+
+    def __exit__(self, *exc) -> None:
+        logging.getLogger("elasticdl_tpu").removeHandler(self)
+
+
+class _BuildSpan:
+    """A jitted entrypoint whose FIRST call runs inside a `compile.build`
+    span and goes through the executable store
+    (`common/executable_store.py`) where the process has one:
+
+    - hit: the stored executable is loaded and called; nothing is traced
+      or lowered (`aot_hit` true, `aot_load_s`; `trace_s` / `lower_s` /
+      `backend_s` read 0.0, the persistent cache is not consulted);
+    - miss: `lower().compile()` through the persistent cache as before,
+      and the result is written to the store;
+    - wherever the store cannot be sound or cannot work (`aot_skip` says
+      why: no store, more than one process, a program that does not
+      serialise, ...) the jitted function is called exactly as before.
+
+    The span carries `entrypoint`, `aot_hit`, `aot_load_s`, `aot_key`, and
+    what JAX's own events say the call was made of
+    (`common/compile_cache.BuildParts`): `trace_s`, `lower_s`,
+    `backend_s` (XLA's compile or the persistent cache's load),
+    `cache_read_s` (the part of `backend_s` that was retrieval),
+    `programs` and `cache_hit`.  What is left of the span is its own:
+    the key, argument handling, `device_put`s, the dispatch.
+
+    Later calls run the same executable, whose own argument check (the
+    C++ one a jitted call makes: treedef, each leaf's shape and dtype,
+    committed shardings; nothing per call is added in Python) refuses
+    any other arguments before anything runs or is donated: such a call
+    (a short last task) falls through to the jitted function.
+    `_cache_size()` counts the stored or ahead-of-time build as one
+    compile and adds the jitted function's own
+    (`obs/stepstats.RetraceWatcher` reads it); other attributes
+    (`lower`, ...) are the jitted function's."""
+
+    def __init__(self, jitted, name: str, mesh=None,
+                 donate_argnums: Tuple[int, ...] = (), static: bool = False):
         self._jitted, self._name, self._built = jitted, name, False
+        self._mesh, self._donated, self._static = mesh, donate_argnums, static
+        # The executable the first call loaded or built ahead of time.
+        self._compiled = None
 
     def __call__(self, *args, **kwargs):
         if self._built:
+            if self._compiled is not None:
+                try:
+                    return self._compiled(*args, **kwargs)
+                except (TypeError, ValueError):
+                    # Not the arguments it was compiled for (`TypeError`:
+                    # tree, shapes, dtypes; `ValueError`: shardings),
+                    # found before it ran: the jitted function's call,
+                    # which raises what is raised today if they are
+                    # wrong for it too.
+                    pass
             return self._jitted(*args, **kwargs)
         from elasticdl_tpu.common import compile_cache
         from elasticdl_tpu.obs import tracing
@@ -269,12 +326,81 @@ class _BuildSpan:
         self._built = True
         with tracing.span("compile.build", entrypoint=self._name) as span:
             with compile_cache.measuring() as parts:
-                out = self._jitted(*args, **kwargs)
+                out = self._first_call(span.fields, args, kwargs)
             span.fields.update(parts.fields())
         return out
 
+    def _cache_size(self) -> int:
+        return int(self._compiled is not None) + self._jitted._cache_size()
+
     def __getattr__(self, attr):
         return getattr(self._jitted, attr)
+
+    def _key(self, store, args, kwargs) -> str:
+        import jax
+
+        from elasticdl_tpu.common import executable_store
+        from elasticdl_tpu.parallel import packed
+
+        if store is None:
+            raise executable_store.Skip("the process has no executable store")
+        if self._static:
+            raise executable_store.Skip("static arguments")
+        leaves, treedef = jax.tree_util.tree_flatten((args, kwargs))
+        return store.key(
+            entrypoint=self._name, donate_argnums=self._donated,
+            trace_state={"oov_debug": packed.oov_debug_enabled()},
+            mesh=self._mesh, treedef=treedef,
+            signature=[
+                executable_store.leaf_signature(leaf) for leaf in leaves
+            ],
+        )
+
+    def _first_call(self, fields: dict, args, kwargs):
+        from elasticdl_tpu.common import compile_cache, executable_store
+
+        fields.update(aot_hit=False, aot_load_s=0.0)
+        store = compile_cache.executable_store()
+        try:
+            key = self._key(store, args, kwargs)
+        except executable_store.Skip as skip:
+            fields["aot_skip"] = str(skip)
+            return self._jitted(*args, **kwargs)
+        fields["aot_key"] = key[:12]
+        started = time.monotonic()
+        try:
+            found = store.load(key, self._mesh)
+        except executable_store.Skip as skip:
+            fields["aot_skip"], found = str(skip), None
+        if found is not None:
+            self._compiled, record = found
+            fields.update(
+                aot_hit=True,
+                aot_load_s=round(time.monotonic() - started, 6),
+            )
+            for line in record["engine_lines"]:
+                logger.info("%s (stored build)", line)
+            logger.info(
+                "%s: loaded the stored build %s (%d bytes) in %.2fs",
+                self._name, key[:12], record["bytes"], fields["aot_load_s"],
+            )
+            return self._compiled(*args, **kwargs)
+        with _EngineLines() as lines:
+            self._compiled = self._jitted.lower(*args, **kwargs).compile()
+        started = time.monotonic()
+        try:
+            written = store.save(
+                key, self._compiled,
+                {"entrypoint": self._name, "engine_lines": lines},
+            )
+        except executable_store.Skip as skip:
+            fields["aot_skip"] = str(skip)
+        else:
+            logger.info(
+                "%s: stored the build as %s (%d bytes) in %.2fs",
+                self._name, key[:12], written, time.monotonic() - started,
+            )
+        return self._compiled(*args, **kwargs)
 
 
 def _journal_plan(record: Dict[str, Any]) -> None:
@@ -386,4 +512,7 @@ class CompilePlan:
                 "donated_argnums": list(donate_argnums),
                 "devices": int(self.mesh.devices.size),
             })
-        return _BuildSpan(compiled, name)
+        return _BuildSpan(
+            compiled, name, mesh=self.mesh, donate_argnums=donate_argnums,
+            static=static_argnums not in (None, ()),
+        )

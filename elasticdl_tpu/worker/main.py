@@ -97,7 +97,7 @@ def _configure_process(argv, imports):
     logger.info(
         "JAX compilation cache: %s",
         compile_cache.configure(
-            getattr(args, "jax_compilation_cache_dir", "")
+            getattr(args, "jax_compilation_cache_dir", ""), args=args
         ),
     )
     if getattr(args, "oov_diagnostics", False):

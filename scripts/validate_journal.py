@@ -224,6 +224,8 @@ EVENT_OPTIONAL_FIELDS = {
         "heavy_imports", "imported", "jax_import_s", "cause",
         "since_exit_s", "trace_s", "lower_s", "backend_s",
         "cache_read_s", "programs",
+        # The executable store's part in a compile.build.
+        "aot_hit", "aot_load_s", "aot_key", "aot_skip",
     ),
     "phase_transition": ("cause",),
     "rescale_cost": (

@@ -217,6 +217,78 @@ def test_compile_shard_map_strategy_runs_map_style_body():
     assert last["strategy"] == "shard_map"
 
 
+def _instructions(hlo_text):
+    """The program's instructions without their source locations."""
+    return [
+        re.sub(r", metadata=\{[^}]*\}", "", line)
+        for line in hlo_text.splitlines()
+        if " = " in line or line.startswith(("ENTRY", "%", "}"))
+    ]
+
+
+@pytest.fixture
+def executable_store(tmp_path, monkeypatch):
+    """An open executable store for this process (the suite runs with the
+    compilation cache off, so `compile_cache.configure` opens none)."""
+    from elasticdl_tpu.common import args as args_lib
+    from elasticdl_tpu.common import compile_cache, executable_store
+
+    args = args_lib.build_worker_parser().parse_args([
+        "--model_zoo=model_zoo", "--model_def=mnist.mnist_functional_api",
+        "--worker_id=0", "--master_addr=localhost:1",
+    ])
+    store = executable_store.ExecutableStore(
+        str(tmp_path / "executables"), args
+    )
+    monkeypatch.setattr(compile_cache, "_store", store)
+    return store
+
+
+@pytest.mark.parametrize("strategy", ["pjit", "shard_map"])
+def test_stored_build_is_the_program_the_trace_compiles(
+    executable_store, strategy
+):
+    """An entrypoint served from the executable store runs the executable
+    its own `lower().compile()` gives, on the 8-device mesh, under either
+    strategy; `.lower` still reaches the jitted function through the
+    wrapper, and the compile is counted once."""
+    mesh = build_mesh(MeshConfig(data=8, model=1))
+    plan = pc.CompilePlan(mesh, trainer="test_trainer")
+    sharded = NamedSharding(mesh, P(DATA_AXIS))
+
+    def build():
+        if strategy == "shard_map":
+            return plan.compile(
+                lambda x: x * jax.lax.psum(jnp.ones((), x.dtype), DATA_AXIS),
+                name="stored_map", in_specs=(P(DATA_AXIS),),
+                out_specs=P(DATA_AXIS), journal=False,
+            )
+        return plan.compile(
+            lambda x: x * x.sum(), name="stored_step", journal=False,
+            in_shardings=(sharded,), out_shardings=sharded,
+        )
+
+    x = jax.device_put(jnp.ones((16, 4)), sharded)
+    traced, stored = build(), build()
+    want = np.asarray(traced(x))
+    got = stored(x)
+    np.testing.assert_array_equal(np.asarray(got), want)
+    assert got.sharding.is_equivalent_to(sharded, 2)
+    builds = [
+        e for e in _journal_events("span")
+        if e.get("name") == "compile.build"
+        and e.get("entrypoint", "").startswith("stored_")
+    ][-2:]
+    assert [b["aot_hit"] for b in builds] == [False, True]
+    assert traced._cache_size() == stored._cache_size() == 1
+    # The loaded executable is the traced one, byte for byte of its text;
+    # a fresh lowering differs from it in source locations only.
+    assert stored._compiled.as_text() == traced._compiled.as_text()
+    assert _instructions(stored._compiled.as_text()) == _instructions(
+        stored.lower(x).compile().as_text()
+    )
+
+
 # ---------------------------------------------------------------------------
 # 3. Per-trainer HLO-structure parity (compile layer vs hand-rolled)
 # ---------------------------------------------------------------------------

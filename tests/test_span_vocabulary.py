@@ -147,13 +147,14 @@ BOOT_CHAIN_SPANS = (
 BOOT_CHAIN_FIELDS = (
     "heavy_imports", "imported", "jax_import_s", "cause", "since_exit_s",
     "trace_s", "lower_s", "backend_s", "cache_read_s", "programs",
+    "aot_hit", "aot_load_s", "aot_key", "aot_skip",
 )
 BOOT_CHAIN_METRICS = (
     "setup_named_share", "setup_largest_gap_s", "harness_prepare_s",
     "worker_launch_s", "build_trace_s", "build_lower_s", "build_backend_s",
     "build_cache_read_s", "master_main_to_serving_s", "master_spec_load_s",
     "worker_boot_s", "worker_imports_s", "worker_spec_load_s",
-    "trainer_build_s",
+    "trainer_build_s", "build_aot_load_s", "build_aot_hits",
 )
 
 
@@ -502,6 +503,9 @@ def test_first_call_of_a_compiled_entrypoint_is_a_build_span():
     assert len(builds) == 1
     assert builds[0]["entrypoint"] == "double"
     assert builds[0]["cache_hit"] in (True, False)
+    # No executable store in this process: the jitted function's own call.
+    assert builds[0]["aot_hit"] is False and builds[0]["aot_load_s"] == 0.0
+    assert builds[0]["aot_skip"] == "the process has no executable store"
     assert double._cache_size() == 1  # the jitted function's own
 
 
@@ -649,6 +653,7 @@ def test_report_prints_a_boot_row_for_each_process(tiny_job, capsys):
     text = out[:out.index('{\n  "wall_s"')]
     assert "boot (a row a process" in text
     assert "compile.build dp_train_window: trace " in text
+    assert "aot_load " in text and "aot_hit False, aot_skip " in text
 
 
 _BUILD_PROBE = """
