@@ -8,31 +8,43 @@ shared expert).  `SparseMoeBlock` is that layer for one chip: it is told
 which experts it holds (`held = (first, count)`) and adds nothing that
 stands in for the absent chips or their exchange.  With
 `held = (0, num_experts)` it is the whole layer.  What varies between
-architectures is said in fields.  `kind` names a score function with the
-expert form it comes with (the three models of the zoo know two
-pairings):
+architectures is said in fields.  `score` names the router's score
+function and `expert_form` what an expert (and the shared expert)
+computes; the two are independent:
 
-    kind="softmax_gated_silu"         (model_zoo/qwen3_next, deepseek_v2)
+    score="softmax"
     p = softmax(W_r x)  over all experts, float32
     top-k of p; with `norm_topk_prob` the weights are divided by their
     sum (over all k, held or not); times `routed_scale`
+
+    score="sigmoid"
+    s = sigmoid(W_r x)  over all experts, float32
+    top-k of s + b (a selection bias: it chooses, and is in no weight)
+    w = s at the chosen; with `norm_topk_prob` divided by their sum;
+    times `routed_scale`
+
+    expert_form="gated_silu"    three products
     y = sum_k w_k * W_down,k (silu(W_gate,k x) * W_up,k x)   held k only
       + [sigmoid(w_sg . x) *] shared(x)     shared: the same gated form
 
-    kind="sigmoid_relu2"                               (model_zoo/nemotron_h)
-    s = sigmoid(W_r x)  over all experts, float32
-    top-k of s + b (a selection bias: it chooses, and is in no weight)
-    w = s at the chosen, divided by their sum, times `routed_scale`
+    expert_form="relu2"         two products
     y = sum_k w_k * W_down,k relu(W_up,k x)^2                held k only
       + W_down,s relu(W_up,s x)^2           the shared expert
 
+The zoo's pairings:
+
+    softmax + gated_silu    model_zoo/qwen3_next, deepseek_v2
+    sigmoid + relu2         model_zoo/nemotron_h
+    sigmoid + gated_silu    model_zoo/laguna
+
 `shared_gated` says whether a sigmoid gate of the token multiplies the
-shared expert (Qwen3-Next's does; Nemotron-H's and DeepSeek-V2's, which
-is its `n_shared_experts` experts as ONE MLP of their summed width, do
-not; where the field is None the kind's own source decides).  The gated
-shared expert is the module `shared_expert` with `shared_expert_gate`,
-the ungated one `shared_experts`, as in the sources.  `balance_alpha`
-adds a softmax router's balancing loss (below).
+shared expert (Qwen3-Next's does; Nemotron-H's, Laguna's and
+DeepSeek-V2's, which is its `n_shared_experts` experts as ONE MLP of
+their summed width, do not; where the field is None a softmax router's
+is gated and a sigmoid router's is not, as their first sources have it).
+The gated shared expert is the module `shared_expert` with
+`shared_expert_gate`, the ungated one `shared_experts`, as in the
+sources.  `balance_alpha` adds a softmax router's balancing loss (below).
 
 All run the SAME block plan, loop, counters and scopes; the expert form
 is two or three stacked weight tensors handed to one loop.
@@ -370,13 +382,15 @@ class SparseMoeBlock(nn.Module):
     norm_topk_prob: bool = True
     dtype: Any = jnp.bfloat16
     block_rows: int = 128
-    # A score function and the expert form it comes with (module
-    # docstring): a softmax router with gated-SiLU experts, or a sigmoid
-    # router (a selection bias) with relu^2 experts.
-    kind: str = "softmax_gated_silu"
+    # The router's score function (module docstring): "softmax", or
+    # "sigmoid" with its selection bias.
+    score: str = "softmax"
+    # What an expert computes: "gated_silu" (three products) or "relu2"
+    # (two).
+    expert_form: str = "gated_silu"
     routed_scale: float = 1.0
     # Whether a sigmoid gate multiplies the shared expert; None: as the
-    # kind's first source has it (softmax: gated, sigmoid: not).
+    # score function's first source has it (softmax: gated, sigmoid: not).
     shared_gated: Optional[bool] = None
     # > 0: the softmax router's sequence-wise balancing loss, times this.
     balance_alpha: float = 0.0
@@ -389,13 +403,16 @@ class SparseMoeBlock(nn.Module):
             raise ValueError(
                 f"held={self.held} is no range of {self.num_experts} experts"
             )
-        if self.kind not in ("softmax_gated_silu", "sigmoid_relu2"):
-            raise ValueError(f"no expert layer of kind {self.kind!r}")
-        gated = self.kind == "softmax_gated_silu"
+        if self.score not in ("softmax", "sigmoid"):
+            raise ValueError(f"no router score function {self.score!r}")
+        if self.expert_form not in ("gated_silu", "relu2"):
+            raise ValueError(f"no expert form {self.expert_form!r}")
+        softmax = self.score == "softmax"
+        gated = self.expert_form == "gated_silu"
         shared_gated = (
-            gated if self.shared_gated is None else self.shared_gated
+            softmax if self.shared_gated is None else self.shared_gated
         )
-        if self.balance_alpha and not gated:
+        if self.balance_alpha and not softmax:
             raise ValueError("the balancing loss is a softmax router's")
         shape, d = x.shape, x.shape[-1]
         x = x.reshape(-1, d)
@@ -414,7 +431,7 @@ class SparseMoeBlock(nn.Module):
             ) + [("up_proj", (d, width)), ("down_proj", (width, d))]
         )
         with jax.named_scope("moe_route"):
-            if gated:
+            if softmax:
                 router = self.param("gate", init, (d, self.num_experts),
                                     jnp.float32)
             else:
@@ -426,7 +443,7 @@ class SparseMoeBlock(nn.Module):
                 precision=jax.lax.Precision.HIGHEST,
             )
             balance = None
-            if gated:
+            if softmax:
                 scores = jax.nn.softmax(logits, axis=-1)
                 weight, expert = jax.lax.top_k(scores, self.top_k)
                 if self.balance_alpha:

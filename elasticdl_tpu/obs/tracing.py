@@ -181,12 +181,15 @@ SPAN_NAMES: Dict[str, str] = {
 #: moe_shared), lm_head_loss); with the state-space hybrid (model_zoo/nemotron_h)
 #: fwd_bwd > (ssm > ssm_scan, attn, moe > (...), lm_head_loss).  With the
 #: latent-attention model (model_zoo/deepseek_v2): fwd_bwd > (attn >
-#: (mla_latent, mla_core), mlp, moe > (...), lm_head_loss).
+#: (mla_latent, mla_core), mlp, moe > (...), lm_head_loss).  With the
+#: window-and-full-attention model (model_zoo/laguna): fwd_bwd > (attn >
+#: (attn_full | attn_window, attn_gate), mlp, moe > (...), lm_head_loss).
 DEVICE_SCOPES = (
     "fwd_bwd", "dense_update", "sparse_apply", "grad_accumulate",
     "sparse_adam", "attn", "mlp", "lm_head_loss", "optimizer",
     "gdn", "gdn_scan", "moe", "moe_route", "moe_experts", "moe_shared",
     "ssm", "ssm_scan", "gdn_mix", "mla_latent", "mla_core",
+    "attn_full", "attn_window", "attn_gate",
 )
 
 #: Size bound on the flight recorder's final registry snapshot: the

@@ -30,6 +30,13 @@ scaled by `scale`, 1/sqrt(Dqk) where none is given.  Two engines:
   recomputes a block's probabilities from them.  One [B, Hq, block, block]
   slab is alive at a time.  Query heads of one key-value head are batched
   into one product, so K and V are never repeated.
+
+`causal_attention(window=W)` is a causal BAND: a query at t reads the keys
+`t - W < s <= t`.  The XLA engine alone runs it, and skips what lies
+outside the band, forward and backward: its key loop starts at the band's
+first block (`_first_block`).  The Pallas kernel has no band (a streaming
+one, tried in PR 36, was slower: PERF.md section 6, ROADMAP queue 1
+item 5), so `impl="pallas"` with a window raises.
 """
 
 from __future__ import annotations
@@ -147,22 +154,40 @@ def _block_size(t: int, block: int) -> int:
     return t
 
 
-def _scores(q_i, k_j, i, j, block, scale):
-    """[B,Bq,N,G,D] x [B,Bk,N,D] -> masked scores [B,N,G,Bq,Bk], float32."""
+def _first_block(i, block: int, window):
+    """The first key block query block `i` reads: 0 under a causal mask
+    alone (a literal, so that the loop traces as it did before bands),
+    else the block that holds key `i block - window + 1`, the first of
+    the `window - 1` keys before the block's first row."""
+    if window is None:
+        return 0
+    return jnp.maximum(i - (window + block - 2) // block, 0)
+
+
+def _scores(q_i, k_j, i, j, block, scale, window=None):
+    """[B,Bq,N,G,D] x [B,Bk,N,D] -> masked scores [B,N,G,Bq,Bk], float32.
+    A row with no key in block j (a band's first block) reads NEG_INF
+    throughout; the diagonal block, visited last, holds its own key, and
+    the running maximum then wipes what such a row gathered."""
     s = jnp.einsum(
         "bqngd,bknd->bngqk", q_i, k_j, preferred_element_type=jnp.float32
     ) * scale
     rows = i * block + jnp.arange(block)
     cols = j * block + jnp.arange(block)
-    return jnp.where(cols[None, :] > rows[:, None], NEG_INF, s)
+    masked = cols[None, :] > rows[:, None]
+    if window is not None:
+        masked = masked | (cols[None, :] <= rows[:, None] - window)
+    return jnp.where(masked, NEG_INF, s)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def causal_gqa_attention(q, k, v, block: int, scale=None):
+@partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def causal_gqa_attention(q, k, v, block: int, scale=None, window=None):
     """q [B, T, Hq, D]; k [B, T, Hkv, D]; v [B, T, Hkv, Dv] ->
     [B, T, Hq, Dv]; softmax of q k^T scale (1/sqrt(D) where none is
-    given) under a causal mask, float32 accumulation."""
-    return _gqa_fwd(q, k, v, block, scale)[0]
+    given) under a causal mask, float32 accumulation.  With `window` a
+    query reads the `window` keys up to its own, and a query block visits
+    only the key blocks that band touches, forward and backward."""
+    return _gqa_fwd(q, k, v, block, scale, window)[0]
 
 
 def _blocked(x, block):  # [B, T, ...] -> [T / block, B, block, ...]
@@ -172,7 +197,7 @@ def _blocked(x, block):  # [B, T, ...] -> [T / block, B, block, ...]
     )
 
 
-def _gqa_fwd(q, k, v, block, scale):
+def _gqa_fwd(q, k, v, block, scale, window=None):
     b, t, hq, d = q.shape
     n, dv = k.shape[2], v.shape[3]
     g = hq // n
@@ -185,7 +210,7 @@ def _gqa_fwd(q, k, v, block, scale):
 
         def kv_step(j, carry):
             m, l, acc = carry
-            s = _scores(q_i, kb[j], i, j, block, scale)
+            s = _scores(q_i, kb[j], i, j, block, scale, window)
             m_new = jnp.maximum(m, jnp.max(s, axis=-1))
             p = jnp.exp(s - m_new[..., None])
             fix = jnp.exp(m - m_new)
@@ -195,11 +220,14 @@ def _gqa_fwd(q, k, v, block, scale):
             )
             return m_new, l * fix + jnp.sum(p, axis=-1), acc
 
-        m, l, acc = jax.lax.fori_loop(0, i + 1, kv_step, (
-            jnp.full((b, n, g, block), NEG_INF, jnp.float32),
-            jnp.zeros((b, n, g, block), jnp.float32),
-            jnp.zeros((b, n, g, block, dv), jnp.float32),
-        ))
+        m, l, acc = jax.lax.fori_loop(
+            _first_block(i, block, window), i + 1, kv_step,
+            (
+                jnp.full((b, n, g, block), NEG_INF, jnp.float32),
+                jnp.zeros((b, n, g, block), jnp.float32),
+                jnp.zeros((b, n, g, block, dv), jnp.float32),
+            ),
+        )
         return None, ((acc / l[..., None]).astype(q.dtype), m + jnp.log(l))
 
     _, (out, lse) = jax.lax.scan(q_step, None, (qb, jnp.arange(t // block)))
@@ -208,7 +236,7 @@ def _gqa_fwd(q, k, v, block, scale):
     return out, (q, k, v, out, lse)
 
 
-def _gqa_bwd(block, scale, residuals, dout):
+def _gqa_bwd(block, scale, window, residuals, dout):
     q, k, v, out, lse = residuals
     b, t, hq, d = q.shape
     n, dv = k.shape[2], v.shape[3]
@@ -227,7 +255,7 @@ def _gqa_bwd(block, scale, residuals, dout):
 
         def kv_step(j, inner):
             dq_i, dk, dv = inner
-            s = _scores(q_i, kb[j], i, j, block, scale)
+            s = _scores(q_i, kb[j], i, j, block, scale, window)
             p = jnp.exp(s - lse_i[..., None])
             dv_j = jnp.einsum(
                 "bngqk,bqngd->bknd", p.astype(q.dtype), do_i,
@@ -249,7 +277,7 @@ def _gqa_bwd(block, scale, residuals, dout):
             return dq_i, dk.at[j].add(dk_j), dv.at[j].add(dv_j)
 
         dq_i, dk, dv = jax.lax.fori_loop(
-            0, i + 1, kv_step,
+            _first_block(i, block, window), i + 1, kv_step,
             (jnp.zeros(q_i.shape, jnp.float32),) + carry,
         )
         return (dk, dv), dq_i.astype(q.dtype)
@@ -273,10 +301,12 @@ def _head_sizes(d: int, dv: int) -> str:
     return f"D={d}" if d == dv else f"Dqk={d} Dv={dv}"
 
 
-def causal_attention(q, k, v, *, scale=None, impl: str = "auto",
-                     block: int = 512):
+def causal_attention(q, k, v, *, scale=None, window=None,
+                     impl: str = "auto", block: int = 512):
     """Causal softmax attention, grouped-query heads; the scores are
-    scaled by `scale`, 1/sqrt(Dqk) where none is given.
+    scaled by `scale`, 1/sqrt(Dqk) where none is given.  With `window` a
+    query at t reads the keys `t - window < s <= t` (its own among them):
+    the XLA engine's work, which skips the key blocks outside that band.
     q [B, T, Hq, Dqk]; k [B, T, Hkv, Dqk]; v [B, T, Hkv, Dv] (Dv may
     differ from Dqk) -> [B, T, Hq, Dv]."""
     # (`elasticdl_tpu.ops` exports the FUNCTION under the module's name)
@@ -288,25 +318,42 @@ def causal_attention(q, k, v, *, scale=None, impl: str = "auto",
         raise ValueError(f"impl must be auto, pallas or xla, got {impl!r}")
     _, t, hq, d = q.shape
     dv = v.shape[3]
+    if window is not None and window >= t:
+        window = None  # the band holds every key a causal mask leaves
     sizes = _head_sizes(d, dv)
+    if window is not None:
+        if window < 1:
+            raise ValueError(f"window must be at least 1 key, got {window}")
+        if impl == "pallas":
+            raise ValueError(
+                "the Pallas kernel has no band: a window needs impl auto "
+                "or xla"
+            )
+        sizes += f" window={window}"
     use_pallas = impl == "pallas" or (
-        impl == "auto" and jax.default_backend() == "tpu"
-        and supports(t, d, d_v=dv)
+        impl == "auto" and window is None
+        and jax.default_backend() == "tpu" and supports(t, d, d_v=dv)
     )
     if use_pallas:
+        n_rep = hq // k.shape[2]
         logger.info(
             "attention engine: pallas flash_attention T=%d %s "
-            "(interpret=%s)", t, sizes, _use_interpret(),
+            "(interpret=%s, repeat_kv x%d)", t, sizes, _use_interpret(),
+            n_rep,
         )
-        n_rep = hq // k.shape[2]
         return flash_attention(
             q, repeat_kv(k, n_rep), repeat_kv(v, n_rep), causal=True,
             scale=scale,
         )
+    if window is not None:
+        # Blocks of half the window: a query block then reads three key
+        # blocks, 1.5 windows of keys, where blocks of the window's size
+        # read two, 2 windows (measured there: 8.0 against 10.4 ms).
+        block = min(block, max(window // 2, 128))
     block = _block_size(t, block)
     logger.info(
         "attention engine: xla causal_gqa_attention T=%d %s "
         "(blocks of %d)", t, sizes, block,
     )
     with jax.named_scope("attn"):
-        return causal_gqa_attention(q, k, v, block, scale)
+        return causal_gqa_attention(q, k, v, block, scale, window)

@@ -252,7 +252,7 @@ class NemotronHLayer(nn.Module):
                     c.moe_intermediate_size,
                     c.moe_shared_expert_intermediate_size,
                     (c.experts_first, c.experts_held), c.norm_topk_prob,
-                    c.dtype, kind="sigmoid_relu2",
+                    c.dtype, score="sigmoid", expert_form="relu2",
                     routed_scale=c.routed_scaling_factor, name="mixer",
                 )
             else:

@@ -447,7 +447,8 @@ def test_alpha_zero_traces_no_extra_op_into_the_other_models():
         assert names and not any("balance" in name for name in names)
     with pytest.raises(ValueError):
         SparseMoeBlock(
-            8, 2, 16, 16, (0, 8), kind="sigmoid_relu2", balance_alpha=0.1
+            8, 2, 16, 16, (0, 8), score="sigmoid", expert_form="relu2",
+            balance_alpha=0.1,
         ).init(jax.random.PRNGKey(0), jnp.zeros((4, 8)))
 
 

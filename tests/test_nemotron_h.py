@@ -453,7 +453,7 @@ def _moe_layer(first, held, block_rows=128):
         MOE["n_routed_experts"], MOE["num_experts_per_tok"],
         MOE["moe_intermediate_size"],
         MOE["moe_shared_expert_intermediate_size"], (first, held), True,
-        jnp.float32, block_rows, kind="sigmoid_relu2",
+        jnp.float32, block_rows, score="sigmoid", expert_form="relu2",
         routed_scale=MOE["routed_scaling_factor"],
     )
 
@@ -490,7 +490,7 @@ def test_sigmoid_layer_has_the_sources_parameters_and_no_third_product():
     assert set(params["gate"]) == {"weight", "e_score_correction_bias"}
     assert set(params["shared_experts"]) == {"up_proj", "down_proj"}
     with pytest.raises(ValueError):
-        SparseMoeBlock(8, 2, 16, 16, (0, 8), kind="sigmoid_gated_silu").init(
+        SparseMoeBlock(8, 2, 16, 16, (0, 8), score="sigmoid_relu2").init(
             jax.random.PRNGKey(0), jnp.zeros((4, 32))
         )
 
@@ -652,7 +652,8 @@ def _sublayer(kind):
         m["n_routed_experts"], m["num_experts_per_tok"],
         m["moe_intermediate_size"], m["moe_shared_expert_intermediate_size"],
         (m["experts_first"], m["experts_held"]), True, bf16,
-        kind="sigmoid_relu2", routed_scale=m["routed_scaling_factor"],
+        score="sigmoid", expert_form="relu2",
+        routed_scale=m["routed_scaling_factor"],
     ), ref._experts
 
 

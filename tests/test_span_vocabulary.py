@@ -132,6 +132,7 @@ def test_every_emitted_name_is_in_the_vocabulary():
         "sparse_adam", "attn", "mlp", "lm_head_loss", "optimizer",
         "gdn", "gdn_scan", "moe", "moe_route", "moe_experts", "moe_shared",
         "ssm", "ssm_scan", "gdn_mix", "mla_latent", "mla_core",
+        "attn_full", "attn_window", "attn_gate",
     }
 
 
@@ -941,6 +942,34 @@ def _latent_window(seed=0):
     return trainer, trainer.stage_window([batch, batch])
 
 
+def _banded_window(seed=0):
+    """(trainer, staged window) of a tiny Laguna on the dp trainer (a full
+    layer with the dense MLP, a sliding one with experts), each layer
+    rematerialised as the benchmark's configuration runs it."""
+    sys.path.insert(0, REPO_ROOT)
+    from model_zoo.laguna import laguna_lm as zoo
+
+    from elasticdl_tpu.parallel import MeshConfig, build_mesh
+    from elasticdl_tpu.parallel.dp_trainer import DataParallelTrainer
+
+    model = zoo.custom_model(
+        vocab_size=64, hidden_size=32, intermediate_size=48,
+        moe_intermediate_size=16, shared_expert_intermediate_size=16,
+        head_dim=8, sliding_window=4, rope_full_attention_factor=64,
+        rope_full_attention_original_max_position_embeddings=8,
+        experts_first=2, experts_held=4, remat=True,
+    )
+    trainer = DataParallelTrainer(
+        model=model, loss_fn=zoo.loss, optimizer=zoo.optimizer(),
+        mesh=build_mesh(MeshConfig()),
+    )
+    rng = np.random.RandomState(seed)
+    tokens = rng.randint(0, 64, size=(8, 16)).astype(np.int32)
+    trainer.ensure_initialized(tokens)
+    batch = (tokens, tokens, np.ones((8,), np.float32))
+    return trainer, trainer.stage_window([batch, batch])
+
+
 @pytest.mark.parametrize("build,jit_attr,scopes", [
     (_dense_window, "_train_window_jit",
      ("fwd_bwd", "attn", "mlp", "lm_head_loss", "optimizer")),
@@ -953,6 +982,10 @@ def _latent_window(seed=0):
     (_latent_window, "_train_window_jit",
      ("fwd_bwd", "attn", "mla_latent", "mla_core", "mlp", "moe", "moe_route",
       "moe_experts", "moe_shared", "lm_head_loss", "optimizer")),
+    (_banded_window, "_train_window_jit",
+     ("fwd_bwd", "attn", "attn_full", "attn_window", "attn_gate", "mlp",
+      "moe", "moe_route", "moe_experts", "moe_shared", "lm_head_loss",
+      "optimizer")),
     (_sparse_window, "_train_window",
      ("fwd_bwd", "dense_update", "sparse_apply", "grad_accumulate",
       "sparse_adam")),

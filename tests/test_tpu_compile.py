@@ -283,6 +283,36 @@ def test_kernel_compiles_for_v5e(topo, case):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize("backward", [False, True], ids=["fwd", "bwd"])
+def test_xla_band_compiles_for_v5e(topo, backward):
+    """A sliding layer of Laguna (model_zoo/laguna): 64 heads of 128 over
+    8 key-value heads, a band of 512 keys at T = 8192.  The XLA block
+    engine is the one engine a band has: no custom call, K and V never
+    repeated, and one [8, 8, 256, 256] slab of scores alive at a time
+    (blocks of half the window), so the temporaries stay small beside
+    q, k, v and the output (128 + 2 x 16 + 128 MiB of bfloat16)."""
+    from elasticdl_tpu.ops import gqa
+
+    def out(q, k, v):
+        return gqa.causal_attention(q, k, v, window=512)
+
+    def loss(q, k, v):
+        return jnp.sum(out(q, k, v).astype(jnp.float32))
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    args = [
+        jax.ShapeDtypeStruct((1, 8192, heads, 128), jnp.bfloat16,
+                             sharding=one_chip)
+        for heads in (64, 8, 8)
+    ]
+    fn = jax.grad(loss, argnums=(0, 1, 2)) if backward else out
+    compiled = jax.jit(fn).lower(*args).compile()
+    memory = compiled.memory_analysis()
+    print("xla band bytes", backward, memory.temp_size_in_bytes)
+    assert "tpu_custom_call" not in compiled.as_text()
+    assert memory.temp_size_in_bytes < 0.5e9
+
+
 # The hybrid expert model's sublayers (model_zoo/qwen3_next) at the
 # widths and the 2 x 8192 tokens of `qwen3-next.train-synth-8k`: XLA ops
 # (`gdn_pallas`: the DeltaNet sublayer as a TPU backend traces it, its
@@ -532,6 +562,65 @@ def test_deepseek_v2_window_program_compiles_and_fits_for_v5e(
     assert memory.alias_size_in_bytes > 6.42e9              # donated
     total = memory.argument_size_in_bytes + memory.temp_size_in_bytes
     assert total < 13.0e9, total  # 12.51: the chip holds 16
+    assert "tpu_custom_call" not in compiled.as_text()      # the XLA engine
+
+
+# The window-and-full-attention expert model (model_zoo/laguna) at the
+# widths of `laguna-xs2.train-synth-8k`: the whole two-step window program
+# as the worker runs it, 8.30 GB of state donated (12 B x 691,624,960),
+# each layer rematerialised, both kinds of attention layer in the XLA
+# block engine (the configuration's `attn_impl=xla`: measured faster than
+# the Pallas kernels at 6 and 8 query heads a key-value head).  ONE
+# sequence a step fits with room (11.02 GB); two need 17.31 GB in this
+# engine, more than the chip has: the cell runs one.
+@pytest.mark.parametrize("sequences,least,most", [
+    (1, 10.5e9, 11.5e9), (2, 16.0e9, 18.0e9),
+])
+def test_laguna_window_program_compiles_and_fits_for_v5e(
+    topo, monkeypatch, sequences, least, most
+):
+    from elasticdl_tpu.parallel import MeshConfig, build_mesh
+    from elasticdl_tpu.parallel.dp_trainer import DataParallelTrainer
+    from model_zoo.laguna import laguna_lm as zoo
+
+    with open(os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "perfbench", "configs", "laguna-xs.2.json",
+    )) as f:
+        model = {
+            k: v for k, v in json.load(f)["model"].items()
+            if k != "sample_tokens"
+        }
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = build_mesh(MeshConfig(data=1, model=1), devices=topo.devices[:1])
+    trainer = DataParallelTrainer(
+        zoo.custom_model(use_bf16=True, remat=True, attn_impl="xla", **model),
+        zoo.loss, zoo.optimizer(), mesh,
+    )
+    on_chip = NamedSharding(mesh, P())
+    state, _ = jax.eval_shape(
+        lambda: trainer._make_state(
+            jax.random.PRNGKey(0), jnp.zeros((sequences, 8192), jnp.int32)
+        )
+    )
+    state = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=on_chip),
+        state,
+    )
+    window = jax.ShapeDtypeStruct(
+        (2, sequences, 8192), jnp.int32, sharding=on_chip
+    )
+    mask = jax.ShapeDtypeStruct((2, sequences), jnp.float32, sharding=on_chip)
+    compiled = jax.jit(
+        trainer._train_window_impl, donate_argnums=(0,)
+    ).lower(state, window, window, mask).compile()
+    memory = compiled.memory_analysis()
+    print("laguna window bytes", sequences, memory.argument_size_in_bytes,
+          memory.temp_size_in_bytes, memory.alias_size_in_bytes)
+    assert 8.29e9 < memory.argument_size_in_bytes < 8.31e9  # 12 B x 692M
+    assert memory.alias_size_in_bytes > 8.29e9              # donated
+    total = memory.argument_size_in_bytes + memory.temp_size_in_bytes
+    assert least < total < most, total  # the chip holds 16
     assert "tpu_custom_call" not in compiled.as_text()      # the XLA engine
 
 
