@@ -45,6 +45,7 @@ from typing import Any, Callable, Optional
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from elasticdl_tpu.parallel import packed as pk
 from elasticdl_tpu.parallel.packed import PackedSpec
@@ -169,10 +170,12 @@ class Embedding(nn.Module):
         # Record the logical spec so the PS trainer can pack/unpack and
         # drive the sparse optimizers.  `sow` so this is a no-op whenever
         # the collection isn't mutable (i.e. everywhere except init).
+        # A NumPy constant: the spec is static, and stays concrete for
+        # whoever reads it inside a traced init (ps_trainer._make_state).
         self.sow(
             SPECS_COLLECTION,
             "spec",
-            jnp.array([spec.vocab_size, spec.dim], jnp.int32),
+            np.array([spec.vocab_size, spec.dim], np.int32),
         )
         ids = jnp.asarray(ids).astype(jnp.int32)
         # Fixed-vocab contract: ids outside [0, vocab) contribute zeros

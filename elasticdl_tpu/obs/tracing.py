@@ -159,7 +159,8 @@ SPAN_NAMES: Dict[str, str] = {
     "spec.load": "interval: load_model_spec, the zoo module's import "
                  "(`imported`: which frameworks it brought)",
     "worker.backend_init": "interval: first jax.devices()",
-    "state.init": "interval: model.init / restore + placement",
+    "state.init": "interval: the state's shapes, then the jitted init "
+                  "(`dp_init` / `ps_init`) or the restore in its place",
     "compile.build": "interval: first call of a jitted entrypoint "
                      "(`trace_s`, `lower_s`, `backend_s`, `cache_read_s`, "
                      "`programs`, `cache_hit`; the executable store: "

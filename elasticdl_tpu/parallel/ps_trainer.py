@@ -337,39 +337,35 @@ class ShardedEmbeddingTrainer:
     def ensure_initialized(self, features) -> PSTrainState:
         if self._state is not None:
             return self._state
-        # `state.init`: model.init, the slots, and placement (or the
-        # restore over it: `checkpoint.restore.load` nests inside).
+        # `state.init`: the shapes, then the one program that births the
+        # state in its layout (or the restore in its place:
+        # `checkpoint.restore.load` nests inside).
         with tracing.span("state.init", trainer="ps_trainer"):
             self._init_state(features)
         self._compile_steps()
         return self._state
 
-    def _init_state(self, features) -> None:
-        rng = jax.random.PRNGKey(self._seed)
-        # Init with the GLOBAL batch shape (local rows x process count):
-        # perturbation variables take their shape from init, and apply runs
-        # on the assembled global batch.  Zeros keep init identical on
-        # every rank (param init only consumes shapes + rng).
-        procs = jax.process_count()
-        features = jax.tree.map(
-            lambda x: jnp.zeros(
-                (np.shape(x)[0] * procs,) + tuple(np.shape(x)[1:]),
-                np.asarray(x).dtype,
-            ),
-            features,
-        )
+    def _make_state(self, rng, features) -> PSTrainState:
+        """State constructor, pure in its arrays: it runs under jit, so
+        that the state is BORN in its layout (`out_shardings`) by one
+        program, and under `jax.eval_shape`, which gives the state's
+        tree without a FLOP.
+
+        What the host keeps of the model besides arrays is static (the
+        tables' paths, their packed specs, the perturbations' shapes)
+        and is noted on the trainer as a trace passes here: the
+        `eval_shape` of `_init_state` is the first, so a program loaded
+        from the store, or a restore, needs no other."""
         variables = dict(self._model.init(rng, features))
         params_boxed = variables.pop("params")
         variables.pop(IDS_COLLECTION, None)
         variables.pop(OOV_COLLECTION, None)
         perturbs = variables.pop(PERTURBATIONS, {})
         specs_tree = variables.pop(SPECS_COLLECTION, {})
-        model_state = variables
 
         # Split tables (VOCAB_AXIS-marked Partitioned leaves) from dense.
         tables: Dict[str, jnp.ndarray] = {}
-        self._table_paths = {}
-        self._table_specs: Dict[str, PackedSpec] = {}
+        table_paths = {}
 
         def split(path, leaf):
             if (
@@ -379,9 +375,7 @@ class ShardedEmbeddingTrainer:
             ):
                 key = _path_key(path)
                 tables[key] = leaf.unbox()
-                self._table_paths[key] = tuple(
-                    getattr(p, "key", p) for p in path
-                )
+                table_paths[key] = tuple(getattr(p, "key", p) for p in path)
                 return jnp.zeros((), jnp.float32)  # structure placeholder
             return leaf.unbox() if isinstance(leaf, nn.Partitioned) else leaf
 
@@ -392,41 +386,70 @@ class ShardedEmbeddingTrainer:
         params = jax.tree_util.tree_unflatten(
             flat[1], [split(p, v) for p, v in flat[0]]
         )
-        for key, module_path in self._table_paths.items():
-            spec_arr = np.asarray(
-                _collection_get(specs_tree, module_path[:-1], "spec")
+        table_specs: Dict[str, PackedSpec] = {}
+        for key, module_path in table_paths.items():
+            # Sown as a NumPy constant (layers/embedding.py): concrete
+            # here, whatever traces this function.
+            spec = _collection_get(specs_tree, module_path[:-1], "spec")
+            table_specs[key] = PackedSpec(*spec.tolist())  # noqa-invariant: jit-host-sync (a NumPy constant of the module, never a tracer: nothing to sync)
+            assert tables[key].shape == table_specs[key].packed_shape, (
+                key, tables[key].shape, table_specs[key],
             )
-            self._table_specs[key] = PackedSpec(int(spec_arr[0]), int(spec_arr[1]))
-            assert tables[key].shape == self._table_specs[key].packed_shape, (
-                key, tables[key].shape, self._table_specs[key],
-            )
-        slots = {
-            key: self._emb_tx.init_slots(self._table_specs[key], table)
-            for key, table in tables.items()
-        }
+        self._table_paths = table_paths
+        self._table_specs = table_specs
         self._perturb_shapes = jax.tree.map(
             lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
             unbox_partitioned(perturbs),
         )
-        state = PSTrainState(
+        return PSTrainState(
             step=jnp.zeros((), jnp.int32),
             params=params,
             opt_state=self._tx.init(params),
-            model_state=unbox_partitioned(model_state),
+            model_state=unbox_partitioned(variables),
             tables=tables,
-            slots=slots,
+            slots={
+                key: self._emb_tx.init_slots(table_specs[key], table)
+                for key, table in tables.items()
+            },
         )
+
+    def _init_state(self, features) -> None:
+        rng = jax.random.PRNGKey(self._seed)
+        # Init with the GLOBAL batch shape (local rows x process count):
+        # perturbation variables take their shape from init, and apply runs
+        # on the assembled global batch.  Zeros keep init identical on
+        # every rank (param init only consumes shapes + rng); host
+        # zeros, so that making them compiles nothing.
+        procs = jax.process_count()
+        features = jax.tree.map(
+            lambda x: np.zeros(
+                (np.shape(x)[0] * procs,) + tuple(np.shape(x)[1:]),
+                np.asarray(x).dtype,
+            ),
+            features,
+        )
+        # Structure first (no FLOPs, no memory), the shardings from it,
+        # then ONE program whose out_shardings birth the state placed:
+        # no table is computed on the way, fetched to the host or put
+        # back.  Whatever restores supplies every value, so it takes the
+        # shape tree as its template and the init is neither run nor
+        # compiled.
+        shapes = jax.eval_shape(self._make_state, rng, features)
+        plan = self._plan()
+        shardings = self._state_shardings(shapes, plan)
         if self._pending_sharded_restore is not None:
-            self._state = self._restore_sharded(state)
+            self._state = self._restore_sharded(shapes, shardings)
+        elif self._pending_restore is not None:
+            self._state = shd.put(self._pending_restore, shardings)
+            self._pending_restore = None
         else:
-            if self._pending_restore is not None:
-                state = self._pending_restore
-                self._pending_restore = None
-            self._state = self._place_state(jax.device_get(state))
+            self._state = plan.compile(
+                self._make_state, name="ps_init", out_shardings=shardings
+            )(rng, features)
         n_dense = sum(
-            int(np.prod(np.shape(p))) for p in jax.tree.leaves(params)
+            int(np.prod(p.shape)) for p in jax.tree.leaves(shapes.params)
         )
-        n_table = sum(int(np.prod(t.shape)) for t in tables.values())
+        n_table = sum(int(np.prod(t.shape)) for t in shapes.tables.values())
         total_rows = sum(
             spec.vocab_size for spec in self._table_specs.values()
         )
@@ -464,7 +487,7 @@ class ShardedEmbeddingTrainer:
             "device(s) [%s, sparse_kernel=%s]",
             n_dense,
             n_table,
-            len(tables),
+            len(shapes.tables),
             self._mesh.devices.size,
             self._emb_tx.name,
             self._sparse_kernel,
@@ -501,7 +524,7 @@ class ShardedEmbeddingTrainer:
                 else "xla"
             ),
             optimizer=self._emb_tx.name,
-            tables=len(tables),
+            tables=len(shapes.tables),
             table_rows=total_rows,
         )
 
@@ -923,21 +946,27 @@ class ShardedEmbeddingTrainer:
         self._pending_sharded_restore = (saver, step)
         self._host_step = step
 
-    def _restore_sharded(self, template: PSTrainState) -> PSTrainState:
+    def _restore_sharded(
+        self, template: PSTrainState, shardings: PSTrainState
+    ) -> PSTrainState:
         """Materialize the checkpoint under the CURRENT world's shardings:
         dense state replicates from rank 0's pickle; each table/slot row
         interval is read by whichever process now owns it — world-size
-        agnostic, which is what restart-the-world shrink/grow needs."""
+        agnostic, which is what restart-the-world shrink/grow needs.
+        `template` is the state's shape tree (`jax.ShapeDtypeStruct`
+        leaves: nothing was initialised for the checkpoint to overwrite)
+        and `shardings` the tree of its leaves' shardings."""
         saver, step = self._pending_sharded_restore
         with tracing.span("checkpoint.restore.load", step=step) as span:
-            restored = self._restore_sharded_inner(template)
+            restored = self._restore_sharded_inner(template, shardings)
             span.fields["bytes"] = tree_nbytes(restored)
         return restored
 
-    def _restore_sharded_inner(self, template: PSTrainState) -> PSTrainState:
+    def _restore_sharded_inner(
+        self, template: PSTrainState, shardings: PSTrainState
+    ) -> PSTrainState:
         saver, step = self._pending_sharded_restore
         self._pending_sharded_restore = None
-        shardings = self._state_shardings(template)
         dense = saver.load_dense(step)
         if hasattr(saver, "manifest"):
             # Fail with the CAUSE when the checkpoint's table set differs
@@ -986,7 +1015,7 @@ class ShardedEmbeddingTrainer:
                     "with the matching configuration"
                 )
             return self._place_leaf(
-                np.asarray(scalar_slots[k][n], dtype=np.asarray(tmpl).dtype),
+                np.asarray(scalar_slots[k][n], dtype=tmpl.dtype),
                 shardings.slots[k][n],
             )
 
@@ -994,7 +1023,7 @@ class ShardedEmbeddingTrainer:
             k: {
                 n: (
                     load_scalar_slot(k, n, group[n])
-                    if not np.ndim(group[n])
+                    if not group[n].ndim
                     else saver.load_array(
                         step, f"slot|{k}|{n}", shardings.slots[k][n]
                     )

@@ -516,19 +516,40 @@ from elasticdl_tpu.common import args as args_lib, compile_cache
 args = args_lib.build_worker_parser().parse_args(sys.argv[2:])
 compile_cache.configure(args=args)
 import jax, numpy as np
-from model_zoo.transformer import transformer_lm as zoo
 from elasticdl_tpu import obs
 from elasticdl_tpu.parallel import MeshConfig, build_mesh
-from elasticdl_tpu.parallel.dp_trainer import DataParallelTrainer
 
-trainer = DataParallelTrainer(
-    model=zoo.custom_model(vocab=64, d_model=32, num_heads=2, num_layers=1,
-                           max_len=16),
-    loss_fn=zoo.loss, optimizer=zoo.optimizer(),
-    mesh=build_mesh(MeshConfig(), devices=jax.devices()[:1]),
-)
-tokens = np.random.RandomState(0).randint(0, 64, size=(8, 16)).astype(np.int32)
-batch = (tokens, tokens, np.ones((8,), np.float32))
+mesh = build_mesh(MeshConfig(), devices=jax.devices()[:1])
+rng = np.random.RandomState(0)
+if args.model_def == "transformer.transformer_lm":
+    from model_zoo.transformer import transformer_lm as zoo
+    from elasticdl_tpu.parallel.dp_trainer import DataParallelTrainer
+
+    trainer = DataParallelTrainer(
+        model=zoo.custom_model(vocab=64, d_model=32, num_heads=2,
+                               num_layers=1, max_len=16),
+        loss_fn=zoo.loss, optimizer=zoo.optimizer(), mesh=mesh,
+    )
+    tokens = rng.randint(0, 64, size=(8, 16)).astype(np.int32)
+    features, labels, window_name = tokens, tokens, "dp_train_window"
+else:
+    from model_zoo.deepfm import deepfm_functional_api as zoo
+    from elasticdl_tpu.parallel.ps_trainer import ShardedEmbeddingTrainer
+
+    trainer = ShardedEmbeddingTrainer(
+        model=zoo.custom_model(vocab_size=32, embedding_dim=4, hidden=16),
+        loss_fn=zoo.loss, optimizer=zoo.optimizer(lr=0.01), mesh=mesh,
+        embedding_optimizer=zoo.embedding_optimizer(lr=0.01),
+        sparse_apply_every=2,
+    )
+    features = {
+        "dense": rng.rand(8, 13).astype(np.float32),
+        "cat": rng.randint(0, 32, size=(8, 26)).astype(np.int32),
+    }
+    labels = rng.randint(0, 2, size=(8,)).astype(np.int32)
+    trainer.ensure_initialized(features)
+    window_name = "ps_train_window"
+batch = (features, labels, np.ones((8,), np.float32))
 losses = []
 for _ in range(3):
     window = trainer.stage_window([batch, batch])
@@ -538,19 +559,20 @@ print(json.dumps({
     "builds": [
         {k: e.get(k) for k in ("entrypoint", "aot_hit", "aot_skip", "trace_s")}
         for e in obs.journal().tail(400) if e.get("name") == "compile.build"],
-    "cache_size": trainer.jitted_entrypoints()["dp_train_window"]._cache_size(),
+    "cache_size": trainer.jitted_entrypoints()[window_name]._cache_size(),
 }))
 """
 
 
-def _run_probe(probe, cache_dir, *argv, root=REPO_ROOT, cache="true"):
+def _run_probe(probe, cache_dir, *argv, root=REPO_ROOT, cache="true",
+               model_def="transformer.transformer_lm"):
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                JAX_ENABLE_COMPILATION_CACHE=cache,
                JAX_COMPILATION_CACHE_DIR=str(cache_dir))
     proc = subprocess.run(
         [sys.executable, "-c", probe, root,
          f"--model_zoo={os.path.join(root, 'model_zoo')}",
-         "--model_def=transformer.transformer_lm", "--master_addr=x:1",
+         f"--model_def={model_def}", "--master_addr=x:1",
          *(argv or ("--worker_id=0",))],
         cwd=root, env=env, capture_output=True, text=True, timeout=600,
     )
@@ -581,17 +603,25 @@ def test_a_fresh_process_loads_the_build_and_traces_nothing(tmp_path):
     assert other["build"]["aot_key"] != cold["build"]["aot_key"]
 
 
-def test_a_zoo_models_window_gives_the_same_loss_from_a_stored_build(tmp_path):
-    """`dp_init` and `dp_train_window` of the smallest language model the
-    tests build: traced with no store, traced and stored, then loaded in
-    a fresh process; three windows each, losses equal to the last bit,
+@pytest.mark.parametrize("model_def,names,engine_line", [
+    ("transformer.transformer_lm", ["dp_init", "dp_train_window"],
+     "attention engine: xla blockwise_attention T=16 D=16"),
+    ("deepfm.deepfm_functional_api", ["ps_init", "ps_train_window"], None),
+])
+def test_a_zoo_models_window_gives_the_same_loss_from_a_stored_build(
+    tmp_path, model_def, names, engine_line
+):
+    """The init and the window program of the smallest language model the
+    tests build on the dp trainer, and of the smallest CTR model on the PS
+    trainer: traced with no store, traced and stored, then loaded in a
+    fresh process; three windows each, losses equal to the last bit,
     every later window on the loaded executable, and the log of a hit
     names the kernels' engines."""
     cache = tmp_path / "cache"
-    plain, _ = _run_probe(_ZOO_PROBE, cache, cache="false")
-    cold, cold_log = _run_probe(_ZOO_PROBE, cache)
-    warm, warm_log = _run_probe(_ZOO_PROBE, cache)
-    names = ["dp_init", "dp_train_window"]
+    plain, _ = _run_probe(
+        _ZOO_PROBE, cache, cache="false", model_def=model_def)
+    cold, cold_log = _run_probe(_ZOO_PROBE, cache, model_def=model_def)
+    warm, warm_log = _run_probe(_ZOO_PROBE, cache, model_def=model_def)
     assert [b["entrypoint"] for b in warm["builds"]] == names
     assert [b["aot_hit"] for b in plain["builds"]] == [False, False]
     assert {b["aot_skip"] for b in plain["builds"]} == {
@@ -602,10 +632,11 @@ def test_a_zoo_models_window_gives_the_same_loss_from_a_stored_build(tmp_path):
     assert plain["losses"] == cold["losses"] == warm["losses"]
     assert len(warm["losses"]) == 6 and warm["losses"][-1] < warm["losses"][0]
     assert plain["cache_size"] == cold["cache_size"] == warm["cache_size"] == 1
-    line = "attention engine: xla blockwise_attention T=16 D=16"
-    assert line in cold_log and "(stored build)" not in cold_log
-    assert warm_log.count(f"{line} (stored build)") == 2
-    assert "dp_train_window: loaded the stored build" in warm_log
+    if engine_line:
+        assert engine_line in cold_log and "(stored build)" not in cold_log
+        assert warm_log.count(f"{engine_line} (stored build)") == 2
+    assert f"{names[1]}: loaded the stored build" in warm_log
+    assert f"{names[0]}: loaded the stored build" in warm_log
 
 
 def test_a_checkout_under_another_path_hits(tmp_path):

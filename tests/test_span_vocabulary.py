@@ -657,6 +657,42 @@ def test_report_prints_a_boot_row_for_each_process(tiny_job, capsys):
     assert "aot_load " in text and "aot_hit False, aot_skip " in text
 
 
+def test_report_shows_the_two_builds_of_a_ps_job(tmp_path, capsys):
+    """A ParameterServerStrategy worker builds two programs before its
+    first window: `ps_init` inside `state.init` (the state born in its
+    layout, as `dp_init`'s is), then the window program."""
+    from elasticdl_tpu.obs import report
+
+    proc = subprocess.run(
+        [sys.executable, "-m", "elasticdl_tpu.client.main", "train",
+         "--distribution_strategy=ParameterServerStrategy", "--num_workers=1",
+         "--model_zoo=model_zoo", "--model_def=deepfm.deepfm_functional_api",
+         "--training_data=synthetic://criteo?n=256&vocab=64",
+         "--model_params=vocab_size=64", "--records_per_task=64",
+         "--minibatch_size=16", "--job_name=ps_boot",
+         f"--tensorboard_log_dir={tmp_path / 'tb'}"],
+        cwd=REPO_ROOT, capture_output=True, text=True, timeout=600,
+        env=dict(os.environ, JAX_PLATFORMS="cpu",
+                 XLA_FLAGS="--xla_force_host_platform_device_count=1"),
+    )
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    worker = _journal_spans(tmp_path / "tb" / "events_worker_0.jsonl")
+    (state_init,) = [e for e in worker if e["name"] == "state.init"]
+    builds = [e for e in worker if e["name"] == "compile.build"]
+    assert [b["entrypoint"] for b in builds] == ["ps_init", "ps_train_window"]
+    assert builds[0]["parent_span_id"] == state_init["span_id"]
+    assert builds[1]["parent_span_id"] != state_init["span_id"]
+    journal = str(tmp_path / "tb" / "events.jsonl")
+    assert report.main([journal, "--json", "-"]) == 0
+    out = capsys.readouterr().out
+    summary = json.loads(out[out.index('{\n  "wall_s"'):])
+    rows = {row["proc"]: row for row in summary["boot"]}
+    assert [b["entrypoint"] for b in rows["worker_0"]["builds"]] == [
+        "ps_init", "ps_train_window",
+    ]
+    assert "compile.build ps_init: trace " in out
+
+
 _BUILD_PROBE = """
 import json
 from elasticdl_tpu.common import compile_cache

@@ -624,6 +624,68 @@ def test_laguna_window_program_compiles_and_fits_for_v5e(
     assert "tpu_custom_call" not in compiled.as_text()      # the XLA engine
 
 
+# The PS trainer's init at the widths of `deepfm-dac.train-file`: 26
+# fields x 1,000,000 rows of 1 + 10 floats padded to 16 lanes, minibatch
+# 8192, sparse Adam: ONE program whose outputs are the 6.66 GB of state
+# (the table, Adam's m, v and per-row step) born in their layout.  What
+# it needs beside them is held under the 8.0 GB named here, so that the
+# 2 x 6.7 GB the eager init and its host round trip once held
+# (`memory_peak_bytes` 13.35 GB, PERF.md §6 PR 37) cannot come back
+# unseen.
+def test_ps_init_compiles_and_fits_for_v5e(topo):
+    from elasticdl_tpu.parallel import MeshConfig, build_mesh
+    from elasticdl_tpu.parallel.ps_trainer import ShardedEmbeddingTrainer
+    from model_zoo.deepfm import deepfm_functional_api as zoo
+
+    with open(os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "perfbench", "configs", "deepfm-criteo-dac.json",
+    )) as f:
+        config = json.load(f)
+    sizes, flags = config["model"], config["job"]
+    assert "--minibatch_size=8192" in flags
+    assert "--sparse_apply_every=auto" in flags
+    mesh = build_mesh(MeshConfig(data=1, model=1), devices=topo.devices[:1])
+    trainer = ShardedEmbeddingTrainer(
+        zoo.custom_model(
+            vocab_size=sizes["vocab_size"],
+            embedding_dim=sizes["embedding_dim"],
+            hidden=sizes["hidden"][0], sparse_apply_every="auto",
+        ),
+        zoo.loss, zoo.optimizer(), mesh,
+        embedding_optimizer=zoo.embedding_optimizer(),
+        sparse_apply_every="auto", sparse_kernel="xla",
+    )
+    rng = jax.random.PRNGKey(0)
+    features = {
+        "dense": jnp.zeros((8192, sizes["num_dense"]), jnp.float32),
+        "cat": jnp.zeros((8192, sizes["num_categorical"]), jnp.int32),
+    }
+    shapes = jax.eval_shape(trainer._make_state, rng, features)
+    (spec,) = trainer._table_specs.values()  # the trace left it
+    assert (spec.vocab_size, spec.dim, spec.dim_padded) == (
+        26_000_000, 11, 16)
+    on_chip = NamedSharding(mesh, P())
+    compiled = jax.jit(
+        trainer._make_state,
+        out_shardings=trainer._state_shardings(shapes),
+    ).lower(
+        *jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=on_chip),
+            (rng, features),
+        )
+    ).compile()
+    memory = compiled.memory_analysis()
+    print("ps_init bytes", memory.argument_size_in_bytes,
+          memory.output_size_in_bytes, memory.temp_size_in_bytes)
+    assert 6.65e9 < memory.output_size_in_bytes < 6.67e9  # 26e6 x 16 x 4 x 4
+    total = (
+        memory.argument_size_in_bytes + memory.output_size_in_bytes
+        + memory.temp_size_in_bytes
+    )
+    assert total < 8.0e9, total  # the chip holds 16
+
+
 def _four_chip_mesh(topo):
     return jax.sharding.Mesh(
         np.asarray(topo.devices).reshape(2, 2), (DATA_AXIS, MODEL_AXIS)
