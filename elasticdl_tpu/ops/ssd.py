@@ -21,8 +21,9 @@ for ``j <= i`` (0 above the diagonal)::
     S_out  = exp(cum_Q) S_in + ((dt x) o exp(cum_Q - cum))^T B
 
 The products inside a chunk are batched over ALL chunks of the sequence
-at once (at T = 8192 the decays ``L`` of 64 heads are 268 MB in float32,
-the chunk states 134 MB), and only ``S_in -> S_out`` walks the chunks in
+at once (at T = 8192 the decays ``L`` of 64 heads are 268 MB in float32
+in chunks of 128 and 537 MB in chunks of 256, the chunk states 134 and
+67 MB), and only ``S_in -> S_out`` walks the chunks in
 order, a `lax.scan` of T / Q elementwise steps on [B, H, P, N].  The
 backward pass is the one JAX derives (the scan's reverse keeps the state
 at each chunk's start, which the forward has anyway); the model
@@ -54,7 +55,7 @@ from elasticdl_tpu.common.log_utils import get_logger
 
 logger = get_logger("ops.ssd")
 
-CHUNK = 128  # the source's `chunk_size`
+CHUNK = 128  # Nemotron-H's `chunk_size`; Granite 4.0-H hands `chunk=256`
 
 
 def ssd_recurrent(x, dt, a, b, c):

@@ -67,6 +67,10 @@ this `gate` is taken from.
 Device scopes (obs/tracing.py DEVICE_SCOPES): `ssm` (the Mamba-2
 sublayer with its norm and residual) > `ssm_scan`; `attn`; `moe` >
 `moe_route`, `moe_experts`, `moe_shared`; `lm_head_loss`.
+
+`model_zoo/granite_hybrid` imports `Mamba2Mixer`, `Attention` (with its
+`scale`), `RMSNorm` and `_dense` from here: the same mixers inside another
+block.
 """
 
 from __future__ import annotations
@@ -212,6 +216,7 @@ class Attention(nn.Module):
     num_kv_heads: int
     head_dim: int
     dtype: Any
+    scale: Any = None  # of the scores; None: 1/sqrt(head_dim)
 
     @nn.compact
     def __call__(self, x):
@@ -222,7 +227,7 @@ class Attention(nn.Module):
             .reshape(b, t, heads, hd).astype(self.dtype)
             for name, heads in (("q_proj", h), ("k_proj", hkv), ("v_proj", hkv))
         )
-        out = gqa.causal_attention(q, k, v)
+        out = gqa.causal_attention(q, k, v, scale=self.scale)
         return _dense(d, self.dtype, "o_proj")(out.reshape(b, t, h * hd))
 
 

@@ -287,10 +287,10 @@ def test_full_size_configuration_counts_the_parameters_it_states():
 # ---------------------------------------------------------------------------
 
 
-def _ssd_inputs(t, seed, b=2, h=4, p=8, g=2, n=16):
+def _ssd_inputs(t, seed, b=2, h=4, p=8, g=2, n=16, dt_max=0.5):
     rng = np.random.default_rng(seed)
     x = rng.normal(size=(b, t, h, p))
-    dt = np.exp(rng.uniform(np.log(1e-3), np.log(0.5), size=(b, t, h)))
+    dt = np.exp(rng.uniform(np.log(1e-3), np.log(dt_max), size=(b, t, h)))
     a = -rng.uniform(1.0, 16.0, size=(h,))
     bm = rng.normal(size=(b, t, g, n))
     cm = rng.normal(size=(b, t, g, n))
@@ -298,12 +298,20 @@ def _ssd_inputs(t, seed, b=2, h=4, p=8, g=2, n=16):
 
 
 # One chunk; several chunks; two T that are no multiple of 128 (one of
-# them shorter than a chunk); many chunks, the last one padded.
-@pytest.mark.parametrize("t", [128, 512, 200, 50, 1100])
-def test_chunked_ssd_matches_the_recurrence(t):
-    inputs = _ssd_inputs(t, seed=t)
+# them shorter than a chunk); many chunks, the last one padded; and the
+# family's other published shape (Granite 4.0-H): ONE group that every
+# head reads, in chunks of 256, two whole and a padded one.  There dt goes
+# up to 0.1, the largest step a model starts from, where the others go to
+# 0.5: a chunk's running sum of dt A is twice as long at 256, and float32
+# resolves a decay no finer than that sum (see the strong-decay test).
+@pytest.mark.parametrize("t,g,chunk,dt_max", [
+    (128, 2, 128, 0.5), (512, 2, 128, 0.5), (200, 2, 128, 0.5),
+    (50, 2, 128, 0.5), (1100, 2, 128, 0.5), (600, 1, 256, 0.1),
+])
+def test_chunked_ssd_matches_the_recurrence(t, g, chunk, dt_max):
+    inputs = _ssd_inputs(t, seed=t, g=g, dt_max=dt_max)
     want, want_state = ssd_recurrent(*inputs)
-    got, got_state = ssd_chunked(*inputs)
+    got, got_state = ssd_chunked(*inputs, chunk=chunk)
     assert got.shape == want.shape and got.dtype == jnp.float32
     scale = float(jnp.abs(want).max())
     assert float(jnp.abs(got - want).max()) < 1e-5 * max(scale, 1.0)
@@ -311,11 +319,13 @@ def test_chunked_ssd_matches_the_recurrence(t):
         float(jnp.abs(want_state).max()), 1.0)
 
 
-@pytest.mark.parametrize("t,chunk", [(128, 128), (384, 128), (200, 128),
-                                     (150, 32)])
-def test_chunked_ssd_gradients_match_the_recurrence(t, chunk):
+@pytest.mark.parametrize("t,chunk,g,dt_max", [
+    (128, 128, 2, 0.5), (384, 128, 2, 0.5), (200, 128, 2, 0.5),
+    (150, 32, 2, 0.5), (600, 256, 1, 0.1),
+])
+def test_chunked_ssd_gradients_match_the_recurrence(t, chunk, g, dt_max):
     """All five gradients, through the outputs and the final state."""
-    inputs = _ssd_inputs(t, seed=100 + t)
+    inputs = _ssd_inputs(t, seed=100 + t, g=g, dt_max=dt_max)
     rng = np.random.default_rng(t)
     weight = jnp.asarray(rng.normal(size=inputs[0].shape), jnp.float32)
     state_weight = jnp.asarray(rng.normal(size=(2, 4, 8, 16)), jnp.float32)

@@ -1006,6 +1006,32 @@ def _banded_window(seed=0):
     return trainer, trainer.stage_window([batch, batch])
 
 
+def _two_sublayer_window(seed=0):
+    """(trainer, staged window) of a tiny Granite 4.0-H on the dp trainer
+    (a Mamba-2 and an attention layer, each followed by its MLP), each
+    layer rematerialised as the benchmark's configuration runs it."""
+    sys.path.insert(0, REPO_ROOT)
+    from model_zoo.granite_hybrid import granite_hybrid_lm as zoo
+
+    from elasticdl_tpu.parallel import MeshConfig, build_mesh
+    from elasticdl_tpu.parallel.dp_trainer import DataParallelTrainer
+
+    model = zoo.custom_model(
+        vocab_size=64, hidden_size=32, mamba_d_head=8, mamba_d_state=8,
+        mamba_chunk_size=8, head_dim=8, shared_intermediate_size=48,
+        remat=True,
+    )
+    trainer = DataParallelTrainer(
+        model=model, loss_fn=zoo.loss, optimizer=zoo.optimizer(),
+        mesh=build_mesh(MeshConfig()),
+    )
+    rng = np.random.RandomState(seed)
+    tokens = rng.randint(0, 64, size=(8, 16)).astype(np.int32)
+    trainer.ensure_initialized(tokens)
+    batch = (tokens, tokens, np.ones((8,), np.float32))
+    return trainer, trainer.stage_window([batch, batch])
+
+
 @pytest.mark.parametrize("build,jit_attr,scopes", [
     (_dense_window, "_train_window_jit",
      ("fwd_bwd", "attn", "mlp", "lm_head_loss", "optimizer")),
@@ -1021,6 +1047,9 @@ def _banded_window(seed=0):
     (_banded_window, "_train_window_jit",
      ("fwd_bwd", "attn", "attn_full", "attn_window", "attn_gate", "mlp",
       "moe", "moe_route", "moe_experts", "moe_shared", "lm_head_loss",
+      "optimizer")),
+    (_two_sublayer_window, "_train_window_jit",
+     ("fwd_bwd", "ssm", "ssm_scan", "attn", "mlp", "lm_head_loss",
       "optimizer")),
     (_sparse_window, "_train_window",
      ("fwd_bwd", "dense_update", "sparse_apply", "grad_accumulate",

@@ -624,6 +624,63 @@ def test_laguna_window_program_compiles_and_fits_for_v5e(
     assert "tpu_custom_call" not in compiled.as_text()      # the XLA engine
 
 
+# The two-sublayer block under multipliers (model_zoo/granite_hybrid) at
+# the widths of `granite4-h-micro.train-synth`: the whole two-step window
+# program as the worker runs it, 9.27 GB of state donated (12 B x
+# 772,160,448: the LARGEST state of any cell; the tied table is in it
+# once), each of the ten layers rematerialised (nine Mamba-2 layers at ONE
+# group in chunks of 256, whose decays are 537 MB a layer, and one
+# attention layer in the Pallas kernel: K + V of a head of 64 are 4 MiB,
+# under `supports`' cap).  12.58 GB at 1 x 8192 tokens; the chip holds 16
+# and ISSUE 38 sets 15.5 as the most this cell may need before it would
+# have to run 4096 tokens.
+def test_granite_window_program_compiles_and_fits_for_v5e(topo, monkeypatch):
+    from elasticdl_tpu.parallel import MeshConfig, build_mesh
+    from elasticdl_tpu.parallel.dp_trainer import DataParallelTrainer
+    from model_zoo.granite_hybrid import granite_hybrid_lm as zoo
+
+    with open(os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "perfbench", "configs", "granite-4.0-h-micro.json",
+    )) as f:
+        config = json.load(f)
+    model = {
+        k: v for k, v in config["model"].items() if k != "sample_tokens"
+    }
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = build_mesh(MeshConfig(data=1, model=1), devices=topo.devices[:1])
+    trainer = DataParallelTrainer(
+        zoo.custom_model(use_bf16=True, remat=True, **model), zoo.loss,
+        zoo.optimizer(), mesh,
+    )
+    on_chip = NamedSharding(mesh, P())
+    state, _ = jax.eval_shape(
+        lambda: trainer._make_state(
+            jax.random.PRNGKey(0), jnp.zeros((1, 8192), jnp.int32)
+        )
+    )
+    state = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=on_chip),
+        state,
+    )
+    window = jax.ShapeDtypeStruct((2, 1, 8192), jnp.int32, sharding=on_chip)
+    mask = jax.ShapeDtypeStruct((2, 1), jnp.float32, sharding=on_chip)
+    compiled = jax.jit(
+        trainer._train_window_impl, donate_argnums=(0,)
+    ).lower(state, window, window, mask).compile()
+    memory = compiled.memory_analysis()
+    print("granite window bytes", memory.argument_size_in_bytes,
+          memory.temp_size_in_bytes, memory.alias_size_in_bytes)
+    assert 9.26e9 < memory.argument_size_in_bytes < 9.27e9  # 12 B x 772M
+    assert memory.alias_size_in_bytes > 9.26e9              # donated
+    total = memory.argument_size_in_bytes + memory.temp_size_in_bytes
+    assert 12.0e9 < total < 13.2e9, total  # 12.58; never over 15.5
+    assert "tpu_custom_call" in compiled.as_text()          # the flash kernel
+    # the sizes the configuration's file states are these
+    for text in (config["device_bytes"], config["assumed"]["remat"]):
+        assert "12.58 GB" in text and "3.31 GB" in text
+
+
 # The PS trainer's init at the widths of `deepfm-dac.train-file`: 26
 # fields x 1,000,000 rows of 1 + 10 floats padded to 16 lanes, minibatch
 # 8192, sparse Adam: ONE program whose outputs are the 6.66 GB of state
