@@ -29,6 +29,16 @@ skeleton as ever.  A `state.pkl` that starts with anything but the magic
 is what this module wrote before: one plain `pickle.dump` of the tree,
 which `load_latest` still reads.
 
+A save is a stream of leaves: `save` takes the state as it lies on the
+device, starts the device-to-host transfers of the leaves up to
+`_LOOKAHEAD_BYTES` ahead of the one the file is taking (`LeafStream`),
+and lets go of each leaf's host copy once it is written, so the write
+of leaf i runs beside the transfers of leaves i+1 ... i+k and the host
+never holds the whole state.  It is still synchronous: `save` returns
+after the rename.  A host tree (a trainer's collective
+`state_to_host()`, export, debug) goes through the same writer with
+nothing to wait for.
+
 A save writes every byte once and reads none back: the size and CRC32
 the manifest records are taken from the bytes on their way to the file
 (`ChecksumWriter`).  Restore verifies every inventoried file against its
@@ -41,6 +51,7 @@ silently loading garbage.  `keep_max` old checkpoints are retained.
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import io
 import json
@@ -53,7 +64,7 @@ import tempfile
 import time
 import zlib
 from concurrent.futures import ThreadPoolExecutor
-from typing import Any, Dict, Iterable, List, Optional, Tuple
+from typing import Any, Dict, Iterable, Iterator, List, Optional, Tuple
 
 import numpy as np
 
@@ -283,6 +294,142 @@ class ChecksumWriter:
         self._file.close()
 
 
+#: How far ahead of the leaf being written a save starts the transfers
+#: of the leaves behind it, in bytes, and so, with that leaf, all that
+#: the host holds of a device state at once.  The link brings a leaf
+#: over two to three times as fast as the file takes it (`PERF.md` §6
+#: PR 39), so any bound of a few leaves keeps the writer fed; one leaf
+#: is always ahead, whatever its size.
+_LOOKAHEAD_BYTES = 512 << 20
+
+
+def _on_device(leaf) -> bool:
+    """Whether `leaf` is an array a save has to bring to the host (a
+    typed PRNG key is not: it pickles itself)."""
+    import jax
+
+    return isinstance(leaf, jax.Array) and not jax.dtypes.issubdtype(
+        leaf.dtype, jax.dtypes.extended
+    )
+
+
+def streams(tree) -> bool:
+    """Whether a save can take `tree` as it lies on the device: every
+    array of it is whole on this process's devices, so bringing it to
+    the host is no collective."""
+    import jax
+
+    leaves = [x for x in jax.tree.leaves(tree) if _on_device(x)]
+    return bool(leaves) and all(
+        x.is_fully_addressable and x.is_fully_replicated for x in leaves
+    )
+
+
+class LeafStream:
+    """Device arrays to the host in the order a file holds them: each
+    leaf's transfer is started up to `_LOOKAHEAD_BYTES` before the
+    writer asks for it, so the file takes leaf i while leaves i+1 ...
+    i+k cross the link, and a leaf's host copy goes once the writer
+    asks for the next.  Counts what the save's spans report."""
+
+    def __init__(self):
+        self._started_ts, self._started = time.time(), time.monotonic()
+        self.wait_s = 0.0  # the caller, blocked on the device
+        self.leaves = 0  # device leaves handed over
+        self.bytes = 0
+        self.streamed_bytes = 0  # ... whose transfer was started ahead
+        self.lookahead_peak_bytes = 0  # most held on the host at once
+
+    @staticmethod
+    def _start(leaf):
+        """Start `leaf`'s transfer.  -> a handle of this stream's own on
+        the same device buffer: the host copy a transfer leaves cached
+        on a `jax.Array` goes with the handle, not with the trainer's
+        array."""
+        import jax
+
+        if leaf.is_fully_replicated:  # (else: assembled on the leaf)
+            piece = leaf.addressable_shards[0].data
+            leaf = jax.make_array_from_single_device_arrays(
+                piece.shape, piece.sharding, [piece]
+            )
+        leaf.copy_to_host_async()
+        return leaf
+
+    @staticmethod
+    def _fetch(handle) -> np.ndarray:
+        return np.asarray(handle)
+
+    def host_arrays(self, leaves: Iterable) -> Iterator:
+        """Yield each of `leaves` as the host has it (a device array as
+        the `np.ndarray` its transfer made, anything else as it is).
+        A caller that keeps no reference to an array past its turn
+        holds what `lookahead_peak_bytes` says and no more."""
+        leaves = list(leaves)
+        on_device = [_on_device(leaf) for leaf in leaves]
+        ahead: collections.deque = collections.deque()  # handles, in order
+        ahead_bytes = held = 0
+        upcoming = 0
+        try:
+            for at, leaf in enumerate(leaves):
+                if not on_device[at]:
+                    yield leaf
+                    continue
+                if ahead:
+                    handle = ahead.popleft()
+                    ahead_bytes -= leaf.nbytes
+                    self.streamed_bytes += leaf.nbytes
+                else:
+                    handle = self._start(leaf)
+                    held += leaf.nbytes
+                upcoming = max(upcoming, at + 1)
+                while upcoming < len(leaves):
+                    following = leaves[upcoming]
+                    if on_device[upcoming]:
+                        if ahead and (
+                            ahead_bytes + following.nbytes > _LOOKAHEAD_BYTES
+                        ):
+                            break
+                        ahead.append(self._start(following))
+                        ahead_bytes += following.nbytes
+                        held += following.nbytes
+                    upcoming += 1
+                self.lookahead_peak_bytes = max(
+                    self.lookahead_peak_bytes, held
+                )
+                waited = time.monotonic()
+                host = self._fetch(handle)
+                self.wait_s += time.monotonic() - waited
+                self.leaves += 1
+                self.bytes += leaf.nbytes
+                yield host
+                host = handle = None
+                held -= leaf.nbytes
+        finally:
+            ahead.clear()
+
+    def journal(self, **write_fields):
+        """Journal the save this stream was made for, from its making
+        until now, as the two spans its reader sums:
+        `checkpoint.save.gather`, the time the save waited for the
+        device (the program in flight, then any leaf not yet on the
+        host when the writer asked for it; none where no leaf came
+        from a device), and `checkpoint.save.write`, the rest: the
+        time inside the writer."""
+        elapsed_s = time.monotonic() - self._started
+        if self.leaves:
+            tracing.record_child_span(
+                "checkpoint.save.gather", self._started_ts, self.wait_s,
+                bytes=self.bytes,
+            )
+        tracing.record_child_span(
+            "checkpoint.save.write", self._started_ts + self.wait_s,
+            elapsed_s - self.wait_s, streamed_bytes=self.streamed_bytes,
+            lookahead_peak_bytes=self.lookahead_peak_bytes,
+            leaves=self.leaves, **write_fields,
+        )
+
+
 def write_integrity_manifest(
     step_dir: str,
     filenames: Iterable[str],
@@ -377,35 +524,85 @@ def _dense_view(array: np.ndarray):
     return (view, axes) if view.flags.c_contiguous else None
 
 
-class _RawLeafPickler(pickle.Pickler):
-    """Pickles a host tree's skeleton and sets its plain array leaves
-    aside as raw byte views (`buffers`), each named in the skeleton by a
-    persistent id (index, dtype, shape as stored, axes as stored).  That
-    takes in float32 as much as bfloat16, 0-d, read-only (what
-    `jax.device_get` returns) and transposed arrays, none of them
-    copied; an array that is not dense is copied once into one that is
-    (`copied_bytes`)."""
+def _device_axes(array) -> Optional[tuple]:
+    """The order in which the device keeps `array`'s axes, major first,
+    which is the order its transfer brings them to the host in: None
+    for their own order, and where the runtime does not say."""
+    try:
+        axes = tuple(int(a) for a in array.format.layout.major_to_minor)
+    except Exception:  # no layout to ask for: a leaf that is off then
+        return None  # costs one copy (`_DeviceLeaf.bytes_of`)
+    return None if axes == tuple(range(array.ndim)) else axes
 
-    def __init__(self, file):
+
+class _DeviceLeaf:
+    """A leaf still on the device, in a pickler's `buffers`: its length
+    is known from its shape and the axes it is stored by are settled
+    before its transfer, because the skeleton that names them goes to
+    the file before any buffer."""
+
+    def __init__(self, array):
+        self.array = array  # (and keeps its id alive)
+        self.axes = _device_axes(array)
+        self.nbytes = array.nbytes
+
+    def bytes_of(self, host: np.ndarray) -> Tuple[np.ndarray, int]:
+        """-> (`host`'s elements in the stored order as one run of
+        bytes, the bytes copied for it: none unless the transfer
+        brought another order than the device's layout said)."""
+        stored = host if self.axes is None else host.transpose(self.axes)
+        copied = 0
+        if not stored.flags.c_contiguous:
+            stored, copied = np.ascontiguousarray(stored), stored.nbytes
+        return stored.reshape(-1).view(np.uint8), copied
+
+
+class _RawLeafPickler(pickle.Pickler):
+    """Pickles a tree's skeleton and sets its plain array leaves aside
+    (`buffers`), each named in the skeleton by a persistent id (index,
+    dtype, shape as stored, axes as stored).  A host array's buffer is
+    a raw byte view of it: float32 as much as bfloat16, 0-d, read-only
+    (what `jax.device_get` returns) and transposed arrays, none of
+    them copied; an array that is not dense is copied once into one
+    that is (`copied_bytes`).  A device array's is a `_DeviceLeaf`,
+    whose bytes the writer asks a `LeafStream` for."""
+
+    def __init__(self, file, device_leaves: Iterable = ()):
         super().__init__(file, protocol=5)
-        self.buffers: List[np.ndarray] = []
+        self.buffers: List[Any] = []
         self.copied_bytes = 0
         self._pid_of: Dict[int, tuple] = {}
+        # The tree's own leaves, by id: an array INSIDE another leaf's
+        # pickle (a typed key's data) is that leaf's to restore.
+        self._on_device = {id(leaf) for leaf in device_leaves}
 
     def persistent_id(self, obj):
-        if type(obj) is not np.ndarray or obj.dtype.hasobject:
+        on_host = type(obj) is np.ndarray
+        if obj.dtype.hasobject if on_host else (
+            id(obj) not in self._on_device
+        ):
             return None
         pid = self._pid_of.get(id(obj))
-        if pid is None:
+        if pid is not None:
+            return pid
+        if on_host:
             dense = _dense_view(obj)
             if dense is None:
                 dense = np.ascontiguousarray(obj), None
                 self.copied_bytes += obj.nbytes
             view, axes = dense
-            pid = (len(self.buffers), obj.dtype, view.shape, axes)
+            shape = view.shape
             # The buffer's base keeps `obj` (and so its id) alive.
-            self.buffers.append(view.reshape(-1).view(np.uint8))
-            self._pid_of[id(obj)] = pid
+            buffer = view.reshape(-1).view(np.uint8)
+        else:
+            buffer = _DeviceLeaf(obj)
+            axes = buffer.axes
+            shape = obj.shape if axes is None else tuple(
+                obj.shape[axis] for axis in axes
+            )
+        pid = (len(self.buffers), obj.dtype, shape, axes)
+        self.buffers.append(buffer)
+        self._pid_of[id(obj)] = pid
         return pid
 
 
@@ -438,11 +635,18 @@ class _RawLeafUnpickler(_StateUnpickler):
         return array if axes is None else array.transpose(np.argsort(axes))
 
 
-def write_state(writer: ChecksumWriter, state: Any) -> int:
-    """Write a host tree in the raw layout.  -> `copied_bytes`: array
-    bytes that did not go to the file from the leaf's own memory."""
+def write_state(
+    writer: ChecksumWriter, state: Any, stream: Optional[LeafStream] = None
+) -> int:
+    """Write a tree in the raw layout, its device leaves (if any) as
+    `stream` brings them over.  -> `copied_bytes`: array bytes that did
+    not go to the file from the memory the leaf came to the host in."""
+    import jax
+
     skeleton = io.BytesIO()
-    pickler = _RawLeafPickler(skeleton)
+    pickler = _RawLeafPickler(
+        skeleton, filter(_on_device, jax.tree.leaves(state))
+    )
     pickler.dump(state)
     buffers = pickler.buffers
     writer.write(_RAW_MAGIC + struct.pack(
@@ -450,9 +654,18 @@ def write_state(writer: ChecksumWriter, state: Any) -> int:
         *(buffer.nbytes for buffer in buffers),
     ))
     writer.write(skeleton.getbuffer())
+    copied = pickler.copied_bytes
+    arrays = (stream or LeafStream()).host_arrays(
+        getattr(buffer, "array", buffer) for buffer in buffers
+    )
     for buffer in buffers:
-        writer.write(buffer)
-    return pickler.copied_bytes
+        host = next(arrays)
+        if isinstance(buffer, _DeviceLeaf):
+            host, extra = buffer.bytes_of(host)
+            copied += extra
+        writer.write(host)
+        host = None  # let go of before the stream brings the next
+    return copied
 
 
 def _read_exact(f, into) -> None:
@@ -568,30 +781,22 @@ class CheckpointSaver:
     # ------------------------------------------------------------------
 
     def save(self, state: Any, step: int) -> str:
-        """Snapshot a (host or device) pytree at `step`, atomically, with
-        a CRC32 integrity manifest covering the state file."""
-        import jax
-
+        """Snapshot a pytree at `step`, atomically, with a CRC32
+        integrity manifest covering the state file.  Leaves that are
+        still on the device (`streams`) reach the file through a
+        `LeafStream`: the host never holds the whole of them."""
         start = time.monotonic()
         final_dir = self._step_dir(step)
         if os.path.exists(final_dir):
             return final_dir
-        if any(isinstance(x, jax.Array) for x in jax.tree.leaves(state)):
-            # A caller that hands over device arrays (the trainers'
-            # `state_to_host` has its own gather span and hands over
-            # host ones).
-            with tracing.span(
-                "checkpoint.save.gather", bytes=tree_nbytes(state)
-            ):
-                state = jax.device_get(state)
         tmp_dir = tempfile.mkdtemp(
             prefix=f"step_{step:012d}.tmp", dir=self._dir
         )
         state_path = os.path.join(tmp_dir, _STATE_FILE)
-        with tracing.span("checkpoint.save.write") as span:
-            with ChecksumWriter(state_path) as writer:
-                span.fields["copied_bytes"] = write_state(writer, state)
-            span.fields["bytes"] = writer.size
+        stream = LeafStream()
+        with ChecksumWriter(state_path) as writer:
+            copied = write_state(writer, state, stream)
+        stream.journal(copied_bytes=copied, bytes=writer.size)
         with tracing.span(
             "checkpoint.save.crc", bytes=writer.size
         ) as span:

@@ -27,6 +27,10 @@ rank-0 rename after a cross-process barrier):
                              memory)
       shards_p1of2.npz     - process 1's rows
 
+A save is a stream (`saver.LeafStream`): each rank's shards, then rank
+0's dense leaves, cross from the device up to a bounded look-ahead
+before the file takes them, so no rank's host holds more than a few
+shards at once; the files are byte for byte what gather-then-write made.
 Every file goes out through `saver.ChecksumWriter`, so the CRC32 and size
 `integrity.json` records come from the bytes in flight; a rank other
 than 0 leaves its file's pair in a sidecar (`<file>.crc`) in the shared
@@ -48,6 +52,7 @@ import json
 import os
 import pickle
 import struct
+import time
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
@@ -57,6 +62,7 @@ from elasticdl_tpu import obs
 from elasticdl_tpu.checkpoint.saver import (
     CheckpointSaver,
     ChecksumWriter,
+    LeafStream,
     _apply_write_fault,
     _ckpt_metrics,
     tree_nbytes,
@@ -91,15 +97,19 @@ _ZIP_END = struct.Struct("<4s4H2LH")
 _ZIP_MAX32 = 0xFFFFFFFF
 
 
-def write_npz(writer: ChecksumWriter, entries: Dict[str, np.ndarray]) -> int:
+def write_npz(writer: ChecksumWriter, entries) -> int:
     """Write what `np.savez(file, **entries)` would, through `writer`:
     each member is the array's `.npy` header and then its bytes, handed
     over from the array's own memory, with the member's CRC32 and the
-    file's taken in the same one pass.  -> `copied_bytes` (arrays that
-    were neither C- nor Fortran-contiguous and had to be copied once)."""
+    file's taken in the same one pass.  `entries` is a dict or any
+    iterable of (key, array) pairs, asked for one pair at a time (a
+    save hands over pairs whose arrays are still crossing from the
+    device).  -> `copied_bytes` (arrays that were neither C- nor
+    Fortran-contiguous and had to be copied once)."""
     copied = 0
     directory = []
-    for key, array in entries.items():
+    pairs = entries.items() if isinstance(entries, dict) else entries
+    for key, array in pairs:
         if array.dtype.hasobject:
             raise ValueError(f"{key}: object arrays are not checkpointed")
         if not (array.flags.c_contiguous or array.flags.f_contiguous):
@@ -128,6 +138,7 @@ def write_npz(writer: ChecksumWriter, entries: Dict[str, np.ndarray]) -> int:
         crc = writer.end_member()
         writer.write(_ZIP_DESCRIPTOR.pack(b"PK\x07\x08", crc, size, size))
         directory.append((name, flags, crc, size, offset))
+        array = stored = None  # let go of before asking for the next
     directory_offset = writer.size
     for name, flags, crc, size, offset in directory:
         writer.write(_ZIP_CENTRAL.pack(
@@ -236,8 +247,6 @@ class ShardedCheckpointSaver(CheckpointSaver):
         only its own addressable rows of each `sharded` array.  Replicated
         arrays (tables too small to split) are written by rank 0 alone.
         `dense_state` may be None on ranks != 0 (only rank 0 writes it)."""
-        import time
-
         start = time.monotonic()
         process = jax.process_index()
         n_processes = jax.process_count()
@@ -247,54 +256,61 @@ class ShardedCheckpointSaver(CheckpointSaver):
             return final_dir
         os.makedirs(tmp_dir, exist_ok=True)
 
-        entries: Dict[str, np.ndarray] = {}
-        with tracing.span("checkpoint.save.gather") as span:
-            for name, array in sharded.items():
-                dim0 = array.shape[0]
-                seen: set = set()
-                for shard in array.addressable_shards:
-                    lo, hi = _interval(shard, dim0)
-                    if (lo, hi) in seen:
-                        continue  # replicas of these rows on other devices
-                    seen.add((lo, hi))
-                    if (lo, hi) == (0, dim0) and process != 0:
-                        continue  # fully replicated: rank 0 writes it
-                    entries[f"{name}|{lo}|{hi}"] = np.asarray(shard.data)
-            if process == 0:
-                dense_state = jax.device_get(dense_state)
-            span.fields["bytes"] = tree_nbytes(entries) + (
-                tree_nbytes(dense_state) if process == 0 else 0
-            )
+        # This rank's rows, then (rank 0) the dense leaves, in the order
+        # the files hold them and still on the device: `LeafStream`
+        # brings each over while the file takes the one before it.
+        keys, on_device = [], []
+        for name, array in sharded.items():
+            dim0 = array.shape[0]
+            seen: set = set()
+            for shard in array.addressable_shards:
+                lo, hi = _interval(shard, dim0)
+                if (lo, hi) in seen:
+                    continue  # replicas of these rows on other devices
+                seen.add((lo, hi))
+                if (lo, hi) == (0, dim0) and process != 0:
+                    continue  # fully replicated: rank 0 writes it
+                keys.append(f"{name}|{lo}|{hi}")
+                on_device.append(shard.data)
+        dense_leaves, dense_tree = jax.tree.flatten(
+            dense_state if process == 0 else None
+        )
         shard_files = [
             f"shards_p{i}of{n_processes}.npz" for i in range(n_processes)
         ]
         # {file: (crc32, size)} as this rank's writers took them.
         known: Dict[str, Tuple[int, int]] = {}
-        with tracing.span("checkpoint.save.write") as span:
-            mine = shard_files[process]
-            with ChecksumWriter(os.path.join(tmp_dir, mine)) as writer:
-                copied = write_npz(writer, entries)
-            known[mine] = (writer.crc32, writer.size)
-            # Keep the shared tmp dir's mtime fresh while the save is
-            # live so a restarting peer's stale-tmp sweep
-            # (saver.sweep_stale_tmp) never mistakes an in-flight save
-            # for crashed-save garbage.
+        stream = LeafStream()
+        arrays = stream.host_arrays(on_device + dense_leaves)
+        mine = shard_files[process]
+        with ChecksumWriter(os.path.join(tmp_dir, mine)) as writer:
+            copied = write_npz(
+                writer, ((key, next(arrays)) for key in keys)
+            )
+        known[mine] = (writer.crc32, writer.size)
+        # Keep the shared tmp dir's mtime fresh while the save is
+        # live so a restarting peer's stale-tmp sweep
+        # (saver.sweep_stale_tmp) never mistakes an in-flight save
+        # for crashed-save garbage.
+        os.utime(tmp_dir)
+        if process == 0:
+            # A plain pickle stream (readers outside this package
+            # read it with `pickle.load`): it needs its tree whole on
+            # the host, and its arrays pass through pickle's own copy.
+            dense_state = jax.tree.unflatten(dense_tree, list(arrays))
+            with ChecksumWriter(os.path.join(tmp_dir, _DENSE)) as writer:
+                pickle.dump(dense_state, writer)
+            copied += tree_nbytes(dense_state)
+            known[_DENSE] = (writer.crc32, writer.size)
             os.utime(tmp_dir)
-            if process == 0:
-                # A plain pickle stream (readers outside this package
-                # read it with `pickle.load`): its arrays pass through
-                # pickle's own copy.
-                with ChecksumWriter(os.path.join(tmp_dir, _DENSE)) as writer:
-                    pickle.dump(dense_state, writer)
-                copied += tree_nbytes(dense_state)
-                known[_DENSE] = (writer.crc32, writer.size)
-                os.utime(tmp_dir)
-            else:
-                sidecar = os.path.join(tmp_dir, mine + _SIDECAR_SUFFIX)
-                with open(sidecar, "w") as f:
-                    json.dump({"crc32": writer.crc32, "size": writer.size}, f)
-            span.fields["bytes"] = sum(size for _crc, size in known.values())
-            span.fields["copied_bytes"] = copied
+        else:
+            sidecar = os.path.join(tmp_dir, mine + _SIDECAR_SUFFIX)
+            with open(sidecar, "w") as f:
+                json.dump({"crc32": writer.crc32, "size": writer.size}, f)
+        stream.journal(
+            copied_bytes=copied,
+            bytes=sum(size for _crc, size in known.values()),
+        )
 
         if n_processes > 1:
             from jax.experimental import multihost_utils

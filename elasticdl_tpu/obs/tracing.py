@@ -123,9 +123,16 @@ SPAN_NAMES: Dict[str, str] = {
     "data.decode": "interval: columnar concatenate + model transform",
     # checkpoint
     "checkpoint.save": "interval: one save, all ranks",
-    "checkpoint.save.gather": "interval: device -> host",
-    "checkpoint.save.write": "interval: every file written once, its "
-                             "CRC32 taken in flight (`copied_bytes`)",
+    "checkpoint.save.gather": "after: what a streamed save waited for "
+                              "the device, summed (the program in flight, "
+                              "then any leaf not yet on the host when the "
+                              "writer asked); interval: a trainer's "
+                              "collective gather (`state_to_host`)",
+    "checkpoint.save.write": "after: the rest of the stream, the time "
+                             "inside the writer: every file written once, "
+                             "its CRC32 taken in flight (`copied_bytes`, "
+                             "`streamed_bytes`, `lookahead_peak_bytes`, "
+                             "`leaves`)",
     "checkpoint.save.crc": "interval: the integrity manifest alone "
                            "(`reread_bytes`: 0 unless a file is read back)",
     "checkpoint.save.commit": "interval: rename + garbage-collect",

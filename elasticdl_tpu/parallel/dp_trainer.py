@@ -491,8 +491,8 @@ class DataParallelTrainer:
             key = self._leaf_key(path)
             if sharding.is_fully_replicated:
                 if jax.process_index() == 0:
-                    # The saver brings it to the host, inside its
-                    # `checkpoint.save.gather` span.
+                    # The saver's stream brings it to the host, behind
+                    # the shards.
                     dense_leaves[key] = leaf
             else:
                 sharded[key] = leaf

@@ -926,8 +926,8 @@ class ShardedEmbeddingTrainer:
         state = self._state
         # Dense state is replicated and only rank 0 writes it — don't pay
         # the device->host transfer on the other N-1 ranks' hot path.
-        # The saver brings it to the host with the table rows, inside
-        # its `checkpoint.save.gather` span.
+        # The saver's stream brings it to the host behind the table
+        # rows, leaf by leaf.
         dense = None
         if jax.process_index() == 0:
             dense = {

@@ -31,7 +31,7 @@ import numpy as np
 
 from elasticdl_tpu import obs
 from elasticdl_tpu.checkpoint import ShardedCheckpointSaver
-from elasticdl_tpu.checkpoint.saver import save_span
+from elasticdl_tpu.checkpoint.saver import save_span, streams
 from elasticdl_tpu.common import faults
 from elasticdl_tpu.common.constants import Mode, TaskExecCounterKey
 from elasticdl_tpu.common.log_utils import get_logger
@@ -880,9 +880,16 @@ class CollectiveWorker:
                         # Collective: every rank writes its own shards.
                         self._trainer.save_checkpoint(self._ckpt, step)
                     else:
-                        host_state = self._trainer.state_to_host()
+                        # A state that lies whole on this process's
+                        # devices goes to the saver as it is, which
+                        # streams it leaf by leaf; one whose gather is
+                        # a collective comes to the host first, on
+                        # every rank.
+                        state = self._trainer.state
+                        if not streams(state):
+                            state = self._trainer.state_to_host()
                         if self._world.is_leader:
-                            self._ckpt.save(host_state, step)
+                            self._ckpt.save(state, step)
             self._last_ckpt_step = step
 
 

@@ -533,3 +533,411 @@ def test_peer_ranks_checksum_reaches_rank_zeros_manifest(
         "shards_p0of2.npz", "shards_p1of2.npz",
     ]
     assert saver_mod.verify_integrity(final) is None
+
+
+# ---------------------------------------------------------------------------
+# (h) a save is a stream of leaves (PR 39): the file takes leaf i while
+# leaves i+1 ... i+k cross from the device; the bytes are the same bytes
+# ---------------------------------------------------------------------------
+
+
+def _device_tree():
+    rng = np.random.default_rng(5)
+    # (keys in sorted order, the order `jax.device_get` hands a dict
+    # back in: the file of the tree and of its host copy list the
+    # leaves alike)
+    return {
+        "count": jnp.asarray(7, jnp.int32),
+        "ids": jnp.arange(11),
+        "key": jax.random.key(3),
+        "lr": 0.5,
+        "params": {
+            "b": jnp.asarray(rng.normal(size=(9, 3)), jnp.bfloat16),
+            "big": jnp.asarray(rng.normal(size=(64, 48)), jnp.float32),
+            "w": jnp.asarray(rng.normal(size=(37, 5)), jnp.float32),
+        },
+    }
+
+
+def _assert_same_tree(got, want):
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        if isinstance(b, jax.Array) and jax.dtypes.issubdtype(
+            b.dtype, jax.dtypes.prng_key
+        ):
+            a, b = jax.random.key_data(a), jax.random.key_data(b)
+        elif not hasattr(b, "dtype"):
+            assert a == b
+            continue
+        assert _same_bits(a, b)
+
+
+@pytest.fixture
+def transposed_transfers(monkeypatch):
+    """Every 2-d leaf comes to the host with its axes swapped in memory,
+    as a weight that a TPU keeps transposed does."""
+    real = saver_mod.LeafStream._fetch
+
+    def fetch(handle):
+        host = real(handle)
+        return np.asfortranarray(host) if host.ndim == 2 else host
+
+    monkeypatch.setattr(saver_mod.LeafStream, "_fetch", staticmethod(fetch))
+
+
+@pytest.mark.parametrize("case", [
+    "as_it_lies", "transposed_as_the_layout_said", "transposed_unannounced",
+    "leaves_larger_than_the_lookahead",
+])
+def test_streamed_device_tree_restores_bit_for_bit(
+    tmp_path, monkeypatch, request, case
+):
+    tree = _device_tree()
+    copied = 0
+    if case.startswith("transposed"):
+        request.getfixturevalue("transposed_transfers")
+        if case == "transposed_as_the_layout_said":
+            monkeypatch.setattr(
+                saver_mod, "_device_axes",
+                lambda a: (1, 0) if a.ndim == 2 else None,
+            )
+        else:  # the axes were settled before the transfer: one copy each
+            copied = sum(
+                x.nbytes for x in jax.tree.leaves(tree["params"])
+            )
+    elif case == "leaves_larger_than_the_lookahead":
+        monkeypatch.setattr(saver_mod, "_LOOKAHEAD_BYTES", 64)
+    marker = time.time()
+    saver = CheckpointSaver(str(tmp_path))
+    with save_span(rank=0, step=4):
+        final = saver.save(tree, 4)
+    restored, step = saver.load_latest()
+    assert step == 4
+    _assert_same_tree(restored, tree)
+    for leaf in jax.tree.leaves(restored["params"]) + [restored["count"]]:
+        assert type(leaf) is np.ndarray and leaf.flags.writeable
+    (write,) = _spans_since(marker, "checkpoint.save.write")
+    (gather,) = _spans_since(marker, "checkpoint.save.gather")
+    on_device = [x for x in jax.tree.leaves(tree) if saver_mod._on_device(x)]
+    total = sum(x.nbytes for x in on_device)
+    assert write["copied_bytes"] == copied
+    assert write["leaves"] == len(on_device) == 5
+    assert gather["bytes"] == total
+    assert write["bytes"] == os.path.getsize(os.path.join(final, "state.pkl"))
+    # Every leaf but the first was on its way before the writer asked.
+    assert write["streamed_bytes"] == total - tree["count"].nbytes
+    if case == "leaves_larger_than_the_lookahead":
+        assert write["lookahead_peak_bytes"] < total
+    if case == "transposed_as_the_layout_said":
+        # (a transposed leaf comes back in the layout it was saved from)
+        assert restored["params"]["w"].flags.f_contiguous
+
+
+def _parent_write_state(path, state):
+    """`write_state` as the parent commit (2cbfc0d) had it, for a host
+    tree: the bytes an older tree left behind."""
+
+    class Pickler(pickle.Pickler):
+        def __init__(self, file):
+            super().__init__(file, protocol=5)
+            self.buffers = []
+
+        def persistent_id(self, obj):
+            if type(obj) is not np.ndarray or obj.dtype.hasobject:
+                return None
+            view, axes = saver_mod._dense_view(obj)
+            self.buffers.append(view.reshape(-1).view(np.uint8))
+            return (len(self.buffers) - 1, obj.dtype, view.shape, axes)
+
+    import io
+
+    skeleton = io.BytesIO()
+    pickler = Pickler(skeleton)
+    pickler.dump(state)
+    with open(path, "wb") as f:
+        f.write(b"EDLRAW01" + struct.pack(
+            f"<{2 + len(pickler.buffers)}Q", skeleton.tell(),
+            len(pickler.buffers), *(b.nbytes for b in pickler.buffers),
+        ))
+        f.write(skeleton.getbuffer())
+        for buffer in pickler.buffers:
+            f.write(buffer)
+
+
+@pytest.mark.parametrize("layout", ["EDLRAW01", "plain_pickle"])
+def test_read_state_reads_what_the_parents_code_wrote(tmp_path, layout):
+    host = jax.device_get(_device_tree())
+    host["f"] = _leaf("fortran")
+    path = str(tmp_path / "state.pkl")
+    if layout == "EDLRAW01":
+        _parent_write_state(path, host)
+        assert open(path, "rb").read(8) == b"EDLRAW01"
+    else:
+        with open(path, "wb") as f:
+            pickle.dump(host, f)
+    _assert_same_tree(read_state(path), host)
+
+
+def test_the_stream_writes_the_layout_the_parent_reads(tmp_path):
+    """No second layout came with the stream: a device tree's file is
+    the `EDLRAW01` file of the same tree on the host, to the byte after
+    the skeleton (whose pickle memoises an equal shape tuple or not),
+    so a tree older than this PR reads it."""
+    tree = _device_tree()
+    with ChecksumWriter(str(tmp_path / "device")) as writer:
+        assert write_state(writer, tree) == 0
+    _parent_write_state(str(tmp_path / "host"), jax.device_get(tree))
+    device = (tmp_path / "device").read_bytes()
+    host = (tmp_path / "host").read_bytes()
+    n_buffers = struct.unpack_from("<Q", device, 16)[0]
+    assert device[:8] == host[:8] == b"EDLRAW01"
+    assert device[16:24 + 8 * n_buffers] == host[16:24 + 8 * n_buffers]
+    payload = sum(struct.unpack_from(f"<{n_buffers}Q", device, 24))
+    assert device[-payload:] == host[-payload:]
+    _assert_same_tree(read_state(str(tmp_path / "device")), tree)
+
+
+class _CountingStream(saver_mod.LeafStream):
+    """A leaf source that counts what is on the host: a handle's bytes
+    from its start until the stream lets go of it."""
+
+    outstanding = 0
+    peak = 0
+    order = []
+
+    class Handle:
+        def __init__(self, leaf):
+            self.leaf = leaf
+            cls = _CountingStream
+            cls.outstanding += leaf.nbytes
+            cls.peak = max(cls.peak, cls.outstanding)
+
+        def __del__(self):
+            _CountingStream.outstanding -= self.leaf.nbytes
+
+    @staticmethod
+    def _start(leaf):
+        _CountingStream.order.append(("start", id(leaf)))
+        return _CountingStream.Handle(leaf)
+
+    @staticmethod
+    def _fetch(handle):
+        _CountingStream.order.append(("fetch", id(handle.leaf)))
+        return np.asarray(handle.leaf)
+
+
+@pytest.mark.parametrize("sizes,bound", [
+    ([100] * 12, 256), ([64, 8, 8, 200, 8, 300, 8, 8], 128),
+    ([4096, 4096, 4096, 16], 1024), ([10], 1 << 20),
+])
+def test_the_host_never_holds_more_than_the_lookahead_and_one_leaf(
+    monkeypatch, sizes, bound
+):
+    monkeypatch.setattr(saver_mod, "_LOOKAHEAD_BYTES", bound)
+    _CountingStream.outstanding = _CountingStream.peak = 0
+    _CountingStream.order = []
+    leaves = [jnp.arange(n, dtype=jnp.uint8) for n in sizes]
+    stream = _CountingStream()
+    held_by_writer = []
+    for leaf, host in zip(leaves, stream.host_arrays(leaves)):
+        assert _same_bits(host, leaf)
+        # (the writer has the leaf it was handed, and what is ahead)
+        held_by_writer.append(_CountingStream.outstanding)
+    assert _CountingStream.outstanding == 0  # all let go of
+    largest = max(sizes)
+    # The leaf being written, and ahead of it the bound or, where one
+    # leaf is larger than the bound, that one leaf.
+    assert _CountingStream.peak <= largest + max(bound, largest)
+    if largest <= bound:
+        assert _CountingStream.peak <= bound + largest
+    assert stream.lookahead_peak_bytes == _CountingStream.peak
+    assert max(held_by_writer) <= _CountingStream.peak
+    # Transfers start in the order the file holds the leaves, each
+    # before its fetch, and (but for the first) before the fetch of the
+    # leaf ahead of it: the write of leaf i runs beside i+1's transfer.
+    starts = [i for kind, i in _CountingStream.order if kind == "start"]
+    assert starts == [id(leaf) for leaf in leaves]
+    for n, leaf in enumerate(leaves[1:]):
+        assert _CountingStream.order.index(("start", id(leaf))) < (
+            _CountingStream.order.index(("fetch", id(leaves[n])))
+        )
+    assert stream.leaves == len(sizes) and stream.bytes == sum(sizes)
+    assert stream.streamed_bytes == sum(sizes[1:])
+
+
+def test_a_host_leaf_among_device_leaves_keeps_its_place():
+    host = np.arange(5)
+    leaves = [jnp.ones(3), host, "text", jnp.zeros((2, 2))]
+    stream = saver_mod.LeafStream()
+    got = list(stream.host_arrays(leaves))
+    assert got[1] is host and got[2] == "text"
+    assert _same_bits(got[0], leaves[0]) and _same_bits(got[3], leaves[3])
+    assert stream.leaves == 2 and stream.streamed_bytes == leaves[3].nbytes
+
+
+@pytest.mark.parametrize("kind", ["full", "sharded"])
+def test_a_transfer_that_fails_mid_stream_commits_nothing(
+    tmp_path, monkeypatch, kind
+):
+    real = saver_mod.LeafStream._fetch
+    fetched = []
+
+    def fetch(handle):
+        fetched.append(handle)
+        if len(fetched) == 3:
+            raise RuntimeError("the device went away")
+        return real(handle)
+
+    if kind == "full":
+        saver = CheckpointSaver(str(tmp_path), keep_max=5)
+        saver.save(_device_tree(), 1)
+        monkeypatch.setattr(saver_mod.LeafStream, "_fetch", staticmethod(fetch))
+        with pytest.raises(RuntimeError, match="went away"):
+            saver.save(_device_tree(), 2)
+    else:
+        saver, _final, table = _sharded_save(tmp_path, step=1)
+        dense = {"step": jnp.int32(2), "a": jnp.ones(3), "b": jnp.ones(4)}
+        monkeypatch.setattr(saver_mod.LeafStream, "_fetch", staticmethod(fetch))
+        with pytest.raises(RuntimeError, match="went away"):
+            saver.save(2, dense, {"table|t": table})
+    monkeypatch.setattr(saver_mod.LeafStream, "_fetch", staticmethod(real))
+    # The previous checkpoint is still the newest committed one ...
+    assert saver.steps() == [1]
+    if kind == "full":
+        restored, step = saver.load_latest()
+        assert step == 1
+        _assert_same_tree(restored, _device_tree())
+    else:
+        assert saver.latest_step() == 1
+    # ... and what the failed save left is a tmp dir, which the next
+    # saver sweeps once it is stale.
+    (tmp,) = [n for n in os.listdir(tmp_path) if ".tmp" in n]
+    old = time.time() - saver_mod.STALE_TMP_GRACE_S - 10
+    os.utime(tmp_path / tmp, (old, old))
+    type(saver)(str(tmp_path))
+    assert not [n for n in os.listdir(tmp_path) if ".tmp" in n]
+    assert saver.steps() == [1]
+
+
+def test_a_streamed_file_torn_after_its_checksum_falls_back(tmp_path):
+    """The manifest's CRC32 and size are those of the bytes the stream
+    handed to the file, whatever became of the file afterwards."""
+    saver = CheckpointSaver(str(tmp_path), keep_max=5)
+    saver.save(_device_tree(), 1)
+    tree = jax.tree.map(
+        lambda x: x + 1 if saver_mod._on_device(x) else x, _device_tree()
+    )
+    faults.install("ckpt.write:truncate@1")  # tears after the checksum
+    try:
+        final = saver.save(tree, 2)
+    finally:
+        faults.clear()
+    with ChecksumWriter(str(tmp_path / "whole")) as writer:
+        write_state(writer, tree)
+    files = json.load(open(os.path.join(final, "integrity.json")))["files"]
+    assert files["state.pkl"] == {
+        "crc32": file_crc32(str(tmp_path / "whole")),
+        "size": os.path.getsize(tmp_path / "whole"),
+    }
+    assert os.path.getsize(os.path.join(final, "state.pkl")) < writer.size
+    restored, step = saver.load_latest()
+    assert step == 1
+    _assert_same_tree(restored, _device_tree())
+    assert "step_000000000002.quarantined" in os.listdir(tmp_path)
+
+
+def test_a_host_tree_is_saved_as_before(tmp_path):
+    """`save(host_state)`: no device to wait for, so no gather span of
+    the saver's; the file is the one the parent wrote."""
+    host = jax.device_get(_device_tree())
+    marker = time.time()
+    with save_span(rank=0, step=3):
+        final = CheckpointSaver(str(tmp_path)).save(host, 3)
+    assert not _spans_since(marker, "checkpoint.save.gather")
+    (write,) = _spans_since(marker, "checkpoint.save.write")
+    assert write["leaves"] == write["streamed_bytes"] == 0
+    assert write["lookahead_peak_bytes"] == write["copied_bytes"] == 0
+    _parent_write_state(str(tmp_path / "parent"), host)
+    assert open(os.path.join(final, "state.pkl"), "rb").read() == (
+        tmp_path / "parent"
+    ).read_bytes()
+    assert not saver_mod.streams(host)
+
+
+def _worker_with(saver):
+    from test_span_vocabulary import _collective_worker
+
+    return _collective_worker(saver)
+
+
+def test_state_to_host_gathers_as_before():
+    worker = _worker_with(None)
+    trainer = worker._trainer
+    trainer.train_step(np.ones((4, 2), np.float32), np.ones(4, np.float32))
+    assert saver_mod.streams(trainer.state)
+    marker = time.time()
+    host = trainer.state_to_host()
+    (gather,) = _spans_since(marker, "checkpoint.save.gather")
+    assert gather["bytes"] == saver_mod.tree_nbytes(trainer.state)
+    assert all(
+        type(x) is np.ndarray for x in jax.tree.leaves(host)
+    ) and not saver_mod.streams(host)
+    _assert_same_tree(host, trainer.state)
+
+
+def test_the_worker_hands_the_saver_the_state_as_it_lies_on_the_device(
+    tmp_path, monkeypatch
+):
+    """... and the save stays synchronous: `checkpoint_saved` is
+    journaled inside `checkpoint.save`, whose `.write` child says how
+    much of the state was streamed."""
+    from elasticdl_tpu.proto import elasticdl_pb2 as pb
+
+    saver = CheckpointSaver(str(tmp_path))
+    worker = _worker_with(saver)
+    monkeypatch.setattr(
+        type(worker._trainer), "state_to_host",
+        lambda self: pytest.fail("a state that streams needs no gather"),
+    )
+    marker = time.time()
+    worker._process_train_task(
+        pb.Task(task_id=1, type=pb.TRAINING, shard_name="s", start=0, end=8)
+    )
+    saves = _spans_since(marker, "checkpoint.save")
+    writes = _spans_since(marker, "checkpoint.save.write")
+    gathers = _spans_since(marker, "checkpoint.save.gather")
+    assert saves and len(saves) == len(writes) == len(gathers)
+    committed = [
+        e for e in obs.journal().tail(2000)
+        if e.get("event") == "checkpoint_saved" and e["ts"] >= marker
+    ]
+    assert len(committed) == len(saves)
+    state_bytes = saver_mod.tree_nbytes(worker._trainer.state)
+    for save, write, gather, event in zip(saves, writes, gathers, committed):
+        lo, hi = save["start_ts"], save["start_ts"] + save["duration_s"]
+        assert lo <= event["ts"] <= hi + 1e-3
+        for child in (write, gather):
+            assert child["parent_span_id"] == save["span_id"]
+            assert lo <= child["start_ts"] <= hi
+        assert write["leaves"] == len(jax.tree.leaves(worker._trainer.state))
+        assert gather["bytes"] == state_bytes
+        assert 0 < write["lookahead_peak_bytes"] <= state_bytes
+        assert 0 < write["streamed_bytes"] < state_bytes
+        assert gather["duration_s"] + write["duration_s"] <= (
+            save["duration_s"] + 1e-3
+        )
+    restored, step = saver.load_latest()
+    assert step == worker._trainer.step
+    _assert_same_tree(restored, worker._trainer.state)
+
+
+def test_a_state_sharded_over_devices_takes_the_host_path():
+    mesh = build_mesh(MeshConfig())
+    if mesh.devices.size < 2:
+        pytest.skip("one device: nothing is sharded")
+    table = jax.device_put(
+        jnp.zeros((64, 4)), NamedSharding(mesh, P(("data", "model")))
+    )
+    assert not saver_mod.streams({"t": table, "w": jnp.ones(3)})
+    assert saver_mod.streams({"w": jnp.ones(3), "n": 2})
+    assert not saver_mod.streams({"n": 2})

@@ -225,3 +225,69 @@ def test_table_layout_mismatch_raises_with_cause(tmp_path):
     split.set_sharded_restore(saver, 1)
     with pytest.raises(ValueError, match="table layout changed"):
         split.ensure_initialized(feats)
+
+
+def _gather_then_write(step_dir, trainer):
+    """The parent commit's order (sharded.py:250-297 at 2cbfc0d): every
+    shard and the dense state to the host, then the files."""
+    import pickle
+
+    import jax
+
+    from elasticdl_tpu.checkpoint.saver import ChecksumWriter
+    from elasticdl_tpu.checkpoint.sharded import _interval, write_npz
+
+    state = trainer.state
+    entries = {}
+    for name, array in trainer._sharded_arrays(state).items():
+        seen = set()
+        for shard in array.addressable_shards:
+            lo, hi = _interval(shard, array.shape[0])
+            if (lo, hi) not in seen:
+                seen.add((lo, hi))
+                entries[f"{name}|{lo}|{hi}"] = np.asarray(shard.data)
+    dense = jax.device_get({
+        "step": state.step, "params": state.params,
+        "opt_state": state.opt_state, "model_state": state.model_state,
+        "scalar_slots": trainer._scalar_slots(state),
+    })
+    os.makedirs(step_dir)
+    with ChecksumWriter(os.path.join(step_dir, "shards_p0of1.npz")) as writer:
+        write_npz(writer, entries)
+    with ChecksumWriter(os.path.join(step_dir, "dense.pkl")) as writer:
+        pickle.dump(dense, writer)
+
+
+@pytest.mark.parametrize("lookahead", [1 << 29, 64])
+@pytest.mark.parametrize("name", ["shards_p0of1.npz", "dense.pkl"])
+def test_streamed_files_are_the_gathered_files_byte_for_byte(
+    tmp_path, monkeypatch, name, lookahead
+):
+    """`perfbench/configs/deepfm_reference.py` opens both files itself:
+    the stream changes the order of the work, not one byte of a file."""
+    import time
+
+    from elasticdl_tpu.checkpoint import saver as saver_mod
+    from elasticdl_tpu.checkpoint.saver import save_span
+    from test_checkpoint_single_pass import _spans_since
+
+    monkeypatch.setattr(saver_mod, "_LOOKAHEAD_BYTES", lookahead)
+    mesh = build_mesh(MeshConfig())
+    trainer = _make_trainer(mesh)
+    ids, labels = _train_batches()
+    for _ in range(2):
+        trainer.train_step(ids, labels)
+    marker = time.time()
+    saver = ShardedCheckpointSaver(str(tmp_path / "streamed"))
+    with save_span(rank=0, step=2):
+        trainer.save_checkpoint(saver, 2)
+    _gather_then_write(str(tmp_path / "gathered"), trainer)
+    streamed = tmp_path / "streamed" / "step_000000000002" / name
+    assert streamed.read_bytes() == (tmp_path / "gathered" / name).read_bytes()
+    (gather,) = _spans_since(marker, "checkpoint.save.gather")
+    (write,) = _spans_since(marker, "checkpoint.save.write")
+    assert write["leaves"] > 2 and gather["bytes"] > 0
+    assert 0 < write["streamed_bytes"] < gather["bytes"]
+    assert 0 < write["lookahead_peak_bytes"] <= gather["bytes"]
+    if lookahead == 64:  # one leaf ahead, whatever its size
+        assert write["lookahead_peak_bytes"] < gather["bytes"]
