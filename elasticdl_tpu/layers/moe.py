@@ -91,10 +91,30 @@ no reverse-mode rule, so `grouped_expert_mlp` carries its own backward
 pass: the same loop, recomputing a block's activations and accumulating
 the held experts' weight gradients in float32.
 
+**A block's rows come from the shapes** (`block_rows_for`).  A block
+costs about as much as 220 of its rows whatever it holds: the products
+read the expert's weights out of the stack and, backward, read and write
+the expert's whole float32 gradient once a BLOCK; only the products, the
+gather and the scatter-add grow with the rows.  So a block is the
+smallest power of two that holds what a uniform router gives an expert,
+tokens x top_k / num_experts, in ONE block (two blocks of half the rows
+would pad to the same rows and cross the weights twice), kept within 128
+and 512 (past 512 the padding of an expert's last block costs more
+products than the weights' traffic saves: PERF.md section 6, PR 40).
+`SparseMoeBlock.block_rows` is None for that rule and a number to
+overrule it (tests do).  Every input of the rule is a static shape the
+layer is handed; no model is named.  The scatter-add is NOT told that a
+block's tokens are sorted and unique (they are): on a TPU v5e that hint
+made it seven times slower (same section).
+
 Counters (the ``routing`` collection, cumulative, uint32, updated only
 where the collection is mutable, i.e. in training): pairs routed to held
-experts, rows the loop processed, and the load of each held expert.  The
-worker journals their per-task differences as ``moe.routing``.
+experts, rows the loop processed, the loop's trips (`blocks`), the load
+of each held expert, and, not cumulative, the `block_rows` the layer last
+ran with.  The worker journals their per-task differences as
+``moe.routing``: `pairs / (blocks x block_rows)` is the fill of the
+blocks, what the padding cost.  A state restored from a checkpoint that
+has no `blocks` or `block_rows` gets them at zero (`with_absent_counters`).
 """
 
 from __future__ import annotations
@@ -106,9 +126,21 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
-from flax.traverse_util import flatten_dict
+from flax.traverse_util import flatten_dict, unflatten_dict
 
 ROUTING_COLLECTION = "routing"
+#: The bounds of a block's rows where the shapes decide (module docstring).
+MIN_BLOCK_ROWS, MAX_BLOCK_ROWS = 128, 512
+
+
+def block_rows_for(tokens: int, top_k: int, num_experts: int) -> int:
+    """The expert loop's block for a layer of these static shapes: the
+    smallest power of two that holds the pairs a uniform router gives an
+    expert, within [MIN_BLOCK_ROWS, MAX_BLOCK_ROWS]."""
+    share = max(-(-tokens * top_k // num_experts), 1)
+    return min(
+        max(1 << (share - 1).bit_length(), MIN_BLOCK_ROWS), MAX_BLOCK_ROWS
+    )
 
 
 def _block_plan(local_ids, n_held: int, block: int):
@@ -381,7 +413,8 @@ class SparseMoeBlock(nn.Module):
     held: Tuple[int, int]            # (first held expert, how many)
     norm_topk_prob: bool = True
     dtype: Any = jnp.bfloat16
-    block_rows: int = 128
+    # A block of the expert loop; None: from the shapes (`block_rows_for`).
+    block_rows: Optional[int] = None
     # The router's score function (module docstring): "softmax", or
     # "sigmoid" with its selection bias.
     score: str = "softmax"
@@ -416,7 +449,9 @@ class SparseMoeBlock(nn.Module):
             raise ValueError("the balancing loss is a softmax router's")
         shape, d = x.shape, x.shape[-1]
         x = x.reshape(-1, d)
-        n = x.shape[0]
+        block = self.block_rows or block_rows_for(
+            x.shape[0], self.top_k, self.num_experts
+        )
         init = nn.initializers.lecun_normal()
         expert_init = nn.initializers.variance_scaling(
             1.0, "fan_in", "truncated_normal", in_axis=-2, out_axis=-1,
@@ -467,11 +502,10 @@ class SparseMoeBlock(nn.Module):
                 weight = weight * self.routed_scale
             is_held = (expert >= first) & (expert < first + n_held)
             local = jnp.where(is_held, expert - first, n_held).reshape(-1)
-            plan = _block_plan(local.astype(jnp.int32), n_held,
-                               self.block_rows)
+            plan = _block_plan(local.astype(jnp.int32), n_held, block)
         y, rows = grouped_expert_mlp(
             x.astype(self.dtype), weights, weight.reshape(-1),
-            plan, self.top_k, self.block_rows,
+            plan, self.top_k, block,
         )
         with jax.named_scope("moe_shared"):
             shared = (GatedMLP if gated else Relu2MLP)(
@@ -488,21 +522,29 @@ class SparseMoeBlock(nn.Module):
                     preferred_element_type=jnp.float32,
                 )) * shared
             y = y + shared
-        self._count(is_held, rows, plan["counts"], n_held, balance)
+        self._count(is_held, rows, plan, block, balance)
         return y.reshape(shape)
 
-    def _count(self, is_held, rows, counts, n_held: int, balance) -> None:
+    def _count(self, is_held, rows, plan, block: int, balance) -> None:
         zero = lambda *s: jnp.zeros(s, jnp.uint32)  # noqa: E731
         pairs = self.variable(ROUTING_COLLECTION, "pairs", zero)
         processed = self.variable(ROUTING_COLLECTION, "processed", zero)
-        load = self.variable(ROUTING_COLLECTION, "load", zero, n_held)
+        blocks = self.variable(ROUTING_COLLECTION, "blocks", zero)
+        block_rows = self.variable(ROUTING_COLLECTION, "block_rows", zero)
+        load = self.variable(
+            ROUTING_COLLECTION, "load", zero, plan["counts"].shape[0]
+        )
         counting = self.is_mutable_collection(ROUTING_COLLECTION) and (
             not self.is_initializing()
         )
         if counting:
             pairs.value = pairs.value + jnp.sum(is_held).astype(jnp.uint32)
             processed.value = processed.value + rows.astype(jnp.uint32)
-            load.value = load.value + counts.astype(jnp.uint32)
+            blocks.value = blocks.value + plan["block_end"][-1].astype(
+                jnp.uint32
+            )
+            block_rows.value = jnp.uint32(block)
+            load.value = load.value + plan["counts"].astype(jnp.uint32)
         if balance is not None:  # only a layer that is told an alpha
             total = self.variable(
                 ROUTING_COLLECTION, "balance",
@@ -512,12 +554,35 @@ class SparseMoeBlock(nn.Module):
                 total.value = total.value + jax.lax.stop_gradient(balance)
 
 
+def with_absent_counters(model_state):
+    """`model_state` whose ``routing`` layers all hold `blocks` and
+    `block_rows`: a checkpoint written before the layer counted them
+    restores with them at zero, so the state keeps the tree the layer
+    writes.  The same object where nothing is absent."""
+    routing = (model_state or {}).get(ROUTING_COLLECTION)
+    if not routing:
+        return model_state
+    flat = flatten_dict(dict(routing))
+    absent = {
+        path[:-1] + (key,): np.zeros((), np.uint32)
+        for path in flat if path[-1] == "pairs"
+        for key in ("blocks", "block_rows") if path[:-1] + (key,) not in flat
+    }
+    if not absent:
+        return model_state
+    return {
+        **model_state,
+        ROUTING_COLLECTION: unflatten_dict({**flat, **absent}),
+    }
+
+
 class RoutingLedger:
     """A task's share of the cumulative ``routing`` counters: the worker
     reads them where it has already fetched the task's loss (no device
     sync of its own inside a step) and journals the difference to the
-    last reading as a ``moe.routing`` span.  uint32 differences are
-    right across a wrap."""
+    last reading as a ``moe.routing`` span (`block_rows` is no sum: the
+    largest of the layers' last).  uint32 differences are right across a
+    wrap."""
 
     def __init__(self):
         self._seen = None  # None: not seeded yet
@@ -537,6 +602,7 @@ class RoutingLedger:
             ])
             for key, dtype in (
                 ("pairs", np.uint32), ("processed", np.uint32),
+                ("blocks", np.uint32), ("block_rows", np.uint32),
                 ("load", np.uint32), ("balance", np.float32),
             )
             if layers[0] + (key,) in flat
@@ -556,15 +622,17 @@ class RoutingLedger:
             return None
         seen = self._seen or {key: 0 * value for key, value in now.items()}
         self._seen = now
-        pairs, processed, load = (
+        pairs, processed, blocks, load = (
             (now[key] - seen[key]).astype("int64")
-            for key in ("pairs", "processed", "load")
+            for key in ("pairs", "processed", "blocks", "load")
         )
         fields = {
             "layers": int(load.shape[0]),
             "held": int(load.shape[1]),
             "pairs": int(pairs.sum()),
             "dropped": int(pairs.sum() - processed.sum()),
+            "blocks": int(blocks.sum()),
+            "block_rows": int(now["block_rows"].max()),
             "load_max": int(load.max()),
             "load_mean": float(load.mean()),
         }

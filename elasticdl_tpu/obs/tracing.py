@@ -175,9 +175,11 @@ SPAN_NAMES: Dict[str, str] = {
     # expert routing (layers/moe.py): a task's counters ride on a span
     "moe.routing": "after: worker, one a task of a model with expert "
                    "layers: pairs routed to held experts, dropped (0), "
-                   "largest and mean load of a held expert, and the mean "
-                   "balancing loss where the routers have one "
-                   "(`balance_loss`)",
+                   "the expert loop's trips (`blocks`) and the rows of "
+                   "one (`block_rows`: pairs / (blocks x block_rows) is "
+                   "the blocks' fill), largest and mean load of a held "
+                   "expert, and the mean balancing loss where the routers "
+                   "have one (`balance_loss`)",
 }
 
 #: ``jax.named_scope`` names on device ops (op metadata only; they show

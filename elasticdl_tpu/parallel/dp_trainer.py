@@ -24,6 +24,7 @@ import optax
 
 from elasticdl_tpu.checkpoint.saver import tree_nbytes
 from elasticdl_tpu.common.log_utils import get_logger
+from elasticdl_tpu.layers.moe import ROUTING_COLLECTION, with_absent_counters
 from elasticdl_tpu.obs import tracing
 from elasticdl_tpu.parallel import compile as pc
 from elasticdl_tpu.parallel import sharding as shd
@@ -221,6 +222,11 @@ class DataParallelTrainer:
     @state.setter
     def state(self, value: TrainState):
         value = TrainState(*value)
+        # A checkpoint older than one of the expert layers' counters
+        # restores with it at zero: the tree the step program writes.
+        value = value._replace(
+            model_state=with_absent_counters(value.model_state)
+        )
         self._state = self._place_state(jax.device_get(value))
         self._host_step = int(np.asarray(jax.device_get(value.step)))
         if self._train_step is None:
@@ -534,6 +540,11 @@ class DataParallelTrainer:
                 leaves.append(saver.load_array(step, key, sharding))
             elif key in dense["leaves"]:
                 leaves.append(shd.put(dense["leaves"][key], sharding))
+            elif f"/{ROUTING_COLLECTION}/" in key:
+                # A counter younger than the checkpoint (layers/moe.py).
+                leaves.append(
+                    shd.put(np.zeros(leaf.shape, leaf.dtype), sharding)
+                )
             else:
                 raise KeyError(
                     f"Checkpoint at step {step} missing leaf {key} "

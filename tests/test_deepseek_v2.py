@@ -284,6 +284,156 @@ def test_shares_add_up_to_the_uncut_layer(held):
     assert _rel(_apply_moe(params, x, 8 - held, held)[0], one) < 1e-5
 
 
+# The four cells that run the layer, by one step's tokens, top-k and the
+# router's width (`perfbench/configs/*.json`), and two ends of the rule.
+@pytest.mark.parametrize("tokens,top_k,num_experts,want", [
+    pytest.param(2 * 8192, 6, 64, 512, id="deepseek-v2-lite"),
+    pytest.param(8192, 6, 128, 512, id="nemotron-3-nano"),
+    pytest.param(2 * 8192, 10, 512, 512, id="qwen3-next"),
+    pytest.param(8192, 8, 256, 256, id="laguna-xs.2"),
+    pytest.param(80, 2, 8, 128, id="never-under-128"),
+    pytest.param(65536, 8, 8, 512, id="never-over-512"),
+    pytest.param(1028, 2, 8, 512, id="257-pairs-take-one-block-of-512"),
+])
+def test_block_rows_come_from_the_shapes(tokens, top_k, num_experts, want):
+    """The smallest power of two that holds a uniform router's pairs an
+    expert (1,536, 384, 320, 256 in the four cells), within [128, 512]."""
+    assert moe.block_rows_for(tokens, top_k, num_experts) == want
+
+
+@pytest.mark.parametrize("tokens,told,want", [
+    (200, None, 128), (900, None, 256), (1100, None, 512), (2048, 16, 16),
+])
+def test_layer_takes_the_shapes_block_unless_it_is_told_one(
+    tokens, told, want
+):
+    """8 experts, 2 a token: 900 tokens are 225 pairs an expert, 1100
+    are 275; the `block_rows` counter says what the loop ran with."""
+    params = _moe_params()
+    x = jnp.asarray(
+        np.random.default_rng(0).normal(size=(tokens, MOE["hidden_size"])),
+        jnp.float32,
+    )
+    layer = _moe_layer(2, 4, told)
+    zeros = layer.init(jax.random.PRNGKey(0), x[:2])[ROUTING_COLLECTION]
+    assert int(zeros["block_rows"]) == int(zeros["blocks"]) == 0
+    y, counted = layer.apply(
+        {"params": _share(params, 2, 4), ROUTING_COLLECTION: zeros},
+        x, mutable=[ROUTING_COLLECTION],
+    )
+    counted = counted[ROUTING_COLLECTION]
+    assert int(counted["block_rows"]) == want
+    load = np.asarray(counted["load"], np.int64)
+    assert int(counted["blocks"]) == int(np.ceil(load / want).sum())
+    model = dict(MOE, experts_first=2, experts_held=4)
+    assert _rel(y, ref._experts(_share(params, 2, 4), x, model)) < 1e-5
+
+
+# Held experts 2..5 under a router that is told its choice: none, exactly
+# one block of 512 (and whole blocks of 128 and 16), more than 512, and
+# a part of any block.
+DICTATED_LOADS = (0, 512, 600, 37)
+
+
+def _dictated(loads, tokens, seed):
+    """(x [tokens, d], a router weight) such that held expert 2 + h is
+    chosen by exactly `loads[h]` tokens: x's first 8 columns are the
+    logits (chosen 2 to 2.5, an expert held elsewhere -0.5 to 0.5, a held
+    one not chosen under -2) and the router is the identity on them."""
+    rng = np.random.default_rng(seed)
+    experts, k, d = (MOE["n_routed_experts"], MOE["num_experts_per_tok"],
+                     MOE["hidden_size"])
+    logits = rng.uniform(-0.5, 0.5, size=(tokens, experts))
+    logits[:, 2:6] = -2.0 - rng.uniform(0, 0.5, size=(tokens, 4))
+    marks = np.zeros(tokens, np.int64)
+    for h, load in enumerate(loads):
+        chosen = rng.choice(np.flatnonzero(marks < k), load, replace=False)
+        logits[chosen, 2 + h] = 2.0 + rng.uniform(0, 0.5, size=load)
+        marks[chosen] += 1
+    x = rng.normal(size=(tokens, d))
+    x[:, :experts] = logits
+    return jnp.asarray(x, jnp.float32), jnp.eye(d, experts, dtype=jnp.float32)
+
+
+@pytest.fixture(scope="module")
+def dictated():
+    """The layer's output, counters and gradients (held parameters, the
+    router among them, and x) at blocks of 16, 128 and 512, and the
+    reference's, under `DICTATED_LOADS`."""
+    x, router = _dictated(DICTATED_LOADS, 700, 7)
+    share = dict(_share(_moe_params(5), 2, 4), gate=router)
+    model = dict(MOE, experts_first=2, experts_held=4)
+    weight = jnp.asarray(
+        np.random.default_rng(8).normal(size=x.shape), jnp.float32
+    )
+
+    def run(block_rows):
+        layer = _moe_layer(2, 4, block_rows)
+        zeros = layer.init(jax.random.PRNGKey(0), x[:2])[ROUTING_COLLECTION]
+
+        def loss(p, x):
+            y, counted = layer.apply(
+                {"params": p, ROUTING_COLLECTION: zeros}, x,
+                mutable=[ROUTING_COLLECTION],
+            )
+            return jnp.sum(weight * y), (y, counted[ROUTING_COLLECTION])
+
+        grads, (y, counted) = jax.grad(loss, (0, 1), has_aux=True)(share, x)
+        return y, counted, grads
+
+    def reference(p, x):
+        return jnp.sum(weight * ref._experts(p, x, model))
+
+    return (
+        {block: run(block) for block in (16, 128, 512)},
+        ref._experts(share, x, model),
+        jax.grad(reference, (0, 1))(share, x),
+    )
+
+
+@pytest.mark.parametrize("block_rows", [16, 128, 512])
+def test_any_block_gives_the_references_output_and_gradients(
+    dictated, block_rows
+):
+    """Output, dx, the three weights' gradients and the pair weights'
+    (which reach the router) do not depend on the block: each block's are
+    the dense reference's, and the blocks' own agree closer still."""
+    runs, want_y, want_grads = dictated
+    y, _, grads = runs[block_rows]
+    assert _rel(y, want_y) < 1e-5
+    assert _rel(y, runs[16][0]) < 1e-6
+    flat = jax.tree_util.tree_leaves_with_path(grads)
+    for (path, g), w, g16 in zip(
+        flat, jax.tree.leaves(want_grads), jax.tree.leaves(runs[16][2])
+    ):
+        scale = float(jnp.abs(w).max())
+        assert scale > 0, jax.tree_util.keystr(path)
+        assert float(jnp.abs(g - w).max()) < 1e-4 * scale, (
+            jax.tree_util.keystr(path)
+        )
+        assert float(jnp.abs(g - g16).max()) < 1e-5 * scale
+    # the expert of no rows has no gradient, whatever the block
+    assert float(jnp.abs(grads[0]["experts_up_proj"][0]).max()) == 0.0
+
+
+@pytest.mark.parametrize("block_rows", [16, 128, 512])
+def test_no_pair_dropped_and_blocks_counted_under_dictated_loads(
+    dictated, block_rows
+):
+    _, counted, _ = dictated[0][block_rows]
+    loads = np.asarray(DICTATED_LOADS)
+    np.testing.assert_array_equal(np.asarray(counted["load"]), loads)
+    assert int(counted["pairs"]) == int(counted["processed"]) == loads.sum()
+    assert int(counted["blocks"]) == int(np.ceil(loads / block_rows).sum())
+    assert int(counted["block_rows"]) == block_rows
+    fields = RoutingLedger().task_delta(
+        {ROUTING_COLLECTION: {"layers_1": {"mlp": counted}}}
+    )
+    assert fields["dropped"] == 0 and fields["pairs"] == loads.sum()
+    assert fields["blocks"] == int(np.ceil(loads / block_rows).sum())
+    assert fields["block_rows"] == block_rows
+
+
 def test_weights_are_the_softmax_at_the_chosen_not_renormalised():
     """`norm_topk_prob: false`: a token's routing weights are p at its
     top-k and sum to less than 1; renormalised they would sum to 1."""

@@ -562,14 +562,22 @@ def test_selection_bias_changes_the_choice_and_not_the_weights():
     assert _rel(got, ref._experts(_share(biased, 5, 1), x, model)) < 1e-5
 
 
-@pytest.mark.parametrize("block_rows", [128, 16])
-def test_no_pair_dropped_and_counters_right_under_a_skewed_router(block_rows):
+@pytest.mark.parametrize("block_rows,tokens", [
+    (128, 300), (16, 300), (512, 300), (512, 600), (None, 300),
+])
+def test_no_pair_dropped_and_counters_right_under_a_skewed_router(
+    block_rows, tokens
+):
     """Every token's first choice is ONE held expert (held range 2..5,
-    expert 3): 300 pairs on one expert, more than two blocks of 128."""
+    expert 3): 300 pairs on one expert, more than two blocks of 128 and
+    less than one of 512; 600, more than one of 512.  Told no block, the
+    layer takes the shapes' (300 x 2 / 8 = 75 pairs an expert: 128)."""
     params = jax.tree.map(lambda a: a, _moe_params(1))
     rng = np.random.default_rng(2)
-    x = jnp.asarray(np.abs(rng.normal(size=(300, MOE["hidden_size"]))) + 0.1,
-                    jnp.float32)
+    x = jnp.asarray(
+        np.abs(rng.normal(size=(tokens, MOE["hidden_size"]))) + 0.1,
+        jnp.float32,
+    )
     params["gate"] = dict(
         params["gate"], weight=params["gate"]["weight"].at[:, 3].set(4.0)
     )
@@ -580,9 +588,12 @@ def test_no_pair_dropped_and_counters_right_under_a_skewed_router(block_rows):
     _, ids = jax.lax.top_k(jax.nn.sigmoid(x @ params["gate"]["weight"]), 2)
     assert bool(jnp.all(jnp.any(ids == 3, axis=-1)))
     load = np.bincount(np.asarray(ids).ravel(), minlength=8)[2:6]
-    assert load[1] == 300
+    assert load[1] == tokens
     np.testing.assert_array_equal(np.asarray(counters["load"]), load)
     assert int(counters["pairs"]) == int(counters["processed"]) == load.sum()
+    block = block_rows or 128
+    blocks = int(np.ceil(load / block).sum())
+    assert int(counters["blocks"]) == blocks
     ledger = RoutingLedger()
     ledger.seed_once({})
     fields = ledger.task_delta(
@@ -590,21 +601,33 @@ def test_no_pair_dropped_and_counters_right_under_a_skewed_router(block_rows):
     )
     assert fields == {
         "layers": 1, "held": 4, "pairs": int(load.sum()), "dropped": 0,
-        "load_max": 300, "load_mean": float(load.mean()),
+        "blocks": blocks, "block_rows": block,
+        "load_max": tokens, "load_mean": float(load.mean()),
     }
 
 
-def test_expert_layer_gradients_match_the_reference():
+@pytest.mark.parametrize("block_rows,tokens", [
+    (32, 150), (16, 700), (128, 700), (512, 700),
+])
+def test_expert_layer_gradients_match_the_reference(block_rows, tokens):
     """The hand-written backward's two-product form (and the router's
-    through the renormalised, scaled sigmoid scores)."""
-    params = _moe_params(3)
+    through the renormalised, scaled sigmoid scores), whatever the block:
+    700 tokens and a router column that makes held expert 3 every
+    token's choice give one expert more than a block of 512 and the
+    others a part of one."""
+    params = jax.tree.map(lambda a: a, _moe_params(3))
     x = jnp.asarray(
-        np.random.default_rng(3).normal(size=(150, MOE["hidden_size"])),
+        np.random.default_rng(3).normal(size=(tokens, MOE["hidden_size"])),
         jnp.float32,
     )
+    if tokens == 700:
+        x = jnp.abs(x) + 0.1
+        params["gate"] = dict(
+            params["gate"], weight=params["gate"]["weight"].at[:, 3].set(0.5)
+        )
     model = dict(MOE, experts_first=2, experts_held=4)
     share = _share(params, 2, 4)
-    layer = _moe_layer(2, 4, 32)
+    layer = _moe_layer(2, 4, block_rows)
     zeros = layer.init(jax.random.PRNGKey(0), x[:2])[ROUTING_COLLECTION]
     weight = jnp.asarray(
         np.random.default_rng(4).normal(size=x.shape), jnp.float32
@@ -782,6 +805,9 @@ def test_trainer_carries_the_counters_and_checkpoint_restores_the_logits(
     assert fields["layers"] == 4 and fields["dropped"] == 0
     # three steps of 4 x 64 tokens, two choices each, four expert layers
     assert 0 < fields["pairs"] < 3 * 4 * 64 * 2 * 4
+    # 64 pairs an expert a step: blocks of 128, at most one an expert
+    assert fields["block_rows"] == 128
+    assert 3 * 4 <= fields["blocks"] <= 3 * 4 * 4
     # the selection bias took three steps of the balancing rule, each
     # +-1e-3 (or 0 for an expert at the mean), and none of AdamW
     gate = trainer.state.params["backbone"]["layers_1"]["mixer"]["gate"]

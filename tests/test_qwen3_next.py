@@ -1083,12 +1083,41 @@ def test_no_pair_dropped_and_counters_right_under_a_skewed_router(block_rows):
     ledger = RoutingLedger()
     ledger.seed_once({})
     fields = ledger.task_delta({ROUTING_COLLECTION: {"layers_0": {"mlp": counters}}})
+    blocks = int(np.ceil(load / block_rows).sum())
+    assert int(counters["blocks"]) == blocks
     assert fields == {
         "layers": 1, "held": 4, "pairs": int(load.sum()), "dropped": 0,
+        "blocks": blocks, "block_rows": block_rows,
         "load_max": 300, "load_mean": float(load.mean()),
     }
     again = ledger.task_delta({ROUTING_COLLECTION: {"layers_0": {"mlp": counters}}})
     assert again["pairs"] == 0 and again["load_max"] == 0
+    assert again["blocks"] == 0 and again["block_rows"] == block_rows
+
+
+@pytest.mark.parametrize("counter", ["blocks", "pairs"])
+def test_task_delta_is_right_across_a_uint32_wrap(counter):
+    """The counters are cumulative uint32 sums: a task whose reading has
+    wrapped past 2**32 still reads its own share."""
+    def state(pairs, blocks):
+        layer = {
+            "pairs": np.uint32(pairs), "processed": np.uint32(pairs),
+            "blocks": np.uint32(blocks), "block_rows": np.uint32(256),
+            "load": np.asarray([pairs, 0], np.uint32),
+        }
+        return {ROUTING_COLLECTION: {"layers_0": {"mlp": layer}}}
+
+    near = 2 ** 32 - 2
+    before = dict(pairs=1000, blocks=10)
+    after = dict(pairs=1600, blocks=13)
+    before[counter] = near
+    after[counter] = (near + {"pairs": 600, "blocks": 3}[counter]) % 2 ** 32
+    assert after[counter] < before[counter]  # it wrapped
+    ledger = RoutingLedger()
+    ledger.seed_once(state(**before))
+    fields = ledger.task_delta(state(**after))
+    assert fields["pairs"] == 600 and fields["blocks"] == 3
+    assert fields["dropped"] == 0 and fields["block_rows"] == 256
 
 
 def test_expert_layer_gradients_match_the_reference():
@@ -1171,6 +1200,100 @@ def test_trainer_carries_the_counters_and_checkpoint_restores_the_logits(
     assert _rel(before, want) < 5e-5
 
 
+def test_checkpoint_older_than_the_block_counters_restores_them_at_zero(
+    tmp_path,
+):
+    """A `routing` collection saved before the layer counted `blocks` and
+    `block_rows` (ISSUE 40) restores with both at zero and the counters it
+    had as they were; the restored trainer trains on and counts."""
+    from elasticdl_tpu.checkpoint import CheckpointSaver
+    from flax.traverse_util import flatten_dict, unflatten_dict
+
+    trainer, model = _trainer()
+    tokens = ref.sample(11, 4, model)
+    trainer.train_step(tokens, tokens)
+    state = trainer.state_to_host()
+    flat = flatten_dict(state.model_state)
+    assert sum(path[-1] == "blocks" for path in flat) == 4
+    older = unflatten_dict({
+        path: leaf for path, leaf in flat.items()
+        if path[-1] not in ("blocks", "block_rows")
+    })
+    CheckpointSaver(str(tmp_path)).save(state._replace(model_state=older), 1)
+    restored, _ = CheckpointSaver(str(tmp_path)).load_latest()
+    assert not any(
+        path[-1] == "blocks" for path in flatten_dict(restored.model_state)
+    )
+    fresh, _ = _trainer()
+    fresh.state = restored
+    got = flatten_dict(jax.device_get(fresh.state.model_state))
+    for path, leaf in got.items():
+        if path[-1] in ("blocks", "block_rows"):
+            assert leaf.dtype == np.uint32 and int(leaf) == 0
+        else:
+            np.testing.assert_array_equal(leaf, flat[path])
+    ledger = RoutingLedger()
+    ledger.seed_once(fresh.state.model_state)
+    assert np.isfinite(float(fresh.train_step(tokens, tokens)))
+    fields = ledger.task_delta(fresh.state.model_state)
+    assert fields["blocks"] > 0 and fields["block_rows"] == 128
+    assert fields["dropped"] == 0 and fields["pairs"] > 0
+
+
+def test_sharded_restore_starts_an_absent_block_counter_at_zero(tmp_path):
+    """The restore by the template's leaf keys: a `routing` counter the
+    checkpoint's writer did not keep yet starts at zero; any other absent
+    leaf is still an error."""
+    import pickle
+
+    from elasticdl_tpu.checkpoint import ShardedCheckpointSaver
+    from flax.traverse_util import flatten_dict
+
+    trainer, model = _trainer()
+    tokens = ref.sample(11, 4, model)
+    trainer.train_step(tokens, tokens)
+    saver = ShardedCheckpointSaver(str(tmp_path))
+    trainer.save_checkpoint(saver, 1)
+    dense_path = tmp_path / "step_000000000001" / "dense.pkl"
+    with open(dense_path, "rb") as f:
+        dense = pickle.load(f)
+    younger = [
+        key for key in dense["leaves"]
+        if key.endswith(("/blocks", "/block_rows"))
+    ]
+    assert len(younger) == 8 and all("/routing/" in key for key in younger)
+
+    def rewrite(without):
+        with open(dense_path, "wb") as f:
+            pickle.dump(dict(dense, leaves={
+                key: leaf for key, leaf in dense["leaves"].items()
+                if key not in without
+            }), f)
+
+    rewrite(younger)
+    fresh, _ = _trainer()
+    fresh.set_sharded_restore(ShardedCheckpointSaver(str(tmp_path)), 1)
+    fresh.ensure_initialized(tokens)
+    routing = jax.device_get(fresh.state.model_state[ROUTING_COLLECTION])
+    kept = jax.device_get(trainer.state.model_state[ROUTING_COLLECTION])
+    for got, want in zip(jax.tree.leaves(routing), jax.tree.leaves(kept)):
+        assert got.dtype == want.dtype and got.shape == want.shape
+    routing, kept = flatten_dict(routing), flatten_dict(kept)
+    for path, leaf in routing.items():
+        if path[-1] in ("blocks", "block_rows"):
+            assert int(leaf) == 0 and int(kept[path]) > 0
+        else:
+            np.testing.assert_array_equal(leaf, kept[path])
+    assert np.isfinite(float(fresh.train_step(tokens, tokens)))
+    rewrite(younger + [next(
+        key for key in dense["leaves"] if "/routing/" not in key
+    )])
+    broken, _ = _trainer()
+    broken.set_sharded_restore(ShardedCheckpointSaver(str(tmp_path)), 1)
+    with pytest.raises(KeyError, match="missing leaf"):
+        broken.ensure_initialized(tokens)
+
+
 def test_two_task_elasticdl_train_end_to_end(tmp_path):
     """`elasticdl train` as a user runs it: master, task dispatch, one
     collective worker, a cadence checkpoint, `moe.routing` a task."""
@@ -1210,6 +1333,12 @@ def test_two_task_elasticdl_train_end_to_end(tmp_path):
     assert [e["step"] for e in routing] == [2, 4]
     assert all(e["dropped"] == 0 and e["pairs"] > 0 for e in routing)
     assert all(e["load_max"] >= e["load_mean"] > 0 for e in routing)
+    # 4 x 64 tokens, 2 of 8 experts each: 64 pairs an expert, blocks of 128
+    assert all(e["block_rows"] == 128 for e in routing)
+    assert all(
+        e["blocks"] * e["block_rows"] >= e["pairs"] and e["blocks"] > 0
+        for e in routing
+    )
 
 
 def test_benchmark_cost_functions_count_what_they_say():

@@ -182,6 +182,11 @@ def test_every_device_scope_and_start_up_span_names_its_reader():
         assert os.path.exists(os.path.join(
             REPO_ROOT, "perfbench", "metrics", name + ".json"))
     span_table = perf[perf.index("| span / family |"):perf.index("## 4. Cells")]
+    # the expert loop's trips and the rows of one (ISSUE 40)
+    for field in ("blocks", "block_rows"):
+        assert f"`{field}`" in tracing.SPAN_NAMES["moe.routing"]
+        assert f"`{field}`" in docs[docs.index("`moe.routing` is journaled"):]
+        assert f"`{field}`" in span_table[span_table.index("| `moe.routing`"):]
     assert "since_main_s" not in span_table.replace(
         "`since_main_s` gone", "")
     assert "`since_main_s`" not in docs

@@ -380,6 +380,42 @@ def test_hybrid_sublayer_compiles_and_fits_for_v5e(topo, kind, monkeypatch):
         assert (kernel in compiled.as_text()) == (kind == "gdn_pallas")
 
 
+def test_expert_layer_compiles_at_the_shapes_block_for_v5e(topo):
+    """`deepseek-v2-lite.train-synth-8k`'s expert layer (8 of 64 experts
+    of width 1408, top-6, 2 x 8192 tokens: 1,536 pairs an expert) told
+    no block: the loop's body gathers blocks of 512 rows, rematerialised
+    as the cell runs it, within 1 GB of temporaries."""
+    from elasticdl_tpu.layers.moe import SparseMoeBlock, block_rows_for
+
+    module = SparseMoeBlock(
+        64, 6, 1408, 2816, (0, 8), False, jnp.bfloat16, shared_gated=False
+    )
+    tokens = (2, 8192, 2048)
+    assert block_rows_for(2 * 8192, 6, 64) == 512
+    variables = jax.eval_shape(lambda: module.init(
+        jax.random.PRNGKey(0), jnp.zeros(tokens, jnp.float32)
+    ))
+
+    def fwd_bwd(variables, x):
+        @jax.checkpoint
+        def total(params, x):
+            return jnp.sum(module.apply({**variables, "params": params}, x))
+
+        return jax.grad(total, argnums=(0, 1))(variables["params"], x)
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    compiled = jax.jit(fwd_bwd).lower(
+        jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+            variables,
+        ),
+        jax.ShapeDtypeStruct(tokens, jnp.float32, sharding=one_chip),
+    ).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.0e9
+    text = compiled.as_text()
+    assert "bf16[512,2048]" in text and "bf16[128,2048]" not in text
+
+
 def test_qwen3_next_window_program_compiles_and_fits_for_v5e(
     topo, monkeypatch
 ):
