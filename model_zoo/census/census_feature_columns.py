@@ -119,13 +119,13 @@ def dataset_fn(dataset, mode, metadata):
 
 
 def eval_metrics_fn():
-    from model_zoo.wide_and_deep.wide_and_deep import _auc
+    from model_zoo.metrics import auc
 
     return {
         "accuracy": lambda outputs, labels: np.mean(
             (outputs > 0).astype(np.int64) == labels.astype(np.int64)
         ),
-        "auc": _auc,
+        "auc": auc,
     }
 
 

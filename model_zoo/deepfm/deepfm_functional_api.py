@@ -251,13 +251,13 @@ def columnar_dataset_fn(columns, mode, metadata, seed: int = 0):
 
 
 def eval_metrics_fn():
-    from model_zoo.wide_and_deep.wide_and_deep import _auc
+    from model_zoo.metrics import auc
 
     return {
         "accuracy": lambda outputs, labels: np.mean(
             (outputs > 0).astype(np.int64) == labels.astype(np.int64)
         ),
-        "auc": _auc,
+        "auc": auc,
     }
 
 

@@ -79,48 +79,22 @@ layer); `moe` > `moe_route`, `moe_experts`, `moe_shared`;
 from __future__ import annotations
 
 import dataclasses
-from functools import partial
 from typing import Any
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
-import optax
 
 from elasticdl_tpu.layers.moe import GatedMLP, SparseMoeBlock
 from elasticdl_tpu.ops import gqa
-# The rest of the zoo contract is that of any causal LM on
-# `synthetic://lm` data: mean next-token cross-entropy over float32
-# logits (under the `lm_head_loss` scope), perplexity and accuracy.
-from model_zoo.transformer.transformer_lm import (  # noqa: F401
-    VOCAB, custom_data_reader, dataset_fn, eval_metrics_fn, loss,
+# The norm, the projection, the optimizer's warm-up and the rest of the zoo
+# contract of any causal LM on `synthetic://lm` data: mean next-token
+# cross-entropy over float32 logits (under the `lm_head_loss` scope),
+# perplexity and accuracy.
+from model_zoo.lm_common import (  # noqa: F401
+    VOCAB, RMSNorm, custom_data_reader, dataset_fn, dense, eval_metrics_fn,
+    loss, warmup_adamw,
 )
-
-
-def _dense(features, dtype, name):
-    """A projection without bias, operands in `dtype`, a float32 result."""
-    return nn.Dense(
-        features, use_bias=False, dtype=dtype, name=name,
-        dot_general=partial(
-            jax.lax.dot_general, preferred_element_type=jnp.float32
-        ),
-    )
-
-
-class RMSNorm(nn.Module):
-    """y = w x rsqrt(mean(x^2) + eps), w from 1; float32."""
-
-    eps: float = 1e-6
-
-    @nn.compact
-    def __call__(self, x):
-        weight = self.param(
-            "weight", nn.initializers.ones_init(), (x.shape[-1],), jnp.float32
-        )
-        x = x.astype(jnp.float32)
-        return weight * x * jax.lax.rsqrt(
-            jnp.mean(x * x, axis=-1, keepdims=True) + self.eps
-        )
 
 
 def softmax_scale(cfg) -> float:
@@ -158,18 +132,18 @@ class LatentAttention(nn.Module):
         b, t, d = x.shape
         h, nope, rope, dv = (c.num_attention_heads, c.qk_nope_head_dim,
                              c.qk_rope_head_dim, c.v_head_dim)
-        q = _dense(h * (nope + rope), c.dtype, "q_proj")(x).reshape(
+        q = dense(h * (nope + rope), c.dtype, "q_proj")(x).reshape(
             b, t, h, nope + rope
         )
         with jax.named_scope("mla_latent"):
-            latent = _dense(
+            latent = dense(
                 c.kv_lora_rank + rope, c.dtype, "kv_a_proj_with_mqa"
             )(x)
             k_pe = latent[..., c.kv_lora_rank:].reshape(b, t, 1, rope)
             latent = RMSNorm(c.rms_norm_eps, name="kv_a_layernorm")(
                 latent[..., :c.kv_lora_rank]
             )
-            kv = _dense(h * (nope + dv), c.dtype, "kv_b_proj")(latent).reshape(
+            kv = dense(h * (nope + dv), c.dtype, "kv_b_proj")(latent).reshape(
                 b, t, h, nope + dv
             )
             cos, sin = _rotary_tables(c, t)
@@ -190,7 +164,7 @@ class LatentAttention(nn.Module):
             out = gqa.causal_attention(
                 q, k, v, scale=softmax_scale(c), impl=c.attn_impl
             )
-        return _dense(d, c.dtype, "o_proj")(
+        return dense(d, c.dtype, "o_proj")(
             out.reshape(b, t, h * dv).astype(c.dtype)
         )
 
@@ -321,7 +295,4 @@ def optimizer(lr: float = 4.2e-4, warmup_steps: int = 2000):
     to `lr` over the first `warmup_steps` steps (step n of them runs at
     lr n / warmup_steps) and stays, as a pre-training job's first steps
     run."""
-    return optax.adamw(
-        lambda count: lr * jnp.minimum(1.0, (count + 1) / warmup_steps),
-        b1=0.9, b2=0.95, weight_decay=0.1,
-    )
+    return warmup_adamw(lr, warmup_steps, b1=0.9, b2=0.95, weight_decay=0.1)

@@ -21,14 +21,14 @@ the convolution's.  `layer_types[i]` says what mixer layer i has, and the
 stack is built from the list's first `num_hidden_layers` entries (the
 published 40 and a cut of 10 are the same code):
 
-- `mamba` (`model_zoo/nemotron_h` `Mamba2Mixer`, imported): `[z | xBC |
+- `mamba` (`model_zoo/lm_common.py` `Mamba2Mixer`): `[z | xBC |
   dt] = in_proj(u)`, the causal depthwise convolution with its bias and
   silu over `xBC`, `dt = softplus(dt + dt_bias)`, `A = -exp(A_log)`, the
   selective state-space recurrence of `ops/ssd.py` in chunks of
   `mamba_chunk_size` with the `mamba_n_heads` heads in `mamba_n_groups`
   groups that share B and C, the skip `D x`, the gated RMSNorm over each
   group of the inner width, `out_proj`.
-- `attention` (`model_zoo/nemotron_h` `Attention`, imported):
+- `attention` (`model_zoo/lm_common.py` `Attention`):
   grouped-query heads, NO position embedding (`position_embedding_type:
   "nope"`), causal softmax of the scores times m_a, NOT 1/sqrt(head_dim)
   (0.015625 = 1/64 where sqrt(64) would give 1/8).
@@ -48,7 +48,7 @@ Module and parameter names follow the source's: `model` holding
 `post_attention_layernorm`, `shared_mlp` with `input_linear` and
 `output_linear`) and `norm`; no `lm_head`.  Kernels in flax's [in, out]
 layout; the Mamba-2 mixer's and the attention's parameters as
-`model_zoo/nemotron_h` names them.
+`model_zoo/lm_common.py` names them.
 
 Assumed where the source's `config.json` is silent, each also in the
 configuration's `assumed`: the Mamba-2 initialisation is `Mamba2Mixer`'s
@@ -81,19 +81,17 @@ from typing import Any
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
-import optax
 
-# The two mixers, the norm and the projection are Nemotron-H's stack's:
-# the same Mamba-2 layer at another shape (one group, chunks of 256) and
-# the same attention without a position embedding, under a caller's scale.
-from model_zoo.nemotron_h.nemotron_h_lm import (
-    Attention, Mamba2Mixer, RMSNorm, _dense,
-)
-# The rest of the zoo contract is that of any causal LM on
-# `synthetic://lm` data: mean next-token cross-entropy over float32
-# logits (under the `lm_head_loss` scope), perplexity and accuracy.
-from model_zoo.transformer.transformer_lm import (  # noqa: F401
-    VOCAB, custom_data_reader, dataset_fn, eval_metrics_fn, loss,
+# The two mixers are the ones Nemotron-H's stack runs too: the same
+# Mamba-2 layer at another shape (one group, chunks of 256) and the same
+# attention without a position embedding, under a caller's scale.  With
+# them the norm, the projection, the optimizer's warm-up and the rest of
+# the zoo contract of any causal LM on `synthetic://lm` data: mean
+# next-token cross-entropy over float32 logits (under the `lm_head_loss`
+# scope), perplexity and accuracy.
+from model_zoo.lm_common import (  # noqa: F401
+    VOCAB, Attention, Mamba2Mixer, RMSNorm, custom_data_reader, dataset_fn,
+    dense, eval_metrics_fn, loss, warmup_adamw,
 )
 
 MAMBA, ATTENTION = "mamba", "attention"
@@ -137,9 +135,9 @@ class SharedMLP(nn.Module):
     @nn.compact
     def __call__(self, h):
         gate, up = jnp.split(
-            _dense(2 * self.width, self.dtype, "input_linear")(h), 2, axis=-1
+            dense(2 * self.width, self.dtype, "input_linear")(h), 2, axis=-1
         )
-        return _dense(h.shape[-1], self.dtype, "output_linear")(
+        return dense(h.shape[-1], self.dtype, "output_linear")(
             (nn.silu(gate) * up).astype(self.dtype)
         )
 
@@ -248,7 +246,4 @@ def optimizer(lr: float = 3e-4, warmup_steps: int = 2000):
     `warmup_steps` steps (step n of them runs at lr n / warmup_steps) and
     stays, as a pre-training job's first steps run and as the zoo's other
     8k stacks do; weight decay 0.01.  No router, so no balancing rule."""
-    return optax.adamw(
-        lambda count: lr * jnp.minimum(1.0, (count + 1) / warmup_steps),
-        weight_decay=0.01,
-    )
+    return warmup_adamw(lr, warmup_steps, weight_decay=0.01)

@@ -57,8 +57,8 @@ configuration's `assumed`: the gate's form (per head, as the sibling
 `Laguna-S-2.1` names it); sigmoid scores, renormalised; no query/key
 norm; the window holds `sliding_window` keys INCLUDING the query's own;
 rotary columns in the half-split order `apply_rotary` reads; the
-balancing rule and the warm-up of `optimizer` (model_zoo/nemotron_h's:
-the same router).  The residual stream is float32.
+balancing rule and the warm-up of `optimizer` (`lm_common.balancing_adamw`,
+Nemotron-H's too: the same router).  The residual stream is float32.
 
 Precision: parameters float32; with `use_bf16` the four attention
 projections, scores and values, the dense layer, the expert products and
@@ -85,17 +85,15 @@ import jax.numpy as jnp
 
 from elasticdl_tpu.layers.moe import GatedMLP, SparseMoeBlock
 from elasticdl_tpu.ops import gqa
-# The norm and the projection are DeepSeek-V2's stack's (the same plain
-# RMSNorm, the same bias-free dense with a float32 result); the optimizer
-# is Nemotron-H's (the same sigmoid router: AdamW under a warm-up, the
-# selection biases moved by the balancing rule).
-from model_zoo.deepseek_v2.deepseek_v2_lm import RMSNorm, _dense
-from model_zoo.nemotron_h.nemotron_h_lm import optimizer  # noqa: F401
-# The rest of the zoo contract is that of any causal LM on
-# `synthetic://lm` data: mean next-token cross-entropy over float32
-# logits (under the `lm_head_loss` scope), perplexity and accuracy.
-from model_zoo.transformer.transformer_lm import (  # noqa: F401
-    VOCAB, custom_data_reader, dataset_fn, eval_metrics_fn, loss,
+# The norm, the projection, and the rest of the zoo contract of any causal
+# LM on `synthetic://lm` data: mean next-token cross-entropy over float32
+# logits (under the `lm_head_loss` scope), perplexity and accuracy.  The
+# optimizer is that of a stack behind the sigmoid router (Nemotron-H's
+# too): AdamW under a warm-up, the selection biases moved by the balancing
+# rule.
+from model_zoo.lm_common import (  # noqa: F401
+    VOCAB, RMSNorm, balancing_adamw as optimizer, custom_data_reader,
+    dataset_fn, dense, eval_metrics_fn, loss,
 )
 
 FULL, SLIDING = "full_attention", "sliding_attention"
@@ -113,7 +111,7 @@ class Attention(nn.Module):
         b, t, d = x.shape
         h, hkv, hd = self.heads, c.num_key_value_heads, c.head_dim
         q, k, v = (
-            _dense(n * hd, c.dtype, name)(x).reshape(b, t, n, hd)
+            dense(n * hd, c.dtype, name)(x).reshape(b, t, n, hd)
             for name, n in (("q_proj", h), ("k_proj", hkv), ("v_proj", hkv))
         )
         q = gqa.apply_rotary(q, cos, sin).astype(c.dtype)
@@ -134,7 +132,7 @@ class Attention(nn.Module):
                     precision=jax.lax.Precision.HIGHEST,
                 ))
                 out = out.astype(jnp.float32) * gate[..., None]
-        return _dense(d, c.dtype, "o_proj")(
+        return dense(d, c.dtype, "o_proj")(
             out.reshape(b, t, h * hd).astype(c.dtype)
         )
 

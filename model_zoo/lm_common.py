@@ -1,0 +1,283 @@
+"""What the zoo's causal-LM stacks share, defined once.
+
+A language model of the zoo is a STACK (`model_zoo/<m>/<m>_lm.py`: the
+modules whose names and layouts follow the model's source) over the pieces
+here.  A stack imports from this module and from `elasticdl_tpu` only,
+never from another model's directory (tests/test_zoo.py holds that), so an
+edit to a stack meets that model's cells and no other; an edit HERE meets
+every cell whose stack uses the piece:
+
+- the causal-LM zoo contract on `synthetic://lm` data (`VOCAB`, `SEQ_LEN`,
+  `custom_data_reader`, `dataset_fn`, `eval_metrics_fn`, `loss`): all six
+  stacks (`gpt2-medium`, `qwen3-next`, `nemotron3-nano`, `deepseek-v2-lite`,
+  `laguna-xs2`, `granite4-h-micro`);
+- `dense`, the bias-free projection with a float32 result: the five 8k
+  stacks; `RMSNorm` (weight from 1): all of them but Qwen3-Next, whose
+  zero-centred norm is another function and stays in its file;
+- `warmup_adamw`: DeepSeek-V2 and Granite; `balancing_adamw`, which wraps
+  it with the rule that moves a sigmoid router's selection biases:
+  Nemotron-H and Laguna, the two stacks behind that router;
+- `Mamba2Mixer` (with `_Conv1d`, `_dt_bias_init`) and the position-free
+  `Attention`: Nemotron-H and Granite 4.0-H.
+
+This module imports no stack and no ring attention: importing an 8k stack
+brings neither `transformer_lm` nor `parallel/ring_attention.py` with it.
+"""
+
+from __future__ import annotations
+
+from functools import partial
+from typing import Any
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+
+from elasticdl_tpu.ops import gqa
+from elasticdl_tpu.ops.ssd import ssd_chunked
+from model_zoo import datasets
+
+VOCAB = 256
+SEQ_LEN = 128
+
+
+# -- the zoo contract of a causal LM on `synthetic://lm` data ---------------
+
+def loss(labels, predictions):
+    """Mean next-token cross-entropy; labels [B, T], logits [B, T, V]."""
+    with jax.named_scope("lm_head_loss"):
+        return optax.softmax_cross_entropy_with_integer_labels(
+            predictions.astype(jnp.float32), labels.astype(jnp.int32)
+        ).mean()
+
+
+def dataset_fn(dataset, mode, metadata):
+    def parse(record):
+        tokens, next_tokens = record
+        return np.asarray(tokens, np.int32), np.asarray(
+            next_tokens, np.int32
+        )
+
+    dataset = dataset.map(parse)
+    if mode == "training":
+        dataset = dataset.shuffle(1024, seed=0)
+    return dataset
+
+
+def eval_metrics_fn():
+    def perplexity(outputs, labels):
+        ce = float(loss(jnp.asarray(labels), jnp.asarray(outputs)))
+        return float(np.exp(min(ce, 20.0)))
+
+    return {
+        "perplexity": perplexity,
+        "accuracy": lambda outputs, labels: float(
+            np.mean(np.argmax(outputs, axis=-1) == labels)
+        ),
+    }
+
+
+def custom_data_reader(data_path: str, **kwargs):
+    name, params = datasets.parse_synthetic_path(data_path)
+    if name != "lm":
+        return None
+    return datasets.synthetic_lm_reader(
+        n=params.get("n", 2048),
+        seq_len=params.get("len", SEQ_LEN),
+        vocab=params.get("vocab", VOCAB),
+        seed=params.get("seed", 0),
+    )
+
+
+def warmup_adamw(lr: float, warmup_steps: int, **adamw):
+    """AdamW whose rate rises linearly to `lr` over the first
+    `warmup_steps` steps (step n of them runs at lr n / warmup_steps) and
+    stays, as a pre-training job's first steps run.  `adamw`: optax's own
+    (`b1`, `b2`, `weight_decay`, ...)."""
+    return optax.adamw(
+        lambda count: lr * jnp.minimum(1.0, (count + 1) / warmup_steps),
+        **adamw,
+    )
+
+
+SELECTION_BIAS = "e_score_correction_bias"
+
+
+def balancing_adamw(lr: float = 3e-4, warmup_steps: int = 2000,
+                    bias_update_rate: float = 1e-3):
+    """The `optimizer` of a stack behind `layers/moe.py`'s sigmoid router
+    (Nemotron-H, Laguna): `warmup_adamw` at weight decay 0.01, and for the
+    routers' selection biases alone the balancing rule in its place.  The
+    bias takes part in no gradient (a selection is not differentiated),
+    `layers/moe.py` hands it `sign(times chosen - mean)` over all experts
+    instead, and plain descent at `bias_update_rate` on that, no moments,
+    no decay, is auxiliary-loss-free balancing (arXiv:2408.15664)."""
+    return optax.multi_transform(
+        {
+            "adamw": warmup_adamw(lr, warmup_steps, weight_decay=0.01),
+            "balance": optax.sgd(bias_update_rate),
+        },
+        lambda params: jax.tree_util.tree_map_with_path(
+            lambda path, _: "balance"
+            if getattr(path[-1], "key", None) == SELECTION_BIAS else "adamw",
+            params,
+        ),
+    )
+
+
+# -- blocks -----------------------------------------------------------------
+
+def dense(features, dtype, name, kernel_init=nn.initializers.lecun_normal()):
+    """A projection without bias, operands in `dtype`, a float32 result:
+    what the MXU accumulates is not rounded again on the way out (a
+    bfloat16 result carries 2^-9 of rounding into a delta rule or a scan,
+    which amplifies it; the operands' rounding averages out over the
+    dot)."""
+    return nn.Dense(
+        features, use_bias=False, dtype=dtype, name=name,
+        kernel_init=kernel_init,
+        dot_general=partial(
+            jax.lax.dot_general, preferred_element_type=jnp.float32
+        ),
+    )
+
+
+class RMSNorm(nn.Module):
+    """y = w x rsqrt(mean(x^2) + eps), w from 1; float32."""
+
+    eps: float
+
+    @nn.compact
+    def __call__(self, x):
+        weight = self.param(
+            "weight", nn.initializers.ones_init(), (x.shape[-1],), jnp.float32,
+        )
+        x = x.astype(jnp.float32)
+        return weight * x * jax.lax.rsqrt(
+            jnp.mean(x * x, axis=-1, keepdims=True) + self.eps
+        )
+
+
+class _Conv1d(nn.Module):
+    """The source's depthwise `conv1d`: `kernel` [taps, channels], `bias`."""
+
+    taps: int
+
+    @nn.compact
+    def __call__(self, channels: int):
+        return (
+            self.param("kernel", nn.initializers.lecun_normal(),
+                       (self.taps, channels), jnp.float32),
+            self.param("bias", nn.initializers.zeros_init(), (channels,),
+                       jnp.float32),
+        )
+
+
+def _dt_bias_init(dt_min: float, dt_max: float, dt_floor: float):
+    def init(key, shape):
+        dt = jnp.exp(jax.random.uniform(
+            key, shape, jnp.float32, np.log(dt_min), np.log(dt_max)
+        ))
+        dt = jnp.maximum(dt, dt_floor)
+        return dt + jnp.log(-jnp.expm1(-dt))  # softplus^-1(dt)
+
+    return init
+
+
+class Mamba2Mixer(nn.Module):
+    """The Mamba-2 layer of `mamba_ssm` / `transformers`, parameters by the
+    source's names (`in_proj`, `conv1d` with `kernel` [taps, channels of
+    x | B | C] and `bias`, `A_log`, `D`, `dt_bias`, `norm` [H P],
+    `out_proj`): `[z | xBC | dt] = in_proj(u)`; `xBC` through a causal
+    depthwise convolution of `conv_kernel` taps with a bias, then silu,
+    split into x [H heads of P], B and C [G groups of N];
+    `dt = softplus(dt + dt_bias)`, `A = -exp(A_log)` a scalar a head; the
+    selective state-space recurrence of `ops/ssd.py` (heads of group g
+    share B and C) under the `ssm_scan` scope, plus the skip `D x`;
+    `y = GroupRMSNorm(y silu(z))` over G groups with a weight; `out_proj`.
+    Seeded as the source: `A_log = log U(1, 16)`, `dt_bias` the inverse
+    softplus of `dt ~ exp(U(log min, log max))` floored (`time_step`), `D`
+    and the norm 1, `out_proj` at `out_scale`."""
+
+    num_heads: int
+    head_dim: int
+    n_groups: int
+    state_size: int
+    conv_kernel: int
+    chunk_size: int
+    eps: float
+    dtype: Any
+    time_step: tuple = (1e-3, 0.1, 1e-4)  # min, max, floor: the init only
+    out_scale: float = 1.0                # `rescale_prenorm_residual`
+
+    @nn.compact
+    def __call__(self, u):
+        b, t, d = u.shape
+        h, p, g, n = (self.num_heads, self.head_dim, self.n_groups,
+                      self.state_size)
+        inner, bc = h * p, g * n
+        z, xbc, dt = jnp.split(
+            dense(2 * inner + 2 * bc + h, self.dtype, "in_proj")(u),
+            [inner, 2 * inner + 2 * bc], axis=-1,
+        )
+        # Causal depthwise convolution over [x | B | C], then silu: the
+        # taps accumulated in float32.
+        kernel, bias = _Conv1d(self.conv_kernel, name="conv1d")(xbc.shape[-1])
+        padded = jnp.pad(xbc, ((0, 0), (self.conv_kernel - 1, 0), (0, 0)))
+        xbc = nn.silu(bias + sum(
+            padded[:, j:j + t] * kernel[j] for j in range(self.conv_kernel)
+        ))
+        x, b_in, c_in = jnp.split(xbc, [inner, inner + bc], axis=-1)
+        x = x.reshape(b, t, h, p)
+        a_log = self.param(
+            "A_log",
+            lambda key, shape: jnp.log(
+                jax.random.uniform(key, shape, jnp.float32, 1.0, 16.0)
+            ),
+            (h,),
+        )
+        skip = self.param("D", nn.initializers.ones_init(), (h,), jnp.float32)
+        dt_bias = self.param("dt_bias", _dt_bias_init(*self.time_step), (h,))
+        dt = jax.nn.softplus(dt + dt_bias)
+        with jax.named_scope("ssm_scan"):
+            y, _ = ssd_chunked(
+                x, dt, -jnp.exp(a_log), b_in.reshape(b, t, g, n),
+                c_in.reshape(b, t, g, n),
+                chunk=self.chunk_size, dtype=self.dtype,
+            )
+        y = (y + skip[:, None] * x).reshape(b, t, inner) * nn.silu(z)
+        # RMSNorm over each of the G groups of the inner width, float32.
+        weight = self.param("norm", nn.initializers.ones_init(), (inner,),
+                            jnp.float32)
+        y = y.reshape(b, t, g, inner // g)
+        y = y * jax.lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True) + self.eps)
+        y = (weight * y.reshape(b, t, inner)).astype(self.dtype)
+        init = nn.initializers.variance_scaling(
+            self.out_scale ** 2, "fan_in", "truncated_normal"
+        )
+        return dense(d, self.dtype, "out_proj", init)(y)
+
+
+class Attention(nn.Module):
+    """Grouped-query causal softmax attention with NO position embedding:
+    `q_proj`, `k_proj`, `v_proj`, `o_proj` without bias."""
+
+    num_heads: int
+    num_kv_heads: int
+    head_dim: int
+    dtype: Any
+    scale: Any = None  # of the scores; None: 1/sqrt(head_dim)
+
+    @nn.compact
+    def __call__(self, x):
+        b, t, d = x.shape
+        h, hkv, hd = self.num_heads, self.num_kv_heads, self.head_dim
+        q, k, v = (
+            dense(heads * hd, self.dtype, name)(x)
+            .reshape(b, t, heads, hd).astype(self.dtype)
+            for name, heads in (("q_proj", h), ("k_proj", hkv), ("v_proj", hkv))
+        )
+        out = gqa.causal_attention(q, k, v, scale=self.scale)
+        return dense(d, self.dtype, "o_proj")(out.reshape(b, t, h * hd))

@@ -12,8 +12,8 @@ by `hybrid_override_pattern[i]`, with plain RMSNorm
 (`y = w x rsqrt(mean(x^2) + eps)`, w from 1), a final norm, an untied
 output head and no bias but the convolution's.
 
-- 'M' (`Mamba2Mixer`): `[z | xBC | dt] = in_proj(u)`; `xBC` through a
-  causal depthwise convolution of `conv_kernel` taps with a bias, then
+- 'M' (`model_zoo/lm_common.py` `Mamba2Mixer`): `[z | xBC | dt] =
+  in_proj(u)`; `xBC` through a causal depthwise convolution of `conv_kernel` taps with a bias, then
   silu, split into x [H heads of P], B and C [G groups of N];
   `dt = softplus(dt + dt_bias)`, `A = -exp(A_log)` a scalar a head; the
   selective state-space recurrence of `ops/ssd.py` (heads of group g
@@ -25,8 +25,8 @@ output head and no bias but the convolution's.
   renormalised and scaled by `routed_scaling_factor`; experts and the
   ungated shared expert are `down(relu(up u)^2)`.  The layer holds a
   RANGE of the experts (`experts_first`, `experts_held`).
-- '*' (`Attention`): q, k, v without bias, grouped-query heads, NO
-  position embedding (the Nemotron-H report, arXiv:2504.03624: the
+- '*' (`model_zoo/lm_common.py` `Attention`): q, k, v without bias,
+  grouped-query heads, NO position embedding (the Nemotron-H report, arXiv:2504.03624: the
   Mamba layers carry position; `rope_theta` in the config is unused by
   `nemotron_h`), causal softmax at 1/sqrt(head_dim), `o_proj`.
 
@@ -56,9 +56,10 @@ the Mamba `out_proj` scaled by 1/sqrt(`num_hidden_layers`)
 whatever part of the pattern this chip holds); `e_score_correction_bias`
 from 0.
 
-Training (`optimizer`): AdamW under a linear warm-up, and for the
-routers' `e_score_correction_bias` alone the balancing rule in its place:
-the bias takes part in no gradient (a selection is not differentiated),
+Training (`optimizer`, `model_zoo/lm_common.py` `balancing_adamw`, which
+Laguna's stack behind the same router takes too): AdamW under a linear
+warm-up, and for the routers' `e_score_correction_bias` alone the
+balancing rule in its place: the bias takes part in no gradient (a selection is not differentiated),
 `layers/moe.py` hands it `sign(times chosen - mean)` over all experts
 instead, and plain descent at `bias_update_rate` on that is
 auxiliary-loss-free balancing (arXiv:2408.15664), the rule of the router
@@ -67,168 +68,28 @@ this `gate` is taken from.
 Device scopes (obs/tracing.py DEVICE_SCOPES): `ssm` (the Mamba-2
 sublayer with its norm and residual) > `ssm_scan`; `attn`; `moe` >
 `moe_route`, `moe_experts`, `moe_shared`; `lm_head_loss`.
-
-`model_zoo/granite_hybrid` imports `Mamba2Mixer`, `Attention` (with its
-`scale`), `RMSNorm` and `_dense` from here: the same mixers inside another
-block.
 """
 
 from __future__ import annotations
 
 import dataclasses
-from functools import partial
 from typing import Any
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
-import numpy as np
-import optax
 
 from elasticdl_tpu.layers.moe import SparseMoeBlock
-from elasticdl_tpu.ops import gqa
-from elasticdl_tpu.ops.ssd import ssd_chunked
-# The rest of the zoo contract is that of any causal LM on
-# `synthetic://lm` data: mean next-token cross-entropy over float32
-# logits (under the `lm_head_loss` scope), perplexity and accuracy.
-from model_zoo.transformer.transformer_lm import (  # noqa: F401
-    VOCAB, custom_data_reader, dataset_fn, eval_metrics_fn, loss,
+# The two mixers another stack runs too, the norm, and the rest of the zoo
+# contract of any causal LM on `synthetic://lm` data: mean next-token
+# cross-entropy over float32 logits (under the `lm_head_loss` scope),
+# perplexity and accuracy.
+from model_zoo.lm_common import (  # noqa: F401
+    SELECTION_BIAS, VOCAB, Attention, Mamba2Mixer, RMSNorm, balancing_adamw,
+    custom_data_reader, dataset_fn, eval_metrics_fn, loss,
 )
 
 MAMBA, EXPERTS, ATTENTION = "M", "E", "*"
-
-
-def _dense(features, dtype, name, kernel_init=nn.initializers.lecun_normal()):
-    """A projection with operands in `dtype` and a float32 result."""
-    return nn.Dense(
-        features, use_bias=False, dtype=dtype, name=name,
-        kernel_init=kernel_init,
-        dot_general=partial(
-            jax.lax.dot_general, preferred_element_type=jnp.float32
-        ),
-    )
-
-
-class RMSNorm(nn.Module):
-    """y = w x rsqrt(mean(x^2) + eps), w from 1; float32."""
-
-    eps: float = 1e-5
-
-    @nn.compact
-    def __call__(self, x):
-        weight = self.param(
-            "weight", nn.initializers.ones_init(), (x.shape[-1],), jnp.float32,
-        )
-        x = x.astype(jnp.float32)
-        return weight * x * jax.lax.rsqrt(
-            jnp.mean(x * x, axis=-1, keepdims=True) + self.eps
-        )
-
-
-class _Conv1d(nn.Module):
-    """The source's depthwise `conv1d`: `kernel` [taps, channels], `bias`."""
-
-    taps: int
-
-    @nn.compact
-    def __call__(self, channels: int):
-        return (
-            self.param("kernel", nn.initializers.lecun_normal(),
-                       (self.taps, channels), jnp.float32),
-            self.param("bias", nn.initializers.zeros_init(), (channels,),
-                       jnp.float32),
-        )
-
-
-def _dt_bias_init(dt_min: float, dt_max: float, dt_floor: float):
-    def init(key, shape):
-        dt = jnp.exp(jax.random.uniform(
-            key, shape, jnp.float32, np.log(dt_min), np.log(dt_max)
-        ))
-        dt = jnp.maximum(dt, dt_floor)
-        return dt + jnp.log(-jnp.expm1(-dt))  # softplus^-1(dt)
-
-    return init
-
-
-class Mamba2Mixer(nn.Module):
-    num_heads: int
-    head_dim: int
-    n_groups: int
-    state_size: int
-    conv_kernel: int
-    chunk_size: int
-    eps: float
-    dtype: Any
-    time_step: tuple = (1e-3, 0.1, 1e-4)  # min, max, floor: the init only
-    out_scale: float = 1.0                # `rescale_prenorm_residual`
-
-    @nn.compact
-    def __call__(self, u):
-        b, t, d = u.shape
-        h, p, g, n = (self.num_heads, self.head_dim, self.n_groups,
-                      self.state_size)
-        inner, bc = h * p, g * n
-        z, xbc, dt = jnp.split(
-            _dense(2 * inner + 2 * bc + h, self.dtype, "in_proj")(u),
-            [inner, 2 * inner + 2 * bc], axis=-1,
-        )
-        # Causal depthwise convolution over [x | B | C], then silu: the
-        # taps accumulated in float32.
-        kernel, bias = _Conv1d(self.conv_kernel, name="conv1d")(xbc.shape[-1])
-        padded = jnp.pad(xbc, ((0, 0), (self.conv_kernel - 1, 0), (0, 0)))
-        xbc = nn.silu(bias + sum(
-            padded[:, j:j + t] * kernel[j] for j in range(self.conv_kernel)
-        ))
-        x, b_in, c_in = jnp.split(xbc, [inner, inner + bc], axis=-1)
-        x = x.reshape(b, t, h, p)
-        a_log = self.param(
-            "A_log",
-            lambda key, shape: jnp.log(
-                jax.random.uniform(key, shape, jnp.float32, 1.0, 16.0)
-            ),
-            (h,),
-        )
-        skip = self.param("D", nn.initializers.ones_init(), (h,), jnp.float32)
-        dt_bias = self.param("dt_bias", _dt_bias_init(*self.time_step), (h,))
-        dt = jax.nn.softplus(dt + dt_bias)
-        with jax.named_scope("ssm_scan"):
-            y, _ = ssd_chunked(
-                x, dt, -jnp.exp(a_log), b_in.reshape(b, t, g, n),
-                c_in.reshape(b, t, g, n),
-                chunk=self.chunk_size, dtype=self.dtype,
-            )
-        y = (y + skip[:, None] * x).reshape(b, t, inner) * nn.silu(z)
-        # RMSNorm over each of the G groups of the inner width, float32.
-        weight = self.param("norm", nn.initializers.ones_init(), (inner,),
-                            jnp.float32)
-        y = y.reshape(b, t, g, inner // g)
-        y = y * jax.lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True) + self.eps)
-        y = (weight * y.reshape(b, t, inner)).astype(self.dtype)
-        init = nn.initializers.variance_scaling(
-            self.out_scale ** 2, "fan_in", "truncated_normal"
-        )
-        return _dense(d, self.dtype, "out_proj", init)(y)
-
-
-class Attention(nn.Module):
-    num_heads: int
-    num_kv_heads: int
-    head_dim: int
-    dtype: Any
-    scale: Any = None  # of the scores; None: 1/sqrt(head_dim)
-
-    @nn.compact
-    def __call__(self, x):
-        b, t, d = x.shape
-        h, hkv, hd = self.num_heads, self.num_kv_heads, self.head_dim
-        q, k, v = (
-            _dense(heads * hd, self.dtype, name)(x)
-            .reshape(b, t, heads, hd).astype(self.dtype)
-            for name, heads in (("q_proj", h), ("k_proj", hkv), ("v_proj", hkv))
-        )
-        out = gqa.causal_attention(q, k, v, scale=self.scale)
-        return _dense(d, self.dtype, "o_proj")(out.reshape(b, t, h * hd))
 
 
 class NemotronHLayer(nn.Module):
@@ -362,29 +223,7 @@ def custom_model(use_bf16: bool = True, **config):
     return NemotronHLM(cfg)
 
 
-SELECTION_BIAS = "e_score_correction_bias"
-
-
-def optimizer(lr: float = 3e-4, warmup_steps: int = 2000,
-              bias_update_rate: float = 1e-3):
-    """AdamW whose rate rises linearly to `lr` over the first
-    `warmup_steps` steps (step n of them runs at lr n / warmup_steps) and
-    stays, as a pre-training job's first steps run; the routers' selection
-    biases are moved by the balancing rule instead (module docstring):
-    descent at `bias_update_rate`, no moments, no decay."""
-    return optax.multi_transform(
-        {
-            "adamw": optax.adamw(
-                lambda count: lr * jnp.minimum(
-                    1.0, (count + 1) / warmup_steps
-                ),
-                weight_decay=0.01,
-            ),
-            "balance": optax.sgd(bias_update_rate),
-        },
-        lambda params: jax.tree_util.tree_map_with_path(
-            lambda path, _: "balance"
-            if getattr(path[-1], "key", None) == SELECTION_BIAS else "adamw",
-            params,
-        ),
-    )
+# AdamW under a linear warm-up, the routers' selection biases moved by the
+# balancing rule instead (module docstring): `lr`, `warmup_steps`,
+# `bias_update_rate`.
+optimizer = balancing_adamw

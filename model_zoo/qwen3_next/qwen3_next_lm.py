@@ -56,29 +56,18 @@ import optax
 from elasticdl_tpu.layers.moe import SparseMoeBlock
 from elasticdl_tpu.ops import gdn_passes, gqa
 from elasticdl_tpu.ops.gated_delta import chunk_gated_delta_rule_rows
-# The rest of the zoo contract is that of any causal LM on
+# The projection and the rest of the zoo contract of any causal LM on
 # `synthetic://lm` data: mean next-token cross-entropy over float32
 # logits (under the `lm_head_loss` scope), perplexity and accuracy.
-from model_zoo.transformer.transformer_lm import (  # noqa: F401
-    VOCAB, custom_data_reader, dataset_fn, eval_metrics_fn, loss,
+from model_zoo.lm_common import (  # noqa: F401
+    VOCAB, custom_data_reader, dataset_fn, dense, eval_metrics_fn, loss,
 )
 
 
-def _dense(features, dtype, name):
-    """A projection with operands in `dtype` and a float32 result: what
-    the MXU accumulates is not rounded again on the way out (a bfloat16
-    result carries 2^-9 of rounding into the delta rule, which amplifies
-    it; the operands' rounding averages out over the dot)."""
-    return nn.Dense(
-        features, use_bias=False, dtype=dtype, name=name,
-        dot_general=partial(
-            jax.lax.dot_general, preferred_element_type=jnp.float32
-        ),
-    )
-
-
 class RMSNorm(nn.Module):
-    """y = x rsqrt(mean(x^2) + eps) (1 + w), w from 0; float32."""
+    """y = x rsqrt(mean(x^2) + eps) (1 + w), w from 0; float32.  The
+    zero-centred norm of this source alone: `lm_common.RMSNorm` is the
+    plain one (w from 1) and another function."""
 
     eps: float = 1e-6
 
@@ -100,7 +89,7 @@ class _HeadMajorDense(nn.Module):
     one [B, T, groups x width] tensor a part, each the product with a
     view of the kernel's columns (a slice and a reshape of the weight,
     100 MB, where the result's would be a relayout of 800 MB).  Operands
-    in `dtype`, float32 results, as `_dense`."""
+    in `dtype`, float32 results, as `lm_common.dense`."""
 
     groups: int
     parts: tuple
@@ -191,7 +180,7 @@ class GatedDeltaNet(nn.Module):
                 out, z, weight, eps=self.eps, dtype=self.dtype,
                 pallas=pallas, mesh=self.mesh,
             )
-        return _dense(d, self.dtype, "out_proj")(out)
+        return dense(d, self.dtype, "out_proj")(out)
 
 
 class GatedAttention(nn.Module):
@@ -208,10 +197,10 @@ class GatedAttention(nn.Module):
     def __call__(self, x):
         b, t, d = x.shape
         h, hkv, hd = self.num_heads, self.num_kv_heads, self.head_dim
-        q_gate = _dense(h * hd * 2, self.dtype, "q_proj")(x)
+        q_gate = dense(h * hd * 2, self.dtype, "q_proj")(x)
         q, gate = jnp.split(q_gate.reshape(b, t, h, 2 * hd), 2, axis=-1)
-        k = _dense(hkv * hd, self.dtype, "k_proj")(x).reshape(b, t, hkv, hd)
-        v = _dense(hkv * hd, self.dtype, "v_proj")(x).reshape(b, t, hkv, hd)
+        k = dense(hkv * hd, self.dtype, "k_proj")(x).reshape(b, t, hkv, hd)
+        v = dense(hkv * hd, self.dtype, "v_proj")(x).reshape(b, t, hkv, hd)
         q = RMSNorm(self.eps, name="q_norm")(q)
         k = RMSNorm(self.eps, name="k_norm")(k)
         cos, sin = gqa.rotary_tables(
@@ -226,7 +215,7 @@ class GatedAttention(nn.Module):
             gate.astype(jnp.float32)
         )
         out = out.reshape(b, t, h * hd).astype(self.dtype)
-        return _dense(d, self.dtype, "o_proj")(out)
+        return dense(d, self.dtype, "o_proj")(out)
 
 
 class DecoderLayer(nn.Module):

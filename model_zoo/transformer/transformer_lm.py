@@ -13,9 +13,10 @@ first-class).  A pre-LN decoder-only transformer whose attention runs:
 
 Everything else is ordinary flax the DataParallelTrainer already handles:
 params replicated (f32), bf16 compute, batch sharded over `data`, XLA
-psums the grads.  Model-zoo contract functions at the bottom; synthetic
-`synthetic://lm?n=N&len=T&vocab=V` data (model_zoo/datasets.py) makes
-next-token loss genuinely learnable in tests.
+psums the grads.  The model-zoo contract functions are
+`model_zoo/lm_common.py`'s; synthetic `synthetic://lm?n=N&len=T&vocab=V`
+data (model_zoo/datasets.py) makes next-token loss genuinely learnable in
+tests.
 """
 
 from __future__ import annotations
@@ -26,7 +27,6 @@ from typing import Any, Optional
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
-import numpy as np
 import optax
 
 from elasticdl_tpu.common.log_utils import get_logger
@@ -35,12 +35,13 @@ from elasticdl_tpu.parallel.ring_attention import (
     blockwise_attention,
     make_ring_attention,
 )
-from model_zoo import datasets
+# The zoo contract of a causal LM on `synthetic://lm` data, which the 8k
+# stacks share with this one.
+from model_zoo.lm_common import (  # noqa: F401
+    SEQ_LEN, VOCAB, custom_data_reader, dataset_fn, eval_metrics_fn, loss,
+)
 
 logger = get_logger("model_zoo.transformer")
-
-VOCAB = 256
-SEQ_LEN = 128
 
 
 def _tp_active(mesh, model_axis_mode: str) -> bool:
@@ -341,51 +342,5 @@ def custom_model(
     )
 
 
-def loss(labels, predictions):
-    """Mean next-token cross-entropy; labels [B, T], logits [B, T, V]."""
-    with jax.named_scope("lm_head_loss"):
-        return optax.softmax_cross_entropy_with_integer_labels(
-            predictions.astype(jnp.float32), labels.astype(jnp.int32)
-        ).mean()
-
-
 def optimizer(lr: float = 3e-3):
     return optax.adamw(lr, weight_decay=0.01)
-
-
-def dataset_fn(dataset, mode, metadata):
-    def parse(record):
-        tokens, next_tokens = record
-        return np.asarray(tokens, np.int32), np.asarray(
-            next_tokens, np.int32
-        )
-
-    dataset = dataset.map(parse)
-    if mode == "training":
-        dataset = dataset.shuffle(1024, seed=0)
-    return dataset
-
-
-def eval_metrics_fn():
-    def perplexity(outputs, labels):
-        ce = float(loss(jnp.asarray(labels), jnp.asarray(outputs)))
-        return float(np.exp(min(ce, 20.0)))
-
-    return {
-        "perplexity": perplexity,
-        "accuracy": lambda outputs, labels: float(
-            np.mean(np.argmax(outputs, axis=-1) == labels)
-        ),
-    }
-
-
-def custom_data_reader(data_path: str, **kwargs):
-    name, params = datasets.parse_synthetic_path(data_path)
-    if name != "lm":
-        return None
-    return datasets.synthetic_lm_reader(
-        n=params.get("n", 2048),
-        seq_len=params.get("len", SEQ_LEN),
-        vocab=params.get("vocab", VOCAB),
-        seed=params.get("seed", 0),
-    )

@@ -22,6 +22,7 @@ import optax
 from elasticdl_tpu.layers import Embedding
 from elasticdl_tpu.parallel import sparse_optim
 from model_zoo import datasets
+from model_zoo.metrics import auc
 
 NUM_DENSE = 13
 NUM_CAT = 26
@@ -102,22 +103,8 @@ def eval_metrics_fn():
         "accuracy": lambda outputs, labels: np.mean(
             (outputs > 0).astype(np.int64) == labels.astype(np.int64)
         ),
-        "auc": _auc,
+        "auc": auc,
     }
-
-
-def _auc(outputs, labels):
-    order = np.argsort(outputs)
-    ranks = np.empty_like(order, dtype=np.float64)
-    ranks[order] = np.arange(1, len(outputs) + 1)
-    pos = labels.astype(bool)
-    n_pos = int(pos.sum())
-    n_neg = len(labels) - n_pos
-    if n_pos == 0 or n_neg == 0:
-        return 0.5
-    return float(
-        (ranks[pos].sum() - n_pos * (n_pos + 1) / 2) / (n_pos * n_neg)
-    )
 
 
 def custom_data_reader(data_path: str, **kwargs):

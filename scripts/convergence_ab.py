@@ -64,7 +64,7 @@ def _logloss(logits: np.ndarray, labels: np.ndarray) -> float:
 
 
 def _auc(logits: np.ndarray, labels: np.ndarray) -> float:
-    from model_zoo.wide_and_deep.wide_and_deep import _auc as rank_auc
+    from model_zoo.metrics import auc as rank_auc
 
     return float(rank_auc(logits, labels))
 

@@ -23,8 +23,19 @@ os.environ.setdefault("TF_CPP_MIN_LOG_LEVEL", "2")
 # and a suite run must not fill the checkout.  tests/test_chip_smoke.py
 # tests the placement itself in subprocesses with their own environment.
 os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "false"
+# The TPU compiler (the `topo` cases) keeps no log directory.
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
 import pytest
+
+# The language models' contract and descriptors are helper modules (the
+# contract's cases are imported into each model's own test files): their
+# `assert`s are rewritten as a test file's are, so that a failure shows the
+# values compared.
+pytest.register_assert_rewrite("lm_contract", *(
+    name[:-3] for name in os.listdir(os.path.dirname(__file__))
+    if name.startswith("spec_") and name.endswith(".py")
+))
 
 
 @pytest.fixture
@@ -197,3 +208,34 @@ def run_kill_recovery_job(
     finally:
         manager.stop()
         master.stop()
+
+
+@pytest.fixture(scope="module")
+def topo():
+    """A DESCRIBED `v5e:2x2`: the TPU compiler compiles for it from shapes
+    alone, no chip (tests/test_tpu_compile*.py, and a model's window
+    program in its tests/test_<m>_program.py)."""
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as exc:  # no libtpu here: nothing to compile with
+        pytest.skip(f"cannot describe a v5e:2x2 topology: {exc}")
+
+
+@pytest.fixture
+def no_persistent_cache():
+    """A compile for a described device is written to the persistent
+    cache but cannot be read back without a chip (the next one warns and
+    recompiles): keep the cache out of these cases."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()

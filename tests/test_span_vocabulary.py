@@ -15,7 +15,6 @@
   and without them, and the names are on the compiled HLO's ``op_name``s.
 """
 
-import contextlib
 import json
 import os
 import subprocess
@@ -29,6 +28,7 @@ from elasticdl_tpu import obs
 from elasticdl_tpu.obs import tracing
 from elasticdl_tpu.obs.journal import EventJournal
 from elasticdl_tpu.obs.stepstats import StepAnatomy
+from lm_contract import scopes_are_metadata
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ZOO = os.path.join(REPO_ROOT, "model_zoo")
@@ -831,35 +831,6 @@ def test_a_relaunch_says_its_cause_and_how_long_after_the_exit():
 # ---------------------------------------------------------------------------
 
 
-def _no_scopes(monkeypatch):
-    import jax
-
-    monkeypatch.setattr(
-        jax, "named_scope", lambda name: contextlib.nullcontext()
-    )
-
-
-def _dense_window(seed=0):
-    """(trainer, staged window) of a tiny transformer on the dp trainer."""
-    sys.path.insert(0, ZOO)
-    from transformer import transformer_lm as zoo
-
-    from elasticdl_tpu.parallel import MeshConfig, build_mesh
-    from elasticdl_tpu.parallel.dp_trainer import DataParallelTrainer
-
-    model = zoo.custom_model(vocab=64, d_model=32, num_heads=2,
-                             num_layers=1, max_len=16)
-    trainer = DataParallelTrainer(
-        model=model, loss_fn=zoo.loss, optimizer=zoo.optimizer(),
-        mesh=build_mesh(MeshConfig()),
-    )
-    rng = np.random.RandomState(seed)
-    tokens = rng.randint(0, 64, size=(8, 16)).astype(np.int32)
-    trainer.ensure_initialized(tokens)
-    batch = (tokens, tokens, np.ones((8,), np.float32))
-    return trainer, trainer.stage_window([batch, batch])
-
-
 def _sparse_window(seed=0):
     """(trainer, staged window) of a tiny DeepFM on the PS trainer."""
     sys.path.insert(0, ZOO)
@@ -886,198 +857,17 @@ def _sparse_window(seed=0):
     return trainer, trainer.stage_window([batch, batch])
 
 
-def _window_outputs(build):
-    import jax
-
-    trainer, window = build()
-    losses = trainer.train_window(window)
-    state = jax.device_get(trainer.state)
-    return np.asarray(losses), [np.asarray(x) for x in jax.tree.leaves(state)]
-
-
-def _op_names(trainer, jitted, window):
-    import re
-
-    text = jitted.lower(trainer.state, *window).compile().as_text()
-    return " ".join(re.findall(r'op_name="([^"]+)"', text))
-
-
-def _hybrid_window(seed=0):
-    """(trainer, staged window) of a tiny Qwen3-Next on the dp trainer,
-    each layer rematerialised as the benchmark's configuration runs it."""
-    sys.path.insert(0, REPO_ROOT)
-    from model_zoo.qwen3_next import qwen3_next_lm as zoo
-
-    from elasticdl_tpu.parallel import MeshConfig, build_mesh
-    from elasticdl_tpu.parallel.dp_trainer import DataParallelTrainer
-
-    model = zoo.custom_model(
-        vocab_size=64, hidden_size=32, head_dim=16, num_attention_heads=2,
-        linear_key_head_dim=8, linear_value_head_dim=8,
-        moe_intermediate_size=16, shared_expert_intermediate_size=16,
-        experts_first=2, experts_held=4, remat=True,
-    )
-    trainer = DataParallelTrainer(
-        model=model, loss_fn=zoo.loss, optimizer=zoo.optimizer(),
-        mesh=build_mesh(MeshConfig()),
-    )
-    rng = np.random.RandomState(seed)
-    tokens = rng.randint(0, 64, size=(8, 16)).astype(np.int32)
-    trainer.ensure_initialized(tokens)
-    batch = (tokens, tokens, np.ones((8,), np.float32))
-    return trainer, trainer.stage_window([batch, batch])
-
-
-def _state_space_window(seed=0):
-    """(trainer, staged window) of a tiny Nemotron-H on the dp trainer,
-    each layer rematerialised as the benchmark's configuration runs it."""
-    sys.path.insert(0, REPO_ROOT)
-    from model_zoo.nemotron_h import nemotron_h_lm as zoo
-
-    from elasticdl_tpu.parallel import MeshConfig, build_mesh
-    from elasticdl_tpu.parallel.dp_trainer import DataParallelTrainer
-
-    model = zoo.custom_model(
-        vocab_size=64, hidden_size=32, hybrid_override_pattern="ME*",
-        mamba_head_dim=8, ssm_state_size=8, chunk_size=8, head_dim=8,
-        moe_intermediate_size=16, moe_shared_expert_intermediate_size=16,
-        experts_first=2, experts_held=4, remat=True,
-    )
-    trainer = DataParallelTrainer(
-        model=model, loss_fn=zoo.loss, optimizer=zoo.optimizer(),
-        mesh=build_mesh(MeshConfig()),
-    )
-    rng = np.random.RandomState(seed)
-    tokens = rng.randint(0, 64, size=(8, 16)).astype(np.int32)
-    trainer.ensure_initialized(tokens)
-    batch = (tokens, tokens, np.ones((8,), np.float32))
-    return trainer, trainer.stage_window([batch, batch])
-
-
-def _latent_window(seed=0):
-    """(trainer, staged window) of a tiny DeepSeek-V2 on the dp trainer
-    (one dense and one expert layer), each layer rematerialised as the
-    benchmark's configuration runs it."""
-    sys.path.insert(0, REPO_ROOT)
-    from model_zoo.deepseek_v2 import deepseek_v2_lm as zoo
-
-    from elasticdl_tpu.parallel import MeshConfig, build_mesh
-    from elasticdl_tpu.parallel.dp_trainer import DataParallelTrainer
-
-    model = zoo.custom_model(
-        vocab_size=64, hidden_size=32, intermediate_size=48,
-        moe_intermediate_size=16, num_hidden_layers=2, qk_nope_head_dim=8,
-        qk_rope_head_dim=8, v_head_dim=8, kv_lora_rank=16,
-        rope_scaling_factor=40, rope_scaling_mscale_all_dim=0.707,
-        rope_scaling_original_max_position_embeddings=8,
-        experts_first=2, experts_held=4, remat=True,
-    )
-    trainer = DataParallelTrainer(
-        model=model, loss_fn=zoo.loss, optimizer=zoo.optimizer(),
-        mesh=build_mesh(MeshConfig()),
-    )
-    rng = np.random.RandomState(seed)
-    tokens = rng.randint(0, 64, size=(8, 16)).astype(np.int32)
-    trainer.ensure_initialized(tokens)
-    batch = (tokens, tokens, np.ones((8,), np.float32))
-    return trainer, trainer.stage_window([batch, batch])
-
-
-def _banded_window(seed=0):
-    """(trainer, staged window) of a tiny Laguna on the dp trainer (a full
-    layer with the dense MLP, a sliding one with experts), each layer
-    rematerialised as the benchmark's configuration runs it."""
-    sys.path.insert(0, REPO_ROOT)
-    from model_zoo.laguna import laguna_lm as zoo
-
-    from elasticdl_tpu.parallel import MeshConfig, build_mesh
-    from elasticdl_tpu.parallel.dp_trainer import DataParallelTrainer
-
-    model = zoo.custom_model(
-        vocab_size=64, hidden_size=32, intermediate_size=48,
-        moe_intermediate_size=16, shared_expert_intermediate_size=16,
-        head_dim=8, sliding_window=4, rope_full_attention_factor=64,
-        rope_full_attention_original_max_position_embeddings=8,
-        experts_first=2, experts_held=4, remat=True,
-    )
-    trainer = DataParallelTrainer(
-        model=model, loss_fn=zoo.loss, optimizer=zoo.optimizer(),
-        mesh=build_mesh(MeshConfig()),
-    )
-    rng = np.random.RandomState(seed)
-    tokens = rng.randint(0, 64, size=(8, 16)).astype(np.int32)
-    trainer.ensure_initialized(tokens)
-    batch = (tokens, tokens, np.ones((8,), np.float32))
-    return trainer, trainer.stage_window([batch, batch])
-
-
-def _two_sublayer_window(seed=0):
-    """(trainer, staged window) of a tiny Granite 4.0-H on the dp trainer
-    (a Mamba-2 and an attention layer, each followed by its MLP), each
-    layer rematerialised as the benchmark's configuration runs it."""
-    sys.path.insert(0, REPO_ROOT)
-    from model_zoo.granite_hybrid import granite_hybrid_lm as zoo
-
-    from elasticdl_tpu.parallel import MeshConfig, build_mesh
-    from elasticdl_tpu.parallel.dp_trainer import DataParallelTrainer
-
-    model = zoo.custom_model(
-        vocab_size=64, hidden_size=32, mamba_d_head=8, mamba_d_state=8,
-        mamba_chunk_size=8, head_dim=8, shared_intermediate_size=48,
-        remat=True,
-    )
-    trainer = DataParallelTrainer(
-        model=model, loss_fn=zoo.loss, optimizer=zoo.optimizer(),
-        mesh=build_mesh(MeshConfig()),
-    )
-    rng = np.random.RandomState(seed)
-    tokens = rng.randint(0, 64, size=(8, 16)).astype(np.int32)
-    trainer.ensure_initialized(tokens)
-    batch = (tokens, tokens, np.ones((8,), np.float32))
-    return trainer, trainer.stage_window([batch, batch])
-
-
-@pytest.mark.parametrize("build,jit_attr,scopes", [
-    (_dense_window, "_train_window_jit",
-     ("fwd_bwd", "attn", "mlp", "lm_head_loss", "optimizer")),
-    (_hybrid_window, "_train_window_jit",
-     ("fwd_bwd", "gdn", "gdn_mix", "gdn_scan", "attn", "moe", "moe_route",
-      "moe_experts", "moe_shared", "lm_head_loss", "optimizer")),
-    (_state_space_window, "_train_window_jit",
-     ("fwd_bwd", "ssm", "ssm_scan", "attn", "moe", "moe_route",
-      "moe_experts", "moe_shared", "lm_head_loss", "optimizer")),
-    (_latent_window, "_train_window_jit",
-     ("fwd_bwd", "attn", "mla_latent", "mla_core", "mlp", "moe", "moe_route",
-      "moe_experts", "moe_shared", "lm_head_loss", "optimizer")),
-    (_banded_window, "_train_window_jit",
-     ("fwd_bwd", "attn", "attn_full", "attn_window", "attn_gate", "mlp",
-      "moe", "moe_route", "moe_experts", "moe_shared", "lm_head_loss",
-      "optimizer")),
-    (_two_sublayer_window, "_train_window_jit",
-     ("fwd_bwd", "ssm", "ssm_scan", "attn", "mlp", "lm_head_loss",
-      "optimizer")),
-    (_sparse_window, "_train_window",
-     ("fwd_bwd", "dense_update", "sparse_apply", "grad_accumulate",
-      "sparse_adam")),
-])
+@pytest.mark.parametrize("build,jit_attr,scopes", [pytest.param(
+    _sparse_window, "_train_window",
+    ("fwd_bwd", "dense_update", "sparse_apply", "grad_accumulate",
+     "sparse_adam"), id="deepfm",
+)])
 def test_scopes_are_on_the_op_names_and_leave_outputs_bit_equal(
     build, jit_attr, scopes, monkeypatch,
 ):
-    trainer, window = build()
-    names = _op_names(trainer, getattr(trainer, jit_attr), window)
-    for scope in scopes:
-        assert f"/{scope}/" in names or f"({scope})" in names, scope
-    with_scopes = _window_outputs(build)
-    _no_scopes(monkeypatch)
-    trainer, window = build()
-    bare = _op_names(trainer, getattr(trainer, jit_attr), window)
-    for scope in ("fwd_bwd", "sparse_apply", "optimizer", "dense_update"):
-        assert f"/{scope}/" not in bare
-    without = _window_outputs(build)
-    np.testing.assert_array_equal(with_scopes[0], without[0])
-    assert len(with_scopes[1]) == len(without[1])
-    for a, b in zip(with_scopes[1], without[1]):
-        np.testing.assert_array_equal(a, b)
+    """The PS trainer's window; each language model's is this check in its
+    own `tests/test_<m>_program.py` (`tests/lm_contract.py`)."""
+    scopes_are_metadata(build, jit_attr, scopes, monkeypatch)
 
 
 def test_every_literal_span_name_in_the_training_path_is_in_the_list():

@@ -171,7 +171,7 @@ def test_windowed_apply_convergence_parity():
     ids, double-applied chunks — moves AUC far beyond 0.03."""
     from model_zoo import datasets
     from model_zoo.deepfm import deepfm_functional_api as zoo
-    from model_zoo.wide_and_deep.wide_and_deep import _auc
+    from model_zoo.metrics import auc
 
     vocab, batch, spe, epochs = 200, 256, 16, 3
     dense, cats, labels = datasets.synthetic_ctr_columns(
@@ -222,7 +222,7 @@ def test_windowed_apply_convergence_parity():
                 )
                 for lo in range(0, 2048, batch)
             ]
-            best = max(best, _auc(np.concatenate(outs), e_labels))
+            best = max(best, auc(np.concatenate(outs), e_labels))
         return best
 
     strict, windowed = run(1), run(8)

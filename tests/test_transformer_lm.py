@@ -1,6 +1,9 @@
 """Transformer LM (long-context config) tests: single-device and
 context-parallel (ring attention over the model axis) training, plus
-parity between the two."""
+parity between the two; and, as the GPT-2 decoder of
+`gpt2-medium.train-synth`, the zoo's language-model contract
+(`tests/lm_contract.py`, at this file's `SPEC`) against the plain reference
+that decides that cell's `correct` (`perfbench/configs/gpt2_reference.py`)."""
 
 import jax
 import jax.numpy as jnp
@@ -8,8 +11,38 @@ import numpy as np
 
 from elasticdl_tpu.parallel import MeshConfig, build_mesh
 from elasticdl_tpu.parallel.dp_trainer import DataParallelTrainer
+from lm_contract import (  # noqa: F401  (the contract's cases, collected here)
+    LMSpec, lm, program_and_reference, pytest_generate_tests,
+    test_full_size_configuration_counts_the_parameters_it_states,
+    test_gradients_match_the_reference,
+    test_logits_and_loss_match_the_reference,
+    test_scopes_are_on_the_op_names_and_leave_outputs_bit_equal,
+)
 from model_zoo import datasets
 from model_zoo.transformer import transformer_lm as zoo
+
+# The cell's JSON speaks the source's keys (`n_embd`, `n_head`, ...), the
+# stack its own.  The reference reads flax's automatic names (`Embed_0`,
+# `Dense_0`, `LayerNorm_0`) and counts what a matmul or a gather reads: not
+# the biases and the LayerNorms' pairs.  Two layers, so that a block after
+# the first is read by its name too.
+SPEC = LMSpec(
+    model_def="transformer.transformer_lm",
+    reference="gpt2_reference.py",
+    cell="gpt2-medium.json",
+    parameters=406_336_593,
+    stated="406.3M",
+    kwargs=lambda m: dict(
+        vocab=m["vocab_size"], d_model=m["n_embd"], num_heads=m["n_head"],
+        num_layers=m["n_layer"], max_len=m["n_positions"],
+    ),
+    whole_model_changes={"n_layer": 2},
+    uncounted=lambda name, leaf: name.endswith(("['bias']", "['scale']")),
+    job_only={},
+    scope_widths=dict(vocab=64, d_model=32, num_heads=2, num_layers=1,
+                      max_len=16),
+    scopes=("fwd_bwd", "attn", "mlp", "lm_head_loss", "optimizer"),
+)
 
 
 def _batches(n=64, mb=16, seq_len=64, seed=0):
