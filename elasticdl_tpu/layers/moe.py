@@ -25,7 +25,8 @@ computes; the two are independent:
 
     expert_form="gated_silu"    three products
     y = sum_k w_k * W_down,k (silu(W_gate,k x) * W_up,k x)   held k only
-      + [sigmoid(w_sg . x) *] shared(x)     shared: the same gated form
+      + [sigmoid(w_sg . x) *] shared(x)     shared: the same gated form,
+                                            where `shared_width` > 0
 
     expert_form="relu2"         two products
     y = sum_k w_k * W_down,k relu(W_up,k x)^2                held k only
@@ -36,7 +37,12 @@ The zoo's pairings:
     softmax + gated_silu    model_zoo/qwen3_next, deepseek_v2
     sigmoid + relu2         model_zoo/nemotron_h
     sigmoid + gated_silu    model_zoo/laguna
+    softmax + gated_silu    model_zoo/mellum: renormalised, WITH the
+                            balancing loss, and no shared expert
 
+`shared_width` 0 is a layer with NOTHING beside its routed experts: no
+shared module, no parameter, no `moe_shared` op (decided at trace time),
+and a token none of whose choices is held here gets exactly 0 from it.
 `shared_gated` says whether a sigmoid gate of the token multiplies the
 shared expert (Qwen3-Next's does; Nemotron-H's, Laguna's and
 DeepSeek-V2's, which is its `n_shared_experts` experts as ONE MLP of
@@ -507,21 +513,24 @@ class SparseMoeBlock(nn.Module):
             x.astype(self.dtype), weights, weight.reshape(-1),
             plan, self.top_k, block,
         )
-        with jax.named_scope("moe_shared"):
-            shared = (GatedMLP if gated else Relu2MLP)(
-                self.shared_width, self.dtype,
-                name="shared_expert" if shared_gated else "shared_experts",
-            )(x)
-            if shared_gated:
-                shared_gate = self.param("shared_expert_gate", init, (d, 1),
-                                         jnp.float32)
-                # A block's product like the expert's own: operands in
-                # `dtype`, written out so that no backend's default decides.
-                shared = jax.nn.sigmoid(jnp.dot(
-                    x.astype(self.dtype), shared_gate.astype(self.dtype),
-                    preferred_element_type=jnp.float32,
-                )) * shared
-            y = y + shared
+        if self.shared_width:  # 0: the routed experts and nothing beside
+            with jax.named_scope("moe_shared"):
+                shared = (GatedMLP if gated else Relu2MLP)(
+                    self.shared_width, self.dtype,
+                    name="shared_expert" if shared_gated else "shared_experts",
+                )(x)
+                if shared_gated:
+                    shared_gate = self.param(
+                        "shared_expert_gate", init, (d, 1), jnp.float32
+                    )
+                    # A block's product like the expert's own: operands in
+                    # `dtype`, written out so that no backend's default
+                    # decides.
+                    shared = jax.nn.sigmoid(jnp.dot(
+                        x.astype(self.dtype), shared_gate.astype(self.dtype),
+                        preferred_element_type=jnp.float32,
+                    )) * shared
+                y = y + shared
         self._count(is_held, rows, plan, block, balance)
         return y.reshape(shape)
 

@@ -92,8 +92,8 @@ from elasticdl_tpu.ops import gqa
 # too): AdamW under a warm-up, the selection biases moved by the balancing
 # rule.
 from model_zoo.lm_common import (  # noqa: F401
-    VOCAB, RMSNorm, balancing_adamw as optimizer, custom_data_reader,
-    dataset_fn, dense, eval_metrics_fn, loss,
+    VOCAB, RMSNorm, balancing_adamw as optimizer, check_listed,
+    custom_data_reader, dataset_fn, dense, eval_metrics_fn, listed, loss,
 )
 
 FULL, SLIDING = "full_attention", "sliding_attention"
@@ -275,16 +275,6 @@ class LagunaLM(nn.Module):
             )
 
 
-def _listed(value, cast):
-    """A per-layer list as a job's flat flags carry it (`a/b/c`), as a
-    Python caller hands it (a sequence), or one entry."""
-    if isinstance(value, str):
-        value = value.split("/")
-    elif not isinstance(value, (list, tuple)):
-        value = (value,)
-    return tuple(cast(entry) for entry in value)
-
-
 def custom_model(use_bf16: bool = True, **config):
     """`config`: the source's `config.json` keys this model reads (see
     `LagunaConfig`; a per-layer list as a sequence or as `a/b/c`), plus
@@ -300,7 +290,7 @@ def custom_model(use_bf16: bool = True, **config):
     for name, cast in (("layer_types", str), ("mlp_layer_types", str),
                        ("num_attention_heads_per_layer", int)):
         if name in config:
-            config[name] = _listed(config[name], cast)
+            config[name] = listed(config[name], cast)
     config.setdefault("experts_held", config.get("num_experts", 8))
     cfg = LagunaConfig(
         dtype=jnp.bfloat16 if use_bf16 else jnp.float32, **config
@@ -316,13 +306,7 @@ def custom_model(use_bf16: bool = True, **config):
     for name, known in (("layer_types", {FULL, SLIDING}),
                         ("mlp_layer_types", {DENSE, SPARSE}),
                         ("num_attention_heads_per_layer", None)):
-        entries = getattr(cfg, name)
-        if len(entries) < layers:
-            raise ValueError(
-                f"{name} lists {len(entries)} layers of {layers}"
-            )
-        if known and set(entries[:layers]) - known:
-            raise ValueError(f"{name} {entries!r} is not made of {sorted(known)}")
+        check_listed(name, getattr(cfg, name), layers, known)
     if any(h % cfg.num_key_value_heads
            for h in cfg.num_attention_heads_per_layer[:layers]):
         raise ValueError(

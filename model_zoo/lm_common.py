@@ -8,15 +8,21 @@ edit to a stack meets that model's cells and no other; an edit HERE meets
 every cell whose stack uses the piece:
 
 - the causal-LM zoo contract on `synthetic://lm` data (`VOCAB`, `SEQ_LEN`,
-  `custom_data_reader`, `dataset_fn`, `eval_metrics_fn`, `loss`): all six
+  `custom_data_reader`, `dataset_fn`, `eval_metrics_fn`, `loss`): all seven
   stacks (`gpt2-medium`, `qwen3-next`, `nemotron3-nano`, `deepseek-v2-lite`,
-  `laguna-xs2`, `granite4-h-micro`);
-- `dense`, the bias-free projection with a float32 result: the five 8k
+  `laguna-xs2`, `granite4-h-micro`, `mellum2`);
+- `dense`, the bias-free projection with a float32 result: the six 8k
   stacks; `RMSNorm` (weight from 1): all of them but Qwen3-Next, whose
-  zero-centred norm is another function and stays in its file;
-- `warmup_adamw`: DeepSeek-V2 and Granite; `balancing_adamw`, which wraps
+  zero-centred norm is another function and stays in its file (Mellum 2
+  also norms every query and key head with it: it is over the last axis,
+  so a [B, T, heads, head_dim] tensor gets one weight vector of
+  `head_dim`);
+- `warmup_adamw`: DeepSeek-V2, Granite and Mellum 2; `balancing_adamw`, which wraps
   it with the rule that moves a sigmoid router's selection biases:
   Nemotron-H and Laguna, the two stacks behind that router;
+- `listed` and `check_listed`, a source's per-layer lists as a job's flat
+  flags carry them and the check that they cover the stack: Laguna and
+  Mellum 2;
 - `Mamba2Mixer` (with `_Conv1d`, `_dt_bias_init`) and the position-free
   `Attention`: Nemotron-H and Granite 4.0-H.
 
@@ -100,6 +106,26 @@ def warmup_adamw(lr: float, warmup_steps: int, **adamw):
         lambda count: lr * jnp.minimum(1.0, (count + 1) / warmup_steps),
         **adamw,
     )
+
+
+def listed(value, cast=str) -> tuple:
+    """A per-layer list of a source's `config.json` as a job's flat flags
+    carry it (`a/b/c`), as a Python caller hands it (a sequence), or one
+    entry."""
+    if isinstance(value, str):
+        value = value.split("/")
+    elif not isinstance(value, (list, tuple)):
+        value = (value,)
+    return tuple(cast(entry) for entry in value)
+
+
+def check_listed(name: str, entries: tuple, layers: int, known=None) -> None:
+    """A per-layer list has an entry for each of the stack's `layers`
+    layers, each one of `known` (where the entries are names)."""
+    if len(entries) < layers:
+        raise ValueError(f"{name} lists {len(entries)} layers of {layers}")
+    if known and set(entries[:layers]) - set(known):
+        raise ValueError(f"{name} {entries!r} is not made of {sorted(known)}")
 
 
 SELECTION_BIAS = "e_score_correction_bias"

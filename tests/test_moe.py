@@ -3,12 +3,14 @@ each model of the zoo configures it, against the plain references that
 decide the benchmark cells' `correct` (`perfbench/configs/*_reference.py`,
 which share no code with the program).
 
-Four routers, one a model (`ROUTERS`): Qwen3-Next's renormalised softmax
+Five routers, one a model (`ROUTERS`): Qwen3-Next's renormalised softmax
 over gated-SiLU experts with a gated shared expert; Nemotron-H's sigmoid
 scores with a selection bias over two-product relu^2 experts; DeepSeek-V2's
 softmax left unrenormalised with an ungated shared expert and the
 sequence-wise balancing loss; Laguna's sigmoid scores with a selection bias
-over gated-SiLU experts.  What holds for every router is one case
+over gated-SiLU experts; Mellum 2's renormalised softmax WITH the balancing
+loss over gated-SiLU experts and nothing beside them (`shared_width` 0).
+What holds for every router is one case
 parametrised by it; what one router alone has stands beside it.  Tiny
 sizes, seeded random weights, float32 on the CPU: 1e-5 of the outputs'
 size, gradients 1e-4 of each leaf's largest entry.
@@ -47,6 +49,10 @@ class Router:
     #: where the router's weight [hidden, experts] is among the parameters
     gate: tuple = ("gate",)
     scores: Callable = staticmethod(lambda logits: jax.nn.softmax(logits, -1))
+    #: whether anything stands beside the routed experts (`shared_width` > 0)
+    shared: bool = True
+    #: the key of `moe` that carries the balancing loss's alpha
+    alpha_key: str = "aux_loss_alpha"
 
     def layer(self, first, held, block_rows=-1, alpha=0.0):
         if block_rows == -1:
@@ -153,8 +159,19 @@ LAGUNA = Router(
     block_rows=16, perturbed=True, gate=("gate", "weight"),
     scores=jax.nn.sigmoid,
 )
+MELLUM = Router(
+    _reference("mellum_reference.py"),
+    _tiny("mellum2-12b-a2.5b.json", 256),
+    lambda m, first, held, block_rows, alpha: SparseMoeBlock(
+        m["num_experts"], m["num_experts_per_tok"],
+        m["moe_intermediate_size"], 0, (first, held), True, jnp.float32,
+        block_rows=block_rows, score="softmax", expert_form="gated_silu",
+        balance_alpha=alpha,
+    ),
+    block_rows=16, perturbed=True, shared=False, alpha_key="balance_alpha",
+)
 ROUTERS = {"qwen3-next": QWEN, "nemotron-h": NEMOTRON,
-           "deepseek-v2": DEEPSEEK, "laguna": LAGUNA}
+           "deepseek-v2": DEEPSEEK, "laguna": LAGUNA, "mellum": MELLUM}
 
 
 def _normal(seed, *shape):
@@ -188,6 +205,13 @@ def test_shares_add_up_to_the_uncut_layer(router, held):
     )
     assert _rel(routed + shared, uncut) < 1e-5
     assert _rel(shared, uncut) > 0.05  # the routed part is in the sum
+    # `shared_width` 0: no leaf, no op, and the layer IS the routed sum
+    layer = r.layer(0, held)
+    variables = jax.eval_shape(layer.init, jax.random.PRNGKey(0), x)
+    lowered = jax.jit(lambda v, x: layer.apply(v, x)).lower(variables, x)
+    assert ("moe_shared" in lowered.as_text(debug_info=True)) == r.shared
+    assert any("shared" in key for key in params) == r.shared
+    assert bool(np.asarray(shared).any()) == r.shared
     # and one share alone is the reference's same share
     one = r.experts(r.share(params, 8 - held, held), x, 8 - held, held)
     assert _rel(r.apply(params, x, 8 - held, held)[0], one) < 1e-5
@@ -196,6 +220,7 @@ def test_shares_add_up_to_the_uncut_layer(router, held):
 @pytest.mark.parametrize("router,block_rows,tokens", [
     pytest.param("qwen3-next", 128, 300, id="qwen3-next-128"),
     pytest.param("qwen3-next", 16, 300, id="qwen3-next-16"),
+    pytest.param("mellum", 128, 300, id="mellum-128"),
 ] + [
     pytest.param("nemotron-h", block, tokens, id=f"nemotron-h-{block}-{tokens}")
     for block, tokens in ((128, 300), (16, 300), (512, 300), (512, 600),
@@ -238,6 +263,8 @@ def test_no_pair_dropped_and_counters_right_under_a_skewed_router(
 
 @pytest.mark.parametrize("router,block_rows,tokens", [
     pytest.param("qwen3-next", 32, 150, id="qwen3-next"),
+    pytest.param("mellum", 32, 150, id="mellum"),
+    pytest.param("mellum", 128, 700, id="mellum-128-700"),
 ] + [
     pytest.param("nemotron-h", block, tokens, id=f"nemotron-h-{block}-{tokens}")
     for block, tokens in ((32, 150), (16, 700), (128, 700), (512, 700))
@@ -499,14 +526,17 @@ def test_balance_loss_on_a_hand_made_routing():
     )
 
 
-def test_injected_gradient_is_the_explicit_sums():
+@pytest.mark.parametrize("router", ["deepseek-v2", "mellum"])
+def test_injected_gradient_is_the_explicit_sums(router):
     """The layer's output does not change with alpha and the router
     receives alpha x d(sum f P)/dW_r on top of its gradient; the counts
-    are constants."""
+    are constants.  Whether the weights are renormalised (Mellum 2's) or
+    not (DeepSeek-V2's), and whether a shared expert stands beside."""
+    r = ROUTERS[router]
     alpha = 0.3
-    params = DEEPSEEK.params(5)
+    params = r.params(5)
     x = jnp.asarray(
-        np.random.default_rng(5).normal(size=(2, 40, DEEPSEEK.moe["hidden_size"])),
+        np.random.default_rng(5).normal(size=(2, 40, r.moe["hidden_size"])),
         jnp.float32,
     )
     weight = jnp.asarray(
@@ -514,19 +544,19 @@ def test_injected_gradient_is_the_explicit_sums():
     )
 
     def program(p, a):
-        return jnp.sum(weight * DEEPSEEK.apply(p, x, 0, 8, alpha=a)[0])
+        return jnp.sum(weight * r.apply(p, x, 0, 8, alpha=a)[0])
 
     np.testing.assert_array_equal(
-        DEEPSEEK.apply(params, x, 0, 8, alpha=alpha)[0],
-        DEEPSEEK.apply(params, x, 0, 8)[0],
+        r.apply(params, x, 0, 8, alpha=alpha)[0],
+        r.apply(params, x, 0, 8)[0],
     )
 
     def explicit(p):
         balance = 0.0
         for row in x:
-            probs, ids, _ = DEEPSEEK.ref._route(p, row, DEEPSEEK.moe)
-            balance += DEEPSEEK.ref.balance_loss(
-                probs, ids, dict(aux_loss_alpha=alpha)
+            probs, ids, *_ = r.ref._route(p, row, r.moe)
+            balance += r.ref.balance_loss(
+                probs, ids, {r.alpha_key: alpha}
             ) / len(x)
         return balance
 
@@ -546,7 +576,7 @@ def test_injected_gradient_is_the_explicit_sums():
                 assert float(jnp.abs(want).max()) == 0
                 assert float(jnp.abs(got).max()) < 1e-6
     # the routing collection counts the loss; the ledger gives the mean
-    _, state = DEEPSEEK.apply(params, x, 0, 8, alpha=alpha)
+    _, state = r.apply(params, x, 0, 8, alpha=alpha)
     counted = float(state["balance"])
     assert counted == pytest.approx(float(explicit(params)), rel=1e-5)
     ledger = RoutingLedger()

@@ -132,7 +132,7 @@ def test_every_emitted_name_is_in_the_vocabulary():
         "sparse_adam", "attn", "mlp", "lm_head_loss", "optimizer",
         "gdn", "gdn_scan", "moe", "moe_route", "moe_experts", "moe_shared",
         "ssm", "ssm_scan", "gdn_mix", "mla_latent", "mla_core",
-        "attn_full", "attn_window", "attn_gate",
+        "attn_full", "attn_window", "attn_gate", "attn_proj", "attn_rotary",
     }
 
 

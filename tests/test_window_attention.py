@@ -55,13 +55,20 @@ def _engine(window, block=BLOCK):
 # W smaller than, equal to and larger than a block of 64; one key; one
 # short of and one past a block's edge; the whole sequence and beyond.
 WINDOWS = [1, 17, 63, 64, 65, 100, 128, 129, 255, 256, 1000]
+#: `_qkv`'s shape for the band's other published shape (Mellum 2's: a
+#: window of 1024 under 8 query heads a key-value head), where the front
+#: door gives the engine blocks of 512.
+MELLUM_BAND = dict(hq=8, hkv=1, t=2048, d=16, b=1)
 
 
 @pytest.mark.parametrize("impl", ["xla", "auto"])
-@pytest.mark.parametrize("window", WINDOWS)
-def test_band_matches_a_masked_plain_softmax(impl, window):
+@pytest.mark.parametrize("window,shape", [
+    pytest.param(window, dict(hq=4, hkv=2), id=str(window))
+    for window in WINDOWS
+] + [pytest.param(1024, MELLUM_BAND, id="1024-of-2048-x8")])
+def test_band_matches_a_masked_plain_softmax(impl, window, shape):
     """`impl` is the front door's; the engine beneath is the XLA one."""
-    q, k, v, weight = _qkv(window, 4, 2)
+    q, k, v, weight = _qkv(window, **shape)
     engine = _engine(window)
     want = _plain(q, k, v, window)
     # the front door, with its own choice of blocks, gives the same
@@ -80,7 +87,7 @@ def test_band_matches_a_masked_plain_softmax(impl, window):
     for name, a, b, c in zip("qkv", got, ref, front(q, k, v)):
         np.testing.assert_allclose(a, b, rtol=2e-3, atol=2e-4, err_msg=name)
         np.testing.assert_allclose(c, b, rtol=2e-3, atol=2e-4, err_msg=name)
-    if window < T:  # the band is in the result: full causal differs
+    if window < q.shape[1]:  # the band is in the result: full causal differs
         assert float(jnp.max(jnp.abs(engine(q, k, v) - _plain(q, k, v)))) > 1e-2
 
 
