@@ -14,7 +14,9 @@ strict apply; weights random from --seed, data written from --seed):
                XLA twin (fused sparse lookup / lookup+FM / dedup+apply,
                flash attention fwd+bwd, the ring step), the delta
                rule's kernel pair and the two passes around it
-               (ops/gdn_passes.py), which the backend picks — correctness only
+               (ops/gdn_passes.py), which the backend picks, and the pass
+               in front of the attention engine (ops/rotary_pack.py) —
+               correctness only
   train        `python -m elasticdl_tpu.client.main train` with
                ParameterServerStrategy on an ETRF file: master -> task
                dispatch -> one worker subprocess -> file -> native codec
@@ -653,6 +655,48 @@ def phase_kernels(args) -> dict:
         scale = max(float(jnp.max(jnp.abs(w))), 1.0)
         check(f"gdn passes {which} ~ jax.numpy chain", g, w,
               1e-5, 1e-5 * scale, secs)
+
+    # -- from a projection's result to the attention engine's operand
+    # (ops/rotary_pack.py), against apply_rotary after the head norm: the
+    # same float32 ops on both sides (1e-6: the order of a norm's sum);
+    # the bfloat16 operand within one rounding ------------------------------
+    from elasticdl_tpu.ops import gqa, rotary_pack
+
+    keys = jax.random.split(jax.random.PRNGKey(args.seed + 17), 3)
+    result = jax.random.normal(keys[0], (2, t_rule, 4, 128))
+    weight = 1.0 + 0.1 * jax.random.normal(keys[1], (128,))
+    d_operand = jax.random.normal(keys[2], (2, 4, t_rule, 128))
+    for rotary_dim, normed in ((128, False), (64, True)):
+        cos, sin = gqa.rotary_tables(jnp.arange(t_rule), rotary_dim, 1e4)
+
+        def pack_grads(kernels, dtype):
+            def loss(x, w):
+                w = w if normed else None
+                out = (
+                    rotary_pack.rotary_pack(
+                        x, cos, sin, dtype, w, interpret=not on_tpu
+                    ) if kernels
+                    else rotary_pack.rotary_pack_xla(x, cos, sin, dtype, w)
+                )
+                return jnp.sum(out * d_operand), out
+
+            return jax.grad(loss, argnums=(0, 1), has_aux=True)
+
+        what = f"rotary_pack {rotary_dim}/128" + (" normed" if normed else "")
+        for dtype, rtol in ((jnp.float32, 1e-6), (jnp.bfloat16, 2 ** -7)):
+            (got_g, got_out), secs = run(
+                what, pack_grads(True, dtype), result, weight
+            )
+            want_g, want_out = twin(pack_grads(False, dtype), result, weight)
+            name = f"{what} {jnp.dtype(dtype).name}"
+            check(f"{name} ~ apply_rotary", got_out, want_out, rtol, 1e-6,
+                  secs)
+            check(f"{name} d result ~ its vjp", got_g[0], want_g[0],
+                  1e-5, 1e-5, secs)
+            if normed:
+                scale = float(jnp.max(jnp.abs(want_g[1])))
+                check(f"{name} d weight ~ its vjp", got_g[1], want_g[1],
+                      1e-5, 1e-5 * scale, secs)
 
     return {"device": device, "checks": len(checks)}
 

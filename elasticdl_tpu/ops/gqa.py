@@ -23,13 +23,27 @@ scaled by `scale`, 1/sqrt(Dqk) where none is given.  Two engines:
   T = 8192, 16 heads, 2 sequences); cutting its queries into independent
   rematerialised blocks leaves XLA free to hold several blocks' slabs at
   once (11 GB, compiled for a v5e).  So this engine walks the query blocks
-  in a `lax.scan` and, for each, only the key blocks a causal mask lets it
+  in a loop and, for each, only the key blocks a causal mask lets it
   see (a device-side loop with a trip count of its own, so the half of
   the score matrix above the diagonal is never computed), keeps the
   output and the log-sum-exp, and has its own backward pass that
   recomputes a block's probabilities from them.  One [B, Hq, block, block]
   slab is alive at a time.  Query heads of one key-value head are batched
   into one product, so K and V are never repeated.
+
+  Its layout contract (ISSUE 43): operands, results and their gradients
+  are [B, H, T, D], HEADS IN FRONT OF TOKENS, so that a block of one
+  head is a contiguous [block, D] matrix, and both loops take block `i`
+  by a dynamic slice of the token axis and write their results the same
+  way.  Nothing is laid out again between the caller and the loops: the
+  old layout, the block axis moved in front of the batch for a
+  `lax.scan`, was free at one sequence and a physical transpose at two,
+  and the compiler pulled it back through whatever produced q and k, at
+  a float32 copy a crossing (8-15 GB a step in four cells, PERF.md
+  section 6, PR 43).  A caller that holds a projection's result packs q
+  and k with `ops/rotary_pack.py` (norm, rotation, one rounding, this
+  layout, one pass) and says `packed=True`; for the others the front
+  door swaps the axes (`heads_first`) on the way in and out.
 
 `causal_attention(window=W)` is a causal BAND: a query at t reads the keys
 `t - W < s <= t`.  The XLA engine alone runs it, and skips what lies
@@ -165,12 +179,12 @@ def _first_block(i, block: int, window):
 
 
 def _scores(q_i, k_j, i, j, block, scale, window=None):
-    """[B,Bq,N,G,D] x [B,Bk,N,D] -> masked scores [B,N,G,Bq,Bk], float32.
+    """[B,N,G,Bq,D] x [B,N,Bk,D] -> masked scores [B,N,G,Bq,Bk], float32.
     A row with no key in block j (a band's first block) reads NEG_INF
     throughout; the diagonal block, visited last, holds its own key, and
     the running maximum then wipes what such a row gathered."""
     s = jnp.einsum(
-        "bqngd,bknd->bngqk", q_i, k_j, preferred_element_type=jnp.float32
+        "bngqd,bnkd->bngqk", q_i, k_j, preferred_element_type=jnp.float32
     ) * scale
     rows = i * block + jnp.arange(block)
     cols = j * block + jnp.arange(block)
@@ -180,42 +194,56 @@ def _scores(q_i, k_j, i, j, block, scale, window=None):
     return jnp.where(masked, NEG_INF, s)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def causal_gqa_attention(q, k, v, block: int, scale=None, window=None):
-    """q [B, T, Hq, D]; k [B, T, Hkv, D]; v [B, T, Hkv, Dv] ->
-    [B, T, Hq, Dv]; softmax of q k^T scale (1/sqrt(D) where none is
-    given) under a causal mask, float32 accumulation.  With `window` a
-    query reads the `window` keys up to its own, and a query block visits
-    only the key blocks that band touches, forward and backward."""
-    return _gqa_fwd(q, k, v, block, scale, window)[0]
+def _take(x, i, block, axis=-2):
+    """Block `i` of the token axis (the one before the last; the last of
+    a row statistic): read where it lies."""
+    return jax.lax.dynamic_slice_in_dim(x, i * block, block, axis % x.ndim)
 
 
-def _blocked(x, block):  # [B, T, ...] -> [T / block, B, block, ...]
-    b, t = x.shape[:2]
-    return jnp.moveaxis(
-        x.reshape((b, t // block, block) + x.shape[2:]), 1, 0
+def _put(x, x_i, i, block, axis=-2, add=False):
+    """`x` with block `i` of its token axis set to `x_i`, or raised by
+    it."""
+    if add:
+        x_i = x_i + _take(x, i, block, axis)
+    return jax.lax.dynamic_update_slice_in_dim(
+        x, x_i, i * block, axis % x.ndim
     )
 
 
+@partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def causal_gqa_attention(q, k, v, block: int, scale=None, window=None):
+    """HEADS IN FRONT OF TOKENS: q [B, Hq, T, D]; k [B, Hkv, T, D];
+    v [B, Hkv, T, Dv] -> [B, Hq, T, Dv] (`heads_first` packs and unpacks;
+    `rotary_pack` makes q and k so); softmax of q k^T scale (1/sqrt(D)
+    where none is given) under a causal mask, float32 accumulation.  A
+    block of one head is a contiguous [block, D] matrix in every operand
+    and result, forward and backward, and the loops take block `i` by a
+    dynamic slice of the token axis: nothing is laid out again for them.
+    With `window` a query reads the `window` keys up to its own, and a
+    query block visits only the key blocks that band touches, forward and
+    backward."""
+    return _gqa_fwd(q, k, v, block, scale, window)[0]
+
+
 def _gqa_fwd(q, k, v, block, scale, window=None):
-    b, t, hq, d = q.shape
-    n, dv = k.shape[2], v.shape[3]
+    b, hq, t, d = q.shape
+    n, dv = k.shape[1], v.shape[3]
     g = hq // n
     scale = 1.0 / (d ** 0.5) if scale is None else scale
-    qb = _blocked(q.reshape(b, t, n, g, d), block)
-    kb, vb = _blocked(k, block), _blocked(v, block)
+    grouped = q.reshape(b, n, g, t, d)
 
-    def q_step(_, xs):
-        q_i, i = xs
+    def q_step(i, results):
+        out, lse = results
+        q_i = _take(grouped, i, block)
 
         def kv_step(j, carry):
             m, l, acc = carry
-            s = _scores(q_i, kb[j], i, j, block, scale, window)
+            s = _scores(q_i, _take(k, j, block), i, j, block, scale, window)
             m_new = jnp.maximum(m, jnp.max(s, axis=-1))
             p = jnp.exp(s - m_new[..., None])
             fix = jnp.exp(m - m_new)
             acc = acc * fix[..., None] + jnp.einsum(
-                "bngqk,bknd->bngqd", p.astype(v.dtype), vb[j],
+                "bngqk,bnkd->bngqd", p.astype(v.dtype), _take(v, j, block),
                 preferred_element_type=jnp.float32,
             )
             return m_new, l * fix + jnp.sum(p, axis=-1), acc
@@ -228,70 +256,73 @@ def _gqa_fwd(q, k, v, block, scale, window=None):
                 jnp.zeros((b, n, g, block, dv), jnp.float32),
             ),
         )
-        return None, ((acc / l[..., None]).astype(q.dtype), m + jnp.log(l))
+        return (
+            _put(out, (acc / l[..., None]).astype(q.dtype), i, block),
+            _put(lse, m + jnp.log(l), i, block, axis=-1),
+        )
 
-    _, (out, lse) = jax.lax.scan(q_step, None, (qb, jnp.arange(t // block)))
-    # out [T/block, B, N, G, block, Dv] -> [B, T, Hq, Dv]
-    out = jnp.moveaxis(out, (0, 4), (1, 2)).reshape(b, t, hq, dv)
+    out, lse = jax.lax.fori_loop(0, t // block, q_step, (
+        jnp.zeros((b, n, g, t, dv), q.dtype),
+        jnp.zeros((b, n, g, t), jnp.float32),
+    ))
+    out = out.reshape(b, hq, t, dv)
     return out, (q, k, v, out, lse)
 
 
 def _gqa_bwd(block, scale, window, residuals, dout):
     q, k, v, out, lse = residuals
-    b, t, hq, d = q.shape
-    n, dv = k.shape[2], v.shape[3]
+    b, hq, t, d = q.shape
+    n, dv = k.shape[1], v.shape[3]
     g = hq // n
     scale = 1.0 / (d ** 0.5) if scale is None else scale
-    qb = _blocked(q.reshape(b, t, n, g, d), block)
-    dob = _blocked(dout.reshape(b, t, n, g, dv), block)
-    delta = _blocked(jnp.sum(
-        dout.astype(jnp.float32) * out.astype(jnp.float32), axis=-1
-    ).reshape(b, t, n, g), block)                    # [T/block,B,block,N,G]
-    kb, vb = _blocked(k, block), _blocked(v, block)
+    grouped = q.reshape(b, n, g, t, d)
+    dout = dout.reshape(b, n, g, t, dv)
+    delta = jnp.sum(
+        dout.astype(jnp.float32)
+        * out.reshape(dout.shape).astype(jnp.float32), axis=-1
+    )                                                    # [B,N,G,T]
 
-    def q_step(carry, xs):
-        q_i, do_i, delta_i, lse_i, i = xs
-        delta_i = jnp.moveaxis(delta_i, 1, 3)        # [B,N,G,block]
+    def q_step(i, grads):
+        dq, dk, dv = grads
+        q_i, do_i = _take(grouped, i, block), _take(dout, i, block)
+        delta_i, lse_i = _take(delta, i, block, -1), _take(lse, i, block, -1)
 
         def kv_step(j, inner):
             dq_i, dk, dv = inner
-            s = _scores(q_i, kb[j], i, j, block, scale, window)
+            k_j, v_j = _take(k, j, block), _take(v, j, block)
+            s = _scores(q_i, k_j, i, j, block, scale, window)
             p = jnp.exp(s - lse_i[..., None])
             dv_j = jnp.einsum(
-                "bngqk,bqngd->bknd", p.astype(q.dtype), do_i,
+                "bngqk,bngqd->bnkd", p.astype(q.dtype), do_i,
                 preferred_element_type=jnp.float32,
             )
             dp = jnp.einsum(
-                "bqngd,bknd->bngqk", do_i, vb[j],
+                "bngqd,bnkd->bngqk", do_i, v_j,
                 preferred_element_type=jnp.float32,
             )
             ds = (p * (dp - delta_i[..., None]) * scale).astype(q.dtype)
             dq_i = dq_i + jnp.einsum(
-                "bngqk,bknd->bqngd", ds, kb[j],
+                "bngqk,bnkd->bngqd", ds, k_j,
                 preferred_element_type=jnp.float32,
             )
             dk_j = jnp.einsum(
-                "bngqk,bqngd->bknd", ds, q_i,
+                "bngqk,bngqd->bnkd", ds, q_i,
                 preferred_element_type=jnp.float32,
             )
-            return dq_i, dk.at[j].add(dk_j), dv.at[j].add(dv_j)
+            return (dq_i, _put(dk, dk_j, j, block, add=True),
+                    _put(dv, dv_j, j, block, add=True))
 
         dq_i, dk, dv = jax.lax.fori_loop(
             _first_block(i, block, window), i + 1, kv_step,
-            (jnp.zeros(q_i.shape, jnp.float32),) + carry,
+            (jnp.zeros(q_i.shape, jnp.float32), dk, dv),
         )
-        return (dk, dv), dq_i.astype(q.dtype)
+        return _put(dq, dq_i.astype(q.dtype), i, block), dk, dv
 
-    (dk, dv), dq = jax.lax.scan(
-        q_step,
-        (jnp.zeros(kb.shape, jnp.float32), jnp.zeros(vb.shape, jnp.float32)),
-        (qb, dob, delta, lse, jnp.arange(t // block)),
-    )
-
-    def unblocked(x, like):  # [T/block, B, block, ...] -> like's shape
-        return jnp.moveaxis(x, 0, 1).reshape(like.shape).astype(like.dtype)
-
-    return unblocked(dq, q), unblocked(dk, k), unblocked(dv, v)
+    dq, dk, dv = jax.lax.fori_loop(0, t // block, q_step, (
+        jnp.zeros(grouped.shape, q.dtype),
+        jnp.zeros(k.shape, jnp.float32), jnp.zeros(v.shape, jnp.float32),
+    ))
+    return dq.reshape(q.shape), dk.astype(k.dtype), dv.astype(v.dtype)
 
 
 causal_gqa_attention.defvjp(_gqa_fwd, _gqa_bwd)
@@ -301,8 +332,14 @@ def _head_sizes(d: int, dv: int) -> str:
     return f"D={d}" if d == dv else f"Dqk={d} Dv={dv}"
 
 
+def heads_first(x):
+    """[B, T, H, D] <-> [B, H, T, D]."""
+    return jnp.swapaxes(x, 1, 2)
+
+
 def causal_attention(q, k, v, *, scale=None, window=None,
-                     impl: str = "auto", block: int = 512):
+                     impl: str = "auto", block: int = 512,
+                     packed: bool = False):
     """Causal softmax attention, grouped-query heads; the scores are
     scaled by `scale`, 1/sqrt(Dqk) where none is given.  With `window` a
     query at t reads the keys `t - window < s <= t` (its own among them):
@@ -317,6 +354,8 @@ def causal_attention(q, k, v, *, scale=None, window=None,
     if impl not in ("auto", "pallas", "xla"):
         raise ValueError(f"impl must be auto, pallas or xla, got {impl!r}")
     _, t, hq, d = q.shape
+    if packed:
+        t, hq = hq, t
     dv = v.shape[3]
     if window is not None and window >= t:
         window = None  # the band holds every key a causal mask leaves
@@ -335,6 +374,10 @@ def causal_attention(q, k, v, *, scale=None, window=None,
         and jax.default_backend() == "tpu" and supports(t, d, d_v=dv)
     )
     if use_pallas:
+        if packed:  # the kernel's own transposes meet these and go
+            return heads_first(causal_attention(
+                *map(heads_first, (q, k, v)), scale=scale, impl="pallas",
+            ))
         n_rep = hq // k.shape[2]
         logger.info(
             "attention engine: pallas flash_attention T=%d %s "
@@ -359,7 +402,13 @@ def causal_attention(q, k, v, *, scale=None, window=None,
     block = _block_size(t, block)
     logger.info(
         "attention engine: xla causal_gqa_attention T=%d %s "
-        "(blocks of %d)", t, sizes, block,
+        "(blocks of %d; operands: %s)", t, sizes, block,
+        "heads first, packed by the caller" if packed
+        else "as given, tokens first",
     )
     with jax.named_scope("attn"):
-        return causal_gqa_attention(q, k, v, block, scale, window)
+        if packed:
+            return causal_gqa_attention(q, k, v, block, scale, window)
+        return heads_first(causal_gqa_attention(
+            *map(heads_first, (q, k, v)), block, scale, window
+        ))

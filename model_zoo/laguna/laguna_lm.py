@@ -85,6 +85,7 @@ import jax.numpy as jnp
 
 from elasticdl_tpu.layers.moe import GatedMLP, SparseMoeBlock
 from elasticdl_tpu.ops import gqa
+from elasticdl_tpu.ops.rotary_pack import rotary_pack
 # The norm, the projection, and the rest of the zoo contract of any causal
 # LM on `synthetic://lm` data: mean next-token cross-entropy over float32
 # logits (under the `lm_head_loss` scope), perplexity and accuracy.  The
@@ -114,13 +115,13 @@ class Attention(nn.Module):
             dense(n * hd, c.dtype, name)(x).reshape(b, t, n, hd)
             for name, n in (("q_proj", h), ("k_proj", hkv), ("v_proj", hkv))
         )
-        q = gqa.apply_rotary(q, cos, sin).astype(c.dtype)
-        k = gqa.apply_rotary(k, cos, sin).astype(c.dtype)
+        q, k = (rotary_pack(p, cos, sin, c.dtype) for p in (q, k))
         with jax.named_scope("attn_window" if self.sliding else "attn_full"):
-            out = gqa.causal_attention(
-                q, k, v.astype(c.dtype), impl=c.attn_impl,
+            out = gqa.heads_first(gqa.causal_attention(
+                q, k, gqa.heads_first(v.astype(c.dtype)), impl=c.attn_impl,
                 window=c.sliding_window if self.sliding else None,
-            )
+                packed=True,
+            ))
         if c.gating:
             with jax.named_scope("attn_gate"):
                 w_gate = self.param(

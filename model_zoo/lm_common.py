@@ -186,6 +186,20 @@ class RMSNorm(nn.Module):
         )
 
 
+class NormWeight(nn.Module):
+    """An `RMSNorm`'s parameter alone (`weight` [width], from 1), under
+    the norm's name, for a pass that takes the norm inside
+    (`ops/rotary_pack.py`)."""
+
+    width: int
+
+    @nn.compact
+    def __call__(self):
+        return self.param(
+            "weight", nn.initializers.ones_init(), (self.width,), jnp.float32,
+        )
+
+
 class _Conv1d(nn.Module):
     """The source's depthwise `conv1d`: `kernel` [taps, channels], `bias`."""
 

@@ -97,13 +97,14 @@ import jax.numpy as jnp
 
 from elasticdl_tpu.layers.moe import GatedMLP, SparseMoeBlock
 from elasticdl_tpu.ops import gqa
+from elasticdl_tpu.ops.rotary_pack import rotary_pack
 # The norm, the projection, the optimizer's warm-up and the rest of the zoo
 # contract of any causal LM on `synthetic://lm` data: mean next-token
 # cross-entropy over float32 logits (under the `lm_head_loss` scope),
 # perplexity and accuracy.
 from model_zoo.lm_common import (  # noqa: F401
-    VOCAB, RMSNorm, check_listed, custom_data_reader, dataset_fn, dense,
-    eval_metrics_fn, listed, loss, warmup_adamw,
+    VOCAB, NormWeight, RMSNorm, check_listed, custom_data_reader, dataset_fn,
+    dense, eval_metrics_fn, listed, loss, warmup_adamw,
 )
 
 FULL, SLIDING = "full_attention", "sliding_attention"
@@ -126,16 +127,21 @@ class Attention(nn.Module):
                                 ("v_proj", hkv))
             )
         with jax.named_scope("attn_rotary"):
-            if c.qk_norm:  # over a head's columns, one weight vector each
-                q = RMSNorm(c.rms_norm_eps, name="q_norm")(q)
-                k = RMSNorm(c.rms_norm_eps, name="k_norm")(k)
-            q = gqa.apply_rotary(q, cos, sin).astype(c.dtype)
-            k = gqa.apply_rotary(k, cos, sin).astype(c.dtype)
-        with jax.named_scope("attn_window" if self.sliding else "attn_full"):
-            out = gqa.causal_attention(
-                q, k, v.astype(c.dtype), impl=c.attn_impl,
-                window=c.sliding_window if self.sliding else None,
+            # over a head's columns, one weight vector each
+            q, k = (
+                rotary_pack(
+                    p, cos, sin, c.dtype,
+                    NormWeight(hd, name=norm)() if c.qk_norm else None,
+                    c.rms_norm_eps,
+                )
+                for p, norm in ((q, "q_norm"), (k, "k_norm"))
             )
+        with jax.named_scope("attn_window" if self.sliding else "attn_full"):
+            out = gqa.heads_first(gqa.causal_attention(
+                q, k, gqa.heads_first(v.astype(c.dtype)), impl=c.attn_impl,
+                window=c.sliding_window if self.sliding else None,
+                packed=True,
+            ))
         with jax.named_scope("attn_proj"):
             return dense(d, c.dtype, "o_proj")(
                 out.reshape(b, t, h * hd).astype(c.dtype)

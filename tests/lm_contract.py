@@ -32,6 +32,7 @@ import importlib
 import importlib.util
 import json
 import os
+import re
 from typing import Any, Callable, NamedTuple, Optional
 
 import jax
@@ -212,6 +213,9 @@ class CompileSpec:
     total: dict                        # sequences -> (least, most)
     in_text: tuple = ()
     not_in_text: tuple = ()
+    #: (least, most) bytes of the top-level `copy` ops of 16 MB and more
+    #: (`program_moves`) at the cell's own count of sequences
+    copy_bytes: tuple = ()
     #: sizes the cell's `device_bytes` and `assumed.remat` state in words
     stated_sizes: tuple = ()
     names_mesh: bool = False           # the stack is told the trainer's mesh
@@ -687,49 +691,106 @@ def test_two_task_elasticdl_train_end_to_end(lm, tmp_path):
 # ---------------------------------------------------------------------------
 
 
-def test_window_program_compiles_and_fits_for_v5e(
-    topo, no_persistent_cache, monkeypatch, lm, sequences
-):
-    """`dp_trainer`'s two-step window program as the worker compiles it
-    for the model's cell, at the widths of the cell's JSON `model` and the
-    flags its job adds (`job_only`), for a DESCRIBED v5e: the state is
-    donated and, with the temporaries, fits the chip's 16 GB (or, at a
-    count of sequences the cell does not run, is known not to).  A
-    described device leaves `jax.default_backend()` at the CPU, so the case
-    says "tpu" and one device for the engines' choice."""
+def compile_program(lm, topo, sequences, one_step=False):
+    """`dp_trainer`'s two-step window program (or its one step) as the
+    worker compiles it for the model's cell, at the widths of the cell's
+    JSON `model` and the flags its job adds (`job_only`), for a DESCRIBED
+    v5e (`conftest.topo`), the state donated.  A described device leaves
+    `jax.default_backend()` at the CPU, so for the engines' choice the
+    compile says "tpu" and one device.  `scripts/program_copies.py` reads
+    the same program."""
+    from unittest import mock
+
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from elasticdl_tpu.parallel import MeshConfig, build_mesh
     from elasticdl_tpu.parallel.dp_trainer import DataParallelTrainer
 
     want, config, zoo = lm.compile, lm.config, lm.zoo
-    assert next(iter(want.total)) == lm.job_flags[1]  # the cell's own
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    monkeypatch.setattr(jax, "device_count", lambda: 1)
-    mesh = build_mesh(MeshConfig(data=1, model=1), devices=topo.devices[:1])
-    keywords = dict(lm.job_only, **({"mesh": mesh} if want.names_mesh else {}))
-    tokens = config["model"]["sample_tokens"]
-    trainer = DataParallelTrainer(
-        lm.build(config["model"], use_bf16=True, **keywords),
-        zoo.loss, zoo.optimizer(), mesh,
-    )
-    on_chip = NamedSharding(mesh, P())
-    state, _ = jax.eval_shape(
-        lambda: trainer._make_state(
-            jax.random.PRNGKey(0), jnp.zeros((sequences, tokens), jnp.int32)
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"), \
+            mock.patch.object(jax, "device_count", lambda: 1):
+        mesh = build_mesh(
+            MeshConfig(data=1, model=1), devices=topo.devices[:1]
         )
-    )
-    state = jax.tree.map(
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=on_chip),
-        state,
-    )
-    window = jax.ShapeDtypeStruct(
-        (2, sequences, tokens), jnp.int32, sharding=on_chip
-    )
-    mask = jax.ShapeDtypeStruct((2, sequences), jnp.float32, sharding=on_chip)
-    compiled = jax.jit(
-        trainer._train_window_impl, donate_argnums=(0,)
-    ).lower(state, window, window, mask).compile()
+        keywords = dict(
+            lm.job_only, **({"mesh": mesh} if want.names_mesh else {})
+        )
+        tokens = config["model"]["sample_tokens"]
+        trainer = DataParallelTrainer(
+            lm.build(config["model"], use_bf16=True, **keywords),
+            zoo.loss, zoo.optimizer(), mesh,
+        )
+        on_chip = NamedSharding(mesh, P())
+        state, _ = jax.eval_shape(
+            lambda: trainer._make_state(
+                jax.random.PRNGKey(0),
+                jnp.zeros((sequences, tokens), jnp.int32),
+            )
+        )
+        state = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=on_chip),
+            state,
+        )
+        lead = () if one_step else (2,)
+        batch = jax.ShapeDtypeStruct(
+            lead + (sequences, tokens), jnp.int32, sharding=on_chip
+        )
+        mask = jax.ShapeDtypeStruct(
+            lead + (sequences,), jnp.float32, sharding=on_chip
+        )
+        program = (trainer._train_step_impl if one_step
+                   else trainer._train_window_impl)
+        return jax.jit(program, donate_argnums=(0,)).lower(
+            state, batch, batch, mask
+        ).compile()
+
+
+_HLO_BYTES = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2, "s16": 2,
+              "u16": 2, "f32": 4, "s32": 4, "u32": 4, "f64": 8, "s64": 8,
+              "u64": 8}
+_HLO_OP = re.compile(
+    r"^\s*(?:ROOT )?%?[\w.\-]+ = (\w+)\[([\d,]*)\]\S* ([\w\-]+)\("
+)
+_HLO_OP_NAME = re.compile(r'op_name="([^"]*)"')
+
+
+def program_moves(text, opcodes=("copy",), least=16 << 20):
+    """The ops of a compiled program's text that lay an array out again
+    in HBM: every instruction with one of `opcodes` that stands in a
+    computation of its own (the entry, a loop's body; not inside a
+    fusion) and writes `least` bytes or more -> [(opcode, "f32[8,128]",
+    bytes written, op_name or "")].  A loop's body counts once: the
+    window's two steps are one step's bytes."""
+    found, fused = [], False
+    for line in text.splitlines():
+        if not line.startswith(" "):  # a computation's header, or its end
+            fused = "fused_computation" in line
+            continue
+        match = None if fused else _HLO_OP.match(line)
+        if match is None or match.group(3) not in opcodes:
+            continue
+        dtype, dims = match.group(1), match.group(2)
+        size = _HLO_BYTES.get(dtype, 4) * int(
+            np.prod([int(d) for d in dims.split(",") if d], dtype=np.int64)
+        )
+        if size >= least:
+            name = _HLO_OP_NAME.search(line)
+            found.append((match.group(3), f"{dtype}[{dims}]", size,
+                          name.group(1) if name else ""))
+    return found
+
+
+def test_window_program_compiles_and_fits_for_v5e(
+    topo, no_persistent_cache, lm, sequences
+):
+    """The cell's window program (`compile_program`): the state is donated
+    and, with the temporaries, fits the chip's 16 GB (or, at a count of
+    sequences the cell does not run, is known not to); at the cell's own
+    count the engines' operands are not laid out again in HBM beyond the
+    bytes the descriptor states."""
+    want, config = lm.compile, lm.config
+    assert next(iter(want.total)) == lm.job_flags[1]  # the cell's own
+    compiled = compile_program(lm, topo, sequences)
     memory = compiled.memory_analysis()
     print(lm.cell, "window bytes", sequences, memory.argument_size_in_bytes,
           memory.temp_size_in_bytes, memory.alias_size_in_bytes)
@@ -744,6 +805,10 @@ def test_window_program_compiles_and_fits_for_v5e(
         assert kernel in text, kernel
     for kernel in want.not_in_text:
         assert kernel not in text, kernel
+    if want.copy_bytes and sequences == lm.job_flags[1]:
+        copied = sum(size for _, _, size, _ in program_moves(text))
+        least, most = want.copy_bytes
+        assert least <= copied <= most, copied
     # the sizes the configuration's file states are these
     for size in want.stated_sizes:
         assert size in config["device_bytes"], size

@@ -132,6 +132,9 @@ SPEC = LMSpec(
         in_text=("conv_silu_fwd", "conv_silu_bwd", "gated_norm_fwd",
                  "gated_norm_bwd", "delta_rule_fwd", "delta_rule_bwd"),
         names_mesh=True,
+        # 5.46 GB before PR 43; 3.3 GB are the head's, the experts' and the
+        # router's, 1.2 the one attention layer's q and gated output
+        copy_bytes=(2.46e9, 5.41e9),
     ),
     scope_widths=dict(
         vocab_size=64, hidden_size=32, head_dim=16, num_attention_heads=2,

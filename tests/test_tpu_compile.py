@@ -227,7 +227,43 @@ def _gdn_pass(which, backward):
     return bwd if backward else fwd, [(s, jnp.float32) for s in shapes]
 
 
+def _rotary_pack(which, backward):
+    """`ops/rotary_pack.py` at the cells' shapes: Laguna's 64 query heads
+    of a banded layer (the whole head rotated: one roll), its 48 of a
+    full layer (half of the head: two rolls), Mellum 2's 4 key heads of
+    two sequences under the head norm."""
+    from elasticdl_tpu.ops import gqa, rotary_pack
+
+    b, h, rotary_dim, normed = {
+        "laguna_window_q": (1, 64, 128, False),
+        "laguna_full_q": (1, 48, 64, False),
+        "mellum_k": (2, 4, 128, True),
+    }[which]
+
+    def fwd(x, *weight):
+        cos, sin = gqa.rotary_tables(jnp.arange(8192), rotary_dim, 1e4)
+        return rotary_pack.rotary_pack(
+            x, cos, sin, jnp.bfloat16, *weight, interpret=False
+        )
+
+    def bwd(d_out, *args):
+        return jax.vjp(fwd, *args)[1](d_out)
+
+    shapes = [((b, 8192, h, 128), jnp.float32)] + [
+        ((128,), jnp.float32)
+    ] * normed
+    if backward:
+        return bwd, [((b, h, 8192, 128), jnp.bfloat16)] + shapes
+    return fwd, shapes
+
+
 _CASES = {
+    **{
+        f"rotary_pack_{which}_{'bwd' if backward else 'fwd'}":
+            functools.partial(_rotary_pack, which, backward)
+        for which in ("laguna_window_q", "laguna_full_q", "mellum_k")
+        for backward in (False, True)
+    },
     **{
         f"gdn_{which}_{'bwd' if backward else 'fwd'}":
             functools.partial(_gdn_pass, which, backward)

@@ -46,10 +46,10 @@ def _qkv(seed, hq, hkv, t=T, d=32, b=2):
 def _engine(window, block=BLOCK):
     """The XLA engine in blocks of 64 (under a band `causal_attention`
     gives it blocks of half the window, 128 at the least)."""
-    return lambda q, k, v: gqa.causal_gqa_attention(
-        q, k, v, block, None,
+    return lambda q, k, v: gqa.heads_first(gqa.causal_gqa_attention(
+        *map(gqa.heads_first, (q, k, v)), block, None,
         window if window and window < q.shape[1] else None,
-    )
+    ))
 
 
 # W smaller than, equal to and larger than a block of 64; one key; one
@@ -125,9 +125,7 @@ def test_no_window_and_a_window_of_the_whole_sequence_are_todays_output(impl):
     for window in (None, T, T + 1):
         np.testing.assert_array_equal(out(window), base)
     if impl == "xla":
-        np.testing.assert_array_equal(
-            base, gqa.causal_gqa_attention(q, k, v, BLOCK, None)
-        )
+        np.testing.assert_array_equal(base, _engine(None)(q, k, v))
     else:
         np.testing.assert_array_equal(
             base, fa.flash_attention(
@@ -273,10 +271,13 @@ def test_band_with_another_head_size_for_v_and_a_scale():
 
 
 @pytest.mark.parametrize("window,impl,engine,ending", [
-    (512, "auto", "xla causal_gqa_attention", "(blocks of 256)"),
-    (512, "xla", "xla causal_gqa_attention", "(blocks of 256)"),
+    (512, "auto", "xla causal_gqa_attention",
+     "(blocks of 256; operands: as given, tokens first)"),
+    (512, "xla", "xla causal_gqa_attention",
+     "(blocks of 256; operands: as given, tokens first)"),
     (None, "auto", "pallas flash_attention", "repeat_kv x8)"),
-    (None, "xla", "xla causal_gqa_attention", "(blocks of 512)"),
+    (None, "xla", "xla causal_gqa_attention",
+     "(blocks of 512; operands: as given, tokens first)"),
 ])
 def test_log_line_names_the_window_and_the_repeats(
     window, impl, engine, ending, monkeypatch, caplog
@@ -311,3 +312,79 @@ def test_log_line_names_the_window_and_the_repeats(
         line.startswith(want + " (") and line.endswith(ending)
         for line in lines
     ), lines
+
+
+@pytest.mark.parametrize("window", [None, 40], ids=["causal", "band-40"])
+@pytest.mark.parametrize("d,dv", [(128, 128), (192, 128), (128, 256)],
+                         ids=["128", "192-128", "128-256"])
+@pytest.mark.parametrize("group", [1, 6, 8])
+@pytest.mark.parametrize("sequences", [1, 2])
+def test_heads_first_engine_at_one_and_two_sequences(
+    sequences, group, d, dv, window
+):
+    """The engine as the packed callers reach it (ISSUE 43): operands and
+    results [B, H, T, D], blocks taken in place by a dynamic slice of the
+    token axis, at one sequence (where the old block-major layout was
+    free) and at two (where it was a physical transpose), with one, six
+    and eight query heads a key-value head and the zoo's head sizes, v's
+    other than q's among them."""
+    keys = jax.random.split(jax.random.PRNGKey(group + d + dv), 4)
+    t, hkv, block = 128, 2, 32
+    q = jax.random.normal(keys[0], (sequences, t, hkv * group, d)) * 0.3
+    k = jax.random.normal(keys[1], (sequences, t, hkv, d)) * 0.3
+    v = jax.random.normal(keys[2], (sequences, t, hkv, dv))
+    weight = jax.random.normal(keys[3], (sequences, t, hkv * group, dv))
+
+    def engine(q, k, v):  # tokens first in, tokens first out
+        return gqa.heads_first(gqa.causal_gqa_attention(
+            *map(gqa.heads_first, (q, k, v)), block, None, window
+        ))
+
+    want = _plain(q, k, v, window)
+    assert want.shape == (sequences, t, hkv * group, dv)
+    np.testing.assert_allclose(engine(q, k, v), want, rtol=2e-4, atol=2e-5)
+    got = jax.grad(lambda *a: jnp.sum(engine(*a) * weight), (0, 1, 2))(q, k, v)
+    ref = jax.grad(
+        lambda *a: jnp.sum(_plain(*a, window) * weight), (0, 1, 2)
+    )(q, k, v)
+    for name, a, b in zip("qkv", got, ref):
+        np.testing.assert_allclose(a, b, rtol=2e-3, atol=2e-4, err_msg=name)
+
+
+@pytest.mark.parametrize("window", [None, 100])
+def test_packed_front_door_is_the_engine_without_the_transposes(
+    window, caplog
+):
+    """`packed=True`: the operands arrive heads first and the result
+    leaves so, to the bit what the tokens-first door gives, and the log
+    line says which door it was."""
+    import logging
+
+    q, k, v, _ = _qkv(13, 4, 2)
+    logger = logging.getLogger("elasticdl_tpu.ops.gqa")
+    logger.addHandler(caplog.handler)
+    try:
+        with caplog.at_level(logging.INFO, logger="elasticdl_tpu.ops.gqa"):
+            packed = gqa.causal_attention(
+                *map(gqa.heads_first, (q, k, v)), window=window, impl="xla",
+                block=BLOCK, packed=True,
+            )
+            given = gqa.causal_attention(
+                q, k, v, window=window, impl="xla", block=BLOCK
+            )
+    finally:
+        logger.removeHandler(caplog.handler)
+    assert packed.shape == gqa.heads_first(given).shape
+    np.testing.assert_array_equal(gqa.heads_first(packed), given)
+    lines = [r.getMessage() for r in caplog.records
+             if r.getMessage().startswith("attention engine:")]
+    assert lines[0].endswith("operands: heads first, packed by the caller)")
+    assert lines[1].endswith("operands: as given, tokens first)")
+    # the Pallas kernel behind the same door, heads first in and out
+    if window is None:
+        np.testing.assert_array_equal(
+            gqa.heads_first(gqa.causal_attention(
+                *map(gqa.heads_first, (q, k, v)), impl="pallas", packed=True
+            )),
+            gqa.causal_attention(q, k, v, impl="pallas"),
+        )
