@@ -13,8 +13,9 @@ strict apply; weights random from --seed, data written from --seed):
   kernels      every Pallas kernel a user flag reaches, once, against its
                XLA twin (fused sparse lookup / lookup+FM / dedup+apply,
                flash attention fwd+bwd, the ring step), the delta
-               rule's kernel pair and the two passes around it
-               (ops/gdn_passes.py), which the backend picks, and the pass
+               rule's kernel pair, the two passes around it and the
+               pair around a state-space scan (ops/gdn_passes.py),
+               which the backend picks, and the pass
                in front of the attention engine (ops/rotary_pack.py) —
                correctness only
   train        `python -m elasticdl_tpu.client.main train` with
@@ -654,6 +655,41 @@ def phase_kernels(args) -> dict:
     ):
         scale = max(float(jnp.max(jnp.abs(w))), 1.0)
         check(f"gdn passes {which} ~ jax.numpy chain", g, w,
+              1e-5, 1e-5 * scale, secs)
+
+    # -- the same for the pair a Mamba-2 layer calls: the convolution with
+    # its bias and no norm by head, then the skip, the gate and the norm by
+    # group -----------------------------------------------------------------
+    keys = jax.random.split(jax.random.PRNGKey(args.seed + 15), 7)
+    rows, y, z, d_out = (
+        jax.random.normal(k, (2, t_rule, 512)) for k in keys[:4]
+    )
+    taps = jax.random.normal(keys[4], (4, 512))
+    bias, weight = jax.random.normal(keys[5], (2, 512))
+    skip = jax.random.normal(keys[6], (8,))
+
+    def group_grads(pallas):
+        def loss(rows, taps, bias, y, z, skip, weight):
+            x = gdn_passes.conv_silu(rows, taps, bias, pallas=pallas)
+            out = gdn_passes.gated_group_norm(
+                y, x, z, skip, weight, groups=2, eps=1e-5, pallas=pallas
+            )
+            return jnp.sum(out * d_out), (x, out)
+
+        return jax.grad(loss, argnums=range(7), has_aux=True)
+
+    operands = (rows, taps, bias, y, z, skip, weight)
+    (got_g, got_out), secs = run(
+        "state-space passes", group_grads(True), *operands
+    )
+    want_g, want_out = twin(group_grads(False), *operands)
+    for g, w, which in zip(
+        got_out + got_g, want_out + want_g,
+        ("conv_silu", "gated_group_norm", "d rows", "d taps", "d bias",
+         "d y", "d z", "d skip", "d weight"),
+    ):
+        scale = max(float(jnp.max(jnp.abs(w))), 1.0)
+        check(f"state-space passes {which} ~ jax.numpy chain", g, w,
               1e-5, 1e-5 * scale, secs)
 
     # -- from a projection's result to the attention engine's operand

@@ -1,39 +1,64 @@
-"""What surrounds the gated delta rule in a Gated DeltaNet layer, each as
-ONE pass over head-major [B, T, H D] rows, the layout the rule's kernels
-read and write (`ops/gated_delta.py`):
+"""The float32 passes around a recurrence, each as ONE pass over
+[B, T, W] rows, forward and backward.  Two families of layer call them:
+
+- a Gated DeltaNet layer (`model_zoo/qwen3_next` `GatedDeltaNet`), around
+  the gated delta rule, on head-major [B, T, H D] rows, the layout the
+  rule's kernels read and write (`ops/gated_delta.py`): `conv_silu` once
+  for each of q (with the l2-norm by head, ``scale = 1 / sqrt(Dk)``), k
+  and v, and `gated_rms_norm`;
+- a Mamba-2 layer (`model_zoo/lm_common.py` `Mamba2Mixer`: Nemotron-H,
+  Granite 4.0-H), around the state-space scan (`ops/ssd.py`): `conv_silu`
+  with the bias and no norm, for x and for [B | C], and
+  `gated_group_norm`.  A kernel takes an ARRAY: a slice of a wider one
+  handed to it is a copy in HBM first, and the slice's gradient a pad, so
+  that layer makes z, x, [B | C] and dt one product each.
+
+The passes:
 
 - `conv_silu`: rows in float32 -> causal depthwise convolution of
   `taps.shape[0]` taps accumulated in float32 (+ `bias`) -> silu -> where
   `head` is given, the l2-norm of each head of `head` columns,
   ``x rsqrt(sum x^2 + eps) scale`` -> rows in float32.  It knows nothing
-  of DeltaNet: a layer calls it once for each of its tensors (q with
-  ``scale = 1 / sqrt(Dk)``, k, v without the norm), and a state-space
-  layer's conv + silu has the same form (rows, taps, bias).
-- `gated_rms_norm`: o rows and gate rows z in float32 -> per head
-  ``weight (o rsqrt(mean o^2 + eps)) silu(z)`` -> rows in `dtype`.
+  of either layer (rows, taps, bias).
+- `gated_rms_norm` (DeltaNet): o rows and gate rows z in float32 -> per
+  HEAD ``weight (o rsqrt(mean o^2 + eps)) silu(z)`` -> rows in `dtype`;
+  the weight is one head's, [D].
+- `gated_group_norm` (Mamba-2): y, x, z rows in float32, a skip a head
+  and a weight a column -> ``weight GroupRMS((y + skip x) silu(z))`` over
+  G GROUPS of W / G columns -> rows in `dtype`.  Not the norm above with
+  other arguments: that one norms and then gates, this one gates and then
+  norms, so the gate is inside the mean and its derivative goes through
+  the norm's; a group is 512 or 4096 columns where a head is 128, so a
+  block's rows follow from the group's width (`_group_rows`); and the
+  weight is the whole width's.  Two equations, two pairs of kernels.
 
-Two engines, chosen by the caller from what `engine` can see (the log
-says which and why, once a trace, beside the rule's line):
+Two engines, chosen by the caller from what `engine` (`engine_groups` in
+a state-space layer's terms) can see; the log says which and why, once a
+trace, beside the recurrence's line:
 
-- Pallas kernels on a TPU where `supports` holds and the trace is for
-  one device or names its mesh (then a shard's sequences a device under
-  the rule's `_over_batch`).  Grid (sequence, block of `ROWS` rows, head),
-  every step independent of every other:
+- Pallas kernels on a TPU where `supports` (`supports_groups`) holds and
+  the trace is for one device or names its mesh (then a shard's sequences
+  a device under the rule's `_over_batch`).  Grid (sequence, block of
+  rows, block of columns), every step independent of every other; a
+  block is `ROWS` rows of one head (or one lane tile), or the same bytes
+  of one group.  The convolution:
   the `taps - 1` rows before a block come from a second, 8-row block of
   the same array (the rows a float32 tile holds), and the backward pass,
   one kernel too, reads 8 rows after the block as well, recomputes the
   convolution and silu there from the input it already has, and writes
   d(rows) and one partial sum a block of d(taps) (and d(bias)); XLA adds
-  the partial sums up.  Everything is float32, in and out and between;
-  the one cast is `gated_rms_norm`'s result to `dtype`, which is what the
-  out-projection's product takes.
-- the plain `jax.numpy` chain (`conv_silu_xla`, `gated_rms_norm_xla`)
-  everywhere else: the definition the tests hold the kernels to, and the
-  engine of the CPU's tests and of head sizes that are no whole lane
-  tiles.
+  the partial sums up.  The norms' backward kernels recompute the forward
+  from their inputs likewise and write a block's partial sums of
+  d(weight) (and d(skip), by column).  Everything is float32, in and out
+  and between; the one cast is a norm's result to `dtype`, which is what
+  the out-projection's product takes.
+- the plain `jax.numpy` chain (`conv_silu_xla`, `gated_rms_norm_xla`,
+  `gated_group_norm_xla`) everywhere else: the definition the tests hold
+  the kernels to, and the engine of the CPU's tests and of widths that
+  are no whole lane tiles.
 
 Neither engine names a scope: the caller's `jax.named_scope` (the
-model's `gdn_mix`) reaches the `custom_vjp`s' backward kernels too.
+models' `gdn_mix`, `ssm`) reaches the `custom_vjp`s' backward kernels too.
 """
 
 from __future__ import annotations
@@ -95,6 +120,20 @@ def gated_rms_norm_xla(rows, gate, weight, *, eps=1e-6, dtype=jnp.float32):
     )
 
 
+def gated_group_norm_xla(y, x, z, skip, weight, *, groups, eps=1e-6,
+                         dtype=jnp.float32):
+    """`gated_group_norm` in XLA ops: the skip a head, the gate, the
+    RMSNorm over each of the `groups` groups of the width, float32."""
+    b, t, inner = y.shape
+    heads = skip.shape[0]
+    y = (
+        y.reshape(b, t, heads, -1) + skip[:, None] * x.reshape(b, t, heads, -1)
+    ).reshape(b, t, inner) * jax.nn.silu(z)
+    y = y.reshape(b, t, groups, inner // groups)
+    y = y * jax.lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True) + eps)
+    return (weight * y.reshape(b, t, inner)).astype(dtype)
+
+
 # ----------------------------------------------------------------------
 # The choice of engine
 # ----------------------------------------------------------------------
@@ -114,19 +153,48 @@ def supports(t: int, dk: int, dv: int, taps: int) -> bool:
     )
 
 
-def engine(t, hk, hv, dk, dv, taps, mesh=None):
-    """-> "pallas" or "xla" for both passes of a layer with these
-    shapes, by the rule's own `_engine` (backend, what the trace is for)
-    and `supports`; the worker's log says which and why."""
-    found, why = gated_delta._engine(
-        supports(t, dk, dv, taps), mesh,
-        "head sizes, a length or taps the kernels do not take",
+def supports_groups(t: int, width: int, group: int, taps: int) -> bool:
+    """`supports` in a state-space layer's terms: rows `width` wide
+    through `conv_silu` with no norm by head (blocks of one lane tile),
+    and `gated_group_norm` over groups of `group` columns, a block's
+    columns one group and its rows what `_group_rows` gives, whole
+    float32 tiles."""
+    return (
+        width % LANE_TILE == 0 and group % LANE_TILE == 0 and t % HALO == 0
+        and 1 <= taps <= HALO + 1 and _group_rows(group, ROWS) >= HALO
     )
+
+
+def _chosen(supported, mesh, unsupported, shapes):
+    """-> "pallas" or "xla" by the rule's own `_engine` (backend, what
+    the trace is for) and what a `supports` found; the worker's log says
+    which and why, once a trace."""
+    found, why = gated_delta._engine(supported, mesh, unsupported)
     gated_delta.logger.info(
-        "gdn passes engine: %s T=%d Hk=%d Hv=%d D=%s (%s)", found, t, hk, hv,
-        dk if dk == dv else f"{dk}/{dv}", why,
+        "gdn passes engine: %s %s (%s)", found, shapes, why
     )
     return found
+
+
+def engine(t, hk, hv, dk, dv, taps, mesh=None):
+    """-> "pallas" or "xla" for both passes of a Gated DeltaNet layer
+    with these shapes."""
+    return _chosen(
+        supports(t, dk, dv, taps), mesh,
+        "head sizes, a length or taps the kernels do not take",
+        f"T={t} Hk={hk} Hv={hv} D={dk if dk == dv else f'{dk}/{dv}'}",
+    )
+
+
+def engine_groups(t, width, groups, group, taps, mesh=None):
+    """-> "pallas" or "xla" for both passes of a state-space layer: the
+    convolution over rows `width` wide, the norm over `groups` groups of
+    `group` columns."""
+    return _chosen(
+        supports_groups(t, width, group, taps), mesh,
+        "widths, a length or taps the kernels do not take",
+        f"T={t} W={width} G={groups}x{group}",
+    )
 
 
 def _blocks(t: int, width: int, head: int, rows: int):
@@ -137,6 +205,13 @@ def _blocks(t: int, width: int, head: int, rows: int):
     rows = min(t, rows)
     columns = head or LANE_TILE
     return rows, columns, (pl.cdiv(t, rows), width // columns)
+
+
+def _group_rows(group: int, rows: int) -> int:
+    """Rows of a block whose columns are one group of `group`: as many
+    as give it the bytes of `rows` rows of one lane tile, in whole
+    float32 tiles (512 at groups of 512, 64 at one group of 4096)."""
+    return rows * LANE_TILE // group // HALO * HALO
 
 
 def _params():
@@ -366,7 +441,7 @@ def conv_silu(rows, taps, bias=None, *, head=0, scale=1.0, eps=1e-6,
     return _conv_silu(
         rows.astype(jnp.float32), taps.astype(jnp.float32),
         None if bias is None else bias.astype(jnp.float32).reshape(1, -1),
-        (head, float(scale), eps, ROWS,
+        (head, float(scale), eps, ROWS,  # noqa-invariant: jit-host-sync (`scale` is a Python number, a static of the kernel, never a traced array)
          _use_interpret() if interpret is None else interpret,
          gated_delta._several(mesh)),
     )
@@ -505,6 +580,170 @@ def gated_rms_norm(rows, gate, weight, *, eps=1e-6, dtype=jnp.float32,
         rows.astype(jnp.float32), gate.astype(jnp.float32),
         weight.astype(jnp.float32).reshape(1, -1),
         (eps, jnp.dtype(dtype), ROWS,
+         _use_interpret() if interpret is None else interpret,
+         gated_delta._several(mesh)),
+    )
+
+
+# ----------------------------------------------------------------------
+# Skip, gate, RMS norm by group
+# ----------------------------------------------------------------------
+
+
+def _group_fwd_kernel(y_ref, x_ref, z_ref, skip_ref, w_ref, o_ref, *, eps):
+    gate = z_ref[0]  # one group's columns
+    gated = (y_ref[0] + skip_ref[...] * x_ref[0]) * (
+        gate * jax.nn.sigmoid(gate)
+    )
+    o_ref[0] = (w_ref[...] * (gated * jax.lax.rsqrt(
+        jnp.mean(gated * gated, axis=-1, keepdims=True) + eps
+    ))).astype(o_ref.dtype)
+
+
+def _group_bwd_kernel(y_ref, x_ref, z_ref, skip_ref, w_ref, do_ref, dy_ref,
+                      dx_ref, dz_ref, sums_ref, *, t, eps):
+    """d y, d x, d z of a block and the block's share of d weight and,
+    in the row after it, of d skip by column: the skip, the gate and the
+    norm once more from the inputs."""
+    skip = skip_ref[...]
+    rows = y_ref.shape[1]
+    # past the sequence's end a block holds anything: no share of it may
+    # reach the sums over the block's rows
+    inside = pl.program_id(1) * rows + jax.lax.broadcasted_iota(
+        jnp.int32, (rows, 1), 0
+    ) < t
+    x = jnp.where(inside, x_ref[0], 0.0)
+    gate = jnp.where(inside, z_ref[0], 0.0)
+    summed = jnp.where(inside, y_ref[0], 0.0) + skip * x
+    d_out = jnp.where(inside, do_ref[0].astype(jnp.float32), 0.0)
+    sig = jax.nn.sigmoid(gate)
+    silu = gate * sig
+    gated = summed * silu
+    norm = jax.lax.rsqrt(
+        jnp.mean(gated * gated, axis=-1, keepdims=True) + eps
+    )
+    normed = gated * norm
+    sums_ref[0, 0, 0:1] = jnp.sum(d_out * normed, axis=0, keepdims=True)
+    d_normed = d_out * w_ref[...]
+    d_gated = norm * (d_normed - normed * jnp.mean(
+        d_normed * normed, axis=-1, keepdims=True
+    ))
+    d_summed = d_gated * silu
+    dy_ref[0] = d_summed
+    dx_ref[0] = d_summed * skip
+    sums_ref[0, 0, 1:2] = jnp.sum(d_summed * x, axis=0, keepdims=True)
+    dz_ref[0] = (d_gated * summed) * (sig * (1.0 + gate * (1.0 - sig)))
+
+
+def _group_specs(t, width, groups, rows):
+    """-> (grid of one sequence, a block of rows, a [1, W] parameter's
+    columns of a block)."""
+    group = width // groups
+    block_rows = min(t, _group_rows(group, rows))
+    return (
+        (pl.cdiv(t, block_rows), groups),
+        pl.BlockSpec((1, block_rows, group), lambda s, i, j: (s, i, j)),
+        _column_spec(1, group),
+    )
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _group_forward_call(static, y, x, z, skip, weight):
+    groups, eps, dtype, block_rows, interpret, mesh = static
+    _, t, width = y.shape
+    grid, block, columns = _group_specs(t, width, groups, block_rows)
+
+    def call_for(b):
+        return pl.pallas_call(
+            functools.partial(_group_fwd_kernel, eps=eps),
+            grid=(b,) + grid,
+            in_specs=[block, block, block, columns, columns],
+            out_specs=block,
+            out_shape=jax.ShapeDtypeStruct((b, t, width), dtype),
+            compiler_params=_params(),
+            name="gated_group_norm_fwd",
+            interpret=interpret,
+        )
+
+    return gated_delta._over_batch(
+        mesh, y.shape[0], call_for, whole=(3, 4)
+    )(y, x, z, skip, weight)
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _group_backward_call(static, y, x, z, skip, weight, d_out):
+    """-> (d y, d x, d z, [B, blocks, 2, W] partial sums of d weight and
+    of d skip by column)."""
+    groups, eps, _, block_rows, interpret, mesh = static
+    _, t, width = y.shape
+    grid, block, columns = _group_specs(t, width, groups, block_rows)
+
+    def call_for(b):
+        rows = jax.ShapeDtypeStruct((b, t, width), jnp.float32)
+        return pl.pallas_call(
+            functools.partial(_group_bwd_kernel, t=t, eps=eps),
+            grid=(b,) + grid,
+            in_specs=[block, block, block, columns, columns, block],
+            out_specs=[
+                block, block, block,
+                pl.BlockSpec(
+                    (1, 1, 2, block.block_shape[2]),
+                    lambda s, i, j: (s, i, 0, j),
+                ),
+            ],
+            out_shape=[
+                rows, rows, rows,
+                jax.ShapeDtypeStruct((b, grid[0], 2, width), jnp.float32),
+            ],
+            compiler_params=_params(),
+            name="gated_group_norm_bwd",
+            interpret=interpret,
+        )
+
+    return gated_delta._over_batch(
+        mesh, y.shape[0], call_for, whole=(3, 4)
+    )(y, x, z, skip, weight, d_out)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _gated_group_norm(y, x, z, skip, weight, static):
+    return _group_forward_call(static, y, x, z, skip, weight)
+
+
+def _gated_group_norm_fwd(y, x, z, skip, weight, static):
+    return (
+        _group_forward_call(static, y, x, z, skip, weight),
+        (y, x, z, skip, weight),
+    )
+
+
+def _gated_group_norm_bwd(static, residuals, d_out):
+    d_y, d_x, d_z, sums = _group_backward_call(static, *residuals, d_out)
+    sums = jnp.sum(sums, axis=(0, 1))
+    return d_y, d_x, d_z, sums[1:], sums[:1]
+
+
+_gated_group_norm.defvjp(_gated_group_norm_fwd, _gated_group_norm_bwd)
+
+
+def gated_group_norm(y, x, z, skip, weight, *, groups, eps=1e-6,
+                     dtype=jnp.float32, pallas=False, interpret=None,
+                     mesh=None):
+    """y, x, z [B, T, W] float32, skip [H] (a head is W / H columns),
+    weight [W] -> weight GroupRMS((y + skip x) silu(z)) over `groups`
+    groups of W / groups columns, in `dtype`."""
+    if not pallas:
+        return gated_group_norm_xla(
+            y, x, z, skip, weight, groups=groups, eps=eps, dtype=dtype
+        )
+    width = y.shape[-1]
+    return _gated_group_norm(
+        y.astype(jnp.float32), x.astype(jnp.float32), z.astype(jnp.float32),
+        jnp.repeat(skip.astype(jnp.float32), width // skip.shape[0]).reshape(
+            1, -1
+        ),
+        weight.astype(jnp.float32).reshape(1, -1),
+        (groups, eps, jnp.dtype(dtype), ROWS,
          _use_interpret() if interpret is None else interpret,
          gated_delta._several(mesh)),
     )

@@ -65,12 +65,20 @@ state-space form's four products, attention, the MLP's two products and
 the head take bfloat16 operands and accumulate in float32.  Always
 float32: the residual stream, every norm, `dt`, the decays, the recurrent
 state, the attention softmax's statistics, the four multipliers'
-products, the logits and the loss.
+products, the logits and the loss; and in a Mamba-2 layer everything
+between `in_proj`'s float32 result and `out_proj`'s operand but the scan's
+four products: the convolution's taps, silu, the skip, the gate and the
+norm over the one group, whose result alone is cast (`ops/gdn_passes.py`,
+in its kernels and in its plain chain alike).
 
 Device scopes (obs/tracing.py DEVICE_SCOPES): `ssm` (the Mamba-2 sublayer
 with its norm and residual) > `ssm_scan`; `attn`; `mlp` (every MLP
 sublayer with its norm and residual); `lm_head_loss` (the final norm, the
-tied product and the loss).
+tied product and the loss).  `ssm` outside `ssm_scan` is the two
+projections and the passes of `ops/gdn_passes.py` (on a TPU the kernels
+`conv_silu_fwd|bwd`, twice a layer, and `gated_group_norm_fwd|bwd`; the
+worker's log line `gdn passes engine:` says which engine a trace held),
+which name no scope of their own.
 """
 
 from __future__ import annotations
@@ -124,6 +132,7 @@ class GraniteHybridConfig:
     rms_norm_eps: float = 1e-5
     dtype: Any = jnp.bfloat16
     remat: bool = False
+    mesh: Any = None
 
 
 class SharedMLP(nn.Module):
@@ -157,7 +166,7 @@ class DecoderLayer(nn.Module):
             mixer = Mamba2Mixer(
                 c.mamba_n_heads, c.mamba_d_head, c.mamba_n_groups,
                 c.mamba_d_state, c.mamba_d_conv, c.mamba_chunk_size,
-                c.rms_norm_eps, c.dtype, name="mamba",
+                c.rms_norm_eps, c.dtype, mesh=c.mesh, name="mamba",
             ) if mamba else Attention(
                 c.num_attention_heads, c.num_key_value_heads, c.head_dim,
                 c.dtype, scale=c.attention_multiplier, name="self_attn",
@@ -204,12 +213,15 @@ class GraniteHybridLM(nn.Module):
             ) / c.logits_scaling
 
 
-def custom_model(use_bf16: bool = True, **config):
+def custom_model(use_bf16: bool = True, mesh=None, **config):
     """`config`: the source's `config.json` keys this model reads (see
     `GraniteHybridConfig`; `layer_types` as a sequence or, as a job's flat
     flags carry it, `mamba/mamba/attention`), plus `remat` (rematerialise
     each layer in the backward pass).  The stack is the first
-    `num_hidden_layers` entries of `layer_types`."""
+    `num_hidden_layers` entries of `layer_types`.  `mesh`: the job's
+    mesh, which `ModelSpec.build_model` hands to a model that names it;
+    under a mesh of several devices the Mamba-2 layers' passes run a data
+    shard's sequences a device (`ops/gdn_passes.py`)."""
     unknown = set(config) - set(GraniteHybridConfig.__dataclass_fields__)
     if unknown:
         raise ValueError(
@@ -220,7 +232,7 @@ def custom_model(use_bf16: bool = True, **config):
         kinds.split("/") if isinstance(kinds, str) else kinds
     )
     cfg = GraniteHybridConfig(
-        dtype=jnp.bfloat16 if use_bf16 else jnp.float32, **config
+        dtype=jnp.bfloat16 if use_bf16 else jnp.float32, mesh=mesh, **config
     )
     if cfg.num_local_experts:
         raise ValueError(

@@ -12,19 +12,21 @@ every cell whose stack uses the piece:
   stacks (`gpt2-medium`, `qwen3-next`, `nemotron3-nano`, `deepseek-v2-lite`,
   `laguna-xs2`, `granite4-h-micro`, `mellum2`);
 - `dense`, the bias-free projection with a float32 result: the six 8k
-  stacks; `RMSNorm` (weight from 1): all of them but Qwen3-Next, whose
-  zero-centred norm is another function and stays in its file (Mellum 2
-  also norms every query and key head with it: it is over the last axis,
-  so a [B, T, heads, head_dim] tensor gets one weight vector of
-  `head_dim`);
+  stacks (`SplitDense`, the same parameter read as a product a range of
+  its columns: `Mamba2Mixer`); `RMSNorm` (weight from 1): all of them
+  but Qwen3-Next, whose zero-centred norm is another function and stays
+  in its file (Mellum 2 also norms every query and key head with it: it
+  is over the last axis, so a [B, T, heads, head_dim] tensor gets one
+  weight vector of `head_dim`);
 - `warmup_adamw`: DeepSeek-V2, Granite and Mellum 2; `balancing_adamw`, which wraps
   it with the rule that moves a sigmoid router's selection biases:
   Nemotron-H and Laguna, the two stacks behind that router;
 - `listed` and `check_listed`, a source's per-layer lists as a job's flat
   flags carry them and the check that they cover the stack: Laguna and
   Mellum 2;
-- `Mamba2Mixer` (with `_Conv1d`, `_dt_bias_init`) and the position-free
-  `Attention`: Nemotron-H and Granite 4.0-H.
+- `Mamba2Mixer` (with `_Conv1d`, `_dt_bias_init`; its float32 passes are
+  `ops/gdn_passes.py`'s, which Qwen3-Next's DeltaNet layers call too) and
+  the position-free `Attention`: Nemotron-H and Granite 4.0-H.
 
 This module imports no stack and no ring attention: importing an 8k stack
 brings neither `transformer_lm` nor `parallel/ring_attention.py` with it.
@@ -41,7 +43,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 
-from elasticdl_tpu.ops import gqa
+from elasticdl_tpu.ops import gdn_passes, gqa
 from elasticdl_tpu.ops.ssd import ssd_chunked
 from model_zoo import datasets
 
@@ -170,6 +172,33 @@ def dense(features, dtype, name, kernel_init=nn.initializers.lecun_normal()):
     )
 
 
+class SplitDense(nn.Module):
+    """`dense` whose result comes as one array a range of its columns:
+    ONE parameter, `kernel` [in, sum(widths)] as `dense` would hold and
+    seed it, and a product for each of `widths` (operands in `dtype`,
+    float32 results).  For a layer that hands the ranges to kernels: each
+    is then an array as it lies, and its gradient reaches the weight's
+    product whole, with no slice laid out on the way in and no pad summed
+    on the way back."""
+
+    widths: tuple
+    dtype: Any
+
+    @nn.compact
+    def __call__(self, x):
+        kernel = self.param(
+            "kernel", nn.initializers.lecun_normal(),
+            (x.shape[-1], sum(self.widths)), jnp.float32,
+        ).astype(self.dtype)
+        x = x.astype(self.dtype)
+        ends = np.cumsum(self.widths)
+        return tuple(
+            jnp.dot(x, kernel[:, end - width:end],
+                    preferred_element_type=jnp.float32)
+            for width, end in zip(self.widths, ends)
+        )
+
+
 class RMSNorm(nn.Module):
     """y = w x rsqrt(mean(x^2) + eps), w from 1; float32."""
 
@@ -237,6 +266,15 @@ class Mamba2Mixer(nn.Module):
     selective state-space recurrence of `ops/ssd.py` (heads of group g
     share B and C) under the `ssm_scan` scope, plus the skip `D x`;
     `y = GroupRMSNorm(y silu(z))` over G groups with a weight; `out_proj`.
+    The float32 chains between the projections and the scan are passes of
+    `ops/gdn_passes.py`: `conv_silu` for x and for [B | C], and
+    `gated_group_norm` for the skip, the gate and the norm, each ONE
+    kernel forward and one backward where `gdn_passes.engine_groups` finds
+    a TPU, widths in whole lane tiles and one device or the `mesh` the
+    program is compiled for, and that module's plain `jax.numpy`
+    definitions elsewhere (the CPU's tests); `in_proj` is ONE parameter
+    and four products (`SplitDense`), so that z, x, [B | C] and dt reach
+    the passes as arrays of their own.
     Seeded as the source: `A_log = log U(1, 16)`, `dt_bias` the inverse
     softplus of `dt ~ exp(U(log min, log max))` floored (`time_step`), `D`
     and the norm 1, `out_proj` at `out_scale`."""
@@ -251,6 +289,7 @@ class Mamba2Mixer(nn.Module):
     dtype: Any
     time_step: tuple = (1e-3, 0.1, 1e-4)  # min, max, floor: the init only
     out_scale: float = 1.0                # `rescale_prenorm_residual`
+    mesh: Any = None  # what the program is compiled for: the engines' choice
 
     @nn.compact
     def __call__(self, u):
@@ -258,19 +297,28 @@ class Mamba2Mixer(nn.Module):
         h, p, g, n = (self.num_heads, self.head_dim, self.n_groups,
                       self.state_size)
         inner, bc = h * p, g * n
-        z, xbc, dt = jnp.split(
-            dense(2 * inner + 2 * bc + h, self.dtype, "in_proj")(u),
-            [inner, 2 * inner + 2 * bc], axis=-1,
+        # z, x, [B | C] and dt, float32: ONE parameter, a product a range
+        # of its columns, so that each is an array the passes take as it
+        # lies and its gradient comes back whole (a slice handed to a
+        # kernel is a copy in HBM, and its gradient a pad).
+        z, x, bc_in, dt = SplitDense(
+            (inner, inner, 2 * bc, h), self.dtype, name="in_proj"
+        )(u)
+        passes = dict(mesh=self.mesh, pallas=gdn_passes.engine_groups(
+            t, inner + 2 * bc, g, inner // g, self.conv_kernel, self.mesh
+        ) == "pallas")
+        # Causal depthwise convolution over [x | B | C], then silu, the
+        # taps accumulated in float32: a column at a time, so x's pass and
+        # [B | C]'s are two.
+        kernel, bias = _Conv1d(self.conv_kernel, name="conv1d")(
+            inner + 2 * bc
         )
-        # Causal depthwise convolution over [x | B | C], then silu: the
-        # taps accumulated in float32.
-        kernel, bias = _Conv1d(self.conv_kernel, name="conv1d")(xbc.shape[-1])
-        padded = jnp.pad(xbc, ((0, 0), (self.conv_kernel - 1, 0), (0, 0)))
-        xbc = nn.silu(bias + sum(
-            padded[:, j:j + t] * kernel[j] for j in range(self.conv_kernel)
-        ))
-        x, b_in, c_in = jnp.split(xbc, [inner, inner + bc], axis=-1)
-        x = x.reshape(b, t, h, p)
+        x = gdn_passes.conv_silu(
+            x, kernel[:, :inner], bias[:inner], **passes
+        )
+        b_in, c_in = jnp.split(gdn_passes.conv_silu(
+            bc_in, kernel[:, inner:], bias[inner:], **passes
+        ), 2, axis=-1)
         a_log = self.param(
             "A_log",
             lambda key, shape: jnp.log(
@@ -283,17 +331,18 @@ class Mamba2Mixer(nn.Module):
         dt = jax.nn.softplus(dt + dt_bias)
         with jax.named_scope("ssm_scan"):
             y, _ = ssd_chunked(
-                x, dt, -jnp.exp(a_log), b_in.reshape(b, t, g, n),
-                c_in.reshape(b, t, g, n),
+                x.reshape(b, t, h, p), dt, -jnp.exp(a_log),
+                b_in.reshape(b, t, g, n), c_in.reshape(b, t, g, n),
                 chunk=self.chunk_size, dtype=self.dtype,
             )
-        y = (y + skip[:, None] * x).reshape(b, t, inner) * nn.silu(z)
-        # RMSNorm over each of the G groups of the inner width, float32.
+        # The skip, the gate and the RMSNorm over each of the G groups of
+        # the inner width, float32; the result in the out-projection's type.
         weight = self.param("norm", nn.initializers.ones_init(), (inner,),
                             jnp.float32)
-        y = y.reshape(b, t, g, inner // g)
-        y = y * jax.lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True) + self.eps)
-        y = (weight * y.reshape(b, t, inner)).astype(self.dtype)
+        y = gdn_passes.gated_group_norm(
+            y.reshape(b, t, inner), x, z, skip, weight, groups=g,
+            eps=self.eps, dtype=self.dtype, **passes,
+        )
         init = nn.initializers.variance_scaling(
             self.out_scale ** 2, "fan_in", "truncated_normal"
         )

@@ -46,7 +46,11 @@ state-space form's four products, attention and the expert products take
 bfloat16 operands and accumulate in float32.  Always float32: the
 residual stream, every norm, the router (logits at `Precision.HIGHEST`,
 sigmoid, top-k), `dt`, the decays `exp(dt A)`, the recurrent state, the
-logits and the loss.
+logits and the loss; and in a Mamba-2 layer everything between `in_proj`'s
+float32 result and `out_proj`'s operand but the scan's four products: the
+convolution's taps, silu, the skip, the gate and the group norm, whose
+result alone is cast (`ops/gdn_passes.py`, in its kernels and in its plain
+chain alike).
 
 Seeded initialisation, as the source's: `A_log = log U(1, 16)`,
 `dt_bias` the inverse softplus of `dt ~ exp(U(log time_step_min,
@@ -67,7 +71,11 @@ this `gate` is taken from.
 
 Device scopes (obs/tracing.py DEVICE_SCOPES): `ssm` (the Mamba-2
 sublayer with its norm and residual) > `ssm_scan`; `attn`; `moe` >
-`moe_route`, `moe_experts`, `moe_shared`; `lm_head_loss`.
+`moe_route`, `moe_experts`, `moe_shared`; `lm_head_loss`.  `ssm` outside
+`ssm_scan` is the two projections and the passes of `ops/gdn_passes.py`
+(on a TPU the kernels `conv_silu_fwd|bwd`, twice a layer, and
+`gated_group_norm_fwd|bwd`; the worker's log line `gdn passes engine:`
+says which engine a trace held), which name no scope of their own.
 """
 
 from __future__ import annotations
@@ -110,7 +118,7 @@ class NemotronHLayer(nn.Module):
                     (c.time_step_min, c.time_step_max, c.time_step_floor),
                     (c.num_hidden_layers or len(c.hybrid_override_pattern))
                     ** -0.5 if c.rescale_prenorm_residual else 1.0,
-                    name="mixer",
+                    c.mesh, name="mixer",
                 )
             elif self.kind == EXPERTS:
                 mixer = SparseMoeBlock(
@@ -164,6 +172,7 @@ class NemotronHConfig:
     experts_held: int = 8
     dtype: Any = jnp.bfloat16
     remat: bool = False
+    mesh: Any = None
 
 
 class _Backbone(nn.Module):
@@ -202,17 +211,20 @@ class NemotronHLM(nn.Module):
             )
 
 
-def custom_model(use_bf16: bool = True, **config):
+def custom_model(use_bf16: bool = True, mesh=None, **config):
     """`config`: the source's `config.json` keys this model reads (see
     `NemotronHConfig`), plus `experts_first` / `experts_held` (the range of
     experts this chip holds) and `remat` (rematerialise each layer in the
-    backward pass)."""
+    backward pass).  `mesh`: the job's mesh, which `ModelSpec.build_model`
+    hands to a model that names it; under a mesh of several devices the
+    Mamba-2 layers' passes run a data shard's sequences a device
+    (`ops/gdn_passes.py`)."""
     unknown = set(config) - set(NemotronHConfig.__dataclass_fields__)
     if unknown:
         raise ValueError(f"nemotron_h_lm has no parameter(s) {sorted(unknown)}")
     config.setdefault("experts_held", config.get("n_routed_experts", 8))
     cfg = NemotronHConfig(
-        dtype=jnp.bfloat16 if use_bf16 else jnp.float32, **config
+        dtype=jnp.bfloat16 if use_bf16 else jnp.float32, mesh=mesh, **config
     )
     letters = set(cfg.hybrid_override_pattern)
     if not letters or letters - {MAMBA, EXPERTS, ATTENTION}:

@@ -115,6 +115,59 @@ def _log_lines(logger):
     return lines, handler
 
 
+def engines_as_on_a_tpu(monkeypatch):
+    """The engines a TPU would be given, in interpret mode: the choice
+    of the delta rule's kernels and of `ops/gdn_passes.py`'s by shapes
+    alone."""
+    from elasticdl_tpu.ops import gated_delta, gdn_passes
+
+    monkeypatch.setattr(
+        gated_delta, "_engine",
+        lambda supported, mesh, *why: (
+            "pallas" if supported else "xla", "as on a tpu"
+        ),
+    )
+    monkeypatch.setattr(gated_delta, "_use_interpret", lambda: True)
+    monkeypatch.setattr(gdn_passes, "_use_interpret", lambda: True)
+
+
+def mamba_mixer_in_its_kernels(monkeypatch, module, reference, model):
+    """`Mamba2Mixer` (`module`, float32) on the path a TPU takes, the
+    convolution and the norm in their kernels (interpret mode), against
+    the plain reference's function of the sublayer at the same
+    parameters: the outputs to 1e-5 of their size and every gradient,
+    the input's among them, to 1e-4."""
+    engines_as_on_a_tpu(monkeypatch)
+    rng = np.random.default_rng(0)
+    x, weight = (
+        jnp.asarray(rng.normal(size=(1, 200, model["hidden_size"])),
+                    jnp.float32)
+        for _ in range(2)
+    )
+    params = _perturbed(module.init(jax.random.PRNGKey(1), x)["params"], 3)
+
+    def program(p, x):
+        return module.apply({"params": p}, x)[0]
+
+    def plain(p, x):
+        return reference(p, x[0], model)
+
+    traced = str(jax.make_jaxpr(program)(params, x))
+    assert "conv_silu_fwd" in traced and "gated_group_norm_fwd" in traced
+    with jax.default_matmul_precision("highest"):
+        assert _rel(program(params, x), plain(params, x)) < 1e-5
+        got, want = (
+            jax.grad(lambda p, x: jnp.sum(f(p, x) * weight[0]), (0, 1))(
+                params, x
+            )
+            for f in (program, plain)
+        )
+    flat = jax.tree_util.tree_leaves_with_path(got)
+    assert len(flat) == 9  # eight parameters' gradients and the input's
+    for (path, g), w in zip(flat, jax.tree.leaves(want)):
+        assert _rel(g, w) < 1e-4, jax.tree_util.keystr(path)
+
+
 def _eqns(jaxpr, kernel=None):
     """Every equation of a jaxpr and of the jaxprs inside it -> (the
     equation, the name of the `pallas_call` that holds it or None)."""

@@ -155,7 +155,10 @@ SPEC = LMSpec(
     # 4096 tokens.
     compile=CompileSpec(
         state=(9.26e9, 9.27e9), total={1: (12.0e9, 13.2e9)},
-        in_text=("tpu_custom_call",), stated_sizes=("12.58 GB", "3.31 GB"),
+        # the Mamba-2 layers' passes in their kernels (`ops/gdn_passes.py`)
+        in_text=("conv_silu_fwd", "conv_silu_bwd", "gated_group_norm_fwd",
+                 "gated_group_norm_bwd"),
+        stated_sizes=("12.58 GB", "3.31 GB"), names_mesh=True,
     ),
     # a Mamba-2 and an attention layer, each followed by its MLP
     scope_widths=dict(

@@ -144,13 +144,18 @@ SPEC = LMSpec(
     trained=_trained,
     journal=_journal,
     # 1 x 8192 tokens a step: 8.0 GB of state donated (12 B x 667M) and
-    # 3.25 GB of temporaries with each layer rematerialised (at 2 x 8192
-    # it needs 16.8 GB and does not fit), the attention layer in the
+    # 4.62 GB of temporaries with each layer rematerialised (3.25 until
+    # PR 44: a Mamba-2 layer's `in_proj` as four products holds 1.4 GB more
+    # at the program's peak than one product whose result was sliced; at
+    # 2 x 8192 it needs 16.8 GB and does not fit), the attention layer in the
     # Pallas kernel exactly at `supports`' cap (K + V of a head are 8 MiB
     # of float32).
     compile=CompileSpec(
-        state=(8.0e9, 8.01e9), total={1: (0, 12.5e9)},
-        in_text=("tpu_custom_call",),
+        state=(8.0e9, 8.01e9), total={1: (0, 12.7e9)},
+        # the Mamba-2 layers' passes in their kernels (`ops/gdn_passes.py`)
+        in_text=("conv_silu_fwd", "conv_silu_bwd", "gated_group_norm_fwd",
+                 "gated_group_norm_bwd"),
+        names_mesh=True,
     ),
     scope_widths=dict(
         vocab_size=64, hidden_size=32, hybrid_override_pattern="ME*",

@@ -1,9 +1,11 @@
 """`ops/gdn_passes.py`: what surrounds the delta rule in a Gated DeltaNet
-layer, one pass each way over head-major rows (the causal convolution with
-silu and the l2-norm by head; the gated RMSNorm), the Pallas kernels
-(interpret mode here) against the plain `jax.numpy` chain, and the engine's
-choice.  The layer that calls them is `model_zoo/qwen3_next`'s
-`GatedDeltaNet`, which the engine-choice case traces.
+layer and the scan in a Mamba-2 layer, one pass each way over rows (the
+causal convolution with silu and, for the rule, the l2-norm by head; the
+rule's RMSNorm by head, gated; the scan's skip, gate and RMSNorm by
+group), the Pallas kernels (interpret mode here) against the plain
+`jax.numpy` chain, and the engine's choice.  The layers that call them are
+`model_zoo/qwen3_next`'s `GatedDeltaNet` and `model_zoo/lm_common.py`'s
+`Mamba2Mixer`, which the engine-choice cases trace.
 """
 
 import jax
@@ -103,6 +105,85 @@ def test_gated_norm_kernels_match_the_plain_chain(t, hk, hv, dtype,
 
 
 
+# A state-space layer's two passes, reduced: x (Nemotron-H's and Granite's
+# 4096 columns) and [B | C] (2 x 8 x 128 and 2 x 128), neither a whole
+# count of heads, each with its share of the taps and the bias.
+@pytest.mark.parametrize("t,width", [
+    (200, 512), (1100, 512), (200, 256), (1100, 384),
+])
+def test_conv_silu_with_a_bias_at_a_state_space_layers_widths(
+    t, width, blocks_of_256_rows
+):
+    """The pass with no norm by head and with the bias: outputs and every
+    gradient against the `jax.numpy` chain."""
+    rng = np.random.default_rng(t + width)
+    rows, weight = (
+        jnp.asarray(rng.normal(size=(2, t, width)), jnp.float32)
+        for _ in range(2)
+    )
+    taps = jnp.asarray(rng.normal(size=(4, width)), jnp.float32)
+    bias = jnp.asarray(rng.normal(size=(width,)), jnp.float32)
+
+    def run(pallas):
+        def total(rows, taps, bias):
+            out = gdn_passes.conv_silu(
+                rows, taps, bias, pallas=pallas, interpret=True
+            )
+            return jnp.sum(out * weight), out
+
+        return jax.jit(jax.value_and_grad(
+            total, argnums=(0, 1, 2), has_aux=True
+        ))(rows, taps, bias)
+
+    ((_, got), got_grads), ((_, want), want_grads) = run(True), run(False)
+    _close(got, want, 1e-6, "out")
+    for g, w, name in zip(got_grads, want_grads, ("rows", "taps", "bias")):
+        assert g.shape == w.shape
+        _close(g, w, 2e-6, name)
+
+
+# At blocks of 256 rows of a lane tile a group of 128 columns has blocks
+# of 256 rows and one of 1024 has blocks of 32 (the cells: 512 rows of
+# 512 columns, 64 of 4096): T = 1100 ends in a block that is not whole.
+@pytest.mark.parametrize("t", [64, 200, 1100])
+@pytest.mark.parametrize("groups", [1, 8])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_gated_group_norm_kernels_match_the_plain_chain(t, groups, dtype,
+                                                        blocks_of_256_rows):
+    """Outputs (in the out-projection's operand type) and the gradients
+    of y, x, z, the skip and the norm's weight."""
+    rng = np.random.default_rng(t + groups)
+    inner, heads = 1024, 16
+    y, x, z, weight = (
+        jnp.asarray(rng.normal(size=(2, t, inner)), jnp.float32)
+        for _ in range(4)
+    )
+    skip = jnp.asarray(rng.normal(size=(heads,)), jnp.float32)
+    norm_weight = jnp.asarray(rng.normal(size=(inner,)), jnp.float32)
+
+    def run(pallas):
+        def total(y, x, z, skip, norm_weight):
+            out = gdn_passes.gated_group_norm(
+                y, x, z, skip, norm_weight, groups=groups, eps=1e-5,
+                dtype=dtype, pallas=pallas, interpret=True,
+            )
+            return jnp.sum(out.astype(jnp.float32) * weight), out
+
+        return jax.jit(jax.value_and_grad(
+            total, argnums=range(5), has_aux=True
+        ))(y, x, z, skip, norm_weight)
+
+    ((_, got), got_grads), ((_, want), want_grads) = run(True), run(False)
+    assert got.dtype == want.dtype == dtype
+    # a bfloat16 result may round the last float32 bit the other way
+    _close(got.astype(jnp.float32), want.astype(jnp.float32),
+           1e-6 if dtype == jnp.float32 else 1e-3, "out")
+    for g, w, name in zip(got_grads, want_grads,
+                          ("y", "x", "z", "skip", "weight")):
+        assert g.shape == w.shape
+        _close(g, w, 2e-6, name)
+
+
 @pytest.mark.parametrize("backend,devices,mesh,t,dk,taps,engine,why", [
     # the published shapes on one chip, the cell's case
     ("tpu", 1, None, 8192, 128, 4, "pallas", "one device"),
@@ -160,6 +241,104 @@ def test_gdn_passes_engine_choice(backend, devices, mesh, t, dk, taps, engine,
         # the three conv passes, the rule, the norm, each forward and
         # backward, each mapped on its own
         assert jaxpr.count("shard_map") >= 10
+
+
+_NO_WIDTHS = "widths, a length or taps the kernels do not take"
+
+
+@pytest.mark.parametrize(
+    "backend,devices,mesh,t,groups,state,taps,engine,why", [
+        # the published shapes on one chip, the cells' case: Nemotron-H's
+        # 8 groups of 512 over rows of 6144, Granite's one of 4096 over 4352
+        ("tpu", 1, None, 8192, 8, 128, 4, "pallas", "one device"),
+        ("tpu", 1, None, 8192, 1, 128, 4, "pallas", "one device"),
+        ("tpu", 4, (2, 2), 8192, 8, 128, 4, "pallas",
+         "under shard_map over {'data': 2, 'model': 2}"),
+        ("tpu", 4, None, 8192, 1, 128, 4, "xla",
+         "4 devices and no mesh given"),
+        # groups, and so rows, that are no whole lane tiles; rows that are
+        # no whole float32 tiles; taps that reach past the tile before a
+        # block
+        ("tpu", 1, None, 8192, 64, 128, 4, "xla", _NO_WIDTHS),
+        ("tpu", 1, None, 8192, 1, 96, 4, "xla", _NO_WIDTHS),
+        ("tpu", 1, None, 150, 8, 128, 4, "xla", _NO_WIDTHS),
+        ("tpu", 1, None, 8192, 8, 128, 10, "xla", _NO_WIDTHS),
+        ("cpu", 1, None, 8192, 8, 128, 4, "xla", "backend cpu"),
+    ],
+)
+def test_gdn_passes_engine_choice_of_a_state_space_layer(
+    backend, devices, mesh, t, groups, state, taps, engine, why, monkeypatch
+):
+    """`Mamba2Mixer` at 64 heads of 64, in the layer's own terms (the
+    rows' width, the groups and their width): the trace logs the choice
+    once, before the scan's line, and holds the four kernels, mapped
+    where a mesh of several devices is named (traced only)."""
+    from elasticdl_tpu.ops import ssd
+    from model_zoo.lm_common import Mamba2Mixer
+
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    monkeypatch.setattr(jax, "device_count", lambda: devices)
+    mesh = mesh and _cpu_mesh(*mesh)
+    module = Mamba2Mixer(
+        64, 64, groups, state, taps, 128, 1e-5, jnp.bfloat16, mesh=mesh
+    )
+    x = jax.ShapeDtypeStruct((2, t, 64), jnp.float32)
+    variables = jax.eval_shape(module.init, jax.random.PRNGKey(0), x)
+    lines, handler = _log_lines(gated_delta.logger)
+    ssd.logger.addHandler(handler)
+    try:
+        jaxpr = str(jax.make_jaxpr(
+            jax.grad(lambda v, x: jnp.sum(module.apply(v, x)))
+        )(variables, x))
+    finally:
+        gated_delta.logger.removeHandler(handler)
+        ssd.logger.removeHandler(handler)
+    width = 4096 + 2 * groups * state
+    assert lines[0] == (
+        f"gdn passes engine: {engine} T={t} W={width} "
+        f"G={groups}x{4096 // groups} ({why})"
+    )
+    assert lines[1].startswith("ssd engine: ") and len(lines) == 2
+    for name in ("conv_silu_fwd", "conv_silu_bwd", "gated_group_norm_fwd",
+                 "gated_group_norm_bwd"):
+        assert (name in jaxpr) == (engine == "pallas"), name
+    if why.startswith("under shard_map"):
+        assert jaxpr.count("shard_map") >= 4  # each pass mapped on its own
+
+
+@pytest.mark.parametrize("b,mesh", [(2, (2, 2)), (1, (2, 1))])
+def test_state_space_passes_under_a_mesh_are_the_kernels(b, mesh):
+    """As the next case, for the pair a Mamba-2 layer calls: the taps,
+    the bias, the skip and the norm's weight whole on every device."""
+    rng = np.random.default_rng(b)
+    rows, y, z, weight = (
+        jnp.asarray(rng.normal(size=(b, 200, 256)), jnp.float32)
+        for _ in range(4)
+    )
+    taps = jnp.asarray(rng.normal(size=(4, 256)), jnp.float32)
+    bias = jnp.asarray(rng.normal(size=(256,)), jnp.float32)
+    skip = jnp.asarray(rng.normal(size=(4,)), jnp.float32)
+    norm_weight = jnp.asarray(rng.normal(size=(256,)), jnp.float32)
+
+    def run(mesh):
+        def total(rows, taps, bias, y, z, skip, norm_weight):
+            mixed = gdn_passes.conv_silu(
+                rows, taps, bias, pallas=True, interpret=True, mesh=mesh
+            )
+            out = gdn_passes.gated_group_norm(
+                y, mixed, z, skip, norm_weight, groups=2, pallas=True,
+                interpret=True, mesh=mesh,
+            )
+            return jnp.sum(out * weight), (mixed, out)
+
+        return jax.jit(jax.value_and_grad(
+            total, argnums=range(7), has_aux=True
+        ))(rows, taps, bias, y, z, skip, norm_weight)
+
+    (_, want), want_grads = run(None)
+    (_, got), got_grads = run(_cpu_mesh(*mesh))
+    for g, w in zip(got + got_grads, want + want_grads):
+        np.testing.assert_allclose(g, w, rtol=1e-6, atol=1e-6)
 
 
 @pytest.mark.parametrize("b,mesh", [(2, (2, 2)), (1, (2, 1))])

@@ -22,8 +22,8 @@ import numpy as np
 import pytest
 
 from lm_contract import (  # noqa: F401  (the contract's cases, collected here)
-    _model_kwargs, bf16_case, lm, program_and_reference,
-    pytest_generate_tests,
+    _model_kwargs, bf16_case, lm, mamba_mixer_in_its_kernels,
+    program_and_reference, pytest_generate_tests,
     test_benchmark_cost_functions_count_what_they_say,
     test_bf16_program_is_the_reference_at_the_stated_precision,
     test_float32_products_ask_for_their_precision,
@@ -74,6 +74,17 @@ def test_parameter_names_and_layouts_follow_the_source():
     assert set(shapes) == (
         {f"layers_{i}" for i in range(10)} | {"embed_tokens", "norm"}
     )
+
+
+def test_mamba_sublayer_in_its_kernels_matches_the_reference(monkeypatch):
+    """One group of 256 columns over rows of 384: widths the passes'
+    kernels take (`ops/gdn_passes.py`), which the tiny ones are not."""
+    m = dict(TINY, mamba_d_head=64, mamba_d_state=64)
+    mamba_mixer_in_its_kernels(monkeypatch, zoo.Mamba2Mixer(
+        m["mamba_n_heads"], m["mamba_d_head"], m["mamba_n_groups"],
+        m["mamba_d_state"], m["mamba_d_conv"], m["mamba_chunk_size"],
+        m["rms_norm_eps"], jnp.float32,
+    ), ref._mamba2, m)
 
 
 def test_published_forty_layers_and_the_cut_are_the_same_code():

@@ -20,8 +20,8 @@ import pytest
 
 from elasticdl_tpu.ops import gqa
 from lm_contract import (  # noqa: F401  (the contract's cases, collected here)
-    _log_lines, _model_kwargs, _rel, bf16_case, lm, program_and_reference,
-    pytest_generate_tests,
+    _log_lines, _model_kwargs, _rel, bf16_case, lm,
+    mamba_mixer_in_its_kernels, program_and_reference, pytest_generate_tests,
     test_benchmark_cost_functions_count_what_they_say,
     test_bf16_program_is_the_reference_at_the_stated_precision,
     test_float32_products_ask_for_their_precision,
@@ -132,6 +132,32 @@ def test_parameter_names_and_layouts_follow_the_source():
     assert set(experts["shared_experts"]) == {"up_proj", "down_proj"}
 
 
+def test_in_proj_is_one_parameter_read_as_four_products():
+    """`SplitDense` holds and seeds `dense`'s parameter, and its results,
+    side by side, are `dense`'s."""
+    from model_zoo.lm_common import SplitDense, dense
+
+    x = jnp.asarray(np.random.default_rng(0).normal(size=(2, 9, 24)),
+                    jnp.float32)
+    split = SplitDense((16, 16, 8, 4), jnp.float32, name="in_proj")
+    whole = dense(44, jnp.float32, "in_proj")
+    variables = split.init(jax.random.PRNGKey(2), x)
+    assert jax.tree.map(jnp.shape, variables) == {
+        "params": {"kernel": (24, 44)}
+    }
+    np.testing.assert_array_equal(
+        variables["params"]["kernel"],
+        whole.init(jax.random.PRNGKey(2), x)["params"]["kernel"],
+    )
+    parts = split.apply(variables, x)
+    assert [p.shape[-1] for p in parts] == [16, 16, 8, 4]
+    with jax.default_matmul_precision("highest"):
+        np.testing.assert_allclose(
+            jnp.concatenate(split.apply(variables, x), axis=-1),
+            whole.apply(variables, x), rtol=1e-6, atol=1e-6,
+        )
+
+
 def test_initial_steps_and_decays_are_the_sources():
     """`dt_bias` is the inverse softplus of a step in [time_step_min,
     time_step_max], `A` in [-16, -1]: the decays a trained model has."""
@@ -143,6 +169,17 @@ def test_initial_steps_and_decays_are_the_sources():
     assert float(dt.min()) >= 1e-3 * 0.999 and float(dt.max()) <= 0.1 * 1.001
     a = jnp.exp(params["A_log"])
     assert float(a.min()) >= 1.0 and float(a.max()) <= 16.0
+
+
+def test_mamba_sublayer_in_its_kernels_matches_the_reference(monkeypatch):
+    """Two groups of 128 columns over rows of 512: widths the passes'
+    kernels take (`ops/gdn_passes.py`), which the tiny ones are not."""
+    m = dict(TINY, mamba_head_dim=64, ssm_state_size=64)
+    mamba_mixer_in_its_kernels(monkeypatch, zoo.Mamba2Mixer(
+        m["mamba_num_heads"], m["mamba_head_dim"], m["n_groups"],
+        m["ssm_state_size"], m["conv_kernel"], m["chunk_size"],
+        m["layer_norm_epsilon"], jnp.float32,
+    ), ref._mamba2, m)
 
 
 # ---------------------------------------------------------------------------

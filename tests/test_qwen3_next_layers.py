@@ -15,7 +15,9 @@ import pytest
 
 from elasticdl_tpu.ops import gated_delta, gdn_passes, gqa
 from elasticdl_tpu.ops.gated_delta import chunk_gated_delta_rule_xla
-from lm_contract import _close, _eqns, _log_lines, _perturbed
+from lm_contract import (
+    _close, _eqns, _log_lines, _perturbed, engines_as_on_a_tpu,
+)
 from spec_qwen3_next import TINY, ref, zoo
 
 
@@ -107,19 +109,6 @@ def _parent_gated_delta_net(params, x, hk, hv, dk, dv, eps):
     return out.reshape(b, t, hv * dv) @ params["out_proj"]["kernel"]
 
 
-def _engines_as_on_a_tpu(monkeypatch):
-    """The engines a TPU would be given, in interpret mode: the choice
-    by shapes alone."""
-    monkeypatch.setattr(
-        gated_delta, "_engine",
-        lambda supported, mesh, *why: (
-            "pallas" if supported else "xla", "as on a tpu"
-        ),
-    )
-    monkeypatch.setattr(gated_delta, "_use_interpret", lambda: True)
-    monkeypatch.setattr(gdn_passes, "_use_interpret", lambda: True)
-
-
 @pytest.mark.parametrize("t,hk,hv", [(200, 1, 2), (320, 2, 4), (200, 1, 1)])
 def test_layer_in_one_layout_is_the_layer_it_was(t, hk, hv, monkeypatch):
     """The whole DeltaNet sublayer on the path a TPU takes (the passes
@@ -127,7 +116,7 @@ def test_layer_in_one_layout_is_the_layer_it_was(t, hk, hv, monkeypatch):
     the body it had, from the same parameters in the source's column
     order, at float32: forward to 1e-5, every gradient to 1e-4 of its
     rms."""
-    _engines_as_on_a_tpu(monkeypatch)
+    engines_as_on_a_tpu(monkeypatch)
     module = zoo.GatedDeltaNet(hk, hv, 128, 128, 4, 1e-6, jnp.float32)
     rng = np.random.default_rng(t)
     x = jnp.asarray(rng.normal(size=(2, t, 64)), jnp.float32)

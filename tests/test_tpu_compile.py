@@ -195,13 +195,37 @@ def _delta_rule(backward, d=128):
 
 
 def _gdn_pass(which, backward):
-    """The passes around the rule (`ops/gdn_passes.py`) at the cell's
-    rows: q's conv + silu + l2-norm over 16 heads of 128, v's conv +
-    silu over 32, the gated norm over 32 with its bfloat16 result."""
+    """The passes of `ops/gdn_passes.py` at the cells' rows.  Around the
+    delta rule: q's conv + silu + l2-norm over 16 heads of 128, v's conv
+    + silu over 32, the gated norm over 32 with its bfloat16 result.
+    Around the state-space scan, 1 x 8192 rows: the conv + silu with its
+    bias over x (4096 columns in both models) and over [B | C] (2048 in
+    Nemotron-H, 256 in Granite), the skip, gate and norm over 4096 columns
+    in 8 groups and in 1."""
     from elasticdl_tpu.ops import gdn_passes
 
-    rows = {"q": 16 * 128, "v": 32 * 128, "norm": 32 * 128}[which]
-    if which == "norm":
+    if which.startswith("group_norm"):
+        groups = int(which.rsplit("_", 1)[1])
+
+        def fwd(y, x, z, skip, weight):
+            return gdn_passes.gated_group_norm(
+                y, x, z, skip, weight, groups=groups, eps=1e-5,
+                dtype=jnp.bfloat16, pallas=True, interpret=False,
+            )
+
+        shapes = [(1, 8192, 4096)] * 3 + [(64,), (4096,)]
+    elif which.startswith("conv_bias"):
+        rows = int(which.rsplit("_", 1)[1])
+
+        def fwd(x, taps, bias):
+            return gdn_passes.conv_silu(
+                x, taps, bias, pallas=True, interpret=False
+            )
+
+        shapes = [(1, 8192, rows), (4, rows), (rows,)]
+    elif which == "norm":
+        rows = 32 * 128
+
         def fwd(out, gate, weight):
             return gdn_passes.gated_rms_norm(
                 out, gate, weight, dtype=jnp.bfloat16, pallas=True,
@@ -210,6 +234,8 @@ def _gdn_pass(which, backward):
 
         shapes = [(2, 8192, rows), (2, 8192, rows), (128,)]
     else:
+        rows = {"q": 16 * 128, "v": 32 * 128}[which]
+
         def fwd(x, taps):
             return gdn_passes.conv_silu(
                 x, taps, head=128 if which == "q" else 0, scale=128 ** -0.5,
@@ -267,7 +293,9 @@ _CASES = {
     **{
         f"gdn_{which}_{'bwd' if backward else 'fwd'}":
             functools.partial(_gdn_pass, which, backward)
-        for which in ("q", "v", "norm") for backward in (False, True)
+        for which in ("q", "v", "norm", "conv_bias_4096", "conv_bias_2048",
+                      "conv_bias_256", "group_norm_8", "group_norm_1")
+        for backward in (False, True)
     },
     "delta_rule_fwd": functools.partial(_delta_rule, False),
     "delta_rule_bwd": functools.partial(_delta_rule, True),
