@@ -134,6 +134,8 @@ import jax.numpy as jnp
 import numpy as np
 from flax.traverse_util import flatten_dict, unflatten_dict
 
+from elasticdl_tpu.layers.ledger import TaskLedger
+
 ROUTING_COLLECTION = "routing"
 #: The bounds of a block's rows where the shapes decide (module docstring).
 MIN_BLOCK_ROWS, MAX_BLOCK_ROWS = 128, 512
@@ -585,19 +587,14 @@ def with_absent_counters(model_state):
     }
 
 
-class RoutingLedger:
-    """A task's share of the cumulative ``routing`` counters: the worker
-    reads them where it has already fetched the task's loss (no device
-    sync of its own inside a step) and journals the difference to the
-    last reading as a ``moe.routing`` span (`block_rows` is no sum: the
-    largest of the layers' last).  uint32 differences are right across a
-    wrap."""
+class RoutingLedger(TaskLedger):
+    """``moe.routing``: a task's share of the cumulative ``routing``
+    counters (`layers/ledger.py`; `block_rows` is no sum: the largest of
+    the layers' last).  uint32 differences are right across a wrap."""
 
-    def __init__(self):
-        self._seen = None  # None: not seeded yet
+    span = "moe.routing"
 
-    @staticmethod
-    def _read(model_state) -> dict:
+    def _read(self, model_state) -> dict:
         """{counter: [layers, ...]} of every expert layer's counters."""
         flat = flatten_dict(dict(model_state.get(ROUTING_COLLECTION, {})))
         if not flat:
@@ -617,20 +614,7 @@ class RoutingLedger:
             if layers[0] + (key,) in flat
         }
 
-    def seed_once(self, model_state) -> None:
-        """Before the first task: counters restored from a checkpoint are
-        not this job's tasks' (no state yet: they will start at zero)."""
-        if self._seen is None:
-            self._seen = self._read(model_state or {})
-
-    def task_delta(self, model_state, steps: int = 1):
-        """-> the span's fields, or None for a model that counts nothing.
-        `steps`: the task's steps (`balance_loss` is a mean over them)."""
-        now = self._read(model_state)
-        if not now:
-            return None
-        seen = self._seen or {key: 0 * value for key, value in now.items()}
-        self._seen = now
+    def _fields(self, now, seen, steps):
         pairs, processed, blocks, load = (
             (now[key] - seen[key]).astype("int64")
             for key in ("pairs", "processed", "blocks", "load")
@@ -650,3 +634,11 @@ class RoutingLedger:
                 np.mean(now["balance"] - seen["balance"]) / max(steps, 1)
             )
         return fields
+
+    def refuse(self, fields):
+        if fields["dropped"]:
+            return (
+                f"the expert layers dropped {fields['dropped']} routed "
+                "pair(s): they are built to drop none"
+            )
+        return None

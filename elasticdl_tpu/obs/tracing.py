@@ -180,6 +180,11 @@ SPAN_NAMES: Dict[str, str] = {
                    "the blocks' fill), largest and mean load of a held "
                    "expert, and the mean balancing loss where the routers "
                    "have one (`balance_loss`)",
+    # exits of a looped stack (layers/loop_exits.py): the same route
+    "loop.exits": "after: worker, one a task of a model whose stack is "
+                  "applied several times: the `tokens` its steps "
+                  "counted, the mean exit distribution over them "
+                  "(`p_exit_1` .. `p_exit_R`) and its mean `entropy`",
 }
 
 #: ``jax.named_scope`` names on device ops (op metadata only; they show
@@ -194,12 +199,16 @@ SPAN_NAMES: Dict[str, str] = {
 #: (mla_latent, mla_core), mlp, moe > (...), lm_head_loss).  With the
 #: window-and-full-attention model (model_zoo/laguna): fwd_bwd > (attn >
 #: (attn_full | attn_window, attn_gate), mlp, moe > (...), lm_head_loss).
+#: With the looped stack (model_zoo/ouro): fwd_bwd > (loop > (attn >
+#: (attn_proj, attn_rotary, attn_full), mlp, block_norm), lm_head_loss,
+#: exit_gate).
 DEVICE_SCOPES = (
     "fwd_bwd", "dense_update", "sparse_apply", "grad_accumulate",
     "sparse_adam", "attn", "mlp", "lm_head_loss", "optimizer",
     "gdn", "gdn_scan", "moe", "moe_route", "moe_experts", "moe_shared",
     "ssm", "ssm_scan", "gdn_mix", "mla_latent", "mla_core",
     "attn_full", "attn_window", "attn_gate", "attn_proj", "attn_rotary",
+    "loop", "block_norm", "exit_gate",
 )
 
 #: Size bound on the flight recorder's final registry snapshot: the

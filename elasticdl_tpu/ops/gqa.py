@@ -15,7 +15,15 @@ scaled by `scale`, 1/sqrt(Dqk) where none is given.  Two engines:
   `supports(T, Dqk, d_v=Dv)` holds (K and V of a head resident in VMEM:
   T (Dqk + Dv) 4 B within 8 MiB, so T <= 4096 at head sizes of 256 or of
   192 and 128).  It takes equal head counts, so K and V are repeated to
-  the query heads for it.
+  the query heads for it.  `auto` takes it wherever it fits, and at
+  T 8192, heads of 128, the three shapes measured say it should not (a
+  v5e, forward, rematerialised forward and backward): K and V repeated 8
+  times, 51.35 ms a layer against 35.10 in the XLA engine (32 heads over
+  4, 2 sequences, PR 42), repeated 6 and 8 times in Laguna's two layer
+  types (PR 36: its job says `xla` too), and with NOTHING repeated, 16
+  heads over 16, 1 sequence: 13.74 ms a layer application against
+  12.54 (PR 45; that cell keeps `auto`, for its traced runs' sake).  The rule is still left to a PR that measures every
+  cell whose stack calls it (PERF.md section 7).
 - `causal_gqa_attention`, flash numerics in XLA ops, for everything else.
   `parallel.ring_attention.blockwise_attention` scores ALL queries against
   one key chunk at a time, and the reverse pass JAX derives for its scan

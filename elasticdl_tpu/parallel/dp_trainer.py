@@ -43,7 +43,11 @@ def per_example_loss_fn(loss_fn: Callable) -> Callable:
     The model-zoo contract's `loss(labels, outputs)` returns the batch mean
     (reference contract).  Applying it to singleton batches under vmap
     recovers the per-example loss for any mean-of-per-example loss, which
-    lets the trainer mask padded rows exactly.
+    lets the trainer mask padded rows exactly.  Labels and outputs may
+    each be a tree of arrays with the batch in front (a prediction that
+    is several named arrays: `model_zoo/ouro`'s four exits' logits and
+    their exit distribution; tests/test_ouro_program.py trains it through
+    here): every leaf is mapped.
     """
 
     def singleton(label, output):

@@ -38,7 +38,7 @@ from elasticdl_tpu.common.log_utils import get_logger
 from elasticdl_tpu.common.model_utils import ModelSpec
 from elasticdl_tpu.data.columnar import materialize_columnar_task
 from elasticdl_tpu.data.dataset import Dataset, SequentialRecords, _stack
-from elasticdl_tpu.layers.moe import RoutingLedger
+from elasticdl_tpu.layers.ledger import task_ledgers
 from elasticdl_tpu.data.pipeline import (
     ParsePool,
     PipelineConfig,
@@ -145,8 +145,8 @@ class CollectiveWorker:
         # back to the already-compiled per-step program instead of
         # compiling a one-off K-step scan per distinct tail size.
         self._effective_window: Optional[int] = None
-        # Per-task reading of a model's expert-routing counters.
-        self._routing = RoutingLedger()
+        # Per-task readings of a model's counters, whichever it has.
+        self._ledgers = task_ledgers()
         self._columnar_logged = False
         # Task-type -> reader: evaluation/prediction shards address their
         # own data sources when configured.
@@ -529,28 +529,31 @@ class CollectiveWorker:
         state = self._trainer.state
         return None if state is None else state.model_state
 
-    def _journal_routing(self, start_ts: float, steps: int) -> None:
-        """`moe.routing`, one span a task: what the model's expert layers
-        counted over the task's steps (layers/moe.py; nothing for a model
-        without them).  Called where the task's loss has been fetched."""
+    def _journal_counters(self, start_ts: float, steps: int) -> None:
+        """One span a task for each kind of counter the model keeps in its
+        state (layers/ledger.py: `moe.routing` of a model with expert
+        layers, `loop.exits` of one that is applied several times; nothing
+        for a model with neither): what was counted over the task's
+        steps.  Called where the task's loss has been fetched."""
         model_state = self._model_state()
         if not model_state:
             return
-        fields = self._routing.task_delta(model_state, steps)
-        if fields is not None:
+        for ledger in self._ledgers:
+            fields = ledger.task_delta(model_state, steps)
+            if fields is None:
+                continue
             tracing.record_child_span(
-                "moe.routing", start_ts, time.time() - start_ts,
+                ledger.span, start_ts, time.time() - start_ts,
                 step=self._trainer.step, steps=steps, **fields,
             )
-            if fields["dropped"]:
-                raise RuntimeError(
-                    f"the expert layers dropped {fields['dropped']} routed "
-                    "pair(s): they are built to drop none"
-                )
+            refused = ledger.refuse(fields)
+            if refused:
+                raise RuntimeError(refused)
 
     def _process_train_task(self, task) -> dict:
         task_start_ts = time.time()
-        self._routing.seed_once(self._model_state())
+        for ledger in self._ledgers:
+            ledger.seed_once(self._model_state())
         batch_count = 0
         record_count = 0
         last_loss = None
@@ -762,7 +765,7 @@ class CollectiveWorker:
                 batch_count,
             )
         if last_loss is not None:
-            self._journal_routing(task_start_ts, batch_count)
+            self._journal_counters(task_start_ts, batch_count)
         self._report_version()
         counters = {
             TaskExecCounterKey.BATCH_COUNT: batch_count,

@@ -8,17 +8,25 @@ edit to a stack meets that model's cells and no other; an edit HERE meets
 every cell whose stack uses the piece:
 
 - the causal-LM zoo contract on `synthetic://lm` data (`VOCAB`, `SEQ_LEN`,
-  `custom_data_reader`, `dataset_fn`, `eval_metrics_fn`, `loss`): all seven
+  `custom_data_reader`, `dataset_fn`, `eval_metrics_fn`, `loss`): seven
   stacks (`gpt2-medium`, `qwen3-next`, `nemotron3-nano`, `deepseek-v2-lite`,
-  `laguna-xs2`, `granite4-h-micro`, `mellum2`);
-- `dense`, the bias-free projection with a float32 result: the six 8k
+  `laguna-xs2`, `granite4-h-micro`, `mellum2`); the eighth, `ouro`, takes
+  the data contract alone: its prediction is a named tree (four exits'
+  logits and the exit distribution), so its `loss` and `eval_metrics_fn`
+  are its own;
+- `dense`, the bias-free projection with a float32 result: the seven 8k
   stacks (`SplitDense`, the same parameter read as a product a range of
   its columns: `Mamba2Mixer`); `RMSNorm` (weight from 1): all of them
   but Qwen3-Next, whose zero-centred norm is another function and stays
   in its file (Mellum 2 also norms every query and key head with it: it
   is over the last axis, so a [B, T, heads, head_dim] tensor gets one
-  weight vector of `head_dim`);
-- `warmup_adamw`: DeepSeek-V2, Granite and Mellum 2; `balancing_adamw`, which wraps
+  weight vector of `head_dim`; Ouro has four of them a layer and one
+  that closes every pass);
+- `RotaryAttention`, the projections, `ops/rotary_pack.py` and
+  `ops/gqa.causal_attention` as one piece (a head norm and a band where
+  the stack says so): Mellum 2 (both, by the layer's type) and Ouro
+  (neither);
+- `warmup_adamw`: DeepSeek-V2, Granite, Mellum 2 and Ouro; `balancing_adamw`, which wraps
   it with the rule that moves a sigmoid router's selection biases:
   Nemotron-H and Laguna, the two stacks behind that router;
 - `listed` and `check_listed`, a source's per-layer lists as a job's flat
@@ -44,6 +52,7 @@ import numpy as np
 import optax
 
 from elasticdl_tpu.ops import gdn_passes, gqa
+from elasticdl_tpu.ops.rotary_pack import rotary_pack
 from elasticdl_tpu.ops.ssd import ssd_chunked
 from model_zoo import datasets
 
@@ -347,6 +356,61 @@ class Mamba2Mixer(nn.Module):
             self.out_scale ** 2, "fan_in", "truncated_normal"
         )
         return dense(d, self.dtype, "out_proj", init)(y)
+
+
+class RotaryAttention(nn.Module):
+    """Grouped-query causal softmax attention behind rotary positions, as
+    one piece: `q_proj`, `k_proj`, `v_proj` without bias; q and k from
+    the projections' float32 results to the engine's operands in one pass
+    (`ops/rotary_pack.py`: a norm over each head's columns where
+    `head_norm_eps` is a number, with the weights `q_norm` / `k_norm`,
+    then the rotation by `cos` / `sin` over as many of a head's columns as
+    the tables have, one rounding, heads in front of tokens);
+    `ops/gqa.causal_attention` over every key up to the query's own, or
+    over the last `window` of them; `o_proj`.  Device scopes: `attn_proj`
+    (entered for q, k, v and again for `o_proj`), `attn_rotary`,
+    `attn_full` | `attn_window`."""
+
+    num_heads: int
+    num_kv_heads: int
+    head_dim: int
+    dtype: Any
+    impl: str = "auto"       # `ops/gqa.causal_attention`'s, as it is
+    window: Any = None       # keys a query reads, its own among them
+    head_norm_eps: Any = None  # None: q and k heads are not normed
+
+    @nn.compact
+    def __call__(self, x, cos, sin):
+        b, t, d = x.shape
+        h, hkv, hd = self.num_heads, self.num_kv_heads, self.head_dim
+        with jax.named_scope("attn_proj"):
+            q, k, v = (
+                dense(n * hd, self.dtype, name)(x).reshape(b, t, n, hd)
+                for name, n in (("q_proj", h), ("k_proj", hkv),
+                                ("v_proj", hkv))
+            )
+        with jax.named_scope("attn_rotary"):
+            # over a head's columns, one weight vector each
+            q, k = (
+                rotary_pack(
+                    p, cos, sin, self.dtype,
+                    None if self.head_norm_eps is None
+                    else NormWeight(hd, name=norm)(),
+                    self.head_norm_eps or 1e-6,
+                )
+                for p, norm in ((q, "q_norm"), (k, "k_norm"))
+            )
+        with jax.named_scope(
+            "attn_full" if self.window is None else "attn_window"
+        ):
+            out = gqa.heads_first(gqa.causal_attention(
+                q, k, gqa.heads_first(v.astype(self.dtype)), impl=self.impl,
+                window=self.window, packed=True,
+            ))
+        with jax.named_scope("attn_proj"):
+            return dense(d, self.dtype, "o_proj")(
+                out.reshape(b, t, h * hd).astype(self.dtype)
+            )
 
 
 class Attention(nn.Module):

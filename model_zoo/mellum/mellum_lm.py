@@ -97,55 +97,28 @@ import jax.numpy as jnp
 
 from elasticdl_tpu.layers.moe import GatedMLP, SparseMoeBlock
 from elasticdl_tpu.ops import gqa
-from elasticdl_tpu.ops.rotary_pack import rotary_pack
-# The norm, the projection, the optimizer's warm-up and the rest of the zoo
-# contract of any causal LM on `synthetic://lm` data: mean next-token
+# The norm, the attention behind rotary positions, the optimizer's warm-up
+# and the rest of the zoo contract of any causal LM on `synthetic://lm` data: mean next-token
 # cross-entropy over float32 logits (under the `lm_head_loss` scope),
 # perplexity and accuracy.
 from model_zoo.lm_common import (  # noqa: F401
-    VOCAB, NormWeight, RMSNorm, check_listed, custom_data_reader, dataset_fn,
-    dense, eval_metrics_fn, listed, loss, warmup_adamw,
+    VOCAB, RMSNorm, RotaryAttention, check_listed, custom_data_reader,
+    dataset_fn, eval_metrics_fn, listed, loss, warmup_adamw,
 )
 
 FULL, SLIDING = "full_attention", "sliding_attention"
 DENSE, SPARSE = "dense", "sparse"
 
 
-class Attention(nn.Module):
-    cfg: Any       # MellumConfig
-    sliding: bool
-
-    @nn.compact
-    def __call__(self, x, cos, sin):
-        c = self.cfg
-        b, t, d = x.shape
-        h, hkv, hd = c.num_attention_heads, c.num_key_value_heads, c.head_dim
-        with jax.named_scope("attn_proj"):
-            q, k, v = (
-                dense(n * hd, c.dtype, name)(x).reshape(b, t, n, hd)
-                for name, n in (("q_proj", h), ("k_proj", hkv),
-                                ("v_proj", hkv))
-            )
-        with jax.named_scope("attn_rotary"):
-            # over a head's columns, one weight vector each
-            q, k = (
-                rotary_pack(
-                    p, cos, sin, c.dtype,
-                    NormWeight(hd, name=norm)() if c.qk_norm else None,
-                    c.rms_norm_eps,
-                )
-                for p, norm in ((q, "q_norm"), (k, "k_norm"))
-            )
-        with jax.named_scope("attn_window" if self.sliding else "attn_full"):
-            out = gqa.heads_first(gqa.causal_attention(
-                q, k, gqa.heads_first(v.astype(c.dtype)), impl=c.attn_impl,
-                window=c.sliding_window if self.sliding else None,
-                packed=True,
-            ))
-        with jax.named_scope("attn_proj"):
-            return dense(d, c.dtype, "o_proj")(
-                out.reshape(b, t, h * hd).astype(c.dtype)
-            )
+def Attention(cfg, sliding: bool, **module):
+    """`lm_common.RotaryAttention` at this model's shapes: a norm on every
+    query and key head where `qk_norm`, a sliding layer's band."""
+    return RotaryAttention(
+        cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim,
+        cfg.dtype, cfg.attn_impl,
+        cfg.sliding_window if sliding else None,
+        cfg.rms_norm_eps if cfg.qk_norm else None, **module,
+    )
 
 
 class DecoderLayer(nn.Module):

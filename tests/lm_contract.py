@@ -213,13 +213,24 @@ def _flag(value):
     return str(value)
 
 
-def routing_spans(events):
-    """The two tasks' `moe.routing` spans of a two-task job's journal."""
-    routing = [e for e in events
-               if e.get("event") == "span" and e.get("name") == "moe.routing"]
-    assert len(routing) == 2
-    assert [e["steps"] for e in routing] == [2, 2]
-    return routing
+#: The spans the worker writes a task from a model's counters
+#: (`layers/ledger.py`), each by the kind of model that has it.
+COUNTER_SPANS = ("moe.routing", "loop.exits")
+
+
+def counter_spans(events, name="moe.routing"):
+    """The two tasks' `name` spans of a two-task job's journal; a model
+    with that counter writes no other kind."""
+    found = {
+        kind: [e for e in events
+               if e.get("event") == "span" and e.get("name") == kind]
+        for kind in COUNTER_SPANS
+    }
+    spans = found.pop(name)
+    assert len(spans) == 2
+    assert [e["steps"] for e in spans] == [2, 2]
+    assert not any(found.values())
+    return spans
 
 
 # ---------------------------------------------------------------------------
@@ -288,6 +299,9 @@ class LMSpec:
     held: tuple = ()
     whole_model_changes: dict = dataclasses.field(default_factory=dict)
     logits_rel: float = 1e-5
+    #: a prediction that is a named tree -> the ONE array the reference's
+    #: `forward` returns and the cell compares; None: the prediction is it
+    compared: Optional[Callable] = None
     #: (ref, params, tokens, model) -> (the loss the program reports, what
     #: its gradient has on top); None: the zoo's loss over `ref.forward`
     losses: Optional[Callable] = None
@@ -359,6 +373,10 @@ class LMSpec:
 
     def build(self, model, **keywords):
         return self.zoo.custom_model(**self.kwargs(model), **keywords)
+
+    def array(self, prediction):
+        """What is compared of a prediction (`compared`)."""
+        return self.compared(prediction) if self.compared else prediction
 
     def whole(self, first_held=None) -> Whole:
         """The float32 program and the reference at the reduced widths."""
@@ -462,14 +480,16 @@ def bf16_case(lm):
 
 def test_logits_and_loss_match_the_reference(lm, program_and_reference):
     program, reference, params, tokens, _ = whole = program_and_reference
-    got, want = program(params), reference(params)
-    assert got.shape == want.shape == tokens.shape + (
+    predicted, want = program(params), reference(params)
+    got = lm.array(predicted)  # the prediction itself, or a named tree's
+    assert got.shape == want.shape
+    assert (got.shape[0], got.shape[-2], got.shape[-1]) == tokens.shape + (
         lm.tiny["vocab_size"],)
     assert _rel(got, want) < lm.logits_rel
     # the program REPORTS the first of the two alone
     reported, added = lm.reference_losses(params, whole)
     np.testing.assert_allclose(
-        float(lm.zoo.loss(tokens, got)), float(reported), rtol=1e-5
+        float(lm.zoo.loss(tokens, predicted)), float(reported), rtol=1e-5
     )
     if lm.added_loss_above is not None:
         assert float(added) > lm.added_loss_above
@@ -681,9 +701,11 @@ def test_trainer_carries_the_counters_and_checkpoint_restores_the_logits(
     assert step == 3
     fresh, _ = lm.trainer()
     fresh.state = restored
-    np.testing.assert_array_equal(fresh.eval_step(tokens), before)
+    jax.tree.map(
+        np.testing.assert_array_equal, fresh.eval_step(tokens), before
+    )
     want = lm.ref.forward(restored.params, tokens, model)
-    assert _rel(before, want) < lm.logits_rel
+    assert _rel(lm.array(before), want) < lm.logits_rel
 
 
 class Job(NamedTuple):

@@ -11,7 +11,7 @@ import numpy as np
 from elasticdl_tpu.layers.moe import RoutingLedger
 from lm_contract import (
     Bf16Case, CompileSpec, LMSpec, _model_kwargs, _size, rounded_parts,
-    routing_spans,
+    counter_spans,
 )
 
 
@@ -85,7 +85,7 @@ def _trained(trainer, model):
 
 def _journal(job, events):
     """`moe.routing` a task, with the balancing loss on it."""
-    routing = routing_spans(events)
+    routing = counter_spans(events)
     assert all(e["layers"] == 1 and e["held"] == 4 for e in routing)
     assert all(e["dropped"] == 0 and e["pairs"] > 0 for e in routing)
     assert all(0.5e-3 < e["balance_loss"] < 3e-3 for e in routing)

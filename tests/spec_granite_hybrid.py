@@ -7,7 +7,9 @@ runs it as a job does.
 
 import os
 
-from lm_contract import Bf16Case, CompileSpec, LMSpec, _reference
+from lm_contract import (
+    COUNTER_SPANS, Bf16Case, CompileSpec, LMSpec, _reference,
+)
 
 
 def _whole_model_in_bfloat16():
@@ -105,6 +107,8 @@ def _journal(job, events):
             "step.compile", "step.execute")
     ]
     assert [e["steps"] for e in executed] == [2, 2]
+    # neither expert layers nor a loop: none of the counters' spans
+    assert not any(e.get("name") in COUNTER_SPANS for e in events)
     assert not any(e.get("event") == "checkpoint_restored" for e in events)
     assert job.run(job.tmp_path / "tb2") == 0
     restored = [

@@ -12,7 +12,7 @@ import pytest
 from elasticdl_tpu.layers.moe import RoutingLedger
 from lm_contract import (
     SELECTION_BIAS, Bf16Case, CompileSpec, LMSpec, _size, rounded_parts,
-    routing_spans,
+    counter_spans,
 )
 
 
@@ -119,7 +119,7 @@ def _trained(trainer, model):
 def _journal(job, events):
     """`moe.routing` a task; the per-layer lists rode the job's flat
     flags as a/b/c."""
-    routing = routing_spans(events)
+    routing = counter_spans(events)
     assert all(e["layers"] == 4 and e["held"] == 4 for e in routing)
     assert all(e["dropped"] == 0 and e["pairs"] > 0 for e in routing)
 

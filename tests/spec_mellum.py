@@ -10,7 +10,7 @@ import pytest
 
 from elasticdl_tpu.layers.moe import RoutingLedger
 from lm_contract import (
-    Bf16Case, CompileSpec, LMSpec, _size, rounded_parts, routing_spans,
+    Bf16Case, CompileSpec, LMSpec, _size, rounded_parts, counter_spans,
 )
 
 
@@ -132,7 +132,7 @@ def _trained(trainer, model):
 def _journal(job, events):
     """`moe.routing` a task with the balancing loss on it; the per-layer
     lists rode the job's flat flags as a/b/c."""
-    routing = routing_spans(events)
+    routing = counter_spans(events)
     assert all(e["layers"] == 4 and e["held"] == 4 for e in routing)
     assert all(e["dropped"] == 0 and e["pairs"] > 0 for e in routing)
     assert all(0.05 < e["balance_loss"] < 0.3 for e in routing)

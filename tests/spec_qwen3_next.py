@@ -10,7 +10,7 @@ import jax.numpy as jnp
 
 from elasticdl_tpu.layers.moe import RoutingLedger, SparseMoeBlock
 from lm_contract import (
-    Bf16Case, CompileSpec, LMSpec, routing_spans,
+    Bf16Case, CompileSpec, LMSpec, counter_spans,
     sublayer_at_the_stated_precision,
 )
 
@@ -70,7 +70,7 @@ def _trained(trainer, model):
 
 def _journal(job, events):
     """`moe.routing` a task."""
-    routing = routing_spans(events)
+    routing = counter_spans(events)
     assert [e["step"] for e in routing] == [2, 4]
     assert all(e["dropped"] == 0 and e["pairs"] > 0 for e in routing)
     assert all(e["load_max"] >= e["load_mean"] > 0 for e in routing)

@@ -124,7 +124,7 @@ def test_every_emitted_name_is_in_the_vocabulary():
         "checkpoint.restore.load", "proc.start",
         "master.tensorboard_init", "master.serve_ready",
         "worker.backend_init", "state.init", "compile.build",
-        "moe.routing", *BOOT_CHAIN_SPANS,
+        "moe.routing", "loop.exits", *BOOT_CHAIN_SPANS,
     ):
         assert name in tracing.SPAN_NAMES
     assert set(tracing.DEVICE_SCOPES) >= {
@@ -133,7 +133,14 @@ def test_every_emitted_name_is_in_the_vocabulary():
         "gdn", "gdn_scan", "moe", "moe_route", "moe_experts", "moe_shared",
         "ssm", "ssm_scan", "gdn_mix", "mla_latent", "mla_core",
         "attn_full", "attn_window", "attn_gate", "attn_proj", "attn_rotary",
+        "loop", "block_norm", "exit_gate",
     }
+    # every ledger a model's counters can have writes a span of the list
+    from elasticdl_tpu.layers.ledger import task_ledgers
+
+    assert [ledger.span for ledger in task_ledgers()] == [
+        "moe.routing", "loop.exits",
+    ]
 
 
 #: The start-up chain (ISSUE 34): the two boot spans, their children,
@@ -161,7 +168,7 @@ BOOT_CHAIN_METRICS = (
 
 def test_every_device_scope_and_start_up_span_names_its_reader():
     """`docs/observability.md` and PERF.md's span table name every device
-    scope, the `moe.routing` span and every span and field of the
+    scope, the `moe.routing` and `loop.exits` spans and every span and field of the
     start-up chain, with the reader of each: nothing on the lists is
     without one, and `since_main_s` is gone from both."""
     with open(os.path.join(REPO_ROOT, "docs", "observability.md")) as f:
@@ -169,7 +176,8 @@ def test_every_device_scope_and_start_up_span_names_its_reader():
     with open(os.path.join(REPO_ROOT, "PERF.md")) as f:
         perf = f.read()
     for name in (
-        tracing.DEVICE_SCOPES + ("moe.routing",) + BOOT_CHAIN_SPANS
+        tracing.DEVICE_SCOPES + ("moe.routing", "loop.exits")
+        + BOOT_CHAIN_SPANS
         + BOOT_CHAIN_FIELDS
     ):
         assert f"`{name}`" in docs, name
