@@ -15,7 +15,8 @@ strict apply; weights random from --seed, data written from --seed):
                flash attention fwd+bwd, the ring step), the delta
                rule's kernel pair, the two passes around it and the
                pair around a state-space scan (ops/gdn_passes.py),
-               which the backend picks, and the pass
+               which the backend picks, that scan's own kernel pair
+               (ops/ssd.py), and the pass
                in front of the attention engine (ops/rotary_pack.py) —
                correctness only
   train        `python -m elasticdl_tpu.client.main train` with
@@ -691,6 +692,61 @@ def phase_kernels(args) -> dict:
         scale = max(float(jnp.max(jnp.abs(w))), 1.0)
         check(f"state-space passes {which} ~ jax.numpy chain", g, w,
               1e-5, 1e-5 * scale, secs)
+
+    # -- the scan between them (ops/ssd.py): its kernel pair against the
+    # XLA form at the same bfloat16 products, two groups of eight heads in
+    # chunks of 128 and one of sixteen in chunks of 256; the roundings
+    # fall at the same places and the float32 sums in another order, so a
+    # rounding moves here and there: 1% of the largest (3% off the chip,
+    # where the XLA form's backward keeps d y float32 and the kernels
+    # round it as a TPU's default does) -------------------------------------
+    from elasticdl_tpu.ops import ssd
+
+    for groups, chunk in ((2, 128), (1, 256)):
+        keys = jax.random.split(jax.random.PRNGKey(args.seed + 16), 5)
+        scan_args = (
+            jax.random.normal(keys[0], (1, t_rule, 16 * 64)),
+            jnp.exp(jax.random.uniform(
+                keys[1], (1, t_rule, 16), minval=jnp.log(1e-3),
+                maxval=jnp.log(0.1),
+            )),
+            -jax.random.uniform(keys[2], (16,), minval=1.0, maxval=16.0),
+            jax.random.normal(keys[3], (1, t_rule, 2 * groups * 128)),
+        )
+        d_out = jax.random.normal(keys[4], (1, t_rule, 16 * 64))
+
+        def scan_grads(kernels):
+            def loss(x, dt, a, bc):
+                if kernels:
+                    out, state = ssd.ssd_chunked_pallas(
+                        x, dt, a, bc, groups=groups, chunk=chunk
+                    )
+                else:
+                    b, c = jnp.split(
+                        bc.reshape(1, t_rule, 2 * groups, 128), 2, axis=2
+                    )
+                    out, state = ssd.ssd_chunked_xla(
+                        x.reshape(1, t_rule, 16, 64), dt, a, b, c,
+                        chunk=chunk, dtype=jnp.bfloat16,
+                    )
+                    out = out.reshape(1, t_rule, 16 * 64)
+                return jnp.sum(out * d_out) + jnp.sum(state), (out, state)
+
+            return jax.grad(loss, argnums=range(4), has_aux=True)
+
+        (got_g, got_out), secs = run(
+            f"state-space scan, chunks of {chunk}", scan_grads(True),
+            *scan_args,
+        )
+        want_g, want_out = twin(scan_grads(False), *scan_args)
+        for g, w, which in zip(
+            got_out + got_g, want_out + want_g,
+            ("y", "final state", "d x", "d dt", "d a", "d [B | C]"),
+        ):
+            limit = 1e-2 if on_tpu else 3e-2
+            check(f"state-space scan, chunks of {chunk}, {which} ~ xla "
+                  "engine", g, w, limit,
+                  limit * float(jnp.max(jnp.abs(w))), secs)
 
     # -- from a projection's result to the attention engine's operand
     # (ops/rotary_pack.py), against apply_rotary after the head norm: the

@@ -79,6 +79,11 @@ projections and the passes of `ops/gdn_passes.py` (on a TPU the kernels
 `conv_silu_fwd|bwd`, twice a layer, and `gated_group_norm_fwd|bwd`; the
 worker's log line `gdn passes engine:` says which engine a trace held),
 which name no scope of their own.
+`ssm_scan` is `ops/ssd.py`'s chunked form alone: on a TPU, with bfloat16
+products, the kernel pair `ssd_fwd` / `ssd_bwd`, which reads x and
+[B | C] as the rows the convolutions wrote and writes y as the rows the
+norm reads, and keeps the decays and the masked scores in VMEM; the XLA
+form elsewhere (the log line `ssd engine: pallas|xla ... (why)`).
 """
 
 from __future__ import annotations

@@ -53,7 +53,7 @@ import optax
 
 from elasticdl_tpu.ops import gdn_passes, gqa
 from elasticdl_tpu.ops.rotary_pack import rotary_pack
-from elasticdl_tpu.ops.ssd import ssd_chunked
+from elasticdl_tpu.ops.ssd import ssd_chunked_rows
 from model_zoo import datasets
 
 VOCAB = 256
@@ -273,7 +273,11 @@ class Mamba2Mixer(nn.Module):
     split into x [H heads of P], B and C [G groups of N];
     `dt = softplus(dt + dt_bias)`, `A = -exp(A_log)` a scalar a head; the
     selective state-space recurrence of `ops/ssd.py` (heads of group g
-    share B and C) under the `ssm_scan` scope, plus the skip `D x`;
+    share B and C; its kernel pair or its XLA form, as `ssd_chunked_rows`
+    finds from the backend, the shapes, `dtype` and `mesh`: x and [B | C]
+    go in as the rows the convolutions wrote and y comes back as the rows
+    the norm reads, so no [B, T, H, P] is laid out on the way) under the
+    `ssm_scan` scope, plus the skip `D x`;
     `y = GroupRMSNorm(y silu(z))` over G groups with a weight; `out_proj`.
     The float32 chains between the projections and the scan are passes of
     `ops/gdn_passes.py`: `conv_silu` for x and for [B | C], and
@@ -318,16 +322,17 @@ class Mamba2Mixer(nn.Module):
         ) == "pallas")
         # Causal depthwise convolution over [x | B | C], then silu, the
         # taps accumulated in float32: a column at a time, so x's pass and
-        # [B | C]'s are two.
+        # [B | C]'s are two ([B | C] stays ONE array: the scan reads B and
+        # C as column ranges of it).
         kernel, bias = _Conv1d(self.conv_kernel, name="conv1d")(
             inner + 2 * bc
         )
         x = gdn_passes.conv_silu(
             x, kernel[:, :inner], bias[:inner], **passes
         )
-        b_in, c_in = jnp.split(gdn_passes.conv_silu(
+        bc_in = gdn_passes.conv_silu(
             bc_in, kernel[:, inner:], bias[inner:], **passes
-        ), 2, axis=-1)
+        )
         a_log = self.param(
             "A_log",
             lambda key, shape: jnp.log(
@@ -338,18 +343,19 @@ class Mamba2Mixer(nn.Module):
         skip = self.param("D", nn.initializers.ones_init(), (h,), jnp.float32)
         dt_bias = self.param("dt_bias", _dt_bias_init(*self.time_step), (h,))
         dt = jax.nn.softplus(dt + dt_bias)
+        # x [B, T, H P], [B | C] [B, T, 2 G N] and y as the passes write
+        # and read them: rows, which the scan's kernels take as they lie
         with jax.named_scope("ssm_scan"):
-            y, _ = ssd_chunked(
-                x.reshape(b, t, h, p), dt, -jnp.exp(a_log),
-                b_in.reshape(b, t, g, n), c_in.reshape(b, t, g, n),
-                chunk=self.chunk_size, dtype=self.dtype,
+            y, _ = ssd_chunked_rows(
+                x, dt, -jnp.exp(a_log), bc_in, groups=g,
+                chunk=self.chunk_size, dtype=self.dtype, mesh=self.mesh,
             )
         # The skip, the gate and the RMSNorm over each of the G groups of
         # the inner width, float32; the result in the out-projection's type.
         weight = self.param("norm", nn.initializers.ones_init(), (inner,),
                             jnp.float32)
         y = gdn_passes.gated_group_norm(
-            y.reshape(b, t, inner), x, z, skip, weight, groups=g,
+            y, x, z, skip, weight, groups=g,
             eps=self.eps, dtype=self.dtype, **passes,
         )
         init = nn.initializers.variance_scaling(

@@ -10,9 +10,11 @@ and that sum read and written once at the chip's HBM rate beside the
 `copy` the ledger's newest traced run measured for the cell.  The sum of
 the `copy` ops is what the descriptor's `CompileSpec.copy_bytes` bounds.
 
-Usage (35-60 s a cell; `<cell>` is a `tests/spec_<cell>.py`):
+Usage (35-60 s a cell; `<cell>` is a `tests/spec_<cell>.py`, or the
+name of a `BENCHMARK.json` workload whose configuration one describes):
     python scripts/program_copies.py laguna [--sequences n] [--step]
         [--text out.hlo]
+    python scripts/program_copies.py nemotron3-nano.train-synth-8k
 """
 
 from __future__ import annotations
@@ -58,9 +60,33 @@ def ledger_copy_ms(cell: str):
     return found
 
 
+def descriptor(name: str):
+    """The `SPEC` of `tests/spec_<name>.py`, or of the descriptor whose
+    cell file is the configuration of the `BENCHMARK.json` workload
+    `name`."""
+    try:
+        return importlib.import_module("spec_" + name).SPEC
+    except ModuleNotFoundError:
+        pass
+    with open(os.path.join(REPO_ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    config = next(w["config"] for w in bench["workloads"] if w["name"] == name)
+    cell = os.path.basename(
+        next(c["file"] for c in bench["configs"] if c["name"] == config)
+    )
+    for path in sorted(os.listdir(os.path.join(REPO_ROOT, "tests"))):
+        if path.startswith("spec_") and path.endswith(".py"):
+            spec = importlib.import_module(path[:-len(".py")]).SPEC
+            if spec.cell == cell:
+                return spec
+    raise SystemExit(f"no tests/spec_*.py describes {cell}")
+
+
 def main(argv=None):
     parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    parser.add_argument("cell", help="a descriptor: tests/spec_<cell>.py")
+    parser.add_argument(
+        "cell", help="a descriptor, tests/spec_<cell>.py, or a workload"
+    )
     parser.add_argument("--sequences", type=int, default=None,
                         help="sequences a step (the cell's own)")
     parser.add_argument("--step", action="store_true",
@@ -77,7 +103,7 @@ def main(argv=None):
 
     import lm_contract
 
-    spec = importlib.import_module("spec_" + args.cell).SPEC
+    spec = descriptor(args.cell)
     sequences = args.sequences or spec.job_flags[1]
     topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     compiled = lm_contract.compile_program(

@@ -144,17 +144,24 @@ SPEC = LMSpec(
     trained=_trained,
     journal=_journal,
     # 1 x 8192 tokens a step: 8.0 GB of state donated (12 B x 667M) and
-    # 4.62 GB of temporaries with each layer rematerialised (3.25 until
+    # 3.94 GB of temporaries with each layer rematerialised (3.25 until
     # PR 44: a Mamba-2 layer's `in_proj` as four products holds 1.4 GB more
-    # at the program's peak than one product whose result was sliced; at
-    # 2 x 8192 it needs 16.8 GB and does not fit), the attention layer in the
-    # Pallas kernel exactly at `supports`' cap (K + V of a head are 8 MiB
+    # at the program's peak than one product whose result was sliced; 4.62
+    # until PR 46, whose scan keeps its decays and masked scores in VMEM;
+    # at 2 x 8192 it needs 16.8 GB and does not fit), the attention layer in
+    # the Pallas kernel exactly at `supports`' cap (K + V of a head are 8 MiB
     # of float32).
     compile=CompileSpec(
         state=(8.0e9, 8.01e9), total={1: (0, 12.7e9)},
-        # the Mamba-2 layers' passes in their kernels (`ops/gdn_passes.py`)
+        # the Mamba-2 layers' passes (`ops/gdn_passes.py`) and their scan
+        # (`ops/ssd.py`) in their kernels
         in_text=("conv_silu_fwd", "conv_silu_bwd", "gated_group_norm_fwd",
-                 "gated_group_norm_bwd"),
+                 "gated_group_norm_bwd", "ssd_fwd", "ssd_bwd"),
+        # 2.83 GB and a tenth (`scripts/program_copies.py nemotron_h`): the
+        # experts' operands and the embedding; 9.68 GB until PR 46, 6.8 of
+        # them x, y and d y laid out again between the passes' kernels and
+        # the scan's XLA ops, seven times a layer
+        copy_bytes=(0, 3.12e9),
         names_mesh=True,
     ),
     scope_widths=dict(

@@ -306,6 +306,71 @@ def test_gdn_passes_engine_choice_of_a_state_space_layer(
         assert jaxpr.count("shard_map") >= 4  # each pass mapped on its own
 
 
+_NO_SHAPES = "shapes the kernels do not take"
+
+
+@pytest.mark.parametrize(
+    "backend,devices,mesh,groups,chunk,head,dtype,engine,why", [
+        # the published shapes on one chip, the cells' case: Nemotron-H's
+        # 8 groups of 8 heads in chunks of 128, Granite's one group of 64
+        # heads in chunks of 256
+        ("tpu", 1, None, 8, 128, 64, "bfloat16", "pallas", "one device"),
+        ("tpu", 1, None, 1, 256, 64, "bfloat16", "pallas", "one device"),
+        ("tpu", 4, (1, 1), 8, 128, 64, "bfloat16", "pallas", "one device"),
+        ("tpu", 4, (2, 2), 1, 256, 64, "bfloat16", "pallas",
+         "under shard_map over {'data': 2, 'model': 2}"),
+        ("tpu", 4, None, 8, 128, 64, "bfloat16", "xla",
+         "4 devices and no mesh given"),
+        # a group of four heads is no whole block of heads; a head of 32
+        # is no half of a lane tile; a chunk of 64 no whole one
+        ("tpu", 1, None, 16, 128, 64, "bfloat16", "xla", _NO_SHAPES),
+        ("tpu", 1, None, 8, 128, 32, "bfloat16", "xla", _NO_SHAPES),
+        ("tpu", 1, None, 8, 64, 64, "bfloat16", "xla", _NO_SHAPES),
+        # a float32 model's products ask for HIGHEST, which is XLA's
+        ("tpu", 1, None, 8, 128, 64, "float32", "xla",
+         "float32 products at HIGHEST"),
+        ("cpu", 1, None, 8, 128, 64, "bfloat16", "xla", "backend cpu"),
+    ],
+)
+def test_ssd_engine_choice(backend, devices, mesh, groups, chunk, head, dtype,
+                           engine, why, monkeypatch):
+    """The state-space scan's kernel pair where the backend is a TPU,
+    `ssd.supports` holds, the products are bfloat16 and the trace is for
+    one device or names its mesh; the XLA form everywhere else.  The
+    worker's log line says which and why, and the trace holds `ssd_fwd`
+    and `ssd_bwd` exactly then (traced only: shapes, no device)."""
+    from elasticdl_tpu.ops import ssd
+
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    monkeypatch.setattr(jax, "device_count", lambda: devices)
+    mesh = mesh and _cpu_mesh(*mesh)
+    t, heads, n = 8192, 64, 128
+    shapes = [
+        jax.ShapeDtypeStruct(shape, jnp.float32) for shape in (
+            (2, t, heads * head), (2, t, heads), (heads,),
+            (2, t, 2 * groups * n),
+        )
+    ]
+    lines, handler = _log_lines(ssd.logger)
+    try:
+        jaxpr = str(jax.make_jaxpr(jax.grad(  # a new function: no cached trace
+            lambda *a: jnp.sum(ssd.ssd_chunked_rows(
+                *a, groups=groups, chunk=chunk, dtype=jnp.dtype(dtype),
+                mesh=mesh,
+            )[0]),
+            argnums=range(4),
+        ))(*shapes))
+    finally:
+        ssd.logger.removeHandler(handler)
+    assert lines == [
+        f"ssd engine: {engine} ssd_chunked T={t} H={heads} P={head} N={n} "
+        f"chunks of {chunk}, products in {dtype} ({why})"
+    ]
+    for name in ("ssd_fwd", "ssd_bwd"):
+        assert (name in jaxpr) == (engine == "pallas"), name
+    assert ("shard_map" in jaxpr) == why.startswith("under shard_map")
+
+
 @pytest.mark.parametrize("b,mesh", [(2, (2, 2)), (1, (2, 1))])
 def test_state_space_passes_under_a_mesh_are_the_kernels(b, mesh):
     """As the next case, for the pair a Mamba-2 layer calls: the taps,

@@ -194,6 +194,32 @@ def _delta_rule(backward, d=128):
     ]
 
 
+def _ssd(groups, chunk, backward):
+    """The state-space scan's kernels at the 1 x 8192 tokens and the 64
+    heads of 64 of both cells that run them: Nemotron-H's 8 groups in
+    chunks of 128, Granite's ONE group in chunks of 256."""
+    from elasticdl_tpu.ops import ssd
+
+    assert ssd.supports(8192, 64, 64, groups, 128, chunk)
+
+    def fwd(*args):
+        return ssd.ssd_chunked_pallas(
+            *args, groups=groups, chunk=chunk, interpret=False
+        )
+
+    def bwd(*args):
+        return jax.grad(
+            lambda *a: jnp.sum(fwd(*a)[0]), argnums=range(4)
+        )(*args)
+
+    return bwd if backward else fwd, [
+        (shape, jnp.float32) for shape in (
+            (1, 8192, 4096), (1, 8192, 64), (64,),
+            (1, 8192, 2 * groups * 128),
+        )
+    ]
+
+
 def _gdn_pass(which, backward):
     """The passes of `ops/gdn_passes.py` at the cells' rows.  Around the
     delta rule: q's conv + silu + l2-norm over 16 heads of 128, v's conv
@@ -295,6 +321,12 @@ _CASES = {
             functools.partial(_gdn_pass, which, backward)
         for which in ("q", "v", "norm", "conv_bias_4096", "conv_bias_2048",
                       "conv_bias_256", "group_norm_8", "group_norm_1")
+        for backward in (False, True)
+    },
+    **{
+        f"ssd_{'bwd' if backward else 'fwd'}_{groups}_groups_chunks_of_{chunk}":
+            functools.partial(_ssd, groups, chunk, backward)
+        for groups, chunk in ((8, 128), (1, 256))
         for backward in (False, True)
     },
     "delta_rule_fwd": functools.partial(_delta_rule, False),
