@@ -37,11 +37,12 @@ obs import free of the telemetry module (analysis tooling imports obs).
 
 The step-anatomy plane (obs/stepstats.py) decomposes each training
 step's wall time into exclusive compute-plane sub-phases (data_wait /
-stage / compile / execute / bookkeep) with host-side clocks, counts jit
-retraces per entrypoint, and turns measured rates into MFU + a roofline
-`bound:` verdict; its windowed summaries ride the telemetry heartbeat,
-journal as `step_anatomy` events, and upgrade straggler evidence with
-the dominant phase.  Imported lazily for the same reason as telemetry.
+stage / compile / execute / device_wait / bookkeep) with host-side
+clocks, counts jit retraces per entrypoint, and turns measured rates
+into MFU + a roofline `bound:` verdict; its windowed summaries ride the
+telemetry heartbeat, journal as `step_anatomy` events, and upgrade
+straggler evidence with the dominant phase.  Imported lazily for the
+same reason as telemetry.
 
 The goodput plane (obs/goodput.py) partitions job wall-clock into
 exclusive phases (training / rendezvous / checkpoint / redo / ...)

@@ -12,7 +12,7 @@ run and a production incident get identical forensics:
 Output: a human-readable timeline (one line per phase segment, rescale
 and churn markers inline), an attribution table (seconds and share of
 wall-clock per phase), a compute-phase attribution table (the step
-anatomy's data_wait/stage/compile/execute/bookkeep split from
+anatomy's data_wait/stage/compile/execute/device_wait/bookkeep split from
 `step_anatomy` events, with per-worker dominant phases, straggler
 bottleneck evidence, and `profile_window` pointers at the TensorBoard
 traces covering anomalous windows), a per-rescale cost breakdown

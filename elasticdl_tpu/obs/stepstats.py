@@ -13,7 +13,14 @@ registry, or lock call of this module ever executes under jit):
 - ``compile``    — dispatches during which a watched jitted entrypoint
   compiled (lowering/retrace; detected via the jit compile-cache size,
   polled per dispatch — never inside the traced region);
-- ``execute``    — device execution of an already-compiled program;
+- ``execute``    — the dispatch of an already-compiled program: how
+  long the call took to return, which under asynchronous dispatch is a
+  dispatch clock and not the device's time;
+- ``device_wait`` — the task's one wait for the device (the read of its
+  last loss, `step.device_wait`): where the host catches up with what it
+  dispatched.  It stands with ``execute`` on the device's side, and is
+  booked after the fact in the window the task sealed last
+  (``note_device_wait``), never in the next task's first;
 - ``bookkeep``   — bookkeeping host work between dispatches (version
   reports, telemetry folds, counters).  A checkpoint save and the
   profiler's stop run OUTSIDE it: the save has its own
@@ -70,7 +77,9 @@ from elasticdl_tpu.obs.tracing import annotate
 logger = get_logger("obs.stepstats")
 
 #: The exclusive sub-phases of a training step's wall time.
-PHASES = ("data_wait", "stage", "compile", "execute", "bookkeep")
+PHASES = (
+    "data_wait", "stage", "compile", "execute", "device_wait", "bookkeep",
+)
 
 #: The exclusive sub-phases of one SERVING request's wall time (the
 #: serving plane's twin of PHASES — serving/batcher.py stamps them,
@@ -444,6 +453,21 @@ class StepAnatomy:
             raise ValueError(f"unknown step phase {name!r} (not in {PHASES})")
         with self._lock:
             self._acc[name] += max(0.0, float(seconds))
+
+    def note_device_wait(self, seconds: float) -> None:
+        """Book the task's one wait for the device, measured where the
+        task's last window is already sealed: into that window and the
+        totals, so the seconds stay in the task that dispatched the work
+        (the accumulator would carry them into the next task's first
+        window)."""
+        seconds = max(0.0, float(seconds))
+        with self._lock:
+            self._totals["device_wait"] += seconds
+            if self._windows:
+                last = self._windows[-1]
+                last["device_wait"] = round(
+                    last.get("device_wait", 0.0) + seconds, 6
+                )
 
     def note_overlap_seconds(self, seconds: float) -> None:
         """Book host seconds that ran CONCURRENTLY with device execution

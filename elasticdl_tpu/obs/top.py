@@ -45,17 +45,20 @@ _HEADER_GAUGES = (
 _COLUMNS = (
     "WORKER", "AGE(s)", "P50(ms)", "P95(ms)", "EX/S",
     "TASK", "PROGRESS", "RDZV", "RETRY",
-    "DW%", "ST%", "CO%", "EX%", "BK%", "OV%", "BOUND", "STATE",
+    "DW%", "ST%", "CO%", "EX%", "DV%", "BK%", "OV%", "BOUND", "STATE",
 )
 
 #: Step-anatomy phase -> its percent column, in render order
 #: (obs/stepstats.PHASES; data_wait / stage / compile / execute /
-#: bookkeep — the per-worker phase-fraction columns).  OV% rides beside
-#: them: the async staging engine's overlap credit as a fraction of
-#: accounted-plus-overlapped host time (100% * overlap_s /
-#: (sum(totals) + overlap_s)) — how much host work the pipeline hid
-#: behind device execution.
-_PHASE_COLUMNS = ("data_wait", "stage", "compile", "execute", "bookkeep")
+#: device_wait / bookkeep — the per-worker phase-fraction columns; DV%
+#: is the task's wait for the device, beside which EX% is a dispatch
+#: clock).  OV% rides beside them: the async staging engine's overlap
+#: credit as a fraction of accounted-plus-overlapped host time (100% *
+#: overlap_s / (sum(totals) + overlap_s)) — how much host work the
+#: pipeline hid behind device execution.
+_PHASE_COLUMNS = (
+    "data_wait", "stage", "compile", "execute", "device_wait", "bookkeep",
+)
 
 #: Serving-plane header gauges (one replica's exporter; the table rows
 #: are fleet-wide via the shared journal).

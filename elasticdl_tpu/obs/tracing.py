@@ -86,7 +86,8 @@ logger = get_logger("obs.tracing")
 _TRACER_SEQ = itertools.count()
 
 #: Ordered step-anatomy phases a dispatch window decomposes into
-#: (mirrors stepstats.PHASES; imported lazily there to avoid a cycle).
+#: (stepstats.PHASES but `device_wait`, which is journaled as the real
+#: interval it is; imported lazily there to avoid a cycle).
 _WINDOW_PHASES = ("data_wait", "stage", "compile", "execute", "bookkeep")
 
 #: The training path's span names (master, worker, checkpoint, data)
@@ -102,7 +103,9 @@ SPAN_NAMES: Dict[str, str] = {
     "task.lifetime": "after: master, dispatch -> report (trace root)",
     "rpc.get_task": "after: master, dispatcher under the RPC handler",
     "rpc.report_task_result": "after: master, report handler",
-    "worker.get_task": "after: worker, client half of dispatch",
+    "worker.get_task": "after: worker, client half of dispatch (real "
+                       "tasks only); annotation: every call's RPC, a "
+                       "WAIT poll too",
     "worker.report_task": "interval: worker, result report RPC",
     "worker.task": "interval: worker, one task's execution",
     "worker.join_world": "interval: worker, the rank poll and, in a "
@@ -115,7 +118,13 @@ SPAN_NAMES: Dict[str, str] = {
     "step.execute": "aggregate: dispatch clock of compiled programs",
     "step.dispatch": "annotation only: each device dispatch call "
                      "(what step.compile/step.execute clock)",
-    "step.bookkeep": "aggregate; annotation: telemetry, version report",
+    "step.device_wait": "interval: the task's one wait for the device "
+                        "(the leader's read of its last loss, where the "
+                        "host catches up with what it dispatched; a child "
+                        "of the task's own worker.task: `task_id`, `steps`)",
+    "step.bookkeep": "aggregate; annotation: telemetry, version report "
+                     "(and, annotation only, a task's counters and "
+                     "reports behind its fence)",
     # host data plane (one set per task of a record-file reader)
     "data.index_load": "interval: first range_size after open (reads "
                        "the range's two index entries)",

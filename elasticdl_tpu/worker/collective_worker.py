@@ -550,6 +550,22 @@ class CollectiveWorker:
             if refused:
                 raise RuntimeError(refused)
 
+    def _await_loss(self, task, last_loss, steps: int) -> float:
+        """The task's one wait for the device: its programs were
+        dispatched without waiting, and the read of its last loss is
+        where the host catches up with them.  A `step.device_wait` span
+        (journal and annotation, a child of the task's own `worker.task`)
+        around that read and nothing else; the anatomy books the seconds
+        in this task, beside `execute` on the device's side."""
+        start = time.monotonic()
+        with tracing.span(
+            "step.device_wait", task_id=task.task_id, steps=steps
+        ):
+            loss = float(np.asarray(last_loss))
+        if self._anatomy is not None:
+            self._anatomy.note_device_wait(time.monotonic() - start)
+        return loss
+
     def _process_train_task(self, task) -> dict:
         task_start_ts = time.time()
         for ledger in self._ledgers:
@@ -757,25 +773,32 @@ class CollectiveWorker:
             if staging is not None:
                 staging.drain()
         if last_loss is not None and self._world.is_leader:
+            loss = self._await_loss(task, last_loss, batch_count)
             logger.info(
                 "task %d done: step=%d loss=%.5f (%d global batches)",
                 task.task_id,
                 self._trainer.step,
-                float(np.asarray(last_loss)),
+                loss,
                 batch_count,
             )
-        if last_loss is not None:
-            self._journal_counters(task_start_ts, batch_count)
-        self._report_version()
-        counters = {
-            TaskExecCounterKey.BATCH_COUNT: batch_count,
-            TaskExecCounterKey.RECORD_COUNT: record_count,
-        }
-        # Task boundary — the one place a device sync is already paid
-        # (the task-done log above materialized the last loss).
-        oov = self._trainer.consume_oov_count()
-        if oov:
-            counters[TaskExecCounterKey.OOV_LOOKUP_COUNT] = oov
+        # Behind the fence, the task's own bookkeeping (its counters'
+        # reads, the version report, the OOV count): on the profiler's
+        # host plane a `step.bookkeep` interval like a flush's, so that a
+        # device idle behind it is named; the journal's aggregates and
+        # the anatomy stay the flushes' alone.
+        with tracing.annotate("step.bookkeep"):
+            if last_loss is not None:
+                self._journal_counters(task_start_ts, batch_count)
+            self._report_version()
+            counters = {
+                TaskExecCounterKey.BATCH_COUNT: batch_count,
+                TaskExecCounterKey.RECORD_COUNT: record_count,
+            }
+            # Task boundary — the one place a device sync is already
+            # paid (`_await_loss` above materialized the last loss).
+            oov = self._trainer.consume_oov_count()
+            if oov:
+                counters[TaskExecCounterKey.OOV_LOOKUP_COUNT] = oov
         return counters
 
     # Leader-side eval outputs flush cadence: bounds the accumulated

@@ -109,19 +109,23 @@ class MasterClient:
         `rpc.get_task` span parents under it), and the span journals
         after the fact once the response reveals the trace id — WAIT
         polls and job-complete answers carry no trace and journal no
-        span (a poll loop must not flood the journal)."""
+        span (a poll loop must not flood the journal).  The RPC itself is
+        a `TraceAnnotation` of the same name, for every call."""
         from elasticdl_tpu.obs import tracing
 
         request = pb.GetTaskRequest(worker_id=self._worker_id, task_type=task_type)
         span_id = tracing.tracer().mint_span_id()
         start_ts = time.time()
         start = time.monotonic()
-        task = self._call(
-            "get_task",
-            request,
-            self._retry_policy,
-            metadata=trace_metadata("", span_id=span_id),
-        ).task
+        # On the profiler's host plane every call is named, a WAIT poll
+        # too: a device idle behind the queue is what a trace wants said.
+        with tracing.annotate("worker.get_task"):
+            task = self._call(
+                "get_task",
+                request,
+                self._retry_policy,
+                metadata=trace_metadata("", span_id=span_id),
+            ).task
         if task.trace_id:
             tracing.tracer().record_span(
                 "worker.get_task",

@@ -294,6 +294,113 @@ def test_bookkeep_holds_neither_the_save_nor_the_profilers_stop():
         assert isinstance(saves[0][key], int)
 
 
+# ---------------------------------------------------------------------------
+# The task's one wait for the device, and the queue wait (ISSUE 48)
+# ---------------------------------------------------------------------------
+
+#: The metric files that read the window's chain and the fence.
+WINDOW_CHAIN_METRICS = (
+    "window_named_share", "window_largest_gap_s", "device_wait_share",
+)
+
+
+def test_the_fence_is_one_span_a_task_under_the_tasks_own_span(annotations):
+    """`step.device_wait` wraps the leader's read of the task's last loss:
+    one span a task, journal and annotation, a child of the span that is
+    open around the task, with the task's id and steps; the anatomy books
+    its seconds in the window the task sealed last, not in the next."""
+    from elasticdl_tpu.proto import elasticdl_pb2 as pb
+
+    anatomy = StepAnatomy(0)
+    worker = _collective_worker(None, anatomy=anatomy)
+    marker = time.time()
+    with tracing.span("worker.task", task_id=7) as task_span:
+        worker._process_train_task(pb.Task(
+            task_id=7, type=pb.TRAINING, shard_name="s", start=0, end=16))
+    (fence,) = _spans_since(marker, "step.device_wait")
+    assert fence["parent_span_id"] == task_span.span_id
+    assert (fence["task_id"], fence["steps"]) == (7, 4)
+    entered = [name for name, _ in annotations]
+    assert entered.count("step.device_wait") == 1
+    # Behind the fence, the task's counters and reports are bookkeeping
+    # on the profiler's plane; the journal's aggregates are the flush's.
+    assert entered[-2:] == ["step.device_wait", "step.bookkeep"]
+    assert len(_spans_since(marker, "step.bookkeep")) <= 1
+    # Nothing is left in the accumulator for the next task's first window.
+    assert anatomy.totals()["device_wait"] == pytest.approx(
+        fence["duration_s"], abs=5e-3)
+    assert "device_wait" in anatomy.snapshot()["windows"][-1]
+    assert "device_wait" not in (anatomy.close_window() or {})
+    # The aggregates the flush journals are the five they were.
+    names = {e["name"] for e in _spans_since(marker)}
+    assert "step.device_wait" not in {
+        f"step.{phase}" for phase in tracing._WINDOW_PHASES}
+    assert names >= {"step.data_wait", "step.stage", "step.device_wait"}
+
+
+def test_a_rank_that_reads_no_loss_has_no_fence():
+    from elasticdl_tpu.proto import elasticdl_pb2 as pb
+
+    worker = _collective_worker(None)
+    worker._world = worker._world.__class__(
+        rank=1, world_size=1, rendezvous_id=1, coordinator_addr="")
+    assert not worker._world.is_leader
+    marker = time.time()
+    worker._process_train_task(pb.Task(
+        task_id=8, type=pb.TRAINING, shard_name="s", start=0, end=8))
+    assert not _spans_since(marker, "step.device_wait")
+
+
+@pytest.mark.parametrize("trace_id,journaled", [("t-1", 1), ("", 0)],
+                         ids=["a_task", "a_wait_poll"])
+def test_every_queue_rpc_is_an_annotation_real_tasks_a_span(
+        annotations, trace_id, journaled):
+    """`worker.get_task` journals after the fact and for real tasks only;
+    its RPC is an annotation for every call, a WAIT poll too."""
+    from elasticdl_tpu.proto import elasticdl_pb2 as pb
+    from elasticdl_tpu.worker.master_client import MasterClient
+
+    client = MasterClient.__new__(MasterClient)
+    client._worker_id, client._retry_policy = 0, None
+    client._call = lambda *a, **k: pb.GetTaskResponse(task=pb.Task(
+        task_id=3 if trace_id else -1,
+        type=pb.TRAINING if trace_id else pb.WAIT, trace_id=trace_id))
+    marker = time.time()
+    client.get_task()
+    assert [name for name, _ in annotations] == ["worker.get_task"]
+    assert len(_spans_since(marker, "worker.get_task")) == journaled
+
+
+def test_the_fence_and_the_windows_chain_name_their_readers():
+    """SPAN_NAMES, `docs/observability.md` and PERF.md's span table name
+    `step.device_wait`, the queue wait's annotation and the three metric
+    pairs over them, and every metric has its file."""
+    assert tracing.SPAN_NAMES["step.device_wait"].startswith(
+        "interval: the task's one wait for the device")
+    assert "annotation" in tracing.SPAN_NAMES["worker.get_task"]
+    with open(os.path.join(REPO_ROOT, "docs", "observability.md")) as f:
+        docs = f.read()
+    with open(os.path.join(REPO_ROOT, "PERF.md")) as f:
+        perf = f.read()
+    span_table = perf[perf.index("| span / family |"):perf.index("## 4. Cells")]
+    assert "`step.device_wait`" in docs and "window_chain.py" in docs
+    assert "| `step.device_wait`" in span_table
+    assert "readers/window_chain.py" in perf
+    with open(os.path.join(REPO_ROOT, "BENCHMARK.json")) as f:
+        declared = {m["name"]: m for m in json.load(f)["per_layer"]}
+    for name in WINDOW_CHAIN_METRICS:
+        for metric in (name, name + ".lm"):
+            assert f"`{name}`" in span_table, name
+            assert declared[metric]["source"] == "program_span"
+            with open(os.path.join(
+                    REPO_ROOT, "perfbench", "metrics", metric + ".json")) as f:
+                reader = json.load(f)["reader"]
+            assert reader == ("span_share" if name == "device_wait_share"
+                              else "window_chain")
+            assert os.path.exists(os.path.join(
+                REPO_ROOT, "perfbench", "readers", reader + ".py"))
+
+
 def test_profiler_stop_waits_and_journals_its_own_duration(tmp_path):
     import jax.numpy as jnp
 
@@ -622,7 +729,8 @@ def test_worker_boot_has_its_five_children_in_order(tiny_job):
 
 def test_a_task_after_warm_up_journals_the_spans_it_did(tiny_job):
     """The start-up chain adds nothing inside a task: after the first,
-    every task journals the seven spans it journaled before ISSUE 34."""
+    every task journals the seven spans it journaled before ISSUE 34,
+    and since ISSUE 48 its one wait for the device."""
     _, worker, _ = tiny_job
     by_task = {}
     for e in worker:
@@ -633,8 +741,8 @@ def test_a_task_after_warm_up_journals_the_spans_it_did(tiny_job):
     for names in steady:
         assert sorted(names) == sorted([
             "worker.get_task", "step.data_wait", "step.stage",
-            "step.execute", "step.bookkeep", "worker.task",
-            "worker.report_task",
+            "step.execute", "step.device_wait", "step.bookkeep",
+            "worker.task", "worker.report_task",
         ])
 
 

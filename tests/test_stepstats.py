@@ -434,6 +434,46 @@ def test_note_phase_seconds_books_after_the_fact():
         anatomy.note_phase_seconds("idle", 1.0)
 
 
+def test_device_wait_is_booked_in_the_window_the_task_sealed_last():
+    """The task's one wait for the device is measured after its last
+    window was sealed: it joins THAT window and the totals, and nothing
+    of it is carried into the next task's first window."""
+    anatomy = _fed_anatomy(execute=0.1, examples=64, windows=2)
+    anatomy.note_device_wait(3.0)
+    anatomy.note_device_wait(-1.0)  # clamped, not subtracted
+    first, last = anatomy.snapshot()["windows"]
+    assert "device_wait" not in first
+    assert last["device_wait"] == pytest.approx(3.0)
+    assert anatomy.totals()["device_wait"] == pytest.approx(3.0)
+    assert anatomy.close_window() is None  # nothing left to carry
+    # The wire keeps it, and the fleet view folds it as a phase.
+    clean = stepstats.sanitize_anatomy(anatomy.snapshot())
+    assert clean["windows"][-1]["device_wait"] == pytest.approx(3.0)
+    assert clean["totals"]["device_wait"] == pytest.approx(3.0)
+    # A wait with no sealed window (nothing dispatched) still counts.
+    empty = StepAnatomy(worker_id=0)
+    empty.note_device_wait(1.0)
+    assert empty.totals() == {"device_wait": 1.0}
+
+
+def test_device_wait_stands_on_the_devices_side():
+    """Not a host phase: a job whose wall time is the wait for the device
+    is not host-bound, and the host fraction is what the host phases took
+    of a total that now holds the wait."""
+    assert "device_wait" in PHASES
+    assert "device_wait" not in stepstats.HOST_PHASES
+    anatomy = _fed_anatomy(data_wait=0.3, execute=0.1, examples=64)
+    host_alone = stepstats.phase_fractions(anatomy.totals())
+    assert host_alone["data_wait"] == pytest.approx(0.75)
+    assert stepstats.roofline(1.0, host_alone, None, None)["bound"] == "host"
+    anatomy.note_device_wait(3.6)
+    fractions = stepstats.phase_fractions(anatomy.totals())
+    assert fractions["device_wait"] == pytest.approx(0.9)
+    assert fractions["data_wait"] == pytest.approx(0.075)
+    assert "bound" not in stepstats.roofline(1.0, fractions, None, None)
+    assert sum(fractions.values()) == pytest.approx(1.0)
+
+
 def test_journal_anatomy_helper(obs_registry_snapshot):
     marker = time.time()
     record = stepstats.journal_anatomy(
