@@ -34,8 +34,14 @@ device, starts the device-to-host transfers of the leaves up to
 `_LOOKAHEAD_BYTES` ahead of the one the file is taking (`LeafStream`),
 and lets go of each leaf's host copy once it is written, so the write
 of leaf i runs beside the transfers of leaves i+1 ... i+k and the host
-never holds the whole state.  It is still synchronous: `save` returns
-after the rename.  A host tree (a trainer's collective
+never holds the whole state.  A leaf of more than 16 MiB is cut on the
+device, by programs its trainer built with its step programs
+(`LeafCutter`), and crosses piece by piece: host copies of that size
+come from memory the allocator keeps mapped and hands out again, which
+these machines take into a file more than twice as fast as pages just
+faulted in (`PERF.md` §6 PR 50), and no leaf is on the host whole.  The
+file's bytes are the same either way.  It is still synchronous: `save`
+returns after the rename.  A host tree (a trainer's collective
 `state_to_host()`, export, debug) goes through the same writer with
 nothing to wait for.
 
@@ -51,6 +57,7 @@ silently loading garbage.  `keep_max` old checkpoints are retained.
 
 from __future__ import annotations
 
+import bisect
 import collections
 import contextlib
 import io
@@ -64,7 +71,9 @@ import tempfile
 import time
 import zlib
 from concurrent.futures import ThreadPoolExecutor
-from typing import Any, Dict, Iterable, Iterator, List, Optional, Tuple
+from typing import (
+    Any, Dict, Iterable, Iterator, List, NamedTuple, Optional, Tuple,
+)
 
 import numpy as np
 
@@ -231,16 +240,45 @@ _PIECE_BYTES = 16 << 20
 _BESIDE_BYTES = 1 << 20
 
 
+class _Ranges:
+    """Address ranges, merged: `add` takes one more and says how many of
+    its bytes lay in those it already had."""
+
+    def __init__(self):
+        self._lo: List[int] = []
+        self._hi: List[int] = []
+
+    def add(self, lo: int, hi: int) -> int:
+        first = bisect.bisect_left(self._hi, lo)
+        last = bisect.bisect_right(self._lo, hi)
+        had = sum(
+            max(0, min(hi, self._hi[n]) - max(lo, self._lo[n]))
+            for n in range(first, last)
+        )
+        if first < last:
+            lo, hi = min(lo, self._lo[first]), max(hi, self._hi[last - 1])
+        self._lo[first:last], self._hi[first:last] = [lo], [hi]
+        return had
+
+
 class ChecksumWriter:
     """The write-only file both savers write through: `crc32` and `size`
     are those of the bytes it was handed, taken as they pass, so the
     integrity manifest never reads a file this process has just
     written.  `write` takes any contiguous buffer (an array's own
-    memory, a `PickleBuffer`, bytes) and copies none of it."""
+    memory, a `PickleBuffer`, bytes) and copies none of it.
+    `recycled_bytes` counts the bytes of pieces of `_BESIDE_BYTES` and
+    more that came from an address range this file had already taken
+    bytes from: where the allocator hands memory out again while it is
+    still mapped, the file takes it more than twice as fast as pages
+    that were just faulted in (`PERF.md` §6 PR 50; a range unmapped and
+    mapped anew at the same address counts too)."""
 
     def __init__(self, path: str):
         self.crc32 = 0
         self.size = 0
+        self.recycled_bytes = 0
+        self._taken = _Ranges()
         self._file = open(path, "wb")
         self._helper = ThreadPoolExecutor(
             max_workers=1, thread_name_prefix="ckpt-crc"
@@ -271,6 +309,8 @@ class ChecksumWriter:
                 self._file.write(piece)
             finally:
                 folding.result()
+            at = np.frombuffer(piece, np.uint8).ctypes.data
+            self.recycled_bytes += self._taken.add(at, at + piece.nbytes)
         self.size += view.nbytes
         return view.nbytes
 
@@ -325,18 +365,167 @@ def streams(tree) -> bool:
     )
 
 
+#: What the pieces cut from large leaves and not yet on the host may
+#: hold of the DEVICE's memory: a piece is a buffer there until it has
+#: crossed.  Half a second of the file's appetite, which a link five
+#: times as fast refills with room to spare.
+_PIECES_AHEAD_BYTES = 128 << 20
+
+
+class _Cut(NamedTuple):
+    """How a leaf larger than a piece is cut: in the order the device
+    keeps its axes (the order its bytes have in the file), into ranges
+    of `rows` indices of the first axis one index of which fits a
+    piece, one index at a time of every axis before it."""
+
+    axes: Optional[tuple]  # the device's order of the axes; None: their own
+    shape: tuple  # the leaf's shape in that order
+    axis: int
+    rows: int
+
+    @classmethod
+    def of(cls, shape, dtype, axes, piece_bytes: int) -> "_Cut":
+        stored = tuple(shape) if axes is None else tuple(
+            shape[axis] for axis in axes
+        )
+        inner = np.dtype(dtype).itemsize * int(np.prod(stored, dtype=np.int64))
+        for axis, length in enumerate(stored):
+            inner //= length  # the bytes of one index of this axis
+            if inner <= piece_bytes:
+                break
+        rows = min(length, max(1, piece_bytes // inner))
+        return cls(axes, stored, axis, rows)
+
+    @property
+    def sizes(self) -> tuple:
+        """A piece's shape."""
+        return (
+            (1,) * self.axis + (self.rows,) + self.shape[self.axis + 1:]
+        )
+
+    def pieces(self) -> Iterator[Tuple[np.ndarray, int]]:
+        """-> (where a piece starts, the rows at its head that the piece
+        before it already held), in the file's order.  Every piece has
+        `rows` rows, so one program cuts them all: where the lengths do
+        not divide, the last starts early and its head is skipped."""
+        length = self.shape[self.axis]
+        starts = np.zeros(len(self.shape), np.int32)
+        for outer in np.ndindex(*self.shape[:self.axis]):
+            starts[:self.axis] = outer
+            for row in range(0, length, self.rows):
+                starts[self.axis] = min(row, length - self.rows)
+                yield starts.copy(), row - int(starts[self.axis])
+
+
+def _on_one_device(leaf):
+    """A leaf that every device holds whole, as the first one's array."""
+    if len(leaf.sharding.device_set) > 1:
+        return leaf.addressable_shards[0].data
+    return leaf
+
+
+class LeafCutter:
+    """The device programs that cut a leaf of more than `piece_bytes`
+    into pieces of at most that, so that a `LeafStream` brings it to
+    the host piece by piece: a piece's host copy is small enough for
+    the allocator to hand the same memory out again for the next, and
+    no leaf is ever on the host whole.  One program a (shape, dtype,
+    order of axes), the piece's place its argument.  `warm` builds
+    them (through `build`, the compile layer's door, where a trainer
+    gives one: then the compile cache and the executable store keep
+    them); a leaf that no program was warmed for crosses whole, so a
+    save compiles nothing."""
+
+    def __init__(self, build=None, piece_bytes: int = _PIECE_BYTES):
+        self.piece_bytes = piece_bytes
+        self._build = build
+        self._programs: Dict[tuple, Tuple[_Cut, Any]] = {}
+
+    def _program(self, cut: _Cut):
+        import jax
+
+        def ckpt_piece(leaf, starts):
+            stored = leaf if cut.axes is None else leaf.transpose(cut.axes)
+            return jax.lax.dynamic_slice(
+                stored, tuple(starts[n] for n in range(stored.ndim)),
+                cut.sizes,
+            )
+
+        if self._build is None:
+            return jax.jit(ckpt_piece)
+        order = "".join(map(str, cut.axes or ()))
+        return self._build(
+            ckpt_piece, f"ckpt_piece.{cut.axis}x{cut.rows}.{order or 'rows'}"
+        )
+
+    def warm(self, leaves: Iterable) -> int:
+        """Build the program of every leaf of `leaves` that is to be
+        cut (one that is whole on this process's devices and larger
+        than a piece), each by one call.  -> how many were built."""
+        import jax
+
+        built = 0
+        for leaf in leaves:
+            if not (
+                _on_device(leaf) and leaf.nbytes > self.piece_bytes
+                and leaf.is_fully_addressable and leaf.is_fully_replicated
+            ):
+                continue
+            axes = _device_axes(leaf)
+            key = (leaf.shape, leaf.dtype, axes)
+            if key in self._programs:
+                continue
+            cut = _Cut.of(leaf.shape, leaf.dtype, axes, self.piece_bytes)
+            program = self._program(cut)
+            jax.block_until_ready(program(
+                _on_one_device(leaf), np.zeros(leaf.ndim, np.int32)
+            ))
+            self._programs[key] = (cut, program)
+            built += 1
+        return built
+
+    def cut_of(self, leaf) -> Optional[Tuple[_Cut, Any]]:
+        """(how `leaf` is cut, the program that cuts it), or None for a
+        leaf that crosses whole."""
+        if leaf.nbytes <= self.piece_bytes or not self._programs:
+            return None
+        return self._programs.get(
+            (leaf.shape, leaf.dtype, _device_axes(leaf))
+        )
+
+
+class LeafPieces:
+    """A cut leaf on its way to the host: the leaf's `shape`, `dtype`
+    and `nbytes`, and, walked once, the `np.uint8` runs of its pieces,
+    which back to back are the bytes `_DeviceLeaf.bytes_of` gives for
+    the leaf whole."""
+
+    def __init__(self, leaf, runs: Iterator):
+        self.shape, self.dtype = leaf.shape, leaf.dtype
+        self.nbytes = leaf.nbytes
+        self._runs = runs
+
+    def __iter__(self) -> Iterator[np.ndarray]:
+        return self._runs
+
+
 class LeafStream:
     """Device arrays to the host in the order a file holds them: each
-    leaf's transfer is started up to `_LOOKAHEAD_BYTES` before the
-    writer asks for it, so the file takes leaf i while leaves i+1 ...
-    i+k cross the link, and a leaf's host copy goes once the writer
-    asks for the next.  Counts what the save's spans report."""
+    transfer is started up to `_LOOKAHEAD_BYTES` before the writer asks
+    for it, so the file takes leaf i while leaves i+1 ... i+k cross the
+    link, and a host copy goes once the writer asks for the next.  A
+    leaf that `cutter` has a program for crosses piece by piece
+    (`LeafCutter`).  Counts what the save's spans report."""
 
-    def __init__(self):
+    def __init__(self, cutter: Optional[LeafCutter] = None):
         self._started_ts, self._started = time.time(), time.monotonic()
+        self._cutter = cutter
+        self._device_idle = False  # the program in flight has been waited for
         self.wait_s = 0.0  # the caller, blocked on the device
         self.leaves = 0  # device leaves handed over
         self.bytes = 0
+        self.pieces = 0  # transfers: one a whole leaf, one a piece
+        self.copied_bytes = 0  # pieces that came in an order of their own
         self.streamed_bytes = 0  # ... whose transfer was started ahead
         self.lookahead_peak_bytes = 0  # most held on the host at once
 
@@ -356,55 +545,128 @@ class LeafStream:
         leaf.copy_to_host_async()
         return leaf
 
+    def _start_piece(self, leaf, program, starts):
+        """Cut the piece of `leaf` at `starts` and start its transfer.
+        A piece is taken from the device's memory when it is
+        dispatched, so none is before the program in flight is over
+        (the train programs leave the chip little room)."""
+        if not self._device_idle:
+            waited = time.monotonic()
+            leaf.block_until_ready()
+            self.wait_s += time.monotonic() - waited
+            self._device_idle = True
+        piece = program(_on_one_device(leaf), starts)
+        piece.copy_to_host_async()
+        return piece
+
     @staticmethod
     def _fetch(handle) -> np.ndarray:
         return np.asarray(handle)
 
-    def host_arrays(self, leaves: Iterable) -> Iterator:
-        """Yield each of `leaves` as the host has it (a device array as
-        the `np.ndarray` its transfer made, anything else as it is).
-        A caller that keeps no reference to an array past its turn
-        holds what `lookahead_peak_bytes` says and no more."""
-        leaves = list(leaves)
-        on_device = [_on_device(leaf) for leaf in leaves]
-        ahead: collections.deque = collections.deque()  # handles, in order
-        ahead_bytes = held = 0
-        upcoming = 0
+    def host_arrays(self, leaves: Iterable, whole: Iterable[int] = ()):
+        """Yield each of `leaves` as the host has it: a device array as
+        the `np.ndarray` its transfer made, or, where it is cut (never
+        one whose place in `leaves` is in `whole`), as `LeafPieces`,
+        to be walked to their end before the next leaf is asked for;
+        anything else as it is.  A caller that keeps no reference to
+        an array past its turn holds what `lookahead_peak_bytes` says
+        and no more."""
+        leaves, whole = list(leaves), set(whole)
+        # The transfers to make, in the file's order: (leaf's place,
+        # bytes, bytes of them the file takes, None or the piece's
+        # (program, start)); and of each cut leaf (how it is cut, the
+        # rows to skip at the head of each of its pieces).
+        units: List[tuple] = []
+        cuts: Dict[int, Tuple[_Cut, List[int]]] = {}
+        for at, leaf in enumerate(leaves):
+            if not _on_device(leaf):
+                continue
+            found = None
+            if self._cutter is not None and at not in whole:
+                found = self._cutter.cut_of(leaf)
+            if found is None:
+                units.append((at, leaf.nbytes, leaf.nbytes, None))
+                continue
+            cut, program = found
+            row = leaf.dtype.itemsize * int(np.prod(cut.sizes)) // cut.rows
+            cuts[at] = (cut, [])
+            for starts, skip in cut.pieces():
+                cuts[at][1].append(skip)
+                units.append((
+                    at, row * cut.rows, row * (cut.rows - skip),
+                    (program, starts),
+                ))
+        ahead: collections.deque = collections.deque()  # started, in order
+        started = ahead_bytes = ahead_device = held = in_hand = 0
+
+        def top_up():
+            """Start transfers as far ahead as the bounds allow; one is
+            always ahead, whatever its size."""
+            nonlocal started, ahead_bytes, ahead_device, held
+            while started < len(units):
+                at, nbytes, kept, piece = units[started]
+                device = nbytes if piece else 0
+                if ahead and (
+                    ahead_bytes + nbytes > _LOOKAHEAD_BYTES
+                    or ahead_device + device > _PIECES_AHEAD_BYTES
+                ):
+                    break
+                ahead.append((
+                    self._start(leaves[at]) if piece is None
+                    else self._start_piece(leaves[at], *piece),
+                    nbytes, kept, device,
+                ))
+                ahead_bytes += nbytes
+                ahead_device += device
+                held += nbytes
+                started += 1
+
+        def take() -> np.ndarray:
+            """-> the next transfer's host copy; what the one before it
+            left on the host is let go of."""
+            nonlocal ahead_bytes, ahead_device, held, in_hand
+            held -= in_hand
+            if ahead:
+                self.streamed_bytes += ahead[0][2]
+            else:
+                top_up()
+            handle, in_hand, _kept, device = ahead.popleft()
+            ahead_bytes -= in_hand
+            ahead_device -= device
+            top_up()
+            self.lookahead_peak_bytes = max(self.lookahead_peak_bytes, held)
+            waited = time.monotonic()
+            host = self._fetch(handle)
+            self.wait_s += time.monotonic() - waited
+            self.pieces += 1
+            return host
+
+        def runs(cut: _Cut, skips: List[int]) -> Iterator[np.ndarray]:
+            for skip in skips:
+                host = take()
+                if not host.flags.c_contiguous:  # (a layout of its own)
+                    host = np.ascontiguousarray(host)
+                    self.copied_bytes += host.nbytes
+                yield host.reshape(cut.rows, -1)[skip:].reshape(-1).view(
+                    np.uint8
+                )
+
         try:
             for at, leaf in enumerate(leaves):
-                if not on_device[at]:
+                if not _on_device(leaf):
                     yield leaf
                     continue
-                if ahead:
-                    handle = ahead.popleft()
-                    ahead_bytes -= leaf.nbytes
-                    self.streamed_bytes += leaf.nbytes
-                else:
-                    handle = self._start(leaf)
-                    held += leaf.nbytes
-                upcoming = max(upcoming, at + 1)
-                while upcoming < len(leaves):
-                    following = leaves[upcoming]
-                    if on_device[upcoming]:
-                        if ahead and (
-                            ahead_bytes + following.nbytes > _LOOKAHEAD_BYTES
-                        ):
-                            break
-                        ahead.append(self._start(following))
-                        ahead_bytes += following.nbytes
-                        held += following.nbytes
-                    upcoming += 1
-                self.lookahead_peak_bytes = max(
-                    self.lookahead_peak_bytes, held
-                )
-                waited = time.monotonic()
-                host = self._fetch(handle)
-                self.wait_s += time.monotonic() - waited
                 self.leaves += 1
                 self.bytes += leaf.nbytes
-                yield host
-                host = handle = None
-                held -= leaf.nbytes
+                if at in cuts:
+                    pieces = runs(*cuts[at])
+                    yield LeafPieces(leaf, pieces)
+                    if next(pieces, None) is not None:
+                        raise RuntimeError(
+                            "a cut leaf's pieces were not taken to their end"
+                        )
+                else:
+                    yield take()
         finally:
             ahead.clear()
 
@@ -426,7 +688,7 @@ class LeafStream:
             "checkpoint.save.write", self._started_ts + self.wait_s,
             elapsed_s - self.wait_s, streamed_bytes=self.streamed_bytes,
             lookahead_peak_bytes=self.lookahead_peak_bytes,
-            leaves=self.leaves, **write_fields,
+            leaves=self.leaves, pieces=self.pieces, **write_fields,
         )
 
 
@@ -655,17 +917,23 @@ def write_state(
     ))
     writer.write(skeleton.getbuffer())
     copied = pickler.copied_bytes
-    arrays = (stream or LeafStream()).host_arrays(
+    stream = stream or LeafStream()
+    arrays = stream.host_arrays(
         getattr(buffer, "array", buffer) for buffer in buffers
     )
     for buffer in buffers:
         host = next(arrays)
+        if isinstance(host, LeafPieces):
+            for run in host:
+                writer.write(run)
+            run = None
+            continue
         if isinstance(buffer, _DeviceLeaf):
             host, extra = buffer.bytes_of(host)
             copied += extra
         writer.write(host)
         host = None  # let go of before the stream brings the next
-    return copied
+    return copied + stream.copied_bytes
 
 
 def _read_exact(f, into) -> None:
@@ -780,11 +1048,14 @@ class CheckpointSaver:
 
     # ------------------------------------------------------------------
 
-    def save(self, state: Any, step: int) -> str:
+    def save(
+        self, state: Any, step: int, cutter: Optional[LeafCutter] = None
+    ) -> str:
         """Snapshot a pytree at `step`, atomically, with a CRC32
         integrity manifest covering the state file.  Leaves that are
         still on the device (`streams`) reach the file through a
-        `LeafStream`: the host never holds the whole of them."""
+        `LeafStream`: the host never holds the whole of them, and of a
+        leaf that `cutter` cuts never more than a few pieces."""
         start = time.monotonic()
         final_dir = self._step_dir(step)
         if os.path.exists(final_dir):
@@ -793,10 +1064,13 @@ class CheckpointSaver:
             prefix=f"step_{step:012d}.tmp", dir=self._dir
         )
         state_path = os.path.join(tmp_dir, _STATE_FILE)
-        stream = LeafStream()
+        stream = LeafStream(cutter)
         with ChecksumWriter(state_path) as writer:
             copied = write_state(writer, state, stream)
-        stream.journal(copied_bytes=copied, bytes=writer.size)
+        stream.journal(
+            copied_bytes=copied, bytes=writer.size,
+            recycled_bytes=writer.recycled_bytes,
+        )
         with tracing.span(
             "checkpoint.save.crc", bytes=writer.size
         ) as span:

@@ -62,9 +62,12 @@ from elasticdl_tpu import obs
 from elasticdl_tpu.checkpoint.saver import (
     CheckpointSaver,
     ChecksumWriter,
+    LeafCutter,
+    LeafPieces,
     LeafStream,
     _apply_write_fault,
     _ckpt_metrics,
+    _device_axes,
     tree_nbytes,
     verify_integrity,
     write_integrity_manifest,
@@ -112,7 +115,8 @@ def write_npz(writer: ChecksumWriter, entries) -> int:
     for key, array in pairs:
         if array.dtype.hasobject:
             raise ValueError(f"{key}: object arrays are not checkpointed")
-        if not (array.flags.c_contiguous or array.flags.f_contiguous):
+        cut = isinstance(array, LeafPieces)  # (rows in their own order)
+        if not (cut or array.flags.c_contiguous or array.flags.f_contiguous):
             array = np.ascontiguousarray(array)
             copied += array.nbytes
         name = (key + ".npy").encode("utf-8")
@@ -120,9 +124,10 @@ def write_npz(writer: ChecksumWriter, entries) -> int:
             0 if name.isascii() else _ZIP_UTF8_FLAG
         )
         npy_header = io.BytesIO()
-        np.lib.format.write_array_header_1_0(
-            npy_header, np.lib.format.header_data_from_array_1_0(array)
-        )
+        np.lib.format.write_array_header_1_0(npy_header, {
+            "shape": array.shape, "fortran_order": False,
+            "descr": np.lib.format.dtype_to_descr(array.dtype),
+        } if cut else np.lib.format.header_data_from_array_1_0(array))
         offset = writer.size
         writer.write(_ZIP_LOCAL.pack(
             b"PK\x03\x04", _ZIP_VERSION, flags, 0, 0, _ZIP_DOS_DATE,
@@ -132,8 +137,12 @@ def write_npz(writer: ChecksumWriter, entries) -> int:
         writer.write(npy_header.getbuffer())
         # (the header says `fortran_order` for a Fortran-ordered array,
         # whose bytes are its transpose's)
-        stored = array if array.flags.c_contiguous else array.T
-        writer.write(stored.reshape(-1).view(np.uint8))
+        if cut:
+            for stored in array:
+                writer.write(stored)
+        else:
+            stored = array if array.flags.c_contiguous else array.T
+            writer.write(stored.reshape(-1).view(np.uint8))
         size = npy_header.tell() + array.nbytes
         crc = writer.end_member()
         writer.write(_ZIP_DESCRIPTOR.pack(b"PK\x07\x08", crc, size, size))
@@ -185,6 +194,30 @@ def _interval(shard, dim0: int) -> Tuple[int, int]:
     lo = index.start if index.start is not None else 0
     hi = index.stop if index.stop is not None else dim0
     return int(lo), int(hi)
+
+
+def own_shards(
+    sharded: Dict[str, jax.Array]
+) -> Tuple[List[str], List[jax.Array]]:
+    """-> (the members' names, the rows still on the device) of what
+    this process writes of `sharded`, in the order its file holds them:
+    each interval of rows once, and rows that every process holds on
+    rank 0 alone."""
+    process = jax.process_index()
+    keys, on_device = [], []
+    for name, array in sharded.items():
+        dim0 = array.shape[0]
+        seen: set = set()
+        for shard in array.addressable_shards:
+            lo, hi = _interval(shard, dim0)
+            if (lo, hi) in seen:
+                continue  # replicas of these rows on other devices
+            seen.add((lo, hi))
+            if (lo, hi) == (0, dim0) and process != 0:
+                continue  # fully replicated: rank 0 writes it
+            keys.append(f"{name}|{lo}|{hi}")
+            on_device.append(shard.data)
+    return keys, on_device
 
 
 class ShardedCheckpointSaver(CheckpointSaver):
@@ -242,11 +275,13 @@ class ShardedCheckpointSaver(CheckpointSaver):
         step: int,
         dense_state: Any,
         sharded: Dict[str, jax.Array],
+        cutter: Optional[LeafCutter] = None,
     ) -> str:
         """Every process calls this with the same arguments; each writes
         only its own addressable rows of each `sharded` array.  Replicated
         arrays (tables too small to split) are written by rank 0 alone.
-        `dense_state` may be None on ranks != 0 (only rank 0 writes it)."""
+        `dense_state` may be None on ranks != 0 (only rank 0 writes it).
+        Shards that `cutter` cuts cross and are written piece by piece."""
         start = time.monotonic()
         process = jax.process_index()
         n_processes = jax.process_count()
@@ -259,19 +294,7 @@ class ShardedCheckpointSaver(CheckpointSaver):
         # This rank's rows, then (rank 0) the dense leaves, in the order
         # the files hold them and still on the device: `LeafStream`
         # brings each over while the file takes the one before it.
-        keys, on_device = [], []
-        for name, array in sharded.items():
-            dim0 = array.shape[0]
-            seen: set = set()
-            for shard in array.addressable_shards:
-                lo, hi = _interval(shard, dim0)
-                if (lo, hi) in seen:
-                    continue  # replicas of these rows on other devices
-                seen.add((lo, hi))
-                if (lo, hi) == (0, dim0) and process != 0:
-                    continue  # fully replicated: rank 0 writes it
-                keys.append(f"{name}|{lo}|{hi}")
-                on_device.append(shard.data)
+        keys, on_device = own_shards(sharded)
         dense_leaves, dense_tree = jax.tree.flatten(
             dense_state if process == 0 else None
         )
@@ -280,14 +303,22 @@ class ShardedCheckpointSaver(CheckpointSaver):
         ]
         # {file: (crc32, size)} as this rank's writers took them.
         known: Dict[str, Tuple[int, int]] = {}
-        stream = LeafStream()
-        arrays = stream.host_arrays(on_device + dense_leaves)
+        stream = LeafStream(cutter)
+        # Whole: the dense leaves (their pickle needs them so), and a
+        # shard the device keeps in another order than its rows (a
+        # `.npy` member says C or Fortran order, no third).
+        leaves = on_device + dense_leaves
+        arrays = stream.host_arrays(leaves, whole=[
+            at for at, leaf in enumerate(leaves)
+            if at >= len(on_device) or _device_axes(leaf) is not None
+        ])
         mine = shard_files[process]
         with ChecksumWriter(os.path.join(tmp_dir, mine)) as writer:
             copied = write_npz(
                 writer, ((key, next(arrays)) for key in keys)
             )
         known[mine] = (writer.crc32, writer.size)
+        recycled = writer.recycled_bytes
         # Keep the shared tmp dir's mtime fresh while the save is
         # live so a restarting peer's stale-tmp sweep
         # (saver.sweep_stale_tmp) never mistakes an in-flight save
@@ -302,14 +333,16 @@ class ShardedCheckpointSaver(CheckpointSaver):
                 pickle.dump(dense_state, writer)
             copied += tree_nbytes(dense_state)
             known[_DENSE] = (writer.crc32, writer.size)
+            recycled += writer.recycled_bytes
             os.utime(tmp_dir)
         else:
             sidecar = os.path.join(tmp_dir, mine + _SIDECAR_SUFFIX)
             with open(sidecar, "w") as f:
                 json.dump({"crc32": writer.crc32, "size": writer.size}, f)
         stream.journal(
-            copied_bytes=copied,
+            copied_bytes=copied + stream.copied_bytes,
             bytes=sum(size for _crc, size in known.values()),
+            recycled_bytes=recycled,
         )
 
         if n_processes > 1:

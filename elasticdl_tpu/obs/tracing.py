@@ -141,7 +141,11 @@ SPAN_NAMES: Dict[str, str] = {
                              "inside the writer: every file written once, "
                              "its CRC32 taken in flight (`copied_bytes`, "
                              "`streamed_bytes`, `lookahead_peak_bytes`, "
-                             "`leaves`)",
+                             "`leaves`; `pieces`: the transfers, one a "
+                             "whole leaf and one a piece of a cut one; "
+                             "`recycled_bytes`: bytes taken from an "
+                             "address range the file had taken bytes "
+                             "from before)",
     "checkpoint.save.crc": "interval: the integrity manifest alone "
                            "(`reread_bytes`: 0 unless a file is read back)",
     "checkpoint.save.commit": "interval: rename + garbage-collect",

@@ -403,6 +403,28 @@ class _BuildSpan:
         return self._compiled(*args, **kwargs)
 
 
+def leaf_cutter(plan: "CompilePlan", leaves, **cutter_kwargs):
+    """The programs that cut the large ones of `leaves` into pieces for a
+    streamed save (`checkpoint/saver.py` `LeafCutter`), built now and
+    through `plan`: each is a `compile.build` of its own, which the
+    compile cache and the executable store keep, so that a worker that
+    starts again reads them and a save compiles nothing.  They are
+    programs of ONE device (a leaf that every device holds is cut on
+    the first): under a mesh of several the store, which loads a
+    program for the whole mesh, is not theirs, and they are plain jits
+    that the compile cache alone keeps."""
+    from elasticdl_tpu.checkpoint.saver import LeafCutter
+
+    build = None
+    if plan.mesh.devices.size == 1:
+        def build(fn, name):
+            return plan.compile(fn, name=name, journal=False)
+
+    cutter = LeafCutter(build=build, **cutter_kwargs)
+    cutter.warm(leaves)
+    return cutter
+
+
 def _journal_plan(record: Dict[str, Any]) -> None:
     # Host-side only (trainer init / _compile_steps time); the obs
     # plane never rides a traced step (trace-purity rule).

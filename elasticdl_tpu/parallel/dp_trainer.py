@@ -112,6 +112,7 @@ class DataParallelTrainer:
         self._train_step = None
         self._train_window_jit = None
         self._eval_step = None
+        self.leaf_cutter = None  # (with the step programs)
 
     # -- sharding layout (declarative rule table, parallel/compile.py) --
 
@@ -194,6 +195,8 @@ class DataParallelTrainer:
             in_shardings=(state_shardings, batch),
             out_shardings=batch,
         )
+        # (an FSDP world's shards have no program and cross whole)
+        self.leaf_cutter = pc.leaf_cutter(plan, jax.tree.leaves(state))
 
     def jitted_entrypoints(self) -> dict:
         """Current jitted entrypoints by name — the step-anatomy
@@ -512,7 +515,7 @@ class DataParallelTrainer:
                 "step": int(self._host_step),
                 "leaves": dense_leaves,
             }
-        saver.save(step, dense, sharded)
+        saver.save(step, dense, sharded, cutter=self.leaf_cutter)
 
     def set_sharded_restore(self, saver, step: int) -> None:
         self._pending_sharded_restore = (saver, step)

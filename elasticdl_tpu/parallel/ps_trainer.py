@@ -31,6 +31,7 @@ import numpy as np
 import optax
 
 from elasticdl_tpu.checkpoint.saver import tree_nbytes
+from elasticdl_tpu.checkpoint.sharded import own_shards
 from elasticdl_tpu.common.log_utils import get_logger
 from elasticdl_tpu.obs import tracing
 from elasticdl_tpu.layers.embedding import (
@@ -177,6 +178,7 @@ class ShardedEmbeddingTrainer:
         self._pending_sharded_restore: Optional[Tuple[Any, int]] = None
         self._train_step = None  # jitted lazily once shardings are known
         self._eval_step = None
+        self.leaf_cutter = None  # (with the step programs)
 
     def _remake_fused(self, emb_tx: SparseOptimizer, mesh):
         """Rebuild the optimizer in fused mode, threading the dispatch
@@ -553,6 +555,9 @@ class ShardedEmbeddingTrainer:
             name="ps_eval_step",
             in_shardings=(state_shardings, batch),
             out_shardings=batch,
+        )
+        self.leaf_cutter = pc.leaf_cutter(
+            plan, own_shards(self._sharded_arrays(self._state))[1]
         )
 
     # -- compiled steps -------------------------------------------------
@@ -937,7 +942,9 @@ class ShardedEmbeddingTrainer:
                 "model_state": state.model_state,
                 "scalar_slots": self._scalar_slots(state),
             }
-        saver.save(step, dense, self._sharded_arrays(state))
+        saver.save(
+            step, dense, self._sharded_arrays(state), cutter=self.leaf_cutter
+        )
 
     def set_sharded_restore(self, saver, step: int) -> None:
         """Defer restore until ensure_initialized has built the model's

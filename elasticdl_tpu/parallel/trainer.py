@@ -59,6 +59,11 @@ class Trainer(Protocol):
     #: window to a multiple of it.
     apply_every: int
 
+    #: The programs that cut the state's large leaves into pieces for a
+    #: streamed save (`checkpoint/saver.py` `LeafCutter`); None until
+    #: the step programs are built.
+    leaf_cutter: Any
+
     @property
     def state(self) -> Any: ...
 

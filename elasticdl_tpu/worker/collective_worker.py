@@ -915,7 +915,10 @@ class CollectiveWorker:
                         if not streams(state):
                             state = self._trainer.state_to_host()
                         if self._world.is_leader:
-                            self._ckpt.save(state, step)
+                            self._ckpt.save(
+                                state, step,
+                                cutter=self._trainer.leaf_cutter,
+                            )
             self._last_ckpt_step = step
 
 

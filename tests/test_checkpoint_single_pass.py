@@ -941,3 +941,338 @@ def test_a_state_sharded_over_devices_takes_the_host_path():
     assert not saver_mod.streams({"t": table, "w": jnp.ones(3)})
     assert saver_mod.streams({"w": jnp.ones(3), "n": 2})
     assert not saver_mod.streams({"n": 2})
+
+
+# ---------------------------------------------------------------------------
+# Leaves cut into pieces on the device (PR 50)
+# ---------------------------------------------------------------------------
+
+
+def _cuttable_tree():
+    """Leaves under a piece of 1 KiB, exactly one, one and a remainder,
+    many (along a second axis too: a row of `deep` is 2112 bytes),
+    0-d, bfloat16, and what is no plain array."""
+    rng = np.random.default_rng(11)
+
+    def normal(*shape, dtype=jnp.float32):
+        return jnp.asarray(rng.normal(size=shape), dtype)
+
+    return {
+        "deep": normal(7, 33, 16),
+        "exact": normal(32, 8),
+        "half": normal(100, 24, dtype=jnp.bfloat16),
+        "key": jax.random.key(3),
+        "lr": 0.5,
+        "many": normal(640, 8),
+        "remainder": normal(40, 8),
+        "under": normal(10, 8),
+        "zero": jnp.float32(3.0),
+    }
+
+
+def _integrity(step_dir):
+    with open(os.path.join(step_dir, "integrity.json")) as f:
+        return json.load(f)["files"]
+
+
+@pytest.mark.parametrize("piece_bytes", [256, 1024, 4096, 1 << 20])
+def test_a_state_cut_into_pieces_saves_the_bytes_the_whole_leaves_do(
+    tmp_path, piece_bytes
+):
+    tree = _cuttable_tree()
+    whole = CheckpointSaver(str(tmp_path / "whole")).save(tree, 3)
+    cutter = saver_mod.LeafCutter(piece_bytes=piece_bytes)
+    large = [
+        x for x in jax.tree.leaves(tree)
+        if saver_mod._on_device(x) and x.nbytes > piece_bytes
+    ]
+    # One program a (shape, dtype); warming again builds nothing.
+    assert cutter.warm(jax.tree.leaves(tree)) == len(large)
+    assert cutter.warm(jax.tree.leaves(tree)) == 0
+    marker = time.time()
+    saver = CheckpointSaver(str(tmp_path / "cut"))
+    with save_span(rank=0, step=3):
+        cut = saver.save(tree, 3, cutter=cutter)
+    assert _integrity(cut) == _integrity(whole)
+    restored, step = saver.load_latest()
+    assert step == 3
+    _assert_same_tree(restored, tree)
+    (write,) = _spans_since(marker, "checkpoint.save.write")
+    pieces = sum(
+        sum(1 for _ in saver_mod._Cut.of(
+            x.shape, x.dtype, None, piece_bytes
+        ).pieces())
+        for x in large
+    )
+    on_device = [x for x in jax.tree.leaves(tree) if saver_mod._on_device(x)]
+    assert write["pieces"] == pieces + len(on_device) - len(large)
+    assert write["leaves"] == len(on_device)
+    assert write["copied_bytes"] == 0
+    assert 0 <= write["recycled_bytes"] <= write["bytes"]
+
+
+@pytest.mark.parametrize("shape,dtype,axes,piece_bytes,axis,rows,count", [
+    ((32, 8), np.float32, None, 1024, 0, 32, 1),
+    ((40, 8), np.float32, None, 1024, 0, 32, 2),
+    ((640, 8), np.float32, None, 1024, 0, 32, 20),
+    ((7, 33, 16), np.float32, None, 1024, 1, 16, 7 * 3),
+    ((100, 24), jnp.bfloat16, None, 1024, 0, 21, 5),
+    ((24, 100), np.float32, (1, 0), 1024, 0, 10, 10),
+    ((3, 5, 64, 4), np.float32, (1, 2, 3, 0), 512, 1, 10, 5 * 7),
+    ((12544, 2048), np.float32, None, 16 << 20, 0, 2048, 7),
+    ((32, 2048, 512), np.float32, None, 16 << 20, 0, 4, 8),
+])
+def test_a_leafs_pieces_are_its_bytes_in_the_files_order(
+    shape, dtype, axes, piece_bytes, axis, rows, count
+):
+    cut = saver_mod._Cut.of(shape, dtype, axes, piece_bytes)
+    assert (cut.axis, cut.rows) == (axis, rows)
+    itemsize = np.dtype(dtype).itemsize
+    assert int(np.prod(cut.sizes)) * itemsize <= piece_bytes
+    pieces = list(cut.pieces())
+    assert len(pieces) == count
+    if int(np.prod(shape)) > 1 << 20:
+        return  # (the published shapes: the arithmetic alone)
+    leaf = np.arange(int(np.prod(shape)), dtype=np.int64).reshape(shape)
+    stored = leaf if axes is None else leaf.transpose(axes)
+    runs = []
+    for starts, skip in pieces:  # what the program and the stream do
+        piece = stored[tuple(
+            slice(int(at), int(at) + size)
+            for at, size in zip(starts, cut.sizes)
+        )]
+        assert piece.shape == cut.sizes
+        runs.append(piece.reshape(cut.rows, -1)[skip:].reshape(-1))
+    assert np.array_equal(np.concatenate(runs), stored.reshape(-1))
+
+
+def test_a_leaf_the_device_keeps_transposed_is_cut_along_its_major_axis(
+    tmp_path, monkeypatch, transposed_transfers
+):
+    monkeypatch.setattr(
+        saver_mod, "_device_axes", lambda a: (1, 0) if a.ndim == 2 else None
+    )
+    tree = _cuttable_tree()
+    whole = CheckpointSaver(str(tmp_path / "whole")).save(tree, 1)
+    cutter = saver_mod.LeafCutter(piece_bytes=1024)
+    cutter.warm(jax.tree.leaves(tree))
+    saver = CheckpointSaver(str(tmp_path / "cut"))
+    cut = saver.save(tree, 1, cutter=cutter)
+    assert _integrity(cut) == _integrity(whole)
+    restored, _step = saver.load_latest()
+    _assert_same_tree(restored, tree)
+    assert restored["many"].flags.f_contiguous
+
+
+def test_a_leaf_of_several_lookaheads_is_never_on_the_host_whole(
+    monkeypatch,
+):
+    lookahead, piece_bytes = 8192, 1024
+    monkeypatch.setattr(saver_mod, "_LOOKAHEAD_BYTES", lookahead)
+    leaves = [
+        jnp.arange(5 * lookahead, dtype=jnp.uint8).reshape(-1, 64),
+        jnp.arange(100, dtype=jnp.uint8),
+        jnp.arange(3 * lookahead + 320, dtype=jnp.uint8).reshape(-1, 64),
+    ]
+    cutter = saver_mod.LeafCutter(piece_bytes=piece_bytes)
+    assert cutter.warm(leaves) == 2
+    stream = saver_mod.LeafStream(cutter)
+    got = []
+    for host in stream.host_arrays(leaves):
+        if isinstance(host, saver_mod.LeafPieces):
+            runs = list(host)
+            assert all(run.nbytes <= piece_bytes for run in runs)
+            host = np.concatenate(runs).reshape(host.shape)
+        got.append(host)
+    for host, leaf in zip(got, leaves):
+        assert _same_bits(host, leaf)
+    assert stream.lookahead_peak_bytes <= lookahead + piece_bytes
+    assert stream.leaves == 3
+    assert stream.bytes == sum(x.nbytes for x in leaves)
+    assert stream.pieces == 40 + 1 + 25
+    # All but the first piece was on its way before the writer asked; the
+    # rows a last piece shares with the one before it count once.
+    assert stream.streamed_bytes == stream.bytes - piece_bytes
+    # Without the programs the first leaf alone is five look-aheads.
+    whole = saver_mod.LeafStream()
+    list(whole.host_arrays(leaves))
+    assert whole.lookahead_peak_bytes >= leaves[0].nbytes
+    assert whole.pieces == 3
+
+
+def test_pieces_in_flight_are_bounded_in_device_bytes_too(monkeypatch):
+    monkeypatch.setattr(saver_mod, "_PIECES_AHEAD_BYTES", 4 * 1024)
+    leaf = jnp.arange(64 * 1024, dtype=jnp.uint8).reshape(-1, 64)
+    cutter = saver_mod.LeafCutter(piece_bytes=1024)
+    cutter.warm([leaf])
+    in_flight, most = [], [0]
+
+    class Counting(saver_mod.LeafStream):
+        def _start_piece(self, leaf, program, starts):
+            in_flight.append(starts)
+            most[0] = max(most[0], len(in_flight))
+            return super()._start_piece(leaf, program, starts)
+
+        @staticmethod
+        def _fetch(handle):
+            in_flight.pop(0)
+            return saver_mod.LeafStream._fetch(handle)
+
+    stream = Counting(cutter)
+    pieces = next(stream.host_arrays([leaf]))
+    assert _same_bits(np.concatenate(list(pieces)).reshape(leaf.shape), leaf)
+    # Four pieces ahead and the one the writer has asked for.
+    assert most[0] == 5
+    assert stream.lookahead_peak_bytes == 5 * 1024
+
+
+def test_pieces_not_walked_to_their_end_fail_the_stream():
+    leaf = jnp.arange(4096, dtype=jnp.uint8).reshape(-1, 64)
+    cutter = saver_mod.LeafCutter(piece_bytes=1024)
+    cutter.warm([leaf])
+    arrays = saver_mod.LeafStream(cutter).host_arrays([leaf, jnp.ones(3)])
+    next(iter(next(arrays)))
+    with pytest.raises(RuntimeError, match="not taken to their end"):
+        next(arrays)
+
+
+def test_a_leaf_no_program_was_warmed_for_crosses_whole_and_compiles_nothing(
+    tmp_path,
+):
+    warmed = {"a": jnp.ones((64, 16)), "n": 1}
+    cutter = saver_mod.LeafCutter(piece_bytes=1024)
+    assert cutter.warm(jax.tree.leaves(warmed)) == 1
+    tree = dict(warmed, b=jnp.ones((48, 16)), c=jnp.ones((64, 16), jnp.int32))
+    (program,) = [p for _cut, p in cutter._programs.values()]
+    compiled = program._cache_size()
+    marker = time.time()
+    saver = CheckpointSaver(str(tmp_path))
+    with save_span(rank=0, step=1):
+        saver.save(tree, 1, cutter=cutter)
+    (write,) = _spans_since(marker, "checkpoint.save.write")
+    assert write["pieces"] == 4 + 2  # `a` cut, `b` and `c` whole
+    assert program._cache_size() == compiled
+    restored, _step = saver.load_latest()
+    _assert_same_tree(restored, tree)
+
+
+def test_a_second_save_through_the_plans_programs_compiles_nothing(tmp_path):
+    from elasticdl_tpu.parallel import compile as pc
+
+    tree = _cuttable_tree()
+    plan = pc.CompilePlan(
+        build_mesh(MeshConfig(), devices=jax.devices()[:1]), trainer="test"
+    )
+    marker = time.time()
+    cutter = pc.leaf_cutter(plan, jax.tree.leaves(tree), piece_bytes=1024)
+    builds = _spans_since(marker, "compile.build")
+    # One build a (shape, dtype) over a piece, each a named entrypoint.
+    assert len(builds) == 4
+    assert all(b["entrypoint"].startswith("ckpt_piece.") for b in builds)
+    saver = CheckpointSaver(str(tmp_path))
+    saver.save(tree, 1, cutter=cutter)
+    sizes = [p._cache_size() for _cut, p in cutter._programs.values()]
+    marker = time.time()
+    with save_span(rank=0, step=2):
+        saver.save(tree, 2, cutter=cutter)
+    assert not _spans_since(marker, "compile.build")
+    assert [
+        p._cache_size() for _cut, p in cutter._programs.values()
+    ] == sizes
+    (write,) = _spans_since(marker, "checkpoint.save.write")
+    assert write["pieces"] > write["leaves"]
+    restored, step = saver.load_latest()
+    assert step == 2
+    _assert_same_tree(restored, tree)
+
+
+@pytest.mark.parametrize("kind", ["full", "sharded"])
+def test_a_failure_on_the_helper_thread_fails_the_save(
+    tmp_path, monkeypatch, kind
+):
+    rows = (2 << 20) // 64  # pieces of 1 MiB: folded on the helper thread
+    leaf = jnp.zeros((rows, 16), jnp.float32)
+    cutter = saver_mod.LeafCutter(piece_bytes=1 << 20)
+    cutter.warm([leaf])
+
+    def fold(self, piece):
+        raise RuntimeError("the helper thread failed")
+
+    monkeypatch.setattr(ChecksumWriter, "_fold", fold)
+    with pytest.raises(RuntimeError, match="helper thread failed"):
+        if kind == "full":
+            saver = CheckpointSaver(str(tmp_path))
+            saver.save({"w": leaf}, 1, cutter=cutter)
+        else:
+            saver = ShardedCheckpointSaver(str(tmp_path))
+            saver.save(1, {"step": 1}, {"table|t": leaf}, cutter=cutter)
+    monkeypatch.undo()
+    assert saver.steps() == []
+    assert not [
+        name for name in os.listdir(tmp_path)
+        if name.startswith("step_") and ".tmp" not in name
+    ]
+
+
+def test_sharded_rows_cut_into_pieces_are_the_same_files(tmp_path):
+    mesh = build_mesh(MeshConfig())
+    table = jax.device_put(
+        jnp.arange(256 * 16, dtype=jnp.float32).reshape(256, 16),
+        NamedSharding(mesh, P(("data", "model"))),
+    )
+    # (a dense leaf of a shard's own shape: it is pickled whole)
+    rows = table.addressable_shards[0].data.shape[0]
+    dense = {"step": jnp.int32(5), "like_a_shard": jnp.ones((rows, 16))}
+    from elasticdl_tpu.checkpoint.sharded import own_shards
+
+    whole = ShardedCheckpointSaver(str(tmp_path / "whole")).save(
+        5, dense, {"table|t": table}
+    )
+    cutter = saver_mod.LeafCutter(piece_bytes=256)
+    assert cutter.warm(own_shards({"table|t": table})[1]) == 1
+    marker = time.time()
+    saver = ShardedCheckpointSaver(str(tmp_path / "cut"))
+    with save_span(rank=0, step=5):
+        cut = saver.save(5, dense, {"table|t": table}, cutter=cutter)
+    assert _integrity(cut) == _integrity(whole)
+    (write,) = _spans_since(marker, "checkpoint.save.write")
+    shards = len(own_shards({"table|t": table})[1])
+    assert write["pieces"] == shards * (rows * 64 // 256) + 2
+    restored = saver.load_array(5, "table|t", table.sharding)
+    saver.release(5)
+    assert _same_bits(restored, table)
+    assert _same_bits(
+        saver.load_dense(5)["like_a_shard"], np.ones((rows, 16), np.float32)
+    )
+
+
+@pytest.mark.parametrize("spans,new,had", [
+    ([], (0, 10), 0),
+    ([(0, 10)], (0, 10), 10),
+    ([(0, 10)], (10, 20), 0),
+    ([(0, 10)], (5, 15), 5),
+    ([(0, 10), (20, 30)], (5, 25), 10),
+    ([(0, 10), (20, 30)], (12, 18), 0),
+    ([(0, 10), (10, 20)], (0, 20), 20),
+])
+def test_address_ranges_count_the_bytes_they_already_had(spans, new, had):
+    ranges = saver_mod._Ranges()
+    for lo, hi in spans:
+        ranges.add(lo, hi)
+    assert ranges.add(*new) == had
+    assert ranges.add(*new) == new[1] - new[0]  # all of it, by now
+
+
+def test_bytes_written_from_memory_written_from_before_are_recycled(tmp_path):
+    first = np.ones(3 << 20, np.uint8)
+    other = np.ones(2 << 20, np.uint8)
+    with ChecksumWriter(str(tmp_path / "f")) as writer:
+        writer.write(first)
+        assert writer.recycled_bytes == 0
+        writer.write(other)
+        assert writer.recycled_bytes == 0
+        writer.write(first[1 << 20:])
+        assert writer.recycled_bytes == 2 << 20
+        writer.write(b"small writes are not followed")
+        assert writer.recycled_bytes == 2 << 20
+    assert writer.size == (7 << 20) + 29

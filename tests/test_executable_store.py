@@ -657,3 +657,31 @@ def test_a_checkout_under_another_path_hits(tmp_path):
         f.write("\n")
     edited, _ = _run_probe(_PROCESS_PROBE, cache, root=str(elsewhere))
     assert edited["build"]["aot_hit"] is False
+
+
+@pytest.mark.parametrize("devices", [1, 4])
+def test_a_restart_reads_the_piece_programs_where_the_mesh_is_one_device(
+    store, tmp_path, devices
+):
+    """`pc.leaf_cutter`'s programs cut a leaf on ONE device: the store,
+    which loads a program for a plan's whole mesh, keeps them for a mesh
+    of one and leaves them to the compile cache under a mesh of several
+    (a stored build loaded for four devices refuses a one-device call)."""
+    from elasticdl_tpu.checkpoint.saver import CheckpointSaver
+
+    plan = _plan(devices)
+    leaf = jax.device_put(
+        jnp.arange(64 * 16, dtype=jnp.float32).reshape(64, 16),
+        NamedSharding(plan.mesh, P()),
+    )
+    hits = []
+    for start in range(2):  # a worker's first start, then its second
+        marker = time.time()
+        cutter = pc.leaf_cutter(plan, [leaf], piece_bytes=1024)
+        builds = _builds_since(marker)
+        hits.append([b["aot_hit"] for b in builds])
+        saver = CheckpointSaver(str(tmp_path / f"start{start}"))
+        saver.save({"w": leaf}, 1, cutter=cutter)
+        restored, _step = saver.load_latest()
+        assert np.array_equal(restored["w"], np.asarray(leaf))
+    assert hits == ([[False], [True]] if devices == 1 else [[], []])

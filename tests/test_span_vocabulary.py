@@ -261,7 +261,7 @@ def test_bookkeep_holds_neither_the_save_nor_the_profilers_stop():
     class SleepySaver:
         saves = 0
 
-        def save(self, state, step):
+        def save(self, state, step, cutter=None):
             time.sleep(0.2)
             SleepySaver.saves += 1
 
@@ -504,6 +504,48 @@ def test_sharded_save_has_four_children_in_order_with_the_files_bytes(
     assert by_name["checkpoint.save.crc"]["bytes"] == written + os.path.getsize(
         os.path.join(final, "manifest.json")
     )
+
+
+@pytest.mark.parametrize("kind", ["full", "sharded"])
+def test_the_write_span_carries_the_streams_counters(tmp_path, kind):
+    """`pieces` and `recycled_bytes` (PR 50) beside the four of PR 39;
+    no file under `perfbench/` reads any of them yet (`PERF.md` section 7)."""
+    import jax
+    import jax.numpy as jnp
+
+    from elasticdl_tpu.checkpoint import ShardedCheckpointSaver
+    from elasticdl_tpu.checkpoint.saver import (
+        CheckpointSaver, LeafCutter, save_span,
+    )
+    from elasticdl_tpu.obs.tracing import SPAN_NAMES
+
+    leaf = jnp.arange(64 * 16, dtype=jnp.float32).reshape(64, 16)
+    cutter = LeafCutter(piece_bytes=1024)
+    cutter.warm([leaf])
+    marker = time.time()
+    with save_span(rank=0, step=1):
+        if kind == "full":
+            CheckpointSaver(str(tmp_path)).save(
+                {"w": leaf, "n": jnp.int32(1)}, 1, cutter=cutter
+            )
+        else:
+            ShardedCheckpointSaver(str(tmp_path)).save(
+                1, {"n": jnp.int32(1)}, {"table|t": leaf}, cutter=cutter
+            )
+    (write,) = _spans_since(marker, "checkpoint.save.write")
+    assert write["pieces"] == 4 + 1 and write["leaves"] == 2
+    assert write["recycled_bytes"] == 0  # (pieces under 1 MiB: not followed)
+    assert write["lookahead_peak_bytes"] == 4096 + 4
+    assert write["copied_bytes"] == (0 if kind == "full" else 4)
+    for field in ("pieces", "recycled_bytes", "streamed_bytes", "leaves",
+                  "lookahead_peak_bytes", "copied_bytes"):
+        assert field in write
+        assert f"`{field}`" in SPAN_NAMES["checkpoint.save.write"]
+    reads = " ".join(
+        open(os.path.join(REPO_ROOT, "perfbench", "metrics", name)).read()
+        for name in os.listdir(os.path.join(REPO_ROOT, "perfbench", "metrics"))
+    )
+    assert "recycled_bytes" not in reads and '"pieces"' not in reads
 
 
 # ---------------------------------------------------------------------------
