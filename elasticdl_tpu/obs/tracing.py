@@ -198,6 +198,12 @@ SPAN_NAMES: Dict[str, str] = {
                   "applied several times: the `tokens` its steps "
                   "counted, the mean exit distribution over them "
                   "(`p_exit_1` .. `p_exit_R`) and its mean `entropy`",
+    # noise of a masked-diffusion step (layers/diffusion_noise.py): the
+    # same route
+    "diffusion.noise": "after: worker, one a task of a model trained by "
+                       "masked diffusion: the `tokens` its steps saw, how "
+                       "many of them the records' noise `masked`, and the "
+                       "mean noise level `t_mean` of its sequences",
 }
 
 #: ``jax.named_scope`` names on device ops (op metadata only; they show
@@ -214,14 +220,16 @@ SPAN_NAMES: Dict[str, str] = {
 #: (attn_full | attn_window, attn_gate), mlp, moe > (...), lm_head_loss).
 #: With the looped stack (model_zoo/ouro): fwd_bwd > (loop > (attn >
 #: (attn_proj, attn_rotary, attn_full), mlp, block_norm), lm_head_loss,
-#: exit_gate).
+#: exit_gate).  With the block-diffusion stack (model_zoo/sdar): fwd_bwd >
+#: (attn > (attn_proj, attn_rotary, attn_blockdiff), moe > (...),
+#: lm_head_loss).
 DEVICE_SCOPES = (
     "fwd_bwd", "dense_update", "sparse_apply", "grad_accumulate",
     "sparse_adam", "attn", "mlp", "lm_head_loss", "optimizer",
     "gdn", "gdn_scan", "moe", "moe_route", "moe_experts", "moe_shared",
     "ssm", "ssm_scan", "gdn_mix", "mla_latent", "mla_core",
     "attn_full", "attn_window", "attn_gate", "attn_proj", "attn_rotary",
-    "loop", "block_norm", "exit_gate",
+    "loop", "block_norm", "exit_gate", "attn_blockdiff",
 )
 
 #: Size bound on the flight recorder's final registry snapshot: the

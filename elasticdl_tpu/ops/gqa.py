@@ -1,4 +1,4 @@
-"""Rotary position embedding and grouped-query causal attention.
+"""Rotary position embedding and grouped-query attention under a mask rule.
 
 Rotary is the rotate-half form on the first `rotary_dim` of a head's
 dimensions (a partial rotary factor leaves the rest untouched), with
@@ -31,13 +31,29 @@ scaled by `scale`, 1/sqrt(Dqk) where none is given.  Two engines:
   T = 8192, 16 heads, 2 sequences); cutting its queries into independent
   rematerialised blocks leaves XLA free to hold several blocks' slabs at
   once (11 GB, compiled for a v5e).  So this engine walks the query blocks
-  in a loop and, for each, only the key blocks a causal mask lets it
-  see (a device-side loop with a trip count of its own, so the half of
-  the score matrix above the diagonal is never computed), keeps the
-  output and the log-sum-exp, and has its own backward pass that
-  recomputes a block's probabilities from them.  One [B, Hq, block, block]
-  slab is alive at a time.  Query heads of one key-value head are batched
-  into one product, so K and V are never repeated.
+  in a loop and, for each, only the key blocks its MASK RULE lets it
+  see (a device-side loop with a trip count of its own, so under a causal
+  mask the half of the score matrix above the diagonal is never
+  computed), keeps the output and the log-sum-exp, and has its own
+  backward pass that recomputes a block's probabilities from them.  One
+  [B, Hq, block, block] slab is alive at a time.  Query heads of one
+  key-value head are batched into one product, so K and V are never
+  repeated.
+
+  The mask is a RULE the engine is handed (ISSUE 51), not a case it
+  knows: for query tile i, `visits(i, block)` gives the bounds of its
+  key loop, `tile(i, step, block)` the key tile a step of that loop
+  reads, and `masked(rows, cols)` a tile's own mask from its positions;
+  the tile a row visits LAST holds the row's own key (`_scores`).  Three
+  rules exist, as two classes: `Causal()` (tiles 0..i; the mask `cols >
+  rows`), `Causal(window)` (the band: from `_first_block` to i) and
+  `BlockDiffusion(tokens, length)` (a noised copy of `tokens` tokens in
+  front of their clean copy, blocks of `length`: the clean tiles up to
+  and with the query tile's own, then, for a noised query tile, its own
+  noised tile; n (n + 1) + n visits for 2 n query tiles, forward and
+  backward, where a plain mask over them would make n (2 n + 1)).  The
+  first two trace to the programs they traced to before the rule was an
+  argument (a test holds the lowered text).
 
   Its layout contract (ISSUE 43): operands, results and their gradients
   are [B, H, T, D], HEADS IN FRONT OF TOKENS, so that a block of one
@@ -59,12 +75,20 @@ outside the band, forward and backward: its key loop starts at the band's
 first block (`_first_block`).  The Pallas kernel has no band (a streaming
 one, tried in PR 36, was slower: PERF.md section 6, ROADMAP queue 1
 item 5), so `impl="pallas"` with a window raises.
+
+`causal_attention(block_diffusion=(T, B))` is the block-diffusion mask
+over q, k, v of 2 T positions: the XLA engine alone, in tiles that hold
+whole blocks of ONE copy (T a multiple of the tile, the tile of B:
+checked, with a message that says which); `impl="pallas"` raises, as
+with a window (the kernel's `causal` is a bool, and K + V of a head over
+both copies of 8192 tokens are 16 MiB against its 8).
 """
 
 from __future__ import annotations
 
 import math
 from functools import partial
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -186,20 +210,78 @@ def _first_block(i, block: int, window):
     return jnp.maximum(i - (window + block - 2) // block, 0)
 
 
-def _scores(q_i, k_j, i, j, block, scale, window=None):
-    """[B,N,G,Bq,D] x [B,N,Bk,D] -> masked scores [B,N,G,Bq,Bk], float32.
-    A row with no key in block j (a band's first block) reads NEG_INF
-    throughout; the diagonal block, visited last, holds its own key, and
-    the running maximum then wipes what such a row gathered."""
+class Causal(NamedTuple):
+    """The causal mask, alone or as a band of `window` keys up to the
+    query's own.  A mask RULE is what the engine's loops ask: `visits`,
+    how many key tiles query tile `i` reads, as the bounds of its key
+    loop; `tile`, which tile a step of that loop is; `masked`, a tile's
+    own mask from its rows' and columns' positions.  Here the steps are
+    the tiles themselves, from the band's first to the diagonal."""
+
+    window: Any = None
+
+    def visits(self, i, block):
+        return _first_block(i, block, self.window), i + 1
+
+    def tile(self, i, step, block):
+        return step
+
+    def masked(self, rows, cols):
+        masked = cols[None, :] > rows[:, None]
+        if self.window is not None:
+            masked = masked | (cols[None, :] <= rows[:, None] - self.window)
+        return masked
+
+
+class BlockDiffusion(NamedTuple):
+    """The block-diffusion mask (BD3-LM, arXiv:2503.09573) over 2 `tokens`
+    positions, a NOISED copy of a sequence in front of its CLEAN copy, in
+    blocks of `length` tokens: a noised query reads its own noised block,
+    both directions, and the clean blocks strictly before it; a clean
+    query reads the clean blocks up to and with its own.  With n tiles a
+    half, query tile i of either half reads the clean tiles n .. n + i
+    mod n, and a noised one then its OWN noised tile, last (where every
+    row has a key, its own: `_scores`): n (n + 1) + n visits for the 2 n
+    query tiles, where a plain mask over the 2 n tiles would make
+    n (2 n + 1)."""
+
+    tokens: int
+    length: int
+
+    def visits(self, i, block):
+        n = self.tokens // block
+        return 0, i % n + 1 + (i < n)
+
+    def tile(self, i, step, block):
+        n = self.tokens // block
+        return jnp.where(step <= i % n, n + step, i)
+
+    def masked(self, rows, cols):
+        t, length = self.tokens, self.length
+        noised_q, noised_k = rows[:, None] < t, cols[None, :] < t
+        block_q = (rows % t // length)[:, None]
+        block_k = (cols % t // length)[None, :]
+        allowed = jnp.where(
+            noised_q,
+            jnp.where(noised_k, block_k == block_q, block_k < block_q),
+            ~noised_k & (block_k <= block_q),
+        )
+        return ~allowed
+
+
+def _scores(q_i, k_j, i, j, block, scale, rule):
+    """[B,N,G,Bq,D] x [B,N,Bk,D] -> masked scores [B,N,G,Bq,Bk], float32,
+    of query tile `i` against key tile `j` under `rule`.  A row with no
+    key in tile j (a band's first block, a noised block's rows against
+    its own clean block) reads NEG_INF throughout; the tile visited last
+    holds the row's own key, and the running maximum then wipes what such
+    a row gathered."""
     s = jnp.einsum(
         "bngqd,bnkd->bngqk", q_i, k_j, preferred_element_type=jnp.float32
     ) * scale
     rows = i * block + jnp.arange(block)
     cols = j * block + jnp.arange(block)
-    masked = cols[None, :] > rows[:, None]
-    if window is not None:
-        masked = masked | (cols[None, :] <= rows[:, None] - window)
-    return jnp.where(masked, NEG_INF, s)
+    return jnp.where(rule.masked(rows, cols), NEG_INF, s)
 
 
 def _take(x, i, block, axis=-2):
@@ -219,21 +301,21 @@ def _put(x, x_i, i, block, axis=-2, add=False):
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def causal_gqa_attention(q, k, v, block: int, scale=None, window=None):
+def causal_gqa_attention(q, k, v, block: int, scale=None, rule=Causal()):
     """HEADS IN FRONT OF TOKENS: q [B, Hq, T, D]; k [B, Hkv, T, D];
     v [B, Hkv, T, Dv] -> [B, Hq, T, Dv] (`heads_first` packs and unpacks;
     `rotary_pack` makes q and k so); softmax of q k^T scale (1/sqrt(D)
-    where none is given) under a causal mask, float32 accumulation.  A
-    block of one head is a contiguous [block, D] matrix in every operand
-    and result, forward and backward, and the loops take block `i` by a
-    dynamic slice of the token axis: nothing is laid out again for them.
-    With `window` a query reads the `window` keys up to its own, and a
-    query block visits only the key blocks that band touches, forward and
-    backward."""
-    return _gqa_fwd(q, k, v, block, scale, window)[0]
+    where none is given) under the mask `rule` says (`Causal`, alone or
+    as a band; `BlockDiffusion`, where T counts both copies), float32
+    accumulation.  A block of one head is a contiguous [block, D] matrix
+    in every operand and result, forward and backward, and the loops
+    take block `i` by a dynamic slice of the token axis: nothing is laid
+    out again for them.  A query block visits only the key blocks its
+    rule names (`visits`, `tile`), forward and backward."""
+    return _gqa_fwd(q, k, v, block, scale, rule)[0]
 
 
-def _gqa_fwd(q, k, v, block, scale, window=None):
+def _gqa_fwd(q, k, v, block, scale, rule):
     b, hq, t, d = q.shape
     n, dv = k.shape[1], v.shape[3]
     g = hq // n
@@ -244,9 +326,10 @@ def _gqa_fwd(q, k, v, block, scale, window=None):
         out, lse = results
         q_i = _take(grouped, i, block)
 
-        def kv_step(j, carry):
+        def kv_step(step, carry):
             m, l, acc = carry
-            s = _scores(q_i, _take(k, j, block), i, j, block, scale, window)
+            j = rule.tile(i, step, block)
+            s = _scores(q_i, _take(k, j, block), i, j, block, scale, rule)
             m_new = jnp.maximum(m, jnp.max(s, axis=-1))
             p = jnp.exp(s - m_new[..., None])
             fix = jnp.exp(m - m_new)
@@ -257,7 +340,7 @@ def _gqa_fwd(q, k, v, block, scale, window=None):
             return m_new, l * fix + jnp.sum(p, axis=-1), acc
 
         m, l, acc = jax.lax.fori_loop(
-            _first_block(i, block, window), i + 1, kv_step,
+            *rule.visits(i, block), kv_step,
             (
                 jnp.full((b, n, g, block), NEG_INF, jnp.float32),
                 jnp.zeros((b, n, g, block), jnp.float32),
@@ -277,7 +360,7 @@ def _gqa_fwd(q, k, v, block, scale, window=None):
     return out, (q, k, v, out, lse)
 
 
-def _gqa_bwd(block, scale, window, residuals, dout):
+def _gqa_bwd(block, scale, rule, residuals, dout):
     q, k, v, out, lse = residuals
     b, hq, t, d = q.shape
     n, dv = k.shape[1], v.shape[3]
@@ -295,10 +378,11 @@ def _gqa_bwd(block, scale, window, residuals, dout):
         q_i, do_i = _take(grouped, i, block), _take(dout, i, block)
         delta_i, lse_i = _take(delta, i, block, -1), _take(lse, i, block, -1)
 
-        def kv_step(j, inner):
+        def kv_step(step, inner):
             dq_i, dk, dv = inner
+            j = rule.tile(i, step, block)
             k_j, v_j = _take(k, j, block), _take(v, j, block)
-            s = _scores(q_i, k_j, i, j, block, scale, window)
+            s = _scores(q_i, k_j, i, j, block, scale, rule)
             p = jnp.exp(s - lse_i[..., None])
             dv_j = jnp.einsum(
                 "bngqk,bngqd->bnkd", p.astype(q.dtype), do_i,
@@ -321,7 +405,7 @@ def _gqa_bwd(block, scale, window, residuals, dout):
                     _put(dv, dv_j, j, block, add=True))
 
         dq_i, dk, dv = jax.lax.fori_loop(
-            _first_block(i, block, window), i + 1, kv_step,
+            *rule.visits(i, block), kv_step,
             (jnp.zeros(q_i.shape, jnp.float32), dk, dv),
         )
         return _put(dq, dq_i.astype(q.dtype), i, block), dk, dv
@@ -346,12 +430,16 @@ def heads_first(x):
 
 
 def causal_attention(q, k, v, *, scale=None, window=None,
-                     impl: str = "auto", block: int = 512,
-                     packed: bool = False):
+                     block_diffusion=None, impl: str = "auto",
+                     block: int = 512, packed: bool = False):
     """Causal softmax attention, grouped-query heads; the scores are
     scaled by `scale`, 1/sqrt(Dqk) where none is given.  With `window` a
     query at t reads the keys `t - window < s <= t` (its own among them):
     the XLA engine's work, which skips the key blocks outside that band.
+    With `block_diffusion=(T, B)` q, k and v hold 2 T positions, a noised
+    copy of T tokens in front of their clean copy, and the mask is
+    `BlockDiffusion`'s in blocks of B tokens (not causal: a block reads
+    itself in both directions): the XLA engine's work too.
     q [B, T, Hq, Dqk]; k [B, T, Hkv, Dqk]; v [B, T, Hkv, Dv] (Dv may
     differ from Dqk) -> [B, T, Hq, Dv]."""
     # (`elasticdl_tpu.ops` exports the FUNCTION under the module's name)
@@ -377,6 +465,11 @@ def causal_attention(q, k, v, *, scale=None, window=None,
                 "or xla"
             )
         sizes += f" window={window}"
+    if block_diffusion is not None:
+        return _block_diffusion_attention(
+            q, k, v, t, sizes, BlockDiffusion(*block_diffusion), scale,
+            window, impl, block, packed,
+        )
     use_pallas = impl == "pallas" or (
         impl == "auto" and window is None
         and jax.default_backend() == "tpu" and supports(t, d, d_v=dv)
@@ -408,6 +501,10 @@ def causal_attention(q, k, v, *, scale=None, window=None,
         # rule is left as it was: the quarter's gain is 1% of that step.
         block = min(block, max(window // 2, 128))
     block = _block_size(t, block)
+    return _xla_engine(q, k, v, t, sizes, block, scale, Causal(window), packed)
+
+
+def _xla_engine(q, k, v, t, sizes, block, scale, rule, packed):
     logger.info(
         "attention engine: xla causal_gqa_attention T=%d %s "
         "(blocks of %d; operands: %s)", t, sizes, block,
@@ -416,7 +513,45 @@ def causal_attention(q, k, v, *, scale=None, window=None,
     )
     with jax.named_scope("attn"):
         if packed:
-            return causal_gqa_attention(q, k, v, block, scale, window)
+            return causal_gqa_attention(q, k, v, block, scale, rule)
         return heads_first(causal_gqa_attention(
-            *map(heads_first, (q, k, v)), block, scale, window
+            *map(heads_first, (q, k, v)), block, scale, rule
         ))
+
+
+def _block_diffusion_attention(q, k, v, positions, sizes, rule, scale,
+                               window, impl, block, packed):
+    """The front door's checks under the block-diffusion mask, then the
+    XLA engine in tiles that hold whole blocks of ONE copy."""
+    tokens, length = rule
+    if window is not None:
+        raise ValueError("a block-diffusion mask takes no window")
+    if impl == "pallas":
+        raise ValueError(
+            "the Pallas kernel has no block-diffusion mask (its `causal` "
+            "is a bool, and K and V of a head over both copies pass its "
+            "cap at 8192 tokens): block_diffusion needs impl auto or xla"
+        )
+    if positions != 2 * tokens:
+        raise ValueError(
+            f"block_diffusion=({tokens}, {length}) is a noised and a clean "
+            f"copy of {tokens} tokens, {2 * tokens} positions: q has "
+            f"{positions}"
+        )
+    if tokens % length:
+        raise ValueError(
+            f"a copy holds whole blocks: the block length {length} does not "
+            f"divide its {tokens} tokens"
+        )
+    block = _block_size(tokens, max(block, length))  # a block at the least
+    if block % length:
+        raise ValueError(
+            f"a tile holds whole blocks: the block length {length} does "
+            f"not divide the tile of {block} tokens (the largest power-of-"
+            f"two fraction of the tile asked for that divides {tokens})"
+        )
+    return _xla_engine(
+        q, k, v, positions,
+        sizes + f" block_diffusion=({tokens}, {length})", block, scale, rule,
+        packed,
+    )

@@ -532,8 +532,9 @@ class CollectiveWorker:
     def _journal_counters(self, start_ts: float, steps: int) -> None:
         """One span a task for each kind of counter the model keeps in its
         state (layers/ledger.py: `moe.routing` of a model with expert
-        layers, `loop.exits` of one that is applied several times; nothing
-        for a model with neither): what was counted over the task's
+        layers, `loop.exits` of one that is applied several times,
+        `diffusion.noise` of one trained by masked diffusion; nothing for
+        a model with none of them): what was counted over the task's
         steps.  Called where the task's loss has been fetched."""
         model_state = self._model_state()
         if not model_state:

@@ -13,25 +13,30 @@ every cell whose stack uses the piece:
   `laguna-xs2`, `granite4-h-micro`, `mellum2`); the eighth, `ouro`, takes
   the data contract alone: its prediction is a named tree (four exits'
   logits and the exit distribution), so its `loss` and `eval_metrics_fn`
-  are its own;
-- `dense`, the bias-free projection with a float32 result: the seven 8k
+  are its own; the ninth, `sdar`, takes the READER alone
+  (`custom_data_reader`): its features are a record's tokens with the
+  record's noise, drawn by its own `dataset_fn`, its labels the tokens
+  themselves, and its prediction a tree too;
+- `dense`, the bias-free projection with a float32 result: the eight 8k
   stacks (`SplitDense`, the same parameter read as a product a range of
   its columns: `Mamba2Mixer`); `RMSNorm` (weight from 1): all of them
   but Qwen3-Next, whose zero-centred norm is another function and stays
   in its file (Mellum 2 also norms every query and key head with it: it
   is over the last axis, so a [B, T, heads, head_dim] tensor gets one
   weight vector of `head_dim`; Ouro has four of them a layer and one
-  that closes every pass);
+  that closes every pass; SDAR's as Mellum 2's);
 - `RotaryAttention`, the projections, `ops/rotary_pack.py` and
   `ops/gqa.causal_attention` as one piece (a head norm and a band where
-  the stack says so): Mellum 2 (both, by the layer's type) and Ouro
-  (neither);
-- `warmup_adamw`: DeepSeek-V2, Granite, Mellum 2 and Ouro; `balancing_adamw`, which wraps
+  the stack says so): Mellum 2 (both, by the layer's type), Ouro
+  (neither) and SDAR (the head norm, and in place of a causal mask the
+  block-diffusion one over a noised and a clean copy, `block_diffusion=`:
+  the positions are the tables' rows, so the copies share them);
+- `warmup_adamw`: DeepSeek-V2, Granite, Mellum 2, Ouro and SDAR; `balancing_adamw`, which wraps
   it with the rule that moves a sigmoid router's selection biases:
   Nemotron-H and Laguna, the two stacks behind that router;
 - `listed` and `check_listed`, a source's per-layer lists as a job's flat
   flags carry them and the check that they cover the stack: Laguna and
-  Mellum 2;
+  Mellum 2 (SDAR: `listed` for its `mlp_only_layers`);
 - `Mamba2Mixer` (with `_Conv1d`, `_dt_bias_init`; its float32 passes are
   `ops/gdn_passes.py`'s, which Qwen3-Next's DeltaNet layers call too) and
   the position-free `Attention`: Nemotron-H and Granite 4.0-H.
@@ -373,9 +378,13 @@ class RotaryAttention(nn.Module):
     then the rotation by `cos` / `sin` over as many of a head's columns as
     the tables have, one rounding, heads in front of tokens);
     `ops/gqa.causal_attention` over every key up to the query's own, or
-    over the last `window` of them; `o_proj`.  Device scopes: `attn_proj`
-    (entered for q, k, v and again for `o_proj`), `attn_rotary`,
-    `attn_full` | `attn_window`."""
+    over the last `window` of them, or, with `block_diffusion=(T, B)`,
+    over a noised and a clean copy of T tokens (x holds the 2 T
+    positions, `cos` / `sin` their rows: both copies of a token at ITS
+    position) under the block-diffusion mask in blocks of B; `o_proj`.
+    Device scopes: `attn_proj` (entered for q, k, v and again for
+    `o_proj`), `attn_rotary`, `attn_full` | `attn_window` |
+    `attn_blockdiff`."""
 
     num_heads: int
     num_kv_heads: int
@@ -384,6 +393,7 @@ class RotaryAttention(nn.Module):
     impl: str = "auto"       # `ops/gqa.causal_attention`'s, as it is
     window: Any = None       # keys a query reads, its own among them
     head_norm_eps: Any = None  # None: q and k heads are not normed
+    block_diffusion: Any = None  # (tokens of a copy, a block's length)
 
     @nn.compact
     def __call__(self, x, cos, sin):
@@ -406,12 +416,15 @@ class RotaryAttention(nn.Module):
                 )
                 for p, norm in ((q, "q_norm"), (k, "k_norm"))
             )
-        with jax.named_scope(
-            "attn_full" if self.window is None else "attn_window"
-        ):
+        engine_scope = (
+            "attn_blockdiff" if self.block_diffusion is not None
+            else "attn_full" if self.window is None else "attn_window"
+        )
+        with jax.named_scope(engine_scope):
             out = gqa.heads_first(gqa.causal_attention(
                 q, k, gqa.heads_first(v.astype(self.dtype)), impl=self.impl,
-                window=self.window, packed=True,
+                window=self.window, block_diffusion=self.block_diffusion,
+                packed=True,
             ))
         with jax.named_scope("attn_proj"):
             return dense(d, self.dtype, "o_proj")(

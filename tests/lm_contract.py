@@ -200,6 +200,13 @@ def _dot_precisions(jaxpr):
     ]
 
 
+def _tokens(features):
+    """A step's tokens: its features, or the first of them where the
+    features are a tuple with the record's noise beside the tokens
+    (`model_zoo/sdar`: tokens, mask, t).  They are the labels too."""
+    return features[0] if isinstance(features, tuple) else features
+
+
 def _size(tree):
     return sum(int(np.prod(leaf.shape)) for leaf in jax.tree.leaves(tree))
 
@@ -215,12 +222,12 @@ def _flag(value):
 
 #: The spans the worker writes a task from a model's counters
 #: (`layers/ledger.py`), each by the kind of model that has it.
-COUNTER_SPANS = ("moe.routing", "loop.exits")
+COUNTER_SPANS = ("moe.routing", "loop.exits", "diffusion.noise")
 
 
-def counter_spans(events, name="moe.routing"):
+def counter_spans(events, name="moe.routing", also=()):
     """The two tasks' `name` spans of a two-task job's journal; a model
-    with that counter writes no other kind."""
+    with that counter writes no other kind but those in `also`."""
     found = {
         kind: [e for e in events
                if e.get("event") == "span" and e.get("name") == kind]
@@ -229,7 +236,9 @@ def counter_spans(events, name="moe.routing"):
     spans = found.pop(name)
     assert len(spans) == 2
     assert [e["steps"] for e in spans] == [2, 2]
-    assert not any(found.values())
+    assert not any(
+        spans for kind, spans in found.items() if kind not in also
+    )
     return spans
 
 
@@ -246,7 +255,7 @@ class Whole(NamedTuple):
     program: Callable
     reference: Callable
     params: Any
-    tokens: Any
+    tokens: Any                # the step's features (`_tokens` of them)
     model: dict
 
 
@@ -298,6 +307,9 @@ class LMSpec:
     #: (id, experts_first, experts_held) of the whole-model cases
     held: tuple = ()
     whole_model_changes: dict = dataclasses.field(default_factory=dict)
+    #: tokens [.., sequences, T] (an array or its shape) -> the features of
+    #: a step that takes more than tokens; None: the tokens are the features
+    features: Optional[Callable] = None
     logits_rel: float = 1e-5
     #: a prediction that is a named tree -> the ONE array the reference's
     #: `forward` returns and the cell compares; None: the prediction is it
@@ -374,6 +386,9 @@ class LMSpec:
     def build(self, model, **keywords):
         return self.zoo.custom_model(**self.kwargs(model), **keywords)
 
+    def features_of(self, tokens):
+        return self.features(tokens) if self.features else tokens
+
     def array(self, prediction):
         """What is compared of a prediction (`compared`)."""
         return self.compared(prediction) if self.compared else prediction
@@ -400,7 +415,9 @@ class LMSpec:
     def reference_losses(self, params, whole: Whole):
         if self.losses is not None:
             return self.losses(self.ref, params, whole.tokens, whole.model)
-        return self.zoo.loss(whole.tokens, whole.reference(params)), 0.0
+        return self.zoo.loss(
+            _tokens(whole.tokens), whole.reference(params)
+        ), 0.0
 
     def trainer(self):
         """-> (a float32 trainer at the reduced widths, each layer
@@ -483,13 +500,14 @@ def test_logits_and_loss_match_the_reference(lm, program_and_reference):
     predicted, want = program(params), reference(params)
     got = lm.array(predicted)  # the prediction itself, or a named tree's
     assert got.shape == want.shape
-    assert (got.shape[0], got.shape[-2], got.shape[-1]) == tokens.shape + (
-        lm.tiny["vocab_size"],)
+    assert (got.shape[0], got.shape[-2], got.shape[-1]) == _tokens(
+        tokens).shape + (lm.tiny["vocab_size"],)
     assert _rel(got, want) < lm.logits_rel
     # the program REPORTS the first of the two alone
     reported, added = lm.reference_losses(params, whole)
     np.testing.assert_allclose(
-        float(lm.zoo.loss(tokens, predicted)), float(reported), rtol=1e-5
+        float(lm.zoo.loss(_tokens(tokens), predicted)), float(reported),
+        rtol=1e-5
     )
     if lm.added_loss_above is not None:
         assert float(added) > lm.added_loss_above
@@ -500,7 +518,9 @@ def test_gradients_match_the_reference(lm, program_and_reference):
     program injects a gradient (a balancing loss), the reference
     differentiates the sum."""
     program, _, params, tokens, _ = whole = program_and_reference
-    got = jax.grad(lambda p: lm.zoo.loss(tokens, program(p)))(params)
+    got = jax.grad(
+        lambda p: lm.zoo.loss(_tokens(tokens), program(p))
+    )(params)
     want = jax.grad(lambda p: sum(lm.reference_losses(p, whole)))(params)
     flat_got = jax.tree_util.tree_leaves_with_path(got)
     flat_want = jax.tree.leaves(want)
@@ -535,7 +555,7 @@ def test_full_size_configuration_counts_the_parameters_it_states(lm):
     config, model = lm.config, lm.config["model"]
     shapes = jax.eval_shape(
         lm.build(model).init, jax.random.PRNGKey(0),
-        jnp.zeros((1, 8), jnp.int32),
+        lm.features_of(jnp.zeros((1, 8), jnp.int32)),
     )["params"]
     count = _size(shapes)
     assert count == lm.parameters
@@ -691,7 +711,9 @@ def test_trainer_carries_the_counters_and_checkpoint_restores_the_logits(
 
     trainer, model = lm.trainer()
     tokens = lm.ref.sample(11, 4, model)
-    losses = [float(trainer.train_step(tokens, tokens)) for _ in range(3)]
+    losses = [
+        float(trainer.train_step(tokens, _tokens(tokens))) for _ in range(3)
+    ]
     assert all(np.isfinite(losses)) and losses[-1] < losses[0]
     if lm.trained:
         lm.trained(trainer, model)
@@ -799,7 +821,7 @@ def compile_program(lm, topo, sequences, one_step=False):
         state, _ = jax.eval_shape(
             lambda: trainer._make_state(
                 jax.random.PRNGKey(0),
-                jnp.zeros((sequences, tokens), jnp.int32),
+                lm.features_of(jnp.zeros((sequences, tokens), jnp.int32)),
             )
         )
         state = jax.tree.map(
@@ -816,7 +838,7 @@ def compile_program(lm, topo, sequences, one_step=False):
         program = (trainer._train_step_impl if one_step
                    else trainer._train_window_impl)
         return jax.jit(program, donate_argnums=(0,)).lower(
-            state, batch, batch, mask
+            state, lm.features_of(batch), batch, mask
         ).compile()
 
 
@@ -904,8 +926,9 @@ def _lm_window(spec, seed=0):
     )
     rng = np.random.RandomState(seed)
     tokens = rng.randint(0, 64, size=(8, 16)).astype(np.int32)
-    trainer.ensure_initialized(tokens)
-    batch = (tokens, tokens, np.ones((8,), np.float32))
+    features = spec.features_of(tokens)
+    trainer.ensure_initialized(features)
+    batch = (features, tokens, np.ones((8,), np.float32))
     return trainer, trainer.stage_window([batch, batch])
 
 

@@ -124,7 +124,7 @@ def test_every_emitted_name_is_in_the_vocabulary():
         "checkpoint.restore.load", "proc.start",
         "master.tensorboard_init", "master.serve_ready",
         "worker.backend_init", "state.init", "compile.build",
-        "moe.routing", "loop.exits", *BOOT_CHAIN_SPANS,
+        "moe.routing", "loop.exits", "diffusion.noise", *BOOT_CHAIN_SPANS,
     ):
         assert name in tracing.SPAN_NAMES
     assert set(tracing.DEVICE_SCOPES) >= {
@@ -133,13 +133,13 @@ def test_every_emitted_name_is_in_the_vocabulary():
         "gdn", "gdn_scan", "moe", "moe_route", "moe_experts", "moe_shared",
         "ssm", "ssm_scan", "gdn_mix", "mla_latent", "mla_core",
         "attn_full", "attn_window", "attn_gate", "attn_proj", "attn_rotary",
-        "loop", "block_norm", "exit_gate",
+        "loop", "block_norm", "exit_gate", "attn_blockdiff",
     }
     # every ledger a model's counters can have writes a span of the list
     from elasticdl_tpu.layers.ledger import task_ledgers
 
     assert [ledger.span for ledger in task_ledgers()] == [
-        "moe.routing", "loop.exits",
+        "moe.routing", "loop.exits", "diffusion.noise",
     ]
 
 
@@ -168,7 +168,8 @@ BOOT_CHAIN_METRICS = (
 
 def test_every_device_scope_and_start_up_span_names_its_reader():
     """`docs/observability.md` and PERF.md's span table name every device
-    scope, the `moe.routing` and `loop.exits` spans and every span and field of the
+    scope, the `moe.routing`, `loop.exits` and `diffusion.noise` spans and
+    every span and field of the
     start-up chain, with the reader of each: nothing on the lists is
     without one, and `since_main_s` is gone from both."""
     with open(os.path.join(REPO_ROOT, "docs", "observability.md")) as f:
@@ -176,7 +177,8 @@ def test_every_device_scope_and_start_up_span_names_its_reader():
     with open(os.path.join(REPO_ROOT, "PERF.md")) as f:
         perf = f.read()
     for name in (
-        tracing.DEVICE_SCOPES + ("moe.routing", "loop.exits")
+        tracing.DEVICE_SCOPES
+        + ("moe.routing", "loop.exits", "diffusion.noise")
         + BOOT_CHAIN_SPANS
         + BOOT_CHAIN_FIELDS
     ):

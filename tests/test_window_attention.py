@@ -20,8 +20,22 @@ fa = importlib.import_module("elasticdl_tpu.ops.flash_attention")
 T, BLOCK = 256, 64
 
 
-def _plain(q, k, v, window=None):
-    """A full masked softmax, grouped-query heads, float32."""
+def _allowed(tokens, length):
+    """The block-diffusion mask over [noised | clean], dense, from its
+    three lines: [2 tokens, 2 tokens], rows the queries."""
+    at = np.arange(2 * tokens)
+    noised, blk = at < tokens, at % tokens // length
+    q_noised, k_noised = noised[:, None], noised[None, :]
+    return jnp.asarray(
+        (q_noised & k_noised & (blk[None, :] == blk[:, None]))
+        | (q_noised & ~k_noised & (blk[None, :] < blk[:, None]))
+        | (~q_noised & ~k_noised & (blk[None, :] <= blk[:, None]))
+    )
+
+
+def _plain(q, k, v, window=None, block_diffusion=None):
+    """A full masked softmax, grouped-query heads, float32: causal, a
+    band of `window` keys, or `block_diffusion=(tokens, length)`."""
     t, n_rep = q.shape[1], q.shape[2] // k.shape[2]
     k, v = (jnp.repeat(x, n_rep, axis=2) for x in (k, v))
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) / q.shape[-1] ** 0.5
@@ -29,6 +43,8 @@ def _plain(q, k, v, window=None):
     mask = at[None, :] <= at[:, None]
     if window is not None:
         mask = mask & (at[None, :] > at[:, None] - window)
+    if block_diffusion is not None:
+        mask = _allowed(*block_diffusion)
     weights = jax.nn.softmax(jnp.where(mask, scores, -jnp.inf), axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", weights, v)
 
@@ -45,10 +61,15 @@ def _qkv(seed, hq, hkv, t=T, d=32, b=2):
 
 def _engine(window, block=BLOCK):
     """The XLA engine in blocks of 64 (under a band `causal_attention`
-    gives it blocks of half the window, 128 at the least)."""
+    gives it blocks of half the window, 128 at the least); `window` may
+    be a mask rule instead."""
+    def rule(t):
+        if isinstance(window, gqa.BlockDiffusion):
+            return window
+        return gqa.Causal(window if window and window < t else None)
+
     return lambda q, k, v: gqa.heads_first(gqa.causal_gqa_attention(
-        *map(gqa.heads_first, (q, k, v)), block, None,
-        window if window and window < q.shape[1] else None,
+        *map(gqa.heads_first, (q, k, v)), block, None, rule(q.shape[1]),
     ))
 
 
@@ -61,33 +82,58 @@ WINDOWS = [1, 17, 63, 64, 65, 100, 128, 129, 255, 256, 1000]
 MELLUM_BAND = dict(hq=8, hkv=1, t=2048, d=16, b=1)
 
 
+#: The block-diffusion rule (ISSUE 51) as cases of the same test: a
+#: noised and a clean copy of 256 / 512 tokens in blocks of 4 and 16,
+#: 8 query heads over 2, the engine in tiles of 64 and 128.
+BLOCK_DIFFUSION = [
+    pytest.param(
+        gqa.BlockDiffusion(tokens, length),
+        dict(hq=8, hkv=2, t=2 * tokens, d=16, b=1, block=tile),
+        id=f"block-diffusion-{tokens}-by-{length}-tiles-of-{tile}",
+    )
+    for tokens in (256, 512) for length in (4, 16) for tile in (64, 128)
+]
+
+
 @pytest.mark.parametrize("impl", ["xla", "auto"])
 @pytest.mark.parametrize("window,shape", [
     pytest.param(window, dict(hq=4, hkv=2), id=str(window))
     for window in WINDOWS
-] + [pytest.param(1024, MELLUM_BAND, id="1024-of-2048-x8")])
+] + [pytest.param(1024, MELLUM_BAND, id="1024-of-2048-x8")]
+  + BLOCK_DIFFUSION)
 def test_band_matches_a_masked_plain_softmax(impl, window, shape):
-    """`impl` is the front door's; the engine beneath is the XLA one."""
-    q, k, v, weight = _qkv(window, **shape)
-    engine = _engine(window)
-    want = _plain(q, k, v, window)
+    """`impl` is the front door's; the engine beneath is the XLA one.
+    `window`: a band's keys, or the block-diffusion rule."""
+    shape = dict(shape)
+    block = shape.pop("block", BLOCK)
+    if isinstance(window, gqa.BlockDiffusion):
+        mask = dict(block_diffusion=tuple(window))
+        door = dict(mask, block=block)
+        seed = window.tokens + window.length
+    else:
+        mask = door = dict(window=window)
+        seed = window
+    q, k, v, weight = _qkv(seed, **shape)
+    engine = _engine(window, block)
+    want = _plain(q, k, v, **mask)
     # the front door, with its own choice of blocks, gives the same
     front = jax.grad(lambda *a: jnp.sum(gqa.causal_attention(
-        *a, window=window, impl=impl
+        *a, impl=impl, **door
     ) * weight), (0, 1, 2))
     np.testing.assert_allclose(
-        gqa.causal_attention(q, k, v, window=window, impl=impl), want,
+        gqa.causal_attention(q, k, v, impl=impl, **door), want,
         rtol=2e-4, atol=2e-5,
     )
     np.testing.assert_allclose(engine(q, k, v), want, rtol=2e-4, atol=2e-5)
     got = jax.grad(lambda *a: jnp.sum(engine(*a) * weight), (0, 1, 2))(q, k, v)
     ref = jax.grad(
-        lambda *a: jnp.sum(_plain(*a, window) * weight), (0, 1, 2)
+        lambda *a: jnp.sum(_plain(*a, **mask) * weight), (0, 1, 2)
     )(q, k, v)
     for name, a, b, c in zip("qkv", got, ref, front(q, k, v)):
         np.testing.assert_allclose(a, b, rtol=2e-3, atol=2e-4, err_msg=name)
         np.testing.assert_allclose(c, b, rtol=2e-3, atol=2e-4, err_msg=name)
-    if window < q.shape[1]:  # the band is in the result: full causal differs
+    if "block_diffusion" in mask or window < q.shape[1]:
+        # the mask is in the result: full causal attention differs
         assert float(jnp.max(jnp.abs(engine(q, k, v) - _plain(q, k, v)))) > 1e-2
 
 
@@ -337,7 +383,8 @@ def test_heads_first_engine_at_one_and_two_sequences(
 
     def engine(q, k, v):  # tokens first in, tokens first out
         return gqa.heads_first(gqa.causal_gqa_attention(
-            *map(gqa.heads_first, (q, k, v)), block, None, window
+            *map(gqa.heads_first, (q, k, v)), block, None,
+            gqa.Causal(window),
         ))
 
     want = _plain(q, k, v, window)
@@ -388,3 +435,158 @@ def test_packed_front_door_is_the_engine_without_the_transposes(
             )),
             gqa.causal_attention(q, k, v, impl="pallas"),
         )
+
+
+# ---------------------------------------------------------------------------
+# The mask as a rule (ISSUE 51): the block-diffusion rule's visits, and the
+# two rules there were, lowered as at the parent
+# ---------------------------------------------------------------------------
+
+
+def _count_scored_tiles(monkeypatch, run):
+    """How often `run()` scores a tile, with the loops run in Python
+    (`jax.disable_jit`: a `fori_loop` is then a `for`), so that every
+    visit of the engine, forward or backward, is one call of `_scores`."""
+    visits = []
+    scores = gqa._scores
+
+    def counted(q_i, k_j, i, j, *rest):
+        visits.append((int(i), int(j)))
+        return scores(q_i, k_j, i, j, *rest)
+
+    monkeypatch.setattr(gqa, "_scores", counted)
+    with jax.disable_jit():
+        run()
+    return visits
+
+
+@pytest.mark.parametrize("tokens,tile", [(256, 64), (256, 128), (512, 64)])
+def test_block_diffusion_visits_n_n_plus_1_plus_n_tiles(
+    tokens, tile, monkeypatch
+):
+    """For the 2 n query tiles of a noised and a clean copy the engine
+    scores n (n + 1) + n key tiles, forward, and as many again backward:
+    the clean tiles 0..i for query tile i of either half and the noised
+    tile i for the noised one; a plain mask over the 2 n tiles would
+    score n (2 n + 1).  Counted from the loops as they run."""
+    n = tokens // tile
+    q, k, v, weight = _qkv(1, 4, 2, t=2 * tokens, d=8, b=1)
+    engine = _engine(gqa.BlockDiffusion(tokens, 4), tile)
+    forward = _count_scored_tiles(monkeypatch, lambda: engine(q, k, v))
+    assert len(forward) == n * (n + 1) + n < n * (2 * n + 1)
+    want = (
+        [(i, n + j) for i in range(n) for j in range(i + 1)]      # noised,
+        + [(i, i) for i in range(n)]                              # its own,
+        + [(n + i, n + j) for i in range(n) for j in range(i + 1)]  # clean
+    )
+    assert sorted(forward) == sorted(want)
+    # a noised query tile's own tile is its last
+    assert [j for i, j in forward if i == n - 1][-1] == n - 1
+    both = _count_scored_tiles(monkeypatch, lambda: jax.grad(
+        lambda q: jnp.sum(engine(q, k, v) * weight)
+    )(q))
+    assert sorted(both) == sorted(2 * want)  # the forward, then the backward
+    # the causal rule over the same 2 n tiles, for scale
+    causal = _count_scored_tiles(
+        monkeypatch, lambda: _engine(None, tile)(q, k, v)
+    )
+    assert len(causal) == n * (2 * n + 1)
+    # at the cell's shape: 288 of 528
+    rule, cell = gqa.BlockDiffusion(8192, 4), 8192 // 512
+    assert sum(
+        int(hi) - int(lo)
+        for lo, hi in (rule.visits(i, 512) for i in range(2 * cell))
+    ) == 288 == cell * (cell + 1) + cell
+    assert cell * (2 * cell + 1) == 528
+
+
+def test_block_diffusion_reads_no_tile_outside_its_rule():
+    """SKIPPED, not masked, forward and backward: with K and V poisoned
+    (NaN) in every tile that query tile 1 of the noised half does not
+    visit (the noised tiles but its own, the clean tiles after its own),
+    its rows and its queries' gradients are those of clean inputs."""
+    tokens, tile = 256, 64
+    rule = gqa.BlockDiffusion(tokens, 4)
+    q, k, v, weight = _qkv(2, 4, 2, t=2 * tokens, d=16, b=1)
+    at = jnp.arange(2 * tokens)[None, :, None, None]
+    read = ((at >= tile) & (at < 2 * tile)) | (
+        (at >= tokens) & (at < tokens + 2 * tile)
+    )
+    poison = jnp.where(read, 0.0, jnp.nan)
+    rows = (at >= tile) & (at < 2 * tile)
+
+    def own(q, k, v):
+        return jnp.where(rows, _engine(rule, tile)(q, k, v), 0.0)
+
+    got = own(q, k + poison, v + poison)
+    assert bool(jnp.isfinite(got).all())
+    np.testing.assert_allclose(
+        got, jnp.where(rows, _plain(q, k, v, block_diffusion=rule), 0.0),
+        rtol=2e-4, atol=2e-5,
+    )
+    dq = jax.grad(lambda q: jnp.sum(own(q, k + poison, v + poison) * weight))(q)
+    clean = jax.grad(lambda q: jnp.sum(own(q, k, v) * weight))(q)
+    assert bool(jnp.isfinite(dq[:, tile:2 * tile]).all())
+    np.testing.assert_allclose(
+        dq[:, tile:2 * tile], clean[:, tile:2 * tile], rtol=2e-3, atol=2e-4
+    )
+    # the causal rule over the same positions does read the noised tile 0
+    full = _engine(None, tile)(q, k + poison, v + poison)
+    assert not bool(jnp.isfinite(full[:, tile:2 * tile]).all())
+
+
+def test_front_door_says_what_a_block_diffusion_mask_needs():
+    q, k, v, _ = _qkv(5, 4, 2, t=512, d=8, b=1)
+    door = gqa.causal_attention
+    with pytest.raises(ValueError, match="no block-diffusion mask"):
+        door(q, k, v, block_diffusion=(256, 4), impl="pallas")
+    with pytest.raises(ValueError, match="takes no window"):
+        door(q, k, v, block_diffusion=(256, 4), window=64)
+    with pytest.raises(ValueError, match="1024 positions: q has 512"):
+        door(q, k, v, block_diffusion=(512, 4))
+    with pytest.raises(ValueError, match="does not divide the tile of 64"):
+        door(q[:, :384], k[:, :384], v[:, :384], block_diffusion=(192, 48),
+             block=64)
+    with pytest.raises(ValueError, match="does not divide its 256 tokens"):
+        door(q, k, v, block_diffusion=(256, 3), block=64)
+    # a block of the whole copy: the noised half attends to itself in
+    # both directions and to nothing else
+    whole = door(q, k, v, block_diffusion=(256, 256), impl="auto")
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q[:, :256], jnp.repeat(
+        k[:, :256], 2, axis=2)) / 8 ** 0.5
+    dense = jnp.einsum(
+        "bhqk,bkhd->bqhd", jax.nn.softmax(scores, axis=-1),
+        jnp.repeat(v[:, :256], 2, axis=2),
+    )
+    np.testing.assert_allclose(whole[:, :256], dense, rtol=2e-4, atol=2e-5)
+
+
+@pytest.mark.parametrize("window,golden", [
+    (None, "gqa_causal_packed.hlo.gz"), (512, "gqa_band_packed.hlo.gz"),
+], ids=["causal", "band-512"])
+def test_causal_and_banded_callers_lower_to_the_parents_program(
+    window, golden
+):
+    """The two rules the engine had before it took its mask as a rule
+    lower to the text they lowered to then: forward and backward of the
+    packed front door at 2 x 1024 tokens, 8 heads over 2 of 64, blocks
+    of 256, against the text recorded at the commit before ISSUE 51
+    (`tests/data/`: `jax.jit(...).lower(...).as_text()`, gzipped), so
+    that no cell's program moved."""
+    import difflib
+    import gzip
+    import os
+
+    q = jax.ShapeDtypeStruct((2, 8, 1024, 64), jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((2, 2, 1024, 64), jnp.bfloat16)
+    text = jax.jit(jax.value_and_grad(
+        lambda q, k, v: jnp.sum(gqa.causal_attention(
+            q, k, v, window=window, impl="xla", block=256, packed=True,
+        ).astype(jnp.float32) ** 2), (0, 1, 2),
+    )).lower(q, k, k).as_text()
+    path = os.path.join(os.path.dirname(__file__), "data", golden)
+    with gzip.open(path, "rt") as f:
+        recorded = f.read()
+    assert text == recorded, "".join(list(difflib.unified_diff(
+        recorded.splitlines(True), text.splitlines(True), "parent", "now",
+    ))[:60])
