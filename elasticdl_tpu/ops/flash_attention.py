@@ -17,6 +17,10 @@ over k-blocks, recomputing probabilities from the saved logsumexp).
 
 `flash_attention` is a drop-in for `blockwise_attention`'s self-attention
 case: [B, T, H, D] in, [B, T, H, D] out, differentiable via custom_vjp.
+Its forward rule NAMES the two results that are also the backward pass's
+residuals, the output and the log-sum-exp (`ops/gqa.py` `ATTN_OUT`,
+`ATTN_LSE`), so that a rematerialised layer can keep them and run no
+forward kernel again (`model_zoo/lm_common.KEEP_ATTENTION_RESULTS`).
 Off-TPU (tests, CPU meshes) the kernels run in Pallas interpret mode.
 """
 
@@ -27,7 +31,10 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
+
+from elasticdl_tpu.ops.gqa import ATTN_LSE, ATTN_OUT
 
 NEG_INF = -1e30
 # Measured on the v5e (B4 T2048 H8 D128, causal): fwd 256->4.18ms,
@@ -657,6 +664,8 @@ def _flash(q, k, v, scale, causal, block_q, block_k, interpret):
 
 def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret):
     out, lse = _fwd(q, k, v, scale, causal, block_q, block_k, interpret)
+    out = checkpoint_name(out, ATTN_OUT)
+    lse = checkpoint_name(lse, ATTN_LSE)
     return out, (q, k, v, out, lse)
 
 

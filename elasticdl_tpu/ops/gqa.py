@@ -35,7 +35,10 @@ scaled by `scale`, 1/sqrt(Dqk) where none is given.  Two engines:
   see (a device-side loop with a trip count of its own, so under a causal
   mask the half of the score matrix above the diagonal is never
   computed), keeps the output and the log-sum-exp, and has its own
-  backward pass that recomputes a block's probabilities from them.  One
+  backward pass that recomputes a block's probabilities from them (its
+  forward rule NAMES the two, `ATTN_OUT` and `ATTN_LSE`, as the Pallas
+  kernel's does: a rematerialised layer whose policy saves those names
+  hands them to this backward pass and runs no forward loop again).  One
   [B, Hq, block, block] slab is alive at a time.  Query heads of one
   key-value head are batched into one product, so K and V are never
   repeated.
@@ -92,6 +95,7 @@ from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from elasticdl_tpu.common.log_utils import get_logger
 
@@ -189,6 +193,15 @@ def repeat_kv(x, n_rep: int):
 
 
 NEG_INF = -1e30
+
+# The names both engines' forward rules give the two results that are
+# also residuals of their backward passes (`_gqa_fwd` here, `_flash_fwd`
+# in `ops/flash_attention.py`).  A layer rematerialised under a policy
+# that saves these names (`model_zoo/lm_common.KEEP_ATTENTION_RESULTS`)
+# keeps them, and its recomputed forward holds no engine; anywhere else a
+# name is the identity.
+ATTN_OUT = "attn_out"
+ATTN_LSE = "attn_lse"
 
 
 def _block_size(t: int, block: int) -> int:
@@ -356,7 +369,8 @@ def _gqa_fwd(q, k, v, block, scale, rule):
         jnp.zeros((b, n, g, t, dv), q.dtype),
         jnp.zeros((b, n, g, t), jnp.float32),
     ))
-    out = out.reshape(b, hq, t, dv)
+    out = checkpoint_name(out.reshape(b, hq, t, dv), ATTN_OUT)
+    lse = checkpoint_name(lse, ATTN_LSE)
     return out, (q, k, v, out, lse)
 
 

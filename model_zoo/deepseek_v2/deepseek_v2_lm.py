@@ -92,8 +92,8 @@ from elasticdl_tpu.ops import gqa
 # cross-entropy over float32 logits (under the `lm_head_loss` scope),
 # perplexity and accuracy.
 from model_zoo.lm_common import (  # noqa: F401
-    VOCAB, RMSNorm, custom_data_reader, dataset_fn, dense, eval_metrics_fn,
-    loss, warmup_adamw,
+    KEEP_ATTENTION_RESULTS, VOCAB, RMSNorm, custom_data_reader, dataset_fn,
+    dense, eval_metrics_fn, loss, warmup_adamw,
 )
 
 
@@ -244,7 +244,9 @@ class _Model(nn.Module):
             (c.vocab_size, c.hidden_size), jnp.float32,
         )
         x = embedding[tokens]
-        layer_cls = nn.remat(DecoderLayer) if c.remat else DecoderLayer
+        layer_cls = nn.remat(
+            DecoderLayer, policy=KEEP_ATTENTION_RESULTS
+        ) if c.remat else DecoderLayer
         for i in range(c.num_hidden_layers):
             x = layer_cls(
                 c, i < c.first_k_dense_replace, name=f"layers_{i}"

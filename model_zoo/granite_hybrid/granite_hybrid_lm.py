@@ -103,8 +103,8 @@ import jax.numpy as jnp
 # next-token cross-entropy over float32 logits (under the `lm_head_loss`
 # scope), perplexity and accuracy.
 from model_zoo.lm_common import (  # noqa: F401
-    VOCAB, Attention, Mamba2Mixer, RMSNorm, custom_data_reader, dataset_fn,
-    dense, eval_metrics_fn, loss, warmup_adamw,
+    KEEP_ATTENTION_RESULTS, VOCAB, Attention, Mamba2Mixer, RMSNorm,
+    custom_data_reader, dataset_fn, dense, eval_metrics_fn, loss, warmup_adamw,
 )
 
 MAMBA, ATTENTION = "mamba", "attention"
@@ -197,7 +197,9 @@ class _Model(nn.Module):
             (c.vocab_size, c.hidden_size), jnp.float32,
         )
         x = c.embedding_multiplier * embedding[tokens]
-        layer_cls = nn.remat(DecoderLayer) if c.remat else DecoderLayer
+        layer_cls = nn.remat(
+            DecoderLayer, policy=KEEP_ATTENTION_RESULTS
+        ) if c.remat else DecoderLayer
         for i, kind in enumerate(c.layer_types[:c.num_hidden_layers]):
             x = layer_cls(c, kind, name=f"layers_{i}")(x)
         with jax.named_scope("lm_head_loss"):

@@ -93,8 +93,9 @@ from elasticdl_tpu.ops.rotary_pack import rotary_pack
 # too): AdamW under a warm-up, the selection biases moved by the balancing
 # rule.
 from model_zoo.lm_common import (  # noqa: F401
-    VOCAB, RMSNorm, balancing_adamw as optimizer, check_listed,
-    custom_data_reader, dataset_fn, dense, eval_metrics_fn, listed, loss,
+    KEEP_ATTENTION_RESULTS, VOCAB, RMSNorm, balancing_adamw as optimizer,
+    check_listed, custom_data_reader, dataset_fn, dense, eval_metrics_fn,
+    listed, loss,
 )
 
 FULL, SLIDING = "full_attention", "sliding_attention"
@@ -247,7 +248,9 @@ class _Model(nn.Module):
         )
         x = embedding[tokens]
         tables = rotary_tables(c, tokens.shape[-1])
-        layer_cls = nn.remat(DecoderLayer) if c.remat else DecoderLayer
+        layer_cls = nn.remat(
+            DecoderLayer, policy=KEEP_ATTENTION_RESULTS
+        ) if c.remat else DecoderLayer
         for i in range(c.num_hidden_layers):
             kind = c.layer_types[i]
             x = layer_cls(

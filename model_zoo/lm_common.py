@@ -31,6 +31,8 @@ every cell whose stack uses the piece:
   (neither) and SDAR (the head norm, and in place of a causal mask the
   block-diffusion one over a noised and a clean copy, `block_diffusion=`:
   the positions are the tables' rows, so the copies share them);
+- `KEEP_ATTENTION_RESULTS`, the policy of every stack's `nn.remat`: the
+  eight 8k stacks;
 - `warmup_adamw`: DeepSeek-V2, Granite, Mellum 2, Ouro and SDAR; `balancing_adamw`, which wraps
   it with the rule that moves a sigmoid router's selection biases:
   Nemotron-H and Laguna, the two stacks behind that router;
@@ -170,6 +172,24 @@ def balancing_adamw(lr: float = 3e-4, warmup_steps: int = 2000,
 
 
 # -- blocks -----------------------------------------------------------------
+
+#: What a rematerialised layer KEEPS for its backward pass (`nn.remat`'s
+#: `policy`, in every stack that rematerialises a layer): the attention
+#: engine's two results, `out` and the log-sum-exp, which are residuals of
+#: the engine's own backward pass (`ops/gqa.py` names them in both
+#: engines' forward rules).  Everything else is recomputed: the norms, the
+#: projections, q and k (`rotary_pack`), the MLPs, a state-space or
+#: delta-rule layer whole (nothing under these names).  Without it the
+#: recomputed forward runs the whole engine again only to hand these two
+#: to the backward pass, a fifth to a quarter of an attention core's time,
+#: and what that second forward needs at the backward's peak weighs more
+#: than the kept results (bfloat16 [tokens, Hq Dv] + float32 [Hq, tokens] a
+#: layer): three of the four XLA-engine programs SHRINK (PERF.md section
+#: 6, PR 52).
+KEEP_ATTENTION_RESULTS = jax.checkpoint_policies.save_only_these_names(
+    gqa.ATTN_OUT, gqa.ATTN_LSE
+)
+
 
 def dense(features, dtype, name, kernel_init=nn.initializers.lecun_normal()):
     """A projection without bias, operands in `dtype`, a float32 result:

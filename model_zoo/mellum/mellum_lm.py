@@ -102,8 +102,9 @@ from elasticdl_tpu.ops import gqa
 # cross-entropy over float32 logits (under the `lm_head_loss` scope),
 # perplexity and accuracy.
 from model_zoo.lm_common import (  # noqa: F401
-    VOCAB, RMSNorm, RotaryAttention, check_listed, custom_data_reader,
-    dataset_fn, eval_metrics_fn, listed, loss, warmup_adamw,
+    KEEP_ATTENTION_RESULTS, VOCAB, RMSNorm, RotaryAttention, check_listed,
+    custom_data_reader, dataset_fn, eval_metrics_fn, listed, loss,
+    warmup_adamw,
 )
 
 FULL, SLIDING = "full_attention", "sliding_attention"
@@ -219,7 +220,9 @@ class _Model(nn.Module):
         )
         x = embedding[tokens]
         tables = rotary_tables(c, tokens.shape[-1])
-        layer_cls = nn.remat(DecoderLayer) if c.remat else DecoderLayer
+        layer_cls = nn.remat(
+            DecoderLayer, policy=KEEP_ATTENTION_RESULTS
+        ) if c.remat else DecoderLayer
         for i in range(c.num_hidden_layers):
             kind = c.layer_types[i]
             x = layer_cls(

@@ -98,8 +98,9 @@ from elasticdl_tpu.layers.moe import SparseMoeBlock
 # cross-entropy over float32 logits (under the `lm_head_loss` scope),
 # perplexity and accuracy.
 from model_zoo.lm_common import (  # noqa: F401
-    SELECTION_BIAS, VOCAB, Attention, Mamba2Mixer, RMSNorm, balancing_adamw,
-    custom_data_reader, dataset_fn, eval_metrics_fn, loss,
+    KEEP_ATTENTION_RESULTS, SELECTION_BIAS, VOCAB, Attention, Mamba2Mixer,
+    RMSNorm, balancing_adamw, custom_data_reader, dataset_fn, eval_metrics_fn,
+    loss,
 )
 
 MAMBA, EXPERTS, ATTENTION = "M", "E", "*"
@@ -191,7 +192,9 @@ class _Backbone(nn.Module):
             (c.vocab_size, c.hidden_size), jnp.float32,
         )
         x = embedding[tokens]
-        layer_cls = nn.remat(NemotronHLayer) if c.remat else NemotronHLayer
+        layer_cls = nn.remat(
+            NemotronHLayer, policy=KEEP_ATTENTION_RESULTS
+        ) if c.remat else NemotronHLayer
         for i, kind in enumerate(c.hybrid_override_pattern):
             x = layer_cls(c, kind, name=f"layers_{i}")(x)
         with jax.named_scope("lm_head_loss"):

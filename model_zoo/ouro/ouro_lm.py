@@ -69,12 +69,15 @@ logits and loss.
 
 The passes are ONE `nn.scan` with the parameters broadcast, so the
 program holds L layer bodies whatever R is, each application
-rematerialised on its own under `remat`: at the cell's widths the scan
-builds in a third of the unrolled loop's time and runs 1.5-2.5% faster
-(first two-step window 24.7 s against 75.0, then 1.903 s against 1.952 a
-window; a v5e, PR 45), at 13.78 GB compiled against 10.54 (the scan
-carries the layers' float32 gradient through its backward loop), so the
-loop is not kept as a second path.
+rematerialised on its own under `remat` (all but the attention kernel's
+two results, `lm_common.KEEP_ATTENTION_RESULTS`: 34 MB an application,
+0.82 GB over the 24, 14.59 GB compiled, for a forward kernel fewer an
+application, 47 ms of a 951 ms step on a v5e: PERF.md section 6, PR 52).
+At the cell's widths the scan builds in a third of the unrolled loop's
+time and runs 1.5-2.5% faster (first two-step window 24.7 s against 75.0,
+then 1.903 s against 1.952 a window; a v5e, PR 45), at 13.78 GB compiled
+against 10.54 (the scan carries the layers' float32 gradient through its
+backward loop), so the loop is not kept as a second path.
 
 `attn_impl` is handed to `ops/gqa.causal_attention` as it is.  Its
 default `auto` takes the Pallas kernel at this shape (T 8192 is its cap
@@ -117,8 +120,8 @@ from model_zoo import lm_common
 # and the data contract of any causal LM on `synthetic://lm` data; the
 # loss and the metrics are this stack's own (its prediction is a tree).
 from model_zoo.lm_common import (  # noqa: F401
-    VOCAB, RMSNorm, RotaryAttention, custom_data_reader, dataset_fn,
-    warmup_adamw,
+    KEEP_ATTENTION_RESULTS, VOCAB, RMSNorm, RotaryAttention,
+    custom_data_reader, dataset_fn, warmup_adamw,
 )
 
 
@@ -192,7 +195,9 @@ class _Model(nn.Module):
         cos, sin = gqa.rotary_tables(
             jnp.arange(tokens.shape[-1]), c.head_dim, c.rope_theta
         )
-        layer_cls = nn.remat(DecoderLayer) if c.remat else DecoderLayer
+        layer_cls = nn.remat(
+            DecoderLayer, policy=KEEP_ATTENTION_RESULTS
+        ) if c.remat else DecoderLayer
 
         def one_pass(x):
             """-> (the next pass's input, this pass's closing state).  The

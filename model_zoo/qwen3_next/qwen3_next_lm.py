@@ -60,7 +60,8 @@ from elasticdl_tpu.ops.gated_delta import chunk_gated_delta_rule_rows
 # `synthetic://lm` data: mean next-token cross-entropy over float32
 # logits (under the `lm_head_loss` scope), perplexity and accuracy.
 from model_zoo.lm_common import (  # noqa: F401
-    VOCAB, custom_data_reader, dataset_fn, dense, eval_metrics_fn, loss,
+    KEEP_ATTENTION_RESULTS, VOCAB, custom_data_reader, dataset_fn, dense,
+    eval_metrics_fn, loss,
 )
 
 
@@ -296,7 +297,9 @@ class Qwen3NextLM(nn.Module):
             (c.vocab_size, c.hidden_size), jnp.float32,
         )
         x = embedding[tokens]
-        layer_cls = nn.remat(DecoderLayer) if c.remat else DecoderLayer
+        layer_cls = nn.remat(
+            DecoderLayer, policy=KEEP_ATTENTION_RESULTS
+        ) if c.remat else DecoderLayer
         for i in range(c.num_hidden_layers):
             x = layer_cls(
                 c, (i + 1) % c.full_attention_interval == 0,

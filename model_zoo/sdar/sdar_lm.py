@@ -115,8 +115,8 @@ from elasticdl_tpu.ops import gqa
 # metrics are this stack's own (a step takes the record's noise with its
 # tokens, and its prediction is a tree).
 from model_zoo.lm_common import (  # noqa: F401
-    VOCAB, RMSNorm, RotaryAttention, custom_data_reader, listed,
-    warmup_adamw,
+    KEEP_ATTENTION_RESULTS, VOCAB, RMSNorm, RotaryAttention,
+    custom_data_reader, listed, warmup_adamw,
 )
 
 
@@ -211,7 +211,9 @@ class _Model(nn.Module):
         # the noised and the clean copy of a token share its position
         positions = jnp.concatenate([jnp.arange(t), jnp.arange(t)])
         cos, sin = gqa.rotary_tables(positions, c.head_dim, c.rope_theta)
-        layer_cls = nn.remat(DecoderLayer) if c.remat else DecoderLayer
+        layer_cls = nn.remat(
+            DecoderLayer, policy=KEEP_ATTENTION_RESULTS
+        ) if c.remat else DecoderLayer
         for i in range(c.num_hidden_layers):
             x = layer_cls(c, c.dense(i), t, name=f"layers_{i}")(x, cos, sin)
         with jax.named_scope("lm_head_loss"):

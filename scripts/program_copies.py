@@ -9,6 +9,11 @@ does (no chip), and prints the top-level `copy` and
 and that sum read and written once at the chip's HBM rate beside the
 `copy` the ledger's newest traced run measured for the cell.  The sum of
 the `copy` ops is what the descriptor's `CompileSpec.copy_bytes` bounds.
+Its second line counts the attention engines by the same direction
+(`lm_contract.attention_engine_runs`: the XLA engine's loops, the
+flash-attention kernels): a stack whose rematerialised layers keep the
+engine's results (`model_zoo/lm_common.KEEP_ATTENTION_RESULTS`) reads 0
+under `remat`, which the cell's compile test holds.
 
 Usage (35-60 s a cell; `<cell>` is a `tests/spec_<cell>.py`, or the
 name of a `BENCHMARK.json` workload whose configuration one describes):
@@ -28,14 +33,6 @@ from collections import defaultdict
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 HBM_BYTES_PER_S = 819e9  # a v5e chip's
-
-
-def direction(op_name: str) -> str:
-    if "rematted_computation" in op_name:  # the forward, run again
-        return "remat"
-    if "transpose(jvp" in op_name:
-        return "bwd"
-    return "fwd" if op_name else "-"
 
 
 def short(op_name: str) -> str:
@@ -121,13 +118,20 @@ def main(argv=None):
           f"(aliased {memory.alias_size_in_bytes:,}), "
           f"temporaries {memory.temp_size_in_bytes:,} B")
 
+    runs = lm_contract.attention_engine_runs(text)
+    print("attention engines, fwd / remat / bwd: " + "; ".join(
+        f"{kind} {n['fwd']} / {n['remat']} / {n['bwd']}"
+        for kind, n in runs.items()
+    ))
+
     moves = lm_contract.program_moves(
         text, opcodes=("copy", "dynamic-update-slice")
     )
     for opcode in ("copy", "dynamic-update-slice"):
         rows = defaultdict(lambda: [0, 0])
         for _, shape, size, name in (m for m in moves if m[0] == opcode):
-            row = rows[(shape, direction(name), short(name))]
+            way = lm_contract.op_direction(name) if name else "-"
+            row = rows[(shape, way, short(name))]
             row[0] += size
             row[1] += 1
         total = sum(size for size, _ in rows.values())

@@ -877,6 +877,59 @@ def program_moves(text, opcodes=("copy",), least=16 << 20):
     return found
 
 
+_ENGINE_OP = re.compile(r"/attn/(?:closed_call/)?(while|pallas_call)$")
+_HLO_CALL = re.compile(r" (?:while|custom-call)\(")  # a result may be a tuple
+
+
+def op_direction(op_name: str) -> str:
+    """Which pass of the step an op belongs to, by the transformations its
+    `op_name` carries: `fwd`, `remat` (the forward run again inside a
+    rematerialised layer's backward pass) or `bwd`."""
+    if "rematted_computation" in op_name:
+        return "remat"
+    return "bwd" if "transpose(jvp" in op_name else "fwd"
+
+
+def attention_engine_runs(text):
+    """The attention engines in a compiled program's text, by direction:
+    -> {"loops": {fwd, remat, bwd}, "kernels": {fwd, remat, bwd}}.  Both
+    engines enter the scope `attn` around their `custom_vjp`
+    (`ops/gqa._xla_engine`, `ops/flash_attention.flash_attention`), so an
+    engine's op is one whose `op_name` ENDS in that scope and the
+    primitive: the XLA engine's query loop (a `while`; its key loop, a
+    `while` in that loop's body, is not counted again) or a
+    flash-attention kernel (one forward; a pair, dq and dk/dv, backward).
+    A loop's body is in the text once, whatever its trip count: the
+    window's steps, and the passes of a scanned stack, are one body."""
+    runs = {kind: dict(fwd=0, remat=0, bwd=0) for kind in ("loops", "kernels")}
+    for line in text.splitlines():
+        if not _HLO_CALL.search(line):
+            continue
+        name = _HLO_OP_NAME.search(line)
+        engine = _ENGINE_OP.search(name.group(1)) if name else None
+        if engine is not None:
+            kind = "loops" if engine.group(1) == "while" else "kernels"
+            runs[kind][op_direction(name.group(1))] += 1
+    return runs
+
+
+_OWN_PROGRAM = {}
+
+
+def own_window_program(lm, topo):
+    """-> (`memory_analysis()`, the text) of the cell's window program
+    at the cell's OWN count of sequences, compiled once for the cases
+    that read it (a compile is one to two minutes); the last cell's alone
+    is kept, a text being tens of megabytes."""
+    if lm.cell not in _OWN_PROGRAM:
+        _OWN_PROGRAM.clear()
+        compiled = compile_program(lm, topo, lm.job_flags[1])
+        _OWN_PROGRAM[lm.cell] = (
+            compiled.memory_analysis(), compiled.as_text()
+        )
+    return _OWN_PROGRAM[lm.cell]
+
+
 def test_window_program_compiles_and_fits_for_v5e(
     topo, no_persistent_cache, lm, sequences
 ):
@@ -887,8 +940,11 @@ def test_window_program_compiles_and_fits_for_v5e(
     bytes the descriptor states."""
     want, config = lm.compile, lm.config
     assert next(iter(want.total)) == lm.job_flags[1]  # the cell's own
-    compiled = compile_program(lm, topo, sequences)
-    memory = compiled.memory_analysis()
+    if sequences == lm.job_flags[1]:
+        memory, text = own_window_program(lm, topo)
+    else:
+        compiled = compile_program(lm, topo, sequences)
+        memory, text = compiled.memory_analysis(), compiled.as_text()
     print(lm.cell, "window bytes", sequences, memory.argument_size_in_bytes,
           memory.temp_size_in_bytes, memory.alias_size_in_bytes)
     least, most = want.state
@@ -897,7 +953,6 @@ def test_window_program_compiles_and_fits_for_v5e(
     total = memory.argument_size_in_bytes + memory.temp_size_in_bytes
     least, most = want.total[sequences]
     assert least < total < most, total                   # the chip holds 16
-    text = compiled.as_text()
     for kernel in want.in_text:
         assert kernel in text, kernel
     for kernel in want.not_in_text:
@@ -910,6 +965,24 @@ def test_window_program_compiles_and_fits_for_v5e(
     for size in want.stated_sizes:
         assert size in config["device_bytes"], size
         assert size in config["assumed"]["remat"], size
+
+
+def test_rematerialised_layers_run_no_attention_engine_again(
+    topo, no_persistent_cache, lm
+):
+    """A rematerialised layer KEEPS the attention engine's two results
+    (`lm_common.KEEP_ATTENTION_RESULTS` over the names `ops/gqa.py` gives
+    them), so the cell's window program holds every engine forward and
+    backward and NONE under `rematted_computation`, whichever engine a
+    layer took; what feeds it (the projections, `rotary_pack`) is
+    recomputed as before."""
+    runs = attention_engine_runs(own_window_program(lm, topo)[1])
+    print(lm.cell, "attention engines", runs)
+    loops, kernels = runs["loops"], runs["kernels"]
+    assert loops["fwd"] + kernels["fwd"] > 0, runs
+    assert loops["remat"] == kernels["remat"] == 0, runs
+    assert loops["bwd"] == loops["fwd"], runs
+    assert kernels["bwd"] == 2 * kernels["fwd"], runs  # dq; dk and dv
 
 
 def _lm_window(spec, seed=0):

@@ -161,7 +161,11 @@ SPEC = LMSpec(
     # sets 15.5 as the most this cell may need before it would have to run
     # 4096 tokens.
     compile=CompileSpec(
-        state=(9.26e9, 9.27e9), total={1: (11.6e9, 13.2e9)},
+        # 12.92 GB, 3.65 of them temporaries; 12.15 and 2.89 until PR 52:
+        # the one attention layer keeps 34 MB, and the scheduler's order
+        # of the other nine layers' backward holds 0.77 GB more at its
+        # peak (PERF.md section 7)
+        state=(9.26e9, 9.27e9), total={1: (12.3e9, 13.3e9)},
         # the Mamba-2 layers' passes (`ops/gdn_passes.py`) and their scan
         # (`ops/ssd.py`) in their kernels
         in_text=("conv_silu_fwd", "conv_silu_bwd", "gated_group_norm_fwd",

@@ -180,9 +180,15 @@ SPEC = LMSpec(
     # output's way to `o_proj` and back, PERF.md section 6).
     compile=CompileSpec(
         state=(8.29e9, 8.31e9),
-        total={1: (12.4e9, 13.5e9), 2: (16.0e9, 18.0e9)},
+        # 12.15 GB at the cell's one sequence (12.95 until PR 52); at TWO,
+        # which the chip could not hold (16.81 GB: the slabs of the
+        # engine's second forward at the backward's peak), 12.08 GB
+        # since the layers keep the engine's results
+        total={1: (11.7e9, 12.7e9), 2: (11.6e9, 12.6e9)},
         in_text=("rotary_pack_fwd", "rotary_pack_bwd"),
-        copy_bytes=(2.1e9, 4.6e9),
+        # 1.76 GB and a tenth; 4.18 until PR 52 (2.4 GB of them lay
+        # around the engine's second forward)
+        copy_bytes=(0.88e9, 1.94e9),
     ),
     # a full layer with the dense MLP, a sliding one with experts
     scope_widths=dict(

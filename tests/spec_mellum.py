@@ -192,10 +192,16 @@ SPEC = LMSpec(
     # two.  Top-level copies of 16 MB and more: 2.89 GB a step, 1.61 of it
     # the expert layers' weight-gradient stack (11.79 before PR 43).
     compile=CompileSpec(
-        state=(7.14e9, 7.15e9), total={2: (13.0e9, 14.0e9)},
+        # 12.51 GB, 5.36 of them temporaries (13.42 and 6.28 until PR 52:
+        # the layers keep the engine's results, and its second forward
+        # held more; the configuration's file states PR 42's sizes)
+        state=(7.14e9, 7.15e9), total={2: (12.0e9, 13.0e9)},
         in_text=("rotary_pack_fwd", "rotary_pack_bwd"),
         stated_sizes=("13.94 GB", "6.80 GB"),
-        copy_bytes=(1.45e9, 3.18e9),
+        # 3.42 GB and a tenth; 2.89 until PR 52: each layer's kept `out`
+        # (bfloat16 [2, 32, 8192, 128], 0.13 GB) is laid out once more
+        # on its way into the backward pass
+        copy_bytes=(1.71e9, 3.76e9),
     ),
     # a sliding and a full layer, both with experts
     scope_widths=dict(

@@ -181,7 +181,11 @@ SPEC = LMSpec(
     # through its backward loop; the unrolled loop compiled to 10.54 GB
     # and ran 1.5-2.5% slower, PR 45).  Two sequences a step do not fit.
     compile=CompileSpec(
-        state=(4.0e9, 4.01e9), total={1: (13.3e9, 14.2e9)},
+        # 14.59 GB, 10.59 of them temporaries; 13.78 and 9.77 until PR 52,
+        # what the configuration's file still states: the kernel's
+        # forward holds no large temporaries to give back, so the
+        # program GROWS by what it keeps, 34 MB an application x 24
+        state=(4.0e9, 4.01e9), total={1: (14.1e9, 15.0e9)},
         in_text=("rotary_pack_fwd", "rotary_pack_bwd", "flash_attention"),
         stated_sizes=("13.78 GB", "9.77 GB"),
     ),

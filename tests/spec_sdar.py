@@ -245,7 +245,11 @@ SPEC = LMSpec(
     # GB of the chip's 16 (ISSUE 51 reckoned 13-14), 5.68 of them
     # temporaries.  Two records a step would not.
     compile=CompileSpec(
-        state=(7.74e9, 7.76e9), total={1: (13.0e9, 13.9e9)},
+        # 10.96 GB, 3.21 of them temporaries; 13.43 and 5.68 until PR 52,
+        # what the configuration's file still states (a `benchmark` PR's
+        # to restate): the engine's second forward under `remat` held
+        # more at the backward's peak than the two results kept now
+        state=(7.74e9, 7.76e9), total={1: (10.5e9, 11.4e9)},
         in_text=("rotary_pack_fwd", "rotary_pack_bwd"),
         not_in_text=("flash_attention",),
         stated_sizes=("13.43 GB", "5.68 GB"),

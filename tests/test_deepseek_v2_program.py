@@ -9,6 +9,7 @@ the one that holds it against its reference (`tests/test_deepseek_v2.py`).
 
 from lm_contract import (  # noqa: F401  (the contract's cases, collected here)
     lm, pytest_generate_tests,
+    test_rematerialised_layers_run_no_attention_engine_again,
     test_scopes_are_on_the_op_names_and_leave_outputs_bit_equal,
     test_trainer_carries_the_counters_and_checkpoint_restores_the_logits,
     test_two_task_elasticdl_train_end_to_end,

@@ -86,8 +86,12 @@ def test_equal_head_sizes_trace_the_program_they_traced_before():
     get ONE jaxpr, forward and backward, whatever else the engines learn:
     the digest is of PR 43's tree (jax 0.9.0), where the XLA engine took
     its operands heads first and its blocks in place (before it, PR 31's
-    `330e9ce25b3e684e`); plain-theta `rotary_tables` still traces what it
-    did at PR 31.  A scale given as 1/sqrt(D) traces it too."""
+    `330e9ce25b3e684e`) with, since PR 52, the forward rule's two
+    `checkpoint_name` equations on `out` and `lse` (PR 43's own digest
+    was `4d5ff8ac58522df5`; without those two lines the jaxprs have the
+    same primitives, parameters and result types, line for line);
+    plain-theta `rotary_tables` still traces what it did at PR 31.  A
+    scale given as 1/sqrt(D) traces it too."""
     q = jnp.zeros((2, 256, 4, 32), jnp.float32)
     k = jnp.zeros((2, 256, 2, 32), jnp.float32)
 
@@ -99,7 +103,7 @@ def test_equal_head_sizes_trace_the_program_they_traced_before():
         )
 
     if jax.__version__ == "0.9.0":
-        assert _digest(total(None), q, k, k) == "4d5ff8ac58522df5"
+        assert _digest(total(None), q, k, k) == "75851b953aa597d0"
         assert _digest(
             lambda p: gqa.rotary_tables(p, 64, 1e4), jnp.arange(128)
         ) == "e5b29948198f66e1"
