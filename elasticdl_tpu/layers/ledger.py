@@ -2,8 +2,8 @@
 
 A layer that counts keeps cumulative counters in a mutable collection of
 the model's state (`layers/moe.py`: ``routing``; `layers/loop_exits.py`:
-``exits``; `layers/diffusion_noise.py`: ``noise``), updated inside the step
-program.  The worker reads them where
+``exits``; `layers/diffusion_noise.py`: ``noise``; `layers/delta_gates.py`:
+``gates``), updated inside the step program.  The worker reads them where
 it has already fetched the task's loss (no device sync of its own inside
 a step) and journals the difference to the last reading as ONE span a
 task.  A `TaskLedger` is that reading for one collection: `span` names
@@ -53,8 +53,9 @@ class TaskLedger:
 def task_ledgers() -> list:
     """A fresh ledger of every kind, in the order their spans are
     written."""
+    from elasticdl_tpu.layers.delta_gates import GateLedger
     from elasticdl_tpu.layers.diffusion_noise import NoiseLedger
     from elasticdl_tpu.layers.loop_exits import ExitLedger
     from elasticdl_tpu.layers.moe import RoutingLedger
 
-    return [RoutingLedger(), ExitLedger(), NoiseLedger()]
+    return [RoutingLedger(), ExitLedger(), NoiseLedger(), GateLedger()]

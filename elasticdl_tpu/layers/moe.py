@@ -23,6 +23,15 @@ computes; the two are independent:
     w = s at the chosen; with `norm_topk_prob` divided by their sum;
     times `routed_scale`
 
+    `n_group` > 1: GROUP-LIMITED selection, either score function.  The
+    experts are `n_group` runs of consecutive experts (in a deployment a
+    group is the experts of a few chips, and a token's pairs then reach
+    `topk_group` groups of chips, not all).  With the selection scores
+    (s + b, or p): a group's score is the sum of its two largest; the
+    `topk_group` best groups; the top-k inside them alone.  The weights
+    are as above, of the chosen.  1 / 1: the top-k over all experts, the
+    program it was before the fields existed.
+
     expert_form="gated_silu"    three products
     y = sum_k w_k * W_down,k (silu(W_gate,k x) * W_up,k x)   held k only
       + [sigmoid(w_sg . x) *] shared(x)     shared: the same gated form,
@@ -117,7 +126,10 @@ Counters (the ``routing`` collection, cumulative, uint32, updated only
 where the collection is mutable, i.e. in training): pairs routed to held
 experts, rows the loop processed, the loop's trips (`blocks`), the load
 of each held expert, and, not cumulative, the `block_rows` the layer last
-ran with.  The worker journals their per-task differences as
+ran with; a layer with `n_group` > 1 also counts its tokens and the
+distinct groups each token's choices fell in (`group_tokens`, `groups`)
+and keeps, not cumulative, its `topk_group` (`group_limit`), and the
+ledger REFUSES a task whose tokens reached more groups than that.  The worker journals their per-task differences as
 ``moe.routing``: `pairs / (blocks x block_rows)` is the fill of the
 blocks, what the padding cost.  A state restored from a checkpoint that
 has no `blocks` or `block_rows` gets them at zero (`with_absent_counters`).
@@ -435,6 +447,10 @@ class SparseMoeBlock(nn.Module):
     shared_gated: Optional[bool] = None
     # > 0: the softmax router's sequence-wise balancing loss, times this.
     balance_alpha: float = 0.0
+    # Group-limited selection (module docstring): the experts as `n_group`
+    # runs, a token's choices within its `topk_group` best; 1 / 1: none.
+    n_group: int = 1
+    topk_group: int = 1
 
     @nn.compact
     def __call__(self, x):
@@ -455,6 +471,17 @@ class SparseMoeBlock(nn.Module):
         )
         if self.balance_alpha and not softmax:
             raise ValueError("the balancing loss is a softmax router's")
+        group = self.num_experts // max(self.n_group, 1)
+        if self.n_group > 1 and not (
+            self.num_experts % self.n_group == 0 and group >= 2
+            and 1 <= self.topk_group <= self.n_group
+            and self.top_k <= self.topk_group * group
+        ):
+            raise ValueError(
+                f"n_group={self.n_group}, topk_group={self.topk_group}: no "
+                f"groups of {self.num_experts} experts for a top "
+                f"{self.top_k}"
+            )
         shape, d = x.shape, x.shape[-1]
         x = x.reshape(-1, d)
         block = self.block_rows or block_rows_for(
@@ -488,7 +515,9 @@ class SparseMoeBlock(nn.Module):
             balance = None
             if softmax:
                 scores = jax.nn.softmax(logits, axis=-1)
-                weight, expert = jax.lax.top_k(scores, self.top_k)
+                weight, expert = jax.lax.top_k(
+                    self._within_groups(scores), self.top_k
+                )
                 if self.balance_alpha:
                     balance = self.balance_alpha * sequence_balance_loss(
                         scores, expert, shape[0] if len(shape) == 3 else 1
@@ -496,7 +525,9 @@ class SparseMoeBlock(nn.Module):
                     weight = _with_auxiliary_loss(weight, balance)
             else:
                 scores = jax.nn.sigmoid(logits)
-                _, expert = jax.lax.top_k(scores + select_bias, self.top_k)
+                _, expert = jax.lax.top_k(
+                    self._within_groups(scores + select_bias), self.top_k
+                )
                 weight = jnp.take_along_axis(scores, expert, axis=-1)
                 chosen = jnp.bincount(
                     expert.reshape(-1), length=self.num_experts
@@ -533,10 +564,26 @@ class SparseMoeBlock(nn.Module):
                         preferred_element_type=jnp.float32,
                     )) * shared
                 y = y + shared
-        self._count(is_held, rows, plan, block, balance)
+        self._count(is_held, rows, plan, block, balance, expert)
         return y.reshape(shape)
 
-    def _count(self, is_held, rows, plan, block: int, balance) -> None:
+    def _within_groups(self, selection):
+        """The selection scores [N, E] with every expert outside the
+        token's `topk_group` best groups at -inf (a group's score: the
+        sum of its two largest); as they are where `n_group` is 1."""
+        if self.n_group <= 1:
+            return selection
+        n = selection.shape[0]
+        grouped = selection.reshape(n, self.n_group, -1)
+        best_two, _ = jax.lax.top_k(grouped, 2)
+        _, groups = jax.lax.top_k(jnp.sum(best_two, axis=-1), self.topk_group)
+        allowed = jnp.any(
+            groups[:, :, None] == jnp.arange(self.n_group), axis=1
+        )
+        return jnp.where(allowed[:, :, None], grouped, -jnp.inf).reshape(n, -1)
+
+    def _count(self, is_held, rows, plan, block: int, balance,
+               expert) -> None:
         zero = lambda *s: jnp.zeros(s, jnp.uint32)  # noqa: E731
         pairs = self.variable(ROUTING_COLLECTION, "pairs", zero)
         processed = self.variable(ROUTING_COLLECTION, "processed", zero)
@@ -556,6 +603,20 @@ class SparseMoeBlock(nn.Module):
             )
             block_rows.value = jnp.uint32(block)
             load.value = load.value + plan["counts"].astype(jnp.uint32)
+        if self.n_group > 1:  # only a layer that selects within groups
+            tokens = self.variable(ROUTING_COLLECTION, "group_tokens", zero)
+            groups = self.variable(ROUTING_COLLECTION, "groups", zero)
+            limit = self.variable(ROUTING_COLLECTION, "group_limit", zero)
+            if counting:
+                reached = jnp.any(
+                    (expert // (self.num_experts // self.n_group))[:, :, None]
+                    == jnp.arange(self.n_group), axis=1,
+                )
+                tokens.value = tokens.value + jnp.uint32(expert.shape[0])
+                groups.value = groups.value + jnp.sum(reached).astype(
+                    jnp.uint32
+                )
+                limit.value = jnp.uint32(self.topk_group)
         if balance is not None:  # only a layer that is told an alpha
             total = self.variable(
                 ROUTING_COLLECTION, "balance",
@@ -610,6 +671,8 @@ class RoutingLedger(TaskLedger):
                 ("pairs", np.uint32), ("processed", np.uint32),
                 ("blocks", np.uint32), ("block_rows", np.uint32),
                 ("load", np.uint32), ("balance", np.float32),
+                ("group_tokens", np.uint32), ("groups", np.uint32),
+                ("group_limit", np.uint32),
             )
             if layers[0] + (key,) in flat
         }
@@ -629,6 +692,13 @@ class RoutingLedger(TaskLedger):
             "load_max": int(load.max()),
             "load_mean": float(load.mean()),
         }
+        if "groups" in now:  # distinct groups a token's choices fell in
+            tokens, groups = (
+                (now[key] - seen[key]).astype("int64").sum()
+                for key in ("group_tokens", "groups")
+            )
+            fields["groups_mean"] = float(groups) / max(int(tokens), 1)
+            fields["group_limit"] = int(now["group_limit"].max())
         if "balance" in now:  # the mean over the layers and the steps
             fields["balance_loss"] = float(
                 np.mean(now["balance"] - seen["balance"]) / max(steps, 1)
@@ -640,5 +710,11 @@ class RoutingLedger(TaskLedger):
             return (
                 f"the expert layers dropped {fields['dropped']} routed "
                 "pair(s): they are built to drop none"
+            )
+        if fields.get("groups_mean", 0) > fields.get("group_limit", 0):
+            return (
+                f"a token's choices fell in {fields['groups_mean']:.2f} "
+                f"groups on average, more than the {fields['group_limit']} "
+                "its router may reach"
             )
         return None

@@ -191,8 +191,11 @@ SPAN_NAMES: Dict[str, str] = {
                    "the expert loop's trips (`blocks`) and the rows of "
                    "one (`block_rows`: pairs / (blocks x block_rows) is "
                    "the blocks' fill), largest and mean load of a held "
-                   "expert, and the mean balancing loss where the routers "
-                   "have one (`balance_loss`)",
+                   "expert, the mean balancing loss where the routers "
+                   "have one (`balance_loss`), and where they select "
+                   "within groups the mean number of distinct groups a "
+                   "token's choices fell in (`groups_mean`, at most "
+                   "`group_limit`)",
     # exits of a looped stack (layers/loop_exits.py): the same route
     "loop.exits": "after: worker, one a task of a model whose stack is "
                   "applied several times: the `tokens` its steps "
@@ -204,6 +207,13 @@ SPAN_NAMES: Dict[str, str] = {
                        "masked diffusion: the `tokens` its steps saw, how "
                        "many of them the records' noise `masked`, and the "
                        "mean noise level `t_mean` of its sequences",
+    # gates of a delta rule with a decay a key channel
+    # (layers/delta_gates.py): the same route
+    "kda.gates": "after: worker, one a task of a model with such "
+                 "`layers`: the mean `retention` exp(g) of their state's "
+                 "rows a token, the mean write strength `beta`, and the "
+                 "share of gate values within 1% of the bound "
+                 "(`at_bound_share`)",
 }
 
 #: ``jax.named_scope`` names on device ops (op metadata only; they show
@@ -222,7 +232,9 @@ SPAN_NAMES: Dict[str, str] = {
 #: (attn_proj, attn_rotary, attn_full), mlp, block_norm), lm_head_loss,
 #: exit_gate).  With the block-diffusion stack (model_zoo/sdar): fwd_bwd >
 #: (attn > (attn_proj, attn_rotary, attn_blockdiff), moe > (...),
-#: lm_head_loss).
+#: lm_head_loss).  With the delta-attention hybrid (model_zoo/ling):
+#: fwd_bwd > (kda > (kda_mix, kda_gate, kda_scan), attn > (mla_latent,
+#: mla_core, attn_gate), mlp, moe > (...), lm_head_loss).
 DEVICE_SCOPES = (
     "fwd_bwd", "dense_update", "sparse_apply", "grad_accumulate",
     "sparse_adam", "attn", "mlp", "lm_head_loss", "optimizer",
@@ -230,6 +242,7 @@ DEVICE_SCOPES = (
     "ssm", "ssm_scan", "gdn_mix", "mla_latent", "mla_core",
     "attn_full", "attn_window", "attn_gate", "attn_proj", "attn_rotary",
     "loop", "block_norm", "exit_gate", "attn_blockdiff",
+    "kda", "kda_mix", "kda_gate", "kda_scan",
 )
 
 #: Size bound on the flight recorder's final registry snapshot: the

@@ -97,6 +97,27 @@ rule (the model's `gdn_scan`) reaches both passes of both engines, the
 `custom_vjp`'s backward kernel too (`.../transpose(jvp(gdn_scan))/
 jit(_backward_call)/delta_rule_bwd` in the compiled program's metadata).
 
+**A decay a KEY CHANNEL.**  `g` of [B, T, H, Dk] in place of [B, T, H]
+(the shape decides; no flag) is one rate a row of the state,
+``S <- diag(exp(g_t)) S``: Kimi Delta Attention's rule
+(arXiv:2510.26692; `model_zoo/ling`).  The WY form stands, with the decay
+INSIDE the contractions, ``M[i, j] = beta_i sum_d k_i[d] k_j[d]
+exp(G_i[d] - G_j[d])``, which no [C, C] factor laid over a finished
+product gives: `_decayed_inside` factors it around a reference row a
+sub-chunk of `SUB` rows and relies, on the diagonal sub-blocks, on the
+caller's gate being BOUNDED below (`ops/gdn_passes.decay_gate`).  The
+XLA engine alone computes it (`_chunk_group_channels`, `GROUP_CHANNELS`
+chunks a scan step, the state's walk one product a chunk and everything
+else a product over the group); the kernels walk
+a head's scalar, so `_engine` is told that they do not take it.  Its
+triangular systems are inverted BY BLOCKS (`_unit_lower_inverse_by_blocks`):
+the finite product over a whole chunk loses every digit in float32 where
+a chunk's keys are alike, which seeded layers behind a common stream
+are (PERF.md section 6, PR 53: gradients of 1e1 at single records, then
+NaN at step 19 on the chip).  The scalar path keeps the product it had,
+bit for bit (a test holds its programs to recorded texts), and with it
+that weakness (PERF.md section 7).
+
 Shapes: q, k [B, T, Hk, Dk]; v [B, T, H, Dv]; g, beta [B, T, H], with
 Hk = H or a divisor of it (key head i then serves value heads
 i H / Hk .. (i + 1) H / Hk - 1); or, through
@@ -123,19 +144,26 @@ from elasticdl_tpu.ops.flash_attention import _use_interpret
 logger = get_logger("ops.gated_delta")
 
 CHUNK = 64
+SUB = 16   # rows of a chunk's sub-chunk under a decay a key channel
+BASE = 8   # rows of the blocks a system's inverse is joined from
 GROUP = 8  # chunks a step of the XLA engine's scan
+GROUP_CHANNELS = 16  # the same under a decay a key channel
 EVERY = 8  # chunks between two states the Pallas forward saves
 PRECISION = jax.lax.Precision.HIGH
 
 
 def gated_delta_rule_recurrent(q, k, v, g, beta):
-    """The recurrence itself, one token a step.  -> (o [B,T,H,Dv], S)."""
+    """The recurrence itself, one token a step.  -> (o [B,T,H,Dv], S).
+    `g` [B,T,H], one log-decay a head, or [B,T,H,Dk], one a key channel
+    (a row of the state each)."""
     q, k, v, g, beta = (x.astype(jnp.float32) for x in (q, k, v, g, beta))
     b, _, h, dk = k.shape
+    # a head's decay over [Dk, Dv], or a key channel's over its row [Dv]
+    over = (None,) * (2 if g.ndim == beta.ndim else 1)
 
     def step(state, xs):
         q_t, k_t, v_t, g_t, beta_t = xs          # [B,H,D] / [B,H]
-        state = state * jnp.exp(g_t)[..., None, None]
+        state = state * jnp.exp(g_t)[(...,) + over]
         delta = beta_t[..., None] * (
             v_t - jnp.einsum("bhkv,bhk->bhv", state, k_t)
         )
@@ -148,17 +176,49 @@ def gated_delta_rule_recurrent(q, k, v, g, beta):
     return jnp.moveaxis(out, 0, 1), state
 
 
-def _unit_lower_inverse(m):
-    """(I + M)^-1 for strictly lower triangular M [..., C, C]."""
+def _unit_lower_inverse(m, nilpotent=None):
+    """(I + M)^-1 for strictly lower triangular M [..., C, C]; `nilpotent`:
+    a power of two at which M's powers are known to vanish, where that is
+    less than C (a block-diagonal M)."""
     c = m.shape[-1]
     eye = jnp.eye(c, dtype=m.dtype)
     power = -m
     inverse = eye + power
     span = 2
-    while span < c:
+    while span < (nilpotent or c):
         power = jnp.matmul(power, power, precision=PRECISION)
         inverse = jnp.matmul(inverse, eye + power, precision=PRECISION)
         span *= 2
+    return inverse
+
+
+def _unit_lower_inverse_by_blocks(m):
+    """(I + M)^-1 for strictly lower triangular M [..., C, C], taken BY
+    BLOCKS: the finite product of `_unit_lower_inverse` on the diagonal
+    blocks of `BASE` rows alone, then pairs of blocks joined,
+    [[A, 0], [X, B]]^-1 = [[A^-1, 0], [-B^-1 X A^-1, B^-1]], from `BASE`
+    rows to C.  The product over the WHOLE chunk goes through M^32, whose
+    entries reach C(62, 31) ~ 5e17 where a chunk's keys are alike
+    (M near beta times all ones under the diagonal), and the inverse's
+    entries of size 1 are what is left when those cancel: in float32
+    nothing is.  By blocks no intermediate exceeds the inverse's own
+    entries by more than C(6, 3) = 20.  The same ten [C, C] products: the
+    blocks are masks of whole matrices."""
+    c = m.shape[-1]
+    block = jnp.arange(c)
+
+    def same(size):
+        return (block[:, None] // size) == (block[None, :] // size)
+
+    inverse = _unit_lower_inverse(jnp.where(same(BASE), m, 0.0), BASE)
+    size = BASE
+    while size < c:
+        joined = jnp.where(same(2 * size) & ~same(size), m, 0.0)
+        inverse = inverse - jnp.matmul(
+            inverse, jnp.matmul(joined, inverse, precision=PRECISION),
+            precision=PRECISION,
+        )
+        size *= 2
     return inverse
 
 
@@ -205,6 +265,110 @@ def _chunk_group(state, xs):
     return state, jnp.stack(outs, axis=2)                 # [B,H,G,C,Dv]
 
 
+def _decayed_inside(rows, k, g_cum):
+    """P[i, j] = sum_d x_i[d] k_j[d] exp(G_i[d] - G_j[d]) for j <= i and
+    0 above the diagonal, for each x of `rows` (k itself, then q): the
+    decay of a KEY CHANNEL sits inside the contraction, so it cannot be
+    laid over a finished [C, C] product as `_chunk_group` lays a head's.
+    It factors as (x_i e^(G_i - R)) . (k_j e^(R - G_j)) for any reference
+    row R, and the second factor grows without bound unless R is near j,
+    so the chunk's ROWS are taken in sub-chunks of `SUB`, R the first row
+    of the sub-chunk the row i lies in: a row's factor is then at most 1, a
+    column's before the sub-chunk at most 1 too, and a column's inside it
+    at most e^((SUB - 1) |bound|) where the caller's gate keeps g above
+    `bound` (16 rows at -5 a token: e^75, a float32; a whole chunk of 64
+    would not be).  The sub-chunks are one batched product against ALL of
+    the chunk's columns (a [C, C] product's worth of work, in one op and
+    not C / SUB of growing width); a column behind a row's sub-chunk lies
+    above the diagonal, so its factor is set to 1, which keeps it finite
+    in both passes, and the product there is masked.
+    rows, k, g_cum [..., C, Dk] -> [..., C, C] each."""
+    lead, dk = k.shape[:-2], k.shape[-1]
+    subs = CHUNK // SUB
+    by_sub = g_cum.reshape(lead + (subs, SUB, dk))
+    reference = by_sub[..., :1, :]                        # [..., S, 1, Dk]
+    into = jnp.exp(by_sub - reference)                    # [..., S, SUB, Dk]
+    index = jnp.arange(CHUNK)
+    behind = index[None, :, None] >= (
+        (jnp.arange(subs)[:, None, None] + 1) * SUB
+    )                                                     # [S, C, 1]
+    out_of = k[..., None, :, :] * jnp.exp(jnp.where(
+        behind, 0.0, reference - g_cum[..., None, :, :]
+    ))                                                    # [..., S, C, Dk]
+    lower = index[:, None] >= index[None, :]
+    return [
+        jnp.where(
+            lower,
+            jnp.einsum(
+                "...sik,...sjk->...sij",
+                x.reshape(lead + (subs, SUB, dk)) * into, out_of,
+                precision=PRECISION,
+            ).reshape(lead + (CHUNK, CHUNK)),
+            0.0,
+        )
+        for x in rows
+    ]
+
+
+@jax.checkpoint
+def _chunk_group_channels(state, xs):
+    """`_chunk_group` where the decay is one rate a KEY CHANNEL: g
+    [B, H, G, C, Dk].  The triangular systems are `_chunk_group`'s; what
+    differs is where the decay sits: inside the two [C, C] products
+    (`_decayed_inside`), and as a factor a channel on q, k and the state's
+    rows; the system's inverse is taken by blocks
+    (`_unit_lower_inverse_by_blocks`); and the state's walk is ONE product
+    a chunk: ``S <- S exp(G_C) + k_tail^T (u - w S)`` is
+    ``S <- (diag(exp(G_C)) - k_tail^T w) S + k_tail^T u``, whose two
+    coefficients are products over the whole group at once, as are the
+    outputs once every chunk's starting state is known.  A step of the
+    scan is then some sixty ops whatever the group's size and two more a
+    chunk, where the walk of `_chunk_group` is six a chunk.  That, the
+    sub-chunks' products as one and `GROUP_CHANNELS` 16 are the form that
+    is CHEAP TO TRACE, not the fast one: at 1 x 8192 tokens and 32 heads a
+    layer's forward and backward are 5.2k device ops where 8 chunks a
+    step with the six-op walk are 14.4k, and 57 ms where those are 41
+    (PERF.md section 6, PR 53 (4): a profiler's stop goes by device
+    events, and the cell that runs this traces inside its window)."""
+    q, k, v, g, beta = xs
+    repeat = v.shape[1] // k.shape[1]
+    if repeat > 1:  # each key head serves `repeat` value heads
+        q, k = (jnp.repeat(x, repeat, axis=1) for x in (q, k))
+    g_cum = jnp.cumsum(g, axis=-2)
+    kk, qk = _decayed_inside((k, q), k, g_cum)
+    index = jnp.arange(CHUNK)
+    m = jnp.where(
+        index[:, None] > index[None, :], kk * beta[..., None], 0.0
+    )
+    t_m = _unit_lower_inverse_by_blocks(m)
+    since_start = jnp.exp(g_cum)
+    u = jnp.matmul(t_m, v * beta[..., None], precision=PRECISION)
+    w = jnp.matmul(
+        t_m, k * beta[..., None] * since_start, precision=PRECISION
+    )
+    g_last = g_cum[..., -1:, :]
+    k_tail = k * jnp.exp(g_last - g_cum)
+    carry_decay = jnp.exp(jnp.swapaxes(g_last, -1, -2))   # [B,H,G,Dk,1]
+    keep = carry_decay * jnp.eye(k.shape[-1], dtype=k.dtype) - jnp.einsum(
+        "...ck,...cj->...kj", k_tail, w, precision=PRECISION
+    )                                                     # [B,H,G,Dk,Dk]
+    write = jnp.einsum(
+        "...ck,...cv->...kv", k_tail, u, precision=PRECISION
+    )                                                     # [B,H,G,Dk,Dv]
+    starts = []
+    for i in range(q.shape[2]):
+        starts.append(state)
+        state = jnp.matmul(
+            keep[:, :, i], state, precision=PRECISION
+        ) + write[:, :, i]
+    starts = jnp.stack(starts, axis=2)                    # [B,H,G,Dk,Dv]
+    v_new = u - jnp.matmul(w, starts, precision=PRECISION)
+    out = jnp.matmul(
+        q * since_start, starts, precision=PRECISION
+    ) + jnp.matmul(qk, v_new, precision=PRECISION)
+    return state, out                                     # [B,H,G,C,Dv]
+
+
 def _engine(supported, mesh,
             unsupported="head sizes or counts the kernels do not take"):
     """-> ("pallas" or "xla", why, for the log).  The kernels run where
@@ -243,13 +407,18 @@ def chunk_gated_delta_rule(q, k, v, g, beta, mesh=None):
 
 def chunk_gated_delta_rule_rows(q, k, v, g, beta, hk, mesh=None):
     """`chunk_gated_delta_rule` of head-major rows, the layout the
-    kernels read and write: q, k [B, T, Hk Dk], v [B, T, Hv Dv] (g and
-    beta [B, T, Hv] say how many value heads) -> (o [B, T, Hv Dv],
-    final S).  A layer that holds its tensors as rows goes through no
-    [B, T, H, D] on the way in or out."""
-    b, t, h = g.shape
+    kernels read and write: q, k [B, T, Hk Dk], v [B, T, Hv Dv] (beta
+    [B, T, Hv] says how many value heads; g [B, T, Hv], or
+    [B, T, Hv, Dk] for one rate a key channel, which the XLA engine
+    alone computes) -> (o [B, T, Hv Dv], final S).  A layer that holds
+    its tensors as rows goes through no [B, T, H, D] on the way in or
+    out."""
+    b, t, h = beta.shape
     dk, dv = k.shape[-1] // hk, v.shape[-1] // h
-    engine, why = _engine(supports(dk, dv, hk, h), mesh)
+    if g.ndim == beta.ndim:
+        engine, why = _engine(supports(dk, dv, hk, h), mesh)
+    else:  # one rate a key channel: the kernels walk a head's scalar
+        engine, why = _engine(False, mesh, "a decay a key channel")
     logger.info(
         "delta rule engine: %s chunk_gated_delta_rule T=%d Dk=%d Dv=%d (%s)",
         engine, t, dk, dv, why,
@@ -275,13 +444,16 @@ def _padded(xs, t, chunks):
 
 
 def chunk_gated_delta_rule_xla(q, k, v, g, beta):
-    """`chunk_gated_delta_rule` in XLA ops: a `lax.scan` over groups."""
+    """`chunk_gated_delta_rule` in XLA ops: a `lax.scan` over groups; g
+    [B, T, H], or [B, T, H, Dk] for one rate a key channel (the shape
+    decides: `_chunk_group_channels`)."""
     out_dtype = v.dtype
     q, k, v, g, beta = (x.astype(jnp.float32) for x in (q, k, v, g, beta))
     b, t, h, dv = v.shape
     dk = k.shape[-1]
     n = -(-t // CHUNK)
-    group = min(GROUP, n)
+    channels = g.ndim > beta.ndim
+    group = min(GROUP_CHANNELS if channels else GROUP, n)
     steps = -(-n // group)
     q, k, v, g, beta = _padded((q, k, v, g, beta), t, steps * group)
 
@@ -291,7 +463,8 @@ def chunk_gated_delta_rule_xla(q, k, v, g, beta):
 
     state0 = jnp.zeros((b, h, dk, dv), jnp.float32)
     state, out = jax.lax.scan(
-        _chunk_group, state0, tuple(map(grouped, (q, k, v, g, beta)))
+        _chunk_group_channels if channels else _chunk_group,
+        state0, tuple(map(grouped, (q, k, v, g, beta))),
     )
     # [steps,B,H,G,C,Dv] -> [B,T,H,Dv]
     out = jnp.moveaxis(jnp.moveaxis(out, 0, 1), 2, 4).reshape(

@@ -22,7 +22,13 @@ The passes:
   of either layer (rows, taps, bias).
 - `gated_rms_norm` (DeltaNet): o rows and gate rows z in float32 -> per
   HEAD ``weight (o rsqrt(mean o^2 + eps)) silu(z)`` -> rows in `dtype`;
-  the weight is one head's, [D].
+  the weight is one head's, [D].  The gate's form follows its shape and
+  `activation`: a column's silu (Qwen3-Next; the kernels') or ONE value a
+  head through a sigmoid (`model_zoo/ling` `KimiDeltaAttention`; the XLA
+  chain).
+- `decay_gate` (a delta rule whose decay is one rate a key channel): the
+  projection's rows -> ``bound sigmoid(exp(A_log) (rows + dt_bias))``, a
+  log-decay in (bound, 0); float32, the XLA chain alone.
 - `gated_group_norm` (Mamba-2): y, x, z rows in float32, a skip a head
   and a weight a column -> ``weight GroupRMS((y + skip x) silu(z))`` over
   G GROUPS of W / G columns -> rows in `dtype`.  Not the norm above with
@@ -106,18 +112,24 @@ def conv_silu_xla(rows, taps, bias=None, *, head=0, scale=1.0, eps=1e-6):
     return (heads * scale).reshape(b, t, width)
 
 
-def gated_rms_norm_xla(rows, gate, weight, *, eps=1e-6, dtype=jnp.float32):
-    """`gated_rms_norm` in XLA ops."""
+#: What a norm's gate passes through before it multiplies.
+GATE_ACTIVATIONS = {"silu": jax.nn.silu, "sigmoid": jax.nn.sigmoid}
+
+
+def gated_rms_norm_xla(rows, gate, weight, *, eps=1e-6, dtype=jnp.float32,
+                       activation="silu"):
+    """`gated_rms_norm` in XLA ops; the gate one value a column
+    [B, T, H D] or one a head [B, T, H]."""
     b, t, width = rows.shape
     head = weight.shape[0]
     heads = rows.astype(jnp.float32).reshape(b, t, width // head, head)
     heads = heads * jax.lax.rsqrt(
         jnp.mean(heads * heads, axis=-1, keepdims=True) + eps
     )
-    gate = gate.astype(jnp.float32).reshape(heads.shape)
-    return (weight * heads * jax.nn.silu(gate)).reshape(b, t, width).astype(
-        dtype
-    )
+    gate = gate.astype(jnp.float32).reshape(b, t, width // head, -1)
+    return (
+        weight * heads * GATE_ACTIVATIONS[activation](gate)
+    ).reshape(b, t, width).astype(dtype)
 
 
 def gated_group_norm_xla(y, x, z, skip, weight, *, groups, eps=1e-6,
@@ -571,11 +583,18 @@ _gated_rms_norm.defvjp(_gated_rms_norm_fwd, _gated_rms_norm_bwd)
 
 
 def gated_rms_norm(rows, gate, weight, *, eps=1e-6, dtype=jnp.float32,
-                   pallas=False, interpret=None, mesh=None):
-    """rows, gate [B, T, H D] float32, weight [D] -> per head
-    weight (rows rsqrt(mean rows^2 + eps)) silu(gate), in `dtype`."""
-    if not pallas:
-        return gated_rms_norm_xla(rows, gate, weight, eps=eps, dtype=dtype)
+                   activation="silu", pallas=False, interpret=None,
+                   mesh=None):
+    """rows [B, T, H D] float32, weight [D] -> per head
+    weight (rows rsqrt(mean rows^2 + eps)) act(gate), in `dtype`.  The
+    gate's form is its shape's and `activation`'s: one value a column
+    [B, T, H D] or one a head [B, T, H]; "silu" or "sigmoid".  The
+    kernels compute silu of a column's gate; any other form is the XLA
+    chain whatever `pallas` says."""
+    if not pallas or activation != "silu" or gate.shape != rows.shape:
+        return gated_rms_norm_xla(
+            rows, gate, weight, eps=eps, dtype=dtype, activation=activation
+        )
     return _gated_rms_norm(
         rows.astype(jnp.float32), gate.astype(jnp.float32),
         weight.astype(jnp.float32).reshape(1, -1),
@@ -583,6 +602,22 @@ def gated_rms_norm(rows, gate, weight, *, eps=1e-6, dtype=jnp.float32,
          _use_interpret() if interpret is None else interpret,
          gated_delta._several(mesh)),
     )
+
+
+def decay_gate(rows, a_log, dt_bias, *, bound):
+    """A delta rule's log-decay, one a key channel, BOUNDED below: rows
+    [B, T, H Dk] (the decay's projection), a_log [H], dt_bias [H Dk] ->
+    ``bound sigmoid(exp(a_log)[h] (rows + dt_bias))`` in (bound, 0) for a
+    `bound` < 0, float32 rows.  The bound is what lets the chunked rule
+    take a sub-chunk's decays apart (`ops/gated_delta._decayed_inside`).
+    One elementwise chain, which XLA fuses into the projection's
+    consumer: the plain `jax.numpy` chain is all there is of it."""
+    b, t, width = rows.shape
+    rate = jnp.exp(a_log.astype(jnp.float32))[:, None]
+    pre = (rows.astype(jnp.float32) + dt_bias).reshape(
+        b, t, a_log.shape[0], -1
+    )
+    return (bound * jax.nn.sigmoid(rate * pre)).reshape(b, t, width)
 
 
 # ----------------------------------------------------------------------

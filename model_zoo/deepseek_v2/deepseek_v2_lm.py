@@ -14,8 +14,8 @@ the DeepSeek-V2 report, arXiv:2405.04434).  Layer i of the stack is::
 RMSNorm (`y = w x rsqrt(mean(x^2) + eps)`, w from 1), a final norm, an
 untied output head, no bias anywhere.
 
-- `self_attn` (`LatentAttention`; `q_lora_rank` null: no latent for the
-  queries): `q = q_proj(u)` [H heads of `qk_nope_head_dim` +
+- `self_attn` (`lm_common.LatentAttention`, which Ling's stack shares;
+  `q_lora_rank` null: no latent for the queries): `q = q_proj(u)` [H heads of `qk_nope_head_dim` +
   `qk_rope_head_dim`]; `[c | k_pe] = kv_a_proj_with_mqa(u)`
   [`kv_lora_rank` | `qk_rope_head_dim`]; `c = kv_a_layernorm(c)`;
   `[k_nope | v] = kv_b_proj(c)` [H heads of `qk_nope_head_dim` |
@@ -92,8 +92,9 @@ from elasticdl_tpu.ops import gqa
 # cross-entropy over float32 logits (under the `lm_head_loss` scope),
 # perplexity and accuracy.
 from model_zoo.lm_common import (  # noqa: F401
-    KEEP_ATTENTION_RESULTS, VOCAB, RMSNorm, custom_data_reader, dataset_fn,
-    dense, eval_metrics_fn, loss, warmup_adamw,
+    KEEP_ATTENTION_RESULTS, VOCAB, LatentAttention, RMSNorm,
+    custom_data_reader, dataset_fn, dense, eval_metrics_fn, loss,
+    warmup_adamw,
 )
 
 
@@ -123,50 +124,15 @@ def _rotary_tables(cfg, t: int):
     )
 
 
-class LatentAttention(nn.Module):
-    cfg: Any  # DeepseekV2Config
-
-    @nn.compact
-    def __call__(self, x):
-        c = self.cfg
-        b, t, d = x.shape
-        h, nope, rope, dv = (c.num_attention_heads, c.qk_nope_head_dim,
-                             c.qk_rope_head_dim, c.v_head_dim)
-        q = dense(h * (nope + rope), c.dtype, "q_proj")(x).reshape(
-            b, t, h, nope + rope
-        )
-        with jax.named_scope("mla_latent"):
-            latent = dense(
-                c.kv_lora_rank + rope, c.dtype, "kv_a_proj_with_mqa"
-            )(x)
-            k_pe = latent[..., c.kv_lora_rank:].reshape(b, t, 1, rope)
-            latent = RMSNorm(c.rms_norm_eps, name="kv_a_layernorm")(
-                latent[..., :c.kv_lora_rank]
-            )
-            kv = dense(h * (nope + dv), c.dtype, "kv_b_proj")(latent).reshape(
-                b, t, h, nope + dv
-            )
-            cos, sin = _rotary_tables(c, t)
-            q = jnp.concatenate([
-                q[..., :nope].astype(c.dtype),
-                gqa.apply_rotary(q[..., nope:], cos, sin).astype(c.dtype),
-            ], axis=-1)
-            # The one rotated key part a token, given to every head.
-            k = jnp.concatenate([
-                kv[..., :nope].astype(c.dtype),
-                jnp.broadcast_to(
-                    gqa.apply_rotary(k_pe, cos, sin).astype(c.dtype),
-                    (b, t, h, rope),
-                ),
-            ], axis=-1)
-            v = kv[..., nope:].astype(c.dtype)
-        with jax.named_scope("mla_core"):
-            out = gqa.causal_attention(
-                q, k, v, scale=softmax_scale(c), impl=c.attn_impl
-            )
-        return dense(d, c.dtype, "o_proj")(
-            out.reshape(b, t, h * dv).astype(c.dtype)
-        )
+def latent_attention(cfg, t: int, **module):
+    """-> (`lm_common.LatentAttention` at this configuration's sizes and
+    softmax scale, with neither head norms nor a gate; its rotary tables
+    (cos, sin) for `t` positions)."""
+    return LatentAttention(
+        cfg.num_attention_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+        cfg.v_head_dim, cfg.kv_lora_rank, cfg.rms_norm_eps, cfg.dtype,
+        softmax_scale(cfg), cfg.attn_impl, **module,
+    ), _rotary_tables(cfg, t)
 
 
 class DecoderLayer(nn.Module):
@@ -178,7 +144,11 @@ class DecoderLayer(nn.Module):
         c = self.cfg
         with jax.named_scope("attn"):
             h = RMSNorm(c.rms_norm_eps, name="input_layernorm")(x)
-            x = x + LatentAttention(c, name="self_attn")(h)
+            with jax.named_scope("mla_latent"):
+                attention, tables = latent_attention(
+                    c, x.shape[1], name="self_attn"
+                )
+            x = x + attention(h, *tables)
         with jax.named_scope("mlp" if self.dense else "moe"):
             h = RMSNorm(c.rms_norm_eps, name="post_attention_layernorm")(x)
             if self.dense:

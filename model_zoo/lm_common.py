@@ -39,6 +39,9 @@ every cell whose stack uses the piece:
 - `listed` and `check_listed`, a source's per-layer lists as a job's flat
   flags carry them and the check that they cover the stack: Laguna and
   Mellum 2 (SDAR: `listed` for its `mlp_only_layers`);
+- `LatentAttention` (MLA without a latent for the queries): DeepSeek-V2
+  as it is, Ling with a head-wise output gate and head norms, each an
+  argument DeepSeek-V2 leaves off;
 - `Mamba2Mixer` (with `_Conv1d`, `_dt_bias_init`; its float32 passes are
   `ops/gdn_passes.py`'s, which Qwen3-Next's DeltaNet layers call too) and
   the position-free `Attention`: Nemotron-H and Granite 4.0-H.
@@ -450,6 +453,104 @@ class RotaryAttention(nn.Module):
             return dense(d, self.dtype, "o_proj")(
                 out.reshape(b, t, h * hd).astype(self.dtype)
             )
+
+
+class LatentAttention(nn.Module):
+    """Multi-head latent attention (MLA) with no latent for the queries,
+    parameters by DeepSeek-V2's names: `q = q_proj(x)` [H heads of `nope`
+    + `rope`]; `[c | k_pe] = kv_a_proj_with_mqa(x)` [`kv_lora_rank` |
+    `rope`]; `c = kv_a_layernorm(c)`; `[k_nope | v] = kv_b_proj(c)` [H
+    heads of `nope` | `dv`].  The rotary (`cos`, `sin`: the caller's
+    tables over `rope` columns) turns a head's `q_pe` and the ONE `k_pe` a
+    token, which every head shares; causal softmax over the two parts'
+    `nope + rope` dimensions at `scale`, times `v_h`: two head sizes in
+    one attention (`ops/gqa.causal_attention`); `o_proj`.  Keys and values
+    are materialised from the latent, as training computes them.  What a
+    stack may add, each off where DeepSeek-V2 leaves it off:
+
+    - `head_norm_eps` a number: an RMSNorm of each head's assembled q and
+      k (`q_norm`, `k_norm`, one weight vector of `nope + rope` each)
+      BEFORE the rotary; a head's rotary key part is then its own (the
+      shared `k_pe` under that head's norm);
+    - `head_gate`: the heads' outputs times `sigmoid(x g_proj)` [H], one
+      value a head (float32 at `Precision.HIGHEST`, as Laguna's), under
+      the `attn_gate` scope.
+
+    Device scopes: `mla_latent` (the latent's projections, its norm, the
+    head norms, the rotary, the assembly of q and k), `mla_core` (the
+    engine), `attn_gate`."""
+
+    num_heads: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    kv_lora_rank: int
+    eps: float
+    dtype: Any
+    scale: float
+    impl: str = "auto"
+    head_norm_eps: Any = None
+    head_gate: bool = False
+
+    @nn.compact
+    def __call__(self, x, cos, sin):
+        b, t, d = x.shape
+        h, nope, rope, dv = (self.num_heads, self.qk_nope_head_dim,
+                             self.qk_rope_head_dim, self.v_head_dim)
+        rank, dtype = self.kv_lora_rank, self.dtype
+        q = dense(h * (nope + rope), dtype, "q_proj")(x).reshape(
+            b, t, h, nope + rope
+        )
+        with jax.named_scope("mla_latent"):
+            latent = dense(rank + rope, dtype, "kv_a_proj_with_mqa")(x)
+            k_pe = latent[..., rank:].reshape(b, t, 1, rope)
+            latent = RMSNorm(self.eps, name="kv_a_layernorm")(
+                latent[..., :rank]
+            )
+            kv = dense(h * (nope + dv), dtype, "kv_b_proj")(latent).reshape(
+                b, t, h, nope + dv
+            )
+            k_nope = kv[..., :nope]
+            if self.head_norm_eps is not None:
+                q = RMSNorm(self.head_norm_eps, name="q_norm")(q)
+                k = RMSNorm(self.head_norm_eps, name="k_norm")(
+                    jnp.concatenate(
+                        [k_nope, jnp.broadcast_to(k_pe, (b, t, h, rope))],
+                        axis=-1,
+                    )
+                )
+                k_nope, k_pe = k[..., :nope], k[..., nope:]
+            q = jnp.concatenate([
+                q[..., :nope].astype(dtype),
+                gqa.apply_rotary(q[..., nope:], cos, sin).astype(dtype),
+            ], axis=-1)
+            # The one rotated key part a token, given to every head.
+            k = jnp.concatenate([
+                k_nope.astype(dtype),
+                jnp.broadcast_to(
+                    gqa.apply_rotary(k_pe, cos, sin).astype(dtype),
+                    (b, t, h, rope),
+                ),
+            ], axis=-1)
+            v = kv[..., nope:].astype(dtype)
+        with jax.named_scope("mla_core"):
+            out = gqa.causal_attention(
+                q, k, v, scale=self.scale, impl=self.impl
+            )
+        if self.head_gate:
+            with jax.named_scope("attn_gate"):
+                w_gate = self.param(
+                    "g_proj", nn.initializers.lecun_normal(), (d, h),
+                    jnp.float32,
+                )
+                gate = jax.nn.sigmoid(jnp.dot(
+                    x.astype(jnp.float32), w_gate,
+                    precision=jax.lax.Precision.HIGHEST,
+                ))
+                out = out.astype(jnp.float32) * gate[..., None]
+        return dense(d, dtype, "o_proj")(
+            out.reshape(b, t, h * dv).astype(dtype)
+        )
 
 
 class Attention(nn.Module):

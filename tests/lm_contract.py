@@ -222,7 +222,7 @@ def _flag(value):
 
 #: The spans the worker writes a task from a model's counters
 #: (`layers/ledger.py`), each by the kind of model that has it.
-COUNTER_SPANS = ("moe.routing", "loop.exits", "diffusion.noise")
+COUNTER_SPANS = ("moe.routing", "loop.exits", "diffusion.noise", "kda.gates")
 
 
 def counter_spans(events, name="moe.routing", also=()):

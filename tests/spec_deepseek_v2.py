@@ -21,11 +21,13 @@ def _attention_at_the_stated_precision():
     model = dict(SPEC.tiny, hidden_size=256, qk_nope_head_dim=64,
                  qk_rope_head_dim=32, v_head_dim=64, kv_lora_rank=128,
                  sample_tokens=128)
-    layer = zoo.LatentAttention(zoo.DeepseekV2Config(**_model_kwargs(model)))
+    layer, tables = zoo.latent_attention(
+        zoo.DeepseekV2Config(**_model_kwargs(model)), 128
+    )
     x = jnp.asarray(
         np.random.default_rng(0).normal(size=(1, 128, 256)), jnp.float32
     )
-    return layer, (x,), lambda params, reading: ref._attention(
+    return layer, (x, *tables), lambda params, reading: ref._attention(
         params, x[0], model, rounded_parts(reading)
     )
 

@@ -124,7 +124,8 @@ def test_every_emitted_name_is_in_the_vocabulary():
         "checkpoint.restore.load", "proc.start",
         "master.tensorboard_init", "master.serve_ready",
         "worker.backend_init", "state.init", "compile.build",
-        "moe.routing", "loop.exits", "diffusion.noise", *BOOT_CHAIN_SPANS,
+        "moe.routing", "loop.exits", "diffusion.noise", "kda.gates",
+        *BOOT_CHAIN_SPANS,
     ):
         assert name in tracing.SPAN_NAMES
     assert set(tracing.DEVICE_SCOPES) >= {
@@ -134,12 +135,13 @@ def test_every_emitted_name_is_in_the_vocabulary():
         "ssm", "ssm_scan", "gdn_mix", "mla_latent", "mla_core",
         "attn_full", "attn_window", "attn_gate", "attn_proj", "attn_rotary",
         "loop", "block_norm", "exit_gate", "attn_blockdiff",
+        "kda", "kda_mix", "kda_gate", "kda_scan",
     }
     # every ledger a model's counters can have writes a span of the list
     from elasticdl_tpu.layers.ledger import task_ledgers
 
     assert [ledger.span for ledger in task_ledgers()] == [
-        "moe.routing", "loop.exits", "diffusion.noise",
+        "moe.routing", "loop.exits", "diffusion.noise", "kda.gates",
     ]
 
 
@@ -168,8 +170,8 @@ BOOT_CHAIN_METRICS = (
 
 def test_every_device_scope_and_start_up_span_names_its_reader():
     """`docs/observability.md` and PERF.md's span table name every device
-    scope, the `moe.routing`, `loop.exits` and `diffusion.noise` spans and
-    every span and field of the
+    scope, the `moe.routing`, `loop.exits`, `diffusion.noise` and
+    `kda.gates` spans and every span and field of the
     start-up chain, with the reader of each: nothing on the lists is
     without one, and `since_main_s` is gone from both."""
     with open(os.path.join(REPO_ROOT, "docs", "observability.md")) as f:
@@ -178,7 +180,7 @@ def test_every_device_scope_and_start_up_span_names_its_reader():
         perf = f.read()
     for name in (
         tracing.DEVICE_SCOPES
-        + ("moe.routing", "loop.exits", "diffusion.noise")
+        + ("moe.routing", "loop.exits", "diffusion.noise", "kda.gates")
         + BOOT_CHAIN_SPANS
         + BOOT_CHAIN_FIELDS
     ):
@@ -197,6 +199,10 @@ def test_every_device_scope_and_start_up_span_names_its_reader():
         assert f"`{field}`" in tracing.SPAN_NAMES["moe.routing"]
         assert f"`{field}`" in docs[docs.index("`moe.routing` is journaled"):]
         assert f"`{field}`" in span_table[span_table.index("| `moe.routing`"):]
+    # group-limited selection's two fields (ISSUE 53)
+    for field in ("groups_mean", "group_limit"):
+        assert f"`{field}`" in tracing.SPAN_NAMES["moe.routing"]
+        assert f"`{field}`" in docs[docs.index("`moe.routing` is journaled"):]
     assert "since_main_s" not in span_table.replace(
         "`since_main_s` gone", "")
     assert "`since_main_s`" not in docs
